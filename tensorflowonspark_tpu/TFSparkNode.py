@@ -225,7 +225,7 @@ def _raise_worker_error(mgr) -> None:
 
 
 def _run_map_fun(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
-                 mgr) -> None:
+                 mgr, profiler: bool = False) -> None:
     """Instrumented run of the user's ``map_fun`` — the ONE copy of the
     span/flush/state choreography shared by both input modes (the spawned
     SPARK-mode trainer and the inline TENSORFLOW-mode bootstrap task).
@@ -233,7 +233,9 @@ def _run_map_fun(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
     Invariants encoded here: the multi-host JAX runtime forms BEFORE user
     code runs (reference: TF_CONFIG was exported by the node runtime, not
     by ``map_fun`` — a ``map_fun`` that forgets the call must not silently
-    train per-host islands; no-op on single-node clusters); the trace
+    train per-host islands; no-op on single-node clusters); a node that
+    claimed chips proves JAX came up on exactly those chips before user
+    code trains on anything else (``chip_info.verify_claim``); the trace
     flush happens BEFORE the "finished" state is visible, because shutdown
     (and a driver ``dump_trace`` right after it) keys on that state and
     the ``map_fun`` span must already be on the blackboard by then; a
@@ -246,6 +248,11 @@ def _run_map_fun(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
 
         with obs.span("node.distributed_init"):
             distributed.maybe_initialize(ctx)
+        # this process owns the node's chips from here on: it is the first
+        # (and only long-lived) one to initialise the TPU backend
+        chip_info.verify_claim(_node_chips(ctx))
+        if profiler:
+            _start_profiler_server(ctx)
         fn = cloudpickle.loads(fn_blob)
         tf_args = cloudpickle.loads(args_blob)
         with obs.span("node.map_fun", executor_id=ctx.executor_id):
@@ -273,7 +280,33 @@ def _run_map_fun(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
         obs.flush(mgr)
 
 
-def _background_main(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext) -> None:
+def _node_chips(ctx: TFNodeContext) -> list[int]:
+    """The chips this node's bootstrap claimed (from its own registration)."""
+    for meta in ctx.cluster_info:
+        if meta["executor_id"] == ctx.executor_id:
+            return list(meta.get("chips") or [])
+    return []
+
+
+def _start_profiler_server(ctx: TFNodeContext) -> None:
+    """Start ``jax.profiler``'s server and publish its address on the
+    rendezvous kv.  ``start_server`` initialises the backend, so it runs
+    in the process that owns the chips, never in a bootstrap task about to
+    hand them to a child.  Best-effort: profiling must not stop training.
+    """
+    try:
+        import jax
+
+        _, prof_port = util.find_free_port()
+        jax.profiler.start_server(prof_port)
+        reservation.Client(ctx.server_addr, ctx.auth_token).put(
+            "profiler_address", f"{ctx.cluster_info[0]['host']}:{prof_port}")
+    except Exception as e:
+        logger.warning("could not start jax profiler server: %s", e)
+
+
+def _background_main(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
+                     profiler: bool = False) -> None:
     """Entry point of the spawned trainer process (SPARK input mode)."""
     util.ensure_jax_platform()
     mgr = ctx.mgr
@@ -285,7 +318,7 @@ def _background_main(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext) -> No
     # the spawned trainer is a fresh process: give its tracer the node
     # identity and the blackboard so its spans ship to the driver
     obs.configure(node=f"{ctx.job_name}:{ctx.task_index}", mgr=mgr)
-    _run_map_fun(fn_blob, args_blob, ctx, mgr)
+    _run_map_fun(fn_blob, args_blob, ctx, mgr, profiler)
 
 
 class _MapFn:
@@ -421,8 +454,10 @@ class _MapFn:
             auth_token=meta.get("auth_token"),
         )
 
-        if self.tensorboard and job_name in ("chief", "worker") and task_index == 0:
-            self._start_tensorboard(client, ctx)
+        profiler = bool(self.tensorboard and job_name in ("chief", "worker")
+                        and task_index == 0)
+        if profiler:
+            self._start_tensorboard(client)
 
         if meta["input_mode"] == "spark":
             import multiprocessing
@@ -430,7 +465,7 @@ class _MapFn:
             mp = multiprocessing.get_context("spawn")
             p = mp.Process(
                 target=_background_main,
-                args=(self.fn_blob, self.args_blob, ctx),
+                args=(self.fn_blob, self.args_blob, ctx, profiler),
                 name=f"tfos-trainer-{executor_id}",
                 daemon=True,
             )
@@ -456,26 +491,19 @@ class _MapFn:
             mgr.set("trainer_pid_start",
                     TFManager.proc_start_time(os.getpid()))
             mgr.set("trainer_pid", os.getpid())
-            _run_map_fun(self.fn_blob, self.args_blob, ctx, mgr)
+            _run_map_fun(self.fn_blob, self.args_blob, ctx, mgr, profiler)
 
-    def _start_tensorboard(self, client, ctx) -> None:
-        """Profiler endpoint + TensorBoard (when the binary exists).
+    def _start_tensorboard(self, client) -> None:
+        """Spawn the ``tensorboard`` CLI when the binary exists, publishing
+        its URL on the kv blackboard (reference used the TFManager kv — see
+        ``TFCluster.py::tensorboard_url``).
 
         Reference anchor: ``TFSparkNode.py::_mapfn`` tensorboard branch.  TPU
-        twist: always start ``jax.profiler.start_server`` so profiles can be
-        captured remotely; additionally spawn the ``tensorboard`` CLI if
-        installed, publishing its URL on the kv blackboard (reference used
-        the TFManager kv — see ``TFCluster.py::tensorboard_url``).
+        twist: the same flag also starts ``jax.profiler``'s server so
+        profiles can be captured remotely — in the trainer process
+        (:func:`_start_profiler_server`), because starting it initialises
+        the TPU backend.
         """
-        try:
-            util.ensure_jax_platform()
-            import jax
-
-            _, prof_port = util.find_free_port()
-            jax.profiler.start_server(prof_port)
-            client.put("profiler_address", f"{ctx.cluster_info[0]['host']}:{prof_port}")
-        except Exception as e:  # profiling is best-effort
-            logger.warning("could not start jax profiler server: %s", e)
         tb_bin = util.find_in_path(os.environ.get("PATH", ""), "tensorboard")
         if tb_bin:
             import subprocess

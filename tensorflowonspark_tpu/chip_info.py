@@ -34,24 +34,20 @@ _CLAIM_STALE_SECS = 600.0
 def get_num_host_chips() -> int:
     """Number of TPU chips attached to this host.
 
-    Order of preference: explicit ``TFOS_NUM_CHIPS`` override (tests, CPU
-    hosts), ``/dev/accel*`` device nodes, then ``TPU_ACCELERATOR_TYPE``
-    (e.g. ``v5litepod-4`` → 4 on a single-host slice), else 0.
+    ``TFOS_NUM_CHIPS`` overrides (tests, CPU hosts).  Otherwise the chips
+    are counted from their device nodes: a v5e host exposes one VFIO group
+    per chip as ``/dev/vfio/<n>`` (beside the ``/dev/vfio/vfio`` container
+    node); older TPU VMs expose ``/dev/accel<n>``.  The ``TPU_*``
+    environment is NOT consulted: a machine holding one chip of a four-chip
+    host still carries ``TPU_ACCELERATOR_TYPE=v5litepod-4`` and
+    ``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1``.
     """
     override = os.environ.get("TFOS_NUM_CHIPS")
     if override:
         return int(override)
-    accel = sorted(glob.glob("/dev/accel*"))
-    if accel:
-        return len(accel)
-    acc_type = os.environ.get("TPU_ACCELERATOR_TYPE", "")
-    if "-" in acc_type:
-        try:
-            total = int(acc_type.rsplit("-", 1)[1])
-            return min(total, 4)  # at most 4 chips per v5e host
-        except ValueError:
-            pass
-    return 0
+    vfio = [p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p).isdigit()]
+    return len(vfio) or len(glob.glob("/dev/accel*"))
 
 
 def _claim_dir(app_id: str) -> str:
@@ -171,16 +167,56 @@ def _reap_stale_claims(d: str) -> None:
             pass
 
 
+#: ``TPU_CHIPS_PER_PROCESS_BOUNDS`` by claim size: the claim's shape on the
+#: host's chip grid, not a flat count — a v5e host is 2x2, so four chips are
+#: ``2,2,1``.  All three came up on a v5e host (PR 21).  The flat ``4,1,1``
+#: did too, but only because that host's ``TPU_CHIPS_PER_HOST_BOUNDS`` told
+#: the runtime the grid anyway.
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
 def set_visibility_env(chips: list[int]) -> None:
     """Pin the TPU runtime to ``chips`` before JAX initialises.
 
     The TPU analogue of the reference exporting ``CUDA_VISIBLE_DEVICES``
     (``gpu_info.py::get_gpus`` caller side).  Must run before the first JAX
     device query in the process.
+
+    Also pins ``JAX_PLATFORMS=tpu`` for this process and the children that
+    inherit the claim (health probe, trainer): with chips claimed, a TPU
+    that fails to initialise must raise, not leave JAX quietly on the CPU.
     """
     if not chips:
         return
+    if len(chips) not in _PROCESS_BOUNDS:
+        raise ValueError(
+            f"cannot pin {len(chips)} chips to one process: a claim must "
+            f"be a rectangle of the host's chip grid "
+            f"({sorted(_PROCESS_BOUNDS)} chips)")
     os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
-    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = f"{len(chips)},1,1"
+    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = _PROCESS_BOUNDS[len(chips)]
     os.environ.setdefault("TPU_PROCESS_BOUNDS", "1,1,1")
+    # several executors of one host each load libtpu for their own chips
     os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    os.environ["JAX_PLATFORMS"] = "tpu"
+
+
+def verify_claim(chips: list[int]) -> None:
+    """Raise unless JAX in this process came up on exactly ``chips``.
+
+    Called by the node runtime in the process that owns the claim, before
+    the user's ``map_fun``: a claim of TPU chips that ends on another
+    platform, or on a different number of local devices, is a named error
+    at the driver, never a quiet run on the CPU.  No-op for a node that
+    claimed nothing (CPU hosts, tests).
+    """
+    if not chips:
+        return
+    import jax
+
+    devices = jax.local_devices()
+    if devices[0].platform != "tpu" or len(devices) != len(chips):
+        raise RuntimeError(
+            f"claimed TPU chips {chips} but JAX came up on {len(devices)} "
+            f"{devices[0].platform} device(s) ({devices[0].device_kind}); "
+            "refusing to train on anything but the claimed chips")

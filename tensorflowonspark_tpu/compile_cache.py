@@ -1,30 +1,36 @@
-"""Persistent, fleet-shared XLA compile cache on the ``fs.py`` seam.
+"""Persistent XLA compile cache: on by default, placed from outside.
 
-At mesh scale (many replicas × many tenants × bucket ladders) every
-process pays its own XLA compiles — the dominant cold-start cost.  The
-pre-warm half (``TFModel.warmup``, online warm-on-load) moves compiles
-off the first request's critical path but still pays them once per
-process; this module makes the *second* process (and the rest of the
-fleet) load executables from disk instead:
+Every process pays its own XLA compiles — the dominant cold-start cost of
+a trainer relaunch or a serving replica.  This module turns JAX's
+persistent compilation cache on for every compile-adjacent path (trainer
+construction, serving model load, warmup, the JNI shim's ``load``) and
+counts what it saves:
 
-- **Backing store**: JAX's persistent compilation cache, pointed at a
-  directory resolved through :mod:`tensorflowonspark_tpu.fs` — plain
-  local paths and ``file://`` work with zero dependencies; any remote
-  scheme (``gs://``, ``hdfs://``, ``memory://`` in tests) rides the
-  ``LocalFS``/``FsspecFS`` abstraction via a local **spool**: entries are
-  pulled from the remote namespace at configure time and pushed as new
-  compiles land, so one replica compiles and the fleet loads.
-- **Content-addressed, topology-fenced keys**: JAX's own cache key is a
-  content hash of the lowered computation + compile options + backend +
-  jax version, so a changed model or flag can never collide.  On top of
-  that every entry lives under a *topology namespace*
-  (``jax<ver>-<platform>-<device kind>-d<devices>-p<processes>``): a
-  stale or cross-device entry is not merely unlikely to load — it is
-  never even listed.  Remote entries additionally carry a ``.sha256``
-  sidecar written *after* the payload; the pull path verifies it and
-  **rejects corrupt or half-written entries** (counted in
-  ``serving_compile_cache_disk_writes_total``'s corrupt sibling) instead
-  of handing XLA a truncated executable.
+- **Where the cache lives** is JAX's own setting.  If
+  ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that directory and
+  :func:`ensure` sets none in code — it only installs the counters.  If it
+  is unset, the cache is on at a fixed path inside the checkout
+  (:data:`DEFAULT_DIR`, ``<repo>/.jax_cache``, git-ignored).  The path is
+  part of nothing here that moves: no temp dir, pid or timestamp, because a
+  directory that moves never hits.  No topology sub-directory either — JAX's
+  cache key already covers the computation, compile options, backend,
+  device kind and jax version, so a changed model or chip cannot collide.
+- **Fleet sharing** (optional): ``TFOS_COMPILE_CACHE_DIR=<uri>`` names a
+  shared root resolved through :mod:`tensorflowonspark_tpu.fs` (``gs://``,
+  ``hdfs://``, a shared mount, ``memory://`` in tests).  JAX's cache
+  cannot speak fsspec, so it is pointed at a local **spool** — the
+  ``JAX_COMPILATION_CACHE_DIR`` directory when that is set, else a fixed
+  per-namespace directory under :data:`SPOOL_DIR` beside the default cache
+  — and entries are pulled from the remote namespace at configure time and
+  pushed as new compiles land, so one replica compiles and the fleet
+  loads.  Remote entries live under a
+  *topology namespace* (``jax<ver>-<platform>-<device kind>-d<devices>-
+  p<processes>``) so a heterogeneous fleet sharing one bucket never even
+  lists another topology's entries, and each carries a ``.sha256`` sidecar
+  written *after* the payload; the pull path verifies it and **rejects
+  corrupt or half-written entries** (counted in
+  ``serving_compile_cache_disk_corrupt_total``) instead of handing XLA a
+  truncated executable.
 - **Observability**: disk hits / writes / corrupt-rejections counters and
   a ``serving_compile_disk_seconds`` retrieval-time histogram, split out
   of the in-process compile metrics (``serving_compile_cache_{hits,
@@ -34,15 +40,10 @@ fleet) load executables from disk instead:
   compiling thread, so ``serving.note_compile``'s settle logic can tell
   *this* forward's disk hit from a concurrent one.
 
-Configuration: ``TFOS_COMPILE_CACHE_DIR=<path-or-uri>`` enables;
-``TFOS_COMPILE_CACHE=0`` force-disables even when a dir is set;
+``TFOS_COMPILE_CACHE=0`` opts a process out (the test suite does);
 ``TFOS_COMPILE_CACHE_MIN_COMPILE_S`` (default 0 — serving forwards are
 small and the whole point is the fleet's long tail of them) bounds which
-compiles are worth writing; ``TFOS_COMPILE_CACHE_SPOOL`` overrides the
-local spool root for remote namespaces.  :func:`ensure` is called by
-every compile-adjacent path (trainer construction, serving model load,
-warmup, the JNI shim's ``load``) and is an unconditional no-op when
-unconfigured — zero behavior change unless opted in.
+compiles are worth writing.
 """
 
 from __future__ import annotations
@@ -55,6 +56,12 @@ import threading
 from typing import Any
 
 logger = logging.getLogger(__name__)
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` does not say
+DEFAULT_DIR = os.path.join(_REPO, ".jax_cache")
+#: parent of the per-namespace local spools of a fleet root
+SPOOL_DIR = os.path.join(_REPO, ".jax_cache_spool")
 
 #: JAX monitoring event names (jax/_src/compiler.py, compilation_cache.py).
 #: Note the naming skew: jax's "cache_misses" event fires when an entry is
@@ -77,11 +84,11 @@ _LISTENING = False
 
 _STATE: dict[str, Any] = {
     "attempted": False,     # one configure attempt per process
-    "namespace": None,      # logical cache namespace (root/topology), or None
-    "active_dir": None,     # the local dir jax actually reads/writes
-    "remote_ns": None,      # set only for remote roots
-    "spool": None,          # local spool backing a remote namespace
-    "pushed": set(),        # spool entry names verified to exist remotely
+    "namespace": None,      # remote namespace, else the local dir; or None
+    "active_dir": None,     # the local dir jax reads/writes
+    "set_dir": False,       # ensure() set jax's dir itself (env was unset)
+    "remote_ns": None,      # set only with a fleet root
+    "pushed": set(),        # local entry names verified to exist remotely
     "sync_scheduled": False,  # a delayed background push is pending
     "error": None,          # why configuration failed, if it did
 }
@@ -92,19 +99,19 @@ _STATE: dict[str, Any] = {
 # ---------------------------------------------------------------------------
 
 
-def cache_root() -> str | None:
-    """The configured cache root (path or URI), or None when disabled."""
-    if os.environ.get("TFOS_COMPILE_CACHE", "1").strip().lower() in (
-            "0", "false"):
-        return None
+def enabled() -> bool:
+    """False only under the ``TFOS_COMPILE_CACHE=0`` opt-out."""
+    return os.environ.get("TFOS_COMPILE_CACHE", "1").strip().lower() not in (
+        "0", "false")
+
+
+def fleet_root() -> str | None:
+    """The shared root (``TFOS_COMPILE_CACHE_DIR``, any ``fs.py`` URI) the
+    local cache is synced with, or None."""
     root = os.environ.get("TFOS_COMPILE_CACHE_DIR", "").strip()
     if not root or root.lower() in ("0", "off", "none"):
         return None
     return root
-
-
-def enabled() -> bool:
-    return cache_root() is not None
 
 
 def active() -> bool:
@@ -124,78 +131,74 @@ def min_compile_seconds() -> float:
 
 
 def topology_key() -> str:
-    """The topology namespace an entry set is valid for.
+    """The namespace of a fleet root an entry set is valid for.
 
     JAX's cache key already content-addresses the computation, backend
     and jax version; the namespace exists so a cross-device or
-    cross-version entry is never even LISTED for this process — shared-fs
-    roots serve heterogeneous fleets (a v5e pod and a CPU CI box can
-    share one bucket), and the failure mode "wrong executable silently
-    considered" must be structurally impossible, not just improbable.
-    Requires an initialized backend (callers are about to compile
-    anyway)."""
+    cross-version entry is never even LISTED for (or pulled by) this
+    process — shared roots serve heterogeneous fleets (a v5e pod and a
+    CPU CI box can share one bucket).  Requires an initialized backend
+    (callers are about to compile anyway)."""
     import jax
 
     devices = jax.devices()
     kind = devices[0].device_kind if devices else "unknown"
-    try:
-        processes = jax.process_count()
-    except Exception:
-        processes = 1
     raw = (f"jax{jax.__version__}-{jax.default_backend()}-{kind}"
-           f"-d{len(devices)}-p{processes}")
+           f"-d{len(devices)}-p{jax.process_count()}")
     return re.sub(r"[^A-Za-z0-9_.+-]+", "-", raw)
 
 
 def ensure() -> str | None:
     """Configure the persistent compile cache for this process (idempotent).
 
-    Returns the logical namespace in use, or None when disabled or
-    unconfigurable.  Never raises: a cache problem must not take down a
-    training step or a tenant load — the process just compiles like it
-    always did, and the reason lands in :func:`stats` (and so on
-    ``/healthz``)."""
+    Returns the namespace in use (the fleet namespace when a root is set,
+    else the local directory), or None when opted out or unconfigurable.
+    Never raises: a cache problem must not take down a training step or a
+    tenant load — the process just compiles like it always did, and the
+    reason lands in :func:`stats` (and so on ``/healthz``)."""
     with _LOCK:
         if _STATE["attempted"]:
             return _STATE["namespace"]
-        root = cache_root()
-        if root is None:
+        if not enabled():
             return None
         _STATE["attempted"] = True
         try:
-            _configure(root)
+            _configure()
         except Exception as e:  # pragma: no cover - env-specific failures
             _STATE["error"] = f"{type(e).__name__}: {e}"[:300]
             _STATE["namespace"] = None
-            logger.warning("persistent compile cache disabled: cannot "
-                           "configure %r: %s", root, e)
+            logger.warning("persistent compile cache disabled: %s", e)
         return _STATE["namespace"]
 
 
-def _configure(root: str) -> None:
+def _configure() -> None:
     from tensorflowonspark_tpu import fs, util
 
     util.ensure_jax_platform()
     import jax
 
-    namespace = fs.join(root, topology_key())
-    local = fs.local_path(namespace)
-    if local is not None:
+    root = fleet_root()
+    remote_ns = fs.join(root, topology_key()) if root is not None else None
+    local = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if local:
+        # jax read the variable at import and already uses that directory:
+        # no directory is set in code
         os.makedirs(local, exist_ok=True)
-        active = local
     else:
-        fs.makedirs(namespace)
-        spool = _spool_dir(namespace)
-        os.makedirs(spool, exist_ok=True)
-        _STATE["remote_ns"] = namespace
-        _STATE["spool"] = spool
-        active = spool
-        pulled = pull_entries(namespace, spool, pushed=_STATE["pushed"])
-        logger.info("compile cache %s: pulled %d entries to spool %s "
+        local = DEFAULT_DIR if remote_ns is None else os.path.join(
+            SPOOL_DIR, hashlib.sha256(remote_ns.encode()).hexdigest()[:16])
+        os.makedirs(local, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", local)
+        _STATE["set_dir"] = True
+    namespace = remote_ns or local
+    if remote_ns is not None:
+        fs.makedirs(remote_ns)
+        _STATE["remote_ns"] = remote_ns
+        pulled = pull_entries(remote_ns, local, pushed=_STATE["pushed"])
+        logger.info("compile cache %s: pulled %d entries to %s "
                     "(%d corrupt rejected)", namespace, pulled["pulled"],
-                    spool, pulled["corrupt"])
+                    local, pulled["corrupt"])
     _install_listeners()
-    jax.config.update("jax_compilation_cache_dir", active)
     # serving forwards compile in well under jax's 1s default; the fleet
     # amortizes even tiny compiles, so cache everything unless the
     # operator said otherwise via jax's own env knobs
@@ -204,56 +207,30 @@ def _configure(root: str) -> None:
                           min_compile_seconds())
     if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in os.environ:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _unlatch_jax_cache()
     _STATE["namespace"] = namespace
-    _STATE["active_dir"] = active
+    _STATE["active_dir"] = local
     logger.info("persistent compile cache at %s (local dir %s)",
-                namespace, active)
-
-
-def _spool_dir(namespace: str) -> str:
-    root = os.environ.get("TFOS_COMPILE_CACHE_SPOOL")
-    if not root:
-        import tempfile
-
-        root = os.path.join(tempfile.gettempdir(), "tfos-compile-spool")
-    tag = hashlib.sha256(namespace.encode()).hexdigest()[:16]
-    return os.path.join(root, tag)
-
-
-def _unlatch_jax_cache() -> None:
-    """Re-evaluate jax's once-per-process cache decision.
-
-    jax latches "is a cache configured?" at the first compile; a process
-    that compiled anything before :func:`ensure` ran (a health probe, an
-    unrelated jit) would otherwise ignore the directory forever.  Best
-    effort against jax internals: if the seam moves, the cache silently
-    stays off for such processes — never an error."""
-    try:  # pragma: no cover - depends on jax internals
-        from jax._src import compilation_cache as _cc
-
-        if getattr(_cc, "_cache_checked", False) or \
-                getattr(_cc, "_cache_initialized", False):
-            _cc.reset_cache()
-    except Exception:
-        pass
+                namespace, local)
 
 
 def disable() -> None:
-    """Tear the configuration down (tests, A/B benches): jax stops
-    consulting the directory and the next :func:`ensure` re-reads env."""
+    """Tear the configuration down (tests, A/B benches): the next
+    :func:`ensure` re-reads env.  Undoes only what :func:`ensure` set —
+    a directory jax took from ``JAX_COMPILATION_CACHE_DIR`` is the
+    caller's to change — and has jax drop the cache object it opened, so
+    a directory changed in between is re-read."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     with _LOCK:
-        _STATE.update(attempted=False, namespace=None, active_dir=None,
-                      remote_ns=None, spool=None, error=None,
-                      sync_scheduled=False)
-        _STATE["pushed"] = set()
-        try:
+        if _STATE["set_dir"]:
             import jax
 
             jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
-        _unlatch_jax_cache()
+        _STATE.update(attempted=False, namespace=None, active_dir=None,
+                      set_dir=False, remote_ns=None, error=None,
+                      sync_scheduled=False)
+        _STATE["pushed"] = set()
+        cc.reset_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -375,7 +352,8 @@ def push_entries(spool: str, remote_ns: str, pushed: set) -> int:
 
 
 def sync() -> int:
-    """Push spool entries that are not yet remote; no-op for local roots.
+    """Push spool entries that are not yet remote; no-op without a fleet
+    root.
 
     Called synchronously after warmup (the warm loop just produced the
     exact entry set the fleet wants) and asynchronously after data-plane
@@ -383,7 +361,7 @@ def sync() -> int:
     with _SYNC_LOCK:
         if not _STATE["remote_ns"]:
             return 0
-        n = push_entries(_STATE["spool"], _STATE["remote_ns"],
+        n = push_entries(_STATE["active_dir"], _STATE["remote_ns"],
                          _STATE["pushed"])
         if n:
             logger.info("compile cache: pushed %d new entries to %s", n,
@@ -522,7 +500,7 @@ def stats() -> dict[str, Any]:
 
     return {
         "enabled": enabled(),
-        "dir": cache_root(),
+        "dir": _STATE["active_dir"],
         "namespace": _STATE["namespace"],
         "remote": bool(_STATE["remote_ns"]),
         "error": _STATE["error"],

@@ -65,8 +65,7 @@ def load(export_dir: str, model_name: str = "") -> int:
     from tensorflowonspark_tpu import ckpt, compile_cache, saved_model
 
     # a JVM-embedded interpreter cold-starts like any other fleet member:
-    # point the jit compiles below at the persistent cache (no-op when
-    # TFOS_COMPILE_CACHE_DIR is unset)
+    # point the jit compiles below at the persistent cache
     compile_cache.ensure()
 
     path = export_dir

@@ -1,15 +1,19 @@
 """ctypes binding for the C++ TFRecord codec (``tfrecord_codec.cc``).
 
-Builds ``libtfrecord.so`` with g++ on first use (no pybind11 in the image —
+Builds the shared library with g++ on first use (no pybind11 in the image —
 the ABI is a 5-function ``extern "C"`` surface, so ctypes is the right-sized
-binding).  All functions degrade gracefully: if the compiler or the library
-is unavailable, ``available()`` is False and
-:mod:`tensorflowonspark_tpu.tfrecord` stays on its pure-Python path.
+binding).  The library's file name carries a hash of the source, so a build
+is needed exactly when no library for THIS source exists — never judged by
+mtimes, which a fresh copy of the tree flattens.  If the compiler or the
+library is unavailable, ``available()`` is False,
+:mod:`tensorflowonspark_tpu.tfrecord` stays on its pure-Python path, and
+:func:`load_error` says why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import mmap
 import os
@@ -20,20 +24,29 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "tfrecord_codec.cc")
-_LIB = os.path.join(_DIR, "libtfrecord.so")
 
 _lock = threading.Lock()
 _lib_state: list = []  # [CDLL_or_None] once probed
+_load_error: list = []  # [reason] when the probe ended without a library
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _LIB, _SRC]
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libtfrecord-{tag}.so")
+
+
+def _build(lib_path: str) -> None:
+    """Compile to a private name, then rename: concurrent first uses (one
+    per executor) each publish a whole library or none."""
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError) as e:
-        logger.info("native tfrecord codec build failed (%s); using Python", e)
-        return False
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load():
@@ -43,35 +56,45 @@ def _load():
         if _lib_state:
             return _lib_state[0]
         lib = None
-        if os.environ.get("TFOS_DISABLE_NATIVE") != "1":
-            if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            ):
-                _build()
-            if os.path.exists(_LIB):
-                try:
-                    lib = ctypes.CDLL(_LIB)
-                    u64p = ctypes.POINTER(ctypes.c_uint64)
-                    lib.tfr_write.restype = ctypes.c_long
-                    lib.tfr_write.argtypes = [
-                        ctypes.c_char_p, ctypes.c_char_p, u64p, ctypes.c_long]
-                    lib.tfr_index.restype = ctypes.c_long
-                    lib.tfr_index.argtypes = [
-                        ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int,
-                        ctypes.POINTER(u64p), ctypes.POINTER(u64p)]
-                    lib.tfr_free.argtypes = [ctypes.c_void_p]
-                    lib.tfr_masked_crc.restype = ctypes.c_uint
-                    lib.tfr_masked_crc.argtypes = [
-                        ctypes.c_char_p, ctypes.c_uint64]
-                except OSError as e:  # built for another arch, etc.
-                    logger.info("native tfrecord codec load failed: %s", e)
-                    lib = None
+        if os.environ.get("TFOS_DISABLE_NATIVE") == "1":
+            _load_error.append("TFOS_DISABLE_NATIVE=1")
+        else:
+            try:
+                lib_path = _lib_path()
+                if not os.path.exists(lib_path):
+                    _build(lib_path)
+                lib = ctypes.CDLL(lib_path)
+                u64p = ctypes.POINTER(ctypes.c_uint64)
+                lib.tfr_write.restype = ctypes.c_long
+                lib.tfr_write.argtypes = [
+                    ctypes.c_char_p, ctypes.c_char_p, u64p, ctypes.c_long]
+                lib.tfr_index.restype = ctypes.c_long
+                lib.tfr_index.argtypes = [
+                    ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int,
+                    ctypes.POINTER(u64p), ctypes.POINTER(u64p)]
+                lib.tfr_free.argtypes = [ctypes.c_void_p]
+                lib.tfr_masked_crc.restype = ctypes.c_uint
+                lib.tfr_masked_crc.argtypes = [
+                    ctypes.c_char_p, ctypes.c_uint64]
+            except (OSError, subprocess.SubprocessError) as e:
+                # no g++, a failed build, or a library for another arch
+                stderr = getattr(e, "stderr", b"") or b""
+                _load_error.append(
+                    f"{e!r} {stderr.decode(errors='replace')[-500:]}".strip())
+                logger.info("native tfrecord codec unavailable (%s); "
+                            "using Python", _load_error[0])
         _lib_state.append(lib)
         return lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> str | None:
+    """Why :func:`available` is False (None when the library loaded)."""
+    _load()
+    return _load_error[0] if _load_error else None
 
 
 def masked_crc(data: bytes) -> int:

@@ -1119,9 +1119,10 @@ def check_fleet(collector: FleetCollector, *,
                                  if fleet_warm is not None else None),
             "true_misses": int(cc_by_rid[rid]["misses"]),
             "persistent_dir": persistent,
-            "hint": ("no persistent compile cache configured: every "
-                     "replica (re)pays its own compiles — set "
-                     "TFOS_COMPILE_CACHE_DIR to a shared fs"
+            "hint": ("persistent compile cache off in this replica "
+                     "(TFOS_COMPILE_CACHE=0, or its directory could not "
+                     "be made): it (re)pays its own compiles — and set "
+                     "TFOS_COMPILE_CACHE_DIR to share them across hosts"
                      if not persistent else
                      "cold replica: first requests are paying compiles "
                      "or disk loads"),

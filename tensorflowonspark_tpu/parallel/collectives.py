@@ -764,7 +764,7 @@ def make_bucketed_train_step(
                 out.append(_ag(new_p[i]) if eligible[i] else new_p[i])
             return loss, new_cols, tuple(out), new_opt
 
-        smapped = mesh_lib.shard_map_compat(
+        smapped = mesh_lib.shard_map_unchecked(
             _local_step, mesh,
             in_specs=(replicated(state.params), opt_in_specs,
                       replicated(state.collections), batch_specs),
@@ -813,7 +813,7 @@ def make_bucketed_train_step(
                 new_cols = _cross_replica_mean_collections(new_cols)
             return loss, new_cols, tuple(reduced)
 
-        smapped = mesh_lib.shard_map_compat(
+        smapped = mesh_lib.shard_map_unchecked(
             _local_grads, mesh,
             in_specs=(replicated(state.params),
                       replicated(state.collections), batch_specs),

@@ -73,11 +73,7 @@ def maybe_initialize(ctx) -> bool:
         # Forced multi-process on chip-less hosts (tests, CPU clusters): the
         # CPU backend needs an explicit cross-process collectives impl before
         # backend init, or every process sees only its own local devices.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older jaxlib without gloo: proceed, islands only
-            logger.warning("CPU gloo collectives unavailable; "
-                           "cross-process collectives will not work")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     addr = coordinator_address(ctx.cluster_info)
     timeout_s = int(os.environ.get("TFOS_JAX_DISTRIBUTED_TIMEOUT", "300"))

@@ -120,17 +120,16 @@ def build_mesh(config: MeshConfig | None = None, devices: Sequence[Any] | None =
 
 def _device_array(shape: tuple, devices: list):
     """Devices → ndarray of ``shape``: ICI-torus-aware via ``mesh_utils``
-    on TPU, plain reshape on CPU test topologies."""
+    on TPU (a layout it cannot make raises — a silent reshape would put
+    mesh neighbours on chips that are not ICI neighbours), plain reshape on
+    CPU test topologies."""
     import numpy as np
 
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
-        if devices[0].platform == "tpu":
-            return mesh_utils.create_device_mesh(shape, devices=devices)
-        raise ValueError  # CPU: fall through to reshape
-    except Exception:
-        return np.asarray(devices).reshape(shape)
+        return mesh_utils.create_device_mesh(shape, devices=devices)
+    return np.asarray(devices).reshape(shape)
 
 
 def slice_groups(devices: Sequence[Any], n_slices: int) -> list[list]:
@@ -187,9 +186,8 @@ def hybrid_device_array(config: MeshConfig, devices: list):
     return stacked.reshape(tuple(sizes[a] for a in AXES))
 
 
-def shard_map_compat(f, mesh, *, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions
-    (the kwarg was renamed ``check_rep`` → ``check_vma``).
+def shard_map_unchecked(f, mesh, *, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off (``check_vma=False``).
 
     The one manual-collective entry point shared by ring attention, the
     GPipe schedule, the bucketed gradient collectives
@@ -197,17 +195,10 @@ def shard_map_compat(f, mesh, *, in_specs, out_specs):
     (``obs/roofline.py``) — so "the collective flavor the step path uses"
     is a single construction, not four drifting copies.
     """
-    import inspect
+    import jax
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-    params = inspect.signature(shard_map).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{kw: False})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # -- active mesh -------------------------------------------------------------

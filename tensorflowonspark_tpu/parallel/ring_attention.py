@@ -204,11 +204,11 @@ def make_sharded_attention(mesh, causal: bool = False, impl: str = "ring"):
 
 def _shard_map(f, mesh, *, in_specs, out_specs):
     """``shard_map`` with replication checking off — now a thin alias of
-    :func:`mesh.shard_map_compat` (shared with the bucketed gradient
+    :func:`mesh.shard_map_unchecked` (shared with the bucketed gradient
     collectives and the ICI roofline probe); kept for existing callers."""
-    from tensorflowonspark_tpu.parallel.mesh import shard_map_compat
+    from tensorflowonspark_tpu.parallel.mesh import shard_map_unchecked
 
-    return shard_map_compat(f, mesh, in_specs=in_specs, out_specs=out_specs)
+    return shard_map_unchecked(f, mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def local_attention(q, k, v, causal: bool = False, scale: float | None = None,

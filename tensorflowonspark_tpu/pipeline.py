@@ -593,7 +593,7 @@ class _RunModel:
 
         # the jit executables this load is about to mint are exactly what
         # the persistent compile cache amortizes across the fleet —
-        # configure it before the first compile (no-op when unconfigured)
+        # configure it before the first compile
         compile_cache.ensure()
         state = ckpt.load_pytree(path)
         params = state.get("params", state) if isinstance(state, dict) else state

@@ -16,6 +16,9 @@ from tensorflowonspark_tpu.sparkapi.rdd import RDD
 
 logger = logging.getLogger(__name__)
 
+#: how long ``stop()`` lets executors exit on their own before SIGTERM
+_STOP_GRACE_S = 60.0
+
 _MASTER_RE = re.compile(
     r"^(?:local\[(?P<n>\d+|\*)\]|local-cluster\[(?P<lc>\d+)\s*,[^\]]*\]|local)$"
 )
@@ -165,11 +168,16 @@ class LocalSparkContext:
                 tq.put(None)
             except (OSError, ValueError):
                 pass
-        deadline = time.monotonic() + 10.0
+        # an executor that ran the trainer in-process (InputMode.TENSORFLOW)
+        # tears its TPU client down on the way out; with four chips that
+        # outlasted the 10 s this used to allow, and the SIGTERM landed in
+        # the middle of it (v5e host, PR 21)
+        deadline = time.monotonic() + _STOP_GRACE_S
         for p in self._procs:
             p.join(timeout=max(0.1, deadline - time.monotonic()))
             if p.is_alive():
                 p.terminate()
+                p.join(timeout=5.0)
         self._result_queue.put(None)  # unblock the router
 
     # -- job execution -----------------------------------------------------

@@ -69,10 +69,11 @@ class Trainer:
         # whole constructor
         _t0_wall, _t0 = time.time(), time.perf_counter()
 
-        # persistent compile cache (TFOS_COMPILE_CACHE_DIR): configured
+        # persistent compile cache: on by default (at
+        # JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache), configured
         # BEFORE the init/step jit compiles below so a re-launched trainer
-        # fleet loads its executables from shared fs instead of re-paying
-        # XLA per process; an unconditional no-op when unconfigured
+        # loads its executables from disk instead of re-paying XLA per
+        # process; TFOS_COMPILE_CACHE=0 opts out
         from tensorflowonspark_tpu import compile_cache
 
         compile_cache.ensure()
@@ -193,17 +194,30 @@ class Trainer:
                 collection_shardings=col_overrides or None,
                 mesh_config=self.mesh_config,
             )
-        # sharded-update step: the eagerly-initialized optimizer state
-        # inherited the PARAM layout, but the compiled step stores
-        # scatter-eligible moments as dim-0 shards over the data axes —
-        # reshard once here so every step (and the checkpoint template,
-        # which targets self.state) sees the expected storage layout
+        # Place the state once where the compiled step will leave it, so
+        # every step (and the checkpoint template, which targets
+        # self.state) sees one layout.  Two things start elsewhere.  The
+        # eagerly-initialized optimizer state inherited the PARAM layout,
+        # but the sharded-update step stores scatter-eligible moments as
+        # dim-0 shards over the data axes.  And the eagerly made scalars
+        # (Adam's count, the step counter) are uncommitted single-device
+        # arrays that come back committed to the mesh: to jit a new
+        # signature, so the whole step compiled a second time at step 2
+        # (38 s of a cold ResNet-50 start on a v5e chip, PR 21).
+        replicated = mesh_lib.replicated(self.mesh)
+
+        def _home(leaf):
+            s = leaf.sharding
+            on_mesh = (isinstance(s, jax.sharding.NamedSharding)
+                       and s.mesh == self.mesh)
+            return s if on_mesh else replicated
+
+        homes = jax.tree_util.tree_map(_home, self.state)
         opt_sh = getattr(self.train_step, "opt_state_shardings", None)
         if opt_sh is not None:
-            self.state = TrainState(
-                self.state.params,
-                jax.device_put(self.state.opt_state, opt_sh),
-                self.state.step, self.state.collections)
+            homes = TrainState(homes.params, opt_sh, homes.step,
+                               homes.collections)
+        self.state = jax.device_put(self.state, homes)
         self.eval_step = make_eval_step(
             self.forward_fn, self.mesh, self.param_shardings,
             example, sequence_axes=self.sequence_axes,

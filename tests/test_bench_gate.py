@@ -36,23 +36,58 @@ def _half(value, *, metric="resnet50_images_per_sec_per_chip",
     return half
 
 
-# -- the acceptance check: the in-tree trajectory gates clean ----------------
+# -- the acceptance check: a recorded trajectory gates clean ------------------
 
 
-def test_in_tree_trajectory_produces_machine_readable_verdict():
-    paths = bench_gate.discover(REPO)
-    assert paths, "no BENCH_r*.json in the repo"
+@pytest.fixture()
+def recorded_trajectory(tmp_path):
+    """The shapes of the first five recorded rounds — an empty failure, two
+    healthy on-chip runs (the second with its wide_deep half), a timeout
+    with no number, and a LOUDLY degraded CPU fallback — written as
+    fixtures: records are not kept in the tree."""
+    def early(value, **kw):  # rounds before the roofline stamps
+        half = _half(value, **kw)
+        del half["mem_bw_gbps"], half["ici_bw_gbps"]
+        return half
+
+    resnet = dict(n_chips=1, batch_size=128, loss=4.8477, mfu=0.2989,
+                  flops_per_step=3060675379200.0)
+    wide = early(39.45, metric="wide_deep_steps_per_sec", n_chips=1,
+                 batch_size=4096, loss=0.0, mfu=0.0076,
+                 unit="steps/sec", vs_baseline=0.3945)
+    degraded = "accelerator unavailable: liveness probe failed: timeout " \
+               "after 60s"
+    cpu_wide = early(4262.56, metric="wide_deep_steps_per_sec",
+                     platform="cpu", degraded=degraded, batch_size=16,
+                     unit="steps/sec", vs_baseline=42.6256)
+
+    _write(tmp_path, "BENCH_r01.json", None, rc=1)
+    _write(tmp_path, "BENCH_r02.json", early(2462.82, **resnet))
+    _write(tmp_path, "BENCH_r03.json",
+           early(2462.3, secondary=wide, **resnet))
+    _write(tmp_path, "BENCH_r04.json", None, rc=124)
+    _write(tmp_path, "BENCH_r05.json",
+           early(6689.02, platform="cpu", degraded=degraded, batch_size=16,
+                 secondary=cpu_wide,
+                 probe={"ok": False, "error": "timeout after 60s"}))
+    return str(tmp_path)
+
+
+def test_in_tree_trajectory_produces_machine_readable_verdict(
+        recorded_trajectory):
+    paths = bench_gate.discover(recorded_trajectory)
+    assert len(paths) == 5
     verdict = bench_gate.gate(paths)
     # round-trips through strict JSON (machine-readable contract)
     assert json.loads(json.dumps(verdict))["verdict"] == verdict["verdict"]
-    # the in-tree history must never fail the gate: r05 is LOUDLY degraded
+    # a recorded history must never fail the gate: r05 is LOUDLY degraded
     # (skip), r01/r04 are prior-round empties (warn)
     assert verdict["verdict"] in ("pass", "skip")
     assert verdict["reasons"] == []
 
 
-def test_in_tree_artifacts_all_schema_validate():
-    for path in bench_gate.discover(REPO):
+def test_in_tree_artifacts_all_schema_validate(recorded_trajectory):
+    for path in bench_gate.discover(recorded_trajectory):
         art = bench_gate.load_artifact(path)
         assert art["problems"] == [], f"{path}: {art['problems']}"
         if art["parsed"] is None:

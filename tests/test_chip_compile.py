@@ -1,0 +1,398 @@
+"""What a CPU box can prove about the chip path (PR 21 bring-up).
+
+Three groups:
+
+- **step programs compile for the real chip**: the TPU compiler is installed
+  here and compiles for a *described* ``v5e:2x2`` topology with no chip
+  attached (``on-chip-measurement`` guide, section 2).  Nothing runs, so
+  these say nothing about results or times — they catch what the chip's
+  compiler would refuse (memory, layouts, collectives) at no chip time.
+  ``Trainer`` builds its mesh from ``jax.devices()`` and materialises its
+  own parameters, which a described device cannot hold, so
+  :func:`abstract_train_step` repeats ``Trainer.__init__``'s wiring with
+  ``jax.eval_shape`` in place of arrays — the harness lives here, not as an
+  option of ``Trainer``.
+- **no fallback that hides the device**: chip pinning, the claim check, and
+  a cluster whose trainer cannot reach its claimed chip.
+- **the compile cache is placed from outside**, and ``chip_smoke.py``'s
+  parent stays off JAX.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else compiler logs in /tmp
+
+import jax  # noqa: E402
+
+from tensorflowonspark_tpu import (TFCluster, chip_info,  # noqa: E402
+                                   compile_cache)
+from tensorflowonspark_tpu import models as model_zoo  # noqa: E402
+from tensorflowonspark_tpu.sparkapi import LocalSparkContext  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# ---------------------------------------------------------------------------
+# Step programs compile for a described v5e:2x2
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this environment
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def abstract_train_step(model_name, config, devices, batch_size):
+    """``Trainer.__init__``'s wiring over ``devices`` with shapes for arrays.
+
+    Returns ``(step, state, batch)``: the real compiled-step factory's
+    output (optimizer, donation, sharded update where the mesh allows), and
+    the abstract train state / batch to ``step.lower`` it with.
+    """
+    import optax
+
+    from tensorflowonspark_tpu.parallel import (
+        build_mesh, create_train_state, make_train_step,
+        param_sharding_from_metadata)
+    from tensorflowonspark_tpu.parallel.train import unbox
+    from tensorflowonspark_tpu.trainer import _model_inputs
+
+    lib = model_zoo.get_model(model_name)
+    mesh = build_mesh(None, devices=devices)
+    model = lib.make_model(config, mesh=mesh)
+    optimizer = optax.adamw(1e-3)  # Trainer's default
+    loss_fn = lib.make_loss_fn(model, config)
+    init_args = _model_inputs(lib.example_batch(config, batch_size=2))
+
+    def init_params():
+        return model.init(jax.random.PRNGKey(0), *init_args)["params"]
+
+    param_shardings = param_sharding_from_metadata(
+        jax.eval_shape(init_params), mesh)
+    state = jax.eval_shape(
+        lambda: create_train_state(unbox(init_params()), optimizer))
+    batch = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((batch_size,) + a.shape[1:], a.dtype),
+        lib.example_batch(config, batch_size=2))
+    step = make_train_step(loss_fn, optimizer, mesh, param_shardings, state,
+                           batch)
+    return step, state, batch
+
+
+def _param_count(state) -> int:
+    return sum(int(leaf.size)
+               for leaf in jax.tree_util.tree_leaves(state.params))
+
+
+def _collective_counts(lowered, compiled) -> tuple[dict, dict]:
+    """Collective ops the program asks for (lowered StableHLO) and what the
+    TPU compiler made of them (compiled HLO)."""
+    asked, made = lowered.as_text(), compiled.as_text()
+    return (
+        {op: asked.count(f"stablehlo.{op}")
+         for op in ("reduce_scatter", "all_gather", "all_reduce")},
+        {op: made.count(f"{op}(") + made.count(f"{op}-start(")
+         for op in ("reduce-scatter", "all-gather", "all-reduce")})
+
+
+def _assert_sharded_exchange(step, lowered, compiled) -> None:
+    """The program asks for reduce-scatter + all-gather and no all-reduce,
+    as ``tests/test_collectives.py`` pins on CPU.  The installed TPU
+    compiler keeps the all-gathers but folds every reduce-scatter into
+    all-reduce (+ slice): a replicated bucket's scatter-then-gather pair IS
+    an all-reduce, and it combines them all into one (PERF.md, PR 21) — so
+    of the compiled module only "collectives are there" is asserted."""
+    assert step.update_sharded and step.n_scatter_buckets > 0
+    asked, made = _collective_counts(lowered, compiled)
+    assert asked["reduce_scatter"] > 0 and asked["all_gather"] > 0, asked
+    assert asked["all_reduce"] == 0, asked
+    assert made["all-gather"] > 0, made
+    assert made["reduce-scatter"] + made["all-reduce"] > 0, made
+
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_resnet50_full_width_step_compiles_for_one_v5e_chip(topo):
+    """Every width of ``resnet.Config()`` (depth cut to one block a stage),
+    the real optimizer and donation, batch 128, one described chip."""
+    from tensorflowonspark_tpu.models import resnet
+
+    config = resnet.Config(stage_sizes=(1, 1, 1, 1))
+    step, state, batch = abstract_train_step(
+        "resnet50", config, topo.devices[:1], 128)
+    compiled = step.lower(state, batch).compile()
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+    # the widths are the published ones: 64..2048 channels, 1000 classes
+    assert state.params["Dense_0"]["kernel"].shape == (2048, 1000)
+
+
+@pytest.mark.slow  # ~45 s each here; the builder's by-hand rehearsal
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_resnet50_full_depth_step_compiles_for_v5e(topo, n_devices):
+    from tensorflowonspark_tpu.models import resnet
+
+    step, state, batch = abstract_train_step(
+        "resnet50", resnet.Config(), topo.devices[:n_devices], 128)
+    assert _param_count(state) == 25_557_032
+    lowered = step.lower(state, batch)
+    compiled = lowered.compile()
+    print(f"resnet50 full depth, {n_devices} described device(s): "
+          f"{compiled.memory_analysis()} "
+          f"{_collective_counts(lowered, compiled)}")
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+    if n_devices == 4:
+        _assert_sharded_exchange(step, lowered, compiled)
+    else:
+        assert not step.bucketed  # one data shard: nothing to exchange
+
+
+def test_mnist_mlp_step_compiles_for_one_v5e_chip(topo):
+    from tensorflowonspark_tpu.models import mnist
+
+    step, state, batch = abstract_train_step(
+        "mnist_mlp", mnist.Config(), topo.devices[:1], 128)
+    compiled = step.lower(state, batch).compile()
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+    assert not step.bucketed  # one data shard: nothing to exchange
+
+
+def test_mnist_mlp_sharded_update_compiles_for_four_v5e_chips(topo):
+    """The default sharded-update step over a 4-device mesh of the described
+    chips, through the TPU compiler."""
+    from tensorflowonspark_tpu.models import mnist
+
+    step, state, batch = abstract_train_step(
+        "mnist_mlp", mnist.Config(), topo.devices, 128)
+    lowered = step.lower(state, batch)
+    _assert_sharded_exchange(step, lowered, lowered.compile())
+
+
+def test_peak_tables_know_the_device_kind_the_chip_reports(topo):
+    """``TPU v5 lite`` is what the v5e reports (chip run, PR 21) and what
+    the described topology reports; both peak tables must resolve it."""
+    sys.path.insert(0, REPO)
+    import bench
+    from tensorflowonspark_tpu.obs import roofline
+
+    kind = topo.devices[0].device_kind
+    assert kind == "TPU v5 lite"
+    peak = next(p for key, p in bench.PEAK_FLOPS if key in kind.lower())
+    assert peak == 197e12
+    assert roofline.hbm_peak_gbps(kind) == 819.0
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_trainer_compiles_its_step_once(n_devices):
+    """Found on the chip: the eagerly made scalars of the state (Adam's
+    count, the step counter) changed placement across the first step, and
+    the step compiled twice — the second time at step 2."""
+    from tensorflowonspark_tpu.models import mnist
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    trainer = Trainer("mnist_mlp", config=mnist.Config(),
+                      devices=jax.devices()[:n_devices])
+    batch = trainer.shard(mnist.example_batch(mnist.Config(), batch_size=8))
+    for _ in range(3):
+        trainer.step(batch)
+    assert trainer.train_step._jitted._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# No fallback that hides the device
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("chips,bounds", [
+    ([0], "1,1,1"), ([2, 3], "1,2,1"), ([0, 1, 2, 3], "2,2,1")])
+def test_set_visibility_env_pins_platform_and_bounds(monkeypatch, chips,
+                                                     bounds):
+    for name in ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                 "TPU_PROCESS_BOUNDS", "ALLOW_MULTIPLE_LIBTPU_LOAD"):
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    chip_info.set_visibility_env(chips)
+    assert os.environ["TPU_VISIBLE_CHIPS"] == ",".join(map(str, chips))
+    assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == bounds
+    assert os.environ["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
+
+
+def test_set_visibility_env_refuses_a_claim_that_is_no_rectangle(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with pytest.raises(ValueError, match="3 chips"):
+        chip_info.set_visibility_env([0, 1, 2])
+    chip_info.set_visibility_env([])  # nothing claimed: nothing pinned
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+
+
+def test_host_chips_counted_from_vfio_nodes_not_tpu_env(monkeypatch):
+    """A one-chip machine cut from a four-chip host still says
+    ``TPU_ACCELERATOR_TYPE=v5litepod-4``: only device nodes count."""
+    monkeypatch.delenv("TFOS_NUM_CHIPS")
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    nodes = {"/dev/vfio/*": ["/dev/vfio/0", "/dev/vfio/vfio"],
+             "/dev/accel*": []}
+    monkeypatch.setattr(chip_info.glob, "glob", lambda pat: nodes[pat])
+    assert chip_info.get_num_host_chips() == 1
+    nodes["/dev/vfio/*"] = ["/dev/vfio/vfio"]
+    assert chip_info.get_num_host_chips() == 0
+
+
+def test_verify_claim_names_a_cpu_that_stood_in_for_a_chip():
+    chip_info.verify_claim([])  # nothing claimed: nothing to prove
+    with pytest.raises(RuntimeError, match=r"claimed TPU chips \[0\].*cpu"):
+        chip_info.verify_claim([0])
+
+
+def _train_on_whatever_is_there(marker_path, ctx):
+    import jax.numpy as jnp
+
+    with open(marker_path, "w", encoding="utf-8") as f:
+        f.write(str(jnp.ones(2).devices()))
+
+
+@pytest.mark.parametrize("mode", ["spark", "tensorflow"])
+def test_claimed_chip_out_of_reach_fails_at_driver_naming_executor(
+        monkeypatch, tmp_path, mode):
+    """A faked one-chip claim on this chip-less box: the trainer's TPU init
+    fails (the claim pinned ``JAX_PLATFORMS=tpu``), and the driver gets the
+    executor's name — not a model quietly trained on the CPU."""
+    monkeypatch.setenv("TFOS_NUM_CHIPS", "1")
+    monkeypatch.setenv("TFOS_SCRATCH_ROOT", str(tmp_path))
+    input_mode = (TFCluster.InputMode.SPARK if mode == "spark"
+                  else TFCluster.InputMode.TENSORFLOW)
+    trained = tmp_path / "trained"
+    sc = LocalSparkContext("local-cluster[1,1,1024]", f"claim-{mode}")
+    try:
+        # probe off: the failure under test is the trainer's own, not the
+        # probe child's (which the same pin fails first when it is on)
+        with pytest.raises(RuntimeError) as err:
+            cluster = TFCluster.run(
+                sc, _train_on_whatever_is_there, str(trained),
+                num_executors=1, input_mode=input_mode,
+                num_chips_per_executor=1, health_probe=False,
+                reservation_timeout=60)
+            cluster.shutdown(grace_secs=30)
+        text = _error_chain(err.value)
+        assert "executor 0" in text
+        assert "Unable to initialize backend 'tpu'" in text
+        assert not trained.exists()
+    finally:
+        sc.stop()
+
+
+def _error_chain(e: BaseException) -> str:
+    parts = []
+    while e is not None:
+        parts.append(str(e))
+        e = e.__cause__ or e.__context__
+    return "\n".join(parts)
+
+
+# ---------------------------------------------------------------------------
+# Compile cache placed from outside; chip_smoke's parent stays off JAX
+# ---------------------------------------------------------------------------
+
+
+def _ensure_recording_dir_updates(monkeypatch):
+    """Run ``compile_cache.ensure()`` fresh, recording every directory it
+    sets on jax's config."""
+    set_dirs = []
+    real_update = jax.config.update
+
+    def update(name, value):
+        if name == "jax_compilation_cache_dir":
+            set_dirs.append(value)
+        real_update(name, value)
+
+    monkeypatch.delenv("TFOS_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("TFOS_COMPILE_CACHE_DIR", raising=False)
+    compile_cache.disable()
+    monkeypatch.setattr(jax.config, "update", update)
+    return compile_cache.ensure(), set_dirs
+
+
+def test_cache_dir_from_env_is_left_to_jax(monkeypatch, tmp_path):
+    d = str(tmp_path / "x")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)  # as jax's import did
+    try:
+        ns, set_dirs = _ensure_recording_dir_updates(monkeypatch)
+        assert ns == d and set_dirs == []
+        assert jax.config.jax_compilation_cache_dir == d
+        assert compile_cache.active()
+    finally:
+        compile_cache.disable()
+        jax.config.update("jax_compilation_cache_dir", None)
+
+
+def test_cache_dir_defaults_to_fixed_path_in_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        ns, set_dirs = _ensure_recording_dir_updates(monkeypatch)
+        assert ns == os.path.join(REPO, ".jax_cache") == set_dirs[-1]
+        assert jax.config.jax_compilation_cache_dir == ns
+    finally:
+        compile_cache.disable()
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_no_cache_path_is_built_from_tempfile_pid_or_time():
+    with open(os.path.join(REPO, "tensorflowonspark_tpu",
+                           "compile_cache.py"), encoding="utf-8") as f:
+        src = f.read()
+    for needle in ("tempfile", "getpid", "time.time", "mkdtemp"):
+        assert needle not in src, needle
+
+
+def _run_chip_smoke(*argv, **env):
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *argv],
+        capture_output=True, text=True, timeout=170, cwd=REPO,
+        env=dict(os.environ, **env))
+
+
+def test_chip_smoke_parent_imports_no_jax():
+    prog = ("import sys; sys.argv = ['chip_smoke.py']\n"
+            "import chip_smoke\n"
+            "chip_smoke.parse_args([])\n"
+            "assert 'jax' not in sys.modules, 'parent imported jax'\n"
+            "print('clean')\n")
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, timeout=120, cwd=REPO)
+    assert out.returncode == 0 and "clean" in out.stdout, out.stderr[-2000:]
+
+
+def test_chip_smoke_refuses_a_cpu_naming_the_phase(tmp_path):
+    """The sandbox rehearsal of the contract: with no chip the script
+    exits non-zero, names the phase that found no TPU, and prints no
+    result line."""
+    out = _run_chip_smoke("--out", str(tmp_path), JAX_PLATFORMS="cpu")
+    assert out.returncode != 0
+    assert "phase devices" in out.stderr and "cpu" in out.stderr
+    for line in out.stdout.splitlines():
+        assert '"ok"' not in line, line

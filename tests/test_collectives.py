@@ -218,6 +218,14 @@ def test_bucketed_matches_monolithic_stateful_zero():
                         stateful=True)
 
 
+def _asked_allreduces(step, state, mesh, batch) -> int:
+    """All-reduce ops in the module the step LOWERS to — the structure the
+    program asks for.  The compiled module no longer shows it: the
+    installed XLA combines the per-bucket all-reduces into one."""
+    lowered = step.lower(state, shard_batch(mesh, batch))
+    return lowered.as_text().count("stablehlo.all_reduce")
+
+
 def test_bucketed_step_emits_one_collective_per_bucket():
     """The PR 12 structural claim (all-reduce structure, pinned via
     ``update_shard=False``): the lowered HLO carries one explicit
@@ -228,8 +236,7 @@ def test_bucketed_step_emits_one_collective_per_bucket():
     buck = make_bucketed_train_step(loss_fn, opt, mesh, shardings, state,
                                     batch, bucket_bytes=200,
                                     update_shard=False)
-    hlo = buck.lower(state, shard_batch(mesh, batch)).compile().as_text()
-    n_allreduce = hlo.count("all-reduce(") + hlo.count("all-reduce-start(")
+    n_allreduce = _asked_allreduces(buck, state, mesh, batch)
     assert n_allreduce == buck.n_buckets + 1, (n_allreduce, buck.n_buckets)
 
 
@@ -245,11 +252,8 @@ def test_no_reduce_twin_diverges():
     nored = make_bucketed_train_step(loss_fn, opt, mesh, shardings, state2,
                                      batch, bucket_bytes=200, reduce=False)
     assert nored.update_sharded is False  # forced off on the twin
-    hlo_b = buck.lower(state, shard_batch(mesh, batch)).compile().as_text()
-    hlo_n = nored.lower(state2,
-                        shard_batch(mesh, batch)).compile().as_text()
-    count = lambda h: h.count("all-reduce(") + h.count("all-reduce-start(")  # noqa: E731
-    assert count(hlo_n) < count(hlo_b)
+    assert _asked_allreduces(nored, state2, mesh, batch) \
+        < _asked_allreduces(buck, state, mesh, batch)
 
 
 def test_indivisible_batch_fails_like_monolithic():
@@ -628,8 +632,7 @@ def test_sharded_update_env_opt_out(monkeypatch):
     step = make_bucketed_train_step(loss_fn, opt, mesh, shardings, state,
                                     batch, bucket_bytes=200)
     assert step.update_sharded is False
-    counts = _hlo_counts(step, state, mesh, batch)
-    assert counts["all-reduce"] == step.n_buckets + 1
+    assert _asked_allreduces(step, state, mesh, batch) == step.n_buckets + 1
     monkeypatch.delenv("TFOS_SHARDED_UPDATE")
     step = make_bucketed_train_step(loss_fn, opt, mesh, shardings, state,
                                     batch, bucket_bytes=200,

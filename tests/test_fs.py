@@ -231,8 +231,9 @@ def test_compile_cache_corrupt_and_halfwritten_entries_rejected(tmp_path):
 def test_compile_cache_remote_namespace_configures_spool(tmp_path,
                                                          monkeypatch):
     """ensure() against a remote scheme: jax is pointed at a LOCAL spool
-    (the LRU cache cannot speak fsspec), the remote namespace is created
-    through fs.py, and pre-existing remote entries are pulled in."""
+    (the LRU cache cannot speak fsspec) at a fixed per-namespace path, the
+    remote namespace is created through fs.py, and pre-existing remote
+    entries are pulled in."""
     pytest.importorskip("fsspec")
     from tensorflowonspark_tpu import compile_cache
 
@@ -240,7 +241,8 @@ def test_compile_cache_remote_namespace_configures_spool(tmp_path,
     # pre-seed the topology namespace with one valid remote entry
     monkeypatch.setenv("TFOS_COMPILE_CACHE_DIR", root)
     monkeypatch.delenv("TFOS_COMPILE_CACHE", raising=False)
-    monkeypatch.setenv("TFOS_COMPILE_CACHE_SPOOL", str(tmp_path / "spools"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "SPOOL_DIR", str(tmp_path / "spools"))
     compile_cache.disable()
     try:
         ns = fs.join(root, compile_cache.topology_key())
@@ -258,6 +260,7 @@ def test_compile_cache_remote_namespace_configures_spool(tmp_path,
 
         spool = jax.config.jax_compilation_cache_dir
         assert spool and os.path.isdir(spool)
+        assert os.path.dirname(spool) == str(tmp_path / "spools")
         assert fs.local_path(spool) == spool  # jax got a LOCAL dir
         assert (os.path.join(spool, "jit_seed-cache")) and \
             os.path.exists(os.path.join(spool, "jit_seed-cache"))

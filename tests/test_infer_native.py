@@ -91,7 +91,7 @@ def test_demo_runs_without_python_driver(export):
     path, params, forward, dim = export
     env = dict(os.environ)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("TFOS_JAX_PLATFORM", "cpu")
+    env.setdefault("JAX_PLATFORMS", "cpu")
     env.setdefault("TFOS_NUM_CHIPS", "0")
     proc = subprocess.run(
         [demo, path, "mnist_mlp", "4", str(dim)],
@@ -124,7 +124,7 @@ def _run_harness(export_dir, model_name, batch, dim, tmpdir):
         pytest.skip("JNI harness did not build")
     env = dict(os.environ)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("TFOS_JAX_PLATFORM", "cpu")
+    env.setdefault("JAX_PLATFORMS", "cpu")
     env.setdefault("TFOS_NUM_CHIPS", "0")
     for attempt in range(1 + _HARNESS_STARTUP_RETRIES):
         proc = subprocess.run(

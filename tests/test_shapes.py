@@ -269,25 +269,37 @@ def test_warmup_policy_fallback_for_weights_only_zoo_export(tmp_path):
 
 @pytest.fixture()
 def cache_dir_env(tmp_path, monkeypatch):
+    """Cache on, placed the way an operator places it: through
+    ``JAX_COMPILATION_CACHE_DIR``.  jax reads that variable at import, so
+    the fixture hands the already-imported jax the same value."""
+    import jax
+
     d = str(tmp_path / "cc")
-    monkeypatch.setenv("TFOS_COMPILE_CACHE_DIR", d)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
     monkeypatch.delenv("TFOS_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("TFOS_COMPILE_CACHE_DIR", raising=False)
     compile_cache.disable()
+    jax.config.update("jax_compilation_cache_dir", d)
     yield d
     compile_cache.disable()
+    jax.config.update("jax_compilation_cache_dir", None)
 
 
 def test_compile_cache_disabled_is_total_noop(monkeypatch):
-    monkeypatch.delenv("TFOS_COMPILE_CACHE_DIR", raising=False)
+    import jax
+
+    monkeypatch.setenv("TFOS_COMPILE_CACHE", "0")
     compile_cache.disable()
     assert compile_cache.ensure() is None
     assert not compile_cache.active()
+    assert jax.config.jax_compilation_cache_dir is None
     st = compile_cache.stats()
     assert st["enabled"] is False and st["namespace"] is None
 
 
 def test_compile_cache_opt_out_wins(monkeypatch, tmp_path):
-    monkeypatch.setenv("TFOS_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("TFOS_COMPILE_CACHE_DIR", str(tmp_path / "fleet"))
     monkeypatch.setenv("TFOS_COMPILE_CACHE", "0")
     compile_cache.disable()
     assert compile_cache.ensure() is None
@@ -295,18 +307,18 @@ def test_compile_cache_opt_out_wins(monkeypatch, tmp_path):
     compile_cache.disable()
 
 
-def test_compile_cache_local_namespace_and_writes(cache_dir_env):
-    """ensure() namespaces the root by topology (stale/cross-device
-    entries are never even listed) and a first compile writes an entry
-    the disk-writes counter sees."""
+def test_compile_cache_local_dir_and_writes(cache_dir_env):
+    """ensure() uses the directory jax was given — no sub-namespace: jax's
+    own key fences backend, device kind and version — and a first compile
+    writes an entry the disk-writes counter sees."""
     import jax
     import jax.numpy as jnp
 
     ns = compile_cache.ensure()
-    assert ns is not None
-    assert ns == os.path.join(cache_dir_env, compile_cache.topology_key())
-    assert os.path.isdir(ns)
+    assert ns == cache_dir_env
+    assert jax.config.jax_compilation_cache_dir == cache_dir_env
     assert compile_cache.active()
+    assert compile_cache.stats()["dir"] == cache_dir_env
 
     writes0 = compile_cache.stats()["disk_writes"]
     salt = np.float32(np.random.RandomState(7).randn())  # unique jaxpr
@@ -369,7 +381,7 @@ def test_second_process_cold_start_hits_disk(cache_dir_env):
         "np.asarray(f(np.ones((17, 17), np.float32)))\n"
         "print(json.dumps(compile_cache.stats()))\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               TFOS_COMPILE_CACHE_DIR=cache_dir_env)
+               JAX_COMPILATION_CACHE_DIR=cache_dir_env)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def run():

@@ -86,7 +86,7 @@ within one step config identity.  From round ``--require-coldstart-from``
 (default 15, the round that introduced the persistent compile cache) the
 primary half must carry ``coldstart_seconds`` — second-process cold
 start (fresh subprocess, real tenant load + ladder warmup, time to first
-served request) measured against a seeded ``TFOS_COMPILE_CACHE_DIR`` —
+served request) measured against a seeded compile-cache directory —
 or an explicit ``null`` + ``coldstart_reason``; a numeric value must
 ship its cache-off A/B partner ``coldstart_seconds_nocache``, a numeric
 ``coldstart_disk_hits`` (a "cached" arm that never touched disk measured

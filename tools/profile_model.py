@@ -61,7 +61,7 @@ from bench import ACCEL_BATCH as _ACCEL_BATCH  # noqa: E402 one source of truth
 
 def _run_trace(args, logdir: str) -> dict:
     if args.force_cpu:
-        os.environ["TFOS_JAX_PLATFORM"] = "cpu"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ.setdefault("TFOS_NUM_CHIPS", "0")
     from tensorflowonspark_tpu import util
 
