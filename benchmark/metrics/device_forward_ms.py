@@ -1,0 +1,10 @@
+"""Kernels: device time a traced step spent in operations under the
+differentiated loss (``jvp(...)`` in the operation's ``op_name``; the
+program's ``forward`` scope). The line before the result gives all phases, and
+the time of the operations that carry no name."""
+
+from benchmark import program_spans
+
+
+def read(run: dict):
+    return program_spans.phase_ms(run, "forward")

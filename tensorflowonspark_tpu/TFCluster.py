@@ -212,6 +212,10 @@ class TFCluster:
         drain, then stops the StreamingContext gracefully without stopping
         the SparkContext — same here.  Pass the context whose DStream was fed
         via :meth:`train_stream`.
+
+        Before the rendezvous stops, while the nodes' managers still
+        answer, the job's timeline and counters are written to its scratch
+        directory (:meth:`write_observability`).
         """
         if ssc is not None:
             self._drain_and_stop_streaming(ssc, timeout, qname)
@@ -231,6 +235,10 @@ class TFCluster:
                     )
                 self._check_bootstrap_error()
         finally:
+            try:
+                self.write_observability()
+            except Exception as e:  # observability must not fail a job
+                logger.warning("could not write the job's trace: %s", e)
             if self._obs_server is not None:
                 try:
                     self._obs_server.stop()
@@ -388,32 +396,80 @@ class TFCluster:
                     sum(len(v) for v in by_node.values()), path)
         return obs.chrome.write(path, by_node)
 
-    def _trace_events_by_node(self) -> dict[str, list[dict]]:
+    def _trace_events_by_node(self, kv_snapshots: list | None = None
+                              ) -> dict[str, list[dict]]:
         """Driver buffer + every reachable node's shipped trace events —
         the shared collection step behind :meth:`dump_trace`, the
-        ``/trace`` endpoint, and stall attribution
-        (:meth:`check_anomalies`)."""
-        from tensorflowonspark_tpu import TFManager
-
+        ``/trace`` endpoint, stall attribution (:meth:`check_anomalies`)
+        and :meth:`write_observability` (which hands in the blackboards it
+        already fetched)."""
         tracer = obs.get_tracer()
         by_node: dict[str, list[dict]] = {tracer.node: tracer.snapshot()}
         # retained request traces (tail-sampled span trees: SLO breaches,
         # sheds, errors + the uniform sample) merge into the same
         # timeline — their spans carry trace ids into the Chrome args
         by_node[tracer.node].extend(obs.get_trace_store().events())
-        authkey = bytes.fromhex(self.cluster_meta["authkey_hex"])
-        for meta in self.cluster_info:
-            name = f"{meta['job_name']}:{meta['task_index']}"
-            try:
-                mgr = TFManager.connect(tuple(meta["addr"]), authkey)
-                shipped = obs.collect_blackboard(mgr.kv_snapshot())
-            except Exception as e:
-                logger.warning("trace collect: node %s unreachable: %s",
-                               name, e)
-                continue
-            for node, events in shipped.items():
+        if kv_snapshots is None:
+            kv_snapshots = self._node_blackboards()
+        for kv in kv_snapshots:
+            for node, events in obs.collect_blackboard(kv).items():
                 by_node.setdefault(node, []).extend(events)
         return by_node
+
+    def _node_blackboards(self) -> list[dict]:
+        """One kv snapshot of every node whose manager still answers."""
+        from tensorflowonspark_tpu import TFManager
+
+        authkey = bytes.fromhex(self.cluster_meta["authkey_hex"])
+        out = []
+        for meta in self.cluster_info:
+            try:
+                mgr = TFManager.connect(tuple(meta["addr"]), authkey)
+                out.append(mgr.kv_snapshot())
+            except Exception as e:
+                logger.warning("trace collect: node %s:%s unreachable: %s",
+                               meta["job_name"], meta["task_index"], e)
+        return out
+
+    def write_observability(self) -> str | None:
+        """Write what the job recorded to ``<application's scratch
+        directory>/obs/`` (``util.single_node_scratch_dir``, under
+        ``TFOS_SCRATCH_ROOT``): ``trace.json``, the merged Chrome trace of
+        :meth:`dump_trace` with the events each node lost under ``tfos``
+        (``{"dropped": {node: n}}``: a reader must not take a partial
+        record for a whole one), and ``counters.json``, the registry
+        snapshot of the driver and of every node process that published
+        one.  ``shutdown`` calls it while the nodes' managers still
+        answer; returns the directory, or None for a context without an
+        application id."""
+        import json
+        import os
+
+        from tensorflowonspark_tpu import util
+
+        app_id = getattr(self.sc, "applicationId", None)
+        if not app_id:
+            return None
+        out_dir = os.path.join(util.single_node_scratch_dir(app_id), "obs")
+        os.makedirs(out_dir, exist_ok=True)
+        boards = self._node_blackboards()
+        tracer = obs.get_tracer()
+        if tracer.enabled:
+            doc = obs.chrome.merge(self._trace_events_by_node(boards))
+            dropped = {tracer.node: tracer.dropped}
+            for kv in boards:
+                for node, n in obs.collect_dropped(kv).items():
+                    dropped[node] = dropped.get(node, 0) + n
+            doc["tfos"] = {"dropped": dropped}
+            with open(os.path.join(out_dir, "trace.json"), "w") as f:
+                json.dump(doc, f, sort_keys=True, separators=(",", ":"))
+        counters = {f"{tracer.node}:{os.getpid()}":
+                    obs.get_registry().snapshot()}
+        for kv in boards:
+            counters.update(obs.collect_counters(kv))
+        with open(os.path.join(out_dir, "counters.json"), "w") as f:
+            json.dump(counters, f, sort_keys=True)
+        return out_dir
 
     # -- anomaly attribution -------------------------------------------------
 

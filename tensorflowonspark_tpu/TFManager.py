@@ -570,6 +570,11 @@ class TFManager:
         """kv write. Reference anchor: ``TFManager.py::_set``."""
         self._kv().update({key: value})
 
+    def delete(self, key: str) -> None:
+        """kv delete (no error if absent): how a tracer bounds the chunks
+        it keeps on the blackboard."""
+        self._kv().pop(key, None)
+
     def kv_snapshot(self) -> dict[str, Any]:
         """Full copy of the kv blackboard in one round-trip.
 

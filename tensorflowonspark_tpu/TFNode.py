@@ -28,7 +28,7 @@ from typing import Any, Iterable, Sequence
 
 import numpy as np
 
-from tensorflowonspark_tpu import marker, shm
+from tensorflowonspark_tpu import marker, obs, shm
 
 logger = logging.getLogger(__name__)
 
@@ -101,6 +101,9 @@ class DataFeed:
         self._pf_thread = None
         self._pf_out: _std_queue.Queue | None = None
         self._pf_args: tuple | None = None
+        #: (wall, perf) clock reads of the last EndPartition taken off the
+        #: queue, until the next chunk closes the ``feed.turnround`` span
+        self._partition_ended: tuple | None = None
 
     # -- input -------------------------------------------------------------
 
@@ -140,19 +143,23 @@ class DataFeed:
         payload bytes per transport (plain-row chunks have no cheap byte
         measure and are counted by ``datafeed_rows_total`` only).
 
-        Feed observability (one histogram + two counters per batch, all
-        O(1)): ``datafeed_assemble_seconds`` is the time the trainer spent
-        *waiting on Spark* for this batch — the number that tells you
-        whether the feed or the compute is the bottleneck.  The flight
-        recorder splits that further: queue-blocked time is the ``wait``
-        stage (starvation evidence), everything else in here is ``ingest``
-        (shm read + piece assembly); on the prefetch pump thread both are
-        recorded as overlapped — the consumer's own ``wait`` on the staged
-        queue is the critical-path number there."""
-        from tensorflowonspark_tpu import obs
-
-        t0 = _time_mod.perf_counter()
+        Feed observability, all O(1) a batch: ``feed.queue_wait`` is the
+        time this batch spent blocked on the queue — *waiting on Spark*,
+        the number that tells you whether the feed or the compute is the
+        bottleneck (flight stage ``wait``, starvation evidence) —
+        and ``feed.ingest`` everything else in here (shm read + piece
+        assembly; flight stage ``ingest``).  The two interleave, a chunk
+        at a time, so each is summed over the batch and recorded once,
+        laid end to end from the batch's start: the sums are exact, the
+        edge between them is not.  On the prefetch pump thread both are
+        overlapped — the consumer's own ``feed.wait`` on the staged queue
+        is the critical-path number there.  ``feed.turnround`` runs from
+        an ``EndPartition`` marker taken off the queue to the next chunk
+        taken off it: the feed's dead time between two partitions."""
+        wall_t0, t0 = _time_mod.time(), _time_mod.perf_counter()
         wait_s = 0.0
+        nbytes = 0
+        transport = None
         while self._buffered_rows < batch_size and not self._stop_seen:
             tw = _time_mod.perf_counter()
             if self.interrupt is None:
@@ -168,42 +175,48 @@ class DataFeed:
                             raise FeedInterrupted(
                                 "feed wait interrupted (regroup pending)"
                             ) from None
-            wait_s += _time_mod.perf_counter() - tw
+            now = _time_mod.perf_counter()
+            wait_s += now - tw
             if isinstance(item, marker.StopFeed):
                 self._stop_seen = True
-            elif isinstance(item, shm.ShmChunkRef):
-                cols, tag = shm.read_chunk(item)
-                obs.counter("datafeed_bytes_shm_total").inc(item.nbytes)
-                self._push_piece(marker.ColumnarChunk(cols), tag,
-                                 item.nrows)
-                if self._buffered_rows >= batch_size:
-                    break
-            elif isinstance(item, marker.ColumnarChunk):
-                obs.counter("datafeed_bytes_pickle_total").inc(item.nbytes)
-                self._push_piece(item, item.tag, item.nrows)
-                if self._buffered_rows >= batch_size:
-                    break
-            elif isinstance(item, marker.TaggedChunk):
-                self._push_piece(item.rows, item.tag, len(item.rows))
-                if self._buffered_rows >= batch_size:
-                    break
-            elif isinstance(item, marker.Marker):
+                continue
+            if isinstance(item, marker.Marker):
                 # EndPartition / generic marker: release what we have (the
                 # feeder's partition ended); empty buffer yields empty batch
+                self._partition_ended = (_time_mod.time(), now)
                 break
+            if self._partition_ended is not None:
+                ended_wall, ended = self._partition_ended
+                self._partition_ended = None
+                obs.complete("feed.turnround", ended_wall, now - ended)
+            if isinstance(item, shm.ShmChunkRef):
+                cols, tag = shm.read_chunk(item)
+                obs.counter("datafeed_bytes_shm_total").inc(item.nbytes)
+                nbytes += item.nbytes
+                transport = "shm"
+                self._push_piece(marker.ColumnarChunk(cols), tag,
+                                 item.nrows)
+            elif isinstance(item, marker.ColumnarChunk):
+                obs.counter("datafeed_bytes_pickle_total").inc(item.nbytes)
+                nbytes += item.nbytes
+                transport = "pickle"
+                self._push_piece(item, item.tag, item.nrows)
+            elif isinstance(item, marker.TaggedChunk):
+                transport = "rows"
+                self._push_piece(item.rows, item.tag, len(item.rows))
             else:
+                transport = "rows"
                 rows = item if isinstance(item, list) else [item]
                 self._push_piece(rows, None, len(rows))
-                if self._buffered_rows >= batch_size:
-                    break
         pieces = self._take_pieces(batch_size)
         taken = sum(self._piece_len(p) for p in pieces)
         runs = self._take_tags(taken)
-        dt = _time_mod.perf_counter() - t0
-        obs.histogram("datafeed_assemble_seconds").observe(dt)
+        ingest_s = max(0.0, _time_mod.perf_counter() - t0 - wait_s)
+        obs.complete("feed.queue_wait", wall_t0, wait_s)
+        obs.complete("feed.ingest", wall_t0 + wait_s, ingest_s, rows=taken,
+                     bytes=nbytes, transport=transport)
         obs.flight.recorder("feed").add(
-            overlapped=self.prefetch > 0,
-            wait=wait_s, ingest=max(0.0, dt - wait_s))
+            overlapped=self.prefetch > 0, wait=wait_s, ingest=ingest_s)
         obs.counter("datafeed_batches_total").inc()
         if taken:
             obs.counter("datafeed_rows_total").inc(taken)
@@ -276,14 +289,11 @@ class DataFeed:
                     "configuration.")
         if self._pf_thread is None:
             self._start_prefetch(batch_size, device_put)
-        from tensorflowonspark_tpu import obs
-
-        tw = _time_mod.perf_counter()
-        item = self._pf_out.get()
         # consumer-side starvation: the pump's own wait/ingest overlap and
         # are recorded as such; blocking HERE is the critical-path wait
-        obs.flight.recorder("feed").add(
-            wait=_time_mod.perf_counter() - tw)
+        with obs.span("feed.wait", depth=self._pf_out.qsize()).flight(
+                obs.flight.recorder("feed"), "wait"):
+            item = self._pf_out.get()
         if isinstance(item, BaseException):
             if isinstance(item, FeedInterrupted):
                 # the pump thread died delivering this — reset so the
@@ -313,7 +323,9 @@ class DataFeed:
                 while True:
                     pieces, runs, stopped = self._assemble(batch_size)
                     batch = self._columnarize(pieces, device_put)
-                    self._pf_out.put((batch, runs, stopped))
+                    with obs.span("feed.pump_blocked",
+                                  depth=self._pf_out.qsize()):
+                        self._pf_out.put((batch, runs, stopped))
                     if stopped:
                         return
             except BaseException as e:  # re-raised in next_batch
@@ -381,8 +393,6 @@ class DataFeed:
         pipeline thread exits with the trainer process.
         """
         logger.info("DataFeed terminating: draining input queue")
-        from tensorflowonspark_tpu import obs
-
         obs.event("datafeed.terminate", qname=self.qname_in)
         self.done_feeding = True
         self._stop_seen = True
@@ -458,48 +468,44 @@ class DataFeed:
         — one memcpy per column); a batch covered by a single columnar
         piece is handed out as-is: zero-copy views over the (already
         unlinked) shm segment, from which ``device_put`` transfers
-        directly.  Flight attribution: the column assembly is ``collate``
-        (distinct from ``_assemble``'s ``ingest`` so each stage histogram
-        keeps one observation per batch), an in-feed ``device_put`` is
-        ``stage`` (all overlapped when the prefetch pump runs this)."""
+        directly.  The column assembly is the ``feed.collate`` span
+        (flight stage ``collate``, distinct from ``_assemble``'s
+        ``ingest`` so each stage histogram keeps one observation per
+        batch), an in-feed ``device_put`` is ``feed.stage`` (stage
+        ``stage``; all overlapped when the prefetch pump runs this)."""
         if not pieces:
             return {} if self.input_mapping else []
-        from tensorflowonspark_tpu import obs
-
         rec = obs.flight.recorder("feed")
         bg = self.prefetch > 0
-        t0 = _time_mod.perf_counter()
-        col_sets = [piece.cols if isinstance(piece, marker.ColumnarChunk)
-                    else self._rows_to_cols(piece) for piece in pieces]
-        ncols = len(col_sets[0])
-        if any(len(cs) != ncols for cs in col_sets):
-            raise ValueError(
-                "inconsistent column arity across feed chunks in one batch: "
-                f"{sorted({len(cs) for cs in col_sets})} columns")
-        if len(col_sets) == 1:
-            cols = list(col_sets[0])
-        else:
-            cols = [np.concatenate([cs[i] for cs in col_sets])
-                    for i in range(ncols)]
-        if self.input_mapping and len(self.input_mapping) != len(cols):
-            raise ValueError(
-                f"input_mapping has {len(self.input_mapping)} names but rows "
-                f"have {len(cols)} columns"
-            )
-        t1 = _time_mod.perf_counter()
-        rec.add(overlapped=bg, collate=t1 - t0)
+        with obs.span("feed.collate").flight(rec, "collate", bg):
+            col_sets = [piece.cols if isinstance(piece, marker.ColumnarChunk)
+                        else self._rows_to_cols(piece) for piece in pieces]
+            ncols = len(col_sets[0])
+            if any(len(cs) != ncols for cs in col_sets):
+                raise ValueError(
+                    "inconsistent column arity across feed chunks in one "
+                    f"batch: {sorted({len(cs) for cs in col_sets})} columns")
+            if len(col_sets) == 1:
+                cols = list(col_sets[0])
+            else:
+                cols = [np.concatenate([cs[i] for cs in col_sets])
+                        for i in range(ncols)]
+            if self.input_mapping and len(self.input_mapping) != len(cols):
+                raise ValueError(
+                    f"input_mapping has {len(self.input_mapping)} names but "
+                    f"rows have {len(cols)} columns"
+                )
         if callable(device_put):
-            out = device_put(
-                dict(zip(self.input_mapping, cols)) if self.input_mapping
-                else cols
-            )
-            rec.add(overlapped=bg, stage=_time_mod.perf_counter() - t1)
-            return out
+            with obs.span("feed.stage").flight(rec, "stage", bg):
+                return device_put(
+                    dict(zip(self.input_mapping, cols)) if self.input_mapping
+                    else cols
+                )
         if device_put:
             import jax
 
-            cols = [jax.device_put(c) for c in cols]
-            rec.add(overlapped=bg, stage=_time_mod.perf_counter() - t1)
+            with obs.span("feed.stage").flight(rec, "stage", bg):
+                cols = [jax.device_put(c) for c in cols]
         if self.input_mapping:
             return dict(zip(self.input_mapping, cols))
         return cols

@@ -241,16 +241,20 @@ def _run_map_fun(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
     the ``map_fun`` span must already be on the blackboard by then; a
     failure lands on the error queue + "failed" state before re-raising.
     """
-    import cloudpickle
-
     try:
-        from tensorflowonspark_tpu.parallel import distributed
+        # the first import of JAX in the process that will hold the chips
+        # (seconds, and until PR 24 under no span)
+        with obs.span("node.jax_import", executor_id=ctx.executor_id):
+            import cloudpickle
+
+            from tensorflowonspark_tpu.parallel import distributed
 
         with obs.span("node.distributed_init"):
             distributed.maybe_initialize(ctx)
         # this process owns the node's chips from here on: it is the first
         # (and only long-lived) one to initialise the TPU backend
-        chip_info.verify_claim(_node_chips(ctx))
+        with obs.span("node.chip_verify", executor_id=ctx.executor_id):
+            chip_info.verify_claim(_node_chips(ctx))
         if profiler:
             _start_profiler_server(ctx)
         fn = cloudpickle.loads(fn_blob)
@@ -306,8 +310,14 @@ def _start_profiler_server(ctx: TFNodeContext) -> None:
 
 
 def _background_main(fn_blob: bytes, args_blob: bytes, ctx: TFNodeContext,
-                     profiler: bool = False) -> None:
+                     profiler: bool = False,
+                     spawned_at: float | None = None) -> None:
     """Entry point of the spawned trainer process (SPARK input mode)."""
+    if spawned_at is not None:
+        # the bootstrap task's Process.start() to this line: interpreter
+        # start and the package's imports, on the host's one wall clock
+        obs.complete("node.trainer_spawn", spawned_at,
+                     time.time() - spawned_at, executor_id=ctx.executor_id)
     util.ensure_jax_platform()
     mgr = ctx.mgr
     # start tick BEFORE pid: the orphan watch keys liveness on the pair,
@@ -344,10 +354,12 @@ class _MapFn:
         executor_id = int(part[0])
 
         # a reused python worker may have bootstrapped an EARLIER cluster:
-        # that run's events were already shipped to its own blackboard, so
-        # drop them now — flush publishes the full buffer, and stale spans
-        # with old timestamps would corrupt this cluster's trace timeline
-        obs.get_tracer().clear()
+        # that run's events belong to its blackboard and its timeline, so
+        # drop them (and the manager they shipped through) now.  A worker
+        # that has served no cluster yet keeps what it recorded: its own
+        # start and this task's load are part of this cluster's bootstrap
+        if obs.get_tracer().attached:
+            obs.get_tracer().clear()
 
         # collision guard (reference: util.write_executor_id + cross-check)
         existing = util.read_executor_id(name=_guard_name(cluster_id))
@@ -465,7 +477,8 @@ class _MapFn:
             mp = multiprocessing.get_context("spawn")
             p = mp.Process(
                 target=_background_main,
-                args=(self.fn_blob, self.args_blob, ctx, profiler),
+                args=(self.fn_blob, self.args_blob, ctx, profiler,
+                      time.time()),
                 name=f"tfos-trainer-{executor_id}",
                 daemon=True,
             )
@@ -520,6 +533,9 @@ class _MapFn:
             logger.info("tensorboard binary not found; profiler server only")
 
 
+_NO_ROW = object()  # an empty partition's "first row"
+
+
 class _TrainFn:
     """Feed one RDD partition into the co-located node's input queue.
 
@@ -538,48 +554,78 @@ class _TrainFn:
         self.qname = qname
 
     def __call__(self, iterator: Iterator) -> None:
+        """One partition is one ``feeder.task`` span; its children are the
+        parts of the feed's turn-round at a partition end:
+        ``feeder.connect`` (to the node's manager, state and queue proxy in
+        hand), ``feeder.first_row`` (the iterator's first row: where Spark
+        deserialises the partition), ``feeder.send`` (first to last chunk)
+        and ``feeder.drain_wait`` (the consumption poll)."""
+        with obs.span("feeder.task") as task:
+            self._feed(iterator, task)
+
+    def _feed(self, iterator: Iterator, task) -> None:
         node = _resolve_node(self.cluster_info, self.meta["id"],
                              lost_executors=self.meta.get("lost_executors"))
         if node is None:  # this executor's node was lost in a regroup
             _discard_partition(iterator, self.meta)
             return
-        mgr = _connect_mgr(node, bytes.fromhex(self.meta["authkey_hex"]))
-        _raise_worker_error(mgr)
-        state = mgr.get("state")
+        with obs.span("feeder.connect"):
+            mgr = _connect_mgr(node, bytes.fromhex(self.meta["authkey_hex"]))
+            _raise_worker_error(mgr)
+            state = mgr.get("state")
+            q = mgr.get_queue(self.qname)
+        tracer = obs.get_tracer()
+        if not tracer.attached:
+            # a python worker that did not bootstrap this node (Spark
+            # without worker reuse): its spans ship through this manager
+            tracer.configure(
+                node=f"{node['job_name']}:{node['task_index']}", mgr=mgr)
         if state in ("terminating", "finished", "failed", "lost"):
             logger.info("node state %s: discarding partition", state)
+            task.set(discarded=state)
             for _ in iterator:
                 pass
             _raise_worker_error(mgr)
             return
-        q = mgr.get_queue(self.qname)
         chunk_size = self.meta.get("feed_chunk", 256)
         deadline = time.monotonic() + self.feed_timeout
-        chunk: list[Any] = []
         # feeder-plane flight attribution: `encode` (columnarize + shm
         # write) vs `backpressure` (blocked in the queue put — the wire +
         # byte-bound back-pressure).  A feeder whose verdicts are
         # queue_backpressured is outrunning the trainer, not slow itself.
+        # The same clock reads, summed, are the send span's attrs.
         rec = obs.flight.recorder("feeder")
+        sent = {"chunks": 0, "rows": 0, "bytes": 0, "encode_s": 0.0,
+                "backpressure_s": 0.0}
 
         def send_chunk(rows: list[Any]) -> None:
             t0 = time.perf_counter()
             payload = shm.encode_chunk(rows)
             t1 = time.perf_counter()
             self._put(q, payload, deadline)
-            rec.add(encode=t1 - t0,
-                    backpressure=time.perf_counter() - t1)
+            t2 = time.perf_counter()
+            rec.add(encode=t1 - t0, backpressure=t2 - t1)
             rec.commit()
+            sent["chunks"] += 1
+            sent["rows"] += len(rows)
+            sent["bytes"] += getattr(payload, "nbytes", 0)
+            sent["encode_s"] += t1 - t0
+            sent["backpressure_s"] += t2 - t1
 
         try:
-            for row in iterator:
-                chunk.append(row)
-                if len(chunk) >= chunk_size:
+            with obs.span("feeder.first_row"):
+                first = next(iterator, _NO_ROW)
+            with obs.span("feeder.send") as send:
+                chunk: list[Any] = [] if first is _NO_ROW else [first]
+                for row in iterator:
+                    if len(chunk) >= chunk_size:
+                        send_chunk(chunk)
+                        chunk = []
+                    chunk.append(row)
+                if chunk:
                     send_chunk(chunk)
-                    chunk = []
-            if chunk:
-                send_chunk(chunk)
-            self._put(q, marker.EndPartition(), deadline)
+                self._put(q, marker.EndPartition(), deadline)
+                send.set(**sent)
         except _queue_mod.Full:
             raise RuntimeError(
                 f"feed timed out after {self.feed_timeout}s: trainer not "
@@ -592,19 +638,20 @@ class _TrainFn:
         # a drained queue must still abort this epoch with the attribution
         # (a feed that "completed" into a corpse would never be replayed by
         # the elastic supervisor) instead of reading as consumed
-        while True:
-            if mgr.get("state") in ("terminating", "finished", "failed",
-                                    "lost"):
-                _raise_worker_error(mgr)
-                return
-            if q.qsize() == 0:
-                return
-            if time.monotonic() > deadline:
-                raise RuntimeError(
-                    f"feed timed out after {self.feed_timeout}s waiting for "
-                    f"{q.qsize()} queued chunks to be consumed"
-                )
-            time.sleep(0.05)
+        with obs.span("feeder.drain_wait"):
+            while True:
+                if mgr.get("state") in ("terminating", "finished", "failed",
+                                        "lost"):
+                    _raise_worker_error(mgr)
+                    return
+                if q.qsize() == 0:
+                    return
+                if time.monotonic() > deadline:
+                    raise RuntimeError(
+                        f"feed timed out after {self.feed_timeout}s waiting "
+                        f"for {q.qsize()} queued chunks to be consumed"
+                    )
+                time.sleep(0.05)
 
     def _put(self, q, item, deadline) -> None:
         timeout = max(0.0, deadline - time.monotonic())
@@ -732,6 +779,15 @@ class _ShutdownFn:
                         "nothing to stop")
             return
         mgr = _connect_mgr(node, bytes.fromhex(self.meta["authkey_hex"]))
+        try:
+            self._stop_node(node, mgr)
+        finally:
+            # what this executor process recorded (its feeder tasks' spans
+            # and counters) goes to the blackboard before the driver
+            # collects the job's trace
+            obs.flush(mgr)
+
+    def _stop_node(self, node, mgr) -> None:
         state = mgr.get("state")
         if state in ("finished", "failed", "lost"):
             # "lost": the trainer vanished (SIGKILL/preemption) — the

@@ -425,9 +425,12 @@ def _instruments():
                 "XLA executable loaded from disk instead of compiled — "
                 "neither an in-process jit hit nor a true miss)"),
             obs.counter(
-                "serving_compile_cache_disk_writes_total",
-                "XLA executables written to the persistent compile cache "
-                "(each one is a compile some other process can now skip)"),
+                "compile_cache_disk_writes_total",
+                "XLA executables this process wrote to the persistent "
+                "compile cache as files (each one is a compile some other "
+                "process can now skip); a put that wrote nothing — the "
+                "entry was there, or is larger than the cache — is not "
+                "one"),
             obs.counter(
                 "serving_compile_cache_disk_corrupt_total",
                 "persistent-cache entries REJECTED on pull (digest "
@@ -458,10 +461,44 @@ def _on_event(event: str, **kw) -> None:
             _instruments()[0].inc()
             _TLS.hits = getattr(_TLS, "hits", 0) + 1
         elif event == _EV_WRITE:
-            _instruments()[1].inc()
+            _count_files_written()
             sync_async()
     except Exception:  # pragma: no cover
         pass
+
+
+def _cache_entries() -> int:
+    with os.scandir(_STATE["active_dir"]) as it:
+        return sum(1 for e in it if e.name.endswith("-cache"))
+
+
+def _count_files_written() -> None:
+    """Feed the writes counter from the put itself.  jax fires its write
+    event BEFORE it hands the entry to its cache, and that put may write
+    nothing (the entry is there already; it is larger than the cache; a
+    cache written without a size limit refuses one written with it, PR
+    21) — counting the event counted attempts.  So on the first write
+    event the cache object's ``put`` is wrapped (the event fires just
+    before jax looks ``put`` up, so the wrapper already sees that very
+    write) to count the entries the directory gained across it."""
+    from jax._src import compilation_cache as cc
+
+    cache = cc._cache
+    if cache is None or getattr(cache, "_tfos_counted", False):
+        return
+    put = cache.put
+
+    def counted_put(key, val):
+        before = _cache_entries()
+        try:
+            put(key, val)
+        finally:
+            written = _cache_entries() - before
+            if written > 0:
+                _instruments()[1].inc(written)
+
+    cache.put = counted_put
+    cache._tfos_counted = True
 
 
 def _on_duration(event: str, duration: float, **kw) -> None:
@@ -505,6 +542,6 @@ def stats() -> dict[str, Any]:
         "remote": bool(_STATE["remote_ns"]),
         "error": _STATE["error"],
         "disk_hits": val("serving_compile_cache_disk_hits_total"),
-        "disk_writes": val("serving_compile_cache_disk_writes_total"),
+        "disk_writes": val("compile_cache_disk_writes_total"),
         "disk_corrupt": val("serving_compile_cache_disk_corrupt_total"),
     }

@@ -107,14 +107,6 @@ class MetricsReporter:
                 self._mgr.set(self.key, snap)
             except Exception as e:  # metrics must never kill training
                 logger.warning("metrics publish failed: %s", e)
-            # piggyback a trace flush on the same cadence: the trainer's
-            # spans reach the blackboard while it runs, not only at exit
-            try:
-                from tensorflowonspark_tpu import obs
-
-                obs.get_tracer().flush(self._mgr)
-            except Exception:
-                pass
         return snap
 
 

@@ -3,14 +3,24 @@
 Three layers over ONE event model (ISSUE 1 tentpole; SURVEY.md §5 notes the
 reference had "Python logging ... no metrics registry"):
 
-- **tracing** (:mod:`.trace`) — ``obs.span("reserve")`` context-manager /
-  decorator spans and ``obs.event(...)`` instants, recorded into a bounded
-  per-process ring buffer and shipped executor→driver over the TFManager
-  kv blackboard;
+- **tracing** (:mod:`.trace`) — ``obs.span("cluster.reserve")``
+  context-manager / decorator spans and ``obs.event(...)`` instants,
+  recorded into a bounded per-process ring buffer and shipped
+  executor→driver over the TFManager kv blackboard by a daemon thread
+  (never on the recording thread).  A span is the ONE way the training
+  path times a stretch: its one pair of clock reads feeds the ring, the
+  flight stage its site names (``.flight(recorder, stage)``), ``dur_s``
+  for the goodput ledger, and — in a process that has already imported
+  JAX, and only there — a ``jax.profiler.TraceAnnotation`` of the same
+  name, so the stretch sits in a profiler session's ``.xplane.pb`` on
+  the profiler's clock (``obs.clock_offset`` places the ring's other
+  processes there);
 - **structured event log / Chrome trace** (:mod:`.chrome`) —
   ``TFCluster.dump_trace(path)`` merges every node's events into one
   Chrome-trace-format file (deterministic; schema-checked by
-  ``tools/check_trace.py``);
+  ``tools/check_trace.py``); ``TFCluster.shutdown()`` writes the same
+  document and every process's counters to
+  ``<application scratch dir>/obs/trace.json`` and ``counters.json``;
 - **metrics export** (:mod:`.registry`) — counters / gauges / histograms
   with Prometheus text exposition and a JSON snapshot, published with the
   step metrics and aggregated by ``TFCluster.metrics()`` /
@@ -78,14 +88,36 @@ And the cost accounting plane (ISSUE 18 tentpole):
   chargeback reports by ``tools/costs.py``.  ``TFOS_LEDGER=0``
   disables.
 
-Instrumented out of the box: cluster lifecycle (``TFCluster`` /
-``TFSparkNode`` bootstrap, reserve, probe, shutdown), the trainer
-(``trainer.Trainer`` init + step counters, optional ``jax.profiler`` step
-annotations via ``TFOS_PROFILE_STEPS=1``), the data feed
-(``TFNode.DataFeed`` / ``readers``), checkpointing (``ckpt``), health
-probes (``health``), serving (``pipeline``), and ``bench.py`` (which
+Span names, by layer (one span per layer boundary and batch / partition /
+step — never per record, row or chunk):
+
+- cluster and node runtime: ``cluster.reserve``, ``cluster.train``,
+  ``cluster.feed_epoch``, ``cluster.shutdown``, ``spark.task_send``,
+  ``executor.start``, ``executor.task`` > ``executor.task_load``,
+  ``node.chip_claim``, ``node.manager_start``, ``health.probe``,
+  ``node.register_await``, ``node.trainer_spawn``,
+  ``node.distributed_init``, ``node.chip_verify``, ``node.map_fun``;
+- feed plane, readers: ``reader.batch`` > ``reader.parse``,
+  ``reader.stack``, ``feed.stage``; ``feed.pump_blocked`` (producer on a
+  full queue), ``feed.wait`` (consumer on an empty one); ``readers.epoch``;
+- feed plane, Spark consumer (``TFNode.DataFeed``): ``feed.queue_wait``,
+  ``feed.ingest``, ``feed.collate``, ``feed.stage``, ``feed.pump_blocked``
+  on the pump thread, ``feed.wait`` on the consumer, ``feed.turnround``
+  from an ``EndPartition`` off the queue to the next chunk off it;
+- feed plane, Spark feeder (``TFSparkNode._TrainFn``, never on JAX):
+  ``feeder.task`` > ``feeder.connect``, ``feeder.first_row``,
+  ``feeder.send``, ``feeder.drain_wait``;
+- trainer: ``trainer.init``, ``trainer.step`` (attr ``step``, a trace id
+  of its own) > ``trainer.shard``, ``trainer.dispatch``,
+  ``trainer.checkpoint``; ``ckpt.save`` / ``ckpt.restore``;
+- kernels: ``jax.named_scope`` ``forward`` and ``optimizer`` in the
+  compiled step (``parallel/train.py``).
+
+Also instrumented: elastic regroups (``elastic``), serving
+(``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which
 writes a trace artifact even for degraded runs, attributing the probe
-timeout).  ``TFOS_TRACE=0`` disables recording.
+timeout).  ``TFOS_TRACE=0`` disables recording; spans then still time
+their stretch for the flight recorder and the ledger.
 """
 
 from tensorflowonspark_tpu.obs import (  # noqa: F401
@@ -115,12 +147,17 @@ from tensorflowonspark_tpu.obs.registry import (  # noqa: F401
     snapshot_to_prometheus,
 )
 from tensorflowonspark_tpu.obs.trace import (  # noqa: F401
+    COUNTERS_KV_PREFIX,
     TRACE_KV_PREFIX,
     RequestTrace,
     TraceContext,
     TraceStore,
     Tracer,
+    clock_offset,
     collect_blackboard,
+    collect_counters,
+    collect_dropped,
+    complete,
     configure,
     event,
     flush,
@@ -141,8 +178,9 @@ __all__ = [
     "counter", "gauge", "histogram", "get_registry",
     "merge_snapshots", "merged_to_prometheus", "relabel_snapshot",
     "snapshot_to_prometheus", "snapshot_to_openmetrics",
-    "TRACE_KV_PREFIX", "Tracer", "collect_blackboard", "configure",
-    "event", "flush", "get_tracer", "span",
+    "TRACE_KV_PREFIX", "COUNTERS_KV_PREFIX", "Tracer", "clock_offset",
+    "collect_blackboard", "collect_counters", "collect_dropped",
+    "complete", "configure", "event", "flush", "get_tracer", "span",
     "TraceContext", "RequestTrace", "TraceStore", "get_trace_store",
     "parse_traceparent", "format_traceparent", "merge_request_docs",
     "trace_context", "with_context",

@@ -12,11 +12,22 @@ and the TensorFlow paper treat lifecycle tracing as first-class):
   a collapsed MoE group, a dropped batch) with arbitrary attrs;
 - every process keeps its events in a bounded **ring buffer**
   (:class:`Tracer`) — tracing must never grow memory or kill the hot loop;
-- executor-side tracers **ship** their buffer to the driver through the
-  existing TFManager kv blackboard (each process owns one kv key,
-  ``trace:<node>:<pid>``, so concurrent writers never race), where
-  ``TFCluster.dump_trace`` merges all nodes into a single
-  Chrome-trace-format file (:mod:`tensorflowonspark_tpu.obs.chrome`).
+- executor-side tracers **ship** their events to the driver through the
+  existing TFManager kv blackboard, off the recording thread: a small
+  daemon ships the events recorded since its cursor as one chunk under a
+  key of this process's own (``trace:<node>:<pid>:<chunk>``, so
+  concurrent writers never race), and ``flush()`` ships the rest at
+  process end; ``TFCluster.dump_trace`` (and ``TFCluster.shutdown``,
+  which writes the job's ``obs/trace.json``) merges all nodes into a
+  single Chrome-trace-format file
+  (:mod:`tensorflowonspark_tpu.obs.chrome`);
+- a span opened in a process that has **already imported JAX** also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so the same
+  stretch sits in a profiler session's ``.xplane.pb`` on the profiler's
+  clock, next to the device's operations (:func:`clock_offset` places
+  the ring's wall-clock spans on that timeline).  JAX is never imported
+  here: the driver, the launcher and the executor's feeder task stay
+  off it.
 
 Event record (plain dict, JSON- and pickle-serializable)::
 
@@ -46,7 +57,8 @@ everything else dropped at commit.
 
 Env knobs: ``TFOS_TRACE=0`` disables recording entirely (the record path
 then costs one attribute check); ``TFOS_TRACE_CAPACITY`` sizes the ring
-buffer (default 4096 events per process).  Request tracing has its own
+buffer (default 16384 events per process: a 30 s window of 700 steps at
+8 spans a step fits twice over).  Request tracing has its own
 knobs: ``TFOS_TRACE_REQUESTS=0`` disables per-request span trees,
 ``TFOS_TRACE_ARM`` sets the fraction of (uniform-population) requests
 armed for capture (default 0.05 — explicit inbound contexts always arm,
@@ -60,20 +72,41 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import logging
 import os
 import random
 import re
+import statistics
+import sys
 import threading
 import time
+import weakref
 from typing import Any, Callable
 
 logger = logging.getLogger(__name__)
 
 #: kv-blackboard key prefix under which each process publishes its events
 TRACE_KV_PREFIX = "trace:"
+#: ... and its registry snapshot, at process or task end (:func:`flush`)
+COUNTERS_KV_PREFIX = "counters:"
 
-_DEFAULT_CAPACITY = 4096
+_DEFAULT_CAPACITY = 16384
+
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once JAX is there
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if this process has imported JAX,
+    else None.  Never imports it: a process that must stay off JAX (the
+    driver, the launcher, an executor's feeder task) stays off it."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        _ANNOTATION = getattr(getattr(jax, "profiler", None),
+                              "TraceAnnotation", None)
+    return _ANNOTATION
 
 
 def _enabled_by_env() -> bool:
@@ -224,13 +257,15 @@ class Tracer:
     ``node`` is the identity stamped on every event (``"driver"`` until
     :meth:`configure` names it).  ``mgr`` (a
     :class:`tensorflowonspark_tpu.TFManager.TFManager` handle) enables
-    shipping: :meth:`flush` publishes the current buffer snapshot under
-    this process's own kv key — idempotent full-snapshot overwrite, so a
-    crash between flushes loses at most ``flush_interval`` events and two
-    processes never contend on one key.  Recording is cheap (deque append
-    under a lock); shipping is throttled (every ``flush_interval`` events
-    or ``flush_interval_s`` seconds, whichever comes first) and never
-    raises into the instrumented code path.
+    shipping.  Recording is a deque append under a lock and makes no
+    manager call; a daemon thread ships the events recorded since its
+    cursor every ``flush_interval_s`` seconds as one chunk under a key of
+    its own, :meth:`flush` ships the rest at process end, and neither
+    raises into the instrumented code path.  The blackboard is bounded
+    like the ring: once a process has ``capacity`` events there, its
+    oldest chunks are deleted and counted with the events the ring evicted
+    before they could ship (``dropped`` in every later chunk), so a reader
+    never takes a partial record for a whole one.
     """
 
     def __init__(self, node: str = "driver", capacity: int | None = None):
@@ -238,17 +273,22 @@ class Tracer:
         self.enabled = _enabled_by_env()
         self.capacity = capacity or _capacity_from_env()
         self.dropped = 0
-        self.flush_interval = 64
         self.flush_interval_s = 2.0
         self._events: collections.deque = collections.deque(
             maxlen=self.capacity)
         self._lock = threading.Lock()
         self._local = threading.local()  # per-thread span stack
         self._mgr = None
-        self._since_flush = 0
-        # from construction, not 0.0: monotonic() is machine uptime, and
-        # "uptime > flush_interval_s" must not make the first event flush
-        self._last_flush = time.monotonic()
+        # shipping state, guarded by _ship_lock: events [0, _cursor) of the
+        # _recorded so far are on the blackboard or lost; _chunks lists the
+        # (key, events) this process holds there, oldest first
+        self._recorded = 0
+        self._cursor = 0
+        self._lost = 0
+        self._chunks: collections.deque = collections.deque()
+        self._ship_lock = threading.Lock()
+        self._wake = threading.Event()
+        self._shipper: threading.Thread | None = None
 
     # -- configuration -----------------------------------------------------
 
@@ -257,14 +297,29 @@ class Tracer:
         """Set node identity / blackboard manager; returns self."""
         if node:
             self.node = node
-        if mgr is not None:
-            self._mgr = mgr
         if capacity and capacity != self.capacity:
             with self._lock:
                 self.capacity = capacity
                 self._events = collections.deque(self._events,
                                                  maxlen=capacity)
+        if mgr is not None:
+            self._mgr = mgr
+            self._start_shipper()
         return self
+
+    @property
+    def attached(self) -> bool:
+        """A blackboard manager is configured (events ship somewhere)."""
+        return self._mgr is not None
+
+    def _start_shipper(self) -> None:
+        if not self.enabled or (self._shipper is not None
+                                and self._shipper.is_alive()):
+            return
+        self._shipper = threading.Thread(
+            target=_ship_loop, args=(weakref.ref(self),), daemon=True,
+            name="tfos-trace-shipper")
+        self._shipper.start()
 
     # -- recording ---------------------------------------------------------
 
@@ -275,17 +330,27 @@ class Tracer:
             st = self._local.stack = []
         return st
 
+    def _parent(self) -> tuple:
+        """``(trace_id, span_id, name)`` of what a new record on this thread
+        hangs under: the innermost open span, else the ambient context
+        (no name), else three Nones."""
+        st = getattr(self._local, "stack", None)
+        if st:
+            name, span_id, trace_id = st[-1]
+            return trace_id, span_id, name
+        ctx = getattr(self._local, "ctx", None)
+        if ctx is not None:
+            return ctx.trace_id, ctx.span_id, None
+        return None, None, None
+
     # -- context propagation -------------------------------------------------
 
     def current_context(self) -> TraceContext | None:
         """The context a hop should carry: the innermost open span on this
         thread, else the ambient context installed by :meth:`with_context`,
         else None (nothing to propagate)."""
-        st = getattr(self._local, "stack", None)
-        if st:
-            _, span_id, trace_id = st[-1]
-            return TraceContext(trace_id, span_id)
-        return getattr(self._local, "ctx", None)
+        trace_id, span_id, _ = self._parent()
+        return TraceContext(trace_id, span_id) if trace_id else None
 
     def with_context(self, ctx: TraceContext | None) -> _AmbientContext:
         """Context manager installing ``ctx`` as this thread's ambient
@@ -326,17 +391,30 @@ class Tracer:
             if len(self._events) == self.capacity:
                 self.dropped += 1
             self._events.append(ev)
-            self._since_flush += 1
-            want_flush = self._mgr is not None and (
-                self._since_flush >= self.flush_interval
-                or time.monotonic() - self._last_flush > self.flush_interval_s
-            )
-        if want_flush:
-            self.flush()
+            self._recorded += 1
 
     def span(self, name: str, **attrs: Any) -> "_Span":
         """Context manager *and* decorator timing one phase."""
         return _Span(self, name, attrs)
+
+    def complete(self, name: str, wall_t0: float, dur_s: float,
+                 **attrs: Any) -> None:
+        """Record a span whose two ends the site read itself, because no
+        ``with`` block can hold it: it crosses calls, threads or processes
+        (``feed.turnround``, ``node.trainer_spawn``), is accumulated over
+        interleaved stretches (``feed.queue_wait``), or surrounds a
+        generator's yields (``readers.epoch``).  ``wall_t0`` is its start
+        on ``time.time()``.  It is a child of the span open on this
+        thread, and is in the ring only: an annotation cannot be
+        back-dated onto the profiler's clock."""
+        if not self.enabled:
+            return
+        trace_id, parent_sid, pname = self._parent()
+        if pname:
+            attrs["parent"] = pname
+        self.record(name, "X", wall_t0 * 1e6, dur_s * 1e6, attrs or None,
+                    trace_id=trace_id or new_trace_id(),
+                    span_id=new_span_id(), parent_span_id=parent_sid)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Record an instant (point-in-time) event.  Like span exits, it
@@ -344,15 +422,9 @@ class Tracer:
         its nesting context — and links to it by id (``trace_id`` +
         ``parent_span_id``), falling back to the ambient context when no
         span is open on this thread."""
-        stack = self._stack()
-        trace_id = parent_sid = None
-        if stack:
-            pname, parent_sid, trace_id = stack[-1]
+        trace_id, parent_sid, pname = self._parent()
+        if pname:
             attrs = {**attrs, "parent": pname}
-        else:
-            ctx = getattr(self._local, "ctx", None)
-            if ctx is not None:
-                trace_id, parent_sid = ctx.trace_id, ctx.span_id
         self.record(name, "i", time.time() * 1e6, attrs=attrs or None,
                     trace_id=trace_id, parent_span_id=parent_sid)
 
@@ -367,104 +439,192 @@ class Tracer:
         """Empty the buffer AND detach any configured blackboard manager.
 
         clear() marks a run boundary (a reused worker bootstrapping a new
-        cluster): keeping the old manager would let the next recorded
-        event auto-flush the new run's spans onto the PREVIOUS cluster's
-        blackboard, clobbering its shipped trace.  The new run must
-        :meth:`configure` its own manager.
+        cluster): keeping the old manager would let the shipper put the
+        new run's spans onto the PREVIOUS cluster's blackboard.  The new
+        run must :meth:`configure` its own manager (the shipper thread
+        ends once the manager is gone, and configure starts another).
         """
-        with self._lock:
+        with self._ship_lock, self._lock:
             self._events.clear()
             self.dropped = 0
-            self._since_flush = 0
+            self._recorded = self._cursor = self._lost = 0
+            self._chunks.clear()
             self._mgr = None
+        self._wake.set()
 
     def kv_key(self) -> str:
         return f"{TRACE_KV_PREFIX}{self.node}:{os.getpid()}"
 
     def flush(self, mgr: Any = None) -> bool:
-        """Publish the buffer snapshot to the kv blackboard.
+        """Ship the events recorded since the last shipment as one chunk.
 
-        Returns True on success.  Never raises — observability must not
-        kill training (same contract as ``MetricsReporter.publish``).
+        Returns True on success (or with nothing new to ship).  Never
+        raises — observability must not kill training (same contract as
+        ``MetricsReporter.publish``); a chunk that failed to ship stays in
+        the ring and goes with the next one.
         """
         mgr = mgr if mgr is not None else self._mgr
         if mgr is None or not self.enabled:
             return False
-        payload = {
-            "node": self.node,
-            "pid": os.getpid(),
-            "events": self.snapshot(),
-            "dropped": self.dropped,
-            "flushed_at": time.time(),
-        }
-        try:
-            mgr.set(self.kv_key(), payload)
-        except Exception as e:
-            logger.warning("trace flush failed: %s", e)
+        with self._ship_lock:
             with self._lock:
-                # throttle retries to the normal flush cadence — a dead
-                # manager must not add one failing RPC per recorded event
-                self._since_flush = 0
-                self._last_flush = time.monotonic()
-            return False
-        with self._lock:
-            self._since_flush = 0
-            self._last_flush = time.monotonic()
+                oldest = self._recorded - len(self._events)
+                start = max(self._cursor, oldest)
+                events = list(itertools.islice(
+                    self._events, start - oldest, None))
+                end = self._recorded
+            lost = self._lost + (start - self._cursor)
+            if not events and lost == self._lost and self._chunks:
+                return True
+            try:
+                # bounded like the ring: make room first, so that this
+                # chunk's "dropped" already counts what made room for it
+                held = sum(n for _, n in self._chunks) + len(events)
+                while self._chunks and held > self.capacity:
+                    key, n = self._chunks.popleft()
+                    mgr.delete(key)
+                    held -= n
+                    lost += n
+                key = f"{self.kv_key()}:{end}"
+                mgr.set(key, {
+                    "node": self.node, "pid": os.getpid(),
+                    "events": events, "dropped": lost,
+                    "flushed_at": time.time()})
+            except Exception as e:
+                logger.warning("trace flush failed: %s", e)
+                return False
+            self._chunks.append((key, len(events)))
+            self._cursor, self._lost = end, lost
         return True
+
+
+def _ship_loop(ref: "weakref.ref[Tracer]") -> None:
+    """The shipper thread: every ``flush_interval_s`` seconds (or when
+    woken), ship what the tracer recorded since the last time.  Ends with
+    its tracer, or when the tracer's manager is taken away."""
+    while True:
+        tracer = ref()
+        if tracer is None or tracer._mgr is None:
+            return
+        wake, interval = tracer._wake, tracer.flush_interval_s
+        del tracer
+        wake.wait(interval)
+        wake.clear()
+        tracer = ref()
+        if tracer is None or tracer._mgr is None:
+            return
+        tracer.flush()
+        del tracer
 
 
 class _Span:
     """One timed phase; context manager and decorator in one object.
+
+    One pair of clock reads a span feeds every record of it: the ring
+    event (start on ``time.time()``, duration on ``perf_counter``), the
+    flight stage its site names (:meth:`flight`), ``dur_s`` for the site's
+    own books, and — in a process that has imported JAX — a
+    ``jax.profiler.TraceAnnotation`` of the same name, open exactly as
+    long, which a profiler session records on its own clock (with no
+    session it is a flag test).  A ``step`` attr rides as the
+    annotation's keyword, so the same span is found in both records.
 
     Decorator use creates a fresh timing per call (the instance holds only
     the static name/attrs; per-entry state lives on an internal stack, so
     reentrant/nested use of the same instance is safe).
     """
 
-    __slots__ = ("_tracer", "name", "attrs", "_starts")
+    __slots__ = ("_tracer", "name", "attrs", "_starts", "_flight", "dur_s",
+                 "trace_id", "_cancelled", "_root")
 
     def __init__(self, tracer: Tracer, name: str, attrs: dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
-        self._starts: list[tuple[float, float]] = []
+        self._starts: list[tuple] = []
+        self._flight: tuple | None = None
+        self._cancelled = False
+        self._root = False
+        #: seconds the last completed entry took (None until one has)
+        self.dur_s: float | None = None
+        #: trace id of the last entry (a root span's own, else inherited)
+        self.trace_id: str | None = None
+
+    def flight(self, recorder: Any, stage: str,
+               overlapped: bool = False) -> "_Span":
+        """Also book this span's duration as ``stage`` of a flight
+        recorder's pending batch, from the same clock reads."""
+        self._flight = (recorder, stage, overlapped)
+        return self
+
+    def root(self) -> "_Span":
+        """Start a trace of its own under whatever span is open: a unit
+        that findings cite by trace id (one training step) keeps an id of
+        its own inside the long span that runs the job."""
+        self._root = True
+        return self
+
+    def cancel(self) -> None:
+        """The open entry turned out to hold no work (a reader asked for a
+        batch and the epoch was over): leave it out of the ring and of the
+        flight record."""
+        self._cancelled = True
 
     def __enter__(self) -> "_Span":
-        stack = self._tracer._stack()
-        if stack:
-            # nested: inherit the trace, parent by span id
-            _, parent_sid, trace_id = stack[-1]
+        tracer = self._tracer
+        annotation = None
+        if tracer.enabled:
+            # nested: inherit the trace, parent by span id; else the
+            # context propagated from another thread/process; else (and
+            # for a .root() span) a trace of its own
+            trace_id, parent_sid, _ = (
+                (None, None, None) if self._root else tracer._parent())
+            trace_id = trace_id or new_trace_id()
+            span_id = new_span_id()
+            tracer._stack().append((self.name, span_id, trace_id))
+            self.trace_id = trace_id
+            cls = _trace_annotation()
+            if cls is not None:
+                step = self.attrs.get("step") if self.attrs else None
+                annotation = (cls(self.name) if step is None
+                              else cls(self.name, step=step))
+                annotation.__enter__()
         else:
-            ctx = getattr(self._tracer._local, "ctx", None)
-            if ctx is not None:  # propagated from another thread/process
-                trace_id, parent_sid = ctx.trace_id, ctx.span_id
-            else:  # a root span starts its own trace
-                trace_id, parent_sid = new_trace_id(), None
-        span_id = new_span_id()
+            span_id = trace_id = parent_sid = None
         self._starts.append((time.time(), time.perf_counter(), span_id,
-                             trace_id, parent_sid))
-        stack.append((self.name, span_id, trace_id))
+                             trace_id, parent_sid, annotation))
         return self
 
     def context(self) -> TraceContext | None:
         """This (open) span's context, for explicit cross-thread handoff."""
-        if not self._starts:
+        if not self._starts or self._starts[-1][2] is None:
             return None
-        _, _, span_id, trace_id, _ = self._starts[-1]
+        _, _, span_id, trace_id, _, _ = self._starts[-1]
         return TraceContext(trace_id, span_id)
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        wall_t0, perf_t0, span_id, trace_id, parent_sid = self._starts.pop()
-        dur_us = (time.perf_counter() - perf_t0) * 1e6
+        (wall_t0, perf_t0, span_id, trace_id, parent_sid,
+         annotation) = self._starts.pop()
+        self.dur_s = dur_s = time.perf_counter() - perf_t0
+        if annotation is not None:
+            annotation.__exit__(exc_type, exc, tb)
+        if self._flight is not None and not self._cancelled:
+            recorder, stage, overlapped = self._flight
+            recorder.add(overlapped=overlapped, **{stage: dur_s})
+        if span_id is None:  # tracer disabled when the span opened
+            return
         stack = self._tracer._stack()
         if stack and stack[-1][1] == span_id:
             stack.pop()
+        if self._cancelled:
+            self._cancelled = False
+            return
         attrs = dict(self.attrs) if self.attrs else {}
         if stack:
             attrs["parent"] = stack[-1][0]
         if exc_type is not None:
             attrs["error"] = f"{exc_type.__name__}: {exc}"[:300]
-        self._tracer.record(self.name, "X", wall_t0 * 1e6, dur_us,
+        self._tracer.record(self.name, "X", wall_t0 * 1e6, dur_s * 1e6,
                             attrs or None, trace_id=trace_id,
                             span_id=span_id, parent_span_id=parent_sid)
 
@@ -994,18 +1154,32 @@ def with_context(ctx: TraceContext | None) -> _AmbientContext:
     return _TRACER.with_context(ctx)
 
 
+def complete(name: str, wall_t0: float, dur_s: float, **attrs: Any) -> None:
+    _TRACER.complete(name, wall_t0, dur_s, **attrs)
+
+
 def flush(mgr: Any = None) -> bool:
-    return _TRACER.flush(mgr)
+    """Process or task end: ship the events not yet shipped and publish
+    this process's registry snapshot beside them (``counters:<node>:<pid>``)
+    — what ``TFCluster.shutdown`` writes out as the job's ``obs/trace.json``
+    and ``counters.json``."""
+    mgr = mgr if mgr is not None else _TRACER._mgr
+    if mgr is None:
+        return False
+    ok = _TRACER.flush(mgr)
+    try:
+        from tensorflowonspark_tpu.obs import registry
+
+        mgr.set(f"{COUNTERS_KV_PREFIX}{_TRACER.node}:{os.getpid()}",
+                {"node": _TRACER.node, "pid": os.getpid(),
+                 "registry": registry.get_registry().snapshot()})
+    except Exception as e:
+        logger.warning("registry publish failed: %s", e)
+        return False
+    return ok
 
 
-def collect_blackboard(kv_snapshot: dict[str, Any]) -> dict[str, list[dict]]:
-    """Extract shipped trace payloads from one node's kv snapshot.
-
-    Returns ``{node_name: [events...]}`` — a node may have several
-    publishing processes (bootstrap task, spawned trainer); their events
-    merge under the node name, ordered by timestamp.
-    """
-    by_node: dict[str, list[dict]] = {}
+def _trace_payloads(kv_snapshot: dict[str, Any]):
     for key, payload in kv_snapshot.items():
         if not (isinstance(key, str) and key.startswith(TRACE_KV_PREFIX)):
             continue
@@ -1013,7 +1187,64 @@ def collect_blackboard(kv_snapshot: dict[str, Any]) -> dict[str, list[dict]]:
             continue
         node = payload.get("node") or key[len(TRACE_KV_PREFIX):].rsplit(
             ":", 1)[0]
+        yield node, payload
+
+
+def collect_blackboard(kv_snapshot: dict[str, Any]) -> dict[str, list[dict]]:
+    """Extract shipped trace payloads from one node's kv snapshot.
+
+    Returns ``{node_name: [events...]}`` — a node may have several
+    publishing processes (bootstrap task, spawned trainer), each with
+    several chunks; their events merge under the node name, ordered by
+    timestamp.
+    """
+    by_node: dict[str, list[dict]] = {}
+    for node, payload in _trace_payloads(kv_snapshot):
         by_node.setdefault(node, []).extend(payload["events"])
     for events in by_node.values():
         events.sort(key=lambda e: (e.get("ts", 0), e.get("name", "")))
     return by_node
+
+
+def collect_dropped(kv_snapshot: dict[str, Any]) -> dict[str, int]:
+    """``{node_name: events its processes recorded that the blackboard
+    does not hold}`` (evicted from a ring before they shipped, or deleted
+    from the blackboard to bound it).  A process's count only grows, so
+    its newest chunk's is its largest."""
+    by_process: dict[tuple, int] = {}
+    for node, payload in _trace_payloads(kv_snapshot):
+        key = (node, payload.get("pid"))
+        by_process[key] = max(by_process.get(key, 0),
+                              int(payload.get("dropped") or 0))
+    out: dict[str, int] = {}
+    for (node, _pid), n in by_process.items():
+        out[node] = out.get(node, 0) + n
+    return out
+
+
+def collect_counters(kv_snapshot: dict[str, Any]) -> dict[str, dict]:
+    """``{"<node>:<pid>": registry snapshot}`` of the processes that
+    published one (:func:`flush`)."""
+    return {key[len(COUNTERS_KV_PREFIX):]: payload["registry"]
+            for key, payload in kv_snapshot.items()
+            if isinstance(key, str) and key.startswith(COUNTERS_KV_PREFIX)
+            and isinstance(payload, dict) and "registry" in payload}
+
+
+def clock_offset(pairs: list) -> dict[str, float] | None:
+    """The profiler's clock minus the wall clock, from spans present in
+    both records: ``pairs`` is ``[(ring start, profiler start), ...]`` in
+    seconds (every ``trainer.step`` of a traced window, matched by its
+    ``step``).  The median places any ring span of this host — another
+    thread's, the feeder's, the driver's — on the device's timeline by
+    ``ring start + offset_s``, good to ``spread_s`` (the distance between
+    the quartiles of the pairs' differences).  None without a pair."""
+    diffs = sorted(b - a for a, b in pairs)
+    if not diffs:
+        return None
+    spread = 0.0
+    if len(diffs) >= 2:
+        q1, _, q3 = statistics.quantiles(diffs, n=4)
+        spread = q3 - q1
+    return {"offset_s": statistics.median(diffs), "spread_s": spread,
+            "pairs": len(diffs)}

@@ -753,9 +753,11 @@ def make_bucketed_train_step(
                 ]
             g_tree = jax.tree_util.tree_unflatten(param_treedef, g_list)
             p_tree = jax.tree_util.tree_unflatten(param_treedef, p_list)
-            updates, new_opt = optimizer.update(g_tree, opt_state, p_tree)
-            new_p = jax.tree_util.tree_leaves(
-                optax.apply_updates(p_tree, updates))
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(g_tree, opt_state,
+                                                    p_tree)
+                new_p = jax.tree_util.tree_leaves(
+                    optax.apply_updates(p_tree, updates))
             out = []
             for i in range(len(param_leaves)):
                 # updated shards gather back per leaf as each update's
@@ -837,10 +839,10 @@ def make_bucketed_train_step(
             # depends only on its own bucket's reduction (plus the scalar
             # count), so XLA schedules bucket i's weight update behind
             # bucket i's all-reduce while later buckets are still reducing
-            updates, opt_state = optimizer.update(
-                grads, st.opt_state, st.params)
-
-            params = optax.apply_updates(st.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, st.opt_state, st.params)
+                params = optax.apply_updates(st.params, updates)
             return TrainState(params, opt_state, st.step + 1, new_cols), loss
 
         step = compile_step(_step, mesh, param_shardings, state,

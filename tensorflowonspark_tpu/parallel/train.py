@@ -325,6 +325,30 @@ def _batch_shardings(mesh, batch_example, sequence_axes=None):
     return jax.tree_util.tree_map_with_path(_one, batch_example)
 
 
+#: ``jax.named_scope`` names round the step's phases.  They are metadata of
+#: the compiled program (its operations, and the compile-cache key, which
+#: leaves metadata out, stay what they were): a profile's device
+#: operations then carry ``.../forward/...``, ``.../transpose(jvp(forward))/
+#: ...`` (JAX's own name for the backward of a scope) or ``.../optimizer/...``
+#: in their names, and group by phase.
+FORWARD_SCOPE = "forward"
+OPTIMIZER_SCOPE = "optimizer"
+
+
+def _forward_scoped(loss_fn):
+    """``loss_fn`` under the ``forward`` scope (its attributes kept)."""
+    import functools
+
+    import jax
+
+    @functools.wraps(loss_fn)
+    def scoped(*args):
+        with jax.named_scope(FORWARD_SCOPE):
+            return loss_fn(*args)
+
+    return scoped
+
+
 def make_train_step(
     loss_fn: Callable[[Any, Any], Any],
     optimizer,
@@ -392,6 +416,7 @@ def make_train_step(
             "up automatically) to train the tables."
         )
 
+    loss_fn = _forward_scoped(loss_fn)
     if bucketed is not False:
         ok, reason = collectives.mesh_eligibility(mesh, collection_shardings)
         if bucketed is None and not collectives.bucketing_enabled():
@@ -417,11 +442,14 @@ def make_train_step(
             new_cols = st.collections
         import optax
 
-        if clip_global_norm is not None:
-            grads, _ = optax.clip_by_global_norm(
-                float(clip_global_norm)).update(grads, optax.EmptyState())
-        updates, opt_state = optimizer.update(grads, st.opt_state, st.params)
-        params = optax.apply_updates(st.params, updates)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            if clip_global_norm is not None:
+                grads, _ = optax.clip_by_global_norm(
+                    float(clip_global_norm)).update(grads,
+                                                    optax.EmptyState())
+            updates, opt_state = optimizer.update(grads, st.opt_state,
+                                                  st.params)
+            params = optax.apply_updates(st.params, updates)
         return TrainState(params, opt_state, st.step + 1, new_cols), loss
 
     step = compile_step(_step, mesh, param_shardings, state, batch_example,

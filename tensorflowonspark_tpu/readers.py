@@ -28,6 +28,8 @@ Everything is pull-based and bounded; no unbounded buffering.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import logging
 import queue as _queue_mod
 import threading
@@ -36,11 +38,12 @@ from typing import Any, Callable, Iterator, Sequence
 
 import numpy as np
 
-from tensorflowonspark_tpu import fs, tfrecord
+from tensorflowonspark_tpu import fs, obs, tfrecord
 
 logger = logging.getLogger(__name__)
 
 _END = object()  # sentinel: a producer finished
+_NO_SPAN = contextlib.nullcontext()
 
 
 def shard_files(
@@ -200,39 +203,55 @@ def tfrecord_batches(
     parse = parse_fn or default_parse
     rng = np.random.default_rng(seed)
 
-    def batch_gen() -> Iterator[dict[str, Any]]:
-        from tensorflowonspark_tpu import obs
+    def read_batch(stream: Iterator[bytes]) -> dict[str, Any] | None:
+        """The next batch of the epoch, staged, or None at its end.  One
+        ``reader.batch`` span a batch, whose children split it: read +
+        parse of its records, the stack into columns, the staging."""
+        with obs.span("reader.batch") as sp:
+            with obs.span("reader.parse") as parse_sp:
+                rows = [parse(p)
+                        for p in itertools.islice(stream, batch_size)]
+                if not rows:
+                    parse_sp.cancel()
+            if not rows or (len(rows) < batch_size and drop_remainder):
+                if not rows:
+                    sp.cancel()
+                return None
+            obs.counter("reader_records_total").inc(len(rows))
+            with obs.span("reader.stack"):
+                batch = _columnarize(rows)
+            sp.set(records=len(rows),
+                   bytes=sum(int(c.nbytes) for c in batch.values()))
+            with obs.span("feed.stage"):
+                return _stage(batch)
 
+    def batch_gen() -> Iterator[dict[str, Any]]:
         for epoch in range(num_epochs):
             epoch_files = list(files)
             if shuffle_files:
                 np.random.default_rng(seed + epoch).shuffle(epoch_files)
-            rows: list[dict[str, Any]] = []
-            # the epoch is recorded as a manually-timed complete event, NOT
-            # a `with obs.span(...)` around the loop: a generator suspends
+            # the epoch is recorded with its two ends read here, NOT as a
+            # `with obs.span(...)` around the loop: a generator suspends
             # inside the with-block at every yield, which would leave
             # "readers.epoch" on the CONSUMER thread's span stack and
             # mis-parent unrelated spans recorded between batches (and an
-            # abandoned iterator might never pop it at all)
+            # abandoned iterator might never pop it at all).  The batch's
+            # own spans close before its yield.
             t0_wall, t0 = _time_mod.time(), _time_mod.perf_counter()
-            for payload in _record_stream(epoch_files, readers,
-                                          shuffle_buffer, rng):
-                rows.append(parse(payload))
-                if len(rows) == batch_size:
-                    obs.counter("reader_records_total").inc(len(rows))
-                    yield _stage(_columnarize(rows))
-                    rows = []
-            if rows and not drop_remainder:
-                obs.counter("reader_records_total").inc(len(rows))
-                yield _stage(_columnarize(rows))
-            obs.get_tracer().record(
-                "readers.epoch", "X", t0_wall * 1e6,
-                (_time_mod.perf_counter() - t0) * 1e6,
-                {"epoch": epoch, "files": len(epoch_files)})
+            stream = _record_stream(epoch_files, readers, shuffle_buffer,
+                                    rng)
+            try:
+                while (batch := read_batch(stream)) is not None:
+                    yield batch
+            finally:
+                stream.close()  # → pool.stop(), also when abandoned
+            obs.complete("readers.epoch", t0_wall,
+                         _time_mod.perf_counter() - t0,
+                         epoch=epoch, files=len(epoch_files))
 
     _stage = _stager(device_put)
 
-    yield from prefetched(batch_gen, prefetch)
+    yield from prefetched(batch_gen, prefetch, spans=True)
 
 
 def _stager(device_put) -> Callable[[dict[str, Any]], dict[str, Any]]:
@@ -254,8 +273,12 @@ def _stager(device_put) -> Callable[[dict[str, Any]], dict[str, Any]]:
 
 
 def prefetched(batch_gen_fn: Callable[[], Iterator[Any]],
-               prefetch: int) -> Iterator[Any]:
+               prefetch: int, spans: bool = False) -> Iterator[Any]:
     """Run ``batch_gen_fn()`` in a pipeline thread, ``prefetch`` items ahead.
+
+    ``spans`` (the training readers set it) records the two sides of the
+    hand-off: ``feed.pump_blocked`` while the producer waits on a full
+    queue, ``feed.wait`` while the consumer waits on an empty one.
 
     ``prefetch <= 0`` degrades to the plain generator.  Producer exceptions
     re-raise on the consumer side; abandoning the iterator (break /
@@ -279,12 +302,14 @@ def prefetched(batch_gen_fn: Callable[[], Iterator[Any]],
         gen = batch_gen_fn()
         try:
             for b in gen:
-                while not abandoned.is_set():
-                    try:
-                        out.put(b, timeout=0.1)
-                        break
-                    except _queue_mod.Full:
-                        continue
+                with (obs.span("feed.pump_blocked", depth=out.qsize())
+                      if spans else _NO_SPAN):
+                    while not abandoned.is_set():
+                        try:
+                            out.put(b, timeout=0.1)
+                            break
+                        except _queue_mod.Full:
+                            continue
                 if abandoned.is_set():
                     return
         except BaseException as e:  # surfaced on the consumer side
@@ -306,7 +331,9 @@ def prefetched(batch_gen_fn: Callable[[], Iterator[Any]],
     t.start()
     try:
         while True:
-            item = out.get()
+            with (obs.span("feed.wait", depth=out.qsize())
+                  if spans else _NO_SPAN):
+                item = out.get()
             if item is _END:
                 break
             yield item
@@ -369,8 +396,6 @@ def parquet_batches(
         return pq.ParquetFile(handle), handle
 
     def batch_gen() -> Iterator[dict[str, Any]]:
-        from tensorflowonspark_tpu import obs
-
         for epoch in range(num_epochs):
             epoch_files = list(files)
             if shuffle_files:

@@ -12,6 +12,7 @@ import time
 import uuid
 from typing import Any, Callable, Iterable, Sequence
 
+from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.sparkapi.rdd import RDD
 
 logger = logging.getLogger(__name__)
@@ -112,7 +113,8 @@ class LocalSparkContext:
             # in stop() plus an atexit hook for abandoned contexts.
             p = self._mp.Process(
                 target=executor_main,
-                args=(i, self.applicationId, tq, self._result_queue),
+                args=(i, self.applicationId, tq, self._result_queue,
+                      time.time()),
                 name=f"tfos-executor-{i}",
                 daemon=False,
             )
@@ -212,10 +214,15 @@ class LocalSparkContext:
             # broadcast values and must not be re-pickled per partition
             chain_blob = cloudpickle.dumps((list(chain), action))
             for pindex, part in enumerate(partitions):
-                data_blob = cloudpickle.dumps(part)
-                self._task_queues[pindex % len(self._task_queues)].put(
-                    (job_id, pindex, base_index + pindex, data_blob, chain_blob)
-                )
+                # what the driver does between two tasks of one job:
+                # serialise the next partition and send it
+                with obs.span("spark.task_send", job=job_id,
+                              partition=base_index + pindex) as sp:
+                    data_blob = cloudpickle.dumps(part)
+                    self._task_queues[pindex % len(self._task_queues)].put(
+                        (job_id, pindex, base_index + pindex, data_blob,
+                         chain_blob))
+                    sp.set(bytes=len(data_blob))
             results: dict[int, Any] = {}
             deadline = None if timeout is None else time.monotonic() + timeout
             while len(results) < len(partitions):

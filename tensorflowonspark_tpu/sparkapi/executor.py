@@ -14,16 +14,23 @@ cwd, which Spark keeps distinct per executor).
 from __future__ import annotations
 
 import os
+import time
 import traceback
 
 
-def executor_main(executor_id: int, app_id: str, task_queue, result_queue) -> None:
+def executor_main(executor_id: int, app_id: str, task_queue, result_queue,
+                  started_at: float | None = None) -> None:
     import queue as queue_mod
 
     import cloudpickle
 
-    from tensorflowonspark_tpu import util
+    from tensorflowonspark_tpu import obs, util
 
+    if started_at is not None:
+        # the driver's Process.start() to this line, on the host's one
+        # wall clock: interpreter start and the package's imports
+        obs.complete("executor.start", started_at, time.time() - started_at,
+                     executor_id=executor_id)
     wd = os.path.join(util.single_node_scratch_dir(app_id), f"executor_{executor_id}")
     os.makedirs(wd, exist_ok=True)
     os.chdir(wd)
@@ -44,13 +51,20 @@ def executor_main(executor_id: int, app_id: str, task_queue, result_queue) -> No
         if item is None:
             break
         job_id, task_id, pindex, data_blob, chain_blob = item
-        try:
-            data = cloudpickle.loads(data_blob)
-            chain, action = cloudpickle.loads(chain_blob)
-            it = iter(data)
-            for f in chain:
-                it = f(pindex, it)
-            result = action(pindex, it)
-            result_queue.put((job_id, task_id, True, cloudpickle.dumps(result)))
-        except BaseException:
-            result_queue.put((job_id, task_id, False, traceback.format_exc()))
+        # one span a task; its first child is where this substrate
+        # deserialises the partition (real Spark does it under the task's
+        # iterator: TFSparkNode's `feeder.first_row`)
+        with obs.span("executor.task", job=job_id, partition=pindex):
+            try:
+                with obs.span("executor.task_load", bytes=len(data_blob)):
+                    data = cloudpickle.loads(data_blob)
+                    chain, action = cloudpickle.loads(chain_blob)
+                it = iter(data)
+                for f in chain:
+                    it = f(pindex, it)
+                result = action(pindex, it)
+                result_queue.put(
+                    (job_id, task_id, True, cloudpickle.dumps(result)))
+            except BaseException:
+                result_queue.put(
+                    (job_id, task_id, False, traceback.format_exc()))

@@ -26,7 +26,7 @@ from typing import Any
 
 import numpy as np
 
-from tensorflowonspark_tpu import models as model_zoo
+from tensorflowonspark_tpu import models as model_zoo, obs
 from tensorflowonspark_tpu.parallel import (
     apply_zero_sharding,
     build_mesh,
@@ -57,12 +57,9 @@ class Trainer:
         devices: Any = None,
         step_timeout_s: float | None = None,
         error_sink: Any = None,
-        profile_steps: bool | None = None,
     ):
         import jax
         import optax
-
-        from tensorflowonspark_tpu import obs
 
         # init is the single biggest pre-training phase (sharded init +
         # two jit compiles); span it manually rather than re-indenting the
@@ -225,13 +222,6 @@ class Trainer:
             collection_shardings=col_overrides or None,
         )
 
-        # optional jax.profiler annotations around the jitted step: the
-        # XLA-side twin of the obs spans — step markers show up in captured
-        # profiles (TFSparkNode's profiler server / jax.profiler.trace)
-        if profile_steps is None:
-            profile_steps = os.environ.get(
-                "TFOS_PROFILE_STEPS", "") not in ("", "0", "false", "no")
-        self._profile_steps = bool(profile_steps)
         self._steps_done = 0
         # flight recorder: step() attributes its shard + dispatch
         # (compute) per step and commits the feed-plane record the
@@ -257,15 +247,13 @@ class Trainer:
         self.last_checkpoint_step: int | None = None
         self._elastic = None
         #: trace id of the most recently completed step (step-scoped
-        #: identity: each step's window records as a ``trainer.step`` span
-        #: under its own trace id, so anomaly findings and bench notes can
-        #: cite the exact step they judged)
+        #: identity: each step records as a ``trainer.step`` span under
+        #: its own trace id, so anomaly findings and bench notes can cite
+        #: the exact step they judged)
         self.last_step_trace_id: str | None = None
-        obs.get_tracer().record(
-            "trainer.init", "X", _t0_wall * 1e6,
-            (time.perf_counter() - _t0) * 1e6,
-            {"model": self.model_name or "custom",
-             "mesh": dict(self.mesh.shape)})
+        obs.complete("trainer.init", _t0_wall, time.perf_counter() - _t0,
+                     model=self.model_name or "custom",
+                     mesh=dict(self.mesh.shape))
 
     # -- stepping ------------------------------------------------------------
 
@@ -283,42 +271,64 @@ class Trainer:
         self._step_callbacks.append(fn)
 
     def step(self, batch) -> float:
-        """One sharded optimizer step; returns the (replicated) loss."""
+        """One sharded optimizer step; returns the (replicated) loss.
+
+        The call is the ``trainer.step`` span (children ``trainer.shard``
+        and ``trainer.dispatch``, ``trainer.checkpoint`` where one is
+        taken): one pair of clock reads each feeds the ring, the flight
+        stages ``shard`` / ``compute``, the goodput ledger and, in a
+        profiler session, an annotation of the same name and ``step``."""
         if self._watchdog is not None:
             return self._watchdogged_step(batch)
-        t0 = time.perf_counter()
-        staged = self.shard(batch)
-        t1 = time.perf_counter()
-        with self._step_annotation():
-            self.state, loss = self.train_step(self.state, staged)
-        # `compute` is the dispatch wall: on async backends it understates
-        # true device time until dispatch throttling backs up — which is
-        # exactly when a step becomes device-bound and the number grows.
-        # The shard is its own `shard` stage (not `stage`): a feed that
-        # already device_put the batch recorded the real transfer as
-        # `stage`, and this re-shard of device-resident arrays is ~free —
-        # sharing the name would bimodalize that histogram toward zero
-        compute_s = time.perf_counter() - t1
-        self._flight.add(shard=t1 - t0, compute=compute_s)
-        # goodput ledger: the same windows, phase-classified (the first
-        # step's compute wall IS the jit compile — note_step books it)
-        from tensorflowonspark_tpu.obs import ledger as ledger_mod
+        with obs.span("trainer.step",
+                      step=self._steps_done + 1).root() as sp:
+            loss = self._dispatch(batch, wait=False)
+            loss = self._after_step(loss, batch)
+        self.last_step_trace_id = sp.trace_id or self.last_step_trace_id
+        return loss
 
-        ledger_mod.goodput().note_step(t1 - t0, compute_s)
+    def _dispatch(self, batch, *, wait: bool):
+        """Shard the batch and call the jitted step, inside the open
+        ``trainer.step`` span.  `compute` is the dispatch wall: on async
+        backends it understates true device time until dispatch throttling
+        backs up — which is exactly when a step becomes device-bound and
+        the number grows; with ``wait`` (the watchdogged step) the loss is
+        forced inside it, so it is true device wall.  The shard is its own
+        `shard` stage (not `stage`): a feed that already device_put the
+        batch recorded the real transfer as `stage`, and this re-shard of
+        device-resident arrays is ~free — sharing the name would
+        bimodalize that histogram toward zero."""
+        with obs.span("trainer.shard") as sh:
+            staged = self.shard(batch)
+        with obs.span("trainer.dispatch") as run:
+            self.state, loss = self.train_step(self.state, staged)
+            if wait:
+                import jax
+
+                loss = jax.block_until_ready(loss)
+        # the spans' durations are the flight stages' and the goodput
+        # ledger's (the first step's compute wall IS the jit compile —
+        # note_step books it): no clock is read again
+        self._flight.add(shard=sh.dur_s, compute=run.dur_s)
+        obs.ledger.goodput().note_step(sh.dur_s, run.dur_s)
         # bucketed step: the modelled collective-stage costs ride beside
-        # the dispatch wall as overlapped (`_bg`) stages — on the async
-        # path nothing blocks, so the comm is context, not critical path
+        # the dispatch wall as overlapped (`_bg`) stages: an upper bound
+        # on exposed comm (overlap only shrinks it), and a MODEL must not
+        # name the bottleneck — on a well-overlapped comm-heavy step an
+        # additive split would classify comm_bound exactly when the
+        # overlap works.  The measured comm-vs-compute verdict comes from
+        # bench's step-collectives A/B, which times the no-reduce twin.
         comm = self._comm_stage_seconds()
         if comm:
+            if wait:
+                comm = {k: min(v, run.dur_s) for k, v in comm.items()}
             self._flight.add(overlapped=True, **comm)
-        return self._after_step(loss, batch)
+        return loss
 
     def _peek_gauge(self, name: str) -> "float | None":
         """Read a roofline gauge if a probe ever set it.  Peek, never
         get-or-create: a trainer that merely ASKED must not mint a phantom
         0.0 bandwidth series in processes that never ran the probe."""
-        from tensorflowonspark_tpu import obs
-
         gauge = obs.get_registry().peek(name)
         bw = gauge.value if gauge is not None else None
         return bw if bw and bw > 0 else None
@@ -372,30 +382,11 @@ class Trainer:
                 out["update"] = update_s
         return out
 
-    def _step_annotation(self):
-        """Optional ``jax.profiler.StepTraceAnnotation`` around the jitted
-        step (``profile_steps=True`` / ``TFOS_PROFILE_STEPS=1``) — a no-op
-        context otherwise.  Best-effort: a backend without profiler support
-        must not break training."""
-        import contextlib
-
-        if not self._profile_steps:
-            return contextlib.nullcontext()
-        try:
-            import jax
-
-            return jax.profiler.StepTraceAnnotation(
-                "train_step", step_num=self._steps_done)
-        except Exception:
-            return contextlib.nullcontext()
-
     def _after_step(self, loss, batch):
         """Shared post-step accounting: wall-time + examples → callbacks
         and the obs registry (steps/examples counters, step-time
         histogram — the per-node series ``TFCluster.metrics()`` rolls
         up)."""
-        from tensorflowonspark_tpu import obs
-
         now = time.perf_counter()
         dt = now - self._last_step_t if self._last_step_t else 0.0
         self._last_step_t = now
@@ -410,18 +401,6 @@ class Trainer:
         # (obs.anomaly): a node whose gauge falls behind the freshest
         # peer is wedged — visible from the rollup without any new RPC
         obs.gauge("trainer_last_step_unix_ts").set(time.time())
-        # step-scoped trace id: the step's wall window (previous step →
-        # now: feed wait + shard + dispatch) ships as a trainer.step span
-        # the driver's anomaly findings cite (obs.anomaly.cite_step_traces).
-        # Minted only when a span is actually recorded — an id that exists
-        # in no ring buffer would be a dangling citation (first step: dt=0)
-        if dt > 0:
-            ctx = obs.TraceContext.new()
-            self.last_step_trace_id = ctx.trace_id
-            obs.get_tracer().record(
-                "trainer.step", "X", (time.time() - dt) * 1e6, dt * 1e6,
-                {"step": self._steps_done},
-                trace_id=ctx.trace_id, span_id=ctx.span_id)
         # close the feed-plane flight record (DataFeed wait/ingest + this
         # step's stage/compute) into one classified bottleneck verdict
         self._flight.commit()
@@ -471,46 +450,25 @@ class Trainer:
         chip is the rendezvous health probe's job
         (health.probe_chip_health), not this watchdog's.
         """
-        import jax
-
         signature = self._batch_signature(batch)
         armed = signature in self._watchdog_warm_shapes
-        if armed:
-            self._watchdog.arm()
-            if os.environ.get("TFOS_STEP_WATCHDOG_TEST_HANG"):
-                time.sleep(3600)  # simulated mid-run wedge (tests)
-        try:
-            t0 = time.perf_counter()
-            staged = self.shard(batch)
-            t1 = time.perf_counter()
-            with self._step_annotation():
-                self.state, loss = self.train_step(self.state, staged)
-                loss = jax.block_until_ready(loss)
-            # the watchdogged step forces the loss, so `compute` here is
-            # true device wall, not just dispatch (`shard`, not `stage`:
-            # see step()).  The bucketed step's modelled collective cost
-            # rides beside it as an overlapped (`_bg`) stage, same as the
-            # async path: it is an upper bound on exposed comm (overlap
-            # only shrinks it), and a MODEL must not name the bottleneck
-            # — on a well-overlapped comm-heavy step an additive split
-            # would classify comm_bound exactly when the overlap works.
-            # The measured comm-vs-compute verdict comes from bench's
-            # step-collectives A/B, which times the no-reduce twin.
-            compute_s = time.perf_counter() - t1
-            self._flight.add(shard=t1 - t0, compute=compute_s)
-            from tensorflowonspark_tpu.obs import ledger as ledger_mod
-
-            ledger_mod.goodput().note_step(t1 - t0, compute_s)
-            comm = self._comm_stage_seconds()
-            if comm:
-                self._flight.add(overlapped=True, **{
-                    k: min(v, compute_s) for k, v in comm.items()})
-        finally:
-            # disarm on ANY exit: an exception a caller handles must not
-            # leave a stale armed timestamp that later reads as a stall
-            self._watchdog.beat()
-        self._watchdog_warm_shapes.add(signature)
-        return self._after_step(loss, batch)
+        with obs.span("trainer.step",
+                      step=self._steps_done + 1).root() as sp:
+            if armed:
+                self._watchdog.arm()
+                if os.environ.get("TFOS_STEP_WATCHDOG_TEST_HANG"):
+                    time.sleep(3600)  # simulated mid-run wedge (tests)
+            try:
+                loss = self._dispatch(batch, wait=True)
+            finally:
+                # disarm on ANY exit: an exception a caller handles must
+                # not leave a stale armed timestamp that later reads as a
+                # stall
+                self._watchdog.beat()
+            self._watchdog_warm_shapes.add(signature)
+            loss = self._after_step(loss, batch)
+        self.last_step_trace_id = sp.trace_id or self.last_step_trace_id
+        return loss
 
     def predict(self, batch):
         if getattr(self.forward_fn, "stateful", False):
@@ -571,14 +529,12 @@ class Trainer:
         # forcing state.step syncs the device — but only on the save
         # cadence, where the save itself snapshots the same state anyway
         step = int(np.asarray(self.state.step))
-        t0 = time.perf_counter()
-        self._ckpt_mgr.save(step, self._state_tree())
+        with obs.span("trainer.checkpoint", step=step) as sp:
+            self._ckpt_mgr.save(step, self._state_tree())
         # async saves return after the device→host snapshot; that
         # snapshot wall is the step path's real checkpoint cost, which
         # is exactly what the goodput breakdown should book
-        from tensorflowonspark_tpu.obs import ledger as ledger_mod
-
-        ledger_mod.goodput().note_checkpoint(time.perf_counter() - t0)
+        obs.ledger.goodput().note_checkpoint(sp.dur_s)
         self.last_checkpoint_step = step
 
     def restore_latest(self) -> int | None:

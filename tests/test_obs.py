@@ -3,6 +3,7 @@ nesting/ordering, ring-buffer bounds, blackboard shipping, Chrome-trace
 merge determinism, registry semantics, and Prometheus exposition."""
 
 import json
+import os
 import threading
 import time
 
@@ -101,6 +102,179 @@ def test_threaded_spans_do_not_cross_nest():
     assert "parent" not in (evs["b"].get("attrs") or {})
 
 
+def test_span_feeds_flight_stage_and_duration_from_one_clock_pair():
+    """The site reads no clock of its own: the span's duration is the
+    flight stage's and ``dur_s``."""
+    tr = Tracer(node="t")
+    rec = obs.flight.FlightRecorder("spantest")
+    with tr.span("feed.collate").flight(rec, "collate", True) as sp:
+        time.sleep(0.01)
+    (ev,) = tr.snapshot()
+    assert sp.dur_s == pytest.approx(ev["dur"] * 1e-6)
+    assert rec.totals_overlapped() == {"collate": pytest.approx(sp.dur_s)}
+    with tr.span("reader.batch") as sp:        # nothing to read: leave out
+        sp.cancel()
+    assert len(tr.snapshot()) == 1
+
+
+def test_span_times_its_stretch_with_recording_off(monkeypatch):
+    """``TFOS_TRACE=0``: nothing recorded, but the flight stage and the
+    goodput ledger still get their durations from the span."""
+    monkeypatch.setenv("TFOS_TRACE", "0")
+    tr = Tracer(node="t")
+    rec = obs.flight.FlightRecorder("spantest_off")
+    with tr.span("trainer.shard").flight(rec, "shard") as sp:
+        time.sleep(0.005)
+    assert tr.snapshot() == [] and sp.trace_id is None
+    assert sp.dur_s >= 0.005 and rec.totals() == {"shard": sp.dur_s}
+
+
+def test_root_span_starts_a_trace_of_its_own_and_complete_nests():
+    tr = Tracer(node="t")
+    with tr.span("node.map_fun") as outer:
+        with tr.span("trainer.step", step=1).root() as step:
+            with tr.span("trainer.dispatch"):
+                pass
+        tr.complete("feed.turnround", time.time() - 1.0, 1.0, rows=3)
+    evs = {e["name"]: e for e in tr.snapshot()}
+    assert step.trace_id != outer.trace_id
+    assert evs["trainer.dispatch"]["trace_id"] == step.trace_id
+    assert evs["trainer.dispatch"]["parent_span_id"] == \
+        evs["trainer.step"]["span_id"]
+    assert "parent_span_id" not in evs["trainer.step"]
+    turn = evs["feed.turnround"]
+    assert turn["dur"] == pytest.approx(1e6) and turn["attrs"] == {
+        "rows": 3, "parent": "node.map_fun"}
+    assert turn["parent_span_id"] == evs["node.map_fun"]["span_id"]
+
+
+def test_span_is_in_the_profile_and_in_the_ring_with_the_same_step(tmp_path):
+    """In a process that has imported JAX a span is also a
+    ``TraceAnnotation``: a profiler session holds it on the host plane, on
+    its own clock, with the ring's ``step``; the pairs give the offset
+    between the clocks."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(node="t")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for step in (7, 8, 9):
+            with tr.span("trainer.step", step=step):
+                with tr.span("trainer.dispatch"):
+                    time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in ("trainer.step", "trainer.dispatch"):
+                        profile.setdefault(ev.name, []).append(
+                            (dict(ev.stats).get("step"), ev.start_ns * 1e-9,
+                             ev.duration_ns * 1e-9))
+    ring = [e for e in tr.snapshot() if e["name"] == "trainer.step"]
+    assert [s for s, _, _ in profile["trainer.step"]] == [7, 8, 9] == [
+        e["attrs"]["step"] for e in ring]
+    assert len(profile["trainer.dispatch"]) == 3
+    for (_, _, dur), ev in zip(profile["trainer.step"], ring):
+        assert dur == pytest.approx(ev["dur"] * 1e-6, abs=1e-3)
+    clock = obs.clock_offset([(ev["ts"] * 1e-6, start) for (_, start, _), ev
+                              in zip(profile["trainer.step"], ring)])
+    assert clock["pairs"] == 3 and clock["spread_s"] < 1e-3
+
+
+def test_clock_offset_places_another_process_within_the_pair_spread():
+    true_offset = 4321.25
+    noise = [0.0003, -0.0002, 0.0005, -0.0004, 0.0001, 0.0, 0.0002]
+    pairs = [(1000.0 + i, 1000.0 + i + true_offset + n)
+             for i, n in enumerate(noise)]
+    clock = obs.clock_offset(pairs)
+    assert clock["pairs"] == 7 and 0 < clock["spread_s"] < 1e-3
+    feeder_start = 1003.5           # another process, the same wall clock
+    placed = feeder_start + clock["offset_s"]
+    assert abs(placed - (feeder_start + true_offset)) <= clock["spread_s"]
+    assert obs.clock_offset([]) is None
+
+
+def _no_jax_script(tmp_path, body):
+    import subprocess
+    import sys
+
+    import tensorflowonspark_tpu
+
+    repo = os.path.dirname(os.path.dirname(tensorflowonspark_tpu.__file__))
+    script = tmp_path / "no_jax.py"
+    script.write_text(
+        "import json, os, sys, threading\n"
+        f"sys.path.insert(0, {repo!r})\n"
+        "def main():\n" + "".join(
+            f"    {line}\n" for line in body.strip().splitlines())
+        + "    bad = [m for m in sys.modules if m == 'jax' or "
+        "m.startswith(('jax.', 'jaxlib'))]\n"
+        "    assert not bad, bad\n"
+        "if __name__ == '__main__':\n    main()\n")
+    # no shared-memory segments from here: tests elsewhere list /dev/shm
+    proc = subprocess.run([sys.executable, str(script)], capture_output=True,
+                          text=True, timeout=120, cwd=str(tmp_path),
+                          env=dict(os.environ, TFOS_FEED_SHM="0"))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return proc.stdout
+
+
+def test_a_process_without_jax_imports_none_for_a_span(tmp_path):
+    out = _no_jax_script(tmp_path, """
+from tensorflowonspark_tpu import obs
+with obs.span("cluster.reserve", step=3):
+    with obs.span("feeder.task"):
+        pass
+obs.complete("feed.turnround", 1.0, 0.5)
+print(json.dumps([e["name"] for e in obs.get_tracer().snapshot()]))
+""")
+    assert json.loads(out.strip().splitlines()[-1]) == [
+        "feeder.task", "cluster.reserve", "feed.turnround"]
+
+
+def test_the_executors_feeder_task_stays_off_jax(tmp_path):
+    """A partition fed through ``TFSparkNode.train`` in a process of its
+    own: the feeder's spans are recorded, JAX is never imported."""
+    out = _no_jax_script(tmp_path, """
+from tensorflowonspark_tpu import TFManager, TFSparkNode, marker, obs, util
+key = b"feeder-no-jax"
+mgr = TFManager.start(key, ["input", "output", "error"], mode="local")
+mgr.set("state", "running")
+util.write_executor_id(0, name=TFSparkNode._guard_name("cid"))
+info = [{"executor_id": 0, "addr": list(mgr.address), "job_name": "worker",
+         "task_index": 0}]
+meta = {"id": "cid", "authkey_hex": key.hex(), "feed_chunk": 4}
+q = mgr.get_queue("input")
+def drain():
+    while not isinstance(q.get(), marker.EndPartition):
+        pass
+t = threading.Thread(target=drain)
+t.start()
+TFSparkNode.train(info, meta, 30.0, "input")(
+    iter([(float(i), i) for i in range(10)]))
+t.join()
+spans = {e["name"]: e for e in obs.get_tracer().snapshot()}
+print(json.dumps({k: v.get("attrs") for k, v in spans.items()}))
+mgr.shutdown()
+""")
+    spans = json.loads(out.strip().splitlines()[-1])
+    assert set(spans) == {"feeder.task", "feeder.connect", "feeder.first_row",
+                          "feeder.send", "feeder.drain_wait"}
+    send = spans["feeder.send"]
+    assert send["chunks"] == 3 and send["rows"] == 10 and send["bytes"] > 0
+    assert send["encode_s"] > 0 and send["parent"] == "feeder.task"
+
+
 # ---------------------------------------------------------------------------
 # executor→driver shipping through the (fake) kv blackboard
 # ---------------------------------------------------------------------------
@@ -140,17 +314,94 @@ def test_flush_survives_broken_mgr():
     assert tr.flush(Broken()) is False  # must not raise
 
 
-def test_auto_flush_on_event_threshold():
+def test_recording_never_ships_the_shipper_thread_does():
+    """``record`` appends and returns: no manager call on the recording
+    thread, however many events.  The daemon ships what came since its
+    cursor, as a chunk under a key of its own."""
+    calls = []
+
+    class ThreadMgr(FakeMgr):
+        def set(self, k, v):
+            calls.append(threading.current_thread().name)
+            super().set(k, v)
+
+    tr = Tracer(node="worker:0")
+    mgr = ThreadMgr()
+    tr.flush_interval_s = 0.05
+    tr.configure(mgr=mgr)
+    for i in range(200):
+        tr.event(f"e{i}")
+    deadline = time.time() + 5
+    while time.time() < deadline and sum(
+            len(p["events"]) for p in mgr.kv.values()) < 200:
+        time.sleep(0.02)
+    assert calls and set(calls) == {"tfos-trace-shipper"}
+    shipped = obs.collect_blackboard(mgr.kv_snapshot())["worker:0"]
+    assert sorted(e["name"] for e in shipped) == sorted(
+        f"e{i}" for i in range(200))
+    tr.clear()      # takes the manager away: the shipper thread ends
+    tr._shipper.join(5)
+    assert not tr._shipper.is_alive()
+
+
+def test_incremental_shipping_loses_and_repeats_no_event():
+    """A recorder thread records while the shipper and explicit flushes
+    ship: every event reaches the blackboard exactly once, in chunks that
+    do not overlap."""
     tr = Tracer(node="worker:0")
     mgr = FakeMgr()
+    tr.flush_interval_s = 0.001
     tr.configure(mgr=mgr)
-    tr.flush_interval = 5
-    tr.flush_interval_s = 3600.0  # only the count threshold may trigger
-    for i in range(4):
+    n = 5000
+
+    def recorder():
+        for i in range(n):
+            tr.event("e", i=i)
+
+    th = threading.Thread(target=recorder)
+    th.start()
+    while th.is_alive():
+        tr.flush()
+    th.join()
+    assert tr.flush()
+    chunks = list(mgr.kv.values())
+    assert len(chunks) > 1
+    seen = [e["attrs"]["i"] for p in chunks for e in p["events"]]
+    assert sorted(seen) == list(range(n))       # none lost, none repeated
+    assert all(p["dropped"] == 0 for p in chunks)
+    assert obs.collect_dropped(mgr.kv_snapshot()) == {"worker:0": 0}
+    tr.clear()
+
+
+def test_shipping_bounds_the_blackboard_and_counts_what_it_dropped():
+    class Mgr(FakeMgr):
+        def delete(self, k):
+            self.kv.pop(k, None)
+
+    tr = Tracer(node="w", capacity=10)
+    mgr = Mgr()
+    for i in range(25):         # 15 fall out of the ring before any flush
         tr.event(f"e{i}")
-    assert not mgr.kv  # under threshold: nothing shipped yet
-    tr.event("e4")
-    assert mgr.kv  # fifth event crossed the threshold
+    assert tr.flush(mgr)
+    assert obs.collect_dropped(mgr.kv_snapshot()) == {"w": 15}
+    for i in range(25, 31):     # 10 + 6 > capacity: the old chunk goes
+        tr.event(f"e{i}")
+    assert tr.flush(mgr)
+    names = [e["name"] for e in obs.collect_blackboard(
+        mgr.kv_snapshot())["w"]]
+    assert names == [f"e{i}" for i in range(25, 31)]
+    assert obs.collect_dropped(mgr.kv_snapshot()) == {"w": 25}
+
+
+def test_flush_publishes_the_registry_snapshot(monkeypatch):
+    mgr = FakeMgr()
+    monkeypatch.setattr(obs.trace._TRACER, "node", "worker:3")
+    obs.counter("flush_publishes_total").inc(4)
+    assert obs.flush(mgr)
+    counters = obs.collect_counters(mgr.kv_snapshot())
+    (key,) = counters
+    assert key.startswith("worker:3:")
+    assert counters[key]["counters"]["flush_publishes_total"] == 4
 
 
 def test_collect_blackboard_merges_processes_of_one_node():
