@@ -97,9 +97,16 @@ step — never per record, row or chunk):
   ``node.chip_claim``, ``node.manager_start``, ``health.probe``,
   ``node.register_await``, ``node.trainer_spawn``,
   ``node.distributed_init``, ``node.chip_verify``, ``node.map_fun``;
-- feed plane, readers: ``reader.batch`` > ``reader.parse``,
-  ``reader.stack``, ``feed.stage``; ``feed.pump_blocked`` (producer on a
-  full queue), ``feed.wait`` (consumer on an empty one); ``readers.epoch``;
+- feed plane, readers: ``reader.batch`` (attr ``ahead``: batches whose
+  arrays are being made ahead) > ``reader.parse`` (any wait for the
+  arrays made ahead + read + ``parse_fn`` + each NumPy value's one copy
+  into its row of the batch's column array), ``reader.stack`` (what is
+  then left: ``np.asarray`` of the list columns, the trim of a short
+  last batch), ``feed.stage``;
+  ``feed.pump_blocked`` (producer on a full queue), ``feed.wait``
+  (consumer on an empty one); ``readers.epoch``; counters
+  ``reader_columns_direct_total`` / ``reader_columns_stacked_total``, one
+  increment a column a batch, say which way the columns went;
 - feed plane, Spark consumer (``TFNode.DataFeed``): ``feed.queue_wait``,
   ``feed.ingest``, ``feed.collate``, ``feed.stage``, ``feed.pump_blocked``
   on the pump thread, ``feed.wait`` on the consumer, ``feed.turnround``

@@ -14,7 +14,15 @@ threads + queues over :mod:`tensorflowonspark_tpu.tfrecord`:
 - **parallel readers**: ``readers`` threads interleave records from several
   files at once (I/O-bound decode overlaps);
 - **shuffle**: a bounded reservoir of records, files reshuffled per epoch;
-- **prefetch**: batches are columnarized (and optionally ``device_put`` into
+- **columns filled while parsing**: a NumPy array or scalar that
+  ``parse_fn`` returns is copied once, straight into its row of the
+  batch's column array; only values whose dtype the first row cannot
+  promise (Python lists, numbers, ``bytes``) are collected and stacked
+  after the batch's last record.  Every batch gets arrays of its own, and
+  the arrays of the batches to come are made, and their pages first
+  written, on helper threads beside the parse (as many as it takes to
+  keep ahead of it, four at most);
+- **prefetch**: batches are built (and optionally ``device_put`` into
   HBM) in a pipeline thread ``prefetch`` batches ahead of the consumer, so
   step time approaches ``max(compute, feed)`` instead of their sum
   (``SURVEY.md §3.2`` perf-critical path / hard part (b)).
@@ -28,9 +36,12 @@ Everything is pull-based and bounded; no unbounded buffering.
 
 from __future__ import annotations
 
+import collections
+import concurrent.futures
 import contextlib
 import itertools
 import logging
+import mmap
 import queue as _queue_mod
 import threading
 import time as _time_mod
@@ -70,11 +81,135 @@ def default_parse(payload: bytes) -> dict[str, Any]:
     return {k: v for k, (_, v) in tfrecord.decode_example(payload).items()}
 
 
-def _columnarize(rows: list[dict[str, Any]]) -> dict[str, np.ndarray]:
-    cols: dict[str, np.ndarray] = {}
-    for name in rows[0]:
-        cols[name] = np.asarray([r[name] for r in rows])
-    return cols
+def _is_numpy_value(value: Any) -> bool:
+    """A plain NumPy array or scalar of a native numeric or bool dtype: the
+    values for which ``np.asarray`` over equal rows keeps dtype and shape,
+    so that one row can size the whole column."""
+    return ((type(value) is np.ndarray or isinstance(value, np.generic))
+            and value.dtype.kind in "biufc" and value.dtype.isnative)
+
+
+def _fits(col: np.ndarray, value: Any) -> bool:
+    """``value`` can be a row of the array column ``col`` as it is."""
+    return (_is_numpy_value(value) and value.dtype == col.dtype
+            and value.shape == col.shape[1:])
+
+
+def _fill_columns(rows: Iterator[dict[str, Any]], batch_size: int,
+                  take_made: Callable[[], dict[str, np.ndarray]]
+                  ) -> tuple[int, dict[str, np.ndarray | list]]:
+    """Consume ``rows`` (at most ``batch_size``) into columns, and return
+    their number with the columns.
+
+    A column whose first value is a NumPy value is an array of
+    ``batch_size`` rows that each row is copied into as it arrives (the row
+    is still in cache, and is dropped before the next is parsed): the one
+    that ``take_made()``, asked once there is a row, holds under its name
+    if that fits the value, else a new one.  Any other column is the list
+    of its values, for ``np.asarray`` to type across all of them.  An array
+    column that meets a value of another dtype or shape turns back into the
+    list of its rows so far and goes on as one, so every column ends as
+    ``np.asarray([r[name] for r in rows])`` would.
+    """
+    n = 0
+    cols: dict[str, np.ndarray | list] = {}
+    for row in rows:
+        if n == 0:
+            made = take_made()
+            for name, value in row.items():
+                if not _is_numpy_value(value):
+                    cols[name] = []
+                    continue
+                col = made.get(name)
+                if col is None or not _fits(col, value):
+                    col = np.empty((batch_size, *value.shape), value.dtype)
+                cols[name] = col
+        for name, col in cols.items():
+            value = row[name]
+            if type(col) is list:
+                col.append(value)
+            elif _fits(col, value):
+                col[n] = value
+            else:
+                cols[name] = [*col[:n], value]
+        n += 1
+    return n, cols
+
+
+class _ColumnsAhead:
+    """The array columns of the batches to come, each made new and written
+    to once a page on helper threads.
+
+    The first write to memory fresh from the system is the dearest part of
+    filling a batch — a page fault every 4 KB, three quarters of the time a
+    77 MB batch took on the benchmark's host (``PERF.md``, PR 25) — and it
+    needs no record, so it is done ahead, beside the parse.  Every batch
+    still gets arrays of its own: nothing here is used twice.
+    """
+
+    MAX_DEPTH = 4  # batches made ahead at most, one a helper thread
+
+    def __init__(self) -> None:
+        self._pool: concurrent.futures.ThreadPoolExecutor | None = None
+        self._pending: collections.deque = collections.deque()
+        self.depth = 1
+
+    @staticmethod
+    def _make(spec: dict[str, tuple]) -> dict[str, np.ndarray]:
+        made = {}
+        for name, (shape, dtype) in spec.items():
+            col = made[name] = np.empty(shape, dtype)
+            # one write a page maps it; NumPy drops the GIL for the loop
+            col.reshape(-1)[::max(1, mmap.PAGESIZE // col.itemsize)] = 0
+        return made
+
+    def take(self) -> dict[str, np.ndarray]:
+        """The columns made for the batch that starts now; none before a
+        batch has shown what columns there are.  Waiting for a helper that
+        has not finished costs no more than making them here, and says that
+        the helpers are behind the parse: one more from now on."""
+        if not self._pending:
+            return {}
+        made = self._pending.popleft()
+        if not made.done() and self.depth < self.MAX_DEPTH:
+            self.depth += 1
+        return made.result()
+
+    def expect_more_like(self, cols: dict[str, np.ndarray | list]) -> None:
+        """Have the next batches' columns made like this batch's arrays.
+        Should the rows change, what was made for the old ones does not fit
+        and is dropped column by column in :func:`_fill_columns`."""
+        spec = {name: (col.shape, col.dtype) for name, col in cols.items()
+                if type(col) is not list}
+        if not spec:
+            return
+        if self._pool is None:
+            self._pool = concurrent.futures.ThreadPoolExecutor(
+                self.MAX_DEPTH, thread_name_prefix="tfos-columns")
+        while len(self._pending) < self.depth:
+            self._pending.append(self._pool.submit(self._make, spec))
+
+    def close(self) -> None:
+        if self._pool is not None:
+            self._pool.shutdown(wait=False, cancel_futures=True)
+
+
+def _finish_columns(n: int, cols: dict[str, np.ndarray | list]
+                    ) -> dict[str, np.ndarray]:
+    """What is left to make ``n`` filled rows a batch: ``np.asarray`` over
+    a list column, and a copy of a short last batch's rows (one copy an
+    epoch, so that the batch does not pin the tail of a whole block)."""
+    batch: dict[str, np.ndarray] = {}
+    direct = 0
+    for name, col in cols.items():
+        if type(col) is list:
+            batch[name] = np.asarray(col)
+        else:
+            batch[name] = col if n == len(col) else col[:n].copy()
+            direct += 1
+    obs.counter("reader_columns_direct_total").inc(direct)
+    obs.counter("reader_columns_stacked_total").inc(len(cols) - direct)
+    return batch
 
 
 class _ReaderPool:
@@ -194,6 +329,22 @@ def tfrecord_batches(
     the pipeline thread — the double-buffered host→HBM path.  ``device_put``
     may also be a callable applied to each columnar batch (e.g.
     ``Trainer.shard`` to stage with mesh shardings).
+
+    Every column equals ``np.asarray([parse_fn(p)[name] for p in batch])``.
+    A ``parse_fn`` that returns NumPy arrays or scalars (numeric or bool)
+    has each value copied once, into the batch's own array, as its record
+    is parsed; other values (``default_parse``'s lists, Python numbers,
+    ``bytes``) are stacked after the last record.  Nothing selects between
+    the two but the values themselves.  The arrays are new in every batch;
+    those of the next batches are made ahead by up to four helper threads
+    (:class:`_ColumnsAhead`), because the first write to fresh memory costs
+    more than the copy.  Spans: ``reader.batch`` (attrs ``records``,
+    ``bytes``, ``ahead`` = batches being made ahead) > ``reader.parse``
+    (any wait for the arrays made ahead + read + ``parse_fn`` + the rows'
+    copies), ``reader.stack`` (what is then left: ``np.asarray`` of list
+    columns, the trim of a short last batch), ``feed.stage``.  Counters,
+    one increment a column a batch: ``reader_columns_direct_total``,
+    ``reader_columns_stacked_total``.
     """
     if isinstance(files, str):
         files = fs.glob(files)
@@ -206,21 +357,24 @@ def tfrecord_batches(
     def read_batch(stream: Iterator[bytes]) -> dict[str, Any] | None:
         """The next batch of the epoch, staged, or None at its end.  One
         ``reader.batch`` span a batch, whose children split it: read +
-        parse of its records, the stack into columns, the staging."""
+        parse of its records into the columns, what is left to stack,
+        the staging."""
         with obs.span("reader.batch") as sp:
             with obs.span("reader.parse") as parse_sp:
-                rows = [parse(p)
-                        for p in itertools.islice(stream, batch_size)]
-                if not rows:
+                n, cols = _fill_columns(
+                    map(parse, itertools.islice(stream, batch_size)),
+                    batch_size, ahead.take)
+                if not n:
                     parse_sp.cancel()
-            if not rows or (len(rows) < batch_size and drop_remainder):
-                if not rows:
+            ahead.expect_more_like(cols)
+            if not n or (n < batch_size and drop_remainder):
+                if not n:
                     sp.cancel()
                 return None
-            obs.counter("reader_records_total").inc(len(rows))
+            obs.counter("reader_records_total").inc(n)
             with obs.span("reader.stack"):
-                batch = _columnarize(rows)
-            sp.set(records=len(rows),
+                batch = _finish_columns(n, cols)
+            sp.set(records=n, ahead=ahead.depth,
                    bytes=sum(int(c.nbytes) for c in batch.values()))
             with obs.span("feed.stage"):
                 return _stage(batch)
@@ -250,8 +404,11 @@ def tfrecord_batches(
                          epoch=epoch, files=len(epoch_files))
 
     _stage = _stager(device_put)
-
-    yield from prefetched(batch_gen, prefetch, spans=True)
+    ahead = _ColumnsAhead()
+    try:
+        yield from prefetched(batch_gen, prefetch, spans=True)
+    finally:
+        ahead.close()  # after the pump has stopped: its helpers go too
 
 
 def _stager(device_put) -> Callable[[dict[str, Any]], dict[str, Any]]:
