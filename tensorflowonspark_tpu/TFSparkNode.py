@@ -557,9 +557,11 @@ class _TrainFn:
         """One partition is one ``feeder.task`` span; its children are the
         parts of the feed's turn-round at a partition end:
         ``feeder.connect`` (to the node's manager, state and queue proxy in
-        hand), ``feeder.first_row`` (the iterator's first row: where Spark
-        deserialises the partition), ``feeder.send`` (first to last chunk)
-        and ``feeder.drain_wait`` (the consumption poll)."""
+        hand), ``feeder.first_row`` (the iterator's first row: Spark, and
+        the local substrate like it, unpickle the partition's first batch
+        of rows here), ``feeder.send`` (first to last chunk, the later
+        batches' unpickling under it) and ``feeder.drain_wait`` (the
+        consumption poll)."""
         with obs.span("feeder.task") as task:
             self._feed(iterator, task)
 

@@ -92,8 +92,15 @@ Span names, by layer (one span per layer boundary and batch / partition /
 step — never per record, row or chunk):
 
 - cluster and node runtime: ``cluster.reserve``, ``cluster.train``,
-  ``cluster.feed_epoch``, ``cluster.shutdown``, ``spark.task_send``,
-  ``executor.start``, ``executor.task`` > ``executor.task_load``,
+  ``cluster.feed_epoch``, ``cluster.shutdown``, ``spark.task_send`` (a
+  task's put on its executor's queue, and before it the partition's
+  pickling into row batches the first time any job sends it),
+  ``executor.start``, ``executor.task`` > ``executor.task_load`` (the
+  function chain's load and the row stream's opening: the rows are
+  unpickled batch by batch under the task's iterator); counters
+  ``spark_partition_batches_sent_total`` (row batches put on executors'
+  queues) and ``spark_partition_blobs_reused_total`` (partitions sent
+  without pickling);
   ``node.chip_claim``, ``node.manager_start``, ``health.probe``,
   ``node.register_await``, ``node.trainer_spawn``,
   ``node.distributed_init``, ``node.chip_verify``, ``node.map_fun``;

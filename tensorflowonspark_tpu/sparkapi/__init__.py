@@ -13,6 +13,10 @@ two ways:
   each partition task in one of N persistent, separate executor *processes*
   (spawn), mirroring Spark ``local-cluster[N, cores, mem]`` semantics — the
   mode the reference's own integration tests rely on (``SURVEY.md §4``).
+  It is a faithful stand-in for that mode where a job's cost is concerned:
+  like ``parallelize``, it serialises a partition once, in batches of at
+  most 1,024 rows, keeps the serialised form for every later job, and the
+  executor unpickles batch by batch under the task's iterator.
   Closures are cloudpickled, results return over a shared queue, failures
   propagate driver-side with the executor traceback and **no task retry**
   (``spark.task.maxFailures=1``, the setting the reference documents as

@@ -2,8 +2,9 @@
 
 One instance of :func:`executor_main` runs per executor process.  It mirrors
 what a Spark executor's python worker does with a task: deserialize the
-function chain, apply it to the partition iterator, ship the result (or the
-traceback) back to the driver.
+function chain, apply it to the partition iterator — which unpickles the
+partition's rows one batch at a time, as the task consumes them — and ship
+the result (or the traceback) back to the driver.
 
 Each executor gets its own working directory under the app scratch dir —
 this preserves the reference's executor-id collision-guard semantics
@@ -14,8 +15,19 @@ cwd, which Spark keeps distinct per executor).
 from __future__ import annotations
 
 import os
+import pickle
 import time
 import traceback
+from typing import Any, Iterator
+
+
+def _rows(blobs: list[bytes]) -> Iterator[Any]:
+    """The task's iterator over a partition sent as row-batch pickles: a
+    batch is unpickled when the one before it is exhausted, so the task's
+    function sees its first row after one batch's load, and a consumer that
+    holds the task back (a full feed queue) holds the unpickling back too."""
+    for blob in blobs:
+        yield from pickle.loads(blob)
 
 
 def executor_main(executor_id: int, app_id: str, task_queue, result_queue,
@@ -50,16 +62,17 @@ def executor_main(executor_id: int, app_id: str, task_queue, result_queue,
             continue
         if item is None:
             break
-        job_id, task_id, pindex, data_blob, chain_blob = item
-        # one span a task; its first child is where this substrate
-        # deserialises the partition (real Spark does it under the task's
-        # iterator: TFSparkNode's `feeder.first_row`)
+        job_id, task_id, pindex, blobs, chain_blob = item
+        # one span a task; its first child loads the function chain and
+        # opens the row stream.  The rows themselves are unpickled under
+        # the task's iterator, where Spark has that cost too (TFSparkNode's
+        # `feeder.first_row` sees the first batch's)
         with obs.span("executor.task", job=job_id, partition=pindex):
             try:
-                with obs.span("executor.task_load", bytes=len(data_blob)):
-                    data = cloudpickle.loads(data_blob)
+                with obs.span("executor.task_load",
+                              bytes=sum(map(len, blobs)), chunks=len(blobs)):
                     chain, action = cloudpickle.loads(chain_blob)
-                it = iter(data)
+                    it = _rows(blobs)
                 for f in chain:
                     it = f(pindex, it)
                 result = action(pindex, it)

@@ -1,15 +1,58 @@
 """RDD subset for the local Spark substrate.
 
-Lazy per-partition transform chains over driver-resident partition payloads;
-actions ship ``(payload, chain, action)`` to executor processes via
-``LocalSparkContext.run_job``.  Covers the RDD surface the orchestration
-layer and its tests touch (``SURVEY.md §3``): ``mapPartitions`` /
-``foreachPartition`` / ``map`` / ``collect`` are the load-bearing ones.
+Lazy per-partition transform chains over driver-resident partitions; actions
+ship ``(row-batch pickles, chain, action)`` to executor processes via
+``LocalSparkContext.run_job``.  A stored :class:`Partition` is serialised
+once, the first time a job sends it, in batches of :data:`BATCH_ROWS` rows;
+every RDD derived from it without materialising (``map``,
+``mapPartitions``, ``union`` of chainless RDDs) shares the same
+``Partition`` objects, so later jobs send bytes and pickle nothing.  Covers
+the RDD surface the orchestration layer and its tests touch
+(``SURVEY.md §3``): ``mapPartitions`` / ``foreachPartition`` / ``map`` /
+``collect`` are the load-bearing ones.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, Iterable, Iterator
+
+from tensorflowonspark_tpu import obs
+
+#: rows in one serialised batch, at most.  PySpark's ``parallelize``
+#: serialises a collection once, in batches of ``max(1, min(len(c) //
+#: numSlices, self._batchSize or 1024))`` rows (``pyspark/context.py``), and
+#: its Python worker deserialises batch by batch under the task's iterator.
+BATCH_ROWS = 1024
+
+
+class Partition:
+    """One stored partition: its rows and, once a job has sent it, the
+    row-batch pickles executors read them from.
+
+    The rows are never mutated (``collect`` and ``take`` hand out copies),
+    so the pickles cannot go stale; whatever computes new rows (``cache()``
+    resolving a chain, ``union`` over chains, ``repartition``) makes new
+    ``Partition`` objects.  The price is one serialised copy of each sent
+    partition on the driver for as long as an RDD refers to it."""
+
+    __slots__ = ("rows", "_blobs")
+
+    def __init__(self, rows: list):
+        self.rows = rows
+        self._blobs: list[bytes] | None = None
+
+    def blobs(self) -> list[bytes]:
+        """The partition as ``run_job`` sends it: one pickle a batch of at
+        most :data:`BATCH_ROWS` rows, made on the first call and kept."""
+        if self._blobs is None:
+            import cloudpickle
+
+            self._blobs = [
+                cloudpickle.dumps(self.rows[i:i + BATCH_ROWS])
+                for i in range(0, len(self.rows), BATCH_ROWS)]
+        else:
+            obs.counter("spark_partition_blobs_reused_total").inc()
+        return self._blobs
 
 
 def _fresh_copy(rows: list) -> list:
@@ -53,7 +96,8 @@ class _MapPartitions:
 
 
 class RDD:
-    def __init__(self, sc, partitions: list[Any], chain: list | None = None):
+    def __init__(self, sc, partitions: list[Partition],
+                 chain: list | None = None):
         self._sc = sc
         self._partitions = partitions
         self._chain = chain or []
@@ -85,7 +129,7 @@ class RDD:
             # materialize both sides so the union has a single empty chain
             left = self._sc.run_job(self._partitions, self._chain, _collect_action)
             right = other._sc.run_job(other._partitions, other._chain, _collect_action)
-            return RDD(self._sc, left + right)
+            return RDD(self._sc, [Partition(rows) for rows in left + right])
         return RDD(self._sc, self._partitions + other._partitions)
 
     def repartition(self, numPartitions: int) -> "RDD":
@@ -107,9 +151,9 @@ class RDD:
         """(partitions, chain), collapsing the chain once if cache() was
         requested — later actions reuse the computed partitions."""
         if self._cached and self._chain:
-            self._partitions = self._sc.run_job(
-                self._partitions, self._chain, _collect_action
-            )
+            self._partitions = [
+                Partition(rows) for rows in self._sc.run_job(
+                    self._partitions, self._chain, _collect_action)]
             self._chain = []
         return self._partitions, self._chain
 
@@ -131,14 +175,14 @@ class RDD:
             # round-tripping it through worker IPC for an identity job.
             # Copies keep pyspark semantics (caller mutations must not
             # corrupt the stored partitions).
-            return _fresh_copy([x for part in partitions for x in part])
+            return _fresh_copy([x for part in partitions for x in part.rows])
         parts = self._sc.run_job(partitions, chain, _collect_action)
         return [x for part in parts for x in part]
 
     def count(self) -> int:
         partitions, chain = self._resolved()
         if not chain:
-            return sum(len(part) for part in partitions)
+            return sum(len(part.rows) for part in partitions)
         return sum(self._sc.run_job(partitions, chain, _count_action))
 
     def take(self, n: int) -> list:
@@ -150,7 +194,7 @@ class RDD:
             if len(out) >= n:
                 break
             if not chain:
-                out.extend(_fresh_copy(list(part)))
+                out.extend(_fresh_copy(part.rows))
                 continue
             res = self._sc.run_job([part], chain, _collect_action,
                                    base_index=i)
