@@ -40,10 +40,10 @@ counts what it saves:
   compiling thread, so ``serving.note_compile``'s settle logic can tell
   *this* forward's disk hit from a concurrent one.
 
-``TFOS_COMPILE_CACHE=0`` opts a process out (the test suite does);
-``TFOS_COMPILE_CACHE_MIN_COMPILE_S`` (default 0 — serving forwards are
-small and the whole point is the fleet's long tail of them) bounds which
-compiles are worth writing.
+``TFOS_COMPILE_CACHE=0`` opts a process out (the test suite does).  Every
+compile is worth writing (serving forwards are small and the whole point
+is the fleet's long tail of them) unless jax's own
+``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise.
 """
 
 from __future__ import annotations
@@ -122,14 +122,6 @@ def active() -> bool:
     return _STATE["namespace"] is not None
 
 
-def min_compile_seconds() -> float:
-    try:
-        return float(os.environ.get("TFOS_COMPILE_CACHE_MIN_COMPILE_S",
-                                    "0"))
-    except ValueError:
-        return 0.0
-
-
 def topology_key() -> str:
     """The namespace of a fleet root an entry set is valid for.
 
@@ -203,8 +195,7 @@ def _configure() -> None:
     # amortizes even tiny compiles, so cache everything unless the
     # operator said otherwise via jax's own env knobs
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_seconds())
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in os.environ:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _STATE["namespace"] = namespace

@@ -29,14 +29,14 @@ each engine step packs chunks from SEVERAL admitted requests into one
 jitted prefill call of fixed ``(max_seqs, chunk_len)`` geometry,
 interleaved with decode steps — a long prompt advances at most one
 chunk per step, so it cannot monopolize the loop and every co-tenant's
-TTFT is bounded by the chunk budget (``TFOS_PREFILL_CHUNK``), not the
-longest prompt in flight.  ``TFOS_PREFILL_CHUNK=0`` selects the legacy
+TTFT is bounded by the chunk budget (``prefill_chunk``), not the
+longest prompt in flight.  ``prefill_chunk=0`` selects the legacy
 one-prompt-per-call prefill (pads to ``shapes.prefill_buckets``) — kept
 as the bench baseline.
 
 **Copy-on-write prefix sharing.**  A bounded registry
-(:class:`_PrefixRegistry`, ``TFOS_PREFIX_SHARE`` /
-``TFOS_PREFIX_REGISTRY_MAX``) keyed by token-hash maps completed
+(:class:`_PrefixRegistry`, ``share_prefixes`` /
+``prefix_registry_max``) keyed by token-hash maps completed
 prompts' page-aligned prefixes to REFCOUNTED read-only physical pages.
 Admission looks up the longest common token prefix and maps those pages
 into the new slot's table for free — KV at position t depends only on
@@ -47,10 +47,10 @@ mid-page maps the boundary page too; the first divergent write triggers
 a page COPY (``tinylm.copy_page_fn``, one fixed jit signature) into a
 private page before the write lands — shared pages are never mutated.
 
-**Speculative multi-token decoding.**  With ``TFOS_SPEC_TOKENS >= 1``
-(or ``spec_tokens=``) the single-token step is replaced by a
+**Speculative multi-token decoding.**  With ``spec_tokens >= 1`` the
+single-token step is replaced by a
 propose/verify loop: a cheap DRAFTER proposes up to ``k`` tokens per
-sequence (``TFOS_SPEC_DRAFTER``: ``ngram`` — host-side prompt-lookup,
+sequence (``spec_drafter``: ``ngram`` — host-side prompt-lookup,
 no second model, the default; ``model`` — a smaller ``tinylm`` config
 sharing the vocab, shadow-caching into its own pools through the SAME
 page tables; ``none`` — no drafts, the sampling-capable single-token
@@ -167,11 +167,11 @@ SLO_WINDOW_S = 60.0
 #: per-token spans listed on a retained trace before truncation
 _MAX_TOKEN_SPANS = 32
 #: default chunked-prefill budget, in PAGES per chunk row (the
-#: ``TFOS_PREFILL_CHUNK`` env knob overrides in tokens; 0 = legacy
+#: engine's ``prefill_chunk`` overrides in tokens; 0 = legacy
 #: per-prompt prefill) — two pages bounds a long prompt's hold on the
 #: step loop without paying a chunk call per page
 DEFAULT_PREFILL_CHUNK_PAGES = 2
-#: default prefix-registry entry bound (``TFOS_PREFIX_REGISTRY_MAX``);
+#: default prefix-registry entry bound (``prefix_registry_max``);
 #: each entry pins its prefix pages until evicted, so the bound is a
 #: KV-memory bound too
 DEFAULT_PREFIX_REGISTRY_MAX = 32
@@ -188,29 +188,6 @@ SPEC_WINDOW_MIN_PROPOSED = 16
 
 _DONE = object()
 _ENGINE_SEQ = itertools.count(1)
-
-
-def _env_int(name: str, default: int) -> int:
-    import os
-
-    raw = os.environ.get(name, "").strip()
-    if not raw:
-        return default
-    try:
-        return int(raw)
-    except ValueError:
-        logger.warning("ignoring non-integer %s=%r", name, raw)
-        return default
-
-
-def prefix_share_enabled() -> bool:
-    """COW prefix sharing on/off (``TFOS_PREFIX_SHARE``, default ON).
-    Re-read per engine construction, not cached at import — same
-    late-binding discipline as the other ``TFOS_*`` toggles."""
-    import os
-
-    return os.environ.get("TFOS_PREFIX_SHARE", "1").strip().lower() \
-        not in ("0", "false", "no", "off")
 
 
 class SamplingParams:
@@ -998,8 +975,6 @@ class DecodeEngine:
                  spec_drafter: str | None = None,
                  draft_config=None, draft_params=None,
                  seed: int = 0):
-        import os
-
         import jax
 
         from tensorflowonspark_tpu import obs, shapes, util
@@ -1039,12 +1014,10 @@ class DecodeEngine:
 
         # chunked-prefill geometry: the chunk budget (tokens a prompt
         # may advance per engine step) comes from the argument, else
-        # the TFOS_PREFILL_CHUNK env, else a pages-based default;
-        # 0 selects the legacy one-prompt-per-call prefill
+        # a pages-based default; 0 selects the legacy
+        # one-prompt-per-call prefill
         if prefill_chunk is None:
-            prefill_chunk = _env_int(
-                "TFOS_PREFILL_CHUNK",
-                DEFAULT_PREFILL_CHUNK_PAGES * self.page_size)
+            prefill_chunk = DEFAULT_PREFILL_CHUNK_PAGES * self.page_size
         self.chunked_prefill = int(prefill_chunk) != 0
         self.prefill_chunks = (
             shapes.prefill_chunks(self.max_prompt_len, self.page_size,
@@ -1054,23 +1027,20 @@ class DecodeEngine:
         # writes every position from 0, which would mutate shared
         # pages), so it is forced off in legacy mode
         if share_prefixes is None:
-            share_prefixes = prefix_share_enabled()
+            share_prefixes = True
         self.share_prefixes = bool(share_prefixes) and self.chunked_prefill
         if prefix_registry_max is None:
-            prefix_registry_max = _env_int("TFOS_PREFIX_REGISTRY_MAX",
-                                           DEFAULT_PREFIX_REGISTRY_MAX)
+            prefix_registry_max = DEFAULT_PREFIX_REGISTRY_MAX
         self.prefix_registry_max = int(prefix_registry_max)
 
         # speculative decoding geometry: the configured draft length
-        # (TFOS_SPEC_TOKENS; 0 = legacy single-token step) and the
-        # drafter kind (TFOS_SPEC_DRAFTER: ngram | model | none).
+        # (``spec_tokens``; 0 or None = legacy single-token step) and
+        # the drafter kind (``spec_drafter``: ngram | model | none).
         # Speculation rides the chunk scheduler's phase discipline
         # (prefill-phase slots carry zero table rows so the verify
         # step's writes for them land in trash), so it requires
         # chunked prefill — the default mode
-        if spec_tokens is None:
-            spec_tokens = _env_int("TFOS_SPEC_TOKENS", 0)
-        self.spec_tokens = max(0, int(spec_tokens))
+        self.spec_tokens = max(0, int(spec_tokens or 0))
         if self.spec_tokens and not self.chunked_prefill:
             raise ValueError(
                 "speculative decoding requires chunked prefill "
@@ -1078,8 +1048,7 @@ class DecodeEngine:
         self.spec_ladder = (shapes.spec_ladder(self.spec_tokens)
                             if self.spec_tokens else ())
         if spec_drafter is None:
-            spec_drafter = os.environ.get(
-                "TFOS_SPEC_DRAFTER", "ngram").strip().lower() or "ngram"
+            spec_drafter = "ngram"
         self.spec_drafter = (str(spec_drafter)
                              if self.spec_tokens else "off")
 

@@ -84,14 +84,14 @@ def load(export_dir: str, model_name: str = "") -> int:
         output_order = [o["name"] for o in sig["outputs"]]
     else:
         from tensorflowonspark_tpu import models as model_zoo
-        from tensorflowonspark_tpu.pipeline import _is_tiny
 
         if not model_name:
             raise ValueError(
                 f"export at {export_dir} is weights-only (no saved_forward/) "
                 "— a model_name is required to rebuild the forward")
         lib = model_zoo.get_model(model_name)
-        config = lib.Config.tiny() if _is_tiny(params, lib) else lib.Config()
+        config = (lib.Config.tiny() if model_zoo._is_tiny(params, lib)
+                  else lib.Config())
         module = lib.make_model(config)
         forward = lib.make_forward_fn(module, config)
         if getattr(forward, "stateful", False):

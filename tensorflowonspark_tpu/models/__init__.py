@@ -41,3 +41,42 @@ def get_model(name: str):
 
 def available() -> list[str]:
     return sorted(_REGISTRY)
+
+
+def _model_inputs(batch: dict) -> tuple:
+    """Positional model inputs from an example batch (labels stripped —
+    the shape-policy module's one label-key convention)."""
+    from tensorflowonspark_tpu import shapes
+
+    return tuple(v for k, v in batch.items() if k not in shapes.LABEL_KEYS)
+
+
+def _is_tiny(params, lib) -> bool:
+    """Heuristic: does the restored pytree match the zoo's tiny config?
+
+    Compares leaf count+shapes against ``Config.tiny()``'s abstract init so
+    transform works for both test-sized and full-sized exports without the
+    caller having to pass a config through.
+    """
+    import jax
+
+    from tensorflowonspark_tpu.parallel.train import unbox
+
+    try:
+        tiny = lib.Config.tiny()
+        module = lib.make_model(tiny)
+        batch = lib.example_batch(tiny, batch_size=1)
+        shapes = jax.eval_shape(
+            lambda: module.init(jax.random.PRNGKey(0), *_model_inputs(batch))
+        )
+        tiny_leaves = [
+            tuple(l.shape)
+            for l in jax.tree_util.tree_leaves(unbox(shapes)["params"])
+        ]
+        real_leaves = [
+            tuple(getattr(l, "shape", ()))
+            for l in jax.tree_util.tree_leaves(params)
+        ]
+        return sorted(tiny_leaves) == sorted(real_leaves)
+    except Exception:
+        return False

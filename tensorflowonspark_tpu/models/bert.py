@@ -89,13 +89,35 @@ def make_model(config: Config, mesh=None):
         sharded_attn = ra.make_sharded_attention(mesh, causal=False,
                                                  impl=config.sp_impl)
 
+    class Dense(nn.Module):
+        """``nn.DenseGeneral`` over the last axis, kernel boxed with ``axes``.
+
+        flax's own unboxes what its initializer returns under whatever mesh
+        is current, with the names as a sharding constraint; inside the
+        bucketed step's ``shard_map`` region that mesh is all ``Manual`` and
+        logical names are none of its axes.  ``self.param`` leaves the box
+        to ``parallel.mesh.param_sharding_from_metadata``."""
+        features: tuple
+        axes: tuple
+
+        @nn.compact
+        def __call__(self, x):
+            kernel = self.param(
+                "kernel",
+                nn.with_partitioning(
+                    nn.initializers.normal(stddev=0.02), self.axes),
+                (x.shape[-1],) + self.features, jnp.float32)
+            bias = self.param("bias", nn.initializers.zeros_init(),
+                              self.features, jnp.float32)
+            x, kernel, bias = nn.dtypes.promote_dtype(
+                x, kernel, bias, dtype=dtype)
+            return jax.lax.dot_general(
+                x, kernel, (((x.ndim - 1,), (0,)), ((), ()))) + bias
+
     def dense(features, axes, name=None):
-        return nn.DenseGeneral(
-            features, dtype=dtype, name=name,
-            kernel_init=nn.with_partitioning(
-                nn.initializers.normal(stddev=0.02), axes
-            ),
-        )
+        if isinstance(features, int):
+            features = (features,)
+        return Dense(features, axes, name=name)
 
     class Attention(nn.Module):
         @nn.compact
@@ -123,12 +145,7 @@ def make_model(config: Config, mesh=None):
                                preferred_element_type=jnp.float32
                                ).astype(dtype)
             o = o.reshape(b, s, h * d)
-            return nn.DenseGeneral(
-                config.hidden, axis=-1, dtype=dtype, name="out",
-                kernel_init=nn.with_partitioning(
-                    nn.initializers.normal(stddev=0.02), ("heads", "embed")
-                ),
-            )(o)
+            return dense(config.hidden, ("heads", "embed"), name="out")(o)
 
     class MoEMLP(nn.Module):
         """Expert-parallel FFN (Switch top-1) — see ``parallel/moe.py``.

@@ -46,8 +46,7 @@ And the pipeline flight recorder (ISSUE 6 tentpole):
   queue-backpressured); rendered live on ``/pipeline``, judged by
   ``TFCluster.check_anomalies()`` (persistent feed starvation is a
   finding), and stamped by ``bench.py`` into every artifact as a
-  wall-time-reconciled stage breakdown.  ``TFOS_FLIGHT=0`` disables,
-  ``TFOS_FLIGHT_SAMPLE=N`` thins the histogram traffic.
+  wall-time-reconciled stage breakdown.  ``TFOS_FLIGHT=0`` disables.
 
 The flight recorder also attributes the continuous-batching online
 serving tier (plane ``"online"``:

@@ -77,7 +77,7 @@ from tensorflowonspark_tpu.obs import registry as _registry
 
 logger = logging.getLogger(__name__)
 
-#: per-replica snapshot-ring depth (``TFOS_FLEET_RING`` overrides):
+#: per-replica snapshot-ring depth:
 #: retention ≈ depth × scrape cadence (DEPLOY "Fleet observability
 #: sizing")
 DEFAULT_RING_DEPTH = 64
@@ -299,21 +299,6 @@ class _ReplicaRing:
         self.failures = 0
 
 
-def _ring_depth_default() -> int:
-    raw = os.environ.get("TFOS_FLEET_RING", "").strip()
-    if raw:
-        try:
-            v = int(raw)
-            if v >= 2:
-                return v
-            logger.warning("TFOS_FLEET_RING=%r below the minimum of 2; "
-                           "using default %d", raw, DEFAULT_RING_DEPTH)
-        except ValueError:
-            logger.warning("TFOS_FLEET_RING=%r unparseable; using default "
-                           "%d", raw, DEFAULT_RING_DEPTH)
-    return DEFAULT_RING_DEPTH
-
-
 class FleetCollector:
     """Scrape-side federation: per-replica snapshot rings + windows.
 
@@ -328,7 +313,7 @@ class FleetCollector:
                  timeout_s: float = 1.5, retries: int = 1,
                  prefix: str = "tfos_"):
         self.ring_depth = (int(ring_depth) if ring_depth is not None
-                           else _ring_depth_default())
+                           else DEFAULT_RING_DEPTH)
         self.timeout_s = float(timeout_s)
         self.retries = max(0, int(retries))
         self.prefix = prefix

@@ -16,16 +16,11 @@ enough to leave on:
     ``ingest`` (shm read + chunk intake), ``collate`` (column
     concatenation + mapping), ``stage`` (an in-feed ``device_put``),
     ``shard`` (the trainer's own shard call), ``compute`` (the jitted
-    step dispatch), ``allreduce`` (the bucketed gradient exchange —
-    modelled against the roofline's delivered ICI bandwidth; always
-    recorded ``_bg``: a model is an upper bound on exposed comm and
-    must not name the bottleneck — the measured ``comm_bound`` verdict
-    comes from bench's step-collectives A/B, which times a no-reduce
-    twin).  ``TFNode.DataFeed`` adds the wait/ingest/
-    collate/stage parts, ``trainer.Trainer`` adds shard/compute/
-    allreduce and commits one record per step — every stage name is
-    recorded by exactly one call site, so each histogram stays one
-    observation per batch.
+    step dispatch; the step's collectives run inside it).
+    ``TFNode.DataFeed`` adds the wait/ingest/collate/stage parts,
+    ``trainer.Trainer`` adds shard/compute and commits one record per
+    step — every stage name is recorded by exactly one call site, so
+    each histogram stays one observation per batch.
   - ``"serve"`` — the bucketed serving plane in ``pipeline._RunModel``:
     ``ingest``/``pad``/``stage`` on the prefetch pump (overlapped),
     ``wait``/``compute``/``emit`` on the consumer; ``emit`` includes the
@@ -62,10 +57,8 @@ enough to leave on:
   ``tools/bench_gate.py`` fails any breakdown whose additive stage sum
   does not reconcile with measured wall time.
 
-Env knobs: ``TFOS_FLIGHT=0`` disables recording entirely (every ``add``
-returns after one env check); ``TFOS_FLIGHT_SAMPLE=N`` records the stage
-*histograms* for every Nth committed batch only — verdict counting and the
-additive totals stay exact, so bench breakdowns are unaffected.
+Env knob: ``TFOS_FLIGHT=0`` disables recording entirely (every ``add``
+returns after one env check).
 """
 
 from __future__ import annotations
@@ -135,13 +128,9 @@ def enabled() -> bool:
         "0", "false", "no")
 
 
-def sample_every() -> int:
-    """``TFOS_FLIGHT_SAMPLE=N``: stage histograms recorded every Nth batch
-    (default 1 = every batch).  Totals and verdicts stay exact."""
-    try:
-        return max(1, int(os.environ.get("TFOS_FLIGHT_SAMPLE", "1")))
-    except ValueError:
-        return 1
+#: stage histograms are recorded every Nth committed batch (1 = every
+#: batch); totals and verdicts stay exact whatever it is
+SAMPLE_EVERY = 1
 
 
 def classify(stages: Mapping[str, float],
@@ -261,8 +250,7 @@ class FlightRecorder:
             self._verdicts[verdict] += 1
             self._batches += 1
             self._window.append((stages, verdict))
-            self._sample_histograms = (self._batches
-                                       % sample_every() == 0)
+            self._sample_histograms = self._batches % SAMPLE_EVERY == 0
         self._counter(
             "batches_total",
             f"batches attributed on the {self.plane} plane").inc()

@@ -100,7 +100,7 @@ EVENT_TYPES = frozenset({
     "blackbox.dump",          # a black-box bundle was written
 })
 
-#: per-process ring depth (``TFOS_JOURNAL_RING`` overrides)
+#: per-process ring depth
 DEFAULT_RING = 1024
 #: seconds between spool appends; a SIGKILL loses at most this much tail
 DEFAULT_FLUSH_INTERVAL_S = 1.0
@@ -122,21 +122,6 @@ def enabled() -> bool:
     on = raw.strip().lower() not in ("0", "false", "no", "off")
     _ENABLED_CACHE = (raw, on)
     return on
-
-
-def _ring_default() -> int:
-    raw = os.environ.get("TFOS_JOURNAL_RING", "").strip()
-    if raw:
-        try:
-            v = int(raw)
-            if v >= 16:
-                return v
-            logger.warning("TFOS_JOURNAL_RING=%r below the minimum of "
-                           "16; using default %d", raw, DEFAULT_RING)
-        except ValueError:
-            logger.warning("TFOS_JOURNAL_RING=%r unparseable; using "
-                           "default %d", raw, DEFAULT_RING)
-    return DEFAULT_RING
 
 
 def order_key(ev: Mapping[str, Any]) -> tuple:
@@ -209,7 +194,7 @@ class Journal:
                  spool_dir: str | None = None,
                  flush_interval_s: float = DEFAULT_FLUSH_INTERVAL_S):
         self.node = str(node)
-        cap = int(capacity) if capacity is not None else _ring_default()
+        cap = int(capacity) if capacity is not None else DEFAULT_RING
         self._ring: deque = deque(maxlen=cap)
         #: appended-but-not-yet-spooled events; bounded like the ring so
         #: a wedged filesystem cannot grow memory without limit (overflow

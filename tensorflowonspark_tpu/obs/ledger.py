@@ -29,8 +29,8 @@ price its decisions on.  Two ledgers, one module:
 
   Every meter is a labeled Prometheus family with **cached instrument
   handles** (the ``_Tenant`` rule: the hot path never pays a registry
-  lookup) and bounded cardinality (the registry's
-  ``TFOS_METRIC_SERIES_MAX`` overflow machinery); an evicted tenant's
+  lookup) and bounded cardinality (the registry's per-family series
+  cap and its overflow machinery); an evicted tenant's
   series are removed with it.  The unlabeled
   ``ledger_engine_seconds_total{plane=}`` family records the same walls
   un-apportioned — the conservation denominator: Σ per-tenant

@@ -20,9 +20,9 @@ Two extensions ride the same model (ISSUE 10):
   (one ``# TYPE`` line, standard ``name{tenant="a"}`` exposition).  A
   series is stored under its full series key (``name{k="v"}``, sorted
   labels), so snapshots and cross-node merges need no schema change.
-  Cardinality is bounded per family (``TFOS_METRIC_SERIES_MAX``, default
-  128): past the bound new label sets collapse into one ``_overflow``
-  series (loud, once) instead of growing without limit, and
+  Cardinality is bounded per family (128 series): past the bound new
+  label sets collapse into one ``_overflow`` series (loud, once) instead
+  of growing without limit, and
   :meth:`Registry.remove` evicts a series with its owner (a removed
   tenant takes its series with it).
 - **exemplars**: ``Histogram.observe(v, exemplar={"trace_id": ...})``
@@ -163,19 +163,11 @@ class Histogram:
         return out
 
 
-#: per-family labeled-series cap (``TFOS_METRIC_SERIES_MAX`` overrides):
+#: per-family labeled-series cap:
 #: past it, new label sets collapse into one ``_overflow`` series — a
 #: tenant-per-series registry must not become an unbounded memory leak
 #: when tenant names are attacker- or workload-controlled
 _DEFAULT_SERIES_MAX = 128
-
-
-def _series_max() -> int:
-    try:
-        return max(1, int(os.environ.get("TFOS_METRIC_SERIES_MAX",
-                                         _DEFAULT_SERIES_MAX)))
-    except ValueError:
-        return _DEFAULT_SERIES_MAX
 
 
 _LABEL_NAME_OK_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -268,15 +260,14 @@ class Registry:
                         f"{key!r} already registered as "
                         f"{type(inst).__name__}, not {cls.__name__}")
                 return inst
-            if self._family_series.get(family, 0) >= _series_max():
+            if self._family_series.get(family, 0) >= _DEFAULT_SERIES_MAX:
                 if family not in self._family_warned:
                     self._family_warned.add(family)
                     logger.warning(
                         "metric family %r hit its %d-series label-"
                         "cardinality bound; further label sets collapse "
-                        "into an '_overflow' series (raise "
-                        "TFOS_METRIC_SERIES_MAX or remove() series with "
-                        "their owners)", family, _series_max())
+                        "into an '_overflow' series (remove() series "
+                        "with their owners)", family, _DEFAULT_SERIES_MAX)
                 key = series_key(family,
                                  {k: "_overflow" for k in labels})
                 inst = self._instruments.get(key)

@@ -18,9 +18,7 @@ run, and publishes them beside the throughput number they contextualise —
   single device (there is no interconnect to measure);
 - **cross-slice DCN bandwidth** (:func:`measure_dcn_bandwidth`): the same
   collective over one device per slice, so the ring crosses only the
-  data-centre network — the figure the two-tier bucket sizing
-  (``collectives.dcn_bucket_bytes_default``) consumes; ``None`` + reason
-  on a single-slice topology;
+  data-centre network; ``None`` + reason on a single-slice topology;
 - :func:`probe` runs all three, never raises, and mirrors the results into
   the process obs registry (``roofline_mem_bw_gbps`` /
   ``roofline_ici_bw_gbps`` / ``roofline_dcn_bw_gbps`` gauges) so they ride
@@ -250,10 +248,8 @@ def measure_dcn_bandwidth(size_bytes_per_device: int | None = None,
     :func:`measure_ici_bandwidth` collective over ONE device per slice —
     a 1-D mesh whose only axis crosses the data-centre network, so the
     ring traverses no ICI link and the measured figure is the DCN tier's
-    own delivered bandwidth (the number
-    ``collectives.dcn_bucket_bytes_default`` sizes cross-slice buckets
-    against).  Returns ``{"gbps": None, "reason": ...}`` on a
-    single-slice (or single-device) topology — there is no DCN to
+    own delivered bandwidth.  Returns ``{"gbps": None, "reason": ...}``
+    on a single-slice (or single-device) topology — there is no DCN to
     measure, and stamping a number would launder an ICI figure into a
     DCN field.
     """

@@ -56,16 +56,14 @@ kept only for SLO breaches / sheds / errors plus a small uniform sample,
 everything else dropped at commit.
 
 Env knobs: ``TFOS_TRACE=0`` disables recording entirely (the record path
-then costs one attribute check); ``TFOS_TRACE_CAPACITY`` sizes the ring
-buffer (default 16384 events per process: a 30 s window of 700 steps at
-8 spans a step fits twice over).  Request tracing has its own
-knobs: ``TFOS_TRACE_REQUESTS=0`` disables per-request span trees,
-``TFOS_TRACE_ARM`` sets the fraction of (uniform-population) requests
+then costs one attribute check); the ring buffer holds 16384 events per
+process (a 30 s window of 700 steps at 8 spans a step fits twice over).
+Request tracing has its own knobs: ``TFOS_TRACE_REQUESTS=0`` disables
+per-request span trees, ``TFOS_TRACE_ARM`` sets the fraction of (uniform-population) requests
 armed for capture (default 0.05 — explicit inbound contexts always arm,
 sheds and invalid requests are always captured; see :func:`arm_rate`),
 ``TFOS_TRACE_SAMPLE`` sets the uniform keep fraction for unremarkable
-armed requests (default 0.01), ``TFOS_TRACE_REQUESTS_CAPACITY`` bounds
-the retained-trace ring (default 256 traces).
+armed requests (default 0.01); the retained-trace ring holds 256 traces.
 """
 
 from __future__ import annotations
@@ -111,13 +109,6 @@ def _trace_annotation():
 
 def _enabled_by_env() -> bool:
     return os.environ.get("TFOS_TRACE", "1") not in ("0", "", "false", "no")
-
-
-def _capacity_from_env() -> int:
-    try:
-        return int(os.environ.get("TFOS_TRACE_CAPACITY", _DEFAULT_CAPACITY))
-    except ValueError:
-        return _DEFAULT_CAPACITY
 
 
 # ---------------------------------------------------------------------------
@@ -271,7 +262,7 @@ class Tracer:
     def __init__(self, node: str = "driver", capacity: int | None = None):
         self.node = node
         self.enabled = _enabled_by_env()
-        self.capacity = capacity or _capacity_from_env()
+        self.capacity = capacity or _DEFAULT_CAPACITY
         self.dropped = 0
         self.flush_interval_s = 2.0
         self._events: collections.deque = collections.deque(
@@ -930,14 +921,8 @@ class TraceStore:
     """
 
     def __init__(self, capacity: int | None = None):
-        if capacity is None:
-            try:
-                capacity = int(os.environ.get(
-                    "TFOS_TRACE_REQUESTS_CAPACITY",
-                    _DEFAULT_STORE_CAPACITY))
-            except ValueError:
-                capacity = _DEFAULT_STORE_CAPACITY
-        self.capacity = max(1, capacity)
+        self.capacity = max(
+            1, _DEFAULT_STORE_CAPACITY if capacity is None else capacity)
         self._lock = threading.Lock()
         self._retained: collections.deque = collections.deque(
             maxlen=self.capacity)

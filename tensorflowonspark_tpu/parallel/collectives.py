@@ -50,8 +50,8 @@ On **multi-slice meshes** the exchange is staged per interconnect tier
 when the topology allows it: an in-slice reduce-scatter over the ICI
 axes, then a cross-slice stage over the DCN axis (and the all-gathers
 inverted), with the bucket bound raised to the DCN tier's own sizing
-(``TFOS_DCN_BUCKET_MB`` / the measured ``roofline_dcn_bw_gbps``) since
-every bucket crosses both tiers and the slow tier dominates.  A named
+(``TFOS_DCN_BUCKET_MB``, else four times the ICI bound) since every
+bucket crosses both tiers and the slow tier dominates.  A named
 mesh axis cannot be subdivided, so true two-tier staging requires the
 DCN axis to be *purely* cross-slice (``MeshConfig.dcn_axis()`` size ==
 ``slices``); anything else falls back to single-tier with the reason
@@ -134,11 +134,8 @@ DEFAULT_BUCKET_MB = 4.0
 #: DCN-tier sizing constants: per-collective launch+latency over the
 #: data-centre network is ~ms, not ~10 µs, so cross-slice buckets must be
 #: far bigger before wire time dominates.  ``dcn_bucket_bytes_default``
-#: sizes them as ``_DCN_LAUNCH_DOMINANCE × DCN_LAUNCH_S × bw / 2`` against
-#: the *measured* ``roofline_dcn_bw_gbps`` when a probe ran, else
-#: ``DEFAULT_DCN_BUCKET_RATIO ×`` the ICI bound (DEPLOY.md arithmetic).
-DCN_LAUNCH_S = 1e-3
-_DCN_LAUNCH_DOMINANCE = 10.0
+#: sizes them as ``DEFAULT_DCN_BUCKET_RATIO ×`` the ICI bound, capped
+#: (DEPLOY.md arithmetic).
 DEFAULT_DCN_BUCKET_RATIO = 4.0
 _DCN_BUCKET_CAP = 64 * 1024 * 1024
 
@@ -173,30 +170,16 @@ def bucket_bytes_default() -> int:
 
 
 def dcn_bucket_bytes_default() -> int:
-    """DCN-tier bucket size in bytes, chosen against that tier's own
-    delivered roofline: ``TFOS_DCN_BUCKET_MB`` override; else sized so
-    wire time dominates the ~ms cross-slice launch cost at the
-    *measured* ``roofline_dcn_bw_gbps`` (peeked, never minted — same
-    discipline as the trainer's flight attribution); else
-    :data:`DEFAULT_DCN_BUCKET_RATIO` × the ICI bound."""
+    """DCN-tier bucket size in bytes: ``TFOS_DCN_BUCKET_MB`` override,
+    else :data:`DEFAULT_DCN_BUCKET_RATIO` × the ICI bound, capped."""
     env = os.environ.get("TFOS_DCN_BUCKET_MB", "")
     try:
         if env:
             return max(1, int(float(env) * 1024 * 1024))
     except ValueError:
         pass
-    floor = bucket_bytes_default()
-    try:
-        from tensorflowonspark_tpu import obs
-
-        gauge = obs.get_registry().peek("roofline_dcn_bw_gbps")
-        bw = gauge.value if gauge is not None else None
-    except Exception:
-        bw = None
-    if bw and bw > 0:
-        sized = int(_DCN_LAUNCH_DOMINANCE * DCN_LAUNCH_S * bw * 1e9 / 2.0)
-        return max(floor, min(sized, _DCN_BUCKET_CAP))
-    return min(int(floor * DEFAULT_DCN_BUCKET_RATIO), _DCN_BUCKET_CAP)
+    return min(int(bucket_bytes_default() * DEFAULT_DCN_BUCKET_RATIO),
+               _DCN_BUCKET_CAP)
 
 
 def mesh_eligibility(mesh, collection_shardings=None) -> tuple[bool, str]:

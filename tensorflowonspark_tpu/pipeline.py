@@ -29,6 +29,8 @@ import argparse
 import logging
 from typing import Any, Callable, Sequence
 
+from tensorflowonspark_tpu.saved_model import get_meta_graph_def  # noqa: F401
+
 logger = logging.getLogger(__name__)
 
 
@@ -617,7 +619,8 @@ class _RunModel:
             from tensorflowonspark_tpu import models as model_zoo
 
             lib = model_zoo.get_model(self.model_name)
-            config = lib.Config.tiny() if _is_tiny(params, lib) else lib.Config()
+            config = (lib.Config.tiny() if model_zoo._is_tiny(params, lib)
+                      else lib.Config())
             if collections and "norm" in {
                 f.name for f in dataclasses.fields(config)
             }:
@@ -859,38 +862,6 @@ def _pyval(x):
     return x
 
 
-def _is_tiny(params, lib) -> bool:
-    """Heuristic: does the restored pytree match the zoo's tiny config?
-
-    Compares leaf count+shapes against ``Config.tiny()``'s abstract init so
-    transform works for both test-sized and full-sized exports without the
-    caller having to pass a config through.
-    """
-    import jax
-
-    try:
-        tiny = lib.Config.tiny()
-        module = lib.make_model(tiny)
-        batch = lib.example_batch(tiny, batch_size=1)
-        from tensorflowonspark_tpu.trainer import _model_inputs
-        from tensorflowonspark_tpu.parallel.train import unbox
-
-        shapes = jax.eval_shape(
-            lambda: module.init(jax.random.PRNGKey(0), *_model_inputs(batch))
-        )
-        tiny_leaves = [
-            tuple(l.shape)
-            for l in jax.tree_util.tree_leaves(unbox(shapes)["params"])
-        ]
-        real_leaves = [
-            tuple(getattr(l, "shape", ()))
-            for l in jax.tree_util.tree_leaves(params)
-        ]
-        return sorted(tiny_leaves) == sorted(real_leaves)
-    except Exception:
-        return False
-
-
 # ---------------------------------------------------------------------------
 # Misc helpers (reference-parity)
 # ---------------------------------------------------------------------------
@@ -942,50 +913,6 @@ def single_node_env(num_gpus: int = 0) -> None:
     if _SERVING_PROBE_ERROR:
         raise RuntimeError(_SERVING_PROBE_ERROR)
     util.ensure_jax_platform()
-
-
-def get_meta_graph_def(export_dir: str, tag_set: str = "serve") -> dict:
-    """Describe an exported model: pytree leaf names → shape/dtype.
-
-    Reference anchor: ``pipeline.py::get_meta_graph_def`` (SavedModel
-    MetaGraphDef lookup).  The pytree-checkpoint equivalent of a signature:
-    what tensors the export contains — plus, for self-describing exports,
-    the serving signature itself (input/output names, dtypes, shapes)
-    under the reserved ``"__signature__"`` key, the MetaGraphDef's
-    signature_def equivalent.  Every other entry is a
-    ``{"shape", "dtype"}`` leaf record.
-    """
-    del tag_set  # parity only
-    import os
-
-    import jax
-    import numpy as np
-
-    from tensorflowonspark_tpu import ckpt, saved_model
-
-    path = export_dir
-    model_sub = os.path.join(path, "model")
-    if "://" not in path and os.path.isdir(model_sub):
-        path = model_sub
-    state = ckpt.load_pytree(path)
-    flat = {}
-    for keypath, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
-        name = "/".join(
-            str(getattr(k, "key", getattr(k, "idx", k))) for k in keypath
-        )
-        leaf = np.asarray(leaf)
-        flat[name] = {"shape": tuple(leaf.shape), "dtype": str(leaf.dtype)}
-    try:
-        signature = saved_model.read_signature(export_dir)
-    except FileNotFoundError:
-        return flat  # weights-only export: leaf listing is all there is
-    if "__signature__" in flat:  # a (pathological) leaf of that name wins
-        logger.warning(
-            "export %s has a '__signature__' leaf; omitting the serving "
-            "signature from get_meta_graph_def", export_dir)
-    else:
-        flat["__signature__"] = signature
-    return flat
 
 
 def _spark_context_of(df):

@@ -487,7 +487,7 @@ class Client:
     """
 
     #: bounded retry budget for transient socket errors (see :meth:`_call`);
-    #: override per client or via ``TFOS_RESERVATION_RETRIES``
+    #: override per client
     DEFAULT_RETRIES = 4
     #: first backoff sleep; doubles per attempt, jittered ±50%, capped
     BACKOFF_BASE_S = 0.2
@@ -500,10 +500,8 @@ class Client:
         #: when set, every message is stamped with this generation and the
         #: server fences it (elastic membership; see module docstring)
         self.generation = generation
-        if retries is None:
-            retries = int(os.environ.get("TFOS_RESERVATION_RETRIES",
-                                         str(self.DEFAULT_RETRIES)))
-        self.retries = max(0, retries)
+        self.retries = max(
+            0, self.DEFAULT_RETRIES if retries is None else retries)
 
     def _call(self, msg: dict[str, Any], timeout: float = 30.0,
               retries: int | None = None) -> dict[str, Any]:

@@ -447,6 +447,50 @@ def _fixed_batch_caller(exported, fixed: int,
     return fn
 
 
+def get_meta_graph_def(export_dir: str, tag_set: str = "serve") -> dict:
+    """Describe an exported model: pytree leaf names → shape/dtype.
+
+    Reference anchor: ``pipeline.py::get_meta_graph_def`` (SavedModel
+    MetaGraphDef lookup).  The pytree-checkpoint equivalent of a signature:
+    what tensors the export contains — plus, for self-describing exports,
+    the serving signature itself (input/output names, dtypes, shapes)
+    under the reserved ``"__signature__"`` key, the MetaGraphDef's
+    signature_def equivalent.  Every other entry is a
+    ``{"shape", "dtype"}`` leaf record.
+    """
+    del tag_set  # parity only
+    import os
+
+    import jax
+    import numpy as np
+
+    from tensorflowonspark_tpu import ckpt
+
+    path = export_dir
+    model_sub = os.path.join(path, "model")
+    if "://" not in path and os.path.isdir(model_sub):
+        path = model_sub
+    state = ckpt.load_pytree(path)
+    flat = {}
+    for keypath, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        name = "/".join(
+            str(getattr(k, "key", getattr(k, "idx", k))) for k in keypath
+        )
+        leaf = np.asarray(leaf)
+        flat[name] = {"shape": tuple(leaf.shape), "dtype": str(leaf.dtype)}
+    try:
+        signature = read_signature(export_dir)
+    except FileNotFoundError:
+        return flat  # weights-only export: leaf listing is all there is
+    if "__signature__" in flat:  # a (pathological) leaf of that name wins
+        logger.warning(
+            "export %s has a '__signature__' leaf; omitting the serving "
+            "signature from get_meta_graph_def", export_dir)
+    else:
+        flat["__signature__"] = signature
+    return flat
+
+
 # ---------------------------------------------------------------------------
 # CLI — the `saved_model_cli show|run` parity surface
 # ---------------------------------------------------------------------------
@@ -481,8 +525,6 @@ def _cli(argv=None) -> int:
 
     util.ensure_jax_platform()
     if args.cmd == "show":
-        from tensorflowonspark_tpu.pipeline import get_meta_graph_def
-
         meta = get_meta_graph_def(args.dir)
         sig = meta.pop("__signature__", None)
         if sig is None:

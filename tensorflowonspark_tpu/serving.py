@@ -313,10 +313,9 @@ def cache_health() -> dict[str, Any]:
     return doc
 
 
-#: padded-row fraction above which the bucket ladder is called bad
-#: (``TFOS_SERVING_PAD_WASTE_WARN`` overrides); judged only after
-#: ``_PAD_WARN_MIN_ROWS`` forwarded rows so a ragged first batch can't
-#: cry wolf
+#: padded-row fraction above which the bucket ladder is called bad;
+#: judged only after ``_PAD_WARN_MIN_ROWS`` forwarded rows so a ragged
+#: first batch can't cry wolf
 DEFAULT_PAD_WASTE_WARN = 0.5
 _PAD_WARN_MIN_ROWS = 256
 _PAD_WASTE_WARNED = False
@@ -365,12 +364,7 @@ def note_rows(n_real: int, bucket: int) -> None:
     waste.set(ratio)
     if _PAD_WASTE_WARNED or forwarded < _PAD_WARN_MIN_ROWS:
         return
-    try:
-        threshold = float(os.environ.get("TFOS_SERVING_PAD_WASTE_WARN",
-                                         DEFAULT_PAD_WASTE_WARN))
-    except ValueError:
-        threshold = DEFAULT_PAD_WASTE_WARN
-    if ratio > threshold:
+    if ratio > DEFAULT_PAD_WASTE_WARN:
         from tensorflowonspark_tpu import obs
 
         _PAD_WASTE_WARNED = True
@@ -379,10 +373,10 @@ def note_rows(n_real: int, bucket: int) -> None:
             "%d real rows): the bucket ladder is a bad fit for this "
             "batch-size distribution — add a smaller bucket (each costs "
             "one compile) or lower batch_size",
-            ratio * 100, threshold * 100, int(padded.value),
+            ratio * 100, DEFAULT_PAD_WASTE_WARN * 100, int(padded.value),
             int(rows.value))
         obs.event("serving.padding_waste", ratio=round(ratio, 4),
-                  threshold=threshold, rows=int(rows.value),
+                  threshold=DEFAULT_PAD_WASTE_WARN, rows=int(rows.value),
                   padded=int(padded.value))
 
 

@@ -380,7 +380,7 @@ def model_specs(model_name: str, *, tiny: bool = False
     signature on disk).  Training targets (:data:`LABEL_KEYS`) are
     stripped: they are loss inputs, not serving inputs.  ``tiny``
     selects the zoo's ``Config.tiny()`` geometry (the same choice
-    ``pipeline._is_tiny`` makes from loaded params)."""
+    ``models._is_tiny`` makes from loaded params)."""
     from tensorflowonspark_tpu import models as model_zoo
 
     lib = model_zoo.get_model(model_name)
@@ -402,10 +402,9 @@ def policy_specs(model_name: str, params: Any
     ``OnlineServer.add_tenant`` so the batch and online tiers can never
     drift on what a weights-only ``model_name`` export warms."""
     from tensorflowonspark_tpu import models as model_zoo
-    from tensorflowonspark_tpu.pipeline import _is_tiny
 
     lib = model_zoo.get_model(model_name)
-    return model_specs(model_name, tiny=_is_tiny(params, lib))
+    return model_specs(model_name, tiny=model_zoo._is_tiny(params, lib))
 
 
 def zero_batch(specs: Mapping[str, tuple[tuple, Any]], rows: int) -> dict:

@@ -30,9 +30,9 @@ arXiv:1810.11112).  This module removes it:
   containers) storing through a fresh mmap pays a page-fault per 4 KiB
   that makes it ~10× slower than the write syscall path.
 - **Fallbacks**: ragged / object-dtype rows fall back to the pickled-rows
-  path; columnarizable rows with shm unavailable (or ``TFOS_FEED_SHM=0``)
-  ride as a pickled :class:`~tensorflowonspark_tpu.marker.ColumnarChunk`
-  (still one columnarization, still O(columns) consumer work).
+  path; columnarizable rows with shm unavailable ride as a pickled
+  :class:`~tensorflowonspark_tpu.marker.ColumnarChunk` (still one
+  columnarization, still O(columns) consumer work).
 
 The consumer side (``TFNode.DataFeed``) concatenates pre-columnarized
 chunks with ``np.concatenate`` — or hands out a single chunk's columns as
@@ -81,14 +81,6 @@ def _my_start_tick() -> int:
 def shm_available() -> bool:
     """Can this host back the transport (POSIX shm present and writable)?"""
     return os.path.isdir(_SHM_DIR) and os.access(_SHM_DIR, os.W_OK)
-
-
-def enabled() -> bool:
-    """shm transport selected: available AND not opted out
-    (``TFOS_FEED_SHM=0``)."""
-    if os.environ.get("TFOS_FEED_SHM", "1").strip().lower() in ("0", "false"):
-        return False
-    return shm_available()
 
 
 class ShmChunkRef:
@@ -340,7 +332,7 @@ def encode_chunk(rows: list[Any], tag: str | None = None,
     or the legacy rows payload (``TaggedChunk`` / plain list) when the rows
     cannot be columnarized.  ``transport`` forces a path for benchmarking:
     ``"shm"``, ``"pickle"`` (columnar, no shm), ``"rows"`` (legacy) or
-    None = auto (:func:`enabled`)."""
+    None = auto (:func:`shm_available`)."""
     from tensorflowonspark_tpu import marker
 
     def legacy():
@@ -351,9 +343,7 @@ def encode_chunk(rows: list[Any], tag: str | None = None,
     cols = columnarize(rows)
     if cols is None:
         return legacy()
-    use_shm = enabled() if transport is None else (
-        transport == "shm" and shm_available())
-    if use_shm:
+    if transport in (None, "shm") and shm_available():
         ref = write_chunk(cols, tag=tag)
         if ref is not None:
             return ref

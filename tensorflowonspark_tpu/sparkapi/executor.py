@@ -46,7 +46,6 @@ def executor_main(executor_id: int, app_id: str, task_queue, result_queue,
     wd = os.path.join(util.single_node_scratch_dir(app_id), f"executor_{executor_id}")
     os.makedirs(wd, exist_ok=True)
     os.chdir(wd)
-    os.environ["TFOS_EXECUTOR_ID"] = str(executor_id)
     os.environ["TFOS_APP_ID"] = app_id
     driver_pid = os.getppid()
 

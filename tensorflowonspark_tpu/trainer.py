@@ -27,6 +27,7 @@ from typing import Any
 import numpy as np
 
 from tensorflowonspark_tpu import models as model_zoo, obs
+from tensorflowonspark_tpu.models import _model_inputs
 from tensorflowonspark_tpu.parallel import (
     apply_zero_sharding,
     build_mesh,
@@ -228,15 +229,6 @@ class Trainer:
         # DataFeed's wait/ingest halves accumulated into — one bottleneck
         # verdict per training step
         self._flight = obs.flight.recorder("feed")
-        # bucketed-collective comm model (parallel/collectives.py): the
-        # gradient bytes crossing replicas per step and the exchange world
-        # size, read by _comm_stage_seconds() to attribute the collective
-        # flight stages (`allreduce`, or `scatter`/`update`/`gather` under
-        # the sharded update) against the delivered roofline bandwidths
-        self._comm_info = None
-        if getattr(self.train_step, "bucketed", False):
-            self._comm_info = (self.train_step.comm_bytes,
-                               self.train_step.data_world)
         # periodic checkpointing (enable via checkpoint()) and elastic
         # regroup cooperation (attach_elastic()) both ride _after_step
         self._ckpt_mgr = None
@@ -311,76 +303,7 @@ class Trainer:
         # note_step books it): no clock is read again
         self._flight.add(shard=sh.dur_s, compute=run.dur_s)
         obs.ledger.goodput().note_step(sh.dur_s, run.dur_s)
-        # bucketed step: the modelled collective-stage costs ride beside
-        # the dispatch wall as overlapped (`_bg`) stages: an upper bound
-        # on exposed comm (overlap only shrinks it), and a MODEL must not
-        # name the bottleneck — on a well-overlapped comm-heavy step an
-        # additive split would classify comm_bound exactly when the
-        # overlap works.  The measured comm-vs-compute verdict comes from
-        # bench's step-collectives A/B, which times the no-reduce twin.
-        comm = self._comm_stage_seconds()
-        if comm:
-            if wait:
-                comm = {k: min(v, run.dur_s) for k, v in comm.items()}
-            self._flight.add(overlapped=True, **comm)
         return loss
-
-    def _peek_gauge(self, name: str) -> "float | None":
-        """Read a roofline gauge if a probe ever set it.  Peek, never
-        get-or-create: a trainer that merely ASKED must not mint a phantom
-        0.0 bandwidth series in processes that never ran the probe."""
-        gauge = obs.get_registry().peek(name)
-        bw = gauge.value if gauge is not None else None
-        return bw if bw and bw > 0 else None
-
-    def _comm_stage_seconds(self) -> "dict[str, float]":
-        """Modelled serial cost of this step's collective stages at the
-        *delivered* bandwidths the roofline probes measured — the
-        attribution is only made against measured numbers, never a
-        datasheet; empty on the monolithic step or before/without a probe.
-
-        All-reduce structure: one ``allreduce`` stage
-        (``comm_bytes`` ring cost at ``roofline_ici_bw_gbps``).  Sharded
-        update: the ``comm_model`` per-tier byte split priced per leg —
-        ``scatter`` (gradient reduce-scatter; ICI bytes at the ICI
-        roofline, DCN bytes at ``roofline_dcn_bw_gbps`` when probed, else
-        the ICI figure as an optimistic floor), ``gather`` (the parameter
-        all-gather, same pricing), and ``update`` (the 1/N optimizer
-        update modelled as memory-bound: ~7 passes over the local
-        param/grad/moment shards at ``roofline_mem_bw_gbps`` — AdamW
-        reads p/g/mu/nu and writes p/mu/nu)."""
-        if self._comm_info is None:
-            return {}
-        from tensorflowonspark_tpu.parallel import collectives
-
-        step = self.train_step
-        ici_bw = self._peek_gauge("roofline_ici_bw_gbps")
-        if not getattr(step, "update_sharded", False):
-            s = collectives.ideal_serial_allreduce_seconds(
-                self._comm_info[0], self._comm_info[1], ici_bw)
-            return {"allreduce": s} if s else {}
-        model = getattr(step, "comm_model", None)
-        if not model or not ici_bw:
-            return {}
-        dcn_bw = self._peek_gauge("roofline_dcn_bw_gbps") or ici_bw
-        sc = model["scatter"]
-        out: "dict[str, float]" = {}
-        scatter_s = (sc["exchange_ici"] / (ici_bw * 1e9)
-                     + sc["exchange_dcn"] / (dcn_bw * 1e9))
-        gather_s = (sc["gather_ici"] / (ici_bw * 1e9)
-                    + sc["gather_dcn"] / (dcn_bw * 1e9))
-        if scatter_s > 0:
-            out["scatter"] = scatter_s
-        if gather_s > 0:
-            out["gather"] = gather_s
-        mem_bw = self._peek_gauge("roofline_mem_bw_gbps")
-        if mem_bw:
-            local_bytes = (model["scatter_bytes"] / max(model["world"], 1)
-                           + model["replicated_bytes"])
-            update_s = 7.0 * local_bytes / (mem_bw * 1e9)
-            if update_s > 0:
-                out["update"] = update_s
-        return out
 
     def _after_step(self, loss, batch):
         """Shared post-step accounting: wall-time + examples → callbacks
@@ -456,8 +379,6 @@ class Trainer:
                       step=self._steps_done + 1).root() as sp:
             if armed:
                 self._watchdog.arm()
-                if os.environ.get("TFOS_STEP_WATCHDOG_TEST_HANG"):
-                    time.sleep(3600)  # simulated mid-run wedge (tests)
             try:
                 loss = self._dispatch(batch, wait=True)
             finally:
@@ -626,14 +547,6 @@ class Trainer:
         self.state = TrainState(restored["params"], restored["opt_state"],
                                 restored["step"],
                                 restored.get("collections", {}))
-
-
-def _model_inputs(batch: dict) -> tuple:
-    """Positional model inputs from an example batch (labels stripped —
-    the shape-policy module's one label-key convention)."""
-    from tensorflowonspark_tpu import shapes
-
-    return tuple(v for k, v in batch.items() if k not in shapes.LABEL_KEYS)
 
 
 def _batch_examples(batch) -> int:

@@ -210,9 +210,11 @@ class TestOutageProofing(unittest.TestCase):
             # double-pickling (a wall-clock assertion any tighter than
             # this flakes under CPU contention)
             self.assertGreater(out["feed_transport_speedup"], 0.5)
+            # the feeder is a thread of this process, so its segments
+            # carry this pid: other tests' come and go beside them
             self.assertEqual(
                 [f for f in os.listdir("/dev/shm")
-                 if f.startswith(shm.SEG_PREFIX)], [],
+                 if f.startswith(f"{shm.SEG_PREFIX}_{os.getpid()}_")], [],
                 "feed microbench leaked shm segments")
         else:
             self.assertEqual(out["feed_transport"], "pickle")

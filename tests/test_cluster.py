@@ -495,11 +495,10 @@ def test_trainer_watchdog_tolerates_compile_and_handled_errors():
 
 
 def test_mid_run_wedge_fails_fast_and_named(monkeypatch):
-    """Cluster-level: a trainer whose step wedges mid-run (simulated via
-    TFOS_STEP_WATCHDOG_TEST_HANG) dies fast with the reason on the error
+    """Cluster-level: a trainer whose step wedges mid-run (its compiled
+    step, once warm, never returns) dies fast with the reason on the error
     queue — the driver raises an attributed error instead of hanging the
     mesh until feed_timeout."""
-    monkeypatch.setenv("TFOS_STEP_WATCHDOG_TEST_HANG", "1")
     # shrink the dead-executor manager's orphan lingering so the test's
     # teardown (sc.stop + interpreter exit) stays fast
     monkeypatch.setenv("TFOS_MANAGER_ORPHAN_GRACE_S", "3")
@@ -515,6 +514,13 @@ def test_mid_run_wedge_fails_fast_and_named(monkeypatch):
                     step_timeout_s=3, error_sink=ctx.report_error)
         batch = mnist.example_batch(t.config, batch_size=8)
         t.step(batch)  # first step: compile warm-up, runs unarmed
+
+        def wedged_step(state, staged):
+            import time
+
+            time.sleep(3600)
+
+        t.train_step = wedged_step
         t.step(batch)  # second step arms, then wedges — never returns
 
     ctx = LocalSparkContext("local-cluster[1,1,1024]", "wedge-midrun-test")

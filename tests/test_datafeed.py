@@ -296,8 +296,11 @@ def test_transports_deliver_identical_batches(transport):
     np.testing.assert_array_equal(xs, np.stack([r[0] for r in rows]))
     np.testing.assert_array_equal(ys, np.arange(7))
     if shm.shm_available():
+        # this process wrote them, so they carry its pid: other tests'
+        # segments come and go beside them
         assert not [f for f in os.listdir("/dev/shm")
-                    if f.startswith(shm.SEG_PREFIX)], "segment leaked"
+                    if f.startswith(f"{shm.SEG_PREFIX}_{os.getpid()}_")], \
+            "segment leaked"
 
 
 def test_columnar_chunk_split_across_batches_is_viewed_not_copied():

@@ -81,7 +81,7 @@ def test_recorder_disabled_by_env(monkeypatch):
 
 
 def test_sampling_knob_thins_histograms_not_verdicts(monkeypatch):
-    monkeypatch.setenv("TFOS_FLIGHT_SAMPLE", "3")
+    monkeypatch.setattr(flight, "SAMPLE_EVERY", 3)
     rec = flight.FlightRecorder("unit_sampled")
     for _ in range(9):
         rec.add(compute=0.01)

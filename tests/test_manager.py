@@ -268,11 +268,13 @@ def test_byte_bound_configured_from_env(monkeypatch):
             shm.maybe_unlink_payload(p)
         m.shutdown()
         monkeypatch.delenv("TFOS_FEED_MAX_INFLIGHT_MB")
-        # unlinked everything: no segment left behind
+        # unlinked everything it made (other tests' segments come and
+        # go beside it): no segment of its own left behind
         import os
 
-        assert not [f for f in os.listdir("/dev/shm")
-                    if f.startswith(shm.SEG_PREFIX)]
+        assert not [p.name for p in payloads
+                    if isinstance(p, shm.ShmChunkRef)
+                    and os.path.exists(os.path.join("/dev/shm", p.name))]
 
 
 def test_trainer_pid_start_rides_the_kv(mgr):

@@ -221,10 +221,8 @@ def _no_jax_script(tmp_path, body):
         "m.startswith(('jax.', 'jaxlib'))]\n"
         "    assert not bad, bad\n"
         "if __name__ == '__main__':\n    main()\n")
-    # no shared-memory segments from here: tests elsewhere list /dev/shm
     proc = subprocess.run([sys.executable, str(script)], capture_output=True,
-                          text=True, timeout=120, cwd=str(tmp_path),
-                          env=dict(os.environ, TFOS_FEED_SHM="0"))
+                          text=True, timeout=120, cwd=str(tmp_path))
     assert proc.returncode == 0, proc.stderr[-3000:]
     return proc.stdout
 
@@ -246,7 +244,7 @@ def test_the_executors_feeder_task_stays_off_jax(tmp_path):
     """A partition fed through ``TFSparkNode.train`` in a process of its
     own: the feeder's spans are recorded, JAX is never imported."""
     out = _no_jax_script(tmp_path, """
-from tensorflowonspark_tpu import TFManager, TFSparkNode, marker, obs, util
+from tensorflowonspark_tpu import TFManager, TFSparkNode, marker, obs, shm, util
 key = b"feeder-no-jax"
 mgr = TFManager.start(key, ["input", "output", "error"], mode="local")
 mgr.set("state", "running")
@@ -256,8 +254,8 @@ info = [{"executor_id": 0, "addr": list(mgr.address), "job_name": "worker",
 meta = {"id": "cid", "authkey_hex": key.hex(), "feed_chunk": 4}
 q = mgr.get_queue("input")
 def drain():
-    while not isinstance(q.get(), marker.EndPartition):
-        pass
+    while not isinstance(item := q.get(), marker.EndPartition):
+        shm.maybe_unlink_payload(item)
 t = threading.Thread(target=drain)
 t.start()
 TFSparkNode.train(info, meta, 30.0, "input")(
@@ -770,7 +768,7 @@ def test_labeled_series_share_one_family_type_line():
 
 
 def test_labeled_cardinality_bounded_with_overflow_and_remove(monkeypatch):
-    monkeypatch.setenv("TFOS_METRIC_SERIES_MAX", "2")
+    monkeypatch.setattr(reg, "_DEFAULT_SERIES_MAX", 2)
     r = reg.Registry()
     a = r.counter("x_total", labels={"tenant": "a"})
     b = r.counter("x_total", labels={"tenant": "b"})

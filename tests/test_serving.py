@@ -139,7 +139,7 @@ def test_padding_waste_warning_fires_once_over_threshold(monkeypatch):
     monkeypatch.setattr(serving, "_PAD_WASTE_WARNED", False)
     # the process counters are cumulative across the suite, so use a
     # threshold any nonzero cumulative waste ratio clears
-    monkeypatch.setenv("TFOS_SERVING_PAD_WASTE_WARN", "0.000001")
+    monkeypatch.setattr(serving, "DEFAULT_PAD_WASTE_WARN", 0.000001)
     tracer = obs.get_tracer()
     before = sum(1 for e in tracer.snapshot()
                  if e["name"] == "serving.padding_waste")
@@ -165,7 +165,7 @@ def test_padding_waste_warning_respects_min_volume(monkeypatch):
     from tensorflowonspark_tpu import obs, serving as serving_mod
 
     monkeypatch.setattr(serving_mod, "_PAD_WASTE_WARNED", False)
-    monkeypatch.setenv("TFOS_SERVING_PAD_WASTE_WARN", "0.000001")
+    monkeypatch.setattr(serving_mod, "DEFAULT_PAD_WASTE_WARN", 0.000001)
     # raise the volume guard above anything the suite has accumulated —
     # the counters are process-cumulative by design
     monkeypatch.setattr(serving_mod, "_PAD_WARN_MIN_ROWS", 10**12)

@@ -84,6 +84,35 @@ def test_signature_stable_across_processes():
     assert theirs == ours
 
 
+def test_shape_policy_and_export_format_stay_below_the_cluster_stack(
+        tmp_path):
+    """``shapes``, ``saved_model`` and ``infer_embed`` — imported, then
+    asked for the geometry loaded params imply and for an export's
+    description — pull in neither ``pipeline`` nor ``TFCluster``."""
+    prog = (
+        "import sys\n"
+        "import numpy as np\n"
+        "from tensorflowonspark_tpu import util\n"
+        "util.ensure_jax_platform()\n"
+        "from tensorflowonspark_tpu import ckpt, infer_embed, saved_model, "
+        "shapes\n"
+        "specs = shapes.policy_specs('mnist_mlp', {'w': np.zeros(3)})\n"
+        "assert specs, specs\n"
+        f"ckpt.save_pytree({{'params': {{'w': np.zeros((3, 2))}}}}, "
+        f"{str(tmp_path / 'exp')!r})\n"
+        f"meta = saved_model.get_meta_graph_def({str(tmp_path / 'exp')!r})\n"
+        "assert list(meta) == ['params/w'], meta\n"
+        "high = [m for m in ('pipeline', 'TFCluster')\n"
+        "        if 'tensorflowonspark_tpu.' + m in sys.modules]\n"
+        "assert not high, high\n")
+    out = subprocess.run([sys.executable, "-c", prog],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
 # ---------------------------------------------------------------------------
 # Ladder equivalence with the three legacy call sites
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 Covers the full descriptor lifecycle — write/read round trip, pickled and
 legacy fallbacks, the orphan sweep keyed on the (pid, start tick) identity,
-the ``TFOS_FEED_SHM=0`` opt-out — and asserts after EVERY test that no
+a host without usable shm — and asserts after EVERY test that no
 ``tfos_feed_*`` segment is left behind in ``/dev/shm`` (the acceptance
 criterion: the transport must never leak host shared memory).
 """
@@ -18,30 +18,56 @@ import pytest
 from tensorflowonspark_tpu import marker, shm
 
 
-def _segments():
+def _segments(foreign=False):
+    """This file's feed segments: those this process wrote and those whose
+    writer is gone (a child that a test stranded).  A segment whose writer
+    is another live process is another test file's, mid-feed under the
+    driver's six workers (``foreign=True`` lists those instead)."""
     if not os.path.isdir("/dev/shm"):
         return []
-    return sorted(f for f in os.listdir("/dev/shm")
-                  if f.startswith(shm.SEG_PREFIX + "_"))
+    out = []
+    for f in os.listdir("/dev/shm"):
+        if not f.startswith(shm.SEG_PREFIX + "_"):
+            continue
+        pid = f[len(shm.SEG_PREFIX) + 1:].split("_")[0]
+        theirs = (pid.isdigit() and int(pid) != os.getpid()
+                  and os.path.exists(f"/proc/{pid}"))
+        if theirs == foreign:
+            out.append(f)
+    return sorted(out)
+
+
+def _gauges():
+    """``shm.update_gauges()`` less what other test files hold right now:
+    the gauges count the whole host, as they should."""
+    count, nbytes = shm.update_gauges()
+    for f in _segments(foreign=True):
+        try:
+            nbytes -= os.stat(os.path.join("/dev/shm", f)).st_size
+        except OSError:
+            continue  # consumed since the listing: not in the scan either
+        count -= 1
+    return count, nbytes
 
 
 @pytest.fixture(autouse=True)
 def no_segment_leaks():
-    """The leak assertion: every test leaves /dev/shm exactly as it found
-    it.  Tests that deliberately strand a segment must reap it themselves
-    (that is what they are testing).  The flight-recorder residency gauges
-    (``shm_segments_live`` / ``shm_bytes_resident``, refreshed by every
-    manager watch cycle in production) must agree — and read zero when the
-    directory is clean."""
+    """The leak assertion: every test leaves this file's segments exactly as
+    it found them.  Tests that deliberately strand a segment must reap it
+    themselves (that is what they are testing).  The flight-recorder
+    residency gauges (``shm_segments_live`` / ``shm_bytes_resident``,
+    refreshed by every manager watch cycle in production) must agree with
+    the directory — and read zero when it is clean."""
     before = _segments()
     yield
     assert _segments() == before, "test leaked shm feed segments"
     from tensorflowonspark_tpu import obs
 
-    count, nbytes = shm.update_gauges()
+    total, total_bytes = shm.update_gauges()
+    assert obs.gauge("shm_segments_live").value == total
+    assert obs.gauge("shm_bytes_resident").value == total_bytes
+    count, nbytes = _gauges()
     assert count == len(before)
-    assert obs.gauge("shm_segments_live").value == count
-    assert obs.gauge("shm_bytes_resident").value == nbytes
     if not before:
         assert (count, nbytes) == (0, 0)
 
@@ -166,14 +192,14 @@ def test_encode_chunk_auto_uses_shm_when_enabled():
     shm.unlink_ref(payload)
 
 
-def test_encode_chunk_opt_out_env(monkeypatch):
-    monkeypatch.setenv("TFOS_FEED_SHM", "0")
-    assert not shm.enabled()
-    payload = shm.encode_chunk(_rows(), tag="tA")
-    assert isinstance(payload, marker.ColumnarChunk)
-    assert payload.tag == "tA" and payload.nrows == 6
-    monkeypatch.setenv("TFOS_FEED_SHM", "1")
-    assert shm.enabled()
+def test_encode_chunk_without_usable_shm_keeps_tag_and_rows(monkeypatch):
+    """A host without usable shm keeps tag and row count on the pickled
+    columns, whether the transport was left to the code or asked for."""
+    monkeypatch.setattr(shm, "_SHM_DIR", "/nonexistent-shm-dir")
+    for transport in (None, "shm"):
+        payload = shm.encode_chunk(_rows(), tag="tA", transport=transport)
+        assert isinstance(payload, marker.ColumnarChunk)
+        assert payload.tag == "tA" and payload.nrows == 6
 
 
 def test_encode_chunk_ragged_rows_keep_legacy_path():
@@ -302,7 +328,7 @@ def test_resident_gauges_see_parked_segments():
         assert obs.gauge("shm_bytes_resident").value == nbytes
     finally:
         shm.unlink_ref(ref)
-    assert shm.update_gauges() == (0, 0)
+    assert _gauges() == (0, 0)
 
 
 def test_sweep_keeps_live_creator_segments():

@@ -1,6 +1,7 @@
 """Unit tests for util + marker + chip claiming."""
 
 import os
+import re
 
 import pytest
 
@@ -61,3 +62,26 @@ def test_chipless_host_claims_nothing(monkeypatch, tmp_path):
     monkeypatch.setenv("TFOS_NUM_CHIPS", "0")
     monkeypatch.setenv("TFOS_SCRATCH_ROOT", str(tmp_path))
     assert chip_info.claim_chips(1, "app3", "exec_0") == []
+
+
+def test_deploy_table_lists_every_tfos_name():
+    """DEPLOY.md's environment table and the package's Python name the
+    same ``TFOS_*`` variables: a new name is a reviewed line of the table,
+    and a name that left the code leaves the table."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    read = set()
+    for root, _dirs, files in os.walk(
+            os.path.join(repo, "tensorflowonspark_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(root, fn), encoding="utf-8") as f:
+                    read |= set(re.findall(r"TFOS_[A-Z0-9_]+", f.read()))
+    with open(os.path.join(repo, "DEPLOY.md"), encoding="utf-8") as f:
+        rows = re.findall(r"^\| `(TFOS_[A-Z0-9_]+)` \|.*\| (.+?) \|$",
+                          f.read(), flags=re.M)
+    table = dict(rows)
+    assert len(rows) == len(table), "a name is listed twice"
+    assert table.keys() == read, (
+        sorted(read - table.keys()), sorted(table.keys() - read))
+    assert set(table.values()) <= {
+        "deployment setting", "limit", "switch", "fault hook"}
