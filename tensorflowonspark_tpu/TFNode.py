@@ -91,6 +91,9 @@ class DataFeed:
         self._buffer_tags: list[list] = []
         self._out_route: list[list] = []
         self._stop_seen = False  # StopFeed consumed by the assembling side
+        # terminate() has begun: the drain owns the input queue from here on
+        # and the assembling side takes (and stages) nothing more off it
+        self._terminating = False
         #: optional zero-arg callable (``elastic.ElasticWorker.attach``):
         #: when set and truthy while the consumer is BLOCKED on an empty
         #: queue, ``next_batch`` raises :class:`FeedInterrupted` instead of
@@ -171,12 +174,21 @@ class DataFeed:
                             timeout=self._interrupt_poll_s)
                         break
                     except _std_queue.Empty:
+                        if self._terminating:
+                            item = None
+                            break
                         if self.interrupt():
                             raise FeedInterrupted(
                                 "feed wait interrupted (regroup pending)"
                             ) from None
             now = _time_mod.perf_counter()
             wait_s += now - tw
+            if self._terminating:
+                # terminate() began while this get was pending and its drain
+                # may already have taken the chunks before this one: drop
+                # the item as the drain would, or the batch has a hole
+                shm.maybe_unlink_payload(item)
+                break
             if isinstance(item, marker.StopFeed):
                 self._stop_seen = True
                 continue
@@ -322,6 +334,8 @@ class DataFeed:
             try:
                 while True:
                     pieces, runs, stopped = self._assemble(batch_size)
+                    if self._terminating:
+                        return  # nothing is staged once terminate() began
                     batch = self._columnarize(pieces, device_put)
                     with obs.span("feed.pump_blocked",
                                   depth=self._pf_out.qsize()):
@@ -389,11 +403,18 @@ class DataFeed:
         """Drain remaining input so blocked feeder tasks can finish.
 
         Reference anchor: ``TFNode.py::DataFeed.terminate``.  With an active
-        prefetch thread the staged batches are discarded too; the (daemon)
-        pipeline thread exits with the trainer process.
+        prefetch thread the staged batches are discarded too, and from the
+        moment this begins the pump stages nothing more: the drain below and
+        the pump's pending ``get`` are two consumers of one queue, so the
+        pump may be handed chunk *k+1* after the drain took chunk *k*.  The
+        pump drops what such a ``get`` returns exactly as the drain does (a
+        shared-memory chunk unlinked), runs no ``device_put`` callback on it
+        and ends; a pump that is never handed anything more stays blocked
+        and (a daemon) exits with the trainer process.
         """
         logger.info("DataFeed terminating: draining input queue")
         obs.event("datafeed.terminate", qname=self.qname_in)
+        self._terminating = True  # before the first get of the drain
         self.done_feeding = True
         self._stop_seen = True
         if self._pf_out is not None:
@@ -409,13 +430,9 @@ class DataFeed:
                 return
             except (EOFError, BrokenPipeError):
                 return
-            if isinstance(item, shm.ShmChunkRef):
-                # a drained descriptor is never read: unlink its segment
-                # here or nothing will until the orphan sweep
-                try:
-                    shm.unlink_ref(item)
-                except Exception:
-                    pass
+            # a drained descriptor is never read: unlink its segment here
+            # or nothing will until the orphan sweep
+            shm.maybe_unlink_payload(item)
 
     # -- internals ---------------------------------------------------------
 

@@ -17,17 +17,25 @@ TPU-first choices:
   reference-era split (FTRL/AdaGrad on wide+embeddings, Adam-family on
   the dense tower), which measured 3.6× over AdamW-on-everything
   (``BENCH_NOTES.md``).
-- the table update strategy is ``Config.table_update``: ``"dense"``
-  (gather-VJP grads + full-table AdaGrad pass) or ``"sparse"`` (the
-  sparse embedding engine, ``tensorflowonspark_tpu/embedding.py`` — only
-  the gathered rows are read/written, the TPUEmbedding-style path).
-  Both were profiled on the bench chip; dense wins there because XLA's
-  scatter lowering serializes (~20 ms per 106k-row scatter), sparse wins
-  wherever scatters are fast — see BENCH_NOTES.md for the numbers.
-  The two modes diverge numerically on batches with duplicate ids (dense
-  squares the summed duplicate grads, sparse sums the squared
-  per-occurrence grads into the accumulator) — see the
+- ``Config.table_update`` names the AdaGrad VARIANT of the tables, not
+  how it runs.  ``"dense"`` (the default) sums the gradients of a batch's
+  duplicate ids before it squares them into the accumulator — what the
+  gradient of a gather gives — and ``"sparse"``
+  (``embedding.sparse_adagrad_update``) squares each occurrence on its
+  own.  On batches that repeat an id they are two trajectories; see the
   ``Config.table_update`` comment.
+- how ``"dense"`` EXECUTES is chosen when a batch shape is traced, from
+  static shapes alone (:func:`update_touches_rows`: the table rows a device
+  holds against the ids a step looks up).  A table large for its batch is
+  updated on the looked-up rows: the gradient w.r.t. the gathered rows,
+  duplicates summed by one equality-mask product a feature, two row
+  scatters a table (``embedding.adagrad_update_rows``); nothing of a
+  table's shape is allocated.  A table small for its batch takes the
+  gather's VJP and one pass over the whole table, which is cheaper there.
+  The two meet at about 160 table rows an id on a v5e chip
+  (``ROWS_PER_ID_CROSSOVER`` and the measurements beside it;
+  BENCH_NOTES.md has the older 2.6 M-row ones), agree to float32 rounding
+  and both leave untouched rows bit-identical (``tests/test_models.py``).
 - :func:`make_sharded_train_step` is the model-supplied custom step the
   ``Trainer`` picks up; it composes with the generic machinery through
   ``parallel.train.compile_step`` (same shardings, donation, active mesh).
@@ -38,6 +46,8 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+
+from tensorflowonspark_tpu import obs
 
 NUM_DENSE = 13
 NUM_CAT = 26
@@ -56,19 +66,19 @@ class Config:
     dtype: str = "float32"
     table_dtype: str = "float32"
     table_lr: float = 0.01  # AdaGrad rate for wide+embedding tables
-    # "dense": table grads via the gather's VJP, full-table AdaGrad pass —
-    #   measured fastest on chips whose scatter lowering is serialized
-    #   (~20 ms per 106k-row scatter on the bench v5e; BENCH_NOTES.md).
-    # "sparse": embedding.sparse_adagrad_update touches only gathered rows —
-    #   O(batch) HBM traffic, the right mode where scatters are fast
-    #   (CPU; SparseCore-class hardware).
-    # NOT numerically identical when a batch repeats an id: dense sums the
-    # duplicates' grads BEFORE squaring into the AdaGrad accumulator (the
-    # gather VJP pre-reduces), sparse accumulates each occurrence's
-    # squared grad separately — so switching modes changes the training
-    # trajectory on duplicate-heavy data, not just the speed.  Both are
-    # legitimate AdaGrad variants (combined- vs per-occurrence
-    # accumulation); pick one per run and keep it.
+    # The AdaGrad variant of the tables, not how it runs:
+    # "dense": combined accumulation — the gradients of a batch's duplicate
+    #   ids are SUMMED, then squared into the accumulator (what the gradient
+    #   of a gather gives).  Runs as a pass over the whole table or on the
+    #   looked-up rows alone, chosen from the shapes (update_touches_rows:
+    #   the rows from 160 table rows an id, measured on a v5e chip); the
+    #   same numbers either way.
+    # "sparse": per-occurrence accumulation — embedding.sparse_adagrad_update
+    #   squares each occurrence separately, on the gathered rows.
+    # NOT numerically identical when a batch repeats an id, so switching
+    # changes the training trajectory on duplicate-heavy data, not just the
+    # speed.  Both are legitimate AdaGrad variants; pick one per run and
+    # keep it.
     table_update: str = "dense"
 
     @classmethod
@@ -89,6 +99,45 @@ def fold_ids(cat, config: Config):
 
     offsets = jnp.arange(NUM_CAT, dtype=cat.dtype) * config.hash_buckets
     return cat + offsets[None, :]
+
+
+#: Table rows a looked-up id from which the default (``"dense"``) AdaGrad
+#: touches only the batch's rows and no longer passes over the whole table.
+#: Whole step on one TPU v5e chip, ms (PR 30's chip runs;
+#: ``tools/table_update_crossover.py`` measures the table again):
+#:
+#: ======= ===== ========= ========= ========= =======================
+#: buckets batch rows / id rows      full pass
+#: ======= ===== ========= ========= ========= =======================
+#: 650,000 1,024       635      9.97     24.70 the benchmark's cell
+#: 650,000 4,096       159     40.19     39.43 the threshold, within 2%
+#: 100,000 1,024        98      9.63      7.92
+#: 100,000 4,096        24     39.71     21.80 ``Config()``, ``bench.py``
+#: ======= ===== ========= ========= ========= =======================
+#:
+#: PR 31 measured the two ends again (rows / full pass): 9.97 / 24.71 at
+#: 650,000 x 1,024 and 39.72 / 21.80 at 100,000 x 4,096.
+#: The pass costs about 1.2 ns a table row whatever the batch, plus 174 ns
+#: an id for its own gather and VJP scatter; the rows execution costs 378 ns
+#: an id whatever the table (two row scatters and one more row gather a
+#: table).  They meet at (378 - 174) / 1.2, about 160 rows an id.
+#:
+#: Not measured: (1) vocab-sharded tables.  The rule takes the rows ONE
+#: device holds (``total_buckets // tp``), reasoning that each device passes
+#: over its own rows while every device still handles every id; no
+#: multi-chip run has timed either side, so the ``// tp`` is inferred.
+#: (2) Batches over 4,096: the rule is linear in the ids and the
+#: duplicate-summing mask product is not (``F * B * B * E``: 9 ns an id at
+#: 4,096, 37 at 16,384 by that count).
+ROWS_PER_ID_CROSSOVER = 160
+
+
+def update_touches_rows(table_rows: int, ids_per_step: int) -> bool:
+    """How the ``"dense"`` table update of a step executes: on the rows the
+    batch looked up (True) or as a pass over the whole table (False).  A
+    pure function of static shapes: the rows of a table one device holds
+    and the ids a step looks up."""
+    return table_rows >= ROWS_PER_ID_CROSSOVER * ids_per_step
 
 
 def make_model(config: Config, mesh=None):
@@ -253,10 +302,13 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
     """The model-supplied train step the ``Trainer`` picks up.
 
     MLP tower: ``optimizer`` (optax) over ``state.params``.  Tables: AdaGrad
-    at ``config.table_lr``, either ``"dense"`` (gather-VJP grad + full-table
-    pass) or ``"sparse"`` (``embedding.sparse_adagrad_update`` on only the
-    gathered rows) per ``config.table_update`` — see the module docstring
-    for the measured tradeoff.  Compiled through the same
+    at ``config.table_lr`` in the variant ``config.table_update`` names
+    (``"dense"``: duplicates combined; ``"sparse"``: per occurrence — the
+    module docstring).  ``"dense"`` runs on the looked-up rows alone or as
+    a pass over the whole table, chosen when a batch shape is traced by
+    :func:`update_touches_rows`; the returned step counts its calls by the
+    same rule (``table_update_rows_steps_total`` /
+    ``table_update_full_steps_total``).  Compiled through the same
     ``parallel.train.compile_step`` as the generic path (shardings, buffer
     donation — the table updates land in the donated buffers in place —
     and the active-mesh binding).
@@ -280,7 +332,7 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
             )
         )
 
-    def _dense_adagrad(table, acc, g, eps=1e-10):
+    def _full_adagrad(table, acc, g, eps=1e-10):
         """Full-table AdaGrad pass; untouched rows see g == 0 and are
         unchanged, so the sparseness contract still holds bit-wise."""
         g = g.astype(jnp.float32)
@@ -288,14 +340,26 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
         update = (-config.table_lr * g * jax.lax.rsqrt(acc + eps))
         return table + update.astype(table.dtype), acc
 
+    if collection_shardings is None:
+        # direct callers (not via Trainer, which passes the hook's result)
+        collection_shardings = make_collection_shardings(config, mesh)
+    # the rows of a table one device passes over: 1/tp of them where the
+    # tables are vocab-sharded
+    tp = mesh.shape.get("tp", 1) if collection_shardings else 1
+    rows_held = config.total_buckets // tp
+
     def _step(st, batch):
         emb = st.collections["embedding"]
         acc = st.collections["embedding_opt"]
         ids = fold_ids(batch["cat"], config)
 
-        if sparse:
-            deep_rows = jnp.take(emb["deep"], ids, axis=0)
-            wide_rows = jnp.take(emb["wide"], ids, axis=0)
+        if sparse or update_touches_rows(rows_held, ids.size):
+            # gradient w.r.t. the GATHERED rows: nothing of a table's shape.
+            # The lookups are the forward pass's though they sit outside the
+            # differentiated function, and the scope tells a profile so
+            with jax.named_scope(train_lib.FORWARD_SCOPE):
+                deep_rows = jnp.take(emb["deep"], ids, axis=0)
+                wide_rows = jnp.take(emb["wide"], ids, axis=0)
 
             def loss_of(params, dr, wr):
                 logit = _apply(module, params, st.collections, batch,
@@ -305,10 +369,22 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
             loss, (g_p, g_dr, g_wr) = jax.value_and_grad(
                 loss_of, argnums=(0, 1, 2)
             )(st.params, deep_rows, wide_rows)
-            new_deep, new_dacc = embedding.sparse_adagrad_update(
-                emb["deep"], acc["deep_acc"], ids, g_dr, config.table_lr)
-            new_wide, new_wacc = embedding.sparse_adagrad_update(
-                emb["wide"], acc["wide_acc"], ids, g_wr, config.table_lr)
+            if sparse:
+                new_deep, new_dacc = embedding.sparse_adagrad_update(
+                    emb["deep"], acc["deep_acc"], ids, g_dr, config.table_lr)
+                new_wide, new_wacc = embedding.sparse_adagrad_update(
+                    emb["wide"], acc["wide_acc"], ids, g_wr, config.table_lr)
+            else:
+                # one mask product sums both tables' duplicates: the wide
+                # gradient rides as one more column beside the deep ones
+                sums = embedding.sum_duplicate_grads(
+                    ids, jnp.concatenate([g_dr, g_wr[..., None]], axis=-1))
+                new_deep, new_dacc = embedding.adagrad_update_rows(
+                    emb["deep"], acc["deep_acc"], ids, deep_rows,
+                    sums[..., :-1], config.table_lr)
+                new_wide, new_wacc = embedding.adagrad_update_rows(
+                    emb["wide"], acc["wide_acc"], ids, wide_rows,
+                    sums[..., -1], config.table_lr)
         else:
             def loss_of(params, deep, wide):
                 dr = jnp.take(deep, ids, axis=0)
@@ -320,9 +396,9 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
             loss, (g_p, g_deep, g_wide) = jax.value_and_grad(
                 loss_of, argnums=(0, 1, 2)
             )(st.params, emb["deep"], emb["wide"])
-            new_deep, new_dacc = _dense_adagrad(
+            new_deep, new_dacc = _full_adagrad(
                 emb["deep"], acc["deep_acc"], g_deep)
-            new_wide, new_wacc = _dense_adagrad(
+            new_wide, new_wacc = _full_adagrad(
                 emb["wide"], acc["wide_acc"], g_wide)
 
         updates, opt_state = optimizer.update(g_p, st.opt_state, st.params)
@@ -334,14 +410,36 @@ def make_sharded_train_step(module, config: Config, optimizer, mesh,
         return train_lib.TrainState(params, opt_state, st.step + 1,
                                     cols), loss
 
-    if collection_shardings is None:
-        # direct callers (not via Trainer, which passes the hook's result)
-        collection_shardings = make_collection_shardings(config, mesh)
-    return train_lib.compile_step(
+    step = train_lib.compile_step(
         _step, mesh, param_shardings, state, batch_example,
         sequence_axes=sequence_axes,
         collection_shardings=collection_shardings,
     )
+    return step if sparse else _CountedStep(step, rows_held)
+
+
+class _CountedStep:
+    """The compiled ``"dense"`` step, counting its calls by the execution
+    :func:`update_touches_rows` gives the batch's shape — the rule the trace
+    of that shape applied.  Everything else (``lower``, the jit's
+    attributes) is the step's own."""
+
+    def __init__(self, step, rows_held: int):
+        self._step = step
+        self._rows_held = rows_held
+        # both registered at once, so the one that never counts reads 0
+        self._counters = {
+            True: obs.counter("table_update_rows_steps_total"),
+            False: obs.counter("table_update_full_steps_total")}
+
+    def __call__(self, state, batch):
+        out = self._step(state, batch)
+        self._counters[update_touches_rows(
+            self._rows_held, batch["cat"].size)].inc()
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._step, name)
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0):

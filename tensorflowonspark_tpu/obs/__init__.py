@@ -124,7 +124,10 @@ step — never per record, row or chunk):
   of its own) > ``trainer.shard``, ``trainer.dispatch``,
   ``trainer.checkpoint``; ``ckpt.save`` / ``ckpt.restore``;
 - kernels: ``jax.named_scope`` ``forward`` and ``optimizer`` in the
-  compiled step (``parallel/train.py``).
+  compiled step (``parallel/train.py``); counters
+  ``table_update_rows_steps_total`` / ``table_update_full_steps_total``,
+  one increment a wide&deep step, say which execution of the default
+  table update the step's shapes chose (``models/widedeep.py``).
 
 Also instrumented: elastic regroups (``elastic``), serving
 (``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which

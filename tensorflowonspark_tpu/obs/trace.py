@@ -56,8 +56,10 @@ kept only for SLO breaches / sheds / errors plus a small uniform sample,
 everything else dropped at commit.
 
 Env knobs: ``TFOS_TRACE=0`` disables recording entirely (the record path
-then costs one attribute check); the ring buffer holds 16384 events per
-process (a 30 s window of 700 steps at 8 spans a step fits twice over).
+then costs one attribute check); the ring buffer holds 32768 events per
+process (a trainer records about 8.8 a step: 15,400 in a job of 1,750 steps,
+which the 16384 of before PR 31 held with 6% to spare; a ring that drops
+leaves every reader of its spans with nothing).
 Request tracing has its own knobs: ``TFOS_TRACE_REQUESTS=0`` disables
 per-request span trees, ``TFOS_TRACE_ARM`` sets the fraction of (uniform-population) requests
 armed for capture (default 0.05 — explicit inbound contexts always arm,
@@ -89,7 +91,7 @@ TRACE_KV_PREFIX = "trace:"
 #: ... and its registry snapshot, at process or task end (:func:`flush`)
 COUNTERS_KV_PREFIX = "counters:"
 
-_DEFAULT_CAPACITY = 16384
+_DEFAULT_CAPACITY = 32768
 
 
 _ANNOTATION = None  # jax.profiler.TraceAnnotation, once JAX is there

@@ -315,7 +315,8 @@ def unlink_ref(ref: ShmChunkRef) -> bool:
 
 
 def maybe_unlink_payload(payload: Any) -> None:
-    """Best-effort cleanup of a queue payload that failed to enqueue."""
+    """Best-effort cleanup of a queue payload nobody will read: one that
+    failed to enqueue, or one ``DataFeed.terminate()`` drained."""
     if isinstance(payload, ShmChunkRef):
         try:
             unlink_ref(payload)

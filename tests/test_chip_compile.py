@@ -63,8 +63,9 @@ def abstract_train_step(model_name, config, devices, batch_size):
     """``Trainer.__init__``'s wiring over ``devices`` with shapes for arrays.
 
     Returns ``(step, state, batch)``: the real compiled-step factory's
-    output (optimizer, donation, sharded update where the mesh allows), and
-    the abstract train state / batch to ``step.lower`` it with.
+    output (the model's own where it supplies one, as wide&deep does;
+    optimizer, donation, sharded update where the mesh allows), and the
+    abstract train state / batch to ``step.lower`` it with.
     """
     import optax
 
@@ -78,21 +79,29 @@ def abstract_train_step(model_name, config, devices, batch_size):
     mesh = build_mesh(None, devices=devices)
     model = lib.make_model(config, mesh=mesh)
     optimizer = optax.adamw(1e-3)  # Trainer's default
-    loss_fn = lib.make_loss_fn(model, config)
     init_args = _model_inputs(lib.example_batch(config, batch_size=2))
 
-    def init_params():
-        return model.init(jax.random.PRNGKey(0), *init_args)["params"]
+    def init():
+        return model.init(jax.random.PRNGKey(0), *init_args)
+
+    def make_state():
+        variables = unbox(init())
+        return create_train_state(
+            variables.pop("params"), optimizer, variables)
 
     param_shardings = param_sharding_from_metadata(
-        jax.eval_shape(init_params), mesh)
-    state = jax.eval_shape(
-        lambda: create_train_state(unbox(init_params()), optimizer))
+        jax.eval_shape(init)["params"], mesh)
+    state = jax.eval_shape(make_state)
     batch = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct((batch_size,) + a.shape[1:], a.dtype),
         lib.example_batch(config, batch_size=2))
-    step = make_train_step(loss_fn, optimizer, mesh, param_shardings, state,
-                           batch)
+    make_custom = getattr(lib, "make_sharded_train_step", None)
+    if make_custom is not None:
+        step = make_custom(model, config, optimizer, mesh, param_shardings,
+                           state, batch)
+    else:
+        step = make_train_step(lib.make_loss_fn(model, config), optimizer,
+                               mesh, param_shardings, state, batch)
     return step, state, batch
 
 
@@ -189,6 +198,27 @@ def test_mnist_mlp_sharded_update_compiles_for_four_v5e_chips(topo):
         "mnist_mlp", mnist.Config(), topo.devices, 128)
     lowered = step.lower(state, batch)
     _assert_sharded_exchange(step, lowered, lowered.compile())
+
+
+def test_widedeep_cell_step_holds_no_table_shaped_scratch_on_a_v5e_chip(topo):
+    """The benchmark's ``criteo_widedeep`` shapes (650,000 buckets a feature,
+    batch 1,024) through the TPU compiler: the shape rule takes the
+    touched-rows pass, whose program holds the tables and their
+    accumulators (4.46 GB, donated and written in place) and scratch of
+    under a hundredth of one table.  The full pass held a whole table of
+    scratch, the dense gradient (PERF.md, PR 23)."""
+    from tensorflowonspark_tpu.models import widedeep
+
+    config = widedeep.Config(hash_buckets=650_000)
+    assert widedeep.update_touches_rows(config.total_buckets, 1024 * 26)
+    step, state, batch = abstract_train_step(
+        "wide_deep", config, topo.devices[:1], 1024)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    table_bytes = config.total_buckets * config.embed_dim * 4
+    assert stats.temp_size_in_bytes < table_bytes / 100
+    assert stats.alias_size_in_bytes > 2 * table_bytes  # updated in place
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):

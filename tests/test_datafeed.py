@@ -2,6 +2,9 @@
 
 import os
 import queue
+import random
+import threading
+import time
 import types
 
 import numpy as np
@@ -395,6 +398,131 @@ def test_terminate_unlinks_drained_shm_descriptors():
     feed = DataFeed(mgr, input_mapping=["x", "y"])
     feed.terminate()
     assert not os.path.exists(os.path.join("/dev/shm", ref.name))
+
+
+# -- terminate() against a pump that is waiting on the input queue ---------
+# (the regime of a trainer faster than its feeder: ISSUE 31)
+
+
+def _id_chunk(k, rows=8, transport="pickle"):
+    """Chunk ``k`` of a stream of consecutive ids: (feature, id) rows."""
+    ids = range(k * rows, (k + 1) * rows)
+    return shm.encode_chunk([(np.float32(i), i) for i in ids],
+                            transport=transport)
+
+
+class _HeldQueue(queue.Queue):
+    """An input queue whose consumers the test tells apart: the pump asks
+    with no timeout, ``terminate()``'s drain with one.  After its first, a
+    pump's ``get`` is *pending*: it returns the next item only after the
+    drain has taken one."""
+
+    def __init__(self):
+        super().__init__()
+        self.pump_waiting = threading.Event()
+        self.drained_one = threading.Event()
+        self.pump_got = threading.Event()
+        self._free_gets = 1
+
+    def get(self, block=True, timeout=None):
+        if timeout is None:                          # the pump
+            if self._free_gets:
+                self._free_gets -= 1
+                return super().get(block, timeout)
+            self.pump_waiting.set()
+            assert self.drained_one.wait(10)
+            try:
+                return super().get(block, timeout)
+            finally:
+                self.pump_got.set()
+        item = super().get(block, timeout)
+        if not self.drained_one.is_set():            # the drain's first
+            self.drained_one.set()
+            assert self.pump_got.wait(10)
+        return item
+
+
+@pytest.mark.parametrize("transport", ["pickle", "shm"])
+def test_terminate_stages_nothing_the_pumps_pending_get_receives(transport):
+    """The drain takes chunk *k*, the pump's pending ``get`` then returns
+    chunk *k+1*: the pump must drop it (its segment unlinked) and stage
+    nothing, or a batch with a hole in it reaches the ``device_put``
+    callback after ``terminate()`` began."""
+    if transport == "shm" and not shm.shm_available():
+        pytest.skip("/dev/shm unavailable")
+    mgr = FakeMgr()
+    q = mgr._queues["input"] = _HeldQueue()
+    staged = []
+
+    def stage(batch):
+        staged.append(np.asarray(batch["y"]).copy())
+        return batch
+
+    feed = DataFeed(mgr, input_mapping=["x", "y"], prefetch=2)
+    q.put(_id_chunk(0, transport=transport))
+    first = feed.next_batch(8, device_put=stage)
+    assert list(first["y"]) == list(range(8))
+    assert q.pump_waiting.wait(10)          # the pump's get is pending
+    refs = [_id_chunk(k, transport=transport) for k in (1, 2)]
+    for ref in refs:
+        q.put(ref)
+    feed.terminate()                        # takes chunk 1; the pump gets 2
+    feed._pf_thread.join(10)
+    assert not feed._pf_thread.is_alive()   # the pump ended
+    assert [list(ids) for ids in staged] == [list(range(8))]
+    if transport == "shm":
+        for ref in refs:
+            assert not os.path.exists(os.path.join("/dev/shm", ref.name))
+    assert feed.should_stop() and feed.next_batch(8, device_put=stage) == {}
+
+
+def test_terminate_at_random_phases_never_stages_a_hole():
+    """A slow feeder (a chunk of consecutive ids every 0.3 ms), a consumer
+    faster than it, ``terminate()`` at a random phase, 300 times: what
+    reached the ``device_put`` callback is always ids ``0..n-1`` with no
+    gap.  On the code before the repair 31 to 61 of 300 such trials staged
+    chunk *k+1* after the drain had taken chunk *k* (this box, PR 31)."""
+    class QuickDrain(queue.Queue):
+        """``terminate()``'s one-second quiet rule, shortened."""
+
+        def get(self, block=True, timeout=None):
+            return super().get(block, None if timeout is None else 0.01)
+
+    rng = random.Random(31)
+    holes = []
+    for trial in range(300):
+        mgr = FakeMgr()
+        q = mgr._queues["input"] = QuickDrain()
+        staged = []
+        stop = threading.Event()
+
+        def feeder():
+            k = 0
+            while not stop.is_set():
+                q.put(_id_chunk(k))
+                k += 1
+                time.sleep(0.0003)
+
+        def stage(batch):
+            staged.append(np.asarray(batch["y"]))
+            return batch
+
+        feed = DataFeed(mgr, input_mapping=["x", "y"], prefetch=2)
+        thread = threading.Thread(target=feeder, daemon=True)
+        thread.start()
+        deadline = time.perf_counter() + rng.uniform(0.002, 0.008)
+        while time.perf_counter() < deadline:
+            feed.next_batch(8, device_put=stage)
+        threading.Timer(0.004, stop.set).start()  # the feeder outlives it
+        feed.terminate()
+        stop.set()
+        thread.join(10)
+        assert not thread.is_alive()
+        time.sleep(0.002)                   # a pump mid-stage finishes
+        ids = np.concatenate(staged) if staged else np.zeros(0, np.int64)
+        if not np.array_equal(ids, np.arange(len(ids))):
+            holes.append((trial, ids.tolist()))
+    assert not holes, holes[:3]
 
 
 def test_prefetch_rejects_changed_batch_size():

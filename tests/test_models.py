@@ -202,6 +202,242 @@ def test_widedeep_embedding_step(table_update):
     assert t2.optimizer is explicit
 
 
+# -- the default table update's two executions (PR 30) ------------------------
+
+
+def _table_state(trainer) -> dict:
+    cols = trainer.state.collections
+    return {k: np.asarray(v)
+            for k, v in {**cols["embedding"], **cols["embedding_opt"]}.items()}
+
+
+def _table_step_counts() -> tuple:
+    from tensorflowonspark_tpu import obs
+
+    return (obs.counter("table_update_rows_steps_total").value,
+            obs.counter("table_update_full_steps_total").value)
+
+
+def _cat_batch(kind, cfg, batch_size=16):
+    """A batch whose ids have no duplicates in a column, are one id, or
+    follow a Zipf law (a few hot ids and a tail)."""
+    from tensorflowonspark_tpu.models import widedeep
+
+    rng = np.random.RandomState(5)
+    batch = widedeep.example_batch(cfg, batch_size=batch_size)
+    if kind == "no_duplicates":
+        cat = np.stack([rng.permutation(cfg.hash_buckets)[:batch_size]
+                        for _ in range(widedeep.NUM_CAT)], axis=1)
+    elif kind == "one_id":
+        cat = np.full((batch_size, widedeep.NUM_CAT), 7)
+    else:
+        cat = np.minimum(rng.zipf(1.3, (batch_size, widedeep.NUM_CAT)) - 1,
+                         cfg.hash_buckets - 1)
+    batch["cat"] = cat.astype(np.int32)
+    return batch
+
+
+def test_widedeep_default_is_dense_and_sparse_is_still_accepted():
+    """``"dense"`` and ``"sparse"`` name the two AdaGrad variants; how the
+    default executes is no option (the benchmark's configuration pins the
+    string and refuses another default)."""
+    import dataclasses
+
+    from tensorflowonspark_tpu.models import widedeep
+
+    assert widedeep.Config().table_update == "dense"
+    assert [f.name for f in dataclasses.fields(widedeep.Config)] == [
+        "hash_buckets", "embed_dim", "hidden", "dtype", "table_dtype",
+        "table_lr", "table_update"]
+    Trainer("wide_deep", config=dataclasses.replace(
+        widedeep.Config.tiny(), table_update="sparse"),
+        mesh_config=MeshConfig(dp=8))
+    with pytest.raises(ValueError, match="dense|sparse"):
+        Trainer("wide_deep", config=dataclasses.replace(
+            widedeep.Config.tiny(), table_update="rows"),
+            mesh_config=MeshConfig(dp=8))
+
+
+@pytest.mark.parametrize("rows,ids,touched", [
+    (16_900_000, 26_624, True),    # the benchmark's cell: 635 rows an id
+    (2_600_000, 106_496, False),   # the program's defaults: 24
+    (1_300, 416, False),           # the tiny config of these tests: 3
+    (26_624 * 1_000, 26_624, True),
+    (26_624, 26_624, False),
+])
+def test_widedeep_update_rule_is_a_function_of_static_shapes(rows, ids,
+                                                             touched):
+    from tensorflowonspark_tpu.models import widedeep
+
+    assert widedeep.update_touches_rows(rows, ids) is touched
+    # both sides of the threshold itself
+    at = widedeep.ROWS_PER_ID_CROSSOVER * ids
+    assert widedeep.update_touches_rows(at, ids) is True
+    assert widedeep.update_touches_rows(at - 1, ids) is False
+
+
+@pytest.mark.parametrize("kind", ["no_duplicates", "one_id", "zipf"])
+def test_widedeep_rows_pass_is_the_full_pass(kind, monkeypatch):
+    """The same state and batch through both executions of the default
+    update: losses, tables and accumulators agree to float32 rounding and
+    the rows the batch never looked up are bit-identical to the start."""
+    from tensorflowonspark_tpu.models import widedeep
+
+    cfg = widedeep.Config.tiny()
+    batch = _cat_batch(kind, cfg)
+    runs = {}
+    for name, crossover in (("full", 10 ** 9), ("rows", 0)):
+        monkeypatch.setattr(widedeep, "ROWS_PER_ID_CROSSOVER", crossover)
+        t = Trainer("wide_deep", config=cfg, mesh_config=MeshConfig(dp=8),
+                    seed=3)
+        start = _table_state(t)
+        before = _table_step_counts()
+        losses = [float(t.step(batch)) for _ in range(3)]
+        after = _table_step_counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (
+            (3, 0) if name == "rows" else (0, 3))
+        runs[name] = (losses, _table_state(t))
+    np.testing.assert_allclose(runs["rows"][0], runs["full"][0], rtol=1e-6)
+    ids = np.asarray(widedeep.fold_ids(batch["cat"], cfg)).reshape(-1)
+    untouched = np.setdiff1d(np.arange(cfg.total_buckets), ids)
+    for key, full in runs["full"][1].items():
+        rows = runs["rows"][1][key]
+        np.testing.assert_allclose(rows, full, rtol=1e-5, atol=1e-7)
+        np.testing.assert_array_equal(rows[untouched], start[key][untouched])
+        assert not np.array_equal(rows[ids[0]], start[key][ids[0]])
+
+
+def test_widedeep_rows_pass_vocab_sharded_matches_replicated():
+    """A table large enough for its batch takes the touched-rows pass by
+    the shape rule alone; on ``dp=2, tp=4`` with vocab-sharded tables it
+    gives the replicated run's losses and tables."""
+    import dataclasses
+
+    from tensorflowonspark_tpu.models import widedeep
+
+    cfg = dataclasses.replace(widedeep.Config.tiny(), hash_buckets=8_000)
+    batch = _cat_batch("zipf", cfg, batch_size=8)
+    ids_a_step = batch["cat"].size
+    assert widedeep.update_touches_rows(cfg.total_buckets // 4, ids_a_step)
+
+    before = _table_step_counts()
+    t_tp = Trainer("wide_deep", config=cfg,
+                   mesh_config=MeshConfig(dp=2, tp=4), seed=3)
+    assert t_tp.state.collections["embedding"]["deep"].sharding.spec[0] == "tp"
+    t_rep = Trainer("wide_deep", config=cfg, mesh_config=MeshConfig(dp=8),
+                    seed=3)
+    for _ in range(4):
+        np.testing.assert_allclose(float(t_tp.step(batch)),
+                                   float(t_rep.step(batch)), rtol=1e-5)
+    after = _table_step_counts()
+    assert (after[0] - before[0], after[1] - before[1]) == (8, 0)
+    rep = _table_state(t_rep)
+    for key, tp in _table_state(t_tp).items():
+        np.testing.assert_allclose(tp, rep[key], rtol=1e-5, atol=1e-7)
+    assert t_tp.state.collections["embedding"]["deep"].sharding.spec[0] == "tp"
+
+
+def test_widedeep_rows_step_holds_no_table_shaped_temporary():
+    """The compiled touched-rows step allocates nothing of a table's shape
+    (the full pass holds the dense gradient: a whole table of scratch)."""
+    import dataclasses
+
+    import jax
+
+    from tensorflowonspark_tpu.models import widedeep
+
+    cfg = dataclasses.replace(widedeep.Config.tiny(), hash_buckets=8_000)
+    table_bytes = cfg.total_buckets * cfg.embed_dim * 4
+    t = Trainer("wide_deep", config=cfg, devices=jax.devices()[:1])
+    temp = {}
+    for batch_size in (8, 2_048):  # 1,000 and 4 table rows an id
+        staged = t.shard(_cat_batch("zipf", cfg, batch_size=batch_size))
+        compiled = t.train_step.lower(t.state, staged).compile()
+        temp[batch_size] = compiled.memory_analysis().temp_size_in_bytes
+    assert widedeep.update_touches_rows(cfg.total_buckets, 8 * 26)
+    assert not widedeep.update_touches_rows(cfg.total_buckets, 2_048 * 26)
+    assert temp[8] < table_bytes / 4, temp
+    assert temp[2_048] >= table_bytes, temp
+
+
+def test_widedeep_rows_step_names_its_table_lookups_forward():
+    """The touched-rows step takes the gradient w.r.t. the gathered rows,
+    so its table lookups sit outside the differentiated function and carry
+    no ``jvp(``; the ``forward`` scope keeps them in the forward pass for a
+    profile's reader (the benchmark's ``device_forward_ms``), as the full
+    pass's lookups are.  The accumulators' lookups are the optimizer's."""
+    import dataclasses
+    import re
+
+    import jax
+
+    from tensorflowonspark_tpu.models import widedeep
+
+    cfg = dataclasses.replace(widedeep.Config.tiny(), hash_buckets=8_000)
+    t = Trainer("wide_deep", config=cfg, devices=jax.devices()[:1])
+    staged = t.shard(_cat_batch("zipf", cfg, batch_size=8))
+    hlo = t.train_step.lower(t.state, staged).compile().as_text()
+    lookups = re.findall(r' gather\(.*op_name="([^"]*)"', hlo)
+    forward = [n for n in lookups if re.search(r"(^|/)forward(/|$)", n)]
+    assert len(lookups) == 4 and len(forward) == 2, lookups
+    assert not any("jvp(" in n for n in lookups)
+
+
+def _crossover_tool():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "table_update_crossover.py")
+    spec = importlib.util.spec_from_file_location("crossover_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def test_widedeep_crossover_constant_is_what_its_measurements_fit():
+    """The four chip measurements written beside ROWS_PER_ID_CROSSOVER,
+    through the tool's own fit: the constant lies where the two executions'
+    fitted costs meet."""
+    from tensorflowonspark_tpu.models import widedeep
+
+    measured = [  # buckets, batch, rows pass ms, full pass ms (PR 30)
+        (650_000, 1_024, 9.97, 24.70), (650_000, 4_096, 40.19, 39.43),
+        (100_000, 1_024, 9.63, 7.92), (100_000, 4_096, 39.71, 21.80)]
+    got = _crossover_tool().fit([
+        {"buckets": b, "batch": n, "rows_ms": r, "full_ms": f}
+        for b, n, r, f in measured])
+    assert 1.1 < got["full_ns_a_table_row"] < 1.3
+    assert 350 < got["rows_ns_an_id"] < 400
+    assert abs(got["rows_per_id_crossover"]
+               - widedeep.ROWS_PER_ID_CROSSOVER) < 10
+
+
+def test_widedeep_crossover_tool_forces_each_execution(capsys):
+    """``tools/table_update_crossover.py`` at a toy size: each side's run
+    takes that side (the counters say), the constant is put back, and a
+    line a shape and the fit come out."""
+    import json
+
+    from tensorflowonspark_tpu.models import widedeep
+
+    tool = _crossover_tool()
+    before = _table_step_counts()
+    assert tool.main(["--shapes", "50x8,400x8,50x32", "--steps", "2",
+                      "--repeats", "1"]) == 0
+    after = _table_step_counts()
+    # a shape and a side: 3 warm steps + 1 repeat of 2
+    assert (after[0] - before[0], after[1] - before[1]) == (15, 15)
+    assert widedeep.ROWS_PER_ID_CROSSOVER == 160
+    lines = [json.loads(line)
+             for line in capsys.readouterr().out.splitlines()[1:]]
+    assert [(r["buckets"], r["batch"], r["rule_takes"])
+            for r in lines[:3]] == [(50, 8, "full"), (400, 8, "full"),
+                                    (50, 32, "full")]
+    assert all(r["rows_ms"] > 0 and r["full_ms"] > 0 for r in lines[:3])
+    assert "rows_per_id_crossover" in lines[3]
+
+
 def test_bert_pipeline_parallel_matches_sequential():
     """config.pp_stages > 1: the stacked GPipe trunk on a pp mesh produces
     the same forward as the identical params run sequentially (pp=1 mesh),

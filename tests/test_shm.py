@@ -39,15 +39,23 @@ def _segments(foreign=False):
 
 def _gauges():
     """``shm.update_gauges()`` less what other test files hold right now:
-    the gauges count the whole host, as they should."""
-    count, nbytes = shm.update_gauges()
-    for f in _segments(foreign=True):
+    the gauges count the whole host, as they should.  The scan and the
+    listing of the others' segments are two looks at a directory that the
+    other workers' feeds keep changing, so a reading counts only if the
+    others' segments were the same before and after the scan (a segment
+    that came between the two once read as a count of -1: PR 31's whole
+    run)."""
+    for _ in range(200):
+        theirs = _segments(foreign=True)
+        count, nbytes = shm.update_gauges()
         try:
-            nbytes -= os.stat(os.path.join("/dev/shm", f)).st_size
+            sizes = [os.stat(os.path.join("/dev/shm", f)).st_size
+                     for f in theirs]
         except OSError:
-            continue  # consumed since the listing: not in the scan either
-        count -= 1
-    return count, nbytes
+            continue  # one was consumed since the listing
+        if _segments(foreign=True) == theirs:
+            return count - len(theirs), nbytes - sum(sizes)
+    raise AssertionError("the other test files' segments never held still")
 
 
 @pytest.fixture(autouse=True)
