@@ -29,6 +29,7 @@ _REGISTRY = {
     "wide_deep": "tensorflowonspark_tpu.models.widedeep",
     "bert": "tensorflowonspark_tpu.models.bert",
     "tiny_lm": "tensorflowonspark_tpu.models.tinylm",
+    "granite_hybrid": "tensorflowonspark_tpu.models.granite_hybrid",
 }
 
 

@@ -1,0 +1,625 @@
+"""Hybrid state-space / attention decoder (the ``granitemoehybrid`` layout
+with no routed experts): Mamba-2 mixers, a NoPE GQA layer among them,
+SwiGLU feed-forwards, Granite's four multipliers, trained on packed rows.
+
+Published shape: ``ibm-granite/granite-4.0-h-micro`` ``config.json``; the
+state-space mixer is Mamba-2's SSD (Dao & Gu, arXiv:2405.21060).  For a row
+of tokens ``u`` with segment ids ``s`` (the document's number inside the
+row; documents are contiguous and their ids differ)::
+
+    x = embedding_multiplier * E[u]
+    x = x + residual_multiplier * mixer_i(rms(x))          # every layer i
+    x = x + residual_multiplier * W_down(silu(h W_gate) * (h W_up)), h = rms(x)
+    logits = rms(x) @ E.T / logits_scaling                  # tied head
+
+    mamba:      [z | xBC | dt] = h W_in
+                xBC = silu(conv(xBC) + b)     # causal, depthwise, a tap that
+                                              # would reach another document
+                                              # reads zero
+                [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
+                S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T    # S = 0 entering a
+                y_t = S_t C_t + D x_t                         # document
+                out = rms_w(y * silu(z)) W_out                # norm a group
+    attention:  softmax(attention_multiplier * q k^T) v, no positional
+                encoding, mask ``j <= i and s_j == s_i``
+
+Three places honour document boundaries: the convolution's look-back, the
+recurrent state and the attention mask.  The recurrence runs in the chunked
+(SSD) form (:func:`ssd_scan`), the state carried between chunks in float32.
+Parameters are float32; activations are ``Config.dtype``.  Every layer is
+recomputed in the backward pass (``jax.checkpoint``), attention runs a block
+of queries at a time and the training loss a block of tokens at a time, so
+a row of 8,192 tokens at the published widths trains on one chip beside 16
+bytes of state a parameter; none of the three is an option.
+
+``jax.named_scope`` names a device trace can be cut by: ``ssm_mixer`` (the
+whole mixer) > ``ssm_conv``, ``ssm_scan``; ``attention``; ``mlp``;
+``lm_head``.
+
+The flax module only registers the parameters (a flat dict, as
+``tinylm``'s); the mathematics is in pure functions over that dict.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import numpy as np
+
+#: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
+SEQUENCE_AXES: dict = {}
+
+#: the recipe :func:`make_optimizer` builds (a continued-pre-training AdamW)
+ADAMW = {"b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1}
+
+#: one period of the published pattern: nine state-space layers to one
+#: attention layer, the attention layer sixth
+PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+#: the published model is four periods (attention at 5, 15, 25 and 35)
+PUBLISHED_LAYERS = PERIOD * 4
+
+
+@dataclasses.dataclass(frozen=True)
+class Config:
+    vocab_size: int = 100352        # rows of the vocabulary held here
+    hidden_size: int = 2048
+    layer_types: tuple = PUBLISHED_LAYERS
+    intermediate_size: int = 8192   # the shared SwiGLU MLP
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+    rms_norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    seq_len: int = 8192             # tokens a packed row
+    attention_block: int = 256      # queries scored at a time
+    loss_block: int = 2048          # tokens whose logits are held at a time
+
+    @classmethod
+    def tiny(cls) -> "Config":
+        return cls(vocab_size=64, hidden_size=32,
+                   layer_types=("mamba", "mamba", "attention", "mamba"),
+                   intermediate_size=64, num_attention_heads=4,
+                   num_key_value_heads=2, mamba_n_heads=4, mamba_d_head=16,
+                   mamba_d_state=8, mamba_chunk_size=8, dtype="float32",
+                   seq_len=32, attention_block=16, loss_block=16)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+
+def leaf_shapes(config: Config) -> dict:
+    """Name -> shape of every parameter, in forward order."""
+    d, f = config.hidden_size, config.intermediate_size
+    out = {"embed": (config.vocab_size, d)}
+    for i, kind in enumerate(config.layer_types):
+        p = f"l{i:02d}_"
+        out[p + "norm1"] = (d,)
+        if kind == "mamba":
+            out[p + "in_proj"] = (d, config.d_inner + config.conv_dim
+                                  + config.mamba_n_heads)
+            out[p + "conv_w"] = (config.mamba_d_conv, config.conv_dim)
+            out[p + "conv_b"] = (config.conv_dim,)
+            out[p + "dt_bias"] = (config.mamba_n_heads,)
+            out[p + "A_log"] = (config.mamba_n_heads,)
+            out[p + "D"] = (config.mamba_n_heads,)
+            out[p + "gate_norm"] = (config.d_inner,)
+            out[p + "out_proj"] = (config.d_inner, d)
+        elif kind == "attention":
+            kv = config.num_key_value_heads * config.head_dim
+            out[p + "wq"] = (d, d)
+            out[p + "wk"] = (d, kv)
+            out[p + "wv"] = (d, kv)
+            out[p + "wo"] = (d, d)
+        else:
+            raise ValueError(f"layer {i}: unknown type {kind!r}")
+        out[p + "norm2"] = (d,)
+        out[p + "mlp_gate"] = (d, f)
+        out[p + "mlp_up"] = (d, f)
+        out[p + "mlp_down"] = (f, d)
+    out["final_norm"] = (d,)
+    return out
+
+
+def parameter_count(config: Config) -> int:
+    return sum(int(np.prod(s)) for s in leaf_shapes(config).values())
+
+
+# ---------------------------------------------------------------------------
+# The mathematics, over the flat parameter dict, one row at a time
+# ---------------------------------------------------------------------------
+
+
+def _rms(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    return (y * w).astype(x.dtype)
+
+
+def _mm(spec, a, b, dtype, out=None):
+    """A product with operands in ``dtype``, accumulated in float32."""
+    import jax.numpy as jnp
+
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32
+                      ).astype(out or dtype)
+
+
+def _block(total: int, want: int) -> int:
+    """The largest divisor of ``total`` that is at most ``want``."""
+    return next(b for b in range(min(want, total), 0, -1) if total % b == 0)
+
+
+def causal_conv(xbc, w, b, seg):
+    """Depthwise causal convolution over a packed row: ``y_t = b + sum_j
+    w[K-1-j] * x_{t-j}`` over the taps ``j < K`` whose token ``t-j`` is in
+    ``t``'s document.  ``xbc`` (T, C), ``w`` (K, C), ``seg`` (T,)."""
+    import jax.numpy as jnp
+
+    taps, t = w.shape[0], xbc.shape[0]
+    x32 = xbc.astype(jnp.float32)
+    y = x32 * w[taps - 1] + b
+    for j in range(1, min(taps, t)):
+        back = jnp.pad(x32[:-j], ((j, 0), (0, 0)))
+        same = jnp.pad(seg[:-j], (j, 0), constant_values=-1) == seg
+        y = y + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - j]
+    return y
+
+
+def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
+    """The selective state-space recurrence of one packed row in the
+    chunked (SSD) form: ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T`` with
+    ``S = 0`` entering a document, ``y_t = S_t C_t``.
+
+    ``x`` (T, H, P), ``dt`` (T, H) float32, ``a`` (H,) float32 negative,
+    ``b`` and ``c`` (T, G, N) with H a multiple of G, ``seg`` (T,).  Inside
+    a chunk of ``chunk`` tokens the recurrence is a masked product (every
+    decay an ``exp`` of a difference of within-chunk sums, float32); the
+    state crosses chunks in a ``lax.scan``, float32.  Products take
+    operands in ``dtype``.  ``T`` need not be a multiple of ``chunk``: the
+    row is padded with a document of its own.  Returns (T, H, P) float32.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    t, heads, p = x.shape
+    groups, n = b.shape[1:]
+    rep = heads // groups
+    pad = (-t) % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                       for v in (x, dt, b, c))
+        seg = jnp.pad(seg, (0, pad), constant_values=-1)
+    nc = (t + pad) // chunk
+    segc = seg.reshape(nc, chunk)
+    # within-chunk running sums of the log decay, (nc, H, Q)
+    cs = jnp.cumsum((dt * a).reshape(nc, chunk, heads), axis=1
+                    ).transpose(0, 2, 1)
+    xdt = (x.astype(f32) * dt[..., None]).reshape(nc, chunk, groups, rep, p)
+    bc = b.reshape(nc, chunk, groups, n)
+    cc = c.reshape(nc, chunk, groups, n)
+
+    # inside a chunk: y_i += sum_{j<=i, same document} exp(cs_i - cs_j)
+    #                          (C_i . B_j) dt_j x_j
+    mask = ((segc[:, :, None] == segc[:, None, :])
+            & jnp.tril(jnp.ones((chunk, chunk), bool)))
+    decay = jnp.exp(jnp.where(mask[:, None],
+                              cs[..., :, None] - cs[..., None, :], -jnp.inf))
+    scores = _mm("cign,cjgn->cgij", cc, bc, dtype, out=f32)
+    weights = scores[:, :, None] * decay.reshape(nc, groups, rep, chunk, chunk)
+    y = _mm("cgrij,cjgrp->cigrp", weights, xdt, dtype, out=f32)
+
+    # what a chunk leaves behind: its tokens of the last token's document,
+    # decayed to the chunk's end
+    last = segc[:, -1]
+    to_end = jnp.exp(cs[..., -1:] - cs) * (segc == last[:, None])[:, None, :]
+    left = _mm("cjgn,cjgrp->cgrpn", bc,
+               xdt * to_end.transpose(0, 2, 1).reshape(
+                   nc, chunk, groups, rep, 1), dtype, out=f32)
+    # ... which the next chunk receives if it ends in the same document
+    through = jnp.exp(cs[..., -1]) * jnp.concatenate(
+        [jnp.zeros((1,), bool), last[1:] == last[:-1]])[:, None]
+
+    def cross(state, inp):
+        keep, new = inp
+        return state * keep[..., None, None] + new, state
+
+    _, entering = jax.lax.scan(
+        cross, jnp.zeros(left.shape[1:], f32),
+        (through.reshape(nc, groups, rep), left))
+    # a token reads the entering state if no document began before it
+    before = jnp.concatenate([jnp.full((1,), -2, seg.dtype), last[:-1]])
+    from_start = jnp.exp(cs) * (segc == before[:, None])[:, None, :]
+    y = y + (_mm("cign,cgrpn->cigrp", cc, entering, dtype, out=f32)
+             * from_start.transpose(0, 2, 1).reshape(
+                 nc, chunk, groups, rep, 1))
+    return y.reshape(t + pad, heads, p)[:t]
+
+
+def mamba_mixer(params, prefix: str, h, seg, config: Config):
+    """The Mamba-2 mixer on one row: ``h`` (T, D) -> (T, D)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, dtype = jnp.float32, h.dtype
+    heads, p = config.mamba_n_heads, config.mamba_d_head
+    groups, n = config.mamba_n_groups, config.mamba_d_state
+    d_inner, t = config.d_inner, h.shape[0]
+    w_in = params[prefix + "in_proj"]
+    zx = _mm("td,de->te", h, w_in[:, :d_inner + config.conv_dim], dtype)
+    # the step sizes stay float32 from the product on: exp(dt A) is taken
+    dt = _mm("td,dh->th", h, w_in[:, d_inner + config.conv_dim:], dtype,
+             out=f32)
+    z, xbc = zx[:, :d_inner], zx[:, d_inner:]
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(causal_conv(
+            xbc, params[prefix + "conv_w"], params[prefix + "conv_b"], seg
+        )).astype(dtype)
+    x = xbc[:, :d_inner].reshape(t, heads, p)
+    b = xbc[:, d_inner:d_inner + groups * n].reshape(t, groups, n)
+    c = xbc[:, d_inner + groups * n:].reshape(t, groups, n)
+    dt = jax.nn.softplus(dt + params[prefix + "dt_bias"])
+    with jax.named_scope("ssm_scan"):
+        y = ssd_scan(x, dt, -jnp.exp(params[prefix + "A_log"]), b, c, seg,
+                     config.mamba_chunk_size, dtype)
+    y = y + params[prefix + "D"][:, None] * x.astype(f32)
+    y = y.reshape(t, d_inner) * jax.nn.silu(z.astype(f32))
+    # the gated norm: a group's channels share one mean square
+    y = y.reshape(t, groups, d_inner // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + config.rms_norm_eps)
+    y = (y.reshape(t, d_inner) * params[prefix + "gate_norm"]).astype(dtype)
+    return _mm("te,ed->td", y, params[prefix + "out_proj"], dtype)
+
+
+def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
+    """One block of queries against one block of keys: the scaled scores
+    (kv, rep, i, j) and the mask ``j <= i and same document``."""
+    import jax.numpy as jnp
+
+    s = _mm("ikrd,jkd->krij", qb, kb, dtype, out=jnp.float32) * scale
+    return s, (pq[:, None] >= pk[None, :]) & (sq[:, None] == sk[None, :])
+
+
+def _attend_fwd(q, k, v, seg, scale, size, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    t, kv, rep, hd = q.shape
+    n = t // size
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
+
+    def block(args):
+        qb, sq, pq, i = args
+
+        def keys(j, carry):
+            m, l, acc = carry
+            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
+                              dtype)
+            m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, -1e30), -1))
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            fade = jnp.exp(m - m_new)
+            return (m_new, l * fade + p.sum(-1), acc * fade[..., None]
+                    + _mm("krij,jkd->krid", p, vb[j], dtype, out=f32))
+
+        m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
+            jnp.full((kv, rep, size), -1e30, f32),
+            jnp.zeros((kv, rep, size), f32),
+            jnp.zeros((kv, rep, size, hd), f32)))
+        return ((acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype),
+                m + jnp.log(l))
+
+    out, lse = jax.lax.map(block, (
+        q.reshape(n, size, kv, rep, hd), segb, posb, jnp.arange(n)))
+    return out.reshape(t, kv, rep, hd), lse
+
+
+def _attend_bwd(scale, size, dtype, saved, d_out):
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    q, k, v, seg, out, lse = saved
+    t, kv, rep, hd = q.shape
+    n = t // size
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
+    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
+
+    def block(carry, args):
+        qb, dob, sq, pq, i, lse_b, delta_b = args
+
+        def keys(j, inner):
+            dq, dk, dv = inner
+            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
+                              dtype)
+            p = jnp.where(mask, jnp.exp(s - lse_b[..., None]), 0.0)
+            dp = _mm("ikrd,jkd->krij", dob, vb[j], dtype, out=f32)
+            ds = p * (dp - delta_b[..., None]) * scale
+            return (dq + _mm("krij,jkd->ikrd", ds, kb[j], dtype, out=f32),
+                    dk.at[j].add(_mm("krij,ikrd->jkd", ds, qb, dtype,
+                                     out=f32)),
+                    dv.at[j].add(_mm("krij,ikrd->jkd", p, dob, dtype,
+                                     out=f32)))
+
+        dq, dk, dv = jax.lax.fori_loop(
+            0, i + 1, keys, (jnp.zeros(qb.shape, f32),) + carry)
+        return (dk, dv), dq.astype(dtype)
+
+    with jax.named_scope("attention"):
+        (dk, dv), dq = jax.lax.scan(
+            block, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)), (
+                q.reshape(n, size, kv, rep, hd),
+                d_out.reshape(n, size, kv, rep, hd), segb, posb,
+                jnp.arange(n), lse,
+                delta.reshape(n, size, kv, rep).transpose(0, 2, 3, 1)))
+    return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
+            np.zeros(seg.shape, jax.dtypes.float0))
+
+
+@functools.lru_cache(maxsize=None)
+def _attend():
+    """The blocked attention with its own backward pass (made once: the
+    module imports JAX only when it is used)."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+    def attend(q, k, v, seg, scale, size, dtype):
+        return _attend_fwd(q, k, v, seg, scale, size, dtype)[0]
+
+    def fwd(q, k, v, seg, scale, size, dtype):
+        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype)
+        return out, (q, k, v, seg, out, lse)
+
+    attend.defvjp(fwd, _attend_bwd)
+    return attend
+
+
+def document_attention(q, k, v, seg, scale: float, size: int, dtype):
+    """Causal attention inside documents over one packed row, blocks of
+    ``size`` queries against blocks of ``size`` keys with a running softmax:
+    a block of queries visits the blocks of keys up to its own, so no score
+    above the diagonal is ever made and none is held beyond its block.  The
+    backward pass recomputes each block's probabilities from the saved
+    log-sum-exp.  Every row costs the same whatever its documents are (the
+    blocks of another document are visited and masked): a step's time does
+    not depend on the data.  ``q`` (T, kv, rep, hd), ``k`` and ``v``
+    (T, kv, hd), ``seg`` (T,); returns (T, kv, rep, hd)."""
+    return _attend()(q, k, v, seg, scale, size, dtype)
+
+
+def attention(params, prefix: str, h, seg, config: Config):
+    """Grouped-query attention without positional encoding on one row:
+    ``h`` (T, D) -> (T, D)."""
+    dtype, t = h.dtype, h.shape[0]
+    kv, hd = config.num_key_value_heads, config.head_dim
+    rep = config.num_attention_heads // kv
+    q = _mm("td,de->te", h, params[prefix + "wq"], dtype)
+    k = _mm("td,de->te", h, params[prefix + "wk"], dtype).reshape(t, kv, hd)
+    v = _mm("td,de->te", h, params[prefix + "wv"], dtype).reshape(t, kv, hd)
+    o = document_attention(q.reshape(t, kv, rep, hd), k, v, seg,
+                           config.attention_multiplier,
+                           _block(t, config.attention_block), dtype)
+    return _mm("te,ed->td", o.reshape(t, kv * rep * hd),
+               params[prefix + "wo"], dtype)
+
+
+def mlp(params, prefix: str, h):
+    import jax
+    import jax.numpy as jnp
+
+    dtype = h.dtype
+    gate = _mm("td,df->tf", h, params[prefix + "mlp_gate"], dtype)
+    up = _mm("td,df->tf", h, params[prefix + "mlp_up"], dtype)
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
+    return _mm("tf,fd->td", act, params[prefix + "mlp_down"], dtype)
+
+
+def hidden_states(params, tokens, seg, config: Config):
+    """One row's final hidden states before the last norm: (T, D)."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(config.dtype)
+    res, eps = config.residual_multiplier, config.rms_norm_eps
+
+    def layer(kind, prefix, lp, x, seg):
+        h = _rms(x, lp[prefix + "norm1"], eps)
+        if kind == "mamba":
+            with jax.named_scope("ssm_mixer"):
+                x = x + res * mamba_mixer(lp, prefix, h, seg, config)
+        else:
+            with jax.named_scope("attention"):
+                x = x + res * attention(lp, prefix, h, seg, config)
+        with jax.named_scope("mlp"):
+            return x + res * mlp(lp, prefix,
+                                 _rms(x, lp[prefix + "norm2"], eps))
+
+    x = (config.embedding_multiplier
+         * jnp.take(params["embed"], tokens, axis=0)).astype(dtype)
+    for i, kind in enumerate(config.layer_types):
+        prefix = f"l{i:02d}_"
+        mine = {k: v for k, v in params.items() if k.startswith(prefix)}
+        x = jax.checkpoint(layer, static_argnums=(0, 1))(
+            kind, prefix, mine, x, seg)
+    return x
+
+
+def _logits(params, x, config: Config):
+    import jax.numpy as jnp
+
+    h = _rms(x, params["final_norm"], config.rms_norm_eps)
+    return _mm("td,vd->tv", h, params["embed"], h.dtype,
+               out=jnp.float32) / config.logits_scaling
+
+
+def apply_tokens(params, tokens, segment_ids, config: Config):
+    """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
+    float32 logits."""
+    import jax
+
+    def row(u, s):
+        x = hidden_states(params, u, s, config)
+        with jax.named_scope("lm_head"):
+            return _logits(params, x, config)
+
+    return jax.vmap(row)(tokens, segment_ids)
+
+
+def loss_sums(params, tokens, segment_ids, config: Config):
+    """``(sum of the cross-entropies, positions counted)`` of a batch of
+    packed rows: position ``t`` is scored against ``u_{t+1}`` where that is
+    the same document's.  The logits exist a block of tokens at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    def row(u, s):
+        x = hidden_states(params, u, s, config)
+        t = x.shape[0]
+        target = jnp.roll(u, -1)
+        valid = (jnp.roll(s, -1) == s) & (jnp.arange(t) < t - 1)
+        size = _block(t, config.loss_block)
+
+        def block(args):
+            xb, ub, vb = args
+            logits = _logits(params, xb, config)
+            picked = jnp.take_along_axis(logits, ub[:, None], axis=1)[:, 0]
+            nll = jax.nn.logsumexp(logits, axis=-1) - picked
+            return jnp.sum(jnp.where(vb, nll, 0.0))
+
+        with jax.named_scope("lm_head"):
+            sums = jax.lax.map(jax.checkpoint(block), (
+                x.reshape(t // size, size, -1), target.reshape(-1, size),
+                valid.reshape(-1, size)))
+        return jnp.sum(sums), jnp.sum(valid)
+
+    total, count = jax.vmap(row)(tokens, segment_ids)
+    return jnp.sum(total), jnp.sum(count)
+
+
+# ---------------------------------------------------------------------------
+# The zoo's surface
+# ---------------------------------------------------------------------------
+
+
+def _initializers(config: Config) -> dict:
+    """Mamba-2's published defaults: ``A_log = log(uniform[1, 16])``,
+    ``dt_bias`` the inverse softplus of log-uniform [1e-3, 1e-1], ``D = 1``,
+    the convolution as PyTorch's ``Conv1d`` leaves it, normal(0, 0.02)
+    matrices, unit norms."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    def a_log(key, shape, dtype):
+        return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+    def dt_bias(key, shape, dtype):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype,
+                                        math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    def conv(key, shape, dtype):
+        bound = 1.0 / math.sqrt(config.mamba_d_conv)
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    ones = nn.initializers.ones
+    by_leaf = {"A_log": a_log, "dt_bias": dt_bias, "D": ones,
+               "conv_w": conv, "conv_b": conv, "norm1": ones, "norm2": ones,
+               "gate_norm": ones, "final_norm": ones}
+    return {name: by_leaf.get(name[4:] if name[1:3].isdigit() else name,
+                              nn.initializers.normal(0.02))
+            for name in leaf_shapes(config)}
+
+
+def make_model(config: Config, mesh=None):
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    shapes, inits = leaf_shapes(config), _initializers(config)
+
+    class GraniteHybrid(nn.Module):
+        @nn.compact
+        def __call__(self, tokens, segment_ids):
+            params = {name: self.param(name, inits[name], shape, jnp.float32)
+                      for name, shape in shapes.items()}
+            return apply_tokens(params, tokens, segment_ids, config)
+
+    return GraniteHybrid()
+
+
+def make_optimizer(config: Config, learning_rate: float):
+    import optax
+
+    return optax.adamw(learning_rate, **ADAMW)
+
+
+def make_loss_fn(module, config: Config):
+    """Mean next-token cross-entropy over the positions whose next token is
+    the same document's (the targets are the inputs shifted left)."""
+    import jax.numpy as jnp
+
+    def loss_fn(params, batch):
+        total, count = loss_sums(params, batch["tokens"],
+                                 batch["segment_ids"], config)
+        return total / jnp.maximum(count, 1)
+
+    return loss_fn
+
+
+def make_forward_fn(module, config: Config):
+    def forward(params, batch):
+        return apply_tokens(params, batch["tokens"], batch["segment_ids"],
+                            config)
+
+    return forward
+
+
+def batch_counters(batch) -> dict:
+    """What one step's host batch adds to the program's counters: tokens,
+    tokens that bear a loss (the next token is the same document's) and
+    documents (runs of one segment id)."""
+    seg = np.asarray(batch["segment_ids"])
+    same = seg[:, 1:] == seg[:, :-1]
+    return {"lm_tokens_total": int(seg.size),
+            "lm_loss_tokens_total": int(same.sum()),
+            "lm_documents_total": int(seg.shape[0] + (~same).sum())}
+
+
+def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
+                  seq_len: int | None = None):
+    """Packed rows of two documents each, ``2 * chunk`` tokens unless
+    ``seq_len`` says otherwise (a step compiles at the shape it is fed)."""
+    rng = np.random.RandomState(seed)
+    t = int(seq_len or min(config.seq_len, 2 * config.mamba_chunk_size))
+    cut = rng.randint(1, t, size=(batch_size, 1))
+    return {"tokens": rng.randint(0, config.vocab_size,
+                                  size=(batch_size, t)).astype(np.int32),
+            "segment_ids": (np.arange(t)[None, :] >= cut).astype(np.int32)}

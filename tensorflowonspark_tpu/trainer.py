@@ -22,6 +22,7 @@ from __future__ import annotations
 import logging
 import os
 import time
+import weakref
 from typing import Any
 
 import numpy as np
@@ -100,6 +101,12 @@ class Trainer:
         if self.mesh.shape.get("sp", 1) <= 1:
             self.sequence_axes = {}
         self.loss_fn = self.module_lib.make_loss_fn(self.model, self.config)
+        # a model module may count what a step's host batch holds (a
+        # language model's tokens and documents): ``batch_counters(batch)
+        # -> {counter: n}``, added to the registry once a step
+        self._batch_counters = getattr(self.module_lib, "batch_counters",
+                                       None)
+        self._staged_counts: dict = {}
         self.forward_fn = self.module_lib.make_forward_fn(self.model, self.config)
 
         # example batch sized to the data-parallel world so the compiled
@@ -250,7 +257,16 @@ class Trainer:
     # -- stepping ------------------------------------------------------------
 
     def shard(self, batch):
-        return shard_batch(self.mesh, batch, self.sequence_axes)
+        staged = shard_batch(self.mesh, batch, self.sequence_axes)
+        if self._batch_counters is not None and isinstance(
+                _first_leaf(batch), np.ndarray):
+            # counted here, where the batch is still the host's; the counts
+            # wait under the staged batch's first array until ``step`` is
+            # handed it, and go with that array if it never is
+            first = _first_leaf(staged)
+            self._staged_counts[id(first)] = self._batch_counters(batch)
+            weakref.finalize(first, self._staged_counts.pop, id(first), None)
+        return staged
 
     def add_step_callback(self, fn) -> None:
         """Register ``fn(loss, examples, dt)`` to run after every step.
@@ -291,7 +307,7 @@ class Trainer:
         device-resident arrays is ~free — sharing the name would
         bimodalize that histogram toward zero."""
         with obs.span("trainer.shard") as sh:
-            staged = self.shard(batch)
+            staged = shard_batch(self.mesh, batch, self.sequence_axes)
         with obs.span("trainer.dispatch") as run:
             self.state, loss = self.train_step(self.state, staged)
             if wait:
@@ -318,6 +334,13 @@ class Trainer:
         obs.counter("trainer_steps_total").inc()
         if n:
             obs.counter("trainer_examples_total").inc(n)
+        if self._batch_counters is not None:
+            first = _first_leaf(batch)
+            counts = (self._batch_counters(batch)
+                      if isinstance(first, np.ndarray)
+                      else self._staged_counts.get(id(first)))
+            for name, value in (counts or {}).items():
+                obs.counter(name).inc(value)
         if dt > 0:
             obs.histogram("trainer_step_seconds").observe(dt)
         # wall-clock heartbeat for the driver's stall detector
@@ -547,6 +570,14 @@ class Trainer:
         self.state = TrainState(restored["params"], restored["opt_state"],
                                 restored["step"],
                                 restored.get("collections", {}))
+
+
+def _first_leaf(batch):
+    """The batch's first array: NumPy's while the batch is the host's, a
+    ``jax.Array`` once it is staged; None for an empty batch."""
+    import jax
+
+    return next(iter(jax.tree_util.tree_leaves(batch)), None)
 
 
 def _batch_examples(batch) -> int:

@@ -59,8 +59,11 @@ def topo():
     cc.reset_cache()
 
 
-def abstract_train_step(model_name, config, devices, batch_size):
-    """``Trainer.__init__``'s wiring over ``devices`` with shapes for arrays.
+def abstract_train_step(model_name, config, devices, batch_size,
+                        **example_kw):
+    """``Trainer.__init__``'s wiring over ``devices`` with shapes for arrays
+    (``example_kw`` goes to the model's ``example_batch`` for the batch's
+    shapes: a language model's ``seq_len``).
 
     Returns ``(step, state, batch)``: the real compiled-step factory's
     output (the model's own where it supplies one, as wide&deep does;
@@ -78,7 +81,9 @@ def abstract_train_step(model_name, config, devices, batch_size):
     lib = model_zoo.get_model(model_name)
     mesh = build_mesh(None, devices=devices)
     model = lib.make_model(config, mesh=mesh)
-    optimizer = optax.adamw(1e-3)  # Trainer's default
+    make_opt = getattr(lib, "make_optimizer", None)
+    optimizer = (make_opt(config, 1e-3) if make_opt
+                 else optax.adamw(1e-3))  # Trainer's default
     init_args = _model_inputs(lib.example_batch(config, batch_size=2))
 
     def init():
@@ -94,7 +99,7 @@ def abstract_train_step(model_name, config, devices, batch_size):
     state = jax.eval_shape(make_state)
     batch = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct((batch_size,) + a.shape[1:], a.dtype),
-        lib.example_batch(config, batch_size=2))
+        lib.example_batch(config, batch_size=2, **example_kw))
     make_custom = getattr(lib, "make_sharded_train_step", None)
     if make_custom is not None:
         step = make_custom(model, config, optimizer, mesh, param_shardings,
@@ -219,6 +224,38 @@ def test_widedeep_cell_step_holds_no_table_shaped_scratch_on_a_v5e_chip(topo):
     assert stats.temp_size_in_bytes < table_bytes / 100
     assert stats.alias_size_in_bytes > 2 * table_bytes  # updated in place
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.slow  # ~60 s here; the builder's by-hand rehearsal
+def test_granite_published_width_step_fits_one_v5e_chip(topo):
+    """The ``granite_4_0_h_micro`` configuration as the benchmark builds it
+    (one period of the published widths, an eighth of the vocabulary,
+    772,160,448 float32 parameters under AdamW) on one packed row of 8,192
+    tokens, through the TPU compiler: parameters and both moments are
+    donated and updated in place, and arguments plus temporaries stay under
+    the chip's memory with room for the staged batches.  PERF.md section 4
+    holds the figures."""
+    import json
+
+    from benchmark.configs.granite_4_0_h_micro import program
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "granite_4_0_h_micro", "config.json")) as f:
+        published = json.load(f)
+    config = program.model_config(published)
+    step, state, batch = abstract_train_step(
+        "granite_hybrid", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 772_160_448
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"granite_4_0_h_micro, one described chip: {stats}")
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    # the whole gradient (4 bytes a parameter) is never held at once
+    assert stats.temp_size_in_bytes < 4 * published["parameters"]
+    assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
 
 
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):

@@ -1,0 +1,574 @@
+"""The hybrid state-space / attention decoder (``models/granite_hybrid.py``)
+against the plain reference of the ``granite_4_0_h_micro`` configuration, at
+``Config.tiny()`` in float32 on the CPU; the packed-row traffic generator and
+the configuration's operation counts.
+
+Tolerances: both sides compute in float32 with products at the highest
+precision, so they differ only by the order of their sums (the program's
+chunked scan and running softmax against the reference's token-by-token
+recurrence and whole softmax): 2e-5 relative to the largest entry covers what
+a few hundred float32 additions in another order move, and is 1,000 times
+tighter than a forgotten boundary or multiplier would need.
+"""
+
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.configs.granite_4_0_h_micro import program, reference, work
+from benchmark.traffic import packed_documents
+from tensorflowonspark_tpu.models import granite_hybrid as gh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG_DIR = os.path.join(REPO, "benchmark", "configs", "granite_4_0_h_micro")
+BIG_SEED = 2 ** 31 + 12345          # the driver's seeds pass 32 signed bits
+TOL = 2e-5
+
+
+def _published():
+    with open(os.path.join(CONFIG_DIR, "config.json")) as f:
+        return json.load(f)
+
+
+def _tiny_dict(config: gh.Config, learning_rate=1e-3) -> dict:
+    """``Config.tiny()`` under the keys the configuration's file has."""
+    return {
+        # the list is longer than the depth, as the published one is
+        "layer_types": list(config.layer_types) + ["attention", "mamba"],
+        "num_hidden_layers": len(config.layer_types),
+        "hidden_size": config.hidden_size,
+        "shared_intermediate_size": config.intermediate_size,
+        "vocab_size": config.vocab_size,
+        "num_attention_heads": config.num_attention_heads,
+        "num_key_value_heads": config.num_key_value_heads,
+        "mamba_n_heads": config.mamba_n_heads,
+        "mamba_d_head": config.mamba_d_head,
+        "mamba_d_state": config.mamba_d_state,
+        "mamba_n_groups": config.mamba_n_groups,
+        "mamba_d_conv": config.mamba_d_conv,
+        "mamba_chunk_size": config.mamba_chunk_size,
+        "embedding_multiplier": config.embedding_multiplier,
+        "residual_multiplier": config.residual_multiplier,
+        "attention_multiplier": config.attention_multiplier,
+        "logits_scaling": config.logits_scaling,
+        "rms_norm_eps": config.rms_norm_eps, "dtype": config.dtype,
+        "seq_len": config.seq_len, "parameters": gh.parameter_count(config),
+        "optimizer": dict(gh.ADAMW, name="adamw",
+                          learning_rate=learning_rate),
+    }
+
+
+def _rows(config: gh.Config, n: int, seed: int) -> dict:
+    """Packed rows of three or four documents of uneven length."""
+    rng = np.random.default_rng(seed)
+    t = config.seq_len
+    seg = np.stack([np.searchsorted(
+        np.sort(rng.choice(np.arange(1, t), size=3, replace=False)),
+        np.arange(t), side="right") for _ in range(n)]).astype(np.int32)
+    return {"tokens": rng.integers(0, config.vocab_size, (n, t), np.int32),
+            "segment_ids": seg}
+
+
+def _close(got, want, tol=TOL):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.abs(want).max()), 1e-30)
+    assert float(np.abs(got - want).max()) <= tol * scale, (
+        float(np.abs(got - want).max()), scale)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    config = gh.Config.tiny()
+    ref_config = _tiny_dict(config)
+    weights = reference.make_weights(ref_config, BIG_SEED)
+    params = {program.program_name(k): v for k, v in weights.items()}
+    return config, ref_config, weights, params
+
+
+@pytest.fixture(scope="module", autouse=True)
+def exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _reference_loss(weights, batch, ref_config):
+    tokens, seg = jnp.asarray(batch["tokens"]), jnp.asarray(batch["segment_ids"])
+
+    def row(u, s):
+        x = reference.embed(weights["embed"], u, ref_config)
+        for i, kind in enumerate(reference.layer_types(ref_config)):
+            x = reference.layer(kind, reference._layer_leaves(weights, i), x,
+                                s, ref_config)
+        return reference.loss_sum(x, weights["embed"], weights["final_norm"],
+                                  u, s, ref_config)
+
+    counted = (seg[:, 1:] == seg[:, :-1]).sum()
+    return jax.vmap(row)(tokens, seg).sum() / counted
+
+
+# ---------------------------------------------------------------------------
+# program against reference
+# ---------------------------------------------------------------------------
+
+
+def test_granite_logits_loss_and_every_leafs_gradient_match_the_reference(tiny):
+    config, ref_config, weights, params = tiny
+    batch = _rows(config, 2, 1)
+    _close(gh.apply_tokens(params, batch["tokens"], batch["segment_ids"],
+                           config),
+           reference.forward(weights, jnp.asarray(batch["tokens"]),
+                             jnp.asarray(batch["segment_ids"]), ref_config))
+    loss, grads = jax.jit(jax.value_and_grad(gh.make_loss_fn(None, config)))(
+        params, batch)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w, b: _reference_loss(w, b, ref_config)))(weights, batch)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    assert set(grads) == {program.program_name(k) for k in want}
+    for name, g in want.items():
+        assert float(jnp.abs(g).max()) > 0, name    # every leaf is trained
+        _close(grads[program.program_name(name)], g)
+
+
+def test_granite_trainer_follows_the_reference_for_three_adamw_steps(tiny):
+    """Through ``Trainer``: the seeded weights loaded a leaf at a time, three
+    steps, then the losses, the first gradient's norms as AdamW's first
+    moment shows them, and every parameter.  After three steps of AdamW a
+    difference of 1e-6 in a gradient whose second moment is still tiny can
+    move an update by its whole size (lr 1e-3 of a leaf of order 1e-2), so
+    the parameters are held to 1e-3 of their largest entry; the change's
+    norm, which the benchmark compares, to 1e-3."""
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config, ref_config, _, _ = tiny
+    trainer = Trainer("granite_hybrid", config=config, learning_rate=1e-3,
+                      devices=jax.devices()[:1])      # the cell's one chip
+    names = program.load_weights(trainer, ref_config, reference, BIG_SEED)
+    batches = [_rows(config, 2, 10 + i) for i in range(3)]
+    losses = []
+    for i, batch in enumerate(batches):
+        losses.append(float(trainer.step(program.host_batch(dict(batch)))))
+        if i == 0:
+            grad_norms = program.first_gradient_norms(trainer, ref_config,
+                                                      names)
+    theirs = reference.follow(ref_config, BIG_SEED, batches)
+    np.testing.assert_allclose(losses, theirs["losses"], rtol=1e-5)
+    assert losses[2] < losses[0]
+    for name in names:
+        assert grad_norms[name] == pytest.approx(
+            theirs["grad_norms"][name], rel=1e-4), name
+    weights = reference.make_weights(ref_config, BIG_SEED)
+    state = {"mu": {k: jnp.zeros_like(v) for k, v in weights.items()},
+             "nu": {k: jnp.zeros_like(v) for k, v in weights.items()},
+             "count": 0}
+    first = {k: np.asarray(v) for k, v in weights.items()}
+    for batch in batches:
+        reference.train_step(weights, state, batch, ref_config)
+    mine = program.parameters(trainer, ref_config, names)
+    for name in names:
+        _close(mine[name], weights[name], tol=1e-3)
+        change = float(np.linalg.norm(np.asarray(mine[name]) - first[name]))
+        assert change == pytest.approx(theirs["change_norms"][name],
+                                       rel=1e-3), name
+
+
+def test_granite_bfloat16_activations_stay_near_the_float32_reference(tiny):
+    """The configuration's own precision at the tiny size: bfloat16 keeps 8
+    bits, and a loss near log(64) moves by well under a hundredth of itself."""
+    import dataclasses
+
+    config, ref_config, weights, params = tiny
+    batch = _rows(config, 2, 3)
+    loss = gh.make_loss_fn(None, dataclasses.replace(
+        config, dtype="bfloat16"))(params, batch)
+    assert float(loss) == pytest.approx(
+        float(_reference_loss(weights, batch, ref_config)), rel=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# the chunked scan against the recurrence, token by token
+# ---------------------------------------------------------------------------
+
+
+def _scan_inputs(t=40, heads=4, p=3, groups=2, n=5, seed=0):
+    rng = np.random.default_rng(seed)
+    f = lambda a: jnp.asarray(a, jnp.float32)          # noqa: E731
+    # documents of 7, 13, 1, 11 and 8 tokens: no chunk size divides them all
+    seg = np.repeat(np.arange(5), [7, 13, 1, 11, 8]).astype(np.int32)[:t]
+    return (f(rng.normal(size=(t, heads, p))),
+            f(rng.uniform(0.01, 0.5, size=(t, heads))),
+            f(-rng.uniform(1, 16, size=(heads,))),
+            f(rng.normal(size=(t, groups, n))),
+            f(rng.normal(size=(t, groups, n))), jnp.asarray(seg))
+
+
+@pytest.fixture(scope="module")
+def by_token():
+    """The recurrence token by token (the reference's), and its gradients."""
+    x, dt, a, b, c, seg = inputs = _scan_inputs()
+    weigh = jnp.asarray(np.random.default_rng(1).normal(size=x.shape),
+                        jnp.float32)
+
+    def run(x, dt, a, b, c):
+        y = reference.recurrence(x, dt, a, b, c, seg)
+        return jnp.sum(y * weigh), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2, 3, 4), has_aux=True))(x, dt, a, b, c)
+    return inputs, weigh, y, grads
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 7, 8, 16, 40, 64])
+def test_granite_chunked_scan_is_the_token_recurrence(by_token, chunk):
+    """Forward and the gradient to every operand, for chunks that divide the
+    row (1, 4, 8, 40), that do not (7, 16: the row is padded), that hold
+    whole documents and that cut them, and for one chunk longer than the
+    row."""
+    (x, dt, a, b, c, seg), weigh, want_y, want = by_token
+
+    def chunked(x, dt, a, b, c):
+        y = gh.ssd_scan(x, dt, a, b, c, seg, chunk, jnp.float32)
+        return jnp.sum(y * weigh), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        chunked, (0, 1, 2, 3, 4), has_aux=True))(x, dt, a, b, c)
+    _close(y, want_y)
+    for g, w in zip(grads, want):
+        _close(g, w)
+
+
+@pytest.mark.parametrize("size", [4, 16, 48])
+@pytest.mark.parametrize("documents", ["one", "uneven", "every_four",
+                                       "ids_not_rising"])
+def test_granite_document_attention_is_masked_softmax_attention(size, documents):
+    t, kv, rep, hd = 48, 2, 3, 5
+    rng = np.random.default_rng(2)
+    seg = jnp.asarray({
+        "one": np.zeros(t), "uneven": np.sort(rng.integers(0, 6, t)),
+        "every_four": np.repeat(np.arange(12), 4),
+        "ids_not_rising": np.array([7] * 5 + [3] * 30 + [9] * 13),
+    }[documents].astype(np.int32))
+    q, k, v, weigh = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in (
+        (t, kv, rep, hd), (t, kv, hd), (t, kv, hd), (t, kv, rep, hd)))
+
+    def dense(q, k, v):
+        at = jnp.arange(t)
+        mask = (at[:, None] >= at[None, :]) & (seg[:, None] == seg[None, :])
+        w = jax.nn.softmax(jnp.where(
+            mask, 0.3 * jnp.einsum("ikrd,jkd->krij", q, k), -jnp.inf), -1)
+        return jnp.sum(jnp.einsum("krij,jkd->ikrd", w, v) * weigh)
+
+    def blocked(q, k, v):
+        return jnp.sum(gh.document_attention(q, k, v, seg, 0.3, size,
+                                             jnp.float32) * weigh)
+
+    got, grads = jax.value_and_grad(blocked, (0, 1, 2))(q, k, v)
+    want, want_grads = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(grads, want_grads):
+        _close(g, w)
+
+
+# ---------------------------------------------------------------------------
+# packing, the vocabulary's slice, the depth cut
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("side", ["program", "reference"])
+def test_granite_packed_documents_do_not_see_each_other(tiny, side):
+    """Each document's logits inside a packed row are those of the document
+    alone: the convolution's look-back, the recurrent state and the
+    attention mask all stop at its first token."""
+    config, ref_config, weights, params = tiny
+    # documents of 5, 11, 5 and 11 tokens: neither the scan's chunk (8) nor
+    # attention's block (16) ends where a document does
+    batch = {"tokens": _rows(config, 1, 5)["tokens"],
+             "segment_ids": np.repeat(np.arange(4), [5, 11, 5, 11])[None]
+             .astype(np.int32)}
+
+    @jax.jit
+    def logits(tokens, seg):
+        if side == "program":
+            return gh.apply_tokens(params, tokens, seg, config)[0]
+        return reference.forward(weights, tokens, seg, ref_config)[0]
+
+    packed = logits(batch["tokens"], batch["segment_ids"])
+    seg = batch["segment_ids"][0]
+    for doc in sorted(set(seg.tolist())):
+        mine = np.flatnonzero(seg == doc)
+        alone = logits(batch["tokens"][:, mine], np.zeros((1, len(mine)),
+                                                          np.int32))
+        _close(packed[mine], alone)
+    whole = logits(batch["tokens"], np.zeros_like(batch["segment_ids"]))
+    second = np.flatnonzero(seg == 1)
+    assert float(jnp.abs(whole[second] - packed[second]).max()) > 1e-4
+
+
+def test_granite_vocabulary_slice_gives_the_whole_models_columns(tiny):
+    """A chip that holds the first rows of the tied embedding computes, for
+    ids inside its slice, the same columns of the logits as the whole
+    vocabulary's model."""
+    import dataclasses
+
+    config, _, _, params = tiny
+    held = config.vocab_size // 4
+    sliced = dict(params, embed=params["embed"][:held])
+    batch = _rows(config, 2, 7)
+    tokens = batch["tokens"] % held
+    whole = gh.apply_tokens(params, tokens, batch["segment_ids"], config)
+    part = gh.apply_tokens(sliced, tokens, batch["segment_ids"],
+                           dataclasses.replace(config, vocab_size=held))
+    assert part.shape[-1] == held
+    _close(part, whole[..., :held])
+
+
+def test_granite_depth_cut_keeps_the_first_periods_layer_types():
+    published = _published()
+    model = program.model_config(published)
+    assert len(published["layer_types"]) == 40
+    assert list(model.layer_types) == published["layer_types"][:10]
+    assert model.layer_types == gh.PERIOD
+    assert [i for i, k in enumerate(model.layer_types)
+            if k == "attention"] == [5]
+    assert tuple(published["layer_types"]) == gh.PUBLISHED_LAYERS
+    assert reference.layer_types(published) == list(gh.PERIOD)
+    assert work.layer_types(published) == list(gh.PERIOD)
+    # every width is the published one
+    assert (model.hidden_size, model.intermediate_size, model.d_inner,
+            model.conv_dim, model.head_dim, model.mamba_d_state) == (
+                2048, 8192, 4096, 4352, 64, 128)
+    assert model.vocab_size * 8 == published["published"]["vocab_size"]
+    for key in ("num_hidden_layers", "vocab_size", "dataset"):
+        assert key in published["reduced"]
+
+
+def test_granite_parameter_count_is_the_configurations():
+    published = _published()
+    shapes = reference.leaf_shapes(published)
+    count = sum(int(np.prod(s)) for s, _ in shapes.values())
+    assert count == published["parameters"] == 772_160_448
+    assert gh.parameter_count(program.model_config(published)) == count
+    assert {program.program_name(k): tuple(s) for k, (s, _) in shapes.items()
+            } == gh.leaf_shapes(program.model_config(published))
+    # by hand: a mamba layer, the attention layer, embedding and final norm
+    mamba = (2048 * 8512 + 4 * 4352 + 4352 + 3 * 64 + 4096 + 4096 * 2048
+             + 3 * 2048 * 8192 + 2 * 2048)
+    attn = 2 * 2048 * 2048 + 2 * 2048 * 512 + 3 * 2048 * 8192 + 2 * 2048
+    assert (mamba, attn) == (76_182_976, 60_821_504)
+    assert count == 9 * mamba + attn + 12_544 * 2048 + 2048
+
+
+def test_granite_seeded_leaves_can_be_made_again_one_at_a_time(tiny):
+    _, ref_config, weights, _ = tiny
+    again = reference.make_leaf(ref_config, BIG_SEED, "l01/in_proj")
+    np.testing.assert_array_equal(again, weights["l01/in_proj"])
+    other = reference.make_leaf(ref_config, BIG_SEED + 1, "l01/in_proj")
+    assert float(jnp.abs(other - again).max()) > 0
+    dt = jax.nn.softplus(weights["l00/dt_bias"])
+    assert 1e-3 <= float(dt.min()) and float(dt.max()) <= 1e-1 * 1.0001
+    a = jnp.exp(weights["l00/A_log"])
+    assert 1.0 <= float(a.min()) and float(a.max()) <= 16.0
+
+
+# ---------------------------------------------------------------------------
+# counters, traffic, operation counts
+# ---------------------------------------------------------------------------
+
+
+def test_granite_step_counts_tokens_loss_tokens_and_documents(tiny):
+    """``lm_loss_tokens_total`` a step is the count of positions whose next
+    token is in the same document, whether the step is handed the host's
+    batch or one staged ahead with ``Trainer.shard``."""
+    from tensorflowonspark_tpu import obs
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config = tiny[0]
+    trainer = Trainer("granite_hybrid", config=config,
+                      devices=jax.devices()[:1])
+
+    def totals():
+        counters = obs.get_registry().snapshot()["counters"]
+        return np.array([counters.get(k, 0) for k in (
+            "lm_tokens_total", "lm_loss_tokens_total", "lm_documents_total",
+            "trainer_steps_total")])
+
+    batch = _rows(config, 2, 9)
+    seg = batch["segment_ids"]
+    want = [seg.size, int((seg[:, 1:] == seg[:, :-1]).sum()),
+            sum(len(set(row.tolist())) for row in seg), 1]
+    assert want[1] == seg.size - want[2]
+    before = totals()
+    trainer.step(batch)
+    np.testing.assert_array_equal(totals() - before, want)
+    staged = trainer.shard(batch)
+    trainer.shard(_rows(config, 2, 11))     # staged ahead, never stepped
+    before = totals()
+    trainer.step(staged)
+    np.testing.assert_array_equal(totals() - before, want)
+    del staged
+    import gc
+
+    gc.collect()
+    assert not trainer._staged_counts       # the counts went with the arrays
+
+
+TRAFFIC = {"records": 12, "shards": 4, "seq_len": 256, "vocab": 500,
+           "zipf_s": 1.0, "doc_median": 40, "doc_sigma": 1.2, "doc_min": 4,
+           "doc_max": 256}
+
+
+def test_granite_packed_rows_are_made_again_as_they_were_written(tmp_path):
+    from tensorflowonspark_tpu import tfrecord
+
+    made = packed_documents.generate(TRAFFIC, BIG_SEED, str(tmp_path / "a"))
+    again = packed_documents.generate(TRAFFIC, BIG_SEED, str(tmp_path / "b"))
+    other = packed_documents.generate(TRAFFIC, BIG_SEED + 1,
+                                      str(tmp_path / "c"))
+    read = lambda d: [open(p, "rb").read() for p in sorted(   # noqa: E731
+        glob.glob(d["glob"]))]
+    assert read(made) == read(again) and read(made) != read(other)
+    assert made["records"] == 12 and len(read(made)) == 4
+    parse = program.tfrecord_parse_fn({})
+    seen = {}
+    for path in sorted(glob.glob(made["glob"])):
+        for payload in tfrecord.read_records(path, verify=True):
+            row = parse(payload)
+            seen[int(row["id"])] = row
+    assert sorted(seen) == list(range(12))
+    rows = packed_documents.rows(TRAFFIC, BIG_SEED, [3, 11])
+    batch = program.host_batch({k: np.stack([seen[i][k] for i in (3, 11)])
+                                for k in ("tokens", "segment_ids")})
+    for key in ("tokens", "segment_ids"):
+        assert batch[key].dtype == np.int32
+        np.testing.assert_array_equal(batch[key], rows[key])
+
+
+def test_granite_packed_rows_are_whole_documents_cut_at_the_rows_end():
+    params = dict(TRAFFIC, seq_len=8192, vocab=12544, doc_median=600,
+                  doc_min=16, doc_max=8192)
+    rows = packed_documents.rows(params, BIG_SEED, range(48))
+    seg, tokens = rows["segment_ids"], rows["tokens"]
+    assert seg.shape == tokens.shape == (48, 8192)
+    assert (seg[:, 0] == 0).all() and (np.diff(seg, axis=1) >= 0).all()
+    assert (np.diff(seg, axis=1) <= 1).all()        # numbered in order
+    documents = seg.max(axis=1) + 1
+    assert 4 <= documents.mean() <= 10              # six or seven a row
+    lengths = np.concatenate([np.bincount(r)[:-1] for r in seg])    # uncut
+    assert lengths.min() >= 16 and 300 <= np.median(lengths) <= 900
+    assert 0 <= tokens.min() and tokens.max() < 12544
+    counts = np.bincount(tokens.ravel(), minlength=12544)
+    top = np.sort(counts)[::-1].astype(np.float64)
+    slope = np.polyfit(np.log(np.arange(1, 51)), np.log(top[:50]), 1)[0]
+    assert slope == pytest.approx(-1.0, abs=0.1)    # Zipf s = 1
+    assert counts.argmax() != 0                     # a seeded permutation
+
+
+def test_granite_operation_counts_match_the_hand_worked_figures(tiny):
+    published = _published()
+    in_proj, out_proj, mlp = 2048 * 8512, 4096 * 2048, 3 * 2048 * 8192
+    attn = 2 * 2048 * 2048 + 2 * 2048 * 512
+    matmul = 9 * (in_proj + out_proj + mlp) + attn + mlp + 12_544 * 2048
+    assert work.matmul_parameters(published) == matmul == 771_883_008
+    step = work.step_work(published, 1)
+    assert step["flops"] == 6 * matmul * 8192
+    assert step["bytes"] == 2 * 4 * 8192 + 28 * 772_160_448
+    assert step["examples"] == 1
+    scan = work.scan_work(published, 1)
+    assert scan["flops"] == 15 * 64 * 64 * 128 * 8192 * 9
+    assert scan["bytes"] == ((5 * 4096 + 6 * 128) * 2 + 3 * 64 * 4) * 8192 * 9
+    # the tiny size, by hand: three mamba layers and one attention layer of
+    # width 32 (d_inner 64, conv 80, 4 heads), feed-forward 64, 64 ids
+    tiny_config = tiny[1]
+    mamba = 32 * (64 + 80 + 4) + 64 * 32
+    assert work.matmul_parameters(tiny_config) == (
+        3 * mamba + (2 * 32 * 32 + 2 * 32 * 16) + 4 * 3 * 32 * 64 + 64 * 32)
+    assert work.scan_work(tiny_config, 2) == {
+        "flops": 15 * 4 * 16 * 8 * 2 * 32 * 3,
+        "bytes": ((5 * 64 + 6 * 8) * 4 + 3 * 4 * 4) * 2 * 32 * 3}
+
+
+# ---------------------------------------------------------------------------
+# the cell's per-layer readers
+# ---------------------------------------------------------------------------
+
+FIXTURE_TRACE = os.path.join(REPO, "tests", "benchmark_checks", "fixtures",
+                             "tiny_spans_v5e.xplane.pb.gz")
+NEW_METRICS = ("ssm_scan_device_ms", "ssm_mixer_share_pct",
+               "attention_device_ms", "ssm_scan_roofline_pct",
+               "loss_tokens_per_s_chip")
+
+
+def _cell_run(**over):
+    from benchmark import peaks, spec
+
+    cell = spec.cell(spec.load(REPO), "granite_h_micro_packed_8k")
+    run = {"cell": cell, "notes": [], "peaks": peaks.PEAKS["TPU v5 lite"],
+           "trainer": {"window": {"steps": 50, "seconds": 30.0},
+                       "t_window_start": 0.0}, "driver": {}}
+    run.update(over)
+    return run
+
+
+def test_granite_device_scopes_cut_a_recorded_trace_by_scope_name():
+    """A recorded v5e trace of the tiny ResNet step: a scope's time is the
+    union of the operations whose ``op_name`` holds its name as a word."""
+    from benchmark import device_scopes, program_spans
+
+    out = device_scopes.reduce_xplane(
+        FIXTURE_TRACE, ["ResNet", "Conv_0", "Conv", "ssm_scan"])
+    whole = program_spans.reduce_xplane(FIXTURE_TRACE)
+    assert out["steps"] == whole["steps"] > 0
+    busy = sum(whole["phase_s"].values())
+    assert 0 < out["scope_s"]["Conv_0"] < out["scope_s"]["ResNet"] <= busy
+    assert out["scope_s"]["Conv"] == 0      # a word, not a prefix
+    assert out["scope_s"]["ssm_scan"] == 0  # a program without the scope
+    assert len(out["top_ops"]) == 10
+    assert out["top_ops"][0][2] >= out["top_ops"][-1][2] > 0
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_granite_reader_finds_nothing_where_the_program_wrote_nothing(metric):
+    """An untraced run, or a program with neither the scopes nor the
+    counters (the parent of the PR that added them): no value, no error."""
+    from benchmark import spec
+
+    reader = spec.module("benchmark", "metrics", metric)
+    assert reader.read(_cell_run(_program={
+        "spans": {}, "dropped": 0, "counters": None})) is None
+    assert reader.read(_cell_run(
+        _device_scopes={"steps": 5, "scope_s": {"ssm_scan": 0.0,
+                                                "ssm_mixer": 0.0,
+                                                "attention": 0.0},
+                        "top_ops": []},
+        _program={"spans": {}, "dropped": 0,
+                  "counters": {"n": {"counters": {}}}})) is None
+
+
+def test_granite_readers_turn_scope_seconds_and_counters_into_the_metrics():
+    from benchmark import spec
+
+    run = _cell_run(
+        _device_scopes={"steps": 5, "top_ops": [], "scope_s": {
+            "ssm_scan": 0.5, "ssm_mixer": 1.25, "attention": 0.2}},
+        _program={"spans": {}, "dropped": 0, "counters": {
+            "trainer": {"counters": {"lm_loss_tokens_total": 8180.0 * 60,
+                                     "trainer_steps_total": 60.0}},
+            "driver": {"counters": {}}}})
+    run["trainer"]["trace"] = {"steps": 5, "busy_s": 2.5}
+
+    def read(name):
+        return spec.module("benchmark", "metrics", name).read(run)
+
+    assert read("ssm_scan_device_ms") == pytest.approx(100.0)
+    assert read("attention_device_ms") == pytest.approx(40.0)
+    assert read("ssm_mixer_share_pct") == pytest.approx(50.0)
+    # the recurrence's bytes at 819 GB/s are its bound: 3.19 GB -> 3.89 ms
+    assert read("ssm_scan_roofline_pct") == pytest.approx(
+        100 * (3_189_768_192 / 819e9) / 0.1)
+    assert any("memory bound" in note for note in run["notes"])
+    assert read("loss_tokens_per_s_chip") == pytest.approx(8180 * 50 / 30.0)
