@@ -25,7 +25,13 @@ row; documents are contiguous and their ids differ)::
 
 Three places honour document boundaries: the convolution's look-back, the
 recurrent state and the attention mask.  The recurrence runs in the chunked
-(SSD) form (:func:`ssd_scan`), the state carried between chunks in float32.
+(SSD) form (:func:`ssd_scan`), the state carried between chunks in float32:
+on a TPU, at shapes that fill their tiles (the published ones do), as the
+Pallas kernels of ``ssd_pallas``, which keep a chunk's decay tile on the
+chip; on any other backend and at small shapes (``Config.tiny()``, the
+tests) as ``jnp`` code.  :func:`scan_runs_fused` is the rule, and a step
+counts which applied (``ssm_scan_fused_steps_total`` /
+``ssm_scan_plain_steps_total``).
 Parameters are float32; activations are ``Config.dtype``.  Every layer is
 recomputed in the backward pass (``jax.checkpoint``), attention runs a block
 of queries at a time and the training loss a block of tokens at a time, so
@@ -189,6 +195,27 @@ def causal_conv(xbc, w, b, seg):
     return y
 
 
+def _backend() -> str:
+    """The backend the process computes on (a compile test for a described
+    chip, on a CPU host, says "tpu" here)."""
+    import jax
+
+    return jax.default_backend()
+
+
+def scan_runs_fused(chunk: int, heads: int, p: int, groups: int,
+                    n: int) -> bool:
+    """How :func:`ssd_scan` executes: on the Pallas kernels of
+    ``ssd_pallas`` (True) or as ``jnp`` code (False).  Decided from what the
+    code can observe: the backend is a TPU and the chunk, the heads and the
+    state's widths fill the kernels' tiles (``ssd_pallas.fits``: the
+    published 256, 64 x 64, one group, 128 do; ``Config.tiny()``'s do
+    not)."""
+    from tensorflowonspark_tpu.models import ssd_pallas
+
+    return _backend() == "tpu" and ssd_pallas.fits(chunk, heads, p, groups, n)
+
+
 def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
     """The selective state-space recurrence of one packed row in the
     chunked (SSD) form: ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T`` with
@@ -201,6 +228,12 @@ def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
     state crosses chunks in a ``lax.scan``, float32.  Products take
     operands in ``dtype``.  ``T`` need not be a multiple of ``chunk``: the
     row is padded with a document of its own.  Returns (T, H, P) float32.
+
+    One algorithm, two executions (:func:`scan_runs_fused`): on a TPU, at
+    shapes that fill its tiles, the kernels of ``ssd_pallas`` compute the
+    same terms a (chunk, block of heads) tile at a time and never write a
+    chunks x heads x Q x Q tensor; anywhere else the ``jnp`` form below
+    runs, which is also the kernels' oracle.
     """
     import jax
     import jax.numpy as jnp
@@ -214,6 +247,10 @@ def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
         x, dt, b, c = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
                        for v in (x, dt, b, c))
         seg = jnp.pad(seg, (0, pad), constant_values=-1)
+    if scan_runs_fused(chunk, heads, p, groups, n):
+        from tensorflowonspark_tpu.models import ssd_pallas
+
+        return ssd_pallas.fused_scan(x, dt, a, b, c, seg, chunk, dtype)[:t]
     nc = (t + pad) // chunk
     segc = seg.reshape(nc, chunk)
     # within-chunk running sums of the log decay, (nc, H, Q)
@@ -602,15 +639,24 @@ def make_forward_fn(module, config: Config):
     return forward
 
 
-def batch_counters(batch) -> dict:
-    """What one step's host batch adds to the program's counters: tokens,
-    tokens that bear a loss (the next token is the same document's) and
-    documents (runs of one segment id)."""
+def batch_counters(batch, config: Config) -> dict:
+    """What one step adds to the program's counters.  From its host batch:
+    tokens, tokens that bear a loss (the next token is the same document's)
+    and documents (runs of one segment id).  From the rule its trace
+    applied (:func:`scan_runs_fused`): one step of the scan on the kernels
+    or as ``jnp`` code, the other named with 0 so that both are on the
+    record."""
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
+    scans = "mamba" in config.layer_types
+    fused = scans and scan_runs_fused(
+        config.mamba_chunk_size, config.mamba_n_heads, config.mamba_d_head,
+        config.mamba_n_groups, config.mamba_d_state)
     return {"lm_tokens_total": int(seg.size),
             "lm_loss_tokens_total": int(same.sum()),
-            "lm_documents_total": int(seg.shape[0] + (~same).sum())}
+            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
+            "ssm_scan_fused_steps_total": int(fused),
+            "ssm_scan_plain_steps_total": int(scans and not fused)}
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0,

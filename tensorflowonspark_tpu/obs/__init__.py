@@ -127,7 +127,11 @@ step — never per record, row or chunk):
   compiled step (``parallel/train.py``); counters
   ``table_update_rows_steps_total`` / ``table_update_full_steps_total``,
   one increment a wide&deep step, say which execution of the default
-  table update the step's shapes chose (``models/widedeep.py``).
+  table update the step's shapes chose (``models/widedeep.py``);
+  ``ssm_scan_fused_steps_total`` / ``ssm_scan_plain_steps_total``, one
+  increment a ``granite_hybrid`` step, say whether its state-space scan
+  ran on the Pallas kernels or as ``jnp`` code
+  (``models/granite_hybrid.py::scan_runs_fused``).
 
 Also instrumented: elastic regroups (``elastic``), serving
 (``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which

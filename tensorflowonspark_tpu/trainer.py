@@ -102,8 +102,9 @@ class Trainer:
             self.sequence_axes = {}
         self.loss_fn = self.module_lib.make_loss_fn(self.model, self.config)
         # a model module may count what a step's host batch holds (a
-        # language model's tokens and documents): ``batch_counters(batch)
-        # -> {counter: n}``, added to the registry once a step
+        # language model's tokens and documents) and how the step it
+        # compiled runs it: ``batch_counters(batch, config) -> {counter:
+        # n}``, added to the registry once a step
         self._batch_counters = getattr(self.module_lib, "batch_counters",
                                        None)
         self._staged_counts: dict = {}
@@ -264,7 +265,8 @@ class Trainer:
             # wait under the staged batch's first array until ``step`` is
             # handed it, and go with that array if it never is
             first = _first_leaf(staged)
-            self._staged_counts[id(first)] = self._batch_counters(batch)
+            self._staged_counts[id(first)] = self._batch_counters(
+                batch, self.config)
             weakref.finalize(first, self._staged_counts.pop, id(first), None)
         return staged
 
@@ -336,7 +338,7 @@ class Trainer:
             obs.counter("trainer_examples_total").inc(n)
         if self._batch_counters is not None:
             first = _first_leaf(batch)
-            counts = (self._batch_counters(batch)
+            counts = (self._batch_counters(batch, self.config)
                       if isinstance(first, np.ndarray)
                       else self._staged_counts.get(id(first)))
             for name, value in (counts or {}).items():

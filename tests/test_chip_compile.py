@@ -226,18 +226,65 @@ def test_widedeep_cell_step_holds_no_table_shaped_scratch_on_a_v5e_chip(topo):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_granite_scan_kernels_compile_at_the_published_widths(topo):
+    """The Pallas kernels of the state-space scan (``models/ssd_pallas.py``)
+    through the TPU's compiler at the shapes the benchmark runs — a row of
+    8,192 tokens, 64 heads of 64, state 128, chunks of 256, bfloat16 —
+    forward and gradient: interpret mode cannot refuse a misaligned slice
+    or a kernel that asks for too much fast memory, this does.  Compiled,
+    every kernel call still carries ``ssm_scan`` as a word of its
+    ``op_name`` (``benchmark/device_scopes.py`` finds the scan by it), and
+    nothing of shape chunks x heads x 256 x 256 (537 MB in float32) is
+    held: the temporaries are the states and ``y``."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import ssd_pallas
+
+    t, heads, p, n, chunk = 8192, 64, 64, 128, 256
+    assert ssd_pallas.fits(chunk, heads, p, 1, n)
+    one = SingleDeviceSharding(topo.devices[0])
+    bf = jnp.bfloat16
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((t, heads, p), bf), ((t, heads), jnp.float32),
+        ((heads,), jnp.float32), ((t, 1, n), bf), ((t, 1, n), bf),
+        ((t,), jnp.int32))]
+
+    def loss(x, dt, a, b, c, seg):
+        with jax.named_scope("ssm_scan"):
+            y = ssd_pallas.fused_scan(x, dt, a, b, c, seg, chunk, bf)
+        return jnp.sum(y * y)
+
+    for fn, kernels in ((loss, 2), (jax.grad(loss, (0, 1, 2, 3, 4)), 4)):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        names = re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+            compiled.as_text())
+        assert len(names) == kernels, names
+        assert all(re.search(r"\bssm_scan\b", name) for name in names), names
+        tile_bytes = (t // chunk) * heads * chunk * chunk * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < tile_bytes
+
+
 @pytest.mark.slow  # ~60 s here; the builder's by-hand rehearsal
-def test_granite_published_width_step_fits_one_v5e_chip(topo):
+def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``granite_4_0_h_micro`` configuration as the benchmark builds it
     (one period of the published widths, an eighth of the vocabulary,
     772,160,448 float32 parameters under AdamW) on one packed row of 8,192
     tokens, through the TPU compiler: parameters and both moments are
     donated and updated in place, and arguments plus temporaries stay under
-    the chip's memory with room for the staged batches.  PERF.md section 4
-    holds the figures."""
+    the chip's memory with room for the staged batches.  The scan is the
+    one a chip runs (the Pallas kernels: here the backend is the CPU, so
+    the test says "tpu" in the model's place), three kernels a layer and
+    two more in its backward pass.  PERF.md section 4 holds the figures."""
     import json
 
     from benchmark.configs.granite_4_0_h_micro import program
+    from tensorflowonspark_tpu.models import granite_hybrid
+
+    monkeypatch.setattr(granite_hybrid, "_backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs",
                            "granite_4_0_h_micro", "config.json")) as f:
@@ -250,6 +297,10 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo):
     compiled = step.lower(state, batch).compile()
     stats = compiled.memory_analysis()
     print(f"granite_4_0_h_micro, one described chip: {stats}")
+    # nine mixers: states and output forward, the same again recomputed,
+    # then the states kernel and the backward kernel
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"'
+                                    ) == 9 * 6
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
     assert stats.argument_size_in_bytes < state_bytes + 2 ** 20

@@ -242,6 +242,225 @@ def test_granite_chunked_scan_is_the_token_recurrence(by_token, chunk):
         _close(g, w)
 
 
+# ---------------------------------------------------------------------------
+# the scan on the Pallas kernels (interpret mode), the rule that picks them
+# ---------------------------------------------------------------------------
+
+#: a chunk of 16 tokens, 32 heads (two blocks of sixteen) of 4 values, state 8
+FUSED_CHUNK = 16
+LAYOUTS = {
+    "one_document": [48],
+    "boundary_on_a_chunks_edge": [16, 32],
+    "boundary_inside_a_tile": [7, 13, 1, 19, 8],
+    "a_document_a_token": [1] * 48,
+    "row_not_a_multiple_of_the_chunk": [7, 13, 1, 11, 8],     # 40 tokens
+}
+
+
+def _fused_inputs(lengths, dtype):
+    rng = np.random.default_rng(4)
+    t, heads, p, n = sum(lengths), 32, 4, 8
+    f = lambda a, d=jnp.float32: jnp.asarray(a, jnp.float32).astype(d)  # noqa
+    seg = jnp.asarray(np.repeat(np.arange(len(lengths)) + 3, lengths)
+                      .astype(np.int32))
+    return (f(rng.normal(size=(t, heads, p)), dtype),
+            f(rng.uniform(0.01, 0.5, size=(t, heads))),
+            f(-rng.uniform(1, 16, size=(heads,))),
+            f(rng.normal(size=(t, 1, n)), dtype),
+            f(rng.normal(size=(t, 1, n)), dtype), seg,
+            f(rng.normal(size=(t, heads, p))))
+
+
+def _scan_and_gradients(scan, inputs, dtype):
+    x, dt, a, b, c, seg, weigh = inputs
+
+    def run(x, dt, a, b, c):
+        y = scan(x, dt, a, b, c, seg, FUSED_CHUNK, dtype)
+        return jnp.sum(y * weigh), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2, 3, 4), has_aux=True))(x, dt, a, b, c)
+    return (y,) + grads
+
+
+@pytest.fixture
+def kernels_on_the_cpu(monkeypatch):
+    """The rule says "fused" whatever the shapes, and the kernels run in
+    Pallas's interpreter: ``ssd_scan`` then pads the row, calls
+    ``ssd_pallas.fused_scan`` and cuts the padding off, as on a chip."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(gh, "scan_runs_fused", lambda *shape: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_granite_fused_scan_is_the_token_recurrence(layout, dtype,
+                                                    kernels_on_the_cpu):
+    """``y`` and the gradient to each of ``x``, ``dt``, ``a``, ``b``, ``c``.
+    In float32 against the recurrence token by token, to the tolerance the
+    ``jnp`` form is held to (the running sums' gradient comes from two
+    identities, ``sum_j dW_ij W_ij = <dy_i, y_i>`` and ``sum_i dW_ij W_ij =
+    <(dt x)_j, d(dt x)_j>``, not from the tile).  With bfloat16 operands against the ``jnp``
+    form at the same dtype: both round the same operands (the tile, ``dt
+    x``, the states) to 8 bits before each product, but sum in another
+    order and round the gradients to ``x``, ``b`` and ``c`` once where the
+    ``jnp`` form rounds twice; one bfloat16 step is 2 ** -8 = 0.4% of a
+    value, so 1.5e-2 of the largest entry is a few steps, and a missed term
+    or boundary is of order 1."""
+    dtype = jnp.dtype(dtype)
+    inputs = _fused_inputs(LAYOUTS[layout], dtype)
+    got = _scan_and_gradients(gh.ssd_scan, inputs, dtype)
+    if dtype == jnp.float32:
+        want = _scan_and_gradients(
+            lambda x, dt, a, b, c, seg, chunk, dtype:
+            reference.recurrence(x, dt, a, b, c, seg), inputs, dtype)
+        tol = TOL
+    else:
+        want = _scan_and_gradients(_plain_scan, inputs, dtype)
+        tol = 1.5e-2
+    for name, g, w in zip(("y", "x", "dt", "a", "b", "c"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if name == "a":
+            # a head's gradient to ``a`` is a sum over tokens of what the
+            # gradient to ``dt`` holds a token of, and where no decay acts
+            # (a document a token) it is exactly 0, the kernels' two equal
+            # sums apart by their rounding: judged on the scale of ``dt``'s
+            w = np.append(w, np.abs(want[2]).max())
+            g = np.append(g, w[-1])
+        _close(g, w, tol)
+
+
+def _plain_scan(*args):
+    """``ssd_scan``'s ``jnp`` form while the fixture says "fused"."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(gh, "scan_runs_fused", lambda *shape: False)
+        return gh.ssd_scan(*args)
+
+
+def test_granite_scan_rule_picks_the_kernels_on_a_tpu_at_the_published_shapes(
+        monkeypatch):
+    published = program.model_config(_published())
+    tiny = gh.Config.tiny()
+    shape = lambda c: (c.mamba_chunk_size, c.mamba_n_heads,    # noqa: E731
+                       c.mamba_d_head, c.mamba_n_groups, c.mamba_d_state)
+    assert shape(published) == (256, 64, 64, 1, 128)
+    # here the backend is the CPU: the jnp form, whatever the shapes
+    assert jax.default_backend() == "cpu"
+    assert not gh.scan_runs_fused(*shape(published))
+    assert not gh.scan_runs_fused(*shape(tiny))
+    monkeypatch.setattr(gh, "_backend", lambda: "tpu")
+    assert gh.scan_runs_fused(*shape(published))
+    assert not gh.scan_runs_fused(*shape(tiny))
+    # each of the kernels' tiles has to be whole
+    for chunk, heads, p, groups, n in [(64, 64, 64, 1, 128),
+                                       (256, 60, 64, 1, 128),
+                                       (256, 64, 32, 1, 128),
+                                       (256, 64, 64, 2, 128),
+                                       (256, 64, 64, 1, 64)]:
+        assert not gh.scan_runs_fused(chunk, heads, p, groups, n)
+    assert gh.scan_runs_fused(128, 16, 128, 1, 256)
+
+
+def test_granite_fused_scan_kernels_carry_the_ssm_scan_scope():
+    """``benchmark/device_scopes.py`` finds the scan by ``ssm_scan`` as a
+    word of a device operation's ``op_name``: every kernel call of the
+    forward pass and of the gradient, lowered for a TPU under the scope
+    ``mamba_mixer`` opens, has to carry it (a ``custom_vjp``'s backward
+    function does not inherit its caller's scope, and a kernel's own name
+    is no word boundary)."""
+    import re
+
+    from tensorflowonspark_tpu.models import ssd_pallas
+
+    t, heads, p, n, chunk = 512, 16, 64, 128, 256
+    assert ssd_pallas.fits(chunk, heads, p, 1, n)
+    bf = jnp.bfloat16
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((t, heads, p), bf), ((t, heads), jnp.float32),
+        ((heads,), jnp.float32), ((t, 1, n), bf), ((t, 1, n), bf),
+        ((t,), jnp.int32))]
+
+    def loss(x, dt, a, b, c, seg):
+        with jax.named_scope("ssm_scan"):
+            y = ssd_pallas.fused_scan(x, dt, a, b, c, seg, chunk, bf)
+        return jnp.sum(y * y)   # the gradient needs the forward's y
+
+    word = re.compile(r"\bssm_scan\b")
+    for fn, kernels in ((loss, 2), (jax.grad(loss, (0, 1, 2, 3, 4)), 4)):
+        text = jax.jit(fn).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+        locations = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+
+        def name_of(line):
+            """The name a line's location carries (``loc("name"(...))``),
+            through the aliases."""
+            where = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
+            return locations[where]
+
+        # a kernel call sits in the function of its ``jax.jit`` (the XLA
+        # inliner then joins the call site's name and the kernel's own):
+        # the call sites carry the scope
+        inside, function = {}, None
+        for line in text.splitlines():
+            opened = re.search(r"func\.func (?:public |private )?@(\w+)",
+                               line)
+            function = opened.group(1) if opened else function
+            if "stablehlo.custom_call @tpu_custom_call" in line:
+                inside.setdefault(function, []).append(line)
+        assert sum(len(v) for v in inside.values()) == kernels
+        sites = 0
+        for function, calls in inside.items():
+            if function == "main":
+                named = calls
+            else:
+                named = [line for line in text.splitlines()
+                         if re.search(rf"call @{function}\(", line)]
+            assert named
+            sites += len(named)
+            for line in named:
+                assert word.search(name_of(line)), name_of(line)
+        assert sites == kernels
+
+
+def test_granite_step_counts_the_execution_of_its_scan(tiny, monkeypatch):
+    """One ``Trainer.step``: exactly one of ``ssm_scan_fused_steps_total``
+    and ``ssm_scan_plain_steps_total`` goes up by one, by the rule
+    ``ssd_scan`` applied when the step was traced; the other is on the
+    record with what it had."""
+    from tensorflowonspark_tpu import obs
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config = tiny[0]
+    trainer = Trainer("granite_hybrid", config=config,
+                      devices=jax.devices()[:1])
+    names = ("ssm_scan_fused_steps_total", "ssm_scan_plain_steps_total")
+
+    def totals():
+        counters = obs.get_registry().snapshot()["counters"]
+        return np.array([counters.get(k, 0) for k in names])
+
+    before = totals()
+    trainer.step(_rows(config, 2, 13))
+    assert set(names) <= set(obs.get_registry().snapshot()["counters"])
+    np.testing.assert_array_equal(totals() - before, [0, 1])    # the CPU
+    staged = trainer.shard(_rows(config, 2, 14))
+    before = totals()
+    trainer.step(staged)
+    np.testing.assert_array_equal(totals() - before, [0, 1])
+    # what a chip's step at the published shapes counts
+    monkeypatch.setattr(gh, "_backend", lambda: "tpu")
+    counts = gh.batch_counters(_rows(config, 1, 15),
+                               program.model_config(_published()))
+    assert (counts["ssm_scan_fused_steps_total"],
+            counts["ssm_scan_plain_steps_total"]) == (1, 0)
+    assert gh.batch_counters(_rows(config, 1, 15), config)[
+        "ssm_scan_plain_steps_total"] == 1      # tiny shapes: still jnp
+
+
 @pytest.mark.parametrize("size", [4, 16, 48])
 @pytest.mark.parametrize("documents", ["one", "uneven", "every_four",
                                        "ids_not_rising"])
