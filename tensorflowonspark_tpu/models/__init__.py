@@ -14,6 +14,20 @@ Every model module exposes the same surface:
 - ``make_loss_fn(module, config)`` → ``loss(params, batch) -> scalar``
 - ``example_batch(config, batch_size, seed)`` → dict of numpy arrays
 - ``SEQUENCE_AXES`` → dict leaf-name → axis index sharded over ``sp``
+
+Optional hooks the Trainer looks for: ``make_optimizer``,
+``make_sharded_train_step``, ``make_collection_shardings``,
+``batch_counters(batch, config)`` (what a step's host batch adds to the
+program's counters) and ``device_counters(collections, config)`` (what of
+the step's collections the counters show: what the device decided).
+
+The two decoders trained on packed rows, ``granite_hybrid`` (state-space
+mixers and a NoPE attention layer) and ``mla_moe`` (latent attention, routed
+and shared experts, the multi-token-prediction module), share
+``packed_rows.py``: norm, products, SwiGLU, attention inside documents, the
+blocked loss.  Two expert layers live in ``parallel/moe.py``: ``bert`` calls
+``moe_ffn`` (Switch top-1 with a capacity, over ``ep``), ``mla_moe`` calls
+``routed_experts`` (top-k of a wide router, the experts held here, no drop).
 """
 
 from __future__ import annotations
@@ -30,6 +44,7 @@ _REGISTRY = {
     "bert": "tensorflowonspark_tpu.models.bert",
     "tiny_lm": "tensorflowonspark_tpu.models.tinylm",
     "granite_hybrid": "tensorflowonspark_tpu.models.granite_hybrid",
+    "mla_moe": "tensorflowonspark_tpu.models.mla_moe",
 }
 
 

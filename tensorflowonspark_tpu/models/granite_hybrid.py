@@ -32,6 +32,8 @@ chip; on any other backend and at small shapes (``Config.tiny()``, the
 tests) as ``jnp`` code.  :func:`scan_runs_fused` is the rule, and a step
 counts which applied (``ssm_scan_fused_steps_total`` /
 ``ssm_scan_plain_steps_total``).
+The norm, the products, the feed-forward, the attention and the blocked
+loss are ``packed_rows``'s, which ``mla_moe`` calls too.
 Parameters are float32; activations are ``Config.dtype``.  Every layer is
 recomputed in the backward pass (``jax.checkpoint``), attention runs a block
 of queries at a time and the training loss a block of tokens at a time, so
@@ -49,10 +51,13 @@ The flax module only registers the parameters (a flat dict, as
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import numpy as np
+
+from tensorflowonspark_tpu.models.packed_rows import (
+    block as _block, blocked_cross_entropy, document_attention,
+    loss_positions, mm as _mm, rms as _rms, swiglu)
 
 #: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -153,30 +158,6 @@ def parameter_count(config: Config) -> int:
 # ---------------------------------------------------------------------------
 # The mathematics, over the flat parameter dict, one row at a time
 # ---------------------------------------------------------------------------
-
-
-def _rms(x, w, eps):
-    import jax
-    import jax.numpy as jnp
-
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
-                                     keepdims=True) + eps)
-    return (y * w).astype(x.dtype)
-
-
-def _mm(spec, a, b, dtype, out=None):
-    """A product with operands in ``dtype``, accumulated in float32."""
-    import jax.numpy as jnp
-
-    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
-                      preferred_element_type=jnp.float32
-                      ).astype(out or dtype)
-
-
-def _block(total: int, want: int) -> int:
-    """The largest divisor of ``total`` that is at most ``want``."""
-    return next(b for b in range(min(want, total), 0, -1) if total % b == 0)
 
 
 def causal_conv(xbc, w, b, seg):
@@ -333,125 +314,6 @@ def mamba_mixer(params, prefix: str, h, seg, config: Config):
     return _mm("te,ed->td", y, params[prefix + "out_proj"], dtype)
 
 
-def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
-    """One block of queries against one block of keys: the scaled scores
-    (kv, rep, i, j) and the mask ``j <= i and same document``."""
-    import jax.numpy as jnp
-
-    s = _mm("ikrd,jkd->krij", qb, kb, dtype, out=jnp.float32) * scale
-    return s, (pq[:, None] >= pk[None, :]) & (sq[:, None] == sk[None, :])
-
-
-def _attend_fwd(q, k, v, seg, scale, size, dtype):
-    import jax
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    t, kv, rep, hd = q.shape
-    n = t // size
-    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
-    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
-
-    def block(args):
-        qb, sq, pq, i = args
-
-        def keys(j, carry):
-            m, l, acc = carry
-            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
-                              dtype)
-            m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, -1e30), -1))
-            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-            fade = jnp.exp(m - m_new)
-            return (m_new, l * fade + p.sum(-1), acc * fade[..., None]
-                    + _mm("krij,jkd->krid", p, vb[j], dtype, out=f32))
-
-        m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
-            jnp.full((kv, rep, size), -1e30, f32),
-            jnp.zeros((kv, rep, size), f32),
-            jnp.zeros((kv, rep, size, hd), f32)))
-        return ((acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype),
-                m + jnp.log(l))
-
-    out, lse = jax.lax.map(block, (
-        q.reshape(n, size, kv, rep, hd), segb, posb, jnp.arange(n)))
-    return out.reshape(t, kv, rep, hd), lse
-
-
-def _attend_bwd(scale, size, dtype, saved, d_out):
-    import jax
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    q, k, v, seg, out, lse = saved
-    t, kv, rep, hd = q.shape
-    n = t // size
-    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
-    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
-    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
-
-    def block(carry, args):
-        qb, dob, sq, pq, i, lse_b, delta_b = args
-
-        def keys(j, inner):
-            dq, dk, dv = inner
-            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
-                              dtype)
-            p = jnp.where(mask, jnp.exp(s - lse_b[..., None]), 0.0)
-            dp = _mm("ikrd,jkd->krij", dob, vb[j], dtype, out=f32)
-            ds = p * (dp - delta_b[..., None]) * scale
-            return (dq + _mm("krij,jkd->ikrd", ds, kb[j], dtype, out=f32),
-                    dk.at[j].add(_mm("krij,ikrd->jkd", ds, qb, dtype,
-                                     out=f32)),
-                    dv.at[j].add(_mm("krij,ikrd->jkd", p, dob, dtype,
-                                     out=f32)))
-
-        dq, dk, dv = jax.lax.fori_loop(
-            0, i + 1, keys, (jnp.zeros(qb.shape, f32),) + carry)
-        return (dk, dv), dq.astype(dtype)
-
-    with jax.named_scope("attention"):
-        (dk, dv), dq = jax.lax.scan(
-            block, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)), (
-                q.reshape(n, size, kv, rep, hd),
-                d_out.reshape(n, size, kv, rep, hd), segb, posb,
-                jnp.arange(n), lse,
-                delta.reshape(n, size, kv, rep).transpose(0, 2, 3, 1)))
-    return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
-            dv.reshape(v.shape).astype(v.dtype),
-            np.zeros(seg.shape, jax.dtypes.float0))
-
-
-@functools.lru_cache(maxsize=None)
-def _attend():
-    """The blocked attention with its own backward pass (made once: the
-    module imports JAX only when it is used)."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-    def attend(q, k, v, seg, scale, size, dtype):
-        return _attend_fwd(q, k, v, seg, scale, size, dtype)[0]
-
-    def fwd(q, k, v, seg, scale, size, dtype):
-        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype)
-        return out, (q, k, v, seg, out, lse)
-
-    attend.defvjp(fwd, _attend_bwd)
-    return attend
-
-
-def document_attention(q, k, v, seg, scale: float, size: int, dtype):
-    """Causal attention inside documents over one packed row, blocks of
-    ``size`` queries against blocks of ``size`` keys with a running softmax:
-    a block of queries visits the blocks of keys up to its own, so no score
-    above the diagonal is ever made and none is held beyond its block.  The
-    backward pass recomputes each block's probabilities from the saved
-    log-sum-exp.  Every row costs the same whatever its documents are (the
-    blocks of another document are visited and masked): a step's time does
-    not depend on the data.  ``q`` (T, kv, rep, hd), ``k`` and ``v``
-    (T, kv, hd), ``seg`` (T,); returns (T, kv, rep, hd)."""
-    return _attend()(q, k, v, seg, scale, size, dtype)
-
-
 def attention(params, prefix: str, h, seg, config: Config):
     """Grouped-query attention without positional encoding on one row:
     ``h`` (T, D) -> (T, D)."""
@@ -469,14 +331,8 @@ def attention(params, prefix: str, h, seg, config: Config):
 
 
 def mlp(params, prefix: str, h):
-    import jax
-    import jax.numpy as jnp
-
-    dtype = h.dtype
-    gate = _mm("td,df->tf", h, params[prefix + "mlp_gate"], dtype)
-    up = _mm("td,df->tf", h, params[prefix + "mlp_up"], dtype)
-    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
-    return _mm("tf,fd->td", act, params[prefix + "mlp_down"], dtype)
+    return swiglu(h, params[prefix + "mlp_gate"], params[prefix + "mlp_up"],
+                  params[prefix + "mlp_down"])
 
 
 def hidden_states(params, tokens, seg, config: Config):
@@ -539,23 +395,12 @@ def loss_sums(params, tokens, segment_ids, config: Config):
 
     def row(u, s):
         x = hidden_states(params, u, s, config)
-        t = x.shape[0]
-        target = jnp.roll(u, -1)
-        valid = (jnp.roll(s, -1) == s) & (jnp.arange(t) < t - 1)
-        size = _block(t, config.loss_block)
-
-        def block(args):
-            xb, ub, vb = args
-            logits = _logits(params, xb, config)
-            picked = jnp.take_along_axis(logits, ub[:, None], axis=1)[:, 0]
-            nll = jax.nn.logsumexp(logits, axis=-1) - picked
-            return jnp.sum(jnp.where(vb, nll, 0.0))
-
+        valid = loss_positions(s)
         with jax.named_scope("lm_head"):
-            sums = jax.lax.map(jax.checkpoint(block), (
-                x.reshape(t // size, size, -1), target.reshape(-1, size),
-                valid.reshape(-1, size)))
-        return jnp.sum(sums), jnp.sum(valid)
+            total = blocked_cross_entropy(
+                x, lambda xb: _logits(params, xb, config), jnp.roll(u, -1),
+                valid, config.loss_block)
+        return total, jnp.sum(valid)
 
     total, count = jax.vmap(row)(tokens, segment_ids)
     return jnp.sum(total), jnp.sum(count)

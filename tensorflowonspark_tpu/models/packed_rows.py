@@ -1,0 +1,220 @@
+"""What the decoders trained on packed rows have in common
+(``granite_hybrid``, ``mla_moe``): the RMS norm, the product with operands
+in the activations' type, the SwiGLU feed-forward, causal attention inside
+documents a block of queries at a time, and the next-token cross-entropy a
+block of tokens at a time.
+
+A packed row is ``T`` tokens with segment ids ``s`` (the document's number
+inside the row; documents are contiguous and their ids differ).  One
+implementation of each piece, called by both models: what is measured on one
+model's cell is what the other runs.
+
+JAX is imported where it is used, as in the models.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+
+def rms(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    return (y * w).astype(x.dtype)
+
+
+def mm(spec, a, b, dtype, out=None):
+    """A product with operands in ``dtype``, accumulated in float32."""
+    import jax.numpy as jnp
+
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32
+                      ).astype(out or dtype)
+
+
+def block(total: int, want: int) -> int:
+    """The largest divisor of ``total`` that is at most ``want``."""
+    return next(b for b in range(min(want, total), 0, -1) if total % b == 0)
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    """``W_down (silu(h W_gate) * (h W_up))`` on (T, D) tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = h.dtype
+    gate = mm("td,df->tf", h, w_gate, dtype)
+    up = mm("td,df->tf", h, w_up, dtype)
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
+    return mm("tf,fd->td", act, w_down, dtype)
+
+
+def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
+    """One block of queries against one block of keys: the scaled scores
+    (kv, rep, i, j) and the mask ``j <= i and same document``."""
+    import jax.numpy as jnp
+
+    s = mm("ikrd,jkd->krij", qb, kb, dtype, out=jnp.float32) * scale
+    return s, (pq[:, None] >= pk[None, :]) & (sq[:, None] == sk[None, :])
+
+
+def _attend_fwd(q, k, v, seg, scale, size, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    t, kv, rep, hd = q.shape
+    n = t // size
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
+
+    def block_(args):
+        qb, sq, pq, i = args
+
+        def keys(j, carry):
+            m, l, acc = carry
+            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
+                              dtype)
+            m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, -1e30), -1))
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            fade = jnp.exp(m - m_new)
+            return (m_new, l * fade + p.sum(-1), acc * fade[..., None]
+                    + mm("krij,jkd->krid", p, vb[j], dtype, out=f32))
+
+        m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
+            jnp.full((kv, rep, size), -1e30, f32),
+            jnp.zeros((kv, rep, size), f32),
+            jnp.zeros((kv, rep, size, hd), f32)))
+        return ((acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype),
+                m + jnp.log(l))
+
+    out, lse = jax.lax.map(block_, (
+        q.reshape(n, size, kv, rep, hd), segb, posb, jnp.arange(n)))
+    return out.reshape(t, kv, rep, hd), lse
+
+
+def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    q, k, v, seg, out, lse = saved
+    t, kv, rep, hd = q.shape
+    n = t // size
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
+    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
+
+    def block_(carry, args):
+        qb, dob, sq, pq, i, lse_b, delta_b = args
+
+        def keys(j, inner):
+            dq, dk, dv = inner
+            s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
+                              dtype)
+            p = jnp.where(mask, jnp.exp(s - lse_b[..., None]), 0.0)
+            dp = mm("ikrd,jkd->krij", dob, vb[j], dtype, out=f32)
+            ds = p * (dp - delta_b[..., None]) * scale
+            return (dq + mm("krij,jkd->ikrd", ds, kb[j], dtype, out=f32),
+                    dk.at[j].add(mm("krij,ikrd->jkd", ds, qb, dtype,
+                                    out=f32)),
+                    dv.at[j].add(mm("krij,ikrd->jkd", p, dob, dtype,
+                                    out=f32)))
+
+        dq, dk, dv = jax.lax.fori_loop(
+            0, i + 1, keys, (jnp.zeros(qb.shape, f32),) + carry)
+        return (dk, dv), dq.astype(dtype)
+
+    # a custom backward pass is traced outside the scopes its forward pass
+    # was called under: it opens them again itself
+    with contextlib.ExitStack() as stack:
+        for scope in scopes:
+            stack.enter_context(jax.named_scope(scope))
+        (dk, dv), dq = jax.lax.scan(
+            block_, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)), (
+                q.reshape(n, size, kv, rep, hd),
+                d_out.reshape(n, size, kv, rep, hd), segb, posb,
+                jnp.arange(n), lse,
+                delta.reshape(n, size, kv, rep).transpose(0, 2, 3, 1)))
+    return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
+            np.zeros(seg.shape, jax.dtypes.float0))
+
+
+@functools.lru_cache(maxsize=None)
+def _attend():
+    """The blocked attention with its own backward pass (made once: the
+    module imports JAX only when it is used)."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+    def attend(q, k, v, seg, scale, size, dtype, scopes):
+        return _attend_fwd(q, k, v, seg, scale, size, dtype)[0]
+
+    def fwd(q, k, v, seg, scale, size, dtype, scopes):
+        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype)
+        return out, (q, k, v, seg, out, lse)
+
+    attend.defvjp(fwd, _attend_bwd)
+    return attend
+
+
+def document_attention(q, k, v, seg, scale: float, size: int, dtype,
+                       scopes: tuple = ("attention",)):
+    """Causal attention inside documents over one packed row, blocks of
+    ``size`` queries against blocks of ``size`` keys with a running softmax:
+    a block of queries visits the blocks of keys up to its own, so no score
+    above the diagonal is ever made and none is held beyond its block.  The
+    backward pass recomputes each block's probabilities from the saved
+    log-sum-exp, under the ``jax.named_scope``s ``scopes`` (the caller's:
+    the forward pass runs under the caller's own).  Every row costs the
+    same whatever its documents are (the blocks of another document are
+    visited and masked): a step's time does not depend on the data.  ``q``
+    (T, kv, rep, hd), ``k`` and ``v`` (T, kv, hd), ``seg`` (T,); returns
+    (T, kv, rep, hd)."""
+    return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes))
+
+
+def loss_positions(seg, ahead: int = 1):
+    """(T,) bool: position ``t`` is scored against the token ``ahead``
+    places on where that token and every one between are ``t``'s
+    document's."""
+    import jax.numpy as jnp
+
+    t = seg.shape[0]
+    valid = jnp.arange(t) < t - ahead
+    for k in range(1, ahead + 1):
+        valid = valid & (jnp.roll(seg, -k) == seg)
+    return valid
+
+
+def blocked_cross_entropy(x, logits_fn, targets, valid, want: int):
+    """Sum of the cross-entropies of ``logits_fn(x_t)`` (float32 logits)
+    against ``targets_t`` over the positions ``valid``: the logits exist a
+    block of at most ``want`` tokens at a time, and are made again in the
+    backward pass.  ``x`` (T, D), ``targets`` and ``valid`` (T,)."""
+    import jax
+    import jax.numpy as jnp
+
+    t = x.shape[0]
+    size = block(t, want)
+
+    def block_(args):
+        xb, ub, vb = args
+        logits = logits_fn(xb)
+        picked = jnp.take_along_axis(logits, ub[:, None], axis=1)[:, 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked
+        return jnp.sum(jnp.where(vb, nll, 0.0))
+
+    sums = jax.lax.map(jax.checkpoint(block_), (
+        x.reshape(t // size, size, -1), targets.reshape(-1, size),
+        valid.reshape(-1, size)))
+    return jnp.sum(sums)

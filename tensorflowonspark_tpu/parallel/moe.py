@@ -1,10 +1,10 @@
-"""Mixture-of-Experts FFN with expert parallelism over the ``ep`` mesh axis.
+"""Two mixture-of-experts feed-forward layers.
 
+**:func:`moe_ffn` — Switch top-1 with a capacity, expert-parallel over the
+``ep`` mesh axis** (called by ``models/bert.py`` where ``moe_experts`` > 0).
 Reference anchor: **absent from the reference** (``SURVEY.md §2.3``: EP
 "NO — out of scope for parity") — a beyond-parity capability completing the
 framework's parallelism families (dp/fsdp/tp/sp/pp/**ep**).
-
-Design (TPU-idiomatic, Switch-Transformer routing):
 
 - **Router**: top-1 gating in float32; each token goes to its argmax
   expert, bounded by a per-expert **capacity** ``C = capacity_factor ×
@@ -27,10 +27,26 @@ Design (TPU-idiomatic, Switch-Transformer routing):
 Layout contract: tokens ``(T, M)`` in, experts' weights ``(E, M, H)`` /
 ``(E, H, M)``.  ``T`` must be divisible by nothing in particular (capacity
 handles imbalance), but shard the token dim over the data axes as usual.
+
+**:func:`routed_experts` — top-k of a wide router, the experts held here,
+no token dropped** (called by ``models/mla_moe.py``; the DeepSeek-V3 layout,
+arXiv:2412.19437).  The layer is told *which* of the router's experts this
+chip holds.  It scores every token against all of them (sigmoid, float32),
+chooses the ``top_k`` of score plus a correction bias that takes no
+gradient, weighs the chosen by their normalised scores, keeps every slot
+(token, choice) whose expert is held — any number of them, from none to
+all — sorts the kept slots by expert and multiplies them expert by expert
+as grouped products (``jax.lax.ragged_dot``: the cost follows the slots that
+landed here, not the worst case its buffers are sized for), and adds the
+results back weighted.  What the experts held elsewhere would have added is
+left out; on one chip no exchange runs.  It returns how many tokens chose
+each of the router's experts, which is what the caller's bias update and
+counters read.  No capacity factor exists and no auxiliary loss.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import Any, Mapping
 
@@ -267,3 +283,139 @@ def init_params(rng, num_experts: int, model_dim: int, hidden_dim: int,
             k3, (num_experts, hidden_dim, model_dim), dtype) * scale_out,
         "b_out": jnp.zeros((num_experts, model_dim), dtype),
     }
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over a wide router, the held experts' part, no drop
+# ---------------------------------------------------------------------------
+
+
+def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
+               normalize: bool = True):
+    """``(chosen, gates)`` of tokens ``h`` (T, D): the scores are
+    ``sigmoid(h W_r)`` in float32 at the highest precision (a choice hangs
+    on them), ``chosen`` (T, k) the ``top_k`` experts by score plus
+    ``router_bias`` (E,), ``gates`` (T, k) ``scale`` times the chosen
+    scores, over their sum if ``normalize``.  The gradient runs through
+    the scores and not through the choice or the bias."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        jax.lax.stop_gradient(scores) + router_bias, top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=1)
+    if normalize:
+        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen, scale * picked
+
+
+@functools.lru_cache(maxsize=None)
+def _permutation_ops():
+    """``(spread, gather_back)``: the two row permutations of the layer,
+    each with the other's gather as its backward pass (a gather's own
+    transpose is a scatter, which a TPU runs a row at a time).  Made once:
+    the module imports JAX only when it is used."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    def no_grad(a):
+        return np.zeros(a.shape, jax.dtypes.float0)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def spread(x, order, inv, k):
+        """(T, D) tokens -> (T k, D): row ``i`` is the token of slot
+        ``order[i]`` (slot ``t k + j`` is token ``t``'s ``j``-th choice)."""
+        return jnp.take(x, order // k, axis=0)
+
+    def spread_fwd(x, order, inv, k):
+        return spread(x, order, inv, k), (order, inv)
+
+    def spread_bwd(k, saved, d):
+        order, inv = saved
+        with jax.named_scope("moe_dispatch"):
+            dx = jnp.take(d, inv, axis=0).reshape(-1, k, d.shape[-1])
+            dx = jnp.sum(dx.astype(jnp.float32), axis=1).astype(d.dtype)
+        return dx, no_grad(order), no_grad(inv)
+
+    spread.defvjp(spread_fwd, spread_bwd)
+
+    @jax.custom_vjp
+    def gather_back(rows, order, inv):
+        """Sorted rows -> slot order: row ``s`` is ``rows[inv[s]]``."""
+        return jnp.take(rows, inv, axis=0)
+
+    def back_fwd(rows, order, inv):
+        return gather_back(rows, order, inv), (order, inv)
+
+    def back_bwd(saved, d):
+        order, inv = saved
+        with jax.named_scope("moe_combine"):
+            return jnp.take(d, order, axis=0), no_grad(order), no_grad(inv)
+
+    gather_back.defvjp(back_fwd, back_bwd)
+    return spread, gather_back
+
+
+def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
+                   top_k: int, scale: float, normalize: bool = True):
+    """The held experts' part of a routed SwiGLU layer on tokens ``x``
+    (T, D): ``sum over e chosen and held of g_e W_down_e (silu(x W_gate_e)
+    * (x W_up_e))``, and the tokens that chose each of the router's experts.
+
+    ``router_w`` (D, E) and ``router_bias`` (E,) span all ``E`` experts;
+    ``w_gate``, ``w_up`` (H, D, F) and ``w_down`` (H, F, D) are the ``H``
+    experts held here, ``held`` (a static sequence of ``H`` distinct ids in
+    ``[0, E)``) says which they are, in the weights' order.  Products take
+    operands in ``x``'s type and accumulate in float32; routing is float32.
+
+    Every slot is kept: the ``T top_k`` slots are sorted by held expert
+    (those of experts held elsewhere last), the buffers hold all of them,
+    and the grouped products run over the rows that landed here.  Returns
+    ``(y, counts)``: ``y`` (T, D) in ``x``'s type, ``counts`` (E,) int32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    spread, gather_back = _permutation_ops()
+    f32, dtype = jnp.float32, x.dtype
+    t, d = x.shape
+    n_experts, n_held = router_w.shape[1], len(held)
+    held = np.asarray(held, np.int32)
+    if (n_held != w_gate.shape[0] or len(set(held.tolist())) != n_held
+            or held.min(initial=0) < 0 or held.max(initial=0) >= n_experts):
+        raise ValueError(f"held {held.tolist()}: want {w_gate.shape[0]} "
+                         f"distinct experts of {n_experts}")
+    with jax.named_scope("moe_router"):
+        chosen, gates = topk_route(x, router_w, router_bias, top_k=top_k,
+                                   scale=scale, normalize=normalize)
+        slot_expert = chosen.reshape(-1)
+        counts = jnp.sum(slot_expert[:, None] == jnp.arange(n_experts),
+                         axis=0, dtype=jnp.int32)
+    with jax.named_scope("moe_dispatch"):
+        place = np.full(n_experts, n_held, np.int32)    # elsewhere: last
+        place[held] = np.arange(n_held)
+        order = jnp.argsort(jnp.asarray(place)[slot_expert], stable=True)
+        inv = jnp.argsort(order)
+        group_sizes = counts[held]
+        live = (jnp.arange(t * top_k) < jnp.sum(group_sizes))[:, None]
+        # rows past the live ones belong to no group: whatever a grouped
+        # product leaves there never meets a live row
+        xs = jnp.where(live, spread(x, order, inv, top_k), 0)
+
+    def grouped(rows, w):
+        with jax.named_scope("moe_experts"):
+            return jax.lax.ragged_dot(rows, w.astype(dtype), group_sizes,
+                                      preferred_element_type=f32)
+
+    act = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dtype)
+    out = grouped(act, w_down)
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(live, out, 0).astype(dtype)
+        out = gather_back(out, order, inv).reshape(t, top_k, d)
+        y = jnp.einsum("tk,tkd->td", gates, out.astype(f32),
+                       preferred_element_type=f32).astype(dtype)
+    return y, counts

@@ -231,6 +231,18 @@ class Trainer:
             collection_shardings=col_overrides or None,
         )
 
+        # ... and name what of the step's collections the counters should
+        # show (what the device decided, as which expert a token went to):
+        # ``device_counters(collections, config) -> {counter: cumulative
+        # integer array}``, read a step late so that no step waits for it
+        self._device_counters = None
+        make_counts = getattr(self.module_lib, "device_counters", None)
+        if make_counts is not None and self.state.collections:
+            self._device_counters = _DeviceCounters(
+                make_counts, self.config, self.state.collections)
+            # the last step's counts are read when the trainer goes
+            weakref.finalize(self, self._device_counters.drain)
+
         self._steps_done = 0
         # flight recorder: step() attributes its shard + dispatch
         # (compute) per step and commits the feed-plane record the
@@ -343,6 +355,8 @@ class Trainer:
                       else self._staged_counts.get(id(first)))
             for name, value in (counts or {}).items():
                 obs.counter(name).inc(value)
+        if self._device_counters is not None:
+            self._device_counters.after_step(self.state.collections)
         if dt > 0:
             obs.histogram("trainer_step_seconds").observe(dt)
         # wall-clock heartbeat for the driver's stall detector
@@ -500,6 +514,8 @@ class Trainer:
         self.state = TrainState(restored["params"], restored["opt_state"],
                                 restored["step"],
                                 restored.get("collections", {}))
+        if self._device_counters is not None:
+            self._device_counters.rebase(self.state.collections)
         return step
 
     def finish_checkpoints(self) -> None:
@@ -572,6 +588,50 @@ class Trainer:
         self.state = TrainState(restored["params"], restored["opt_state"],
                                 restored["step"],
                                 restored.get("collections", {}))
+        if self._device_counters is not None:
+            self._device_counters.rebase(self.state.collections)
+
+
+class _DeviceCounters:
+    """Counters of what the device decided, from the step's collections.
+
+    A model module's ``device_counters(collections, config)`` names
+    cumulative integer arrays (any shape) inside the collections.  After
+    every step a small jitted copy of them is dispatched behind the step
+    and its transfer to the host started; the step after reads it (it is
+    there by then: no step waits for anything but its own loss) and adds
+    each array's growth, element by element and modulo 2**32, to the
+    ``obs`` counter of its name.  The copy is compiled when the Trainer is
+    built, and then again never.  :meth:`drain` reads what is pending (the
+    last step's, when the Trainer goes); :meth:`rebase` takes restored
+    collections as the new zero."""
+
+    def __init__(self, make_counts, config, collections):
+        import jax
+
+        self._copy = jax.jit(lambda cols: jax.tree_util.tree_map(
+            lambda v: v + 0, make_counts(cols, config)))
+        self._pending = None
+        self.rebase(collections)
+
+    def rebase(self, collections) -> None:
+        self.drain()
+        self._seen = {name: np.asarray(value).astype(np.int64)
+                      for name, value in self._copy(collections).items()}
+
+    def after_step(self, collections) -> None:
+        self.drain()
+        self._pending = self._copy(collections)
+        for value in self._pending.values():
+            value.copy_to_host_async()
+
+    def drain(self) -> None:
+        pending, self._pending = self._pending, None
+        for name, value in (pending or {}).items():
+            now = np.asarray(value).astype(np.int64)
+            obs.counter(name).inc(int(((now - self._seen[name])
+                                       % (1 << 32)).sum()))
+            self._seen[name] = now
 
 
 def _first_leaf(batch):

@@ -309,6 +309,44 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
 
 
+@pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
+def test_glm_published_width_step_fits_one_v5e_chip(topo):
+    """The ``glm_4_7_flash`` configuration as the benchmark builds it (the
+    dense layer, four expert layers with 8 of 64 experts held, the
+    prediction module, an eighth of the vocabulary: 706,518,528 float32
+    parameters under AdamW) on one packed row of 8,192 tokens, through the
+    TPU compiler: parameters, both moments and the routing state are
+    donated and updated in place, the grouped products are the compiler's
+    own kernels (three a layer forward, three recomputed, six backward),
+    and arguments plus temporaries stay under the chip's memory.  PERF.md
+    section 4 holds the figures."""
+    import json
+
+    from benchmark.configs.glm_4_7_flash import program
+
+    with open(os.path.join(REPO, "benchmark", "configs", "glm_4_7_flash",
+                           "config.json")) as f:
+        published = json.load(f)
+    config = program.model_config(published)
+    step, state, batch = abstract_train_step(
+        "mla_moe", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 706_518_528
+    assert state.collections["moe"]["bias"].shape == (5, 64)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"glm_4_7_flash, one described chip: {stats}")
+    text = compiled.as_text()
+    assert text.count(" custom-call(") >= 5 * 12
+    assert text.count('op_name="ragged-dot-none"') == 5 * 12
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    # temporaries: a gradient's worth and the worst-case slot buffers
+    assert stats.temp_size_in_bytes < 5 * 2 ** 30
+    assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
+
+
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):
     """``TPU v5 lite`` is what the v5e reports (chip run, PR 21) and what
     the described topology reports; both peak tables must resolve it."""

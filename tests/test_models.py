@@ -19,7 +19,8 @@ ALL_MODELS = zoo.available()
 def test_registry_lists_all():
     assert ALL_MODELS == sorted(
         ["mnist_mlp", "cifar10_cnn", "resnet50", "inception_v3",
-         "mobilenet_v1", "wide_deep", "bert", "tiny_lm", "granite_hybrid"]
+         "mobilenet_v1", "wide_deep", "bert", "tiny_lm", "granite_hybrid",
+         "mla_moe"]
     )
 
 
