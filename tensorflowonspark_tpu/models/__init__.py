@@ -24,7 +24,9 @@ the step's collections the counters show: what the device decided).
 The two decoders trained on packed rows, ``granite_hybrid`` (state-space
 mixers and a NoPE attention layer) and ``mla_moe`` (latent attention, routed
 and shared experts, the multi-token-prediction module), share
-``packed_rows.py``: norm, products, SwiGLU, attention inside documents, the
+``packed_rows.py``: norm, products, SwiGLU, attention inside documents (as
+``jnp`` code or, on a TPU where a head fills whole lanes, the Pallas kernels
+of ``attention_pallas.py``; granite's scan has ``ssd_pallas.py``), the
 blocked loss.  Two expert layers live in ``parallel/moe.py``: ``bert`` calls
 ``moe_ffn`` (Switch top-1 with a capacity, over ``ep``), ``mla_moe`` calls
 ``routed_experts`` (top-k of a wide router, the experts held here, no drop).

@@ -1,0 +1,337 @@
+"""``packed_rows.document_attention`` as Pallas TPU kernels: the same blocked
+causal attention inside documents, a (block of queries x block of keys)
+score tile at a time in VMEM, so that no heads x Q x K float32 tensor is
+written to or read from HBM, forward or backward.
+
+One row of ``T`` tokens, head ``h`` of ``kv x rep`` (its keys and values
+those of head ``h // rep``), the heads side by side along the lanes: ``q``
+is (T, heads x hd) and a head's block is a column of whole rows of 128
+lanes (``hd`` a multiple of 128), so nothing is transposed on the way in or
+out.  Two kernels under one ``jax.custom_vjp`` (:func:`fused_attention`):
+
+- ``attention_forward``, grid (head, block of queries): the head's keys and
+  values stay in VMEM, the blocks of keys up to the queries' own are visited
+  in a loop inside the kernel with the running maximum, sum and output in
+  scratch; the log-sum-exp leaves as a row;
+- ``attention_backward``, grid (head, block of keys), the blocks of keys in
+  order: the head's queries and ``d_out`` stay in VMEM and the blocks of
+  queries from the keys' own to the row's end are visited.  A tile's
+  probabilities are made again from the saved log-sum-exp, once, and meet
+  all five products there: the scores and ``dp`` (keys x queries, so that
+  the per-query log-sum-exp and ``delta`` are rows and the products for
+  ``dk`` and ``dv`` need no transpose), ``dv``, ``dk`` and ``dq``, which is
+  summed over the blocks of keys in a float32 scratch of the head's whole
+  row and leaves a block at a time as its rows become final.
+
+The mathematics is the ``jnp`` form's to the rounding: the mask is ``j <= i
+and same document``, masked scores are -1e30, every product takes operands
+in ``dtype`` (``p`` and ``ds`` cast before theirs) and accumulates in
+float32, the softmax is float32.  **Every block on or below the diagonal is
+visited whatever the documents are** — the grid and the loops' bounds come
+from the shapes alone, the segment ids only enter the mask — so a step's
+time does not follow its row.  Only the blocks the diagonal crosses compare
+positions.
+
+``packed_rows.attention_runs_fused`` says when this runs; interpret mode
+(``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+from tensorflowonspark_tpu.models.packed_rows import under
+from tensorflowonspark_tpu.models.ssd_pallas import _dot
+
+#: (queries, keys) a tile, forward and backward, chosen on the chip at the
+#: published 8,192 x 20 x 256 in bfloat16 (kernels alone, ms a call; PERF.md,
+#: PR 35, holds every reading): forward 6.93 at 256 x 512, 5.59 at 512 x
+#: 512, 5.41 at 1,024 x 512, 5.00 at 512 x 1,024, 4.90 at 1,024 x 1,024;
+#: backward 10.05 at 512 x 512, 10.36 at 1,024 x 512, 10.38 at 256 x 512
+FORWARD_BLOCKS = (1024, 1024)
+BACKWARD_BLOCKS = (512, 512)
+#: a head's whole row stays in VMEM (keys and values forward; queries,
+#: ``d_out`` and the float32 ``dq`` backward): 24 MiB and the tiles at
+#: 8,192 x 256; the limit is half of what a v5e has
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+ROW_ENTRIES = 8192 * 256
+MASKED = -1e30
+
+
+def _blocks(t: int, blocks: tuple) -> tuple:
+    """A short row is one block."""
+    return tuple(min(b, t) for b in blocks)
+
+
+def fits(t: int, hd: int) -> bool:
+    """Whether the kernels' tiles exist at these shapes: a head's row fills
+    whole rows of 128 lanes, the row of tokens is whole blocks of queries
+    and of keys of whole rows of lanes too, and a head's row fits the fast
+    memory."""
+    return (hd % 128 == 0 and t % 128 == 0 and t * hd <= ROW_ENTRIES
+            and all(t % b == 0 for b in _blocks(
+                t, FORWARD_BLOCKS + BACKWARD_BLOCKS)))
+
+
+def _rows_at(i, size: int):
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _offsets(rows: int, cols: int, by_row: bool):
+    """(rows, cols) int32: how far an entry's query lies beyond its key
+    when both blocks start at the same token; queries along the rows
+    (``by_row``) or along the columns."""
+    import jax
+    import jax.numpy as jnp
+
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return r - c if by_row else c - r
+
+
+def _forward_kernel(scale, dtype, bk, q_ref, k_ref, v_ref, seg_q_ref,
+                    seg_k_ref, out_ref, lse_ref, m_ref, l_ref, acc_ref):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    bq = q_ref.shape[0]
+    i = pl.program_id(1)
+    q, seg_q = q_ref[...], seg_q_ref[...]
+    m_ref[...] = jnp.full(m_ref.shape, MASKED, f32)
+    l_ref[...] = jnp.zeros(l_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def visit(j, diagonal: bool):
+        at = _rows_at(j, bk)
+        mask = seg_q == seg_k_ref[j]
+        if diagonal:
+            mask = mask & (_offsets(bq, bk, True) >= j * bk - i * bq)
+        s = jnp.where(mask, _dot(q, k_ref[at, :], (1, 1)) * scale, MASKED)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # a row that has met no key of its document yet holds ones here
+        # (exp(-1e30 + 1e30)); the first real score's fade is exactly 0
+        p = jnp.exp(s - m_new)
+        fade = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * fade + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * fade + _dot(
+            p.astype(dtype), v_ref[at, :], (1, 0))
+
+    below = (i * bq) // bk      # blocks of keys wholly before the queries
+
+    def body(j, carry):
+        visit(j, False)
+        return carry
+
+    jax.lax.fori_loop(0, below, body, 0)
+    for d in range(max(1, bq // bk)):
+        visit(below + d, True)
+    l = l_ref[...]
+    out_ref[...] = (acc_ref[...] / l).astype(out_ref.dtype)
+    lse = m_ref[...] + jnp.log(l)
+    lse_ref[...] = jnp.broadcast_to(lse, (bq, 128)).T[0:1]
+
+
+def _backward_kernel(scale, dtype, bq, q_ref, do_ref, k_ref, v_ref,
+                     seg_k_ref, seg_q_ref, lse_ref, delta_ref, dq_ref,
+                     dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    bk = k_ref.shape[0]
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, f32)
+
+    dk_acc[...] = jnp.zeros(dk_acc.shape, f32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, f32)
+    k, v, seg_k = k_ref[...], v_ref[...], seg_k_ref[...]
+
+    def visit(i, diagonal: bool):
+        at = _rows_at(i, bq)
+        q, do = q_ref[at, :], do_ref[at, :]
+        mask = seg_k == seg_q_ref[i]
+        if diagonal:
+            mask = mask & (_offsets(bk, bq, False) >= j * bk - i * bq)
+        s = jnp.where(mask, _dot(k, q, (1, 1)) * scale, MASKED)
+        p = jnp.exp(s - lse_ref[i])
+        ds = p * (_dot(v, do, (1, 1)) - delta_ref[i]) * scale
+        p, ds = p.astype(dtype), ds.astype(dtype)
+        dv_acc[...] += _dot(p, do, (1, 0))
+        dk_acc[...] += _dot(ds, q, (1, 0))
+        dq_acc[at, :] += _dot(ds, k, (0, 0))
+
+    first = (j * bk) // bq      # the first block of queries at these keys
+    crossed = max(1, bk // bq)
+    for d in range(crossed):
+        visit(first + d, True)
+
+    def body(i, carry):
+        visit(i, False)
+        return carry
+
+    jax.lax.fori_loop(first + crossed, q_ref.shape[0] // bq, body, 0)
+    dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+    # no later block of keys reaches these queries
+    dq_ref[...] = dq_acc[_rows_at(j, bk), :].astype(dq_ref.dtype)
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
+    """``out`` (T, heads x hd) in ``dtype`` and the log-sum-exp (heads,
+    T / bq, 1, bq) float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    t, heads = q2.shape[0], q2.shape[1] // hd
+    rep = q2.shape[1] // k2.shape[1]
+    queries = pl.BlockSpec((bq, hd), lambda h, i: (i, h))
+    row = pl.BlockSpec((t, hd), lambda h, i: (0, h // rep))
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, scale, dtype, bk),
+        grid=(heads, t // bq),
+        in_specs=[queries, row, row,
+                  pl.BlockSpec((bq, 1), lambda h, i: (i, 0)),
+                  pl.BlockSpec((t // bk, 1, bk), lambda h, i: (0, 0, 0))],
+        out_specs=[queries, pl.BlockSpec((None, None, 1, bq),
+                                         lambda h, i: (h, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q2.shape, dtype),
+                   jax.ShapeDtypeStruct((heads, t // bq, 1, bq), f32)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
+                        pltpu.VMEM((bq, hd), f32)],
+        compiler_params=_params(), name="attention_forward",
+    )(q2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bk, 1, bk))
+
+
+def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk):
+    """``dq``, ``dk``, ``dv`` a query head (T, heads x hd): ``dq`` in
+    ``dtype``; ``dk`` and ``dv`` too where a key head serves one query
+    head, else float32 for the sum over its ``rep``.  ``lse`` and ``delta``
+    (heads, T / bq, 1, bq) float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    t, heads = q2.shape[0], q2.shape[1] // hd
+    rep = q2.shape[1] // k2.shape[1]
+    row = pl.BlockSpec((t, hd), lambda h, j: (0, h))
+    keys = pl.BlockSpec((bk, hd), lambda h, j: (j, h // rep))
+    per_query = pl.BlockSpec((None, t // bq, 1, bq),
+                             lambda h, j: (h, 0, 0, 0))
+    gradient = pl.BlockSpec((bk, hd), lambda h, j: (j, h))
+    summed = jax.ShapeDtypeStruct(q2.shape, dtype if rep == 1 else f32)
+    return pl.pallas_call(
+        functools.partial(_backward_kernel, scale, dtype, bq),
+        grid=(heads, t // bk),
+        in_specs=[row, row, keys, keys,
+                  pl.BlockSpec((bk, 1), lambda h, j: (j, 0)),
+                  pl.BlockSpec((t // bq, 1, bq), lambda h, j: (0, 0, 0)),
+                  per_query, per_query],
+        out_specs=[gradient, gradient, gradient],
+        out_shape=[jax.ShapeDtypeStruct(q2.shape, dtype), summed, summed],
+        scratch_shapes=[pltpu.VMEM((t, hd), f32), pltpu.VMEM((bk, hd), f32),
+                        pltpu.VMEM((bk, hd), f32)],
+        compiler_params=_params(), name="attention_backward",
+    )(q2, do2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bq, 1, bq), lse,
+      delta)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels():
+    """The two kernel calls under ``jax.jit``: a model calls each once a
+    layer (and the forward again in the layer's recomputation), and a jitted
+    function's body is traced and lowered once a shape, not once a call."""
+    import jax
+
+    return (jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8)),
+            jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11)))
+
+
+def _heads_along_lanes(x, dtype):
+    """(T, ..., hd) -> (T, heads x hd) in ``dtype``."""
+    return x.reshape(x.shape[0], -1).astype(dtype)
+
+
+def _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward, backward):
+    out, lse = _kernels()[0](
+        *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg, scale,
+        dtype, q.shape[-1], *forward)
+    out = out.reshape(q.shape)
+    return out, (q, k, v, seg, out, lse)
+
+
+def _attend_bwd(scale, dtype, scopes, forward, backward, saved, d_out):
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    q, k, v, seg, out, lse = saved
+    t, kv, rep, hd = q.shape
+    bq = backward[0]
+    with under(scopes):
+        delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
+        dq, dk, dv = _kernels()[1](
+            *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg,
+            lse.reshape(kv * rep, t // bq, 1, bq),
+            _heads_along_lanes(d_out, dtype),
+            delta.reshape(t // bq, 1, bq, kv * rep).transpose(3, 0, 1, 2),
+            scale, dtype, hd, *backward)
+        if rep > 1:
+            dk, dv = (g.reshape(q.shape).sum(2) for g in (dk, dv))
+    return (dq.reshape(q.shape).astype(q.dtype),
+            dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
+            np.zeros(seg.shape, jax.dtypes.float0))
+
+
+@functools.lru_cache(maxsize=None)
+def _attend():
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+    def attend(q, k, v, seg, scale, dtype, scopes, forward, backward):
+        return _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward,
+                           backward)[0]
+
+    attend.defvjp(_attend_fwd, _attend_bwd)
+    return attend
+
+
+def fused_attention(q, k, v, seg, scale: float, dtype, scopes: tuple,
+                    forward: tuple = FORWARD_BLOCKS,
+                    backward: tuple = BACKWARD_BLOCKS):
+    """``packed_rows.document_attention`` on the kernels, for shapes that
+    :func:`fits` admits: ``q`` (T, kv, rep, hd), ``k`` and ``v`` (T, kv,
+    hd), ``seg`` (T,); returns (T, kv, rep, hd) in ``dtype``.  The backward
+    pass runs under the ``jax.named_scope``s ``scopes``.  ``forward`` and
+    ``backward`` are each pass's (queries, keys) a tile."""
+    import jax.numpy as jnp
+
+    t = q.shape[0]
+    return _attend()(q, k, v, seg.astype(jnp.int32), float(scale),
+                     jnp.dtype(dtype), tuple(scopes), _blocks(t, forward),
+                     _blocks(t, backward))

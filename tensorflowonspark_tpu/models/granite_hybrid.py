@@ -33,7 +33,11 @@ tests) as ``jnp`` code.  :func:`scan_runs_fused` is the rule, and a step
 counts which applied (``ssm_scan_fused_steps_total`` /
 ``ssm_scan_plain_steps_total``).
 The norm, the products, the feed-forward, the attention and the blocked
-loss are ``packed_rows``'s, which ``mla_moe`` calls too.
+loss are ``packed_rows``'s, which ``mla_moe`` calls too.  Attention has two
+executions as well (``packed_rows.attention_runs_fused``): its kernels want
+a head to fill whole rows of 128 lanes, so the published 32/8 heads of 64
+keep the ``jnp`` form on every backend, and a step says so
+(``attention_plain_steps_total``, ``attention_fused_steps_total`` 0).
 Parameters are float32; activations are ``Config.dtype``.  Every layer is
 recomputed in the backward pass (``jax.checkpoint``), attention runs a block
 of queries at a time and the training loss a block of tokens at a time, so
@@ -56,8 +60,8 @@ import math
 import numpy as np
 
 from tensorflowonspark_tpu.models.packed_rows import (
-    block as _block, blocked_cross_entropy, document_attention,
-    loss_positions, mm as _mm, rms as _rms, swiglu)
+    _backend, attention_runs_fused, block as _block, blocked_cross_entropy,
+    document_attention, loss_positions, mm as _mm, rms as _rms, swiglu)
 
 #: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -174,14 +178,6 @@ def causal_conv(xbc, w, b, seg):
         same = jnp.pad(seg[:-j], (j, 0), constant_values=-1) == seg
         y = y + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - j]
     return y
-
-
-def _backend() -> str:
-    """The backend the process computes on (a compile test for a described
-    chip, on a CPU host, says "tpu" here)."""
-    import jax
-
-    return jax.default_backend()
 
 
 def scan_runs_fused(chunk: int, heads: int, p: int, groups: int,
@@ -487,21 +483,25 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters.  From its host batch:
     tokens, tokens that bear a loss (the next token is the same document's)
-    and documents (runs of one segment id).  From the rule its trace
-    applied (:func:`scan_runs_fused`): one step of the scan on the kernels
-    or as ``jnp`` code, the other named with 0 so that both are on the
-    record."""
+    and documents (runs of one segment id).  From the rules its trace
+    applied (:func:`scan_runs_fused`, ``packed_rows.attention_runs_fused``):
+    one step of the scan, and one of attention, on the kernels or as
+    ``jnp`` code, the other named with 0 so that both are on the record."""
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
     scans = "mamba" in config.layer_types
     fused = scans and scan_runs_fused(
         config.mamba_chunk_size, config.mamba_n_heads, config.mamba_d_head,
         config.mamba_n_groups, config.mamba_d_state)
+    attends = "attention" in config.layer_types
+    on_chip = attends and attention_runs_fused(seg.shape[1], config.head_dim)
     return {"lm_tokens_total": int(seg.size),
             "lm_loss_tokens_total": int(same.sum()),
             "lm_documents_total": int(seg.shape[0] + (~same).sum()),
             "ssm_scan_fused_steps_total": int(fused),
-            "ssm_scan_plain_steps_total": int(scans and not fused)}
+            "ssm_scan_plain_steps_total": int(scans and not fused),
+            "attention_fused_steps_total": int(on_chip),
+            "attention_plain_steps_total": int(attends and not on_chip)}
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0,

@@ -51,7 +51,11 @@ keeps one replica's counts, ``ROADMAP.md`` B.)
 Parameters are float32, activations ``Config.dtype``.  Every layer is
 recomputed in the backward pass, attention runs a block of queries at a time
 and each loss a block of tokens at a time (``packed_rows``, shared with
-``granite_hybrid``); none of the three is an option.
+``granite_hybrid``); none of the three is an option.  On a TPU the published
+heads (20 x 256) run attention on the Pallas kernels of ``attention_pallas``
+(``packed_rows.attention_runs_fused`` is the rule), anywhere else and at
+``Config.tiny()`` as ``jnp`` code, and a step counts which applied
+(``attention_fused_steps_total`` / ``attention_plain_steps_total``).
 
 ``jax.named_scope`` names a device trace can be cut by: ``attention`` >
 ``mla_project`` (the latent projections, their norms, RoPE); ``mlp`` (the
@@ -72,8 +76,8 @@ import math
 import numpy as np
 
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, blocked_cross_entropy, document_attention, loss_positions, mm,
-    rms, swiglu)
+    attention_runs_fused, block, blocked_cross_entropy, document_attention,
+    loss_positions, mm, rms, swiglu)
 
 #: no sequence-parallel sharding: attention sees a whole row
 SEQUENCE_AXES: dict = {}
@@ -510,17 +514,23 @@ def make_forward_fn(module, config: Config):
 
 
 def batch_counters(batch, config: Config) -> dict:
-    """What one step adds to the program's counters, from its host batch:
+    """What one step adds to the program's counters.  From its host batch:
     tokens, tokens that bear the main loss (the next token is the same
-    document's) and the second (the two next are), and documents."""
+    document's) and the second (the two next are), and documents.  From the
+    rule its trace applied (``packed_rows.attention_runs_fused``): one step
+    of attention on the kernels or as ``jnp`` code, the other named with 0
+    so that both are on the record."""
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
+    on_chip = attention_runs_fused(seg.shape[1], config.qk_head_dim)
     return {"lm_tokens_total": int(seg.size),
             "lm_loss_tokens_total": int(same.sum()),
             "mtp_loss_tokens_total": int(
                 (same[:, 1:] & same[:, :-1]).sum()
                 if config.num_nextn_predict_layers else 0),
-            "lm_documents_total": int(seg.shape[0] + (~same).sum())}
+            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
+            "attention_fused_steps_total": int(on_chip),
+            "attention_plain_steps_total": int(not on_chip)}
 
 
 def device_counters(collections, config: Config) -> dict:

@@ -9,6 +9,16 @@ inside the row; documents are contiguous and their ids differ).  One
 implementation of each piece, called by both models: what is measured on one
 model's cell is what the other runs.
 
+Attention is one algorithm with two executions
+(:func:`document_attention`): on a TPU, where a head fills whole rows of
+128 lanes (GLM's 20 x 256 do, granite's 32/8 x 64 do not), the Pallas
+kernels of ``attention_pallas`` keep every (queries x keys) score tile on
+the chip, forward and backward; on any other backend and at other shapes
+(``Config.tiny()``, the tests) the ``jnp`` form in this file runs, which is
+also the kernels' oracle.  :func:`attention_runs_fused` is the rule, and
+both models' steps count which applied (``attention_fused_steps_total`` /
+``attention_plain_steps_total``).
+
 JAX is imported where it is used, as in the models.
 """
 
@@ -41,6 +51,29 @@ def mm(spec, a, b, dtype, out=None):
 def block(total: int, want: int) -> int:
     """The largest divisor of ``total`` that is at most ``want``."""
     return next(b for b in range(min(want, total), 0, -1) if total % b == 0)
+
+
+def _backend() -> str:
+    """The backend the process computes on (a compile test for a described
+    chip, on a CPU host, says "tpu" here)."""
+    import jax
+
+    return jax.default_backend()
+
+
+def attention_runs_fused(t: int, hd: int) -> bool:
+    """How :func:`document_attention` executes on a row of ``t`` tokens and
+    heads of ``hd``: on the Pallas kernels of ``attention_pallas`` (True) or
+    as ``jnp`` code (False).  Decided from what the code can observe: the
+    backend is a TPU, a head's row fills whole rows of 128 lanes and the
+    row of tokens is whole blocks of the kernels' own size
+    (``attention_pallas.fits``: GLM's published 20 x 256 over 8,192 tokens
+    do; granite's 32/8 x 64, whose heads would have to be packed in pairs,
+    and ``Config.tiny()``'s do not).  The number of heads does not enter:
+    the kernels take any."""
+    from tensorflowonspark_tpu.models import attention_pallas
+
+    return _backend() == "tpu" and attention_pallas.fits(t, hd)
 
 
 def swiglu(h, w_gate, w_up, w_down):
@@ -99,9 +132,21 @@ def _attend_fwd(q, k, v, seg, scale, size, dtype):
     return out.reshape(t, kv, rep, hd), lse
 
 
-def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
+def under(scopes):
+    """The ``jax.named_scope``s ``scopes``, opened one inside the other: a
+    custom backward pass is traced outside the scopes its forward pass was
+    called under, and opens them again itself."""
     import contextlib
 
+    import jax
+
+    stack = contextlib.ExitStack()
+    for scope in scopes:
+        stack.enter_context(jax.named_scope(scope))
+    return stack
+
+
+def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
     import jax
     import jax.numpy as jnp
 
@@ -133,11 +178,7 @@ def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
             0, i + 1, keys, (jnp.zeros(qb.shape, f32),) + carry)
         return (dk, dv), dq.astype(dtype)
 
-    # a custom backward pass is traced outside the scopes its forward pass
-    # was called under: it opens them again itself
-    with contextlib.ExitStack() as stack:
-        for scope in scopes:
-            stack.enter_context(jax.named_scope(scope))
+    with under(scopes):
         (dk, dv), dq = jax.lax.scan(
             block_, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)), (
                 q.reshape(n, size, kv, rep, hd),
@@ -170,16 +211,27 @@ def _attend():
 def document_attention(q, k, v, seg, scale: float, size: int, dtype,
                        scopes: tuple = ("attention",)):
     """Causal attention inside documents over one packed row, blocks of
-    ``size`` queries against blocks of ``size`` keys with a running softmax:
-    a block of queries visits the blocks of keys up to its own, so no score
-    above the diagonal is ever made and none is held beyond its block.  The
-    backward pass recomputes each block's probabilities from the saved
-    log-sum-exp, under the ``jax.named_scope``s ``scopes`` (the caller's:
-    the forward pass runs under the caller's own).  Every row costs the
-    same whatever its documents are (the blocks of another document are
-    visited and masked): a step's time does not depend on the data.  ``q``
-    (T, kv, rep, hd), ``k`` and ``v`` (T, kv, hd), ``seg`` (T,); returns
-    (T, kv, rep, hd)."""
+    queries against blocks of keys with a running softmax: a block of
+    queries visits the blocks of keys up to its own, so no score above the
+    diagonal is ever made and none is held beyond its block.  The backward
+    pass recomputes each block's probabilities from the saved log-sum-exp,
+    under the ``jax.named_scope``s ``scopes`` (the caller's: the forward
+    pass runs under the caller's own).  Every row costs the same whatever
+    its documents are (the blocks of another document are visited and
+    masked): a step's time does not depend on the data.  ``q`` (T, kv, rep,
+    hd), ``k`` and ``v`` (T, kv, hd), ``seg`` (T,); returns (T, kv, rep,
+    hd).
+
+    One algorithm, two executions (:func:`attention_runs_fused`): on a TPU,
+    where a head fills whole rows of lanes, the kernels of
+    ``attention_pallas`` keep each score tile on the chip, forward and
+    backward; anywhere else the ``jnp`` form above runs in blocks of
+    ``size``, which is also the kernels' oracle."""
+    if attention_runs_fused(q.shape[0], q.shape[-1]):
+        from tensorflowonspark_tpu.models import attention_pallas
+
+        return attention_pallas.fused_attention(q, k, v, seg, scale, dtype,
+                                                scopes)
     return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes))
 
 

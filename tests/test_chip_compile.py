@@ -268,6 +268,54 @@ def test_granite_scan_kernels_compile_at_the_published_widths(topo):
         assert compiled.memory_analysis().temp_size_in_bytes < tile_bytes
 
 
+def test_attention_kernels_compile_at_the_published_widths(topo):
+    """The Pallas kernels of ``document_attention``
+    (``models/attention_pallas.py``) through the TPU's compiler at the
+    shapes ``glm47_flash_packed_8k`` runs — one row of 8,192 tokens under
+    ``jax.vmap``, 20 heads of 256, bfloat16 — forward and gradient: this
+    refuses what interpret mode cannot (a misaligned slice, a transpose the
+    chip has not, more fast memory than a kernel may use: a head's whole row
+    is resident).  Compiled, every kernel call still carries ``attention``
+    as a word of its ``op_name`` (``benchmark/device_scopes.py`` finds
+    ``mla_device_ms`` by it; the backward pass opens the scope itself), and
+    no score leaves a kernel: the temporaries are ``out``, the loss's
+    float32 copy of it and the gradients, each the size of an operand (84
+    MB)."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import attention_pallas
+
+    t, heads, hd = 8192, 20, 256
+    assert attention_pallas.fits(t, hd)
+    one = SingleDeviceSharding(topo.devices[0])
+    bf = jnp.bfloat16
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((1, t, heads, 1, hd), bf), ((1, t, heads, hd), bf),
+        ((1, t, heads, hd), bf), ((1, t), jnp.int32))]
+
+    def loss(q, k, v, seg):
+        with jax.named_scope("attention"):
+            out = jax.vmap(lambda q, k, v, seg: (
+                attention_pallas.fused_attention(
+                    q, k, v, seg, 1 / 16, bf, ("attention",))))(q, k, v, seg)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    for fn, kernels in ((loss, ["attention_forward"]),
+                        (jax.grad(loss, (0, 1, 2)),
+                         ["attention_forward", "attention_backward"])):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        names = re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+            compiled.as_text())
+        assert [n.split("/")[-2] for n in names] == kernels, names
+        assert all(re.search(r"\battention\b", name) for name in names), names
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 6 * t * heads * hd * 2)
+
+
 @pytest.mark.slow  # ~60 s here; the builder's by-hand rehearsal
 def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``granite_4_0_h_micro`` configuration as the benchmark builds it
@@ -310,7 +358,7 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
 
 
 @pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
-def test_glm_published_width_step_fits_one_v5e_chip(topo):
+def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``glm_4_7_flash`` configuration as the benchmark builds it (the
     dense layer, four expert layers with 8 of 64 experts held, the
     prediction module, an eighth of the vocabulary: 706,518,528 float32
@@ -318,11 +366,18 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo):
     TPU compiler: parameters, both moments and the routing state are
     donated and updated in place, the grouped products are the compiler's
     own kernels (three a layer forward, three recomputed, six backward),
-    and arguments plus temporaries stay under the chip's memory.  PERF.md
-    section 4 holds the figures."""
+    attention is the one a chip runs (the Pallas kernels: here the backend
+    is the CPU, so the test says "tpu" in ``packed_rows``'s place; a layer
+    calls the forward kernel, calls it again in its recomputation and the
+    backward kernel once), and arguments plus temporaries stay under the
+    chip's memory.  PERF.md section 4 holds the figures."""
     import json
+    import re
 
     from benchmark.configs.glm_4_7_flash import program
+    from tensorflowonspark_tpu.models import packed_rows
+
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs", "glm_4_7_flash",
                            "config.json")) as f:
@@ -339,6 +394,14 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo):
     text = compiled.as_text()
     assert text.count(" custom-call(") >= 5 * 12
     assert text.count('op_name="ragged-dot-none"') == 5 * 12
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    # (the compiler's own ``ragged-dot`` kernels are custom calls too)
+    ours = [n for n in kernels if "/attention_" in n]
+    for kernel, calls in (("attention_forward", 12),
+                          ("attention_backward", 6)):
+        assert sum(f"/{kernel}/" in n for n in ours) == calls, ours
+    assert all(re.search(r"\battention\b", n) for n in ours), ours
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
     assert stats.argument_size_in_bytes < state_bytes + 2 ** 20

@@ -459,6 +459,10 @@ def test_granite_step_counts_the_execution_of_its_scan(tiny, monkeypatch):
             counts["ssm_scan_plain_steps_total"]) == (1, 0)
     assert gh.batch_counters(_rows(config, 1, 15), config)[
         "ssm_scan_plain_steps_total"] == 1      # tiny shapes: still jnp
+    # attention's execution is on the record beside the scan's
+    counters = obs.get_registry().snapshot()["counters"]
+    assert counters["attention_plain_steps_total"] >= 2
+    assert counters.get("attention_fused_steps_total", 0) == 0
 
 
 @pytest.mark.parametrize("size", [4, 16, 48])
