@@ -122,7 +122,16 @@ step — never per record, row or chunk):
   ``feeder.send``, ``feeder.drain_wait``;
 - trainer: ``trainer.init``, ``trainer.step`` (attr ``step``, a trace id
   of its own) > ``trainer.shard``, ``trainer.dispatch``,
-  ``trainer.checkpoint``; ``ckpt.save`` / ``ckpt.restore``;
+  ``trainer.checkpoint``; ``ckpt.save`` / ``ckpt.restore``; and the
+  device's side, written by the trainer's completion watcher from its own
+  threads and in the ring only (``trainer._DeviceWatcher``):
+  ``trainer.h2d`` (one a batch that was the host's: the staging call's
+  start to every staged array ready on the device; attr ``bytes``) and
+  ``trainer.device_step`` (one a step, attr ``step``: from the latest of
+  the previous step's end, the dispatch's start and the batch's arrival —
+  ``after`` says which — to the loss ready; attrs ``input_wait_s``,
+  ``dispatch_s``; an upper estimate of the device's time from the host's
+  clock, not the device's own);
 - kernels: ``jax.named_scope`` ``forward`` and ``optimizer`` in the
   compiled step (``parallel/train.py``); counters
   ``table_update_rows_steps_total`` / ``table_update_full_steps_total``,

@@ -57,9 +57,10 @@ everything else dropped at commit.
 
 Env knobs: ``TFOS_TRACE=0`` disables recording entirely (the record path
 then costs one attribute check); the ring buffer holds 32768 events per
-process (a trainer records about 8.8 a step: 15,400 in a job of 1,750 steps,
-which the 16384 of before PR 31 held with 6% to spare; a ring that drops
-leaves every reader of its spans with nothing).
+process (a trainer records about 10.8 a step since PR 37's ``trainer.h2d``
+and ``trainer.device_step``: 18,900 in a job of 1,750 steps, where the 8.8
+a step of before made 15,400, which the 16384 of before PR 31 held with 6%
+to spare; a ring that drops leaves every reader of its spans with nothing).
 Request tracing has its own knobs: ``TFOS_TRACE_REQUESTS=0`` disables
 per-request span trees, ``TFOS_TRACE_ARM`` sets the fraction of (uniform-population) requests
 armed for capture (default 0.05 — explicit inbound contexts always arm,
@@ -528,7 +529,7 @@ class _Span:
     """
 
     __slots__ = ("_tracer", "name", "attrs", "_starts", "_flight", "dur_s",
-                 "trace_id", "_cancelled", "_root")
+                 "t0", "trace_id", "_cancelled", "_root")
 
     def __init__(self, tracer: Tracer, name: str, attrs: dict[str, Any]):
         self._tracer = tracer
@@ -538,8 +539,11 @@ class _Span:
         self._flight: tuple | None = None
         self._cancelled = False
         self._root = False
-        #: seconds the last completed entry took (None until one has)
+        #: seconds the last completed entry took (None until one has), and
+        #: its start on ``time.time()``: a site that hands the stretch on
+        #: (``Trainer``'s completion watcher) reads no clock again
         self.dur_s: float | None = None
+        self.t0: float | None = None
         #: trace id of the last entry (a root span's own, else inherited)
         self.trace_id: str | None = None
 
@@ -599,6 +603,7 @@ class _Span:
         (wall_t0, perf_t0, span_id, trace_id, parent_sid,
          annotation) = self._starts.pop()
         self.dur_s = dur_s = time.perf_counter() - perf_t0
+        self.t0 = wall_t0
         if annotation is not None:
             annotation.__exit__(exc_type, exc, tb)
         if self._flight is not None and not self._cancelled:

@@ -21,6 +21,8 @@ from __future__ import annotations
 
 import logging
 import os
+import queue
+import threading
 import time
 import weakref
 from typing import Any
@@ -108,6 +110,14 @@ class Trainer:
         self._batch_counters = getattr(self.module_lib, "batch_counters",
                                        None)
         self._staged_counts: dict = {}
+        # the device's side of every step (``trainer.h2d``,
+        # ``trainer.device_step``): a completion watcher, made at the first
+        # batch and only while the ring records; a batch a feed staged
+        # waits here, under its first array, for its step to find when it
+        # landed
+        self._watcher: _DeviceWatcher | None = None
+        self._watcher_lock = threading.Lock()
+        self._staged_arrivals: dict = {}
         self.forward_fn = self.module_lib.make_forward_fn(self.model, self.config)
 
         # example batch sized to the data-parallel world so the compiled
@@ -270,17 +280,41 @@ class Trainer:
     # -- stepping ------------------------------------------------------------
 
     def shard(self, batch):
+        """Stage a host batch on the mesh (a feed's ``device_put``, on its
+        pump's thread).  While the ring records, the transfer this starts
+        is the ``trainer.h2d`` span: from here to every staged array being
+        ready on the device, written by the completion watcher."""
+        watcher = self._watch()
+        t0 = time.time() if watcher is not None else 0.0
         staged = shard_batch(self.mesh, batch, self.sequence_axes)
         if self._batch_counters is not None and isinstance(
                 _first_leaf(batch), np.ndarray):
             # counted here, where the batch is still the host's; the counts
             # wait under the staged batch's first array until ``step`` is
             # handed it, and go with that array if it never is
-            first = _first_leaf(staged)
-            self._staged_counts[id(first)] = self._batch_counters(
-                batch, self.config)
-            weakref.finalize(first, self._staged_counts.pop, id(first), None)
+            _keep_under(self._staged_counts, _first_leaf(staged),
+                        self._batch_counters(batch, self.config))
+        if watcher is not None:
+            arrival = watcher.staged(batch, staged, t0)
+            if arrival is not None:
+                _keep_under(self._staged_arrivals, _first_leaf(staged),
+                            arrival)
         return staged
+
+    def _watch(self):
+        """The completion watcher; None while the ring does not record
+        (``TFOS_TRACE=0``: no thread, no queue, no clock read)."""
+        if not obs.get_tracer().enabled:
+            return None
+        if self._watcher is None:
+            with self._watcher_lock:
+                if self._watcher is None:
+                    self._watcher = _DeviceWatcher()
+                    # the threads end when the trainer goes; at the
+                    # interpreter's exit they are daemons and nothing
+                    # waits for a device that may be wedged
+                    weakref.finalize(self, self._watcher.close).atexit = False
+        return self._watcher
 
     def add_step_callback(self, fn) -> None:
         """Register ``fn(loss, examples, dt)`` to run after every step.
@@ -299,7 +333,12 @@ class Trainer:
         and ``trainer.dispatch``, ``trainer.checkpoint`` where one is
         taken): one pair of clock reads each feeds the ring, the flight
         stages ``shard`` / ``compute``, the goodput ledger and, in a
-        profiler session, an annotation of the same name and ``step``."""
+        profiler session, an annotation of the same name and ``step``.
+        The device's side of the step is ``trainer.device_step`` (attr
+        ``step``, this span's) and, for a batch that was the host's,
+        ``trainer.h2d``: the completion watcher (:class:`_DeviceWatcher`)
+        writes both from its own threads, in the ring only, when the loss
+        and the batch are ready; no step waits for either."""
         if self._watchdog is not None:
             return self._watchdogged_step(batch)
         with obs.span("trainer.step",
@@ -315,11 +354,16 @@ class Trainer:
         backends it understates true device time until dispatch throttling
         backs up — which is exactly when a step becomes device-bound and
         the number grows; with ``wait`` (the watchdogged step) the loss is
-        forced inside it, so it is true device wall.  The shard is its own
+        forced inside it, so it is true device wall.  The device's time is
+        the ``trainer.device_step`` span, which the completion watcher
+        ends when the loss is ready (its ``dispatch_s`` is this wall); the
+        flight stage and the goodput ledger keep `compute` as their input.
+        The shard is its own
         `shard` stage (not `stage`): a feed that already device_put the
         batch recorded the real transfer as `stage`, and this re-shard of
         device-resident arrays is ~free — sharing the name would
         bimodalize that histogram toward zero."""
+        watcher = self._watch()
         with obs.span("trainer.shard") as sh:
             staged = shard_batch(self.mesh, batch, self.sequence_axes)
         with obs.span("trainer.dispatch") as run:
@@ -328,6 +372,16 @@ class Trainer:
                 import jax
 
                 loss = jax.block_until_ready(loss)
+        if watcher is not None:
+            # a batch the feed staged (it passed through ``shard_batch``)
+            # brought its arrival; one that was the host's until now began
+            # its transfer under ``trainer.shard``
+            first = _first_leaf(staged)
+            arrival = (self._staged_arrivals.get(id(first))
+                       if first is _first_leaf(batch)
+                       else watcher.staged(batch, staged, sh.t0))
+            watcher.stepped(self._steps_done + 1, run.t0, run.dur_s,
+                            arrival, loss)
         # the spans' durations are the flight stages' and the goodput
         # ledger's (the first step's compute wall IS the jit compile —
         # note_step books it): no clock is read again
@@ -431,10 +485,13 @@ class Trainer:
         return loss
 
     def predict(self, batch):
+        # staged here, not through ``shard``: a batch that no step takes
+        # has nothing to count and no ``trainer.h2d`` to pair with one
+        staged = shard_batch(self.mesh, batch, self.sequence_axes)
         if getattr(self.forward_fn, "stateful", False):
             return self.eval_step(self.state.params, self.state.collections,
-                                  self.shard(batch))
-        return self.eval_step(self.state.params, self.shard(batch))
+                                  staged)
+        return self.eval_step(self.state.params, staged)
 
     @property
     def params(self):
@@ -632,6 +689,159 @@ class _DeviceCounters:
             obs.counter(name).inc(int(((now - self._seen[name])
                                        % (1 << 32)).sum()))
             self._seen[name] = now
+
+
+class _Arrival:
+    """When a staged batch was whole on the device: ``t0`` the staging
+    call's start, ``t1`` the watcher's clock read once every staged array
+    was ready (None while it is not, or if the transfer failed); both on
+    ``time.time()``."""
+
+    __slots__ = ("t0", "t1", "done")
+
+    def __init__(self, t0: float):
+        self.t0 = t0
+        self.t1: float | None = None
+        self.done = threading.Event()
+
+
+class _DeviceWatcher:
+    """The device's side of every step, seen from the host: two daemon
+    threads that wait where no training thread may (``block_until_ready``
+    releases the GIL), read ``time.time()`` and write ring spans.
+
+    - ``trainer.h2d``, one a staged batch: from the staging call's start
+      to every staged array being ready on the device; attr ``bytes`` (the
+      host arrays' ``nbytes``).  A batch whose arrays were all the
+      device's already has none.
+    - ``trainer.device_step``, one a step (attr ``step``): it ends when the
+      step's loss is ready and begins at the latest of the previous step's
+      end, its ``trainer.dispatch`` start and its batch's arrival
+      (``after``: ``prev`` / ``dispatch`` / ``input``).  ``input_wait_s``
+      is by how much the arrival followed the dispatch's start,
+      ``dispatch_s`` the dispatch's wall.  **An upper estimate of the
+      device's busy time, not the device's own clock**: where the
+      dispatch's start began it, the device's first operation lies
+      somewhere inside ``dispatch_s`` or shortly after (the host cannot
+      know where), and the end includes this thread's wake-up.
+
+    A transfer and a step finish in either order, and nothing waits for
+    "whichever first": so one thread a kind, each its own queue, one
+    ``put`` a batch and one a step.  A transfer's arrays are held until
+    they are ready, so a wait that never ends (a wedged device) keeps
+    alive behind it what the feed goes on to stage — its prefetch depth,
+    and never more than ``MAX_PENDING`` batches; the state is never
+    touched (it is donated).  A queue that deep takes no more: that batch
+    or step goes without its span.
+    """
+
+    MAX_PENDING = 64
+
+    def __init__(self):
+        self._transfers: queue.SimpleQueue = queue.SimpleQueue()
+        self._steps: queue.SimpleQueue = queue.SimpleQueue()
+        self._threads = [
+            threading.Thread(target=fn, args=(inbox,), daemon=True, name=name)
+            for fn, inbox, name in (
+                (_watch_transfers, self._transfers, "tfos-trainer-h2d"),
+                (_watch_steps, self._steps, "tfos-trainer-device-step"))]
+        for thread in self._threads:
+            thread.start()
+
+    def staged(self, batch, staged, t0: float):
+        """Hand over what one ``shard_batch`` call moved; returns the
+        batch's :class:`_Arrival`, or None where every array passed
+        through (or the queue is full)."""
+        import jax
+
+        moved, nbytes = [], 0
+        for host, dev in zip(jax.tree_util.tree_leaves(batch),
+                             jax.tree_util.tree_leaves(staged)):
+            if dev is not host:
+                moved.append(dev)
+                nbytes += int(getattr(host, "nbytes", 0))
+        if not moved or self._transfers.qsize() >= self.MAX_PENDING:
+            return None
+        arrival = _Arrival(t0)
+        self._transfers.put((arrival, moved, nbytes))
+        return arrival
+
+    def stepped(self, step: int, dispatch_t0: float, dispatch_s: float,
+                arrival, loss) -> None:
+        if self._steps.qsize() < self.MAX_PENDING:
+            self._steps.put((step, dispatch_t0, dispatch_s, arrival, loss))
+
+    def close(self, timeout_s: float = 5.0) -> None:
+        """Let both threads finish what they hold, then end them (the
+        collector may run this on one of them: that one is not joined)."""
+        for inbox in (self._transfers, self._steps):
+            inbox.put(None)
+        for thread in self._threads:
+            if thread is not threading.current_thread():
+                thread.join(timeout_s)
+
+
+def _watch_transfers(inbox) -> None:
+    import jax
+
+    while True:
+        item = inbox.get()
+        if item is None:
+            return
+        arrival, moved, nbytes = item
+        try:
+            jax.block_until_ready(moved)
+            arrival.t1 = time.time()
+        except Exception:       # the step that takes the batch raises it
+            logger.debug("a staged batch never became ready", exc_info=True)
+        # the arrays are let go before the next wait, whatever came of them
+        item = moved = None
+        arrival.done.set()
+        if arrival.t1 is not None:
+            obs.complete("trainer.h2d", arrival.t0, arrival.t1 - arrival.t0,
+                         bytes=nbytes)
+
+
+def _watch_steps(inbox) -> None:
+    import jax
+
+    prev_end = 0.0
+    while True:
+        item = inbox.get()
+        if item is None:
+            return
+        step, dispatch_t0, dispatch_s, arrival, loss = item
+        try:
+            jax.block_until_ready(loss)
+        except Exception:       # the caller meets it when it reads the loss
+            logger.debug("step %d's loss never became ready", step,
+                         exc_info=True)
+            item = loss = None
+            continue
+        end = time.time()
+        item = loss = None
+        arrived = 0.0
+        if arrival is not None:
+            # the loss is ready, so the batch was: at most the other
+            # thread's wake-up is waited for
+            arrival.done.wait(1.0)
+            arrived = arrival.t1 or 0.0
+        begin, after = max((prev_end, "prev"), (dispatch_t0, "dispatch"),
+                           (arrived, "input"))
+        begin = min(begin, end)
+        obs.complete("trainer.device_step", begin, end - begin, step=step,
+                     after=after, dispatch_s=dispatch_s,
+                     input_wait_s=max(0.0, arrived - dispatch_t0))
+        prev_end = end
+
+
+def _keep_under(table: dict, leaf, value) -> None:
+    """``table[id(leaf)] = value`` for as long as ``leaf`` (a staged
+    batch's first array) lives: what a feed's staging call knows waits
+    there for the step that is handed the batch, and goes with the array
+    if none is."""
+    table[id(leaf)] = value
+    weakref.finalize(leaf, table.pop, id(leaf), None)
 
 
 def _first_leaf(batch):
