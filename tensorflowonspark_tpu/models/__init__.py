@@ -29,7 +29,8 @@ and shared experts, the multi-token-prediction module), share
 of ``attention_pallas.py``; granite's scan has ``ssd_pallas.py``), the
 blocked loss.  Two expert layers live in ``parallel/moe.py``: ``bert`` calls
 ``moe_ffn`` (Switch top-1 with a capacity, over ``ep``), ``mla_moe`` calls
-``routed_experts`` (top-k of a wide router, the experts held here, no drop).
+``routed_experts`` (top-k of a wide router, the experts held here, no drop,
+the work sized to a step's own count of slots that landed here).
 """
 
 from __future__ import annotations

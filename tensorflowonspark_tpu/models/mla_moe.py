@@ -36,14 +36,15 @@ document::
 ``Config.experts_held`` says which of the ``n_routed_experts`` this chip
 holds (all of them unless told otherwise): the router stays as wide as
 published, the held experts' part of the result is computed
-(``parallel/moe.py::routed_experts``: every slot kept, grouped products over
+(``parallel/moe.py::routed_experts``: every slot kept, the work sized to
 the rows that landed here) and what the others would have added is left
 out.  No exchange runs and none is stood in for.
 
 The correction biases take no gradient.  They live, with the cumulative
-count of tokens by expert and the cumulative size of each layer's fullest
-expert, in the ``moe`` collection, which the Trainer's stateful step
-threads and checkpoints; :func:`device_counters` names what of it the
+count of tokens by expert, the cumulative size of each layer's fullest
+expert and the steps in which a layer's held slots overflowed
+``moe.prefix_rows``, in the ``moe`` collection, which the Trainer's stateful
+step threads and checkpoints; :func:`device_counters` names what of it the
 program's counters show.  (One data shard is what has run: on a
 data-parallel mesh the bucketed step averages the replicas' biases and
 keeps one replica's counts, ``ROADMAP.md`` B.)
@@ -208,7 +209,7 @@ def collection_shapes(config: Config) -> dict:
     """The ``moe`` collection: a row an expert layer, in forward order."""
     rows, e = config.expert_layers, config.n_routed_experts
     return {"bias": ((rows, e), "float32"), "counts": ((rows, e), "int32"),
-            "busiest": ((rows,), "int32")}
+            "busiest": ((rows,), "int32"), "overflow": ((rows,), "int32")}
 
 
 # ---------------------------------------------------------------------------
@@ -422,18 +423,29 @@ def loss_terms(params, bias, tokens, segment_ids, config: Config):
     return (*main, *sums(h, "mtp_head_norm", 2), jnp.stack(counts + [c]))
 
 
-def step_collection(collection: dict, counts, config: Config) -> dict:
-    """The ``moe`` collection after a step whose tokens chose ``counts``
-    (expert layers, E): every layer's bias moves ``bias_update_speed``
-    towards its mean load, the counts add up."""
+def step_collection(collection: dict, counts, config: Config,
+                    tokens: int) -> dict:
+    """The ``moe`` collection after a step whose ``tokens`` tokens chose
+    ``counts`` (expert layers, E): every layer's bias moves
+    ``bias_update_speed`` towards its mean load, the counts add up, and a
+    layer whose held experts were chosen more often than
+    ``moe.prefix_rows`` allows (``routed_experts`` then took all the slots)
+    is counted."""
     import jax.numpy as jnp
 
+    from tensorflowonspark_tpu.parallel import moe
+
     load = counts.astype(jnp.float32)
+    held = jnp.asarray(config.experts_held, jnp.int32)
+    fits = moe.prefix_rows(tokens * config.num_experts_per_tok, len(held),
+                           config.n_routed_experts)
     return {
         "bias": collection["bias"] + config.bias_update_speed * jnp.sign(
             jnp.mean(load, axis=-1, keepdims=True) - load),
         "counts": collection["counts"] + counts,
         "busiest": collection["busiest"] + jnp.max(counts, axis=-1),
+        "overflow": collection["overflow"] + (
+            jnp.sum(counts[:, held], axis=-1) > fits),
     }
 
 
@@ -470,7 +482,7 @@ def make_model(config: Config, mesh=None):
                       for name, shape in shapes.items()}
             bias = self.variable(
                 COLLECTION, "bias", jnp.zeros, *state["bias"]).value
-            for name in ("counts", "busiest"):
+            for name in ("counts", "busiest", "overflow"):
                 self.variable(COLLECTION, name, jnp.zeros, *state[name])
             return apply_tokens(params, bias, tokens, segment_ids, config)
 
@@ -498,7 +510,8 @@ def make_loss_fn(module, config: Config):
         loss = (main / jnp.maximum(n_main, 1)
                 + config.mtp_loss_weight * mtp / jnp.maximum(n_mtp, 1))
         return loss, {**collections,
-                      COLLECTION: step_collection(state, counts, config)}
+                      COLLECTION: step_collection(
+                          state, counts, config, batch["tokens"].size)}
 
     loss_fn.stateful = True
     return loss_fn
@@ -538,14 +551,16 @@ def device_counters(collections, config: Config) -> dict:
     int32 arrays whose growth the Trainer adds up, element by element
     (a running total would outgrow 32 bits; an element takes a million
     steps of a row to).  Slots (a token's choice of an expert) routed, the
-    slots whose expert is held here, and every layer's fullest expert."""
+    slots whose expert is held here, every layer's fullest expert, and the
+    layers whose held slots overflowed ``moe.prefix_rows``."""
     import jax.numpy as jnp
 
     state = collections[COLLECTION]
     held = jnp.asarray(config.experts_held, jnp.int32)
     return {"moe_slots_total": state["counts"],
             "moe_local_slots_total": state["counts"][:, held],
-            "moe_busiest_expert_slots_total": state["busiest"]}
+            "moe_busiest_expert_slots_total": state["busiest"],
+            "moe_overflow_layers_total": state["overflow"]}
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0,

@@ -35,13 +35,20 @@ chip holds.  It scores every token against all of them (sigmoid, float32),
 chooses the ``top_k`` of score plus a correction bias that takes no
 gradient, weighs the chosen by their normalised scores, keeps every slot
 (token, choice) whose expert is held — any number of them, from none to
-all — sorts the kept slots by expert and multiplies them expert by expert
-as grouped products (``jax.lax.ragged_dot``: the cost follows the slots that
-landed here, not the worst case its buffers are sized for), and adds the
-results back weighted.  What the experts held elsewhere would have added is
-left out; on one chip no exchange runs.  It returns how many tokens chose
-each of the router's experts, which is what the caller's bias update and
-counters read.  No capacity factor exists and no auxiliary loss.
+all — sorts the kept slots by expert, the held experts' first, and
+multiplies those expert by expert as grouped products
+(``jax.lax.ragged_dot``), and adds the results back weighted.  The slots that
+landed here are a prefix of the sorted order whose length the device knows
+after the router: everything after the router is one function of a static
+row count, traced at :func:`prefix_rows` (three times an even router's share)
+and
+at all the slots, and a ``jax.lax.cond`` takes the first wherever the step's
+count fits it — so gathers, products, masks and sums work on the rows that
+landed here, not on the worst case, and a step that overflows is still
+exact.  What the experts held elsewhere would have added is left out; on one
+chip no exchange runs.  It returns how many tokens chose each of the
+router's experts, which is what the caller's bias update and counters read.
+No capacity factor exists and no auxiliary loss.
 """
 
 from __future__ import annotations
@@ -312,52 +319,150 @@ def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
     return chosen, scale * picked
 
 
+def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
+    """Rows the routed part of :func:`routed_experts` works on when a
+    step's held slots fit them: three times what an even router sends to
+    ``n_held`` of ``n_experts`` experts out of ``slots`` slots, in whole
+    sublanes of 8, and never more than ``slots`` (where a third or more of
+    the experts are held there is one form only).  Three, because a router
+    of seeded weights sends one expert half the tokens: a layer that holds
+    it lands a little over twice the even share in most steps, and at twice
+    such a step took the whole form and 11 ms more, where the rows between
+    twice and three times cost every step 5 (``glm47_flash_packed_8k``,
+    PERF.md section 6)."""
+    share = -(-3 * slots * n_held // n_experts)
+    return min(slots, -(-share // 8) * 8)
+
+
 @functools.lru_cache(maxsize=None)
-def _permutation_ops():
-    """``(spread, gather_back)``: the two row permutations of the layer,
-    each with the other's gather as its backward pass (a gather's own
-    transpose is a scatter, which a TPU runs a row at a time).  Made once:
-    the module imports JAX only when it is used."""
+def _routed_part():
+    """The held experts' part of the layer after the router, as a function
+    of how many sorted rows it works on.  Made once: the module imports JAX
+    only when it is used."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    f32 = jnp.float32
+
     def no_grad(a):
         return np.zeros(a.shape, jax.dtypes.float0)
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-    def spread(x, order, inv, k):
-        """(T, D) tokens -> (T k, D): row ``i`` is the token of slot
-        ``order[i]`` (slot ``t k + j`` is token ``t``'s ``j``-th choice)."""
-        return jnp.take(x, order // k, axis=0)
+    def rows_of_choice(rows, inv, k, j):
+        """(R, D) sorted rows -> (T, D) float32: the row of every token's
+        ``j``-th choice, zero where its place is past R.  A choice at a
+        time: (T k, D) reshaped to (T, k, D) is a copy on a TPU (k rows to
+        a tile of 8), and no float32 array has a row a slot."""
+        return jnp.take(rows, inv.reshape(-1, k)[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(f32)
 
-    def spread_fwd(x, order, inv, k):
-        return spread(x, order, inv, k), (order, inv)
+    # The two row permutations.  A gather's own transpose is a scatter,
+    # which a TPU runs a row at a time (0.87 ms for 8,192 rows of 2,048
+    # where a gather of as many takes 0.05): each has gathers as its
+    # backward pass.  ``idx`` (R,) are the first R slots in sorted order (slot
+    # ``t k + j`` is token ``t``'s ``j``-th choice), ``inv`` (T k,) every
+    # slot's place in that order.
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def spread(x, idx, inv, k):
+        """(T, D) tokens -> (R, D): row ``i`` is the token of slot
+        ``idx[i]``."""
+        return jnp.take(x, idx // k, axis=0)
+
+    def spread_fwd(x, idx, inv, k):
+        return spread(x, idx, inv, k), (idx, inv)
 
     def spread_bwd(k, saved, d):
-        order, inv = saved
+        idx, inv = saved
         with jax.named_scope("moe_dispatch"):
-            dx = jnp.take(d, inv, axis=0).reshape(-1, k, d.shape[-1])
-            dx = jnp.sum(dx.astype(jnp.float32), axis=1).astype(d.dtype)
-        return dx, no_grad(order), no_grad(inv)
+            dx = sum(rows_of_choice(d, inv, k, j) for j in range(k))
+        return dx.astype(d.dtype), no_grad(idx), no_grad(inv)
 
     spread.defvjp(spread_fwd, spread_bwd)
 
     @jax.custom_vjp
-    def gather_back(rows, order, inv):
-        """Sorted rows -> slot order: row ``s`` is ``rows[inv[s]]``."""
-        return jnp.take(rows, inv, axis=0)
+    def combine(rows, gates, idx, inv):
+        """Sorted rows (R, D) -> (T, D): ``y[t] = sum over j of
+        gates[t, j] rows[inv[t k + j]]``, summed in float32."""
+        k = gates.shape[1]
+        return sum(gates[:, j, None] * rows_of_choice(rows, inv, k, j)
+                   for j in range(k)).astype(rows.dtype)
 
-    def back_fwd(rows, order, inv):
-        return gather_back(rows, order, inv), (order, inv)
+    def combine_fwd(rows, gates, idx, inv):
+        return combine(rows, gates, idx, inv), (rows, gates, idx, inv)
 
-    def back_bwd(saved, d):
-        order, inv = saved
+    def combine_bwd(saved, dy):
+        rows, gates, idx, inv = saved
         with jax.named_scope("moe_combine"):
-            return jnp.take(d, order, axis=0), no_grad(order), no_grad(inv)
+            dy_rows = jnp.take(dy, idx // gates.shape[1], axis=0).astype(f32)
+            d_rows = jnp.take(gates.reshape(-1), idx)[:, None] * dy_rows
+            d_gates = jnp.take(jnp.sum(dy_rows * rows.astype(f32), axis=-1),
+                               inv, mode="fill", fill_value=0)
+        return (d_rows.astype(rows.dtype), d_gates.reshape(gates.shape),
+                no_grad(idx), no_grad(inv))
 
-    gather_back.defvjp(back_fwd, back_bwd)
-    return spread, gather_back
+    combine.defvjp(combine_fwd, combine_bwd)
+
+    def over_rows(n_rows, k, order, inv, group_sizes, x, gates, w_gate, w_up,
+                  w_down):
+        """The routed part over the first ``n_rows`` sorted slots, which
+        hold every live one: spread, three grouped products, weighted sum
+        into (T, D)."""
+        dtype = x.dtype
+        with jax.named_scope("moe_dispatch"):
+            idx = order[:n_rows]
+            live = (jnp.arange(n_rows) < jnp.sum(group_sizes))[:, None]
+            # rows past the live ones belong to no group: whatever a
+            # grouped product leaves there never meets a live row
+            xs = jnp.where(live, spread(x, idx, inv, k), 0)
+
+        def grouped(rows, w):
+            with jax.named_scope("moe_experts"):
+                return jax.lax.ragged_dot(rows, w.astype(dtype), group_sizes,
+                                          preferred_element_type=f32)
+
+        act = (jax.nn.silu(grouped(xs, w_gate))
+               * grouped(xs, w_up)).astype(dtype)
+        out = grouped(act, w_down)
+        with jax.named_scope("moe_combine"):
+            out = jnp.where(live, out, 0).astype(dtype)
+            return combine(out, gates, idx, inv)
+
+    def by_count(n_prefix, form, order, inv, group_sizes, *operands):
+        """``form(n_rows)`` of the operands at ``n_prefix`` rows where this
+        step's live slots fit them, at all the slots where they do not;
+        the device chooses."""
+        n_slots, args = order.shape[0], (order, inv, group_sizes, *operands)
+        if n_prefix >= n_slots:
+            return form(n_slots)(*args)
+        return jax.lax.cond(jnp.sum(group_sizes) <= n_prefix, form(n_prefix),
+                            form(n_slots), *args)
+
+    # A differentiated ``cond`` has every branch write zeros in the place
+    # of the other's residuals: the forward and the backward pass choose
+    # each for itself, and the backward one makes the products again.
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+    def routed(n_prefix, k, *args):
+        return by_count(n_prefix,
+                        lambda n: functools.partial(over_rows, n, k), *args)
+
+    def routed_fwd(n_prefix, k, *args):
+        return routed(n_prefix, k, *args), args
+
+    def routed_bwd(n_prefix, k, args, dy):
+        def backward(n_rows):
+            def run(order, inv, group_sizes, dy, *operands):
+                return jax.vjp(functools.partial(
+                    over_rows, n_rows, k, order, inv, group_sizes),
+                    *operands)[1](dy)
+            return run
+
+        grads = by_count(n_prefix, backward, *args[:3], dy, *args[3:])
+        return (*map(no_grad, args[:3]), *grads)
+
+    routed.defvjp(routed_fwd, routed_bwd)
+    return routed
 
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
@@ -373,16 +478,16 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     operands in ``x``'s type and accumulate in float32; routing is float32.
 
     Every slot is kept: the ``T top_k`` slots are sorted by held expert
-    (those of experts held elsewhere last), the buffers hold all of them,
-    and the grouped products run over the rows that landed here.  Returns
-    ``(y, counts)``: ``y`` (T, D) in ``x``'s type, ``counts`` (E,) int32."""
+    (those of experts held elsewhere last), so the live ones are the first
+    ``sum(counts[held])`` rows, and everything after the router runs over
+    :func:`prefix_rows` of them where the step's live rows fit, over all
+    the slots where they do not (one function at two sizes; the device
+    chooses, a layer and a step at a time).  Returns ``(y, counts)``: ``y``
+    (T, D) in ``x``'s type, ``counts`` (E,) int32."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    spread, gather_back = _permutation_ops()
-    f32, dtype = jnp.float32, x.dtype
-    t, d = x.shape
     n_experts, n_held = router_w.shape[1], len(held)
     held = np.asarray(held, np.int32)
     if (n_held != w_gate.shape[0] or len(set(held.tolist())) != n_held
@@ -400,22 +505,7 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
         place[held] = np.arange(n_held)
         order = jnp.argsort(jnp.asarray(place)[slot_expert], stable=True)
         inv = jnp.argsort(order)
-        group_sizes = counts[held]
-        live = (jnp.arange(t * top_k) < jnp.sum(group_sizes))[:, None]
-        # rows past the live ones belong to no group: whatever a grouped
-        # product leaves there never meets a live row
-        xs = jnp.where(live, spread(x, order, inv, top_k), 0)
-
-    def grouped(rows, w):
-        with jax.named_scope("moe_experts"):
-            return jax.lax.ragged_dot(rows, w.astype(dtype), group_sizes,
-                                      preferred_element_type=f32)
-
-    act = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dtype)
-    out = grouped(act, w_down)
-    with jax.named_scope("moe_combine"):
-        out = jnp.where(live, out, 0).astype(dtype)
-        out = gather_back(out, order, inv).reshape(t, top_k, d)
-        y = jnp.einsum("tk,tkd->td", gates, out.astype(f32),
-                       preferred_element_type=f32).astype(dtype)
+    y = _routed_part()(
+        prefix_rows(slot_expert.shape[0], n_held, n_experts), top_k, order,
+        inv, counts[held], x, gates, w_gate, w_up, w_down)
     return y, counts

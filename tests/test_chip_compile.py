@@ -365,7 +365,8 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     parameters under AdamW) on one packed row of 8,192 tokens, through the
     TPU compiler: parameters, both moments and the routing state are
     donated and updated in place, the grouped products are the compiler's
-    own kernels (three a layer forward, three recomputed, six backward),
+    own kernels (three a layer forward, three recomputed, six backward, in
+    each of the two sizes the routed part is traced at),
     attention is the one a chip runs (the Pallas kernels: here the backend
     is the CPU, so the test says "tpu" in ``packed_rows``'s place; a layer
     calls the forward kernel, calls it again in its recomputation and the
@@ -392,8 +393,10 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     stats = compiled.memory_analysis()
     print(f"glm_4_7_flash, one described chip: {stats}")
     text = compiled.as_text()
-    assert text.count(" custom-call(") >= 5 * 12
-    assert text.count('op_name="ragged-dot-none"') == 5 * 12
+    assert text.count(" custom-call(") >= 5 * 24
+    # each of a layer's two forms of the routed part (``moe.prefix_rows``
+    # of the slots, or all of them) holds its own twelve
+    assert text.count('op_name="ragged-dot-none"') == 5 * 24
     kernels = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
     # (the compiler's own ``ragged-dot`` kernels are custom calls too)

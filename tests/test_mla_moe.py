@@ -168,16 +168,52 @@ def test_mla_moe_logits_both_losses_and_every_leafs_gradient_match(tiny):
 def test_mla_moe_collection_moves_on_a_step():
     """Counts add up, the fullest expert of every layer is remembered, the
     bias goes a step towards the mean load (and stays where the load is
-    the mean)."""
-    config = dataclasses.replace(mla_moe.Config.tiny(), n_routed_experts=4)
-    counts = jnp.asarray([[4, 0, 2, 2], [2, 2, 2, 2]], jnp.int32)
-    state = {"bias": jnp.full((2, 4), 0.5), "busiest": jnp.asarray([7, 7]),
-             "counts": jnp.ones((2, 4), jnp.int32)}
-    new = mla_moe.step_collection(state, counts, config)
-    np.testing.assert_allclose(new["bias"], [[0.499, 0.501, 0.5, 0.5],
-                                             [0.5] * 4], atol=1e-7)
-    assert new["counts"].tolist() == [[5, 1, 3, 3], [3, 3, 3, 3]]
-    assert new["busiest"].tolist() == [11, 9]
+    the mean), and a layer is counted whose held expert (one of four: 8 of
+    32 slots if the router is even, 24 fit ``moe.prefix_rows``) took more
+    than fit."""
+    config = dataclasses.replace(
+        mla_moe.Config.tiny(), n_routed_experts=4, experts_held=(2,),
+        num_experts_per_tok=2)
+    counts = jnp.asarray([[16, 0, 8, 8], [8, 8, 8, 8], [2, 2, 26, 2]],
+                         jnp.int32)
+    state = {"bias": jnp.full((3, 4), 0.5), "busiest": jnp.asarray([7] * 3),
+             "counts": jnp.ones((3, 4), jnp.int32),
+             "overflow": jnp.asarray([3] * 3)}
+    new = mla_moe.step_collection(state, counts, config, tokens=16)
+    np.testing.assert_allclose(
+        new["bias"], [[0.499, 0.501, 0.5, 0.5], [0.5] * 4,
+                      [0.501, 0.501, 0.499, 0.501]], atol=1e-7)
+    assert new["counts"].tolist() == [[17, 1, 9, 9], [9] * 4, [3, 3, 27, 3]]
+    assert new["busiest"].tolist() == [23, 15, 33]
+    assert new["overflow"].tolist() == [3, 3, 4]
+
+
+@pytest.mark.parametrize("favoured, overflowed", [
+    ((), 0), ((2, 3, 5), 1)], ids=["even_bias", "everything_here"])
+def test_mla_moe_counts_the_layers_whose_held_slots_overflow(
+        tiny, favoured, overflowed):
+    """``moe_overflow_layers_total``: a step of the whole model under a
+    bias that leaves the choice to the scores (2 of 16 experts held: an
+    eighth of the slots lands here, ``moe.prefix_rows`` holds three), and
+    under one that sends two of every token's three choices here."""
+    config, _, _, params = tiny
+    batch = _rows(config, 2, 21)
+    bias = jnp.zeros((config.expert_layers, config.n_routed_experts)
+                     ).at[:, jnp.asarray(favoured, jnp.int32)].set(10.0)
+    state = {"bias": bias, **{
+        name: jnp.zeros(shape, dtype) for name, (shape, dtype) in
+        mla_moe.collection_shapes(config).items() if name != "bias"}}
+    loss_fn = mla_moe.make_loss_fn(None, config)
+    _, new = jax.jit(loss_fn)(params, {mla_moe.COLLECTION: state}, batch)
+    new = new[mla_moe.COLLECTION]
+    slots = batch["tokens"].size * config.num_experts_per_tok
+    fits = moe.prefix_rows(slots, 2, config.n_routed_experts)
+    assert fits == 3 * slots // 8
+    landed = np.asarray(new["counts"])[:, list(config.experts_held)].sum(-1)
+    assert ((landed > fits) == bool(overflowed)).all(), (landed, fits)
+    assert new["overflow"].tolist() == [overflowed] * config.expert_layers
+    assert mla_moe.device_counters({mla_moe.COLLECTION: new}, config)[
+        "moe_overflow_layers_total"].tolist() == new["overflow"].tolist()
 
 
 def test_mla_moe_trainer_follows_the_reference_for_three_adamw_steps(tiny):
@@ -242,6 +278,11 @@ def test_mla_moe_trainer_follows_the_reference_for_three_adamw_steps(tiny):
         :, list(config.experts_held)].sum()
     assert grew["moe_busiest_expert_slots_total"] == sum(
         np.max(c, axis=-1).sum() for c in theirs["counts"])
+    landed = np.asarray(theirs["counts"])[..., list(config.experts_held)]
+    assert grew["moe_overflow_layers_total"] == int((
+        landed.sum(-1) > moe.prefix_rows(
+            config.num_experts_per_tok * batches[0]["tokens"].size,
+            len(config.experts_held), config.n_routed_experts)).sum())
     assert grew["mtp_loss_tokens_total"] == sum(
         mla_moe.batch_counters(b, config)["mtp_loss_tokens_total"]
         for b in batches)
@@ -327,13 +368,31 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     _close(total, whole, tol=1e-6)
 
 
+def _rows_multiplied(fn, *args) -> set:
+    """The row counts of the grouped products that ``fn`` *runs*: with
+    ``jax.disable_jit`` a ``cond`` calls the one branch the count chose, so
+    this names the form of the routed part that was taken."""
+    seen, real = [], jax.lax.ragged_dot
+
+    def spy(lhs, rhs, group_sizes, **kw):
+        seen.append(lhs.shape[0])
+        return real(lhs, rhs, group_sizes, **kw)
+
+    with pytest.MonkeyPatch.context() as patch, jax.disable_jit():
+        patch.setattr(jax.lax, "ragged_dot", spy)
+        fn(*args)
+    return set(seen)
+
+
 @pytest.mark.parametrize("case", ["all_here", "none_here", "one_expert"])
 def test_routed_experts_drop_no_token_at_the_extremes(case):
     """A bias of 10 decides every choice.  All three choices of every token
-    on held experts: the buffers' worst case, every slot live.  None on a
-    held expert: the result is zero, nothing is computed, the gradient is
-    zero and finite.  Every token choosing one held expert beside two held
-    elsewhere: an expert with every token, no capacity to overflow."""
+    on held experts: the worst case, every slot live, the whole form (144
+    rows).  None on a held expert: the result is zero, the prefix form (88
+    rows) has no live row, the gradient is zero and finite.  Every token
+    choosing one held expert beside two held elsewhere: an expert with
+    every token, no capacity to overflow, and the 48 live rows fit the
+    prefix form."""
     w = _expert_layer(seed=2)
     held = (1, 4, 6)
     favoured = {"all_here": [1, 4, 6], "none_here": [0, 2, 3],
@@ -350,6 +409,9 @@ def test_routed_experts_drop_no_token_at_the_extremes(case):
         part(h, g), jax.grad(lambda h_, g_: jnp.sum(part(h_, g_)[0] ** 2),
                              (0, 1))(h, g)))(w["h"], w["experts_gate"][take])
     tokens = w["h"].shape[0]
+    assert moe.prefix_rows(3 * tokens, 3, 16) == 88
+    assert _rows_multiplied(part, w["h"], w["experts_gate"][take]) == {
+        3 * tokens if case == "all_here" else 88}
     assert [int(counts[e]) for e in favoured] == [tokens] * 3
     assert int(counts.sum()) == 3 * tokens
     held_weights = {k: w[k][take] for k in
@@ -366,6 +428,129 @@ def test_routed_experts_drop_no_token_at_the_extremes(case):
         assert all(float(jnp.abs(g).max()) == 0.0 for g in grad)
     else:
         assert float(jnp.abs(y).max()) > 0
+
+
+@pytest.mark.parametrize("landed, rows", [(87, 88), (88, 88), (89, 144)],
+                         ids=["under", "equal", "over"])
+def test_routed_experts_take_the_form_their_count_fits(landed, rows,
+                                                       monkeypatch):
+    """48 tokens, top-3 of 16, three held: 144 slots, of which
+    ``moe.prefix_rows`` takes 88.  A bias of 10 gives every token one held
+    and one other expert, a bias of -10 leaves two experts, one of them
+    held, for the third choice, and the router's columns for those two are
+    each other's negative: the sign of a token's product with it decides,
+    so flipping tokens sets the count to the slot.  The value and every
+    gradient against ``reference.experts``, under the count, at it and one
+    over, where the whole form runs; and against the whole form alone
+    (``prefix_rows`` answering "all of them") on the same input."""
+    w = _expert_layer(seed=4)
+    held, first, other, last, rival = (1, 4, 6), 4, 0, 6, 9
+    take, tokens = np.asarray(held), w["h"].shape[0]
+    router = w["router"].at[:, rival].set(-w["router"][:, last])
+    bias = jnp.full(16, -10.0).at[jnp.asarray([first, other])].set(10.0)
+    bias = bias.at[jnp.asarray([last, rival])].set(0.0)
+    here = np.arange(tokens) < landed - tokens      # third choice held
+    sign = np.where((np.asarray(w["h"] @ router[:, last]) > 0) == here, 1, -1)
+    h = w["h"] * sign[:, None]
+    leaves = {"router": router, **{k: w[k][take] for k in (
+        "experts_gate", "experts_up", "experts_down")}}
+
+    def mine(h, p):
+        y, counts = moe.routed_experts(
+            h, p["router"], bias, p["experts_gate"], p["experts_up"],
+            p["experts_down"], held, top_k=3, scale=1.8)
+        return jnp.sum(y ** 2), (y, counts)
+
+    def theirs(h, p):
+        y, counts = reference.experts(dict(w, **p), h, bias,
+                                      _ref_config(16, 3, held), lambda a: a)
+        y = y - packed_rows.swiglu(h, w["shared_gate"], w["shared_up"],
+                                   w["shared_down"])
+        return jnp.sum(y ** 2), (y, counts)
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))(h, leaves)
+
+    (_, (y, counts)), grads = run(mine)
+    (_, (want, want_counts)), want_grads = run(theirs)
+    assert int(counts[take].sum()) == landed
+    np.testing.assert_array_equal(counts, want_counts)
+    assert _rows_multiplied(jax.grad(lambda *a: mine(*a)[0], (0, 1)),
+                            h, leaves) == {rows}
+    _close(y, want, tol=1e-5)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert float(jnp.abs(ref).max()) > 0
+        _close(got, ref, tol=1e-5)
+    monkeypatch.setattr(moe, "prefix_rows", lambda slots, *_: slots)
+    (_, (whole, _)), whole_grads = run(mine)
+    _close(y, whole, tol=1e-6)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(whole_grads)):
+        _close(got, ref, tol=1e-6)
+
+
+def _sub_jaxprs(jaxpr):
+    """Every jaxpr inside ``jaxpr``, itself first."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _sub_jaxprs(inner)
+
+
+def _layer_jaxpr(held):
+    """The value and gradient of ``routed_experts`` on 64 bfloat16 tokens,
+    top-3 of 16 (192 slots), D = 32, F = 24, float32 weights."""
+    w = _expert_layer(seed=5, tokens=64, f=24)
+    take = np.asarray(held)
+
+    def loss(h, gate, up, down):
+        y, _ = moe.routed_experts(h, w["router"], jnp.zeros(16), gate, up,
+                                  down, held, top_k=3, scale=1.8)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    return jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2, 3)))(
+        w["h"].astype(jnp.bfloat16), w["experts_gate"][take],
+        w["experts_up"][take], w["experts_down"][take]).jaxpr
+
+
+def test_the_prefix_form_holds_no_row_a_slot():
+    """Two of 16 experts held: 72 of the 192 slots' rows.  The forward pass
+    and the backward pass choose each for itself (two ``cond``s, and no
+    residual crosses one), and in the branch a fitting count takes no
+    array of any type has a row a slot (192 rows, or 64 x 3, of ``F`` or
+    ``D`` numbers): the products' outputs, the ``silu`` pass, the masks,
+    the gathers and the weighted sum all work on 72 rows or on a row a
+    token.  The other branch has such arrays in float32, so the check can
+    see one."""
+    assert moe.prefix_rows(192, 2, 16) == 72
+    conds = [eqn for jaxpr in _sub_jaxprs(_layer_jaxpr((3, 7)))
+             for eqn in jaxpr.eqns if eqn.primitive.name == "cond"]
+    assert len(conds) == 2
+
+    def a_row_a_slot(jaxpr, dtypes):
+        return [v.aval for inner in _sub_jaxprs(jaxpr) for eqn in inner.eqns
+                for v in eqn.outvars
+                if v.aval.dtype in dtypes and v.aval.ndim >= 2
+                and v.aval.shape[-1] in (32, 24)
+                and int(np.prod(v.aval.shape[:-1])) == 192]
+
+    for eqn in conds:
+        whole, prefix = eqn.params["branches"]      # the predicate's 0, 1
+        found = a_row_a_slot(prefix.jaxpr, (jnp.float32, jnp.bfloat16))
+        assert not found, found
+        assert a_row_a_slot(whole.jaxpr, (jnp.float32,))
+        assert all(v.aval.shape[:1] != (192,) for v in eqn.outvars)
+
+
+def test_half_the_experts_held_trace_one_form():
+    """Eight of 16 held: three times an even router's share is more than
+    every slot, so the routed part is traced once, at all the slots, under
+    no ``cond``."""
+    assert moe.prefix_rows(192, 8, 16) == 192
+    assert not [eqn for jaxpr in _sub_jaxprs(_layer_jaxpr(tuple(range(8))))
+                for eqn in jaxpr.eqns if eqn.primitive.name == "cond"]
 
 
 def test_routed_experts_refuse_experts_the_router_does_not_have():
@@ -466,6 +651,13 @@ def test_the_second_loss_scores_nothing_across_a_boundary(tiny):
 
 
 def test_mla_moe_checkpoints_carry_the_routing_state(tiny, tmp_path):
+    """A checkpoint holds the whole ``moe`` collection, the overflow row
+    with it.  One written before that row existed is not filled in with
+    zeros: a checkpoint's tree is that of the code that wrote it (no
+    format is published, the Trainer restores into its own template), and
+    the restore refuses the other tree by the missing row's name rather
+    than resume with a count that starts at nothing."""
+    from tensorflowonspark_tpu import ckpt
     from tensorflowonspark_tpu.trainer import Trainer
 
     config = tiny[0]
@@ -485,8 +677,16 @@ def test_mla_moe_checkpoints_carry_the_routing_state(tiny, tmp_path):
     assert _routing_state(trainer)["counts"].sum() == 3 * per_step
     trainer.restore(str(tmp_path / "ckpt"))
     got = _routing_state(trainer)
-    for name in ("bias", "counts", "busiest"):
+    assert set(got) == {"bias", "counts", "busiest", "overflow"}
+    for name in got:
         np.testing.assert_array_equal(got[name], want[name])
+    old = trainer._state_tree()
+    old["collections"] = {mla_moe.COLLECTION: {
+        k: v for k, v in old["collections"][mla_moe.COLLECTION].items()
+        if k != "overflow"}}
+    ckpt.save_pytree(old, str(tmp_path / "before"))
+    with pytest.raises(ValueError, match="moe.overflow"):
+        trainer.restore(str(tmp_path / "before"))
     # restored counts are where the counters go on from, not growth
     trainer.step(batch)
     assert mla_moe.device_counters(trainer.state.collections, config)[
