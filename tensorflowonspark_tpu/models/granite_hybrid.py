@@ -32,8 +32,9 @@ chip; on any other backend and at small shapes (``Config.tiny()``, the
 tests) as ``jnp`` code.  :func:`scan_runs_fused` is the rule, and a step
 counts which applied (``ssm_scan_fused_steps_total`` /
 ``ssm_scan_plain_steps_total``).
-The norm, the products, the feed-forward, the attention and the blocked
-loss are ``packed_rows``'s, which ``mla_moe`` calls too.  Attention has two
+The norm, the products, the feed-forward, the convolution, the attention
+and the blocked loss are ``packed_rows``'s, which ``mla_moe`` and
+``lfm2_moe`` call too.  Attention has two
 executions as well (``packed_rows.attention_runs_fused``): its kernels want
 a head to fill whole rows of 128 lanes, so the published 32/8 heads of 64
 keep the ``jnp`` form on every backend, and a step says so
@@ -60,8 +61,9 @@ import math
 import numpy as np
 
 from tensorflowonspark_tpu.models.packed_rows import (
-    _backend, attention_runs_fused, block as _block, blocked_cross_entropy,
-    document_attention, loss_positions, mm as _mm, rms as _rms, swiglu)
+    _backend, block as _block, blocked_cross_entropy, causal_conv,
+    document_attention, example_rows, loss_positions, mm as _mm, rms as _rms,
+    row_counters, swiglu)
 
 #: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -162,22 +164,6 @@ def parameter_count(config: Config) -> int:
 # ---------------------------------------------------------------------------
 # The mathematics, over the flat parameter dict, one row at a time
 # ---------------------------------------------------------------------------
-
-
-def causal_conv(xbc, w, b, seg):
-    """Depthwise causal convolution over a packed row: ``y_t = b + sum_j
-    w[K-1-j] * x_{t-j}`` over the taps ``j < K`` whose token ``t-j`` is in
-    ``t``'s document.  ``xbc`` (T, C), ``w`` (K, C), ``seg`` (T,)."""
-    import jax.numpy as jnp
-
-    taps, t = w.shape[0], xbc.shape[0]
-    x32 = xbc.astype(jnp.float32)
-    y = x32 * w[taps - 1] + b
-    for j in range(1, min(taps, t)):
-        back = jnp.pad(x32[:-j], ((j, 0), (0, 0)))
-        same = jnp.pad(seg[:-j], (j, 0), constant_values=-1) == seg
-        y = y + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - j]
-    return y
 
 
 def scan_runs_fused(chunk: int, heads: int, p: int, groups: int,
@@ -481,36 +467,25 @@ def make_forward_fn(module, config: Config):
 
 
 def batch_counters(batch, config: Config) -> dict:
-    """What one step adds to the program's counters.  From its host batch:
-    tokens, tokens that bear a loss (the next token is the same document's)
-    and documents (runs of one segment id).  From the rules its trace
-    applied (:func:`scan_runs_fused`, ``packed_rows.attention_runs_fused``):
-    one step of the scan, and one of attention, on the kernels or as
-    ``jnp`` code, the other named with 0 so that both are on the record."""
-    seg = np.asarray(batch["segment_ids"])
-    same = seg[:, 1:] == seg[:, :-1]
+    """What one step adds to the program's counters:
+    ``packed_rows.row_counters`` (the host batch's tokens, loss tokens and
+    documents, and which execution of attention its trace applied) and, by
+    the same kind of rule (:func:`scan_runs_fused`), one step of the scan on
+    the kernels or as ``jnp`` code, the other named with 0."""
     scans = "mamba" in config.layer_types
     fused = scans and scan_runs_fused(
         config.mamba_chunk_size, config.mamba_n_heads, config.mamba_d_head,
         config.mamba_n_groups, config.mamba_d_state)
-    attends = "attention" in config.layer_types
-    on_chip = attends and attention_runs_fused(seg.shape[1], config.head_dim)
-    return {"lm_tokens_total": int(seg.size),
-            "lm_loss_tokens_total": int(same.sum()),
-            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
+    return {**row_counters(batch["segment_ids"], config.head_dim,
+                           "attention" in config.layer_types),
             "ssm_scan_fused_steps_total": int(fused),
-            "ssm_scan_plain_steps_total": int(scans and not fused),
-            "attention_fused_steps_total": int(on_chip),
-            "attention_plain_steps_total": int(attends and not on_chip)}
+            "ssm_scan_plain_steps_total": int(scans and not fused)}
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
                   seq_len: int | None = None):
     """Packed rows of two documents each, ``2 * chunk`` tokens unless
     ``seq_len`` says otherwise (a step compiles at the shape it is fed)."""
-    rng = np.random.RandomState(seed)
-    t = int(seq_len or min(config.seq_len, 2 * config.mamba_chunk_size))
-    cut = rng.randint(1, t, size=(batch_size, 1))
-    return {"tokens": rng.randint(0, config.vocab_size,
-                                  size=(batch_size, t)).astype(np.int32),
-            "segment_ids": (np.arange(t)[None, :] >= cut).astype(np.int32)}
+    return example_rows(
+        config.vocab_size, batch_size, seed,
+        int(seq_len or min(config.seq_len, 2 * config.mamba_chunk_size)))

@@ -77,8 +77,8 @@ import math
 import numpy as np
 
 from tensorflowonspark_tpu.models.packed_rows import (
-    attention_runs_fused, block, blocked_cross_entropy, document_attention,
-    loss_positions, mm, rms, swiglu)
+    block, blocked_cross_entropy, document_attention, document_positions,
+    example_rows, loss_positions, mm, rms, rope, row_counters, swiglu)
 
 #: no sequence-parallel sharding: attention sees a whole row
 SEQUENCE_AXES: dict = {}
@@ -207,40 +207,15 @@ def parameter_count(config: Config) -> int:
 
 def collection_shapes(config: Config) -> dict:
     """The ``moe`` collection: a row an expert layer, in forward order."""
-    rows, e = config.expert_layers, config.n_routed_experts
-    return {"bias": ((rows, e), "float32"), "counts": ((rows, e), "int32"),
-            "busiest": ((rows,), "int32"), "overflow": ((rows,), "int32")}
+    from tensorflowonspark_tpu.parallel import moe
+
+    return moe.routing_state_shapes(config.n_routed_experts,
+                                    config.expert_layers)
 
 
 # ---------------------------------------------------------------------------
 # The mathematics, over the flat parameter dict
 # ---------------------------------------------------------------------------
-
-
-def document_positions(seg):
-    """(T,) int32: the index of every token inside its document."""
-    import jax
-    import jax.numpy as jnp
-
-    at = jnp.arange(seg.shape[0], dtype=jnp.int32)
-    first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-    return at - jax.lax.cummax(jnp.where(first, at, 0))
-
-
-def rope(x, pos, theta: float):
-    """Rotary embedding over the last axis of ``x`` (T, ..., R), the two
-    halves rotated (``[a | b] -> [a cos - b sin | b cos + a sin]``), at the
-    positions ``pos`` (T,); float32 inside."""
-    import jax.numpy as jnp
-
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = pos.astype(jnp.float32)[:, None] * freq
-    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
-    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
-    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
 
 
 def latent_attention(params, prefix: str, h, seg, pos, config: Config,
@@ -426,27 +401,14 @@ def loss_terms(params, bias, tokens, segment_ids, config: Config):
 def step_collection(collection: dict, counts, config: Config,
                     tokens: int) -> dict:
     """The ``moe`` collection after a step whose ``tokens`` tokens chose
-    ``counts`` (expert layers, E): every layer's bias moves
-    ``bias_update_speed`` towards its mean load, the counts add up, and a
-    layer whose held experts were chosen more often than
-    ``moe.prefix_rows`` allows (``routed_experts`` then took all the slots)
-    is counted."""
-    import jax.numpy as jnp
-
+    ``counts`` (expert layers, E): ``moe.step_routing_state`` at this
+    configuration's experts held, choices a token and bias speed."""
     from tensorflowonspark_tpu.parallel import moe
 
-    load = counts.astype(jnp.float32)
-    held = jnp.asarray(config.experts_held, jnp.int32)
-    fits = moe.prefix_rows(tokens * config.num_experts_per_tok, len(held),
-                           config.n_routed_experts)
-    return {
-        "bias": collection["bias"] + config.bias_update_speed * jnp.sign(
-            jnp.mean(load, axis=-1, keepdims=True) - load),
-        "counts": collection["counts"] + counts,
-        "busiest": collection["busiest"] + jnp.max(counts, axis=-1),
-        "overflow": collection["overflow"] + (
-            jnp.sum(counts[:, held], axis=-1) > fits),
-    }
+    return moe.step_routing_state(
+        collection, counts, config.experts_held,
+        top_k=config.num_experts_per_tok, speed=config.bias_update_speed,
+        tokens=tokens)
 
 
 # ---------------------------------------------------------------------------
@@ -527,49 +489,31 @@ def make_forward_fn(module, config: Config):
 
 
 def batch_counters(batch, config: Config) -> dict:
-    """What one step adds to the program's counters.  From its host batch:
-    tokens, tokens that bear the main loss (the next token is the same
-    document's) and the second (the two next are), and documents.  From the
-    rule its trace applied (``packed_rows.attention_runs_fused``): one step
-    of attention on the kernels or as ``jnp`` code, the other named with 0
-    so that both are on the record."""
+    """What one step adds to the program's counters:
+    ``packed_rows.row_counters`` (the host batch's tokens, loss tokens and
+    documents, and which execution of attention its trace applied) and the
+    tokens that bear the second loss (the two next are the same
+    document's)."""
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
-    on_chip = attention_runs_fused(seg.shape[1], config.qk_head_dim)
-    return {"lm_tokens_total": int(seg.size),
-            "lm_loss_tokens_total": int(same.sum()),
+    return {**row_counters(seg, config.qk_head_dim),
             "mtp_loss_tokens_total": int(
                 (same[:, 1:] & same[:, :-1]).sum()
-                if config.num_nextn_predict_layers else 0),
-            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
-            "attention_fused_steps_total": int(on_chip),
-            "attention_plain_steps_total": int(not on_chip)}
+                if config.num_nextn_predict_layers else 0)}
 
 
 def device_counters(collections, config: Config) -> dict:
-    """What the device decided, for the program's counters: cumulative
-    int32 arrays whose growth the Trainer adds up, element by element
-    (a running total would outgrow 32 bits; an element takes a million
-    steps of a row to).  Slots (a token's choice of an expert) routed, the
-    slots whose expert is held here, every layer's fullest expert, and the
-    layers whose held slots overflowed ``moe.prefix_rows``."""
-    import jax.numpy as jnp
+    """What the device decided, for the program's counters
+    (``moe.routing_counters`` of the ``moe`` collection)."""
+    from tensorflowonspark_tpu.parallel import moe
 
-    state = collections[COLLECTION]
-    held = jnp.asarray(config.experts_held, jnp.int32)
-    return {"moe_slots_total": state["counts"],
-            "moe_local_slots_total": state["counts"][:, held],
-            "moe_busiest_expert_slots_total": state["busiest"],
-            "moe_overflow_layers_total": state["overflow"]}
+    return moe.routing_counters(collections[COLLECTION],
+                                config.experts_held)
 
 
 def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
                   seq_len: int | None = None):
     """Packed rows of two documents each, ``seq_len`` tokens (at most 64
     unless told: a step compiles at the shape it is fed)."""
-    rng = np.random.RandomState(seed)
-    t = int(seq_len or min(config.seq_len, 64))
-    cut = rng.randint(1, t, size=(batch_size, 1))
-    return {"tokens": rng.randint(0, config.vocab_size,
-                                  size=(batch_size, t)).astype(np.int32),
-            "segment_ids": (np.arange(t)[None, :] >= cut).astype(np.int32)}
+    return example_rows(config.vocab_size, batch_size, seed,
+                        int(seq_len or min(config.seq_len, 64)))

@@ -1,23 +1,32 @@
 """What the decoders trained on packed rows have in common
-(``granite_hybrid``, ``mla_moe``): the RMS norm, the product with operands
-in the activations' type, the SwiGLU feed-forward, causal attention inside
-documents a block of queries at a time, and the next-token cross-entropy a
-block of tokens at a time.
+(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``): the RMS norm, the product
+with operands in the activations' type, the SwiGLU feed-forward, the
+positions inside documents and the rotary embedding at them, the depthwise
+causal convolution that stops at a document's first token, causal attention
+inside documents a block of queries at a time, and the next-token
+cross-entropy a block of tokens at a time.
 
 A packed row is ``T`` tokens with segment ids ``s`` (the document's number
 inside the row; documents are contiguous and their ids differ).  One
-implementation of each piece, called by both models: what is measured on one
-model's cell is what the other runs.
+implementation of each piece, each called by two models or by all three
+(:func:`causal_conv`: granite's state-space mixers and LFM2's gated short
+convolutions; :func:`rope` and :func:`document_positions`: GLM's latent
+attention and LFM2's grouped-query attention): what is measured on one
+model's cell is what the others run.
 
 Attention is one algorithm with two executions
 (:func:`document_attention`): on a TPU, where a head fills whole rows of
-128 lanes (GLM's 20 x 256 do, granite's 32/8 x 64 do not), the Pallas
-kernels of ``attention_pallas`` keep every (queries x keys) score tile on
-the chip, forward and backward; on any other backend and at other shapes
-(``Config.tiny()``, the tests) the ``jnp`` form in this file runs, which is
-also the kernels' oracle.  :func:`attention_runs_fused` is the rule, and
-both models' steps count which applied (``attention_fused_steps_total`` /
-``attention_plain_steps_total``).
+128 lanes (GLM's 20 x 256 do; granite's and LFM2's 32/8 x 64 do not), the
+Pallas kernels of ``attention_pallas`` keep every (queries x keys) score
+tile on the chip, forward and backward; on any other backend and at other
+shapes (``Config.tiny()``, the tests) the ``jnp`` form in this file runs,
+which is also the kernels' oracle.  :func:`attention_runs_fused` is the
+rule, and the models' steps count which applied
+(``attention_fused_steps_total`` / ``attention_plain_steps_total``).
+
+What a step of packed rows adds to the program's counters from its host
+batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
+are here too: host code, one copy for the three.
 
 JAX is imported where it is used, as in the models.
 """
@@ -86,6 +95,49 @@ def swiglu(h, w_gate, w_up, w_down):
     up = mm("td,df->tf", h, w_up, dtype)
     act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
     return mm("tf,fd->td", act, w_down, dtype)
+
+
+def document_positions(seg):
+    """(T,) int32: the index of every token inside its document."""
+    import jax
+    import jax.numpy as jnp
+
+    at = jnp.arange(seg.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
+    return at - jax.lax.cummax(jnp.where(first, at, 0))
+
+
+def rope(x, pos, theta: float):
+    """Rotary embedding over the last axis of ``x`` (T, ..., R), the two
+    halves rotated (``[a | b] -> [a cos - b sin | b cos + a sin]``), at the
+    positions ``pos`` (T,); float32 inside."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = pos.astype(jnp.float32)[:, None] * freq
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def causal_conv(xbc, w, b, seg):
+    """Depthwise causal convolution over a packed row: ``y_t = b + sum_j
+    w[K-1-j] * x_{t-j}`` over the taps ``j < K`` whose token ``t-j`` is in
+    ``t``'s document.  ``xbc`` (T, C), ``w`` (K, C), ``b`` (C,) or a
+    number, ``seg`` (T,); float32 out."""
+    import jax.numpy as jnp
+
+    taps, t = w.shape[0], xbc.shape[0]
+    x32 = xbc.astype(jnp.float32)
+    y = x32 * w[taps - 1] + b
+    for j in range(1, min(taps, t)):
+        back = jnp.pad(x32[:-j], ((j, 0), (0, 0)))
+        same = jnp.pad(seg[:-j], (j, 0), constant_values=-1) == seg
+        y = y + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - j]
+    return y
 
 
 def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
@@ -270,3 +322,31 @@ def blocked_cross_entropy(x, logits_fn, targets, valid, want: int):
         x.reshape(t // size, size, -1), targets.reshape(-1, size),
         valid.reshape(-1, size)))
     return jnp.sum(sums)
+
+
+def row_counters(segment_ids, head_dim: int, attends: bool = True) -> dict:
+    """What one step of packed rows adds to the program's counters, whatever
+    the model.  From its host batch's segment ids (B, T): tokens, tokens
+    that bear a loss (the next token is the same document's) and documents
+    (runs of one segment id).  From the rule its trace applied
+    (:func:`attention_runs_fused`; ``attends``: the model has an attention
+    layer): one step of attention on the kernels or as ``jnp`` code, the
+    other named with 0 so that both are on the record."""
+    seg = np.asarray(segment_ids)
+    same = seg[:, 1:] == seg[:, :-1]
+    on_chip = attends and attention_runs_fused(seg.shape[1], head_dim)
+    return {"lm_tokens_total": int(seg.size),
+            "lm_loss_tokens_total": int(same.sum()),
+            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
+            "attention_fused_steps_total": int(on_chip),
+            "attention_plain_steps_total": int(attends and not on_chip)}
+
+
+def example_rows(vocab_size: int, batch_size: int, seed: int, t: int) -> dict:
+    """``batch_size`` packed rows of ``t`` tokens, two documents each (the
+    models' ``example_batch``)."""
+    rng = np.random.RandomState(seed)
+    cut = rng.randint(1, t, size=(batch_size, 1))
+    return {"tokens": rng.randint(0, vocab_size,
+                                  size=(batch_size, t)).astype(np.int32),
+            "segment_ids": (np.arange(t)[None, :] >= cut).astype(np.int32)}

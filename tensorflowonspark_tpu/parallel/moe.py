@@ -29,8 +29,8 @@ Layout contract: tokens ``(T, M)`` in, experts' weights ``(E, M, H)`` /
 handles imbalance), but shard the token dim over the data axes as usual.
 
 **:func:`routed_experts` — top-k of a wide router, the experts held here,
-no token dropped** (called by ``models/mla_moe.py``; the DeepSeek-V3 layout,
-arXiv:2412.19437).  The layer is told *which* of the router's experts this
+no token dropped** (called by ``models/mla_moe.py`` and
+``models/lfm2_moe.py``; the DeepSeek-V3 layout, arXiv:2412.19437).  The layer is told *which* of the router's experts this
 chip holds.  It scores every token against all of them (sigmoid, float32),
 chooses the ``top_k`` of score plus a correction bias that takes no
 gradient, weighs the chosen by their normalised scores, keeps every slot
@@ -48,7 +48,11 @@ landed here, not on the worst case, and a step that overflows is still
 exact.  What the experts held elsewhere would have added is left out; on one
 chip no exchange runs.  It returns how many tokens chose each of the
 router's experts, which is what the caller's bias update and counters read.
-No capacity factor exists and no auxiliary loss.
+No capacity factor exists and no auxiliary loss.  What such a model keeps
+beside its parameters — the correction biases, their update after a step and
+the counts the program's counters show — is :func:`routing_state_shapes`,
+:func:`step_routing_state` and :func:`routing_counters`, which both models
+call.
 """
 
 from __future__ import annotations
@@ -298,13 +302,14 @@ def init_params(rng, num_experts: int, model_dim: int, hidden_dim: int,
 
 
 def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
-               normalize: bool = True):
+               normalize: bool = True, sum_eps: float = 0.0):
     """``(chosen, gates)`` of tokens ``h`` (T, D): the scores are
     ``sigmoid(h W_r)`` in float32 at the highest precision (a choice hangs
     on them), ``chosen`` (T, k) the ``top_k`` experts by score plus
     ``router_bias`` (E,), ``gates`` (T, k) ``scale`` times the chosen
-    scores, over their sum if ``normalize``.  The gradient runs through
-    the scores and not through the choice or the bias."""
+    scores, over their sum (plus ``sum_eps``, where a layout writes one:
+    ``lfm2_moe``'s 1e-6) if ``normalize``.  The gradient runs through the
+    scores and not through the choice or the bias."""
     import jax
     import jax.numpy as jnp
 
@@ -315,7 +320,10 @@ def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
         jax.lax.stop_gradient(scores) + router_bias, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
     if normalize:
-        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+        total = jnp.sum(picked, axis=-1, keepdims=True)
+        # no "+ 0.0" where no epsilon is written: that caller's compiled
+        # step stays the one it was
+        picked = picked / (total + sum_eps if sum_eps else total)
     return chosen, scale * picked
 
 
@@ -466,7 +474,8 @@ def _routed_part():
 
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
-                   top_k: int, scale: float, normalize: bool = True):
+                   top_k: int, scale: float, normalize: bool = True,
+                   sum_eps: float = 0.0):
     """The held experts' part of a routed SwiGLU layer on tokens ``x``
     (T, D): ``sum over e chosen and held of g_e W_down_e (silu(x W_gate_e)
     * (x W_up_e))``, and the tokens that chose each of the router's experts.
@@ -475,7 +484,9 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     ``w_gate``, ``w_up`` (H, D, F) and ``w_down`` (H, F, D) are the ``H``
     experts held here, ``held`` (a static sequence of ``H`` distinct ids in
     ``[0, E)``) says which they are, in the weights' order.  Products take
-    operands in ``x``'s type and accumulate in float32; routing is float32.
+    operands in ``x``'s type and accumulate in float32; routing is float32
+    (:func:`topk_route`, which ``top_k``, ``scale``, ``normalize`` and
+    ``sum_eps`` go to).
 
     Every slot is kept: the ``T top_k`` slots are sorted by held expert
     (those of experts held elsewhere last), so the live ones are the first
@@ -496,7 +507,8 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
                          f"distinct experts of {n_experts}")
     with jax.named_scope("moe_router"):
         chosen, gates = topk_route(x, router_w, router_bias, top_k=top_k,
-                                   scale=scale, normalize=normalize)
+                                   scale=scale, normalize=normalize,
+                                   sum_eps=sum_eps)
         slot_expert = chosen.reshape(-1)
         counts = jnp.sum(slot_expert[:, None] == jnp.arange(n_experts),
                          axis=0, dtype=jnp.int32)
@@ -509,3 +521,60 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
         prefix_rows(slot_expert.shape[0], n_held, n_experts), top_k, order,
         inv, counts[held], x, gates, w_gate, w_up, w_down)
     return y, counts
+
+
+# ---------------------------------------------------------------------------
+# The routing state of a model of such layers: what takes no gradient
+# ---------------------------------------------------------------------------
+
+def routing_state_shapes(n_experts: int, expert_layers: int) -> dict:
+    """Name -> ``(shape, dtype)`` of a model's routing state, a row an
+    expert layer in forward order: every layer's correction bias (it enters
+    the choice and takes no gradient), the cumulative count of tokens by
+    expert, the cumulative size of the layer's fullest expert, and the
+    steps in which the layer's held slots overflowed :func:`prefix_rows`.
+    The Trainer's stateful step threads and checkpoints the collection."""
+    rows, e = expert_layers, n_experts
+    return {"bias": ((rows, e), "float32"), "counts": ((rows, e), "int32"),
+            "busiest": ((rows,), "int32"), "overflow": ((rows,), "int32")}
+
+
+def step_routing_state(state: dict, counts, held, *, top_k: int,
+                       speed: float, tokens: int) -> dict:
+    """The routing state after a step whose ``tokens`` tokens chose
+    ``counts`` (expert layers, E): every layer's bias moves ``speed``
+    towards its mean load (``b_e += speed * sign(mean(c) - c_e)``,
+    arXiv:2412.19437), the counts add up, and a layer whose held experts
+    were chosen more often than :func:`prefix_rows` allows
+    (:func:`routed_experts` then took all the slots) is counted."""
+    import jax.numpy as jnp
+
+    load = counts.astype(jnp.float32)
+    held = jnp.asarray(held, jnp.int32)
+    fits = prefix_rows(tokens * top_k, len(held), counts.shape[-1])
+    return {
+        "bias": state["bias"] + speed * jnp.sign(
+            jnp.mean(load, axis=-1, keepdims=True) - load),
+        "counts": state["counts"] + counts,
+        "busiest": state["busiest"] + jnp.max(counts, axis=-1),
+        "overflow": state["overflow"] + (
+            jnp.sum(counts[:, held], axis=-1) > fits),
+    }
+
+
+def routing_counters(state: dict, held) -> dict:
+    """What the device decided, for the program's counters (a model's
+    ``device_counters`` hook): cumulative int32 arrays whose growth the
+    Trainer adds up, element by element (a running total would outgrow 32
+    bits; an element takes a million steps of a row to).  Slots (a token's
+    choice of an expert) routed, the slots whose expert is held here, every
+    layer's fullest expert, and the layers whose held slots overflowed
+    :func:`prefix_rows`.  One set of names for every model of such layers:
+    one reader serves them all."""
+    import jax.numpy as jnp
+
+    held = jnp.asarray(held, jnp.int32)
+    return {"moe_slots_total": state["counts"],
+            "moe_local_slots_total": state["counts"][:, held],
+            "moe_busiest_expert_slots_total": state["busiest"],
+            "moe_overflow_layers_total": state["overflow"]}

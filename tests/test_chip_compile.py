@@ -413,6 +413,53 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
 
 
+@pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
+def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
+    """The ``lfm2_8b_a1b`` configuration as the benchmark builds it (the
+    dense conv layer, then attention, three conv layers and attention again
+    with 8 of 32 experts held, a quarter of the vocabulary: 606,456,064
+    float32 parameters under AdamW) on one packed row of 8,192 tokens,
+    through the TPU compiler: parameters, both moments and the routing state
+    are donated and updated in place, the grouped products are the
+    compiler's own kernels (three a layer forward, three recomputed, six
+    backward, in each of the two sizes the routed part is traced at: a
+    quarter share leaves ``moe.prefix_rows`` under all the slots), attention
+    at heads of 64 is ``jnp`` code on a TPU too (the test says "tpu" in
+    ``packed_rows``'s place, the rule still says plain, and no kernel of
+    ours is called), and arguments, temporaries and code stay under 15.75
+    GiB.  PERF.md section 4 holds the figures."""
+    import json
+
+    from benchmark.configs.lfm2_8b_a1b import program
+    from tensorflowonspark_tpu.models import packed_rows
+
+    with open(os.path.join(REPO, "benchmark", "configs", "lfm2_8b_a1b",
+                           "config.json")) as f:
+        published = json.load(f)
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    config = program.model_config(published)
+    assert not packed_rows.attention_runs_fused(config.seq_len,
+                                                config.head_dim)
+    step, state, batch = abstract_train_step(
+        "lfm2_moe", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 606_456_064
+    assert state.collections["moe"]["bias"].shape == (5, 32)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"lfm2_8b_a1b, one described chip: {stats}")
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-none"') == 5 * 24
+    assert "/attention_forward/" not in text
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    # temporaries: a gradient's worth and the routed part's slot buffers
+    assert stats.temp_size_in_bytes < 5 * 2 ** 30
+    assert (_device_bytes(compiled) + stats.generated_code_size_in_bytes
+            < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
+
+
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):
     """``TPU v5 lite`` is what the v5e reports (chip run, PR 21) and what
     the described topology reports; both peak tables must resolve it."""
