@@ -243,10 +243,12 @@ def attention(params, prefix: str, h, seg, pos, config: Config):
               dtype)
 
 
-def _layer(mixer: str, ffn: str, prefix: str, config: Config, lp, x, seg, pos,
-           bias):
+def _layer(mixer: str, ffn: str, prefix: str, config: Config,
+           initializing: bool, lp, x, seg, pos, bias):
     """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, counts)``;
-    ``counts`` is (E,) zeros for a dense layer."""
+    ``counts`` is (E,) zeros for a dense layer.  ``initializing``: the
+    module is only learning its parameters from this trace
+    (``moe.routed_experts``)."""
     import jax
     import jax.numpy as jnp
 
@@ -273,11 +275,13 @@ def _layer(mixer: str, ffn: str, prefix: str, config: Config, lp, x, seg, pos,
             lp[prefix + "experts_up"], lp[prefix + "experts_down"],
             config.experts_held, top_k=config.num_experts_per_tok,
             scale=config.routed_scaling_factor,
-            normalize=config.norm_topk_prob, sum_eps=GATE_SUM_EPS)
+            normalize=config.norm_topk_prob, sum_eps=GATE_SUM_EPS,
+            initializing=initializing)
     return x + y.reshape(x.shape), counts
 
 
-def hidden_states(params, bias, tokens, seg, config: Config):
+def hidden_states(params, bias, tokens, seg, config: Config,
+                  initializing: bool = False):
     """``(x, counts)``: the hidden states before the last norm (B, T, D) and
     the tokens that chose each expert, (expert layers, E) int32 in forward
     order.  ``bias`` (expert layers, E) enters the choice where
@@ -295,7 +299,8 @@ def hidden_states(params, bias, tokens, seg, config: Config):
         mine = {k: v for k, v in params.items() if k.startswith(prefix)}
         row = bias[len(counts)] if ffn == "experts" else None
         x, c = jax.checkpoint(functools.partial(
-            _layer, mixer, ffn, prefix, config))(mine, x, seg, pos, row)
+            _layer, mixer, ffn, prefix, config, initializing))(
+                mine, x, seg, pos, row)
         if ffn == "experts":
             counts.append(c)
     return x, jnp.stack(counts) if counts else jnp.zeros(
@@ -309,12 +314,15 @@ def _logits(params, x, config: Config):
     return mm("td,vd->tv", h, params["embed"], h.dtype, out=jnp.float32)
 
 
-def apply_tokens(params, bias, tokens, segment_ids, config: Config):
+def apply_tokens(params, bias, tokens, segment_ids, config: Config,
+                 initializing: bool = False):
     """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits of the tied head."""
+    float32 logits of the tied head.  ``initializing`` is the calling
+    module's ``is_initializing()`` (``moe.routed_experts`` reads it)."""
     import jax
 
-    x, _ = hidden_states(params, bias, tokens, segment_ids, config)
+    x, _ = hidden_states(params, bias, tokens, segment_ids, config,
+                         initializing)
     with jax.named_scope("lm_head"):
         return jax.vmap(lambda xr: _logits(params, xr, config))(x)
 
@@ -380,7 +388,8 @@ def make_model(config: Config, mesh=None):
                 COLLECTION, "bias", jnp.zeros, *state["bias"]).value
             for name in ("counts", "busiest", "overflow"):
                 self.variable(COLLECTION, name, jnp.zeros, *state[name])
-            return apply_tokens(params, bias, tokens, segment_ids, config)
+            return apply_tokens(params, bias, tokens, segment_ids, config,
+                                initializing=self.is_initializing())
 
     return Lfm2Moe()
 
@@ -428,9 +437,19 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied)."""
-    return row_counters(batch["segment_ids"], config.head_dim,
-                        "full_attention" in config.layer_types)
+    documents, and which execution of attention its trace applied; and
+    ``moe.grouped_step_counters``: which execution of the routed experts'
+    grouped products)."""
+    from tensorflowonspark_tpu.parallel import moe
+
+    seg = np.asarray(batch["segment_ids"])
+    return {**row_counters(seg, config.head_dim,
+                           "full_attention" in config.layer_types),
+            **moe.grouped_step_counters(
+                seg.size, config.num_experts_per_tok,
+                len(config.experts_held), config.num_experts,
+                config.hidden_size, config.moe_intermediate_size,
+                config.dtype)}
 
 
 def device_counters(collections, config: Config) -> dict:

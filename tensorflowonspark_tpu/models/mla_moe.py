@@ -252,10 +252,13 @@ def latent_attention(params, prefix: str, h, seg, pos, config: Config,
               params[prefix + "wo"], dtype)
 
 
-def expert_ffn(params, prefix: str, h, bias, config: Config):
+def expert_ffn(params, prefix: str, h, bias, config: Config,
+               initializing: bool = False, scopes: tuple = ()):
     """The shared expert and the held routed experts on tokens ``h`` (N, D).
     Returns ``(y, counts)``, ``counts`` (E,) the tokens that chose each of
-    the router's experts."""
+    the router's experts.  ``initializing``: the module is only learning its
+    parameters from this trace; ``scopes``: the named scopes the layer sits
+    under (both go to ``moe.routed_experts``)."""
     import jax
 
     from tensorflowonspark_tpu.parallel import moe
@@ -268,12 +271,13 @@ def expert_ffn(params, prefix: str, h, bias, config: Config):
         h, params[prefix + "router"], bias, params[prefix + "experts_gate"],
         params[prefix + "experts_up"], params[prefix + "experts_down"],
         config.experts_held, top_k=config.num_experts_per_tok,
-        scale=config.routed_scaling_factor, normalize=config.norm_topk_prob)
+        scale=config.routed_scaling_factor, normalize=config.norm_topk_prob,
+        initializing=initializing, scopes=scopes)
     return y + routed, counts
 
 
-def _layer(kind: str, prefix: str, config: Config, scopes: tuple, lp, x, seg,
-           pos, bias):
+def _layer(kind: str, prefix: str, config: Config, scopes: tuple,
+           initializing: bool, lp, x, seg, pos, bias):
     """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, counts)``;
     ``counts`` is (E,) zeros for a dense layer."""
     import jax
@@ -293,20 +297,22 @@ def _layer(kind: str, prefix: str, config: Config, scopes: tuple, lp, x, seg,
         counts = jnp.zeros((config.n_routed_experts,), jnp.int32)
     else:
         y, counts = expert_ffn(lp, prefix, h.reshape(-1, h.shape[-1]), bias,
-                               config)
+                               config, initializing, scopes)
     return x + y.reshape(x.shape), counts
 
 
 def _run_layer(params, prefix, kind, x, seg, pos, bias, config: Config,
-               scopes: tuple = ()):
+               scopes: tuple = (), initializing: bool = False):
     import jax
 
     mine = {k: v for k, v in params.items() if k.startswith(prefix)}
     return jax.checkpoint(functools.partial(
-        _layer, kind, prefix, config, scopes))(mine, x, seg, pos, bias)
+        _layer, kind, prefix, config, scopes, initializing))(
+            mine, x, seg, pos, bias)
 
 
-def hidden_states(params, bias, tokens, seg, config: Config):
+def hidden_states(params, bias, tokens, seg, config: Config,
+                  initializing: bool = False):
     """``(x, pos, counts)``: the main model's hidden states before the
     last norm (B, T, D), the positions inside documents, and a (E,) count a
     main expert layer (a list, forward order)."""
@@ -321,7 +327,8 @@ def hidden_states(params, bias, tokens, seg, config: Config):
         if prefix == "mtp_":
             continue
         row = bias[len(counts)] if kind == "experts" else None
-        x, c = _run_layer(params, prefix, kind, x, seg, pos, row, config)
+        x, c = _run_layer(params, prefix, kind, x, seg, pos, row, config,
+                          initializing=initializing)
         if kind == "experts":
             counts.append(c)
     return x, pos, counts
@@ -358,12 +365,15 @@ def _head(params, norm: str, config: Config):
     return logits
 
 
-def apply_tokens(params, bias, tokens, segment_ids, config: Config):
+def apply_tokens(params, bias, tokens, segment_ids, config: Config,
+                 initializing: bool = False):
     """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits of the main head."""
+    float32 logits of the main head.  ``initializing`` is the calling
+    module's ``is_initializing()`` (``moe.routed_experts`` reads it)."""
     import jax
 
-    x, _, _ = hidden_states(params, bias, tokens, segment_ids, config)
+    x, _, _ = hidden_states(params, bias, tokens, segment_ids, config,
+                            initializing)
     with jax.named_scope("lm_head"):
         return jax.vmap(_head(params, "final_norm", config))(x)
 
@@ -446,7 +456,8 @@ def make_model(config: Config, mesh=None):
                 COLLECTION, "bias", jnp.zeros, *state["bias"]).value
             for name in ("counts", "busiest", "overflow"):
                 self.variable(COLLECTION, name, jnp.zeros, *state[name])
-            return apply_tokens(params, bias, tokens, segment_ids, config)
+            return apply_tokens(params, bias, tokens, segment_ids, config,
+                                initializing=self.is_initializing())
 
     return MlaMoe()
 
@@ -491,12 +502,20 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters:
     ``packed_rows.row_counters`` (the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied) and the
-    tokens that bear the second loss (the two next are the same
-    document's)."""
+    documents, and which execution of attention its trace applied), which
+    execution of the routed experts' grouped products
+    (``moe.grouped_step_counters``) and the tokens that bear the second loss
+    (the two next are the same document's)."""
+    from tensorflowonspark_tpu.parallel import moe
+
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
     return {**row_counters(seg, config.qk_head_dim),
+            **moe.grouped_step_counters(
+                seg.size, config.num_experts_per_tok,
+                len(config.experts_held), config.n_routed_experts,
+                config.hidden_size, config.moe_intermediate_size,
+                config.dtype),
             "mtp_loss_tokens_total": int(
                 (same[:, 1:] & same[:, :-1]).sum()
                 if config.num_nextn_predict_layers else 0)}

@@ -140,7 +140,17 @@ step — never per record, row or chunk):
   ``ssm_scan_fused_steps_total`` / ``ssm_scan_plain_steps_total``, one
   increment a ``granite_hybrid`` step, say whether its state-space scan
   ran on the Pallas kernels or as ``jnp`` code
-  (``models/granite_hybrid.py::scan_runs_fused``).
+  (``models/granite_hybrid.py::scan_runs_fused``);
+  ``attention_fused_steps_total`` / ``attention_plain_steps_total``, one
+  increment a step of a packed-row decoder, say the same of
+  ``packed_rows.document_attention`` (``attention_runs_fused``);
+  ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``,
+  one increment a step of ``mla_moe`` and of ``lfm2_moe``, say whether the
+  routed experts' grouped products, in the form a step takes when a
+  layer's slots fit ``moe.prefix_rows``, ran on the Pallas kernels of
+  ``parallel/grouped_pallas.py`` or as ``jax.lax.ragged_dot``
+  (``parallel/moe.py::grouped_runs_fused``; ``moe_overflow_layers_total``
+  counts the layer-steps that took the other form).
 
 Also instrumented: elastic regroups (``elastic``), serving
 (``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which

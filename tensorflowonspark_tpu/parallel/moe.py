@@ -36,8 +36,17 @@ chooses the ``top_k`` of score plus a correction bias that takes no
 gradient, weighs the chosen by their normalised scores, keeps every slot
 (token, choice) whose expert is held — any number of them, from none to
 all — sorts the kept slots by expert, the held experts' first, and
-multiplies those expert by expert as grouped products
-(``jax.lax.ragged_dot``), and adds the results back weighted.  The slots that
+multiplies those expert by expert as grouped products, and adds the results
+back weighted.  A grouped product is one algorithm with two executions
+(:func:`grouped_runs_fused` is the rule, and the models' steps count which
+applied: ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``):
+on a TPU, at shapes that fill their tiles, in the form a step takes when its
+slots fit, the Pallas kernels of ``grouped_pallas`` walk the row tiles that
+hold a live row and no others, so a product's time follows the step's live
+rows; on any other backend, at other shapes (``Config.tiny()``, the tests),
+while a module initialises (nothing of that trace is ever run) and in the
+form a layer takes when its slots overflow, ``jax.lax.ragged_dot`` runs,
+which is also the kernels' oracle.  The slots that
 landed here are a prefix of the sorted order whose length the device knows
 after the router: everything after the router is one function of a static
 row count, traced at :func:`prefix_rows` (three times an even router's share)
@@ -327,6 +336,62 @@ def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
     return chosen, scale * picked
 
 
+def _backend() -> str:
+    """The backend the process computes on (a compile test for a described
+    chip, on a CPU host, says "tpu" here)."""
+    import jax
+
+    return jax.default_backend()
+
+
+def grouped_runs_fused(rows: int, k: int, n: int, dtype, *,
+                       initializing: bool = False,
+                       overflow: bool = False) -> bool:
+    """How a grouped product of ``rows`` sorted rows (rows, ``k``) by held
+    weights (H, ``k``, ``n``) executes, operands in ``dtype``: on the Pallas
+    kernels of ``grouped_pallas`` (True) or as ``jax.lax.ragged_dot``
+    (False).  Decided from what the code can observe:
+
+    - the backend is a TPU, ``k`` and ``n`` are whole rows of 128 lanes and
+      the rows whole row tiles of the kernels' own (``grouped_pallas.fits``:
+      the published 2,048 by 1,536 and by 1,792 over 12,288 and 24,576 rows
+      do; ``Config.tiny()``'s do not);
+    - the module is not ``initializing``: a flax module's ``init`` traces
+      its forward pass to learn its parameters and runs none of it
+      (``Trainer.__init__`` traces it twice); the kernels there cost a warm
+      start of ``lfm2_8b_a1b_packed_8k`` 1.6 s of tracing and importing
+      (PERF.md section 6, PR 42);
+    - the form is not the ``overflow`` one: a layer whose live slots pass
+      :func:`prefix_rows` takes all the slots, which happens in none of
+      ``lfm2_8b_a1b_packed_8k``'s steps and on one seed in nine of
+      ``glm47_flash_packed_8k``'s, over a buffer three quarters live, where
+      a walk over the live tiles saves least; its kernels were half of a
+      step's, 44 MB of its code and 0.4 s of every start's load and
+      lowering.
+
+    The same for a product and its two gradients (``k`` and ``n`` change
+    places), so a form of the routed part runs all twelve of its products
+    one way."""
+    from tensorflowonspark_tpu.parallel import grouped_pallas
+
+    return (not initializing and not overflow and _backend() == "tpu"
+            and grouped_pallas.fits(rows, k, n, dtype))
+
+
+def grouped_step_counters(tokens: int, top_k: int, n_held: int,
+                          n_experts: int, d: int, f: int, dtype) -> dict:
+    """What one step of a model of such layers adds to the program's
+    counters: one step of grouped products on the kernels or as
+    ``jax.lax.ragged_dot``, the other named with 0 so that both are on the
+    record.  On the kernels means: the form :func:`routed_experts` takes for
+    ``tokens`` tokens of width ``d`` and experts ``f`` wide when a layer's
+    slots fit (:func:`prefix_rows` of them) runs all its products there."""
+    fused = grouped_runs_fused(
+        prefix_rows(tokens * top_k, n_held, n_experts), d, f, dtype)
+    return {"moe_grouped_fused_steps_total": int(fused),
+            "moe_grouped_plain_steps_total": int(not fused)}
+
+
 def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
     """Rows the routed part of :func:`routed_experts` works on when a
     step's held slots fit them: three times what an even router sends to
@@ -343,10 +408,11 @@ def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _routed_part():
+def _routed_part(scopes: tuple = ()):
     """The held experts' part of the layer after the router, as a function
-    of how many sorted rows it works on.  Made once: the module imports JAX
-    only when it is used."""
+    of how many sorted rows it works on.  Made once for the calls that sit
+    under the same ``scopes`` (the module imports JAX only when it is
+    used)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -411,11 +477,12 @@ def _routed_part():
 
     combine.defvjp(combine_fwd, combine_bwd)
 
-    def over_rows(n_rows, k, order, inv, group_sizes, x, gates, w_gate, w_up,
-                  w_down):
+    def over_rows(n_rows, fused, k, order, inv, group_sizes, x, gates, w_gate,
+                  w_up, w_down):
         """The routed part over the first ``n_rows`` sorted slots, which
-        hold every live one: spread, three grouped products, weighted sum
-        into (T, D)."""
+        hold every live one: spread, three grouped products (``fused``: on
+        the kernels, as :func:`grouped_runs_fused` said), weighted sum into
+        (T, D)."""
         dtype = x.dtype
         with jax.named_scope("moe_dispatch"):
             idx = order[:n_rows]
@@ -424,8 +491,17 @@ def _routed_part():
             # grouped product leaves there never meets a live row
             xs = jnp.where(live, spread(x, idx, inv, k), 0)
 
+        if fused:
+            from tensorflowonspark_tpu.parallel import grouped_pallas
+
+            with jax.named_scope("moe_experts"):
+                visits = grouped_pallas.plan(group_sizes, n_rows)
+
         def grouped(rows, w):
             with jax.named_scope("moe_experts"):
+                if fused:
+                    return grouped_pallas.grouped_product(rows, w, visits,
+                                                          "moe_experts")
                 return jax.lax.ragged_dot(rows, w.astype(dtype), group_sizes,
                                           preferred_element_type=f32)
 
@@ -436,46 +512,56 @@ def _routed_part():
             out = jnp.where(live, out, 0).astype(dtype)
             return combine(out, gates, idx, inv)
 
-    def by_count(n_prefix, form, order, inv, group_sizes, *operands):
-        """``form(n_rows)`` of the operands at ``n_prefix`` rows where this
-        step's live slots fit them, at all the slots where they do not;
-        the device chooses."""
+    def by_count(n_prefix, fused, form, order, inv, group_sizes, *operands):
+        """``form(n_rows, fused)`` of the operands at ``n_prefix`` rows
+        where this step's live slots fit them, at all the slots (the
+        overflow form) where they do not; the device chooses.  ``fused``
+        says of each of the two whether its products run on the kernels."""
         n_slots, args = order.shape[0], (order, inv, group_sizes, *operands)
         if n_prefix >= n_slots:
-            return form(n_slots)(*args)
-        return jax.lax.cond(jnp.sum(group_sizes) <= n_prefix, form(n_prefix),
-                            form(n_slots), *args)
+            return form(n_slots, fused[0])(*args)
+        return jax.lax.cond(jnp.sum(group_sizes) <= n_prefix,
+                            form(n_prefix, fused[0]),
+                            form(n_slots, fused[1]), *args)
 
     # A differentiated ``cond`` has every branch write zeros in the place
     # of the other's residuals: the forward and the backward pass choose
     # each for itself, and the backward one makes the products again.
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-    def routed(n_prefix, k, *args):
-        return by_count(n_prefix,
-                        lambda n: functools.partial(over_rows, n, k), *args)
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+    def routed(n_prefix, fused, k, *args):
+        return by_count(n_prefix, fused, lambda n, on_kernels:
+                        functools.partial(over_rows, n, on_kernels, k), *args)
 
-    def routed_fwd(n_prefix, k, *args):
-        return routed(n_prefix, k, *args), args
+    def routed_fwd(n_prefix, fused, k, *args):
+        return routed(n_prefix, fused, k, *args), args
 
-    def routed_bwd(n_prefix, k, args, dy):
-        def backward(n_rows):
+    def routed_bwd(n_prefix, fused, k, args, dy):
+        def backward(n_rows, on_kernels):
             def run(order, inv, group_sizes, dy, *operands):
                 return jax.vjp(functools.partial(
-                    over_rows, n_rows, k, order, inv, group_sizes),
-                    *operands)[1](dy)
+                    over_rows, n_rows, on_kernels, k, order, inv,
+                    group_sizes), *operands)[1](dy)
             return run
 
-        grads = by_count(n_prefix, backward, *args[:3], dy, *args[3:])
+        grads = by_count(n_prefix, fused, backward, *args[:3], dy, *args[3:])
         return (*map(no_grad, args[:3]), *grads)
 
     routed.defvjp(routed_fwd, routed_bwd)
-    return routed
+    # A model's expert layers share their shapes: under ``jax.jit`` the part
+    # is traced, differentiated and lowered once a shape and an execution
+    # (both are in the static arguments), not once a layer.  The trace of a
+    # step is paid at every start, warm or cold (PERF.md section 6, PR 42).
+    # The compiler names every copy of a shared function after all its
+    # callers, so calls under different ``jax.named_scope``s (a prediction
+    # module's layer) get a function of their own: ``scopes``.
+    return jax.jit(routed, static_argnums=(0, 1, 2))
 
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
                    top_k: int, scale: float, normalize: bool = True,
-                   sum_eps: float = 0.0):
+                   sum_eps: float = 0.0, initializing: bool = False,
+                   scopes: tuple = ()):
     """The held experts' part of a routed SwiGLU layer on tokens ``x``
     (T, D): ``sum over e chosen and held of g_e W_down_e (silu(x W_gate_e)
     * (x W_up_e))``, and the tokens that chose each of the router's experts.
@@ -486,7 +572,12 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     ``[0, E)``) says which they are, in the weights' order.  Products take
     operands in ``x``'s type and accumulate in float32; routing is float32
     (:func:`topk_route`, which ``top_k``, ``scale``, ``normalize`` and
-    ``sum_eps`` go to).
+    ``sum_eps`` go to).  ``initializing`` is what the calling module
+    observes of itself (flax's ``is_initializing()``): such a trace is never
+    run, and :func:`grouped_runs_fused` puts no kernel into it.  ``scopes``
+    are the ``jax.named_scope``s the caller has opened round the layer, where
+    a profile should tell its operations from another layer's (``mla_moe``'s
+    prediction module): layers that name the same share one traced part.
 
     Every slot is kept: the ``T top_k`` slots are sorted by held expert
     (those of experts held elsewhere last), so the live ones are the first
@@ -517,9 +608,17 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
         place[held] = np.arange(n_held)
         order = jnp.argsort(jnp.asarray(place)[slot_expert], stable=True)
         inv = jnp.argsort(order)
-    y = _routed_part()(
-        prefix_rows(slot_expert.shape[0], n_held, n_experts), top_k, order,
-        inv, counts[held], x, gates, w_gate, w_up, w_down)
+    n_slots = slot_expert.shape[0]
+    n_prefix = prefix_rows(n_slots, n_held, n_experts)
+    # the two forms, at ``n_prefix`` rows and (the overflow one) at all the
+    # slots: which of them run their products on the kernels
+    fused = tuple(
+        grouped_runs_fused(rows, *w_gate.shape[1:], x.dtype,
+                           initializing=initializing, overflow=overflow)
+        for rows, overflow in ((n_prefix, False), (n_slots, True)))
+    y = _routed_part(tuple(scopes))(n_prefix, fused, top_k, order, inv,
+                                    counts[held], x, gates, w_gate, w_up,
+                                    w_down)
     return y, counts
 
 

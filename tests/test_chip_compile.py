@@ -316,6 +316,57 @@ def test_attention_kernels_compile_at_the_published_widths(topo):
                 < 6 * t * heads * hd * 2)
 
 
+@pytest.mark.parametrize("rows,k,n", [
+    (24576, 2048, 1792), (24576, 1792, 2048),     # lfm2_8b_a1b_packed_8k
+    (12288, 2048, 1536), (12288, 1536, 2048),     # glm47_flash_packed_8k
+    (32768, 2048, 1792),        # all the slots: a model with one form
+])
+def test_grouped_kernels_compile_at_the_published_widths(topo, rows, k, n):
+    """The Pallas kernels of the routed experts' grouped products
+    (``parallel/grouped_pallas.py``) through the TPU's compiler at shapes
+    the two expert cells run — eight groups, bfloat16 rows against float32
+    weights — forward and gradient: this refuses what interpret mode cannot
+    (a grid whose length is a device scalar, a product contracted over the
+    rows' axis, more fast memory than a kernel may use: a group's weights
+    are resident).  Compiled, every kernel call carries ``moe_experts`` as a
+    word of its ``op_name`` (``benchmark/moe_scopes.py`` finds the grouped
+    products by it; the backward pass opens the scope itself), nothing is a
+    ``ragged-dot`` kernel of the compiler's, and the temporaries are the
+    operands' size: the result, the cast weights and the two gradients."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.parallel import grouped_pallas
+
+    bf, groups = jnp.bfloat16, 8
+    assert grouped_pallas.fits(rows, k, n, bf)
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((rows, k), bf), ((groups, k, n), jnp.float32),
+        ((groups,), jnp.int32))]
+
+    def loss(x, w, sizes):
+        with jax.named_scope("moe_experts"):
+            out = grouped_pallas.grouped_product(
+                x, w, grouped_pallas.plan(sizes, rows), "moe_experts")
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        return jnp.sum(jnp.where(live, out, 0) ** 2)
+
+    for fn, kernels in ((loss, ["grouped_rows"]),
+                        (jax.grad(loss, (0, 1)),
+                         ["grouped_rows", "grouped_rows", "grouped_weights"])):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        text = compiled.as_text()
+        names = _pallas_calls(text)
+        assert sorted(name.split("/")[-2] for name in names) == kernels, names
+        assert all(re.search(r"\bmoe_experts\b", name) for name in names)
+        assert "ragged-dot" not in text
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 4 * (rows * (k + n) + groups * k * n) * 2)
+
+
 @pytest.mark.slow  # ~60 s here; the builder's by-hand rehearsal
 def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``granite_4_0_h_micro`` configuration as the benchmark builds it
@@ -357,6 +408,35 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
 
 
+def _pallas_calls(text: str) -> list:
+    """The ``op_name`` of every Pallas call in a compiled module's text."""
+    import re
+
+    return re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+
+
+def _assert_grouped_kernels(text: str, layers: int) -> None:
+    """``text``: a compiled step.  The form of the routed part that a step
+    takes when a layer's slots fit (``moe.prefix_rows`` of them) holds
+    twelve grouped products on the kernels: the forward three, the same
+    again where the backward pass asks for them, the rows' gradients and
+    the weights'; each under the ``moe_experts`` scope.  The overflow form
+    (all the slots) keeps the compiler's own ``ragged-dot`` kernels, twelve
+    a layer: it is compiled, loaded at every start and run in almost no
+    step, so it is kept small (``moe.grouped_runs_fused``)."""
+    import re
+
+    ours = [n for n in _pallas_calls(text) if "/grouped_" in n]
+    for kernel, calls in (("grouped_rows", 9), ("grouped_weights", 3)):
+        assert sum(f"/{kernel}/" in n for n in ours) == layers * calls
+    assert all(re.search(r"\bmoe_experts\b", n) for n in ours), ours
+    # (the compiler drops the scopes a ``ragged_dot`` was traced under and
+    # keeps its caller's: the routed part is a ``jax.jit`` of its own)
+    assert len(re.findall(r'op_name="[^"]*\bragged-dot-none"',
+                          text)) == layers * 12
+
+
 @pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
 def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``glm_4_7_flash`` configuration as the benchmark builds it (the
@@ -364,21 +444,26 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     prediction module, an eighth of the vocabulary: 706,518,528 float32
     parameters under AdamW) on one packed row of 8,192 tokens, through the
     TPU compiler: parameters, both moments and the routing state are
-    donated and updated in place, the grouped products are the compiler's
-    own kernels (three a layer forward, three recomputed, six backward, in
-    each of the two sizes the routed part is traced at),
+    donated and updated in place, the grouped products are the ones a chip
+    runs (the Pallas kernels of ``grouped_pallas`` in the form a step takes
+    when its slots fit: nine ``grouped_rows`` and three ``grouped_weights``
+    a layer; the compiler's own ``ragged-dot`` kernels in the overflow
+    form),
     attention is the one a chip runs (the Pallas kernels: here the backend
-    is the CPU, so the test says "tpu" in ``packed_rows``'s place; a layer
-    calls the forward kernel, calls it again in its recomputation and the
-    backward kernel once), and arguments plus temporaries stay under the
-    chip's memory.  PERF.md section 4 holds the figures."""
+    is the CPU, so the test says "tpu" in ``packed_rows``'s place and in
+    ``moe``'s; a layer calls the forward kernel, calls it again in its
+    recomputation and the backward kernel once), and arguments plus
+    temporaries stay under the chip's memory.  PERF.md section 4 holds the
+    figures."""
     import json
     import re
 
     from benchmark.configs.glm_4_7_flash import program
     from tensorflowonspark_tpu.models import packed_rows
+    from tensorflowonspark_tpu.parallel import moe
 
     monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs", "glm_4_7_flash",
                            "config.json")) as f:
@@ -393,14 +478,13 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     stats = compiled.memory_analysis()
     print(f"glm_4_7_flash, one described chip: {stats}")
     text = compiled.as_text()
-    assert text.count(" custom-call(") >= 5 * 24
-    # each of a layer's two forms of the routed part (``moe.prefix_rows``
-    # of the slots, or all of them) holds its own twelve
-    assert text.count('op_name="ragged-dot-none"') == 5 * 24
-    kernels = re.findall(
-        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
-    # (the compiler's own ``ragged-dot`` kernels are custom calls too)
-    ours = [n for n in kernels if "/attention_" in n]
+    _assert_grouped_kernels(text, layers=5)
+    # the five layers share one traced routed part (``jax.jit``), and each
+    # copy the compiler makes of it still carries its caller's scopes: the
+    # prediction module's twelve products are named for it
+    grouped = [n for n in _pallas_calls(text) if "/grouped_" in n]
+    assert sum("mtp" in n.split("/") for n in grouped) == 12, grouped[:3]
+    ours = [n for n in _pallas_calls(text) if "/attention_" in n]
     for kernel, calls in (("attention_forward", 12),
                           ("attention_backward", 6)):
         assert sum(f"/{kernel}/" in n for n in ours) == calls, ours
@@ -420,23 +504,27 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     with 8 of 32 experts held, a quarter of the vocabulary: 606,456,064
     float32 parameters under AdamW) on one packed row of 8,192 tokens,
     through the TPU compiler: parameters, both moments and the routing state
-    are donated and updated in place, the grouped products are the
-    compiler's own kernels (three a layer forward, three recomputed, six
-    backward, in each of the two sizes the routed part is traced at: a
-    quarter share leaves ``moe.prefix_rows`` under all the slots), attention
-    at heads of 64 is ``jnp`` code on a TPU too (the test says "tpu" in
-    ``packed_rows``'s place, the rule still says plain, and no kernel of
-    ours is called), and arguments, temporaries and code stay under 15.75
-    GiB.  PERF.md section 4 holds the figures."""
+    are donated and updated in place, the grouped products are the ones a
+    chip runs (the Pallas kernels of ``grouped_pallas``, nine
+    ``grouped_rows`` and three ``grouped_weights`` a layer, in the form at
+    ``moe.prefix_rows`` of the slots — a quarter share leaves that under
+    all of them — and the compiler's own ``ragged-dot`` kernels in the
+    overflow form), attention at heads of 64 is ``jnp`` code on a TPU too (the
+    test says "tpu" in ``packed_rows``'s place and in ``moe``'s, the
+    attention rule still says plain, and no attention kernel is called),
+    and arguments, temporaries and code stay under 15.75 GiB.  PERF.md
+    section 4 holds the figures."""
     import json
 
     from benchmark.configs.lfm2_8b_a1b import program
     from tensorflowonspark_tpu.models import packed_rows
+    from tensorflowonspark_tpu.parallel import moe
 
     with open(os.path.join(REPO, "benchmark", "configs", "lfm2_8b_a1b",
                            "config.json")) as f:
         published = json.load(f)
     monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
     config = program.model_config(published)
     assert not packed_rows.attention_runs_fused(config.seq_len,
                                                 config.head_dim)
@@ -449,7 +537,7 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     stats = compiled.memory_analysis()
     print(f"lfm2_8b_a1b, one described chip: {stats}")
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-none"') == 5 * 24
+    _assert_grouped_kernels(text, layers=5)
     assert "/attention_forward/" not in text
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
