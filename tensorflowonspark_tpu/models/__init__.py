@@ -21,22 +21,27 @@ Optional hooks the Trainer looks for: ``make_optimizer``,
 program's counters) and ``device_counters(collections, config)`` (what of
 the step's collections the counters show: what the device decided).
 
-The three decoders trained on packed rows, ``granite_hybrid`` (state-space
+The four decoders trained on packed rows, ``granite_hybrid`` (state-space
 mixers and a NoPE attention layer), ``mla_moe`` (latent attention, routed
-and shared experts, the multi-token-prediction module) and ``lfm2_moe``
+and shared experts, the multi-token-prediction module), ``lfm2_moe``
 (gated short-convolution mixers, a QK-normed RoPE attention layer, routed
-experts and no shared one), share ``packed_rows.py``: norm, products,
-SwiGLU, the positions inside documents and RoPE at them (``mla_moe``,
-``lfm2_moe``), the depthwise causal convolution that stops at a document's
-first token (``granite_hybrid``, ``lfm2_moe``), attention inside documents
-(as ``jnp`` code or, on a TPU where a head fills whole lanes, the Pallas
-kernels of ``attention_pallas.py``; granite's scan has ``ssd_pallas.py``),
-the blocked loss.  Two expert layers live in ``parallel/moe.py``: ``bert``
-calls ``moe_ffn`` (Switch top-1 with a capacity, over ``ep``), ``mla_moe``
-and ``lfm2_moe`` call ``routed_experts`` (top-k of a wide router, the experts
-held here, no drop, the work sized to a step's own count of slots that
-landed here) and keep its routing state — correction biases, their update,
-the counts the program's counters show — by the same three functions there.
+experts and no shared one) and ``kimi_linear`` (Kimi Delta Attention mixers
+— a channel-wise gated delta rule computed in chunks — beside NoPE latent
+attention whose values are narrower than its keys, routed and shared
+experts), share ``packed_rows.py``: norm, products, SwiGLU, the positions
+inside documents and RoPE at them (``mla_moe``, ``lfm2_moe``), the depthwise
+causal convolution that stops at a document's first token
+(``granite_hybrid``, ``lfm2_moe``, ``kimi_linear``), latent attention with or
+without a query latent and rotation (``mla_moe``, ``kimi_linear``), attention
+inside documents (as ``jnp`` code or, on a TPU where a head fills whole
+lanes, the Pallas kernels of ``attention_pallas.py``; granite's scan has
+``ssd_pallas.py``), the blocked loss.  Two expert layers live in
+``parallel/moe.py``: ``bert`` calls ``moe_ffn`` (Switch top-1 with a
+capacity, over ``ep``), ``mla_moe``, ``lfm2_moe`` and ``kimi_linear`` call
+``routed_experts`` (top-k of a wide router, the experts held here, no drop,
+the work sized to a step's own count of slots that landed here) and keep its
+routing state — correction biases, their update, the counts the program's
+counters show — by the same three functions there.
 """
 
 from __future__ import annotations
@@ -55,6 +60,7 @@ _REGISTRY = {
     "granite_hybrid": "tensorflowonspark_tpu.models.granite_hybrid",
     "mla_moe": "tensorflowonspark_tpu.models.mla_moe",
     "lfm2_moe": "tensorflowonspark_tpu.models.lfm2_moe",
+    "kimi_linear": "tensorflowonspark_tpu.models.kimi_linear",
 }
 
 

@@ -58,6 +58,12 @@ heads (20 x 256) run attention on the Pallas kernels of ``attention_pallas``
 ``Config.tiny()`` as ``jnp`` code, and a step counts which applied
 (``attention_fused_steps_total`` / ``attention_plain_steps_total``).
 
+The latent attention itself is ``packed_rows.latent_attention`` (since PR
+43, when a second layout came to call it: ``kimi_linear``, with no query
+latent, no rotation and values narrower than its keys; this layout's keys
+and values happen to be one width, and the blocked attention no longer asks
+for that).
+
 ``jax.named_scope`` names a device trace can be cut by: ``attention`` >
 ``mla_project`` (the latent projections, their norms, RoPE); ``mlp`` (the
 dense feed-forward); ``shared_expert``; ``moe_router``, ``moe_dispatch``,
@@ -76,9 +82,10 @@ import math
 
 import numpy as np
 
+from tensorflowonspark_tpu.models import packed_rows
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, blocked_cross_entropy, document_attention, document_positions,
-    example_rows, loss_positions, mm, rms, rope, row_counters, swiglu)
+    block, blocked_cross_entropy, document_positions, example_rows,
+    loss_positions, mm, rms, row_counters, swiglu)
 
 #: no sequence-parallel sharding: attention sees a whole row
 SEQUENCE_AXES: dict = {}
@@ -122,10 +129,6 @@ class Config:
     loss_block: int = 2048          # tokens whose logits are held at a time
 
     def __post_init__(self):
-        if self.qk_nope_head_dim + self.qk_rope_head_dim != self.v_head_dim:
-            raise ValueError("the blocked attention wants keys and values of"
-                             " one size a head: qk_nope + qk_rope = "
-                             f"{self.qk_head_dim}, v {self.v_head_dim}")
         if self.num_nextn_predict_layers not in (0, 1):
             raise ValueError("one prediction module, or none")
         if self.qk_rope_head_dim % 2:
@@ -220,36 +223,16 @@ def collection_shapes(config: Config) -> dict:
 
 def latent_attention(params, prefix: str, h, seg, pos, config: Config,
                      scopes: tuple = ("attention",)):
-    """Multi-head latent attention on one row: ``h`` (T, D) -> (T, D).
-    The expanded heads are ``qk_head_dim`` wide for keys and values alike,
-    so ``document_attention`` serves them as ``kv`` = heads, ``rep`` = 1."""
-    import jax
-    import jax.numpy as jnp
-
-    dtype, t = h.dtype, h.shape[0]
-    heads, nope = config.num_attention_heads, config.qk_nope_head_dim
-    eps = config.rms_norm_eps
-    with jax.named_scope("mla_project"):
-        c_q = rms(mm("td,dr->tr", h, params[prefix + "q_a"], dtype),
-                  params[prefix + "q_a_norm"], eps)
-        q = mm("tr,re->te", c_q, params[prefix + "q_b"], dtype).reshape(
-            t, heads, config.qk_head_dim)
-        kv_a = mm("td,dr->tr", h, params[prefix + "kv_a"], dtype)
-        c_kv = rms(kv_a[:, :config.kv_lora_rank],
-                   params[prefix + "kv_a_norm"], eps)
-        kv = mm("tr,re->te", c_kv, params[prefix + "kv_b"], dtype).reshape(
-            t, heads, nope + config.v_head_dim)
-        q = jnp.concatenate(
-            [q[..., :nope], rope(q[..., nope:], pos, config.rope_theta)], -1)
-        k_rope = rope(kv_a[:, config.kv_lora_rank:], pos, config.rope_theta)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            k_rope[:, None, :], (t, heads, config.qk_rope_head_dim))], -1)
-    o = document_attention(
-        q[:, :, None, :], k, kv[..., nope:], seg,
-        1.0 / math.sqrt(config.qk_head_dim),
-        block(t, config.attention_block), dtype, scopes)
-    return mm("te,ed->td", o.reshape(t, heads * config.v_head_dim),
-              params[prefix + "wo"], dtype)
+    """``packed_rows.latent_attention`` at this layout's sizes: the queries
+    through a latent of ``q_lora_rank``, the shared part of queries and keys
+    turned by RoPE(``rope_theta``)."""
+    return packed_rows.latent_attention(
+        params, prefix, h, seg, pos, heads=config.num_attention_heads,
+        nope=config.qk_nope_head_dim, rope_dim=config.qk_rope_head_dim,
+        v_dim=config.v_head_dim, kv_rank=config.kv_lora_rank,
+        eps=config.rms_norm_eps,
+        size=block(h.shape[0], config.attention_block),
+        q_rank=config.q_lora_rank, theta=config.rope_theta, scopes=scopes)
 
 
 def expert_ffn(params, prefix: str, h, bias, config: Config,
@@ -510,7 +493,8 @@ def batch_counters(batch, config: Config) -> dict:
 
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
-    return {**row_counters(seg, config.qk_head_dim),
+    return {**row_counters(seg, config.qk_head_dim,
+                           v_head_dim=config.v_head_dim),
             **moe.grouped_step_counters(
                 seg.size, config.num_experts_per_tok,
                 len(config.experts_held), config.n_routed_experts,

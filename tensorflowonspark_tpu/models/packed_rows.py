@@ -1,22 +1,26 @@
 """What the decoders trained on packed rows have in common
-(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``): the RMS norm, the product
-with operands in the activations' type, the SwiGLU feed-forward, the
-positions inside documents and the rotary embedding at them, the depthwise
-causal convolution that stops at a document's first token, causal attention
-inside documents a block of queries at a time, and the next-token
+(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``): the RMS
+norm, the product with operands in the activations' type, the SwiGLU
+feed-forward, the positions inside documents and the rotary embedding at
+them, the depthwise causal convolution that stops at a document's first
+token, causal attention inside documents a block of queries at a time (the
+values at their own width), latent attention over it, and the next-token
 cross-entropy a block of tokens at a time.
 
 A packed row is ``T`` tokens with segment ids ``s`` (the document's number
 inside the row; documents are contiguous and their ids differ).  One
-implementation of each piece, each called by two models or by all three
-(:func:`causal_conv`: granite's state-space mixers and LFM2's gated short
-convolutions; :func:`rope` and :func:`document_positions`: GLM's latent
-attention and LFM2's grouped-query attention): what is measured on one
-model's cell is what the others run.
+implementation of each piece, each called by two models or more
+(:func:`causal_conv`: granite's state-space mixers, LFM2's gated short
+convolutions and Kimi Linear's delta-rule mixers; :func:`rope` and
+:func:`document_positions`: GLM's latent attention and LFM2's grouped-query
+attention; :func:`latent_attention`: GLM's, with a query latent and RoPE, and
+Kimi Linear's, with neither): what is measured on one model's cell is what
+the others run.
 
 Attention is one algorithm with two executions
 (:func:`document_attention`): on a TPU, where a head fills whole rows of
-128 lanes (GLM's 20 x 256 do; granite's and LFM2's 32/8 x 64 do not), the
+128 lanes (GLM's 20 x 256 do; granite's and LFM2's 32/8 x 64 and Kimi
+Linear's keys of 192 beside values of 128 do not), the
 Pallas kernels of ``attention_pallas`` keep every (queries x keys) score
 tile on the chip, forward and backward; on any other backend and at other
 shapes (``Config.tiny()``, the tests) the ``jnp`` form in this file runs,
@@ -26,7 +30,7 @@ rule, and the models' steps count which applied
 
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
-are here too: host code, one copy for the three.
+are here too: host code, one copy for the four.
 
 JAX is imported where it is used, as in the models.
 """
@@ -70,19 +74,22 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def attention_runs_fused(t: int, hd: int) -> bool:
-    """How :func:`document_attention` executes on a row of ``t`` tokens and
-    heads of ``hd``: on the Pallas kernels of ``attention_pallas`` (True) or
-    as ``jnp`` code (False).  Decided from what the code can observe: the
-    backend is a TPU, a head's row fills whole rows of 128 lanes and the
-    row of tokens is whole blocks of the kernels' own size
-    (``attention_pallas.fits``: GLM's published 20 x 256 over 8,192 tokens
-    do; granite's 32/8 x 64, whose heads would have to be packed in pairs,
-    and ``Config.tiny()``'s do not).  The number of heads does not enter:
-    the kernels take any."""
+def attention_runs_fused(t: int, hd: int, vd: int | None = None) -> bool:
+    """How :func:`document_attention` executes on a row of ``t`` tokens,
+    heads of ``hd`` and values of ``vd`` (a head's width where not given):
+    on the Pallas kernels of ``attention_pallas`` (True) or as ``jnp`` code
+    (False).  Decided from what the code can observe: the backend is a TPU,
+    a head's row fills whole rows of 128 lanes, the row of tokens is whole
+    blocks of the kernels' own size (``attention_pallas.fits``: GLM's
+    published 20 x 256 over 8,192 tokens do; granite's 32/8 x 64, whose
+    heads would have to be packed in pairs, and ``Config.tiny()``'s do not)
+    and the values are as wide as the keys (the kernels take one width: a
+    latent layout with narrower values runs the ``jnp`` form).  The number
+    of heads does not enter: the kernels take any."""
     from tensorflowonspark_tpu.models import attention_pallas
 
-    return _backend() == "tpu" and attention_pallas.fits(t, hd)
+    return (_backend() == "tpu" and vd in (None, hd)
+            and attention_pallas.fits(t, hd))
 
 
 def swiglu(h, w_gate, w_up, w_down):
@@ -155,8 +162,9 @@ def _attend_fwd(q, k, v, seg, scale, size, dtype):
 
     f32 = jnp.float32
     t, kv, rep, hd = q.shape
+    vd = v.shape[-1]
     n = t // size
-    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, vd)
     segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
 
     def block_(args):
@@ -175,13 +183,13 @@ def _attend_fwd(q, k, v, seg, scale, size, dtype):
         m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
             jnp.full((kv, rep, size), -1e30, f32),
             jnp.zeros((kv, rep, size), f32),
-            jnp.zeros((kv, rep, size, hd), f32)))
+            jnp.zeros((kv, rep, size, vd), f32)))
         return ((acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype),
                 m + jnp.log(l))
 
     out, lse = jax.lax.map(block_, (
         q.reshape(n, size, kv, rep, hd), segb, posb, jnp.arange(n)))
-    return out.reshape(t, kv, rep, hd), lse
+    return out.reshape(t, kv, rep, vd), lse
 
 
 def under(scopes):
@@ -205,8 +213,9 @@ def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
     f32 = jnp.float32
     q, k, v, seg, out, lse = saved
     t, kv, rep, hd = q.shape
+    vd = v.shape[-1]
     n = t // size
-    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, hd)
+    kb, vb = k.reshape(n, size, kv, hd), v.reshape(n, size, kv, vd)
     segb, posb = seg.reshape(n, size), jnp.arange(t).reshape(n, size)
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
 
@@ -234,7 +243,7 @@ def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
         (dk, dv), dq = jax.lax.scan(
             block_, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)), (
                 q.reshape(n, size, kv, rep, hd),
-                d_out.reshape(n, size, kv, rep, hd), segb, posb,
+                d_out.reshape(n, size, kv, rep, vd), segb, posb,
                 jnp.arange(n), lse,
                 delta.reshape(n, size, kv, rep).transpose(0, 2, 3, 1)))
     return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
@@ -271,20 +280,69 @@ def document_attention(q, k, v, seg, scale: float, size: int, dtype,
     pass runs under the caller's own).  Every row costs the same whatever
     its documents are (the blocks of another document are visited and
     masked): a step's time does not depend on the data.  ``q`` (T, kv, rep,
-    hd), ``k`` and ``v`` (T, kv, hd), ``seg`` (T,); returns (T, kv, rep,
-    hd).
+    hd), ``k`` (T, kv, hd), ``v`` (T, kv, vd) — values at their own width,
+    which a latent-attention layout may make narrower than its keys —,
+    ``seg`` (T,); returns (T, kv, rep, vd).
 
     One algorithm, two executions (:func:`attention_runs_fused`): on a TPU,
     where a head fills whole rows of lanes, the kernels of
     ``attention_pallas`` keep each score tile on the chip, forward and
-    backward; anywhere else the ``jnp`` form above runs in blocks of
-    ``size``, which is also the kernels' oracle."""
-    if attention_runs_fused(q.shape[0], q.shape[-1]):
+    backward (they take keys and values of one width); anywhere else the
+    ``jnp`` form above runs in blocks of ``size``, which is also the
+    kernels' oracle."""
+    if attention_runs_fused(q.shape[0], q.shape[-1], v.shape[-1]):
         from tensorflowonspark_tpu.models import attention_pallas
 
         return attention_pallas.fused_attention(q, k, v, seg, scale, dtype,
                                                 scopes)
     return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes))
+
+
+def latent_attention(params, prefix: str, h, seg, pos, *, heads: int,
+                     nope: int, rope_dim: int, v_dim: int, kv_rank: int,
+                     eps: float, size: int, q_rank=None, theta=None,
+                     scopes: tuple = ("attention",)):
+    """Multi-head latent attention (arXiv:2405.04434) on one row: ``h``
+    (T, D) -> (T, D).  Keys and values are expanded from one normed latent
+    of ``kv_rank`` a token (``kv_a``, ``kv_a_norm``, ``kv_b``); a head's key
+    is its own ``nope`` numbers and ``rope_dim`` more that ``kv_a`` writes
+    once for all heads, its value ``v_dim`` wide.  Two things are the
+    caller's layout's: the queries come through a normed latent of
+    ``q_rank`` (``q_a``, ``q_a_norm``, ``q_b``) or, where it is None, from
+    one projection ``wq``; the shared ``rope_dim`` numbers of queries and
+    keys are turned by RoPE(``theta``) at the positions ``pos`` or, where
+    ``theta`` is None, are left as they are (no positional encoding; ``pos``
+    is not read).  The softmax is scaled by ``(nope + rope_dim) ** -0.5``
+    and runs in ``document_attention`` as ``kv`` = heads, ``rep`` = 1, in
+    blocks of ``size`` queries; ``wo`` writes the heads back."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype, t = h.dtype, h.shape[0]
+    with jax.named_scope("mla_project"):
+        if q_rank is None:
+            q = mm("td,de->te", h, params[prefix + "wq"], dtype)
+        else:
+            c_q = rms(mm("td,dr->tr", h, params[prefix + "q_a"], dtype),
+                      params[prefix + "q_a_norm"], eps)
+            q = mm("tr,re->te", c_q, params[prefix + "q_b"], dtype)
+        q = q.reshape(t, heads, nope + rope_dim)
+        kv_a = mm("td,dr->tr", h, params[prefix + "kv_a"], dtype)
+        c_kv = rms(kv_a[:, :kv_rank], params[prefix + "kv_a_norm"], eps)
+        kv = mm("tr,re->te", c_kv, params[prefix + "kv_b"], dtype).reshape(
+            t, heads, nope + v_dim)
+        k_rope = kv_a[:, kv_rank:]
+        if theta is not None:
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], pos, theta)], -1)
+            k_rope = rope(k_rope, pos, theta)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_rope[:, None, :], (t, heads, rope_dim))], -1)
+    o = document_attention(
+        q[:, :, None, :], k, kv[..., nope:], seg,
+        (nope + rope_dim) ** -0.5, size, dtype, scopes)
+    return mm("te,ed->td", o.reshape(t, heads * v_dim),
+              params[prefix + "wo"], dtype)
 
 
 def loss_positions(seg, ahead: int = 1):
@@ -324,17 +382,20 @@ def blocked_cross_entropy(x, logits_fn, targets, valid, want: int):
     return jnp.sum(sums)
 
 
-def row_counters(segment_ids, head_dim: int, attends: bool = True) -> dict:
+def row_counters(segment_ids, head_dim: int, attends: bool = True,
+                 v_head_dim: int | None = None) -> dict:
     """What one step of packed rows adds to the program's counters, whatever
     the model.  From its host batch's segment ids (B, T): tokens, tokens
     that bear a loss (the next token is the same document's) and documents
     (runs of one segment id).  From the rule its trace applied
     (:func:`attention_runs_fused`; ``attends``: the model has an attention
-    layer): one step of attention on the kernels or as ``jnp`` code, the
-    other named with 0 so that both are on the record."""
+    layer; ``v_head_dim``: its values' width where it is not ``head_dim``):
+    one step of attention on the kernels or as ``jnp`` code, the other named
+    with 0 so that both are on the record."""
     seg = np.asarray(segment_ids)
     same = seg[:, 1:] == seg[:, :-1]
-    on_chip = attends and attention_runs_fused(seg.shape[1], head_dim)
+    on_chip = attends and attention_runs_fused(seg.shape[1], head_dim,
+                                               v_head_dim)
     return {"lm_tokens_total": int(seg.size),
             "lm_loss_tokens_total": int(same.sum()),
             "lm_documents_total": int(seg.shape[0] + (~same).sum()),

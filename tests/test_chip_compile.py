@@ -548,6 +548,60 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
             < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
 
 
+@pytest.mark.slow  # ~3 min here; the builder's by-hand rehearsal
+def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
+                                                            monkeypatch):
+    """The ``kimi_linear_48b_a3b`` configuration as the benchmark builds it
+    (the published layers 1-5: KDA with the dense SwiGLU, then KDA, KDA,
+    latent attention, KDA with 8 of 256 experts held beside a shared one, an
+    eighth of the vocabulary and an untied head: 602,433,408 float32
+    parameters under AdamW) on one packed row of 8,192 tokens, through the
+    TPU compiler: parameters, both moments and the routing state are donated
+    and updated in place; the chunked recurrence is ``jnp`` code (its
+    groups of chunks two ``while`` loops a layer and pass, one inside the
+    other, no kernel of its own); the grouped products are the ones a
+    chip runs (the Pallas kernels of ``grouped_pallas`` in the form at
+    ``moe.prefix_rows`` of the slots — 6,144 rows at a 1/32 share, 24 tiles
+    of 256 — and the compiler's own ``ragged-dot`` kernels in the overflow
+    form); attention at keys of 192 and values of 128 is ``jnp`` code on a
+    TPU too (the test says "tpu" in ``packed_rows``'s place and in
+    ``moe``'s, the attention rule still says plain, and no attention kernel
+    is called), and arguments, temporaries and code stay under 15.75 GiB.
+    PERF.md section 4 holds the figures."""
+    import json
+
+    from benchmark.configs.kimi_linear_48b_a3b import program
+    from tensorflowonspark_tpu.models import packed_rows
+    from tensorflowonspark_tpu.parallel import grouped_pallas, moe
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "kimi_linear_48b_a3b", "config.json")) as f:
+        published = json.load(f)
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    config = program.model_config(published)
+    assert not packed_rows.attention_runs_fused(config.seq_len,
+                                                config.qk_head_dim)
+    rows = moe.prefix_rows(8 * 8192, 8, 256)
+    assert rows == 6144 and grouped_pallas.fits(rows, 2304, 1024, "bfloat16")
+    step, state, batch = abstract_train_step(
+        "kimi_linear", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 602_433_408
+    assert state.collections["moe"]["bias"].shape == (4, 256)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"kimi_linear_48b_a3b, one described chip: {stats}")
+    text = compiled.as_text()
+    _assert_grouped_kernels(text, layers=4)
+    assert "/attention_forward/" not in text
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    assert (_device_bytes(compiled) + stats.generated_code_size_in_bytes
+            < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
+
+
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):
     """``TPU v5 lite`` is what the v5e reports (chip run, PR 21) and what
     the described topology reports; both peak tables must resolve it."""

@@ -310,8 +310,10 @@ def test_the_shared_pieces_exist_once():
     assert granite_hybrid.causal_conv is packed_rows.causal_conv
     assert lfm2_moe.causal_conv is packed_rows.causal_conv
     for piece in ("rope", "document_positions"):
-        assert getattr(mla_moe, piece) is getattr(packed_rows, piece)
         assert getattr(lfm2_moe, piece) is getattr(packed_rows, piece)
+    # GLM's rotation went with its latent attention into ``packed_rows``
+    assert mla_moe.document_positions is packed_rows.document_positions
+    assert mla_moe.packed_rows is packed_rows and not hasattr(mla_moe, "rope")
     assert mla_moe.COLLECTION == lfm2_moe.COLLECTION == "moe"
     glm = mla_moe.Config.tiny()
     assert mla_moe.collection_shapes(glm) == moe.routing_state_shapes(
