@@ -260,8 +260,11 @@ def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
     return y.reshape(t + pad, heads, p)[:t]
 
 
-def mamba_mixer(params, prefix: str, h, seg, config: Config):
-    """The Mamba-2 mixer on one row: ``h`` (T, D) -> (T, D)."""
+def mamba_mixer(params, prefix: str, h, seg, config: Config,
+                initializing: bool = False):
+    """The Mamba-2 mixer on one row: ``h`` (T, D) -> (T, D).
+    ``initializing``: the module is only learning its parameters from this
+    trace (``packed_rows.causal_conv`` reads it)."""
     import jax
     import jax.numpy as jnp
 
@@ -276,9 +279,10 @@ def mamba_mixer(params, prefix: str, h, seg, config: Config):
              out=f32)
     z, xbc = zx[:, :d_inner], zx[:, d_inner:]
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv(
-            xbc, params[prefix + "conv_w"], params[prefix + "conv_b"], seg
-        )).astype(dtype)
+        xbc = causal_conv(
+            xbc, params[prefix + "conv_w"], params[prefix + "conv_b"], seg,
+            silu=True, out=dtype, scopes=("ssm_mixer", "ssm_conv"),
+            initializing=initializing)
     x = xbc[:, :d_inner].reshape(t, heads, p)
     b = xbc[:, d_inner:d_inner + groups * n].reshape(t, groups, n)
     c = xbc[:, d_inner + groups * n:].reshape(t, groups, n)
@@ -317,8 +321,10 @@ def mlp(params, prefix: str, h):
                   params[prefix + "mlp_down"])
 
 
-def hidden_states(params, tokens, seg, config: Config):
-    """One row's final hidden states before the last norm: (T, D)."""
+def hidden_states(params, tokens, seg, config: Config,
+                  initializing: bool = False):
+    """One row's final hidden states before the last norm: (T, D).
+    ``initializing`` is the calling module's ``is_initializing()``."""
     import jax
     import jax.numpy as jnp
 
@@ -329,7 +335,8 @@ def hidden_states(params, tokens, seg, config: Config):
         h = _rms(x, lp[prefix + "norm1"], eps)
         if kind == "mamba":
             with jax.named_scope("ssm_mixer"):
-                x = x + res * mamba_mixer(lp, prefix, h, seg, config)
+                x = x + res * mamba_mixer(lp, prefix, h, seg, config,
+                                          initializing)
         else:
             with jax.named_scope("attention"):
                 x = x + res * attention(lp, prefix, h, seg, config)
@@ -355,13 +362,15 @@ def _logits(params, x, config: Config):
                out=jnp.float32) / config.logits_scaling
 
 
-def apply_tokens(params, tokens, segment_ids, config: Config):
+def apply_tokens(params, tokens, segment_ids, config: Config,
+                 initializing: bool = False):
     """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits."""
+    float32 logits.  ``initializing`` is the calling module's
+    ``is_initializing()`` (``packed_rows.causal_conv`` reads it)."""
     import jax
 
     def row(u, s):
-        x = hidden_states(params, u, s, config)
+        x = hidden_states(params, u, s, config, initializing)
         with jax.named_scope("lm_head"):
             return _logits(params, x, config)
 
@@ -434,7 +443,8 @@ def make_model(config: Config, mesh=None):
         def __call__(self, tokens, segment_ids):
             params = {name: self.param(name, inits[name], shape, jnp.float32)
                       for name, shape in shapes.items()}
-            return apply_tokens(params, tokens, segment_ids, config)
+            return apply_tokens(params, tokens, segment_ids, config,
+                                initializing=self.is_initializing())
 
     return GraniteHybrid()
 
@@ -469,7 +479,8 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters:
     ``packed_rows.row_counters`` (the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied) and, by
+    documents, and which executions of attention and of the mixers'
+    convolution its trace applied) and, by
     the same kind of rule (:func:`scan_runs_fused`), one step of the scan on
     the kernels or as ``jnp`` code, the other named with 0."""
     scans = "mamba" in config.layer_types
@@ -477,7 +488,9 @@ def batch_counters(batch, config: Config) -> dict:
         config.mamba_chunk_size, config.mamba_n_heads, config.mamba_d_head,
         config.mamba_n_groups, config.mamba_d_state)
     return {**row_counters(batch["segment_ids"], config.head_dim,
-                           "attention" in config.layer_types),
+                           "attention" in config.layer_types,
+                           conv=(config.conv_dim, config.mamba_d_conv)
+                           if scans else None),
             "ssm_scan_fused_steps_total": int(fused),
             "ssm_scan_plain_steps_total": int(scans and not fused)}
 

@@ -531,10 +531,13 @@ def _l2(x):
                              + L2_EPS)
 
 
-def kda_mixer(params, prefix: str, h, seg, config: Config):
+def kda_mixer(params, prefix: str, h, seg, config: Config,
+              initializing: bool = False):
     """Kimi Delta Attention on one row: ``h`` (T, D) -> (T, D).  The
     convolutions, the L2 norms, the decay, the step size and the heads' norm
-    are float32 between the products."""
+    are float32 between the products.  ``initializing``: the module is only
+    learning its parameters from this trace (``packed_rows.causal_conv``
+    reads it)."""
     import jax
     import jax.numpy as jnp
 
@@ -551,15 +554,18 @@ def kda_mixer(params, prefix: str, h, seg, config: Config):
         gate = mm("tr,re->te", mm("td,dr->tr", h, params[pre + "g_a"], dtype),
                   params[pre + "g_b"], dtype)
     with jax.named_scope("kda_conv"):
-        q, k, v = (jax.nn.silu(causal_conv(
-            x, params[pre + f"{name}_conv"], 0.0, seg)).reshape(t, heads, hd)
-                   for x, name in zip(qkv, ("q", "k", "v")))
+        # q and k go on to their L2 norms in float32; v to the products
+        q, k, v = (causal_conv(
+            x, params[pre + f"{name}_conv"], 0.0, seg, silu=True, out=out,
+            scopes=("kda_mixer", "kda_conv"), initializing=initializing
+        ).reshape(t, heads, hd)
+                   for x, name, out in zip(qkv, "qkv", (f32, f32, dtype)))
     with jax.named_scope("kda_scan"):
         g = -jnp.exp(params[pre + "A_log"])[:, None] * jax.nn.softplus(
             decay.reshape(t, heads, hd)
             + params[pre + "dt_bias"].reshape(heads, hd))
         o = kda_scan((_l2(q) * hd ** -0.5).astype(dtype),
-                     _l2(k).astype(dtype), v.astype(dtype), g, beta, seg,
+                     _l2(k).astype(dtype), v, g, beta, seg,
                      config.kda_chunk, dtype, ("kda_mixer", "kda_scan"))
     with jax.named_scope("kda_out"):
         o = rms(o, params[pre + "o_norm"], config.rms_norm_eps)
@@ -613,7 +619,7 @@ def _layer(mixer: str, ffn: str, prefix: str, config: Config,
     eps = config.rms_norm_eps
     if mixer == "kda":
         scope, mix = "kda_mixer", lambda hr, sr: kda_mixer(
-            lp, prefix, hr, sr, config)
+            lp, prefix, hr, sr, config, initializing)
     else:
         scope, mix = "attention", lambda hr, sr: attention(
             lp, prefix, hr, sr, config)
@@ -798,16 +804,19 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied;
-    ``moe.grouped_step_counters``: which execution of the routed experts'
-    grouped products; and the chunks :func:`kda_scan` took: chunks a row x
-    rows x heads x KDA layers)."""
+    documents, and which executions of attention and of the mixers'
+    convolutions its trace applied; ``moe.grouped_step_counters``: which
+    execution of the routed experts' grouped products; and the chunks
+    :func:`kda_scan` took: chunks a row x rows x heads x KDA layers)."""
     from tensorflowonspark_tpu.parallel import moe
 
     seg = np.asarray(batch["segment_ids"])
     mixers = [mixer for _, mixer, _ in layer_kinds(config)]
     return {**row_counters(seg, config.qk_head_dim,
-                           "full_attention" in mixers, config.v_head_dim),
+                           "full_attention" in mixers, config.v_head_dim,
+                           conv=(config.kda_width,
+                                 config.short_conv_kernel_size)
+                           if "kda" in mixers else None),
             **moe.grouped_step_counters(
                 seg.size, config.num_experts_per_token,
                 len(config.experts_held), config.num_experts,

@@ -202,20 +202,23 @@ def collection_shapes(config: Config) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def conv_mixer(params, prefix: str, h, seg):
+def conv_mixer(params, prefix: str, h, seg, initializing: bool = False):
     """The gated short convolution on one row: ``h`` (T, D) -> (T, D).  The
-    gates and the convolution are float32 between the two products."""
+    gates and the convolution are float32 between the two products
+    (``c * conv(b * z)``, inside ``packed_rows.causal_conv``, which also
+    reads ``initializing``: the module is only learning its parameters from
+    this trace)."""
     import jax
-    import jax.numpy as jnp
 
     dtype, d = h.dtype, h.shape[1]
     with jax.named_scope("conv_in_proj"):
         bcz = mm("td,de->te", h, params[prefix + "in_proj"], dtype)
     with jax.named_scope("short_conv"):
-        b, c, z = (bcz[:, i * d:(i + 1) * d].astype(jnp.float32)
-                   for i in range(3))
-        y = (c * causal_conv(b * z, params[prefix + "conv_w"], 0.0, seg)
-             ).astype(dtype)
+        b, c, z = (bcz[:, i * d:(i + 1) * d] for i in range(3))
+        y = causal_conv(b, params[prefix + "conv_w"], 0.0, seg, times=z,
+                        gate=c, out=dtype,
+                        scopes=("conv_mixer", "short_conv"),
+                        initializing=initializing)
     with jax.named_scope("conv_out_proj"):
         return mm("te,ed->td", y, params[prefix + "out_proj"], dtype)
 
@@ -257,7 +260,7 @@ def _layer(mixer: str, ffn: str, prefix: str, config: Config,
     eps = config.norm_eps
     if mixer == "conv":
         scope, mix = "conv_mixer", lambda hr, sr, pr: conv_mixer(
-            lp, prefix, hr, sr)
+            lp, prefix, hr, sr, initializing)
     else:
         scope, mix = "attention", lambda hr, sr, pr: attention(
             lp, prefix, hr, sr, pr, config)
@@ -437,14 +440,16 @@ def make_forward_fn(module, config: Config):
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied; and
-    ``moe.grouped_step_counters``: which execution of the routed experts'
-    grouped products)."""
+    documents, and which executions of attention and of the short
+    convolution its trace applied; and ``moe.grouped_step_counters``: which
+    execution of the routed experts' grouped products)."""
     from tensorflowonspark_tpu.parallel import moe
 
     seg = np.asarray(batch["segment_ids"])
     return {**row_counters(seg, config.head_dim,
-                           "full_attention" in config.layer_types),
+                           "full_attention" in config.layer_types,
+                           conv=(config.hidden_size, config.conv_L_cache)
+                           if "conv" in config.layer_types else None),
             **moe.grouped_step_counters(
                 seg.size, config.num_experts_per_tok,
                 len(config.experts_held), config.num_experts,

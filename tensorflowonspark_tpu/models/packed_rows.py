@@ -28,6 +28,17 @@ which is also the kernels' oracle.  :func:`attention_runs_fused` is the
 rule, and the models' steps count which applied
 (``attention_fused_steps_total`` / ``attention_plain_steps_total``).
 
+The convolution is one algorithm with two executions too
+(:func:`causal_conv`): on a TPU, where the channels are whole rows of 128
+lanes, the row of tokens whole tiles of the kernels' own and the module is
+not initialising (the published 8,192 x 4,352, x 4,096 and x 2,048 are;
+``Config.tiny()``'s are not), the Pallas kernels of ``conv_pallas`` read a
+tile of the row once, keep the ``K - 1`` rows before it and write once what
+the callers apply to the convolution (SiLU, a gate, the cast), forward and
+backward; anywhere else the ``K`` shifted sums in this file run, which are
+also the kernels' oracle.  :func:`conv_runs_fused` is the rule
+(``conv_fused_steps_total`` / ``conv_plain_steps_total``).
+
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
 are here too: host code, one copy for the four.
@@ -130,21 +141,66 @@ def rope(x, pos, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def causal_conv(xbc, w, b, seg):
-    """Depthwise causal convolution over a packed row: ``y_t = b + sum_j
+def conv_runs_fused(t: int, c: int, taps: int, b=0.0,
+                    initializing: bool = False) -> bool:
+    """How :func:`causal_conv` executes on a row of ``t`` tokens and ``c``
+    channels with ``taps`` taps and the bias ``b``: on the Pallas kernels of
+    ``conv_pallas`` (True) or as ``jnp`` code (False).  Decided from what
+    the code can observe: the backend is a TPU, the channels are whole rows
+    of 128 lanes, the row of tokens is whole tiles of the kernels' own size
+    and the taps fit their halo (``conv_pallas.fits``: the published 8,192 x
+    4,352 x 4, 8,192 x 4,096 x 4 and 8,192 x 2,048 x 3 do; ``Config.tiny()``'s
+    do not), the bias is a (C,) array or a Python number, and the module is
+    not initialising (``initializing``, flax's ``is_initializing()``: such a
+    trace only learns the parameters' shapes and pays for no kernel)."""
+    from tensorflowonspark_tpu.models import conv_pallas
+
+    return (_backend() == "tpu" and not initializing
+            and conv_pallas.fits(t, c, taps)
+            and (isinstance(b, (int, float)) or np.shape(b) == (c,)))
+
+
+def causal_conv(xbc, w, b, seg, *, times=None, gate=None, silu: bool = False,
+                out=None, scopes: tuple = (), initializing: bool = False):
+    """Depthwise causal convolution over a packed row: ``u_t = b + sum_j
     w[K-1-j] * x_{t-j}`` over the taps ``j < K`` whose token ``t-j`` is in
-    ``t``'s document.  ``xbc`` (T, C), ``w`` (K, C), ``b`` (C,) or a
-    number, ``seg`` (T,); float32 out."""
+    ``t``'s document, and what its callers apply straight to it: ``y =
+    gate * silu(u)``.  ``xbc`` (T, C), ``w`` (K, C), ``b`` (C,) or a number,
+    ``seg`` (T,).  ``x`` is ``xbc`` or, where ``times`` (T, C) is given,
+    their product; ``silu`` and ``gate`` (T, C) are each left out where not
+    given; everything is float32 inside and the result is cast once, to
+    ``out`` (float32 where not given).
+
+    One algorithm, two executions (:func:`conv_runs_fused`): on a TPU, at
+    shapes that fill its tiles, the kernels of ``conv_pallas`` read a tile
+    of the row once, keep the ``K - 1`` rows before it and write the result
+    once, forward and backward (the backward pass under the
+    ``jax.named_scope``s ``scopes``, the caller's: the forward pass runs
+    under the caller's own); anywhere else, and while a module initialises
+    (``initializing``), the ``jnp`` form below runs — ``K`` shifted sums
+    that JAX differentiates —, which is also the kernels' oracle."""
+    import jax
     import jax.numpy as jnp
 
     taps, t = w.shape[0], xbc.shape[0]
+    if conv_runs_fused(t, xbc.shape[1], taps, b, initializing):
+        from tensorflowonspark_tpu.models import conv_pallas
+
+        return conv_pallas.fused_conv(xbc, w, b, seg, times=times, gate=gate,
+                                      silu=silu, out=out, scopes=scopes)
     x32 = xbc.astype(jnp.float32)
+    if times is not None:
+        x32 = x32 * times.astype(jnp.float32)
     y = x32 * w[taps - 1] + b
     for j in range(1, min(taps, t)):
         back = jnp.pad(x32[:-j], ((j, 0), (0, 0)))
         same = jnp.pad(seg[:-j], (j, 0), constant_values=-1) == seg
         y = y + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - j]
-    return y
+    if silu:
+        y = jax.nn.silu(y)
+    if gate is not None:
+        y = gate.astype(jnp.float32) * y
+    return y.astype(out or jnp.float32)
 
 
 def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
@@ -383,7 +439,8 @@ def blocked_cross_entropy(x, logits_fn, targets, valid, want: int):
 
 
 def row_counters(segment_ids, head_dim: int, attends: bool = True,
-                 v_head_dim: int | None = None) -> dict:
+                 v_head_dim: int | None = None,
+                 conv: tuple | None = None) -> dict:
     """What one step of packed rows adds to the program's counters, whatever
     the model.  From its host batch's segment ids (B, T): tokens, tokens
     that bear a loss (the next token is the same document's) and documents
@@ -391,16 +448,25 @@ def row_counters(segment_ids, head_dim: int, attends: bool = True,
     (:func:`attention_runs_fused`; ``attends``: the model has an attention
     layer; ``v_head_dim``: its values' width where it is not ``head_dim``):
     one step of attention on the kernels or as ``jnp`` code, the other named
-    with 0 so that both are on the record."""
+    with 0 so that both are on the record.  For a model that calls
+    :func:`causal_conv` (``conv``: its (channels, taps); None: it has no
+    such layer and the pair is left out) likewise, by
+    :func:`conv_runs_fused`: ``conv_fused_steps_total`` /
+    ``conv_plain_steps_total``."""
     seg = np.asarray(segment_ids)
     same = seg[:, 1:] == seg[:, :-1]
     on_chip = attends and attention_runs_fused(seg.shape[1], head_dim,
                                                v_head_dim)
-    return {"lm_tokens_total": int(seg.size),
-            "lm_loss_tokens_total": int(same.sum()),
-            "lm_documents_total": int(seg.shape[0] + (~same).sum()),
-            "attention_fused_steps_total": int(on_chip),
-            "attention_plain_steps_total": int(attends and not on_chip)}
+    counts = {"lm_tokens_total": int(seg.size),
+              "lm_loss_tokens_total": int(same.sum()),
+              "lm_documents_total": int(seg.shape[0] + (~same).sum()),
+              "attention_fused_steps_total": int(on_chip),
+              "attention_plain_steps_total": int(attends and not on_chip)}
+    if conv is not None:
+        fused = conv_runs_fused(seg.shape[1], *conv)
+        counts.update(conv_fused_steps_total=int(fused),
+                      conv_plain_steps_total=int(not fused))
+    return counts
 
 
 def example_rows(vocab_size: int, batch_size: int, seed: int, t: int) -> dict:

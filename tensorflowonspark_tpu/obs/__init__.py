@@ -144,6 +144,10 @@ step — never per record, row or chunk):
   ``attention_fused_steps_total`` / ``attention_plain_steps_total``, one
   increment a step of a packed-row decoder, say the same of
   ``packed_rows.document_attention`` (``attention_runs_fused``);
+  ``conv_fused_steps_total`` / ``conv_plain_steps_total``, one increment a
+  step of ``granite_hybrid``, ``lfm2_moe`` and ``kimi_linear``, say
+  whether ``packed_rows.causal_conv`` ran on the Pallas kernels of
+  ``models/conv_pallas.py`` or as ``jnp`` code (``conv_runs_fused``);
   ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``,
   one increment a step of ``mla_moe`` and of ``lfm2_moe``, say whether the
   routed experts' grouped products, in the form a step takes when a

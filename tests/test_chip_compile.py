@@ -367,6 +367,103 @@ def test_grouped_kernels_compile_at_the_published_widths(topo, rows, k, n):
                 < 4 * (rows * (k + n) + groups * k * n) * 2)
 
 
+@pytest.mark.parametrize("c,taps,form", [
+    (4352, 4, "granite"),       # granite_h_micro_packed_8k: bias, SiLU
+    (4096, 4, "kimi"),          # kimi_linear_packed_8k: SiLU, to float32
+    (2048, 3, "lfm2"),          # lfm2_8b_a1b_packed_8k: two gates
+])
+def test_conv_kernels_compile_at_the_published_widths(topo, c, taps, form):
+    """The Pallas kernels of ``packed_rows.causal_conv``
+    (``models/conv_pallas.py``) through the TPU's compiler at the three
+    shapes the benchmark runs — a row of 8,192 tokens in bfloat16, the taps
+    and the bias float32 — forward and gradient: this refuses what interpret
+    mode cannot (a rotation or a concatenation off the tiling, a block the
+    fast memory cannot hold).  Compiled, the forward pass is one
+    ``conv_forward`` call under the caller's scope and the gradient one more
+    and a ``conv_backward`` under the scopes the call names (the backward
+    pass opens them itself: ``benchmark/device_scopes.py`` finds the
+    convolution by them), and no float32 copy of the row is held beside
+    the operands: the ``jnp`` form's temporaries are five of them."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import conv_pallas
+
+    t, bf, f32 = 8192, jnp.bfloat16, jnp.float32
+    assert conv_pallas.fits(t, c, taps)
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((t, c), bf), ((taps, c), f32), ((c,), f32), ((t,), jnp.int32),
+        ((t, c), bf), ((t, c), bf))]
+    scopes = ("the_mixer", "the_conv")
+
+    def conv(x, w, b, seg, z, g):
+        how = {"granite": dict(silu=True, out=bf),
+               "kimi": dict(silu=True, out=f32),
+               "lfm2": dict(times=z, gate=g, out=bf)}[form]
+        with packed_rows_under(scopes):
+            return conv_pallas.fused_conv(
+                x, w, b if form == "granite" else 0.0, seg, scopes=scopes,
+                **how)
+
+    def loss(x, w, b, seg, z, g):
+        return jnp.sum(conv(x, w, b, seg, z, g).astype(f32) ** 2)
+
+    from tensorflowonspark_tpu.models.packed_rows import (
+        under as packed_rows_under)
+
+    for fn, kernels in ((conv, ["conv_forward"]),
+                        (jax.grad(loss, (0, 1, 2, 4, 5)),
+                         ["conv_backward", "conv_forward"])):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        names = _pallas_calls(compiled.as_text())
+        assert sorted(name.split("/")[-2] for name in names) == kernels, names
+        assert all(re.search(rf"\b{scope}\b", name)
+                   for name in names for scope in scopes), names
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * t * c * 4
+
+
+def test_the_jnp_convolution_moves_ten_times_its_operands(topo):
+    """Why ``causal_conv`` has kernels, read from the compiler with no chip
+    (``pytest tests/test_chip_compile.py -k moves_ten_times -s`` prints
+    the readings; PERF.md section 7): compiled alone for the described chip
+    at granite's shape, the ``jnp`` form — K shifted float32 sums — moves
+    more than ten times the bytes of ``x`` in and ``y`` out forward (1.71 GB
+    against 0.143) and holds more than four float32 copies of the row with
+    its gradient (a shift of 1 to 3 rows, along sublanes, is not fused into
+    one pass); the two attempts at one ``jnp`` expression of PR 44 read 0.43
+    GB forward and 2.71 with the gradient (one padded array sliced K times)
+    and 2.93 / 7.43 (a grouped ``conv_general_dilated`` and a correction)."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import packed_rows
+
+    t, c, bf, f32 = 8192, 4352, jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((t, c), bf), ((4, c), f32), ((c,), f32), ((t,), jnp.int32))]
+
+    def conv(x, w, b, seg):     # the backend is the CPU here: the jnp form
+        return packed_rows.causal_conv(x, w, b, seg, silu=True, out=bf)
+
+    def loss(x, w, b, seg):
+        return jnp.sum(conv(x, w, b, seg).astype(f32) ** 2)
+
+    forward = jax.jit(conv).lower(*shapes).compile()
+    both = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        *shapes).compile()
+    moved = [m.cost_analysis()["bytes accessed"] for m in (forward, both)]
+    held = both.memory_analysis().temp_size_in_bytes
+    print(f"jnp causal_conv at {t} x {c}: {moved[0] / 1e9:.3f} GB forward, "
+          f"{moved[1] / 1e9:.3f} GB value and gradient, "
+          f"{held / 1e6:.1f} MB of temporaries")
+    assert moved[0] > 10 * (2 * t * c * 2)
+    assert held > 4 * t * c * 4
+
+
 @pytest.mark.slow  # ~60 s here; the builder's by-hand rehearsal
 def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     """The ``granite_4_0_h_micro`` configuration as the benchmark builds it
@@ -376,14 +473,18 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     donated and updated in place, and arguments plus temporaries stay under
     the chip's memory with room for the staged batches.  The scan is the
     one a chip runs (the Pallas kernels: here the backend is the CPU, so
-    the test says "tpu" in the model's place), three kernels a layer and
-    two more in its backward pass.  PERF.md section 4 holds the figures."""
+    the test says "tpu" in the model's place and in ``packed_rows``'s),
+    three kernels a layer and two more in its backward pass; so is the
+    mixers' convolution (``conv_pallas``: a ``conv_forward`` a layer, one
+    more in its recomputation, a ``conv_backward``).  PERF.md section 4
+    holds the figures."""
     import json
 
     from benchmark.configs.granite_4_0_h_micro import program
-    from tensorflowonspark_tpu.models import granite_hybrid
+    from tensorflowonspark_tpu.models import granite_hybrid, packed_rows
 
     monkeypatch.setattr(granite_hybrid, "_backend", lambda: "tpu")
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs",
                            "granite_4_0_h_micro", "config.json")) as f:
@@ -397,9 +498,11 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     stats = compiled.memory_analysis()
     print(f"granite_4_0_h_micro, one described chip: {stats}")
     # nine mixers: states and output forward, the same again recomputed,
-    # then the states kernel and the backward kernel
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"'
-                                    ) == 9 * 6
+    # then the states kernel and the backward kernel; the convolution
+    # forward, recomputed and backward, each under the mixer's scopes
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9 * 9
+    _assert_conv_kernels(text, 9, "ssm_mixer/ssm_conv")
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
     assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
@@ -414,6 +517,20 @@ def _pallas_calls(text: str) -> list:
 
     return re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+
+
+def _assert_conv_kernels(text: str, convs: int, scopes: str) -> None:
+    """``text``: a compiled step of ``convs`` calls of
+    ``packed_rows.causal_conv``: each is a ``conv_forward`` kernel in the
+    forward pass, one more in its layer's recomputation and a
+    ``conv_backward``, all under the caller's ``scopes``."""
+    import re
+
+    ours = [n for n in _pallas_calls(text) if "/conv_" in n]
+    for kernel, calls in (("conv_forward", 2), ("conv_backward", 1)):
+        assert sum(f"/{kernel}/" in n for n in ours) == convs * calls, ours
+    assert all(re.search(rf"\b{scope}\b", n)
+               for n in ours for scope in scopes.split("/")), ours
 
 
 def _assert_grouped_kernels(text: str, layers: int) -> None:
@@ -538,6 +655,7 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     print(f"lfm2_8b_a1b, one described chip: {stats}")
     text = compiled.as_text()
     _assert_grouped_kernels(text, layers=5)
+    _assert_conv_kernels(text, 4, "conv_mixer/short_conv")
     assert "/attention_forward/" not in text
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
@@ -594,6 +712,7 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     print(f"kimi_linear_48b_a3b, one described chip: {stats}")
     text = compiled.as_text()
     _assert_grouped_kernels(text, layers=4)
+    _assert_conv_kernels(text, 4 * 3, "kda_mixer/kda_conv")
     assert "/attention_forward/" not in text
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
