@@ -44,11 +44,19 @@ numbers them::
 
 The recurrence is computed in chunks of ``Config.kda_chunk`` tokens
 (:func:`kda_scan`), every product a matrix product, equal to the recurrence
-up to rounding; its backward pass makes a group of chunks again from the
-state that entered it and differentiates that (``jax.vjp`` of the chunked
-form, a group at a time), and a layer's recomputation keeps the scan's
-outputs and group states by name, so the recurrence runs forward once a
-step.
+up to rounding.  One algorithm, two executions
+(:func:`kda_scan_runs_fused`): on a TPU, at shapes that fill its tiles (the
+published 32 heads of 128 x 128 at chunks of 64), the two Pallas kernels of
+``kda_pallas`` — a (block of heads, pair of chunks) cell reads its slice of
+the operands once, keeps every intermediate in VMEM with the state carried
+across the chunks in scratch, and writes the outputs, a backward kernel
+beside the forward one; everywhere else ``jnp`` code whose backward pass
+makes a group of chunks again from the state that entered it and
+differentiates that (``jax.vjp`` of the chunked form, a group at a time),
+which is the kernels' oracle in the tests.  A step counts which ran
+(``kda_scan_fused_steps_total`` / ``kda_scan_plain_steps_total``).  In both
+a layer's recomputation keeps by name what the scan holds between its
+passes (``SAVED``), so the recurrence runs forward once a step.
 
 ``Config.experts_held`` says which of the ``num_experts`` this chip holds
 (all of them unless told otherwise): the router stays as wide as published,
@@ -75,7 +83,8 @@ on every backend (``packed_rows.attention_runs_fused``) and a step says so
 norm and the mixer whole) > ``kda_project`` (q, k, v, the decay's, beta's and
 the gate's projections), ``kda_conv`` (the three convolutions and SiLU),
 ``kda_scan`` (the L2 norms, the decay, the chunked recurrence and its
-hand-over between chunks), ``kda_out`` (the heads' norm, the gate, ``W_o``);
+hand-over between chunks: the kernels' calls, forward and backward, carry
+it and ``kda_mixer`` in their ``op_name``), ``kda_out`` (the heads' norm, the gate, ``W_o``);
 ``attention`` > ``mla_project``; ``mlp``; ``shared_expert``; ``moe_router``,
 ``moe_dispatch``, ``moe_experts``, ``moe_combine`` (``routed_experts``');
 ``lm_head``.
@@ -118,15 +127,17 @@ L2_EPS = 1e-6
 #: sub-block (``exp(88.7)`` is the last float32)
 EXPONENT_CAP = 80.0
 
-#: chunks :func:`kda_scan` takes at a time (256 tokens of the published 64): a
-#: group's working set is what its backward pass holds at once, and on a v5e
-#: the backward pass of a layer's scan takes 18.4 ms at 2 and at 4 chunks a
-#: group, 22.0 at 8 and 29.6 at 16 (PERF.md section 6, PR 43)
+#: chunks the ``jnp`` form of :func:`kda_scan` takes at a time (256 tokens of
+#: the published 64): a group's working set is what its backward pass holds
+#: at once, and on a v5e the backward pass of a layer's scan takes 18.4 ms at
+#: 2 and at 4 chunks a group, 22.0 at 8 and 29.6 at 16 (PERF.md section 6,
+#: PR 43)
 SCAN_GROUP = 4
 
 #: what :func:`kda_scan` names for a caller's ``jax.checkpoint`` to keep: its
-#: outputs and the state entering each group of chunks
-SAVED = ("kda_scan_out", "kda_scan_states")
+#: outputs, the state entering each group of chunks (the kernels: each pair)
+#: and, of the kernels alone, the chunks' inverses
+SAVED = ("kda_scan_out", "kda_scan_states", "kda_scan_inverse")
 
 #: the bounds ``A_log`` and ``dt_bias`` are drawn between (the public
 #: implementation's, which are Mamba-2's)
@@ -445,6 +456,21 @@ def _grouped_rule(chunk: int, dtype, scopes: tuple):
     return rule
 
 
+def kda_scan_runs_fused(chunk: int, heads: int, dk: int, dv: int) -> bool:
+    """How :func:`kda_scan` executes at chunks of ``chunk`` tokens and
+    ``heads`` heads with keys of ``dk`` and values of ``dv``: on the Pallas
+    kernels of ``kda_pallas`` (True) or as ``jnp`` code (False).  Decided
+    from what the code can observe: the backend is a TPU, keys and values
+    are whole rows of 128 lanes, a chunk is 64 tokens (two fill a cell's 128
+    rows, a quarter sub-block is a whole tile) and the heads are whole
+    blocks (``kda_pallas.fits``: the published 32 x 128 x 128 at chunks of
+    64 do; ``Config.tiny()``'s do not)."""
+    from tensorflowonspark_tpu.models import kda_pallas, packed_rows
+
+    return (packed_rows._backend() == "tpu"
+            and kda_pallas.fits(chunk, heads, dk, dv))
+
+
 def kda_scan(q, k, v, g, beta, seg, chunk: int, dtype, scopes: tuple = ()):
     """The gated delta rule of one packed row in chunks: ``S' = Diag(exp
     g_t) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``, ``o_t =
@@ -475,41 +501,55 @@ def kda_scan(q, k, v, g, beta, seg, chunk: int, dtype, scopes: tuple = ()):
     all of it) forgets that much and no more there, and nothing overflows at
     any decay.  Never ``exp(G) exp(-G)`` over a chunk: ``G`` reaches -100.
 
-    The solve (:func:`unit_lower_inverse`, then one product) gives ``U = U_0
-    - W S_0`` (both at once), so the state crosses a chunk in two products;
-    ``G`` is a product with a triangle of ones.  The row is taken ``SCAN_GROUP``
-    chunks at a time (a ``lax.scan`` whose carry is the state, float32): a
-    group's pairwise terms and solves are made for all its chunks at once,
+    The solve (the inverse, then one product) gives ``U = U_0 - W S_0``
+    (both at once), so the state crosses a chunk in two products; ``G`` is a
+    product with a triangle of ones.  Products take operands in ``dtype``
+    and accumulate in float32, the running sums, the inverse and the
+    state's carry are float32 at the highest precision.
+
+    One algorithm, two executions (:func:`kda_scan_runs_fused`).  On a TPU
+    at shapes that fill its tiles, ``kda_pallas.fused_scan``: a forward and
+    a backward kernel over (block of heads, pair of chunks) cells, the
+    chunks in turn with the state in VMEM scratch, nothing of a chunk's own
+    in HBM; between the passes it holds the state entering each pair of
+    chunks and the chunks' inverses, in ``dtype``.  Elsewhere the ``jnp``
+    form below: the row is taken ``SCAN_GROUP`` chunks at a time (a
+    ``lax.scan`` whose carry is the state): a group's pairwise terms and
+    solves (:func:`unit_lower_inverse`) are made for all its chunks at once,
     the state crosses its chunks in an inner ``lax.scan``, the outputs are
-    made from the states that leaves.  The backward pass is this function's
-    own (:func:`_grouped_rule`): it makes a group again from the state that
-    entered it and differentiates that, last group first, under the
+    made from the states that leaves; its backward pass
+    (:func:`_grouped_rule`) makes a group again from the state that entered
+    it and differentiates that, last group first, so what is held between
+    the two passes is a state a group.  Either backward pass runs under the
     ``jax.named_scope``s ``scopes`` (the caller's: the forward pass runs
-    under the caller's own), so what is held between the two passes is a
-    state a group and nothing of a chunk's own.  Both are named
-    (``SAVED``): a caller that recomputes its layer keeps them by name and
-    does not run the recurrence a second time for them.  Products take
-    operands in ``dtype`` and accumulate in float32, the running sums and
-    the inverse are float32 at the highest precision.  ``T`` need not be a
+    under the caller's own), and what either holds between its passes is
+    named (``SAVED``): a caller that recomputes its layer keeps it by name
+    and does not run the recurrence a second time.  ``T`` need not be a
     multiple of ``chunk``: the row is padded with a document of its own.
     Returns (T, H, V) in ``dtype``."""
     import jax.numpy as jnp
 
+    from tensorflowonspark_tpu.models import kda_pallas
+
     t, heads, _ = q.shape
-    pad = (-t) % chunk
+    fused = kda_scan_runs_fused(chunk, heads, q.shape[-1], v.shape[-1])
+    pad = (-t) % (kda_pallas.CELL if fused else chunk)
     if pad:
         q, k, v, g, beta = (
             jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
             for a in (q, k, v, g, beta))
         seg = jnp.pad(seg, (0, pad), constant_values=-1)
     nc = (t + pad) // chunk
-    size = block(nc, SCAN_GROUP)
 
     # documents by their index in the row (from 1), chunk by chunk
     first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
     doc = jnp.cumsum(first.astype(jnp.int32)).reshape(nc, chunk)
     last = doc[:, -1]
     before = jnp.concatenate([jnp.zeros((1,), jnp.int32), last[:-1]])
+    if fused:
+        return kda_pallas.fused_scan(q, k, v, g, beta, doc, last, before,
+                                     dtype, SAVED, scopes)[:t]
+    size = block(nc, SCAN_GROUP)
 
     def groups(a):          # (nc, ...) -> (groups, size, ...)
         return a.reshape((nc // size, size) + a.shape[1:])
@@ -806,17 +846,23 @@ def batch_counters(batch, config: Config) -> dict:
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
     documents, and which executions of attention and of the mixers'
     convolutions its trace applied; ``moe.grouped_step_counters``: which
-    execution of the routed experts' grouped products; and the chunks
-    :func:`kda_scan` took: chunks a row x rows x heads x KDA layers)."""
+    execution of the routed experts' grouped products; the chunks
+    :func:`kda_scan` took: chunks a row x rows x heads x KDA layers; and, by
+    the same kind of rule (:func:`kda_scan_runs_fused`), one step of the
+    recurrence on the kernels or as ``jnp`` code, the other named with 0)."""
     from tensorflowonspark_tpu.parallel import moe
 
     seg = np.asarray(batch["segment_ids"])
     mixers = [mixer for _, mixer, _ in layer_kinds(config)]
+    scans = "kda" in mixers
+    fused = scans and kda_scan_runs_fused(
+        config.kda_chunk, config.kda_num_heads, config.kda_head_dim,
+        config.kda_head_dim)
     return {**row_counters(seg, config.qk_head_dim,
                            "full_attention" in mixers, config.v_head_dim,
                            conv=(config.kda_width,
                                  config.short_conv_kernel_size)
-                           if "kda" in mixers else None),
+                           if scans else None),
             **moe.grouped_step_counters(
                 seg.size, config.num_experts_per_token,
                 len(config.experts_held), config.num_experts,
@@ -824,7 +870,9 @@ def batch_counters(batch, config: Config) -> dict:
                 config.dtype),
             "kda_chunks_total": int(
                 seg.shape[0] * -(-seg.shape[1] // config.kda_chunk)
-                * config.kda_num_heads * mixers.count("kda"))}
+                * config.kda_num_heads * mixers.count("kda")),
+            "kda_scan_fused_steps_total": int(fused),
+            "kda_scan_plain_steps_total": int(scans and not fused)}
 
 
 def device_counters(collections, config: Config) -> dict:

@@ -148,6 +148,10 @@ step — never per record, row or chunk):
   step of ``granite_hybrid``, ``lfm2_moe`` and ``kimi_linear``, say
   whether ``packed_rows.causal_conv`` ran on the Pallas kernels of
   ``models/conv_pallas.py`` or as ``jnp`` code (``conv_runs_fused``);
+  ``kda_scan_fused_steps_total`` / ``kda_scan_plain_steps_total``, one
+  increment a ``kimi_linear`` step, say whether its chunked delta rule ran
+  on the Pallas kernels of ``models/kda_pallas.py`` or as ``jnp`` code
+  (``models/kimi_linear.py::kda_scan_runs_fused``);
   ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``,
   one increment a step of ``mla_moe`` and of ``lfm2_moe``, say whether the
   routed experts' grouped products, in the form a step takes when a

@@ -425,6 +425,65 @@ def test_conv_kernels_compile_at_the_published_widths(topo, c, taps, form):
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * t * c * 4
 
 
+def test_kda_kernels_compile_at_the_published_widths(topo, monkeypatch):
+    """The Pallas kernels of the chunked delta rule (``models/kda_pallas.py``)
+    through the TPU's compiler at the shapes the benchmark runs — a KDA
+    layer's row of 8,192 tokens, 32 heads of 128 x 128, chunks of 64,
+    bfloat16 operands, the decay and ``beta`` float32 — forward and
+    gradient, through ``kimi_linear.kda_scan`` with a TPU in the backend's
+    place: this refuses what interpret mode cannot (a slice off the tiling,
+    a cell the fast memory cannot hold).  Compiled, the forward pass is one
+    ``kda_forward`` call under the caller's scopes and the gradient one more
+    and a ``kda_backward`` under the scopes the call names (the backward
+    pass opens them itself: ``benchmark/kda_scopes.py`` finds the scan by
+    them), no loop is left, and nothing of a group's size (4 x 32 x 64 x 128
+    float32, of which the ``jnp`` form holds some fifty) is held: the
+    temporaries are the states entering the 64 cells and the chunks'
+    inverses, in bfloat16 (and, of the gradient here, the outputs and
+    their cotangent)."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import (kda_pallas, kimi_linear,
+                                              packed_rows)
+
+    t, heads, hd, bf, f32 = 8192, 32, 128, jnp.bfloat16, jnp.float32
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    assert kimi_linear.kda_scan_runs_fused(64, heads, hd, hd)
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((t, heads * hd), bf), ((t, heads * hd), bf), ((t, heads * hd), bf),
+        ((t, heads * hd), f32), ((t, heads), f32), ((t,), jnp.int32))]
+    scopes = ("kda_mixer", "kda_scan")
+
+    def scan(q, k, v, g, beta, seg):
+        with packed_rows.under(scopes):
+            return kimi_linear.kda_scan(
+                *(a.reshape(t, heads, hd) for a in (q, k, v, g)), beta, seg,
+                64, bf, scopes)
+
+    def loss(*a):
+        return jnp.sum(scan(*a).astype(f32) ** 2)
+
+    held = (t // kda_pallas.CELL * heads * hd * hd      # the states
+            + t // 2 * heads * hd) * 2                  # the inverses
+    for fn, kernels in ((scan, ["kda_forward"]),
+                        (jax.grad(loss, (0, 1, 2, 3, 4)),
+                         ["kda_backward", "kda_forward"])):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        text = compiled.as_text()
+        names = _pallas_calls(text)
+        assert sorted(name.split("/")[-2] for name in names) == kernels, names
+        assert all(re.search(rf"\b{scope}\b", name)
+                   for name in names for scope in scopes), names
+        assert " while(" not in text
+        assert "f32[4,32,64,128]" not in text
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < held + 2 * t * heads * hd * 2 + 2 ** 22)
+
+
 def test_the_jnp_convolution_moves_ten_times_its_operands(topo):
     """Why ``causal_conv`` has kernels, read from the compiler with no chip
     (``pytest tests/test_chip_compile.py -k moves_ten_times -s`` prints
@@ -531,6 +590,21 @@ def _assert_conv_kernels(text: str, convs: int, scopes: str) -> None:
         assert sum(f"/{kernel}/" in n for n in ours) == convs * calls, ours
     assert all(re.search(rf"\b{scope}\b", n)
                for n in ours for scope in scopes.split("/")), ours
+
+
+def _assert_kda_kernels(text: str, layers: int) -> None:
+    """``text``: a compiled step of ``layers`` KDA layers: each is one
+    ``kda_forward`` kernel — one, not two: the layer's recomputation keeps
+    what the scan names and does not run the recurrence again — and one
+    ``kda_backward``, all under ``kda_mixer`` and ``kda_scan``."""
+    import re
+
+    ours = [n for n in _pallas_calls(text)
+            if "/kda_forward/" in n or "/kda_backward/" in n]
+    for kernel in ("kda_forward", "kda_backward"):
+        assert sum(f"/{kernel}/" in n for n in ours) == layers, ours
+    assert all(re.search(rf"\b{scope}\b", n)
+               for n in ours for scope in ("kda_mixer", "kda_scan")), ours
 
 
 def _assert_grouped_kernels(text: str, layers: int) -> None:
@@ -675,9 +749,9 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     eighth of the vocabulary and an untied head: 602,433,408 float32
     parameters under AdamW) on one packed row of 8,192 tokens, through the
     TPU compiler: parameters, both moments and the routing state are donated
-    and updated in place; the chunked recurrence is ``jnp`` code (its
-    groups of chunks two ``while`` loops a layer and pass, one inside the
-    other, no kernel of its own); the grouped products are the ones a
+    and updated in place; the chunked recurrence runs on the kernels of
+    ``kda_pallas`` (a forward and a backward call a KDA layer: the layer's
+    recomputation keeps what the scan names); the grouped products are the ones a
     chip runs (the Pallas kernels of ``grouped_pallas`` in the form at
     ``moe.prefix_rows`` of the slots — 6,144 rows at a 1/32 share, 24 tiles
     of 256 — and the compiler's own ``ragged-dot`` kernels in the overflow
@@ -713,6 +787,7 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     text = compiled.as_text()
     _assert_grouped_kernels(text, layers=4)
     _assert_conv_kernels(text, 4 * 3, "kda_mixer/kda_conv")
+    _assert_kda_kernels(text, layers=4)
     assert "/attention_forward/" not in text
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place

@@ -25,10 +25,12 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.configs.kimi_linear_48b_a3b import program, reference
 from tensorflowonspark_tpu import obs
-from tensorflowonspark_tpu.models import kimi_linear, mla_moe, packed_rows
+from tensorflowonspark_tpu.models import (kda_pallas, kimi_linear, mla_moe,
+                                          packed_rows)
 from tensorflowonspark_tpu.parallel import moe
 
 BIG_SEED = 2 ** 31 + 4321           # the driver's seeds pass 32 signed bits
@@ -145,6 +147,11 @@ def _scan_inputs(t, heads, dk, dv, decay, seed):
     return [jnp.asarray(a, jnp.float32) for a in arrays]
 
 
+def _segments(t, cuts):
+    return jnp.asarray(np.searchsorted(np.asarray(cuts, int), np.arange(t),
+                                       side="right"), jnp.int32)
+
+
 @pytest.mark.parametrize("t,chunk,cuts,decay", [
     (128, 64, (), 0.1),                 # one document over two chunks
     (128, 64, (37, 64, 100), 0.1),      # ends inside a chunk, on an edge
@@ -163,8 +170,7 @@ def test_kda_chunked_scan_is_the_recurrence_values_and_gradients(
     -100 inside a 64-token chunk: ``exp(-G)`` is no float32 there (the last
     is ``exp(88.7)``), the differences the program takes are."""
     q, k, v, g, beta = args = _scan_inputs(t, 2, 8, 6, decay, seed=t + chunk)
-    seg = jnp.asarray(np.searchsorted(np.asarray(cuts, int), np.arange(t),
-                                      side="right"), jnp.int32)
+    seg = _segments(t, cuts)
     if decay > 2:
         assert float(np.cumsum(np.asarray(g)[:64], 0).min()) < -100
     w = jnp.asarray(np.random.default_rng(1).standard_normal((t, 2, 6)),
@@ -184,19 +190,161 @@ def test_kda_chunked_scan_is_the_recurrence_values_and_gradients(
         _close(a, b)
 
 
-def test_kda_scan_stays_finite_at_any_decay():
+@pytest.mark.parametrize("fused", [False, True])
+def test_kda_scan_stays_finite_at_any_decay(fused, monkeypatch):
     """Past ``EXPONENT_CAP`` inside one sub-block (15 tokens at -6 and
     more) the program forgets what the recurrence would still remember by
     ``exp(-80)``: the outputs stay finite and a token's own write is
-    exact."""
-    q, k, v, g, beta = _scan_inputs(64, 2, 8, 6, 12.0, seed=3)
+    exact, as ``jnp`` code and on the kernels (the interpreter here)."""
+    heads, dk, dv = (4, 128, 128) if fused else (2, 8, 6)
+    q, k, v, g, beta = _scan_inputs(64, heads, dk, dv, 12.0, seed=3)
     seg = jnp.zeros((64,), jnp.int32)
-    out = kimi_linear.kda_scan(q, k, v, g, beta, seg, 64, jnp.float32)
+    if fused:
+        monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    assert kimi_linear.kda_scan_runs_fused(64, heads, dk, dv) == fused
+    with pltpu.force_tpu_interpret_mode():
+        out = kimi_linear.kda_scan(q, k, v, g, beta, seg, 64, jnp.float32)
     assert bool(jnp.isfinite(out).all())
     first = beta[0, :, None] * v[0] * jnp.sum(k[0] * q[0], -1)[:, None]
     _close(out[0], first, tol=1e-5)
     assert kimi_linear.sub_block(64) == 16 and kimi_linear.sub_block(8) == 2
     assert kimi_linear.sub_block(6) == 6
+    assert kda_pallas.SUB == 16
+
+
+# ---------------------------------------------------------------------------
+# the recurrence on the Pallas kernels (interpret mode), the rule that picks
+# them
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("t,heads,cuts,decay", [
+    (128, 4, (37, 64, 100), 0.1),   # starts inside a chunk, on an edge
+    (128, 4, (37, 64, 100), 2.5),   # the same at g of -1.25 to -2.5
+    (192, 4, (5, 190, 191), 2.0),   # a chunk whole inside a document, a
+                                    # one-token document, an odd chunk
+    (100, 8, (64, 65), 0.5),        # a row that is no whole chunks, a
+                                    # one-token document on a chunk's first
+                                    # token, two blocks of heads
+])
+def test_kda_kernels_are_the_recurrence_values_and_gradients(
+        t, heads, cuts, decay, monkeypatch):
+    """``kimi_linear.kda_scan`` on the kernels of ``kda_pallas``, run in
+    Pallas's interpreter, at keys and values of 128 in float32 against the
+    reference's token-by-token ``delta_rule``: the outputs and the gradients
+    of all five operands, to the tolerance the ``jnp`` form is held to."""
+    args = _scan_inputs(t, heads, 128, 128, decay, seed=t + heads)
+    seg = _segments(t, cuts)
+    w = jnp.asarray(np.random.default_rng(1).standard_normal((t, heads, 128)),
+                    jnp.float32)
+
+    def mine(*a):
+        return kimi_linear.kda_scan(*a, seg, 64, jnp.float32, ("a", "b"))
+
+    def theirs(*a):
+        return reference.delta_rule(*a, seg)
+
+    (_, want_out), want = _value_and_gradients(theirs, w, 5)(*args)
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        fn = _value_and_gradients(mine, w, 5)
+        assert "kda_backward" in str(jax.make_jaxpr(fn)(*args))
+        (_, out), got = fn(*args)
+    _close(out, want_out)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b).max()) > 0
+        _close(a, b)
+
+
+def test_kda_kernels_follow_the_jnp_form_in_bfloat16(monkeypatch):
+    """With operands in bfloat16 the two executions round at the same
+    places but for the state between chunks (the kernels hold the entering
+    state and the inverse in bfloat16 between their passes, the ``jnp`` form
+    makes them again): values and gradients agree to bfloat16's rounding,
+    far inside what separates either from a forgotten term."""
+    t, heads = 256, 4
+    q, k, v, g, beta = _scan_inputs(t, heads, 128, 128, 0.3, seed=11)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    seg = _segments(t, (50, 128, 131))
+    w = jnp.asarray(np.random.default_rng(2).standard_normal((t, heads, 128)),
+                    jnp.float32)
+
+    def mine(*a):
+        return kimi_linear.kda_scan(*a, seg, 64, jnp.bfloat16).astype(
+            jnp.float32)
+
+    (_, want_out), want = _value_and_gradients(mine, w, 5)(q, k, v, g, beta)
+    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        (_, out), got = _value_and_gradients(mine, w, 5)(q, k, v, g, beta)
+    _close(out, want_out, tol=2e-2)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol=3e-2)
+
+
+@pytest.mark.parametrize("backend,published,fused", [
+    ("cpu", False, 0), ("cpu", True, 0), ("tpu", False, 0), ("tpu", True, 1)])
+def test_one_rule_says_which_recurrence_runs_and_which_is_counted(
+        backend, published, fused, monkeypatch):
+    """``kda_scan_runs_fused`` reads the backend and the shapes: the
+    published 32 heads of 128 x 128 at chunks of 64 run the kernels on a
+    TPU, ``Config.tiny()``'s run ``jnp`` code everywhere; and
+    ``batch_counters`` names ``kda_scan_fused_steps_total`` and
+    ``kda_scan_plain_steps_total``, one of them 1 and the other 0, by that
+    rule."""
+    import json
+    import os
+
+    config = kimi_linear.Config.tiny()
+    if published:
+        with open(os.path.join(os.path.dirname(program.__file__),
+                               "config.json")) as f:
+            config = program.model_config(json.load(f))
+        assert (config.kda_num_heads, config.kda_head_dim,
+                config.kda_chunk) == (32, 128, 64)
+    monkeypatch.setattr(packed_rows, "_backend", lambda: backend)
+    assert kimi_linear.kda_scan_runs_fused(
+        config.kda_chunk, config.kda_num_heads, config.kda_head_dim,
+        config.kda_head_dim) == bool(fused)
+    counts = kimi_linear.batch_counters(
+        {"segment_ids": np.zeros((1, config.seq_len), np.int32)}, config)
+    assert (counts["kda_scan_fused_steps_total"],
+            counts["kda_scan_plain_steps_total"]) == (fused, 1 - fused)
+    assert counts["kda_chunks_total"] == (
+        config.seq_len // config.kda_chunk * config.kda_num_heads
+        * len([i for i in config.kda_layers
+               if i <= config.num_hidden_layers]))
+    assert not kda_pallas.fits(64, 30, 128, 128)        # no whole blocks
+    assert not kda_pallas.fits(32, 32, 128, 128)        # no pair a cell
+    assert not kda_pallas.fits(64, 32, 128, 64)         # values of half a row
+    for text in (obs.__doc__, kimi_linear.__doc__):
+        assert "kda_scan_fused_steps_total" in text
+        assert "kda_scan_plain_steps_total" in text
+        assert "kda_scan_runs_fused" in text
+
+
+def test_a_step_counts_the_execution_of_its_recurrence(tiny):
+    """One ``Trainer.step``: exactly one of ``kda_scan_fused_steps_total``
+    and ``kda_scan_plain_steps_total`` goes up by one, by the rule
+    ``kda_scan`` applied when the step was traced (here the CPU's: the
+    ``jnp`` form); the other is on the record with what it had."""
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config = tiny[0]
+    trainer = Trainer("kimi_linear", config=config,
+                      devices=jax.devices()[:1])
+    names = ("kda_scan_fused_steps_total", "kda_scan_plain_steps_total")
+
+    def totals():
+        counters = obs.get_registry().snapshot()["counters"]
+        return np.array([counters.get(k, 0) for k in names])
+
+    for seed in (13, 14):
+        before = totals()
+        trainer.step(_rows(config, 2, seed))
+        assert set(names) <= set(obs.get_registry().snapshot()["counters"])
+        np.testing.assert_array_equal(totals() - before, [0, 1])
 
 
 @pytest.mark.parametrize("c,sub,scale", [(64, 16, 0.3), (64, 16, 1.0),
@@ -216,30 +364,47 @@ def test_unit_lower_inverse_is_the_inverse(c, sub, scale):
     assert float(jnp.abs(jnp.triu(got, 1)).max()) == 0.0
 
 
-def test_a_layers_recomputation_keeps_what_the_scan_names(monkeypatch):
+@pytest.mark.parametrize("fused", [False, True])
+def test_a_layers_recomputation_keeps_what_the_scan_names(fused, monkeypatch):
     """``hidden_states`` recomputes a layer in the backward pass but for
-    ``kimi_linear.SAVED`` — the scan's outputs and the state entering each
-    group of chunks —, so the recurrence runs forward once and not twice:
-    with the names taken away the lowered gradient holds more loops (the
-    gradients themselves are the whole model's test's)."""
+    ``kimi_linear.SAVED`` — the scan's outputs and what it holds between its
+    passes: the state entering each group of chunks, and of the kernels the
+    state entering each pair and the chunks' inverses —, so the recurrence
+    runs forward once and not twice: with the names taken away the gradient
+    holds more loops (the ``jnp`` form, lowered) or a second forward kernel
+    (the kernels, traced with a TPU in the backend's place; the gradients
+    themselves are the whole model's test's)."""
     config = dataclasses.replace(
         kimi_linear.Config.tiny(), num_hidden_layers=1, kda_layers=(1,),
         full_attn_layers=(), seq_len=64)
+    if fused:
+        config = dataclasses.replace(config, kda_num_heads=4,
+                                     kda_head_dim=128, kda_chunk=64,
+                                     seq_len=128)
+        monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    assert kimi_linear.batch_counters(
+        {"segment_ids": np.zeros((1, config.seq_len), np.int32)}, config)[
+            "kda_scan_fused_steps_total"] == int(fused)
     params = {k: jnp.zeros(s, jnp.float32)
               for k, s in kimi_linear.leaf_shapes(config).items()}
-    batch = kimi_linear.example_batch(config, 1, seq_len=64)
+    batch = kimi_linear.example_batch(config, 1, seq_len=config.seq_len)
     bias = jnp.zeros((config.expert_layers, config.num_experts))
 
     def loops():
-        return jax.jit(jax.grad(lambda p: kimi_linear.loss_terms(
-            p, bias, batch["tokens"], batch["segment_ids"], config)[0])
-        ).lower(params).as_text().count("stablehlo.while")
+        grad = jax.jit(jax.grad(lambda p: kimi_linear.loss_terms(
+            p, bias, batch["tokens"], batch["segment_ids"], config)[0]))
+        if fused:
+            text = str(jax.make_jaxpr(grad)(params))
+            assert text.count("kda_backward") == 1
+            return text.count("kda_forward")
+        return grad.lower(params).as_text().count("stablehlo.while")
 
     kept = loops()
     keep = jax.checkpoint_policies.save_only_these_names
     monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
                         lambda *names: keep())
     assert loops() > kept > 0
+    assert not fused or kept == 1
 
 
 @pytest.mark.parametrize("side", ["program", "reference"])
