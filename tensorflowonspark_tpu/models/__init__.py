@@ -21,27 +21,36 @@ Optional hooks the Trainer looks for: ``make_optimizer``,
 program's counters) and ``device_counters(collections, config)`` (what of
 the step's collections the counters show: what the device decided).
 
-The four decoders trained on packed rows, ``granite_hybrid`` (state-space
+The four decoders trained on packed rows — ``granite_hybrid`` (state-space
 mixers and a NoPE attention layer), ``mla_moe`` (latent attention, routed
-and shared experts, the multi-token-prediction module), ``lfm2_moe``
-(gated short-convolution mixers, a QK-normed RoPE attention layer, routed
-experts and no shared one) and ``kimi_linear`` (Kimi Delta Attention mixers
-— a channel-wise gated delta rule computed in chunks — beside NoPE latent
-attention whose values are narrower than its keys, routed and shared
-experts), share ``packed_rows.py``: norm, products, SwiGLU, the positions
-inside documents and RoPE at them (``mla_moe``, ``lfm2_moe``), the depthwise
-causal convolution that stops at a document's first token
-(``granite_hybrid``, ``lfm2_moe``, ``kimi_linear``), latent attention with or
-without a query latent and rotation (``mla_moe``, ``kimi_linear``), attention
-inside documents (as ``jnp`` code or, on a TPU where a head fills whole
-lanes, the Pallas kernels of ``attention_pallas.py``; granite's scan has
-``ssd_pallas.py``), the blocked loss.  Two expert layers live in
-``parallel/moe.py``: ``bert`` calls ``moe_ffn`` (Switch top-1 with a
-capacity, over ``ep``), ``mla_moe``, ``lfm2_moe`` and ``kimi_linear`` call
-``routed_experts`` (top-k of a wide router, the experts held here, no drop,
-the work sized to a step's own count of slots that landed here) and keep its
-routing state — correction biases, their update, the counts the program's
-counters show — by the same three functions there.
+and shared experts, the multi-token-prediction module), ``lfm2_moe`` (gated
+short-convolution mixers, a QK-normed RoPE attention layer, routed experts
+and no shared one) and ``kimi_linear`` (Kimi Delta Attention mixers beside
+NoPE latent attention whose values are narrower than its keys, routed and
+shared experts) — each keep their ``Config``, ``ADAMW``, ``leaf_shapes``,
+``layer_kinds`` (``mla_moe``: ``layer_prefixes``), mixers, ``_layer``,
+``logits`` and ``batch_counters``, and share the rest:
+
+- ``packed_decoder.py``, the skeleton: one ``Decoder`` a model, whose methods
+  are the surface above under every model's name (``make_model`` — a module
+  that declares its variables and, while it initialises, traces no forward
+  pass —, ``make_optimizer``, ``make_loss_fn``, ``make_forward_fn``,
+  ``example_batch``, ``parameter_count``, ``apply_tokens``, and for the three
+  expert models ``collection_shapes`` and ``device_counters``), the
+  checkpointed layer loop, the feed-forward half of an expert model's layer,
+  the loss over rows;
+- ``packed_rows.py``, the mathematics: norm, products, SwiGLU, the positions
+  inside documents and RoPE at them, the depthwise causal convolution that
+  stops at a document's first token, latent attention with or without a
+  query latent and rotation, attention inside documents, the blocked loss;
+- ``kernels.py``, the one seam between an algorithm and its Pallas kernels
+  (``attention_pallas``, ``conv_pallas``, ``ssd_pallas``, ``kda_pallas``
+  here, ``parallel/grouped_pallas.py``): ``backend()``, the rule
+  ``runs_fused`` and the counter pairs ``step_counters``;
+- ``parallel/moe.py``: ``routed_experts`` (top-k of a wide router, the
+  experts held here, no drop) behind ``expert_ffn``, a layout's ``Routing``
+  and its routing state.  (``bert`` calls the other expert layer there,
+  ``moe_ffn``: Switch top-1 with a capacity, over ``ep``.)
 """
 
 from __future__ import annotations

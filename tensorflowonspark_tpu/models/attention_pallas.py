@@ -32,7 +32,8 @@ from the shapes alone, the segment ids only enter the mask — so a step's
 time does not follow its row.  Only the blocks the diagonal crosses compare
 positions.
 
-``packed_rows.attention_runs_fused`` says when this runs; interpret mode
+``packed_rows.attention_runs_fused`` says when this runs
+(``kernels.runs_fused`` of :func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
 """
 
@@ -42,8 +43,9 @@ import functools
 
 import numpy as np
 
+from tensorflowonspark_tpu.models.kernels import (
+    compiler_params, dot as _dot, jitted)
 from tensorflowonspark_tpu.models.packed_rows import under
-from tensorflowonspark_tpu.models.ssd_pallas import _dot
 
 #: (queries, keys) a tile, forward and backward, chosen on the chip at the
 #: published 8,192 x 20 x 256 in bfloat16 (kernels alone, ms a call; PERF.md,
@@ -188,14 +190,6 @@ def _backward_kernel(scale, dtype, bq, q_ref, do_ref, k_ref, v_ref,
     dq_ref[...] = dq_acc[_rows_at(j, bk), :].astype(dq_ref.dtype)
 
 
-def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
-
-
 def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
     """``out`` (T, heads x hd) in ``dtype`` and the log-sum-exp (heads,
     T / bq, 1, bq) float32."""
@@ -221,7 +215,8 @@ def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
                    jax.ShapeDtypeStruct((heads, t // bq, 1, bq), f32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
                         pltpu.VMEM((bq, hd), f32)],
-        compiler_params=_params(), name="attention_forward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES),
+        name="attention_forward",
     )(q2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bk, 1, bk))
 
 
@@ -255,20 +250,10 @@ def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk):
         out_shape=[jax.ShapeDtypeStruct(q2.shape, dtype), summed, summed],
         scratch_shapes=[pltpu.VMEM((t, hd), f32), pltpu.VMEM((bk, hd), f32),
                         pltpu.VMEM((bk, hd), f32)],
-        compiler_params=_params(), name="attention_backward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES),
+        name="attention_backward",
     )(q2, do2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bq, 1, bq), lse,
       delta)
-
-
-@functools.lru_cache(maxsize=None)
-def _kernels():
-    """The two kernel calls under ``jax.jit``: a model calls each once a
-    layer (and the forward again in the layer's recomputation), and a jitted
-    function's body is traced and lowered once a shape, not once a call."""
-    import jax
-
-    return (jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8)),
-            jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11)))
 
 
 def _heads_along_lanes(x, dtype):
@@ -277,7 +262,7 @@ def _heads_along_lanes(x, dtype):
 
 
 def _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward, backward):
-    out, lse = _kernels()[0](
+    out, lse = jitted(_forward, (4, 5, 6, 7, 8))(
         *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg, scale,
         dtype, q.shape[-1], *forward)
     out = out.reshape(q.shape)
@@ -294,7 +279,7 @@ def _attend_bwd(scale, dtype, scopes, forward, backward, saved, d_out):
     bq = backward[0]
     with under(scopes):
         delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
-        dq, dk, dv = _kernels()[1](
+        dq, dk, dv = jitted(_backward, (7, 8, 9, 10, 11))(
             *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg,
             lse.reshape(kv * rep, t // bq, 1, bq),
             _heads_along_lanes(d_out, dtype),

@@ -35,7 +35,8 @@ Same arithmetic as the ``jnp`` form, float32 inside, the same roundings at
 the same places; the two differ by the order of the sums over rows in the
 taps' gradients and by SiLU's derivative written out.
 
-``packed_rows.conv_runs_fused`` says when this runs; interpret mode
+``packed_rows.conv_runs_fused`` says when this runs
+(``kernels.runs_fused`` of :func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
 """
 
@@ -46,6 +47,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from tensorflowonspark_tpu.models.kernels import compiler_params, jitted
 from tensorflowonspark_tpu.models.packed_rows import under
 
 #: rows a tile and the widest tile of channels (whole rows of 128 lanes),
@@ -249,14 +251,6 @@ def _backward_kernel(form: Form, *refs):
     pos_after[...] = pos_ref[:HALO, :]
 
 
-def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
-
-
 def _specs(form: Form, t: int, c: int, rows: int, backward: bool):
     """``(grid, tile, in_specs)``: the grid, the BlockSpec of a (rows x
     channels) tile and those of the operands both kernels share, in the
@@ -294,7 +288,7 @@ def _forward(form: Form, rows: int, x, times, w, b, pos, gate):
         functools.partial(_forward_kernel, form), grid=grid,
         in_specs=in_specs, out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x.shape, form.out),
-        compiler_params=_params(), name="conv_forward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES), name="conv_forward",
     )(*_operands(form, x, times, w, b, pos, gate))
 
 
@@ -320,27 +314,16 @@ def _backward(form: Form, rows: int, x, times, w, b, pos, gate, dy):
             jax.ShapeDtypeStruct((ACC_ROWS, c), f32)],
         scratch_shapes=[pltpu.VMEM((HALO, cols), f32),
                         pltpu.VMEM((HALO, 1), jnp.int32)],
-        compiler_params=_params(), name="conv_backward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES),
+        name="conv_backward",
     )(*_operands(form, x, times, w, b, pos, gate), dy)
     outs = list(outs)
     return (outs.pop(0), _take(outs, form.times), _take(outs, form.gate),
             outs.pop(0))
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels():
-    """The two kernel calls under ``jax.jit``: a model calls each once a
-    convolution and pass (granite 27 times a step, Kimi Linear 36), and a
-    jitted function's body is traced and lowered once a shape and form, not
-    once a call."""
-    import jax
-
-    return (jax.jit(_forward, static_argnums=(0, 1)),
-            jax.jit(_backward, static_argnums=(0, 1)))
-
-
 def _conv_fwd(x, times, w, b, pos, gate, form, rows, scopes):
-    return (_kernels()[0](form, rows, x, times, w, b, pos, gate),
+    return (jitted(_forward, (0, 1))(form, rows, x, times, w, b, pos, gate),
             (x, times, w, b, pos, gate))
 
 
@@ -349,7 +332,7 @@ def _conv_bwd(form, rows, scopes, saved, dy):
 
     x, times, w, b, pos, gate = saved
     with under(scopes):
-        dx, dtimes, dgate, acc = _kernels()[1](
+        dx, dtimes, dgate, acc = jitted(_backward, (0, 1))(
             form, rows, x, times, w, b, pos, gate, dy)
         dw = acc[:form.taps].astype(w.dtype)
         db = (acc[ACC_ROWS - 1].reshape(b.shape).astype(b.dtype)

@@ -26,43 +26,33 @@ row; documents are contiguous and their ids differ)::
 Three places honour document boundaries: the convolution's look-back, the
 recurrent state and the attention mask.  The recurrence runs in the chunked
 (SSD) form (:func:`ssd_scan`), the state carried between chunks in float32:
-on a TPU, at shapes that fill their tiles (the published ones do), as the
-Pallas kernels of ``ssd_pallas``, which keep a chunk's decay tile on the
-chip; on any other backend and at small shapes (``Config.tiny()``, the
-tests) as ``jnp`` code.  :func:`scan_runs_fused` is the rule, and a step
-counts which applied (``ssm_scan_fused_steps_total`` /
-``ssm_scan_plain_steps_total``).
+on a TPU at shapes that fill their tiles (the published ones do) as the
+Pallas kernels of ``ssd_pallas``, anywhere else as ``jnp`` code.
+:func:`scan_runs_fused` is the rule, and a step counts which applied
+(``ssm_scan_fused_steps_total`` / ``ssm_scan_plain_steps_total``).
 The norm, the products, the feed-forward, the convolution, the attention
-and the blocked loss are ``packed_rows``'s, which ``mla_moe`` and
-``lfm2_moe`` call too.  Attention has two
-executions as well (``packed_rows.attention_runs_fused``): its kernels want
-a head to fill whole rows of 128 lanes, so the published 32/8 heads of 64
-keep the ``jnp`` form on every backend, and a step says so
-(``attention_plain_steps_total``, ``attention_fused_steps_total`` 0).
-Parameters are float32; activations are ``Config.dtype``.  Every layer is
-recomputed in the backward pass (``jax.checkpoint``), attention runs a block
-of queries at a time and the training loss a block of tokens at a time, so
-a row of 8,192 tokens at the published widths trains on one chip beside 16
-bytes of state a parameter; none of the three is an option.
+and the blocked loss are ``packed_rows``'s, the layer loop, the loss over
+rows and the registry's surface ``packed_decoder``'s (its docstring says what
+holds for every such decoder).  Attention's kernels want a head to fill
+whole rows of 128 lanes (``packed_rows.attention_runs_fused``), so the
+published 32/8 heads of 64 keep the ``jnp`` form on every backend, and a
+step says so (``attention_plain_steps_total``).  Recomputation and the
+blocked attention and loss are why a row of 8,192 tokens at the published
+widths trains on one chip beside 16 bytes of state a parameter.
 
 ``jax.named_scope`` names a device trace can be cut by: ``ssm_mixer`` (the
 whole mixer) > ``ssm_conv``, ``ssm_scan``; ``attention``; ``mlp``;
 ``lm_head``.
-
-The flax module only registers the parameters (a flat dict, as
-``tinylm``'s); the mathematics is in pure functions over that dict.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 
-import numpy as np
-
+from tensorflowonspark_tpu.models import packed_decoder
+from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 from tensorflowonspark_tpu.models.packed_rows import (
-    _backend, block as _block, blocked_cross_entropy, causal_conv,
-    document_attention, example_rows, loss_positions, mm as _mm, rms as _rms,
+    block as _block, causal_conv, document_attention, mm as _mm, rms as _rms,
     row_counters, swiglu)
 
 #: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
@@ -124,12 +114,18 @@ class Config:
         return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
 
+def layer_kinds(config: Config) -> list:
+    """``(prefix, mixer)`` of every layer in forward order: ``mixer`` is
+    ``"mamba"`` or ``"attention"``."""
+    return [(f"l{i:02d}_", mixer)
+            for i, mixer in enumerate(config.layer_types)]
+
+
 def leaf_shapes(config: Config) -> dict:
     """Name -> shape of every parameter, in forward order."""
     d, f = config.hidden_size, config.intermediate_size
     out = {"embed": (config.vocab_size, d)}
-    for i, kind in enumerate(config.layer_types):
-        p = f"l{i:02d}_"
+    for p, kind in layer_kinds(config):
         out[p + "norm1"] = (d,)
         if kind == "mamba":
             out[p + "in_proj"] = (d, config.d_inner + config.conv_dim
@@ -148,7 +144,7 @@ def leaf_shapes(config: Config) -> dict:
             out[p + "wv"] = (d, kv)
             out[p + "wo"] = (d, d)
         else:
-            raise ValueError(f"layer {i}: unknown type {kind!r}")
+            raise ValueError(f"layer {p}: unknown type {kind!r}")
         out[p + "norm2"] = (d,)
         out[p + "mlp_gate"] = (d, f)
         out[p + "mlp_up"] = (d, f)
@@ -157,26 +153,19 @@ def leaf_shapes(config: Config) -> dict:
     return out
 
 
-def parameter_count(config: Config) -> int:
-    return sum(int(np.prod(s)) for s in leaf_shapes(config).values())
-
-
 # ---------------------------------------------------------------------------
-# The mathematics, over the flat parameter dict, one row at a time
+# The mathematics, over the flat parameter dict; the mixers one row at a time
 # ---------------------------------------------------------------------------
 
 
 def scan_runs_fused(chunk: int, heads: int, p: int, groups: int,
                     n: int) -> bool:
-    """How :func:`ssd_scan` executes: on the Pallas kernels of
-    ``ssd_pallas`` (True) or as ``jnp`` code (False).  Decided from what the
-    code can observe: the backend is a TPU and the chunk, the heads and the
-    state's widths fill the kernels' tiles (``ssd_pallas.fits``: the
-    published 256, 64 x 64, one group, 128 do; ``Config.tiny()``'s do
-    not)."""
+    """Whether :func:`ssd_scan` runs on the kernels of ``ssd_pallas``:
+    ``kernels.runs_fused`` of ``ssd_pallas.fits`` (the published 256, 64 x
+    64, one group, 128 fill the tiles; ``Config.tiny()``'s do not)."""
     from tensorflowonspark_tpu.models import ssd_pallas
 
-    return _backend() == "tpu" and ssd_pallas.fits(chunk, heads, p, groups, n)
+    return runs_fused(ssd_pallas, chunk, heads, p, groups, n)
 
 
 def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
@@ -260,11 +249,8 @@ def ssd_scan(x, dt, a, b, c, seg, chunk: int, dtype):
     return y.reshape(t + pad, heads, p)[:t]
 
 
-def mamba_mixer(params, prefix: str, h, seg, config: Config,
-                initializing: bool = False):
-    """The Mamba-2 mixer on one row: ``h`` (T, D) -> (T, D).
-    ``initializing``: the module is only learning its parameters from this
-    trace (``packed_rows.causal_conv`` reads it)."""
+def mamba_mixer(params, prefix: str, h, seg, config: Config):
+    """The Mamba-2 mixer on one row: ``h`` (T, D) -> (T, D)."""
     import jax
     import jax.numpy as jnp
 
@@ -281,8 +267,7 @@ def mamba_mixer(params, prefix: str, h, seg, config: Config,
     with jax.named_scope("ssm_conv"):
         xbc = causal_conv(
             xbc, params[prefix + "conv_w"], params[prefix + "conv_b"], seg,
-            silu=True, out=dtype, scopes=("ssm_mixer", "ssm_conv"),
-            initializing=initializing)
+            silu=True, out=dtype, scopes=("ssm_mixer", "ssm_conv"))
     x = xbc[:, :d_inner].reshape(t, heads, p)
     b = xbc[:, d_inner:d_inner + groups * n].reshape(t, groups, n)
     c = xbc[:, d_inner + groups * n:].reshape(t, groups, n)
@@ -316,45 +301,39 @@ def attention(params, prefix: str, h, seg, config: Config):
                params[prefix + "wo"], dtype)
 
 
-def mlp(params, prefix: str, h):
-    return swiglu(h, params[prefix + "mlp_gate"], params[prefix + "mlp_up"],
-                  params[prefix + "mlp_down"])
-
-
-def hidden_states(params, tokens, seg, config: Config,
-                  initializing: bool = False):
-    """One row's final hidden states before the last norm: (T, D).
-    ``initializing`` is the calling module's ``is_initializing()``."""
+def _layer(mixer: str, prefix: str, config: Config, scopes: tuple, lp, x,
+           seg, pos, bias):
+    """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, None)`` (no
+    router, no counts; ``pos`` and ``bias`` are not read)."""
     import jax
+
+    res, eps = config.residual_multiplier, config.rms_norm_eps
+    h = _rms(x, lp[prefix + "norm1"], eps)
+    if mixer == "mamba":
+        scope, mix = "ssm_mixer", lambda hr, sr: mamba_mixer(
+            lp, prefix, hr, sr, config)
+    else:
+        scope, mix = "attention", lambda hr, sr: attention(
+            lp, prefix, hr, sr, config)
+    with jax.named_scope(scope):
+        x = x + res * jax.vmap(mix)(h, seg)
+    with jax.named_scope("mlp"):
+        h = _rms(x, lp[prefix + "norm2"], eps).reshape(-1, x.shape[-1])
+        return x + res * swiglu(
+            h, lp[prefix + "mlp_gate"], lp[prefix + "mlp_up"],
+            lp[prefix + "mlp_down"]).reshape(x.shape), None
+
+
+def _embed(params, tokens, config: Config):
     import jax.numpy as jnp
 
-    dtype = jnp.dtype(config.dtype)
-    res, eps = config.residual_multiplier, config.rms_norm_eps
-
-    def layer(kind, prefix, lp, x, seg):
-        h = _rms(x, lp[prefix + "norm1"], eps)
-        if kind == "mamba":
-            with jax.named_scope("ssm_mixer"):
-                x = x + res * mamba_mixer(lp, prefix, h, seg, config,
-                                          initializing)
-        else:
-            with jax.named_scope("attention"):
-                x = x + res * attention(lp, prefix, h, seg, config)
-        with jax.named_scope("mlp"):
-            return x + res * mlp(lp, prefix,
-                                 _rms(x, lp[prefix + "norm2"], eps))
-
-    x = (config.embedding_multiplier
-         * jnp.take(params["embed"], tokens, axis=0)).astype(dtype)
-    for i, kind in enumerate(config.layer_types):
-        prefix = f"l{i:02d}_"
-        mine = {k: v for k, v in params.items() if k.startswith(prefix)}
-        x = jax.checkpoint(layer, static_argnums=(0, 1))(
-            kind, prefix, mine, x, seg)
-    return x
+    return (config.embedding_multiplier
+            * jnp.take(params["embed"], tokens, axis=0)).astype(
+                jnp.dtype(config.dtype))
 
 
-def _logits(params, x, config: Config):
+def logits(params, x, config: Config):
+    """The tied head on states ``x`` (N, D): float32 (N, V)."""
     import jax.numpy as jnp
 
     h = _rms(x, params["final_norm"], config.rms_norm_eps)
@@ -362,118 +341,40 @@ def _logits(params, x, config: Config):
                out=jnp.float32) / config.logits_scaling
 
 
-def apply_tokens(params, tokens, segment_ids, config: Config,
-                 initializing: bool = False):
-    """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits.  ``initializing`` is the calling module's
-    ``is_initializing()`` (``packed_rows.causal_conv`` reads it)."""
-    import jax
-
-    def row(u, s):
-        x = hidden_states(params, u, s, config, initializing)
-        with jax.named_scope("lm_head"):
-            return _logits(params, x, config)
-
-    return jax.vmap(row)(tokens, segment_ids)
-
-
-def loss_sums(params, tokens, segment_ids, config: Config):
-    """``(sum of the cross-entropies, positions counted)`` of a batch of
-    packed rows: position ``t`` is scored against ``u_{t+1}`` where that is
-    the same document's.  The logits exist a block of tokens at a time."""
-    import jax
-    import jax.numpy as jnp
-
-    def row(u, s):
-        x = hidden_states(params, u, s, config)
-        valid = loss_positions(s)
-        with jax.named_scope("lm_head"):
-            total = blocked_cross_entropy(
-                x, lambda xb: _logits(params, xb, config), jnp.roll(u, -1),
-                valid, config.loss_block)
-        return total, jnp.sum(valid)
-
-    total, count = jax.vmap(row)(tokens, segment_ids)
-    return jnp.sum(total), jnp.sum(count)
-
-
 # ---------------------------------------------------------------------------
 # The zoo's surface
 # ---------------------------------------------------------------------------
 
 
-def _initializers(config: Config) -> dict:
-    """Mamba-2's published defaults: ``A_log = log(uniform[1, 16])``,
+def _init(config: Config):
+    """``(name, shape) ->`` a leaf's initializer, Mamba-2's published
+    defaults: ``A_log = log(uniform[1, 16])``,
     ``dt_bias`` the inverse softplus of log-uniform [1e-3, 1e-1], ``D = 1``,
     the convolution as PyTorch's ``Conv1d`` leaves it, normal(0, 0.02)
     matrices, unit norms."""
     import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
 
-    def a_log(key, shape, dtype):
-        return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-    def dt_bias(key, shape, dtype):
-        dt = jnp.exp(jax.random.uniform(key, shape, dtype,
-                                        math.log(1e-3), math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
-
-    def conv(key, shape, dtype):
-        bound = 1.0 / math.sqrt(config.mamba_d_conv)
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-    ones = nn.initializers.ones
-    by_leaf = {"A_log": a_log, "dt_bias": dt_bias, "D": ones,
+    ones, normal = nn.initializers.ones, nn.initializers.normal(0.02)
+    conv = packed_decoder.conv_taps(config.mamba_d_conv)
+    by_leaf = {"A_log": packed_decoder.a_log,
+               "dt_bias": packed_decoder.dt_bias, "D": ones,
                "conv_w": conv, "conv_b": conv, "norm1": ones, "norm2": ones,
                "gate_norm": ones, "final_norm": ones}
-    return {name: by_leaf.get(name[4:] if name[1:3].isdigit() else name,
-                              nn.initializers.normal(0.02))
-            for name in leaf_shapes(config)}
+    return lambda name, shape: by_leaf.get(
+        name[4:] if name[1:3].isdigit() else name, normal)
 
 
-def make_model(config: Config, mesh=None):
-    import flax.linen as nn
-    import jax.numpy as jnp
-
-    shapes, inits = leaf_shapes(config), _initializers(config)
-
-    class GraniteHybrid(nn.Module):
-        @nn.compact
-        def __call__(self, tokens, segment_ids):
-            params = {name: self.param(name, inits[name], shape, jnp.float32)
-                      for name, shape in shapes.items()}
-            return apply_tokens(params, tokens, segment_ids, config,
-                                initializing=self.is_initializing())
-
-    return GraniteHybrid()
-
-
-def make_optimizer(config: Config, learning_rate: float):
-    import optax
-
-    return optax.adamw(learning_rate, **ADAMW)
-
-
-def make_loss_fn(module, config: Config):
-    """Mean next-token cross-entropy over the positions whose next token is
-    the same document's (the targets are the inputs shifted left)."""
-    import jax.numpy as jnp
-
-    def loss_fn(params, batch):
-        total, count = loss_sums(params, batch["tokens"],
-                                 batch["segment_ids"], config)
-        return total / jnp.maximum(count, 1)
-
-    return loss_fn
-
-
-def make_forward_fn(module, config: Config):
-    def forward(params, batch):
-        return apply_tokens(params, batch["tokens"], batch["segment_ids"],
-                            config)
-
-    return forward
+_DECODER = packed_decoder.Decoder(
+    adamw=ADAMW, leaf_shapes=leaf_shapes, layers=layer_kinds, layer=_layer,
+    logits=logits, init=_init, embed=_embed,
+    example_tokens=lambda config: 2 * config.mamba_chunk_size)
+make_model = _DECODER.make_model
+make_optimizer = _DECODER.make_optimizer
+make_loss_fn = _DECODER.make_loss_fn        # loss(params, batch): no router
+make_forward_fn = _DECODER.make_forward_fn
+parameter_count = _DECODER.parameter_count
+example_batch = _DECODER.example_batch
+apply_tokens = _DECODER.apply_tokens        # its ``bias`` is None: no router
 
 
 def batch_counters(batch, config: Config) -> dict:
@@ -484,21 +385,11 @@ def batch_counters(batch, config: Config) -> dict:
     the same kind of rule (:func:`scan_runs_fused`), one step of the scan on
     the kernels or as ``jnp`` code, the other named with 0."""
     scans = "mamba" in config.layer_types
-    fused = scans and scan_runs_fused(
-        config.mamba_chunk_size, config.mamba_n_heads, config.mamba_d_head,
-        config.mamba_n_groups, config.mamba_d_state)
     return {**row_counters(batch["segment_ids"], config.head_dim,
                            "attention" in config.layer_types,
                            conv=(config.conv_dim, config.mamba_d_conv)
                            if scans else None),
-            "ssm_scan_fused_steps_total": int(fused),
-            "ssm_scan_plain_steps_total": int(scans and not fused)}
-
-
-def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
-                  seq_len: int | None = None):
-    """Packed rows of two documents each, ``2 * chunk`` tokens unless
-    ``seq_len`` says otherwise (a step compiles at the shape it is fed)."""
-    return example_rows(
-        config.vocab_size, batch_size, seed,
-        int(seq_len or min(config.seq_len, 2 * config.mamba_chunk_size)))
+            **step_counters("ssm_scan", scan_runs_fused(
+                config.mamba_chunk_size, config.mamba_n_heads,
+                config.mamba_d_head, config.mamba_n_groups,
+                config.mamba_d_state), scans)}

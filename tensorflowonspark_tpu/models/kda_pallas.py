@@ -53,7 +53,8 @@ product takes operands in ``dtype`` and accumulates in float32.  Nothing is
 skipped by what the documents are: every cell costs the same whatever its
 row holds.
 
-``kimi_linear.kda_scan_runs_fused`` says when this runs; interpret mode
+``kimi_linear.kda_scan_runs_fused`` says when this runs
+(``kernels.runs_fused`` of :func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
 """
 
@@ -64,6 +65,7 @@ import types
 
 import numpy as np
 
+from tensorflowonspark_tpu.models.kernels import compiler_params, jitted
 from tensorflowonspark_tpu.models.kimi_linear import EXPONENT_CAP, sub_block
 from tensorflowonspark_tpu.models.packed_rows import under
 
@@ -350,14 +352,6 @@ def _backward_kernel(hb, dtype, q_ref, k_ref, v_ref, g_ref, beta_ref,
         dbeta_ref[:, j:j + 1] = d_beta
 
 
-def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
-
-
 def _specs(hb: int, cells: int, backward: bool):
     """The blocks of a grid cell (block of heads ``h``, step ``s``: cell
     ``s`` of the row, or ``cells - 1 - s`` in the backward pass): a block of
@@ -398,7 +392,7 @@ def _forward(dtype, q, k, v, g, beta, marks, row):
                    jax.ShapeDtypeStruct((cells * CHUNK, nhb * hb * LANES),
                                         dtype)],
         scratch_shapes=[pltpu.VMEM((hb, LANES, LANES), jnp.float32)],
-        compiler_params=_params(), name="kda_forward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES), name="kda_forward",
     )(q, k, v, g, beta, marks, row)
 
 
@@ -420,19 +414,8 @@ def _backward(dtype, q, k, v, g, beta, marks, row, states, inverse, d_o):
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
                    for a in (q, k, v, g, beta)],
         scratch_shapes=[pltpu.VMEM((hb, LANES, LANES), jnp.float32)],
-        compiler_params=_params(), name="kda_backward",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES), name="kda_backward",
     )(q, k, v, g, beta, marks, row, states, inverse, d_o)
-
-
-@functools.lru_cache(maxsize=None)
-def _kernels():
-    """The two kernel calls under ``jax.jit``: a model calls each once a
-    KDA layer, and a jitted function's body — a block of heads unrolled —
-    is traced and lowered once a shape, not once a call."""
-    import jax
-
-    return (jax.jit(_forward, static_argnums=0),
-            jax.jit(_backward, static_argnums=0))
 
 
 @functools.lru_cache(maxsize=None)
@@ -445,7 +428,7 @@ def _core(dtype, saved: tuple, scopes: tuple):
 
     def fwd(*xs):
         o, *kept = (checkpoint_name(a, n) for a, n in zip(
-            _kernels()[0](dtype, *xs), saved))
+            jitted(_forward, 0)(dtype, *xs), saved))
         return o, (xs, kept)
 
     def bwd(res, d_o):
@@ -453,7 +436,7 @@ def _core(dtype, saved: tuple, scopes: tuple):
         # a custom_vjp's backward function does not inherit the caller's
         # scopes
         with under(scopes):
-            grads = _kernels()[1](dtype, *xs, *kept, d_o)
+            grads = jitted(_backward, 0)(dtype, *xs, *kept, d_o)
         return tuple(grads) + tuple(
             np.zeros(a.shape, jax.dtypes.float0) for a in xs[5:])
 

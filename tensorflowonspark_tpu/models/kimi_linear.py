@@ -45,36 +45,23 @@ numbers them::
 The recurrence is computed in chunks of ``Config.kda_chunk`` tokens
 (:func:`kda_scan`), every product a matrix product, equal to the recurrence
 up to rounding.  One algorithm, two executions
-(:func:`kda_scan_runs_fused`): on a TPU, at shapes that fill its tiles (the
-published 32 heads of 128 x 128 at chunks of 64), the two Pallas kernels of
-``kda_pallas`` — a (block of heads, pair of chunks) cell reads its slice of
-the operands once, keeps every intermediate in VMEM with the state carried
-across the chunks in scratch, and writes the outputs, a backward kernel
-beside the forward one; everywhere else ``jnp`` code whose backward pass
-makes a group of chunks again from the state that entered it and
-differentiates that (``jax.vjp`` of the chunked form, a group at a time),
-which is the kernels' oracle in the tests.  A step counts which ran
+(:func:`kda_scan_runs_fused`; :func:`kda_scan` describes both): on a TPU, at
+shapes that fill its tiles (the published 32 heads of 128 x 128 at chunks of
+64), the two Pallas kernels of ``kda_pallas``, everywhere else ``jnp`` code
+with a backward pass of its own, which is the kernels' oracle in the tests.
+A step counts which ran
 (``kda_scan_fused_steps_total`` / ``kda_scan_plain_steps_total``).  In both
 a layer's recomputation keeps by name what the scan holds between its
 passes (``SAVED``), so the recurrence runs forward once a step.
 
-``Config.experts_held`` says which of the ``num_experts`` this chip holds
-(all of them unless told otherwise): the router stays as wide as published,
-the held experts' part of the result is computed
-(``parallel/moe.py::routed_experts``) and what the others would have added is
-left out.  No exchange runs and none is stood in for.  The correction biases
-and the counts behind them are the ``moe`` collection
-(``moe.routing_state_shapes``), as ``mla_moe``'s and ``lfm2_moe``'s.
-
 What is this model's own is the KDA mixer.  The norm, the products, the
-SwiGLU, the convolution, the latent attention, the blocked attention and the
-blocked loss are ``packed_rows``'s (``granite_hybrid``, ``mla_moe`` and
-``lfm2_moe`` call them too), the routed layer and the routing state
-``parallel/moe.py``'s.  Parameters are float32, activations ``Config.dtype``,
-the decay, the norms, the router and the state's carry float32; every layer
-is recomputed in the backward pass, attention runs a block of queries at a
-time and the loss a block of tokens at a time; none of the three is an
-option.  The published keys of 192 a head are not whole rows of 128 lanes
+convolution, the latent attention, the blocked attention and the blocked
+loss are ``packed_rows``'s, the routed layer and the routing state (the
+``moe`` collection) ``parallel/moe.py``'s, the layer loop, the feed-forward
+half of a layer and the registry's surface ``packed_decoder``'s, whose
+docstring says what holds for every such decoder (``Config.experts_held``
+among it); here the decay, the norms, the router and the state's carry are
+float32.  The published keys of 192 a head are not whole rows of 128 lanes
 and the values are narrower than the keys, so attention runs as ``jnp`` code
 on every backend (``packed_rows.attention_runs_fused``) and a step says so
 (``attention_plain_steps_total``).
@@ -88,22 +75,19 @@ it and ``kda_mixer`` in their ``op_name``), ``kda_out`` (the heads' norm, the ga
 ``attention`` > ``mla_project``; ``mlp``; ``shared_expert``; ``moe_router``,
 ``moe_dispatch``, ``moe_experts``, ``moe_combine`` (``routed_experts``');
 ``lm_head``.
-
-The flax module only registers the parameters and the collection (flat
-dicts); the mathematics is in pure functions over them.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import numpy as np
 
+from tensorflowonspark_tpu.models import packed_decoder
+from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, blocked_cross_entropy, causal_conv, example_rows,
-    latent_attention, loss_positions, mm, rms, row_counters, swiglu, under)
+    block, causal_conv, latent_attention, mm, rms, row_counters, under)
 
 #: no sequence-parallel sharding: the state has no hand-over across ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -111,8 +95,8 @@ SEQUENCE_AXES: dict = {}
 #: the recipe :func:`make_optimizer` builds (a continued-pre-training AdamW)
 ADAMW = {"b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1}
 
-#: the collection of non-gradient state (``parallel/moe.py``'s)
-COLLECTION = "moe"
+#: the collection of non-gradient state (``packed_decoder.COLLECTION``)
+COLLECTION = packed_decoder.COLLECTION
 
 #: the published pattern, layers numbered from 1: ``K K K A`` six times, then
 #: ``K K A``
@@ -138,11 +122,6 @@ SCAN_GROUP = 4
 #: outputs, the state entering each group of chunks (the kernels: each pair)
 #: and, of the kernels alone, the chunks' inverses
 SAVED = ("kda_scan_out", "kda_scan_states", "kda_scan_inverse")
-
-#: the bounds ``A_log`` and ``dt_bias`` are drawn between (the public
-#: implementation's, which are Mamba-2's)
-A_RANGE = (1.0, 16.0)
-DT_RANGE = (0.001, 0.1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,7 +206,7 @@ def leaf_shapes(config: Config) -> dict:
     """Name -> shape of every parameter, in forward order."""
     d, heads = config.hidden_size, config.num_attention_heads
     p, hd, kh = config.kda_width, config.kda_head_dim, config.kda_num_heads
-    f, held = config.moe_intermediate_size, len(config.experts_held)
+    f = config.moe_intermediate_size
     out = {"embed": (config.vocab_size, d)}
     for pre, mixer, ffn in layer_kinds(config):
         out[pre + "norm1"] = (d,)
@@ -255,32 +234,23 @@ def leaf_shapes(config: Config) -> dict:
                 config.qk_nope_head_dim + config.v_head_dim))
             out[pre + "wo"] = (heads * config.v_head_dim, d)
         out[pre + "norm2"] = (d,)
-        if ffn == "dense":
-            out[pre + "mlp_gate"] = (d, config.intermediate_size)
-            out[pre + "mlp_up"] = (d, config.intermediate_size)
-            out[pre + "mlp_down"] = (config.intermediate_size, d)
-        else:
-            out[pre + "router"] = (d, config.num_experts)
-            out[pre + "shared_gate"] = (d, f * config.num_shared_experts)
-            out[pre + "shared_up"] = (d, f * config.num_shared_experts)
-            out[pre + "shared_down"] = (f * config.num_shared_experts, d)
-            out[pre + "experts_gate"] = (held, d, f)
-            out[pre + "experts_up"] = (held, d, f)
-            out[pre + "experts_down"] = (held, f, d)
+        out.update(packed_decoder.ffn_leaf_shapes(
+            pre, ffn, d, config.intermediate_size, f, routing(config),
+            shared=f * config.num_shared_experts))
     out["final_norm"] = (d,)
     out["head"] = (config.vocab_size, d)
     return out
 
 
-def parameter_count(config: Config) -> int:
-    return sum(int(np.prod(s)) for s in leaf_shapes(config).values())
-
-
-def collection_shapes(config: Config) -> dict:
-    """The ``moe`` collection: a row an expert layer, in forward order."""
+def routing(config: Config):
+    """This layout's routed layers, as ``parallel/moe.py`` names them."""
     from tensorflowonspark_tpu.parallel import moe
 
-    return moe.routing_state_shapes(config.num_experts, config.expert_layers)
+    return moe.Routing(
+        n_experts=config.num_experts, layers=config.expert_layers,
+        held=config.experts_held, top_k=config.num_experts_per_token,
+        scale=config.routed_scaling_factor,
+        normalize=config.moe_renormalize, speed=config.bias_update_speed)
 
 
 # ---------------------------------------------------------------------------
@@ -457,18 +427,14 @@ def _grouped_rule(chunk: int, dtype, scopes: tuple):
 
 
 def kda_scan_runs_fused(chunk: int, heads: int, dk: int, dv: int) -> bool:
-    """How :func:`kda_scan` executes at chunks of ``chunk`` tokens and
-    ``heads`` heads with keys of ``dk`` and values of ``dv``: on the Pallas
-    kernels of ``kda_pallas`` (True) or as ``jnp`` code (False).  Decided
-    from what the code can observe: the backend is a TPU, keys and values
-    are whole rows of 128 lanes, a chunk is 64 tokens (two fill a cell's 128
-    rows, a quarter sub-block is a whole tile) and the heads are whole
-    blocks (``kda_pallas.fits``: the published 32 x 128 x 128 at chunks of
-    64 do; ``Config.tiny()``'s do not)."""
-    from tensorflowonspark_tpu.models import kda_pallas, packed_rows
+    """Whether :func:`kda_scan` runs on the kernels of ``kda_pallas`` at
+    chunks of ``chunk`` tokens and ``heads`` heads with keys of ``dk`` and
+    values of ``dv``: ``kernels.runs_fused`` of ``kda_pallas.fits`` (the
+    published 32 x 128 x 128 at chunks of 64 do; ``Config.tiny()``'s do
+    not)."""
+    from tensorflowonspark_tpu.models import kda_pallas
 
-    return (packed_rows._backend() == "tpu"
-            and kda_pallas.fits(chunk, heads, dk, dv))
+    return runs_fused(kda_pallas, chunk, heads, dk, dv)
 
 
 def kda_scan(q, k, v, g, beta, seg, chunk: int, dtype, scopes: tuple = ()):
@@ -571,13 +537,10 @@ def _l2(x):
                              + L2_EPS)
 
 
-def kda_mixer(params, prefix: str, h, seg, config: Config,
-              initializing: bool = False):
+def kda_mixer(params, prefix: str, h, seg, config: Config):
     """Kimi Delta Attention on one row: ``h`` (T, D) -> (T, D).  The
     convolutions, the L2 norms, the decay, the step size and the heads' norm
-    are float32 between the products.  ``initializing``: the module is only
-    learning its parameters from this trace (``packed_rows.causal_conv``
-    reads it)."""
+    are float32 between the products."""
     import jax
     import jax.numpy as jnp
 
@@ -597,8 +560,7 @@ def kda_mixer(params, prefix: str, h, seg, config: Config,
         # q and k go on to their L2 norms in float32; v to the products
         q, k, v = (causal_conv(
             x, params[pre + f"{name}_conv"], 0.0, seg, silu=True, out=out,
-            scopes=("kda_mixer", "kda_conv"), initializing=initializing
-        ).reshape(t, heads, hd)
+            scopes=("kda_mixer", "kda_conv")).reshape(t, heads, hd)
                    for x, name, out in zip(qkv, "qkv", (f32, f32, dtype)))
     with jax.named_scope("kda_scan"):
         g = -jnp.exp(params[pre + "A_log"])[:, None] * jax.nn.softplus(
@@ -625,123 +587,33 @@ def attention(params, prefix: str, h, seg, config: Config):
         size=block(h.shape[0], config.attention_block))
 
 
-def expert_ffn(params, prefix: str, h, bias, config: Config,
-               initializing: bool = False):
-    """The shared expert and the held routed experts on tokens ``h`` (N, D).
-    Returns ``(y, counts)``, ``counts`` (E,) the tokens that chose each of
-    the router's experts."""
-    import jax
-
-    from tensorflowonspark_tpu.parallel import moe
-
-    with jax.named_scope("shared_expert"):
-        y = swiglu(h, params[prefix + "shared_gate"],
-                   params[prefix + "shared_up"],
-                   params[prefix + "shared_down"])
-    routed, counts = moe.routed_experts(
-        h, params[prefix + "router"], bias, params[prefix + "experts_gate"],
-        params[prefix + "experts_up"], params[prefix + "experts_down"],
-        config.experts_held, top_k=config.num_experts_per_token,
-        scale=config.routed_scaling_factor,
-        normalize=config.moe_renormalize, initializing=initializing)
-    return y + routed, counts
-
-
-def _layer(mixer: str, ffn: str, prefix: str, config: Config,
-           initializing: bool, lp, x, seg, bias):
+def _layer(mixer: str, ffn: str, prefix: str, config: Config, scopes: tuple,
+           lp, x, seg, pos, bias):
     """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, counts)``;
-    ``counts`` is (E,) zeros for a dense layer.  ``initializing``: the
-    module is only learning its parameters from this trace
-    (``moe.routed_experts``)."""
+    ``counts`` is (E,) zeros for a dense layer (``pos`` is not read: no
+    layer has a positional encoding)."""
     import jax
-    import jax.numpy as jnp
 
     eps = config.rms_norm_eps
     if mixer == "kda":
         scope, mix = "kda_mixer", lambda hr, sr: kda_mixer(
-            lp, prefix, hr, sr, config, initializing)
+            lp, prefix, hr, sr, config)
     else:
         scope, mix = "attention", lambda hr, sr: attention(
             lp, prefix, hr, sr, config)
     with jax.named_scope(scope):
         x = x + jax.vmap(mix)(rms(x, lp[prefix + "norm1"], eps), seg)
-    h = rms(x, lp[prefix + "norm2"], eps).reshape(-1, x.shape[-1])
-    if ffn == "dense":
-        with jax.named_scope("mlp"):
-            y = swiglu(h, lp[prefix + "mlp_gate"], lp[prefix + "mlp_up"],
-                       lp[prefix + "mlp_down"])
-        counts = jnp.zeros((config.num_experts,), jnp.int32)
-    else:
-        y, counts = expert_ffn(lp, prefix, h, bias, config, initializing)
-    return x + y.reshape(x.shape), counts
+    return packed_decoder.feed_forward(
+        lp, prefix, ffn, x, bias, eps, routing(config), shared=True,
+        scopes=scopes)
 
 
-def hidden_states(params, bias, tokens, seg, config: Config,
-                  initializing: bool = False):
-    """``(x, counts)``: the hidden states before the last norm (B, T, D) and
-    the tokens that chose each expert, (expert layers, E) int32 in forward
-    order.  ``bias`` (expert layers, E) enters the choice."""
-    import jax
-    import jax.numpy as jnp
-
-    x = jnp.take(params["embed"], tokens, axis=0).astype(
-        jnp.dtype(config.dtype))
-    counts = []
-    for prefix, mixer, ffn in layer_kinds(config):
-        mine = {k: v for k, v in params.items() if k.startswith(prefix)}
-        row = bias[len(counts)] if ffn == "experts" else None
-        # a layer is made again in the backward pass, but for what its
-        # recurrence names: the scan is not run a second time for them
-        x, c = jax.checkpoint(
-            functools.partial(_layer, mixer, ffn, prefix, config,
-                              initializing),
-            policy=jax.checkpoint_policies.save_only_these_names(*SAVED))(
-                mine, x, seg, row)
-        if ffn == "experts":
-            counts.append(c)
-    return x, jnp.stack(counts) if counts else jnp.zeros(
-        (0, config.num_experts), jnp.int32)
-
-
-def _logits(params, x, config: Config):
+def logits(params, x, config: Config):
+    """The untied head on states ``x`` (N, D): float32 (N, V)."""
     import jax.numpy as jnp
 
     h = rms(x, params["final_norm"], config.rms_norm_eps)
     return mm("td,vd->tv", h, params["head"], h.dtype, out=jnp.float32)
-
-
-def apply_tokens(params, bias, tokens, segment_ids, config: Config,
-                 initializing: bool = False):
-    """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits of the untied head.  ``initializing`` is the calling
-    module's ``is_initializing()`` (``moe.routed_experts`` reads it)."""
-    import jax
-
-    x, _ = hidden_states(params, bias, tokens, segment_ids, config,
-                         initializing)
-    with jax.named_scope("lm_head"):
-        return jax.vmap(lambda xr: _logits(params, xr, config))(x)
-
-
-def loss_terms(params, bias, tokens, segment_ids, config: Config):
-    """``(sum of the cross-entropies, positions counted, counts)`` of a
-    batch of packed rows: position ``t`` is scored against ``u_{t+1}`` where
-    that is the same document's; the logits exist a block of tokens at a
-    time."""
-    import jax
-    import jax.numpy as jnp
-
-    x, counts = hidden_states(params, bias, tokens, segment_ids, config)
-
-    def row(xr, u, s):
-        valid = loss_positions(s)
-        return blocked_cross_entropy(
-            xr, lambda xb: _logits(params, xb, config), jnp.roll(u, -1),
-            valid, config.loss_block), jnp.sum(valid)
-
-    with jax.named_scope("lm_head"):
-        total, count = jax.vmap(row)(x, tokens, segment_ids)
-    return jnp.sum(total), jnp.sum(count), counts
 
 
 # ---------------------------------------------------------------------------
@@ -749,96 +621,46 @@ def loss_terms(params, bias, tokens, segment_ids, config: Config):
 # ---------------------------------------------------------------------------
 
 
-def make_model(config: Config, mesh=None):
+def _init(config: Config):
+    """``(name, shape) ->`` a leaf's initializer: unit norms, normal
+    matrices, the taps as PyTorch's ``Conv1d`` leaves them, ``A_log`` and
+    ``dt_bias`` as the public code and Mamba-2 draw them."""
     import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
 
-    shapes, state = leaf_shapes(config), collection_shapes(config)
-    ones = nn.initializers.ones
-    normal = nn.initializers.normal(config.init_std)
-    # the matrices that write into the residual stream start smaller, by
-    # the layers that add to it (``mla_moe.make_model`` says why a seeded
-    # router needs it)
-    out = nn.initializers.normal(config.init_std / math.sqrt(
-        2 * max(config.num_hidden_layers, 1)))
-
-    def taps(key, shape, dtype):    # as PyTorch's ``Conv1d`` leaves them
-        bound = 1.0 / math.sqrt(config.short_conv_kernel_size)
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-    def a_log(key, shape, dtype):   # as the public code and Mamba-2 draw it
-        return jnp.log(jax.random.uniform(key, shape, dtype, *A_RANGE))
-
-    def dt_bias(key, shape, dtype):     # softplus(dt_bias) log-uniform
-        dt = jnp.exp(jax.random.uniform(
-            key, shape, dtype, *(math.log(b) for b in DT_RANGE)))
-        return dt + jnp.log(-jnp.expm1(-dt))
+    normal, out = packed_decoder.normals(config.init_std,
+                                         config.num_hidden_layers)
+    taps = packed_decoder.conv_taps(config.short_conv_kernel_size)
 
     def init(name, shape):
         if name.endswith("_A_log"):
-            return a_log
+            return packed_decoder.a_log
         if name.endswith("_dt_bias"):
-            return dt_bias
+            return packed_decoder.dt_bias
         if len(shape) == 1:
-            return ones
+            return nn.initializers.ones
         if name.endswith("_conv"):
             return taps
         return out if name.endswith(("_wo", "_down")) else normal
 
-    class KimiLinear(nn.Module):
-        @nn.compact
-        def __call__(self, tokens, segment_ids):
-            params = {name: self.param(name, init(name, shape), shape,
-                                       jnp.float32)
-                      for name, shape in shapes.items()}
-            bias = self.variable(
-                COLLECTION, "bias", jnp.zeros, *state["bias"]).value
-            for name in ("counts", "busiest", "overflow"):
-                self.variable(COLLECTION, name, jnp.zeros, *state[name])
-            return apply_tokens(params, bias, tokens, segment_ids, config,
-                                initializing=self.is_initializing())
-
-    return KimiLinear()
+    return init
 
 
-def make_optimizer(config: Config, learning_rate: float):
-    import optax
-
-    return optax.adamw(learning_rate, **ADAMW)
-
-
-def make_loss_fn(module, config: Config):
-    """``loss(params, collections, batch) -> (loss, new collections)``: the
-    mean next-token cross-entropy over the positions whose next token is
-    the same document's; the ``moe`` collection moves on a step."""
-    import jax.numpy as jnp
-
-    from tensorflowonspark_tpu.parallel import moe
-
-    def loss_fn(params, collections, batch):
-        state = collections[COLLECTION]
-        total, count, counts = loss_terms(
-            params, state["bias"], batch["tokens"], batch["segment_ids"],
-            config)
-        return total / jnp.maximum(count, 1), {
-            **collections, COLLECTION: moe.step_routing_state(
-                state, counts, config.experts_held,
-                top_k=config.num_experts_per_token,
-                speed=config.bias_update_speed,
-                tokens=batch["tokens"].size)}
-
-    loss_fn.stateful = True
-    return loss_fn
-
-
-def make_forward_fn(module, config: Config):
-    def forward(params, collections, batch):
-        return apply_tokens(params, collections[COLLECTION]["bias"],
-                            batch["tokens"], batch["segment_ids"], config)
-
-    forward.stateful = True
-    return forward
+#: a layer is made again in the backward pass, but for what its recurrence
+#: names (``SAVED``): the scan is not run a second time for them
+_DECODER = packed_decoder.Decoder(
+    adamw=ADAMW, leaf_shapes=leaf_shapes, layers=layer_kinds, layer=_layer,
+    logits=logits, init=_init, routing=routing, saved=SAVED)
+collection_shapes = _DECODER.collection_shapes
+hidden_states = _DECODER.hidden_states
+apply_tokens = _DECODER.apply_tokens
+loss_terms = _DECODER.next_token_terms
+make_model = _DECODER.make_model
+make_optimizer = _DECODER.make_optimizer
+make_loss_fn = _DECODER.make_loss_fn
+make_forward_fn = _DECODER.make_forward_fn
+device_counters = _DECODER.device_counters
+parameter_count = _DECODER.parameter_count
+example_batch = _DECODER.example_batch
 
 
 def batch_counters(batch, config: Config) -> dict:
@@ -855,38 +677,17 @@ def batch_counters(batch, config: Config) -> dict:
     seg = np.asarray(batch["segment_ids"])
     mixers = [mixer for _, mixer, _ in layer_kinds(config)]
     scans = "kda" in mixers
-    fused = scans and kda_scan_runs_fused(
-        config.kda_chunk, config.kda_num_heads, config.kda_head_dim,
-        config.kda_head_dim)
     return {**row_counters(seg, config.qk_head_dim,
                            "full_attention" in mixers, config.v_head_dim,
                            conv=(config.kda_width,
                                  config.short_conv_kernel_size)
                            if scans else None),
             **moe.grouped_step_counters(
-                seg.size, config.num_experts_per_token,
-                len(config.experts_held), config.num_experts,
-                config.hidden_size, config.moe_intermediate_size,
-                config.dtype),
+                seg.size, routing(config), config.hidden_size,
+                config.moe_intermediate_size, config.dtype),
             "kda_chunks_total": int(
                 seg.shape[0] * -(-seg.shape[1] // config.kda_chunk)
                 * config.kda_num_heads * mixers.count("kda")),
-            "kda_scan_fused_steps_total": int(fused),
-            "kda_scan_plain_steps_total": int(scans and not fused)}
-
-
-def device_counters(collections, config: Config) -> dict:
-    """What the device decided, for the program's counters
-    (``moe.routing_counters`` of the ``moe`` collection)."""
-    from tensorflowonspark_tpu.parallel import moe
-
-    return moe.routing_counters(collections[COLLECTION],
-                                config.experts_held)
-
-
-def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
-                  seq_len: int | None = None):
-    """Packed rows of two documents each, ``seq_len`` tokens (at most 64
-    unless told: a step compiles at the shape it is fed)."""
-    return example_rows(config.vocab_size, batch_size, seed,
-                        int(seq_len or min(config.seq_len, 64)))
+            **step_counters("kda_scan", kda_scan_runs_fused(
+                config.kda_chunk, config.kda_num_heads, config.kda_head_dim,
+                config.kda_head_dim), scans)}

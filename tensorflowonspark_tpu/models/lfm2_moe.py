@@ -29,23 +29,14 @@ differ), ``p_t`` the index of token ``t`` inside its document::
     every expert layer, once a step:  c_e = tokens that chose e;
                 b_e += bias_update_speed * sign(mean(c) - c_e)
 
-``Config.experts_held`` says which of the ``num_experts`` this chip holds
-(all of them unless told otherwise): the router stays as wide as published,
-the held experts' part of the result is computed
-(``parallel/moe.py::routed_experts``) and what the others would have added is
-left out.  No exchange runs and none is stood in for.  The correction biases
-and the counts behind them are the ``moe`` collection
-(``moe.routing_state_shapes``), as ``mla_moe``'s.
-
 Nothing here is this model's alone but the two mixers' wiring: the norm, the
-products, the SwiGLU, the convolution, the positions, the rotation, the
-attention and the blocked loss are ``packed_rows``'s (``granite_hybrid`` and
-``mla_moe`` call them too), the routed layer and the routing state
-``parallel/moe.py``'s.  Parameters are float32, activations
-``Config.dtype``; every layer is recomputed in the backward pass, attention
-runs a block of queries at a time and the loss a block of tokens at a time;
-none of the three is an option.  The published heads of 64 half-fill a row
-of lanes, so attention runs as ``jnp`` code on every backend
+products, the convolution, the rotation, the attention and the blocked loss
+are ``packed_rows``'s, the routed layer and the routing state (the ``moe``
+collection) ``parallel/moe.py``'s, the layer loop, the feed-forward half of
+a layer, the positions and the registry's surface ``packed_decoder``'s,
+whose docstring says what holds for every such decoder
+(``Config.experts_held`` among it).  The published heads of 64 half-fill a
+row of lanes, so attention runs as ``jnp`` code on every backend
 (``packed_rows.attention_runs_fused``) and a step says so
 (``attention_plain_steps_total``).
 
@@ -54,23 +45,18 @@ of lanes, so attention runs as ``jnp`` code on every backend
 ``conv_out_proj``; ``attention`` > ``qk_norm_rope``; ``mlp`` (the dense
 feed-forward); ``moe_router``, ``moe_dispatch``, ``moe_experts``,
 ``moe_combine`` (``routed_experts``'); ``lm_head``.
-
-The flax module only registers the parameters and the collection (flat
-dicts); the mathematics is in pure functions over them.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import numpy as np
 
+from tensorflowonspark_tpu.models import packed_decoder
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, blocked_cross_entropy, causal_conv, document_attention,
-    document_positions, example_rows, loss_positions, mm, rms, rope,
-    row_counters, swiglu)
+    block, causal_conv, document_attention, mm, rms, rope, row_counters)
 
 #: no sequence-parallel sharding: the convolution has no halo over ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -78,8 +64,8 @@ SEQUENCE_AXES: dict = {}
 #: the recipe :func:`make_optimizer` builds (a continued-pre-training AdamW)
 ADAMW = {"b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1}
 
-#: the collection of non-gradient state (``parallel/moe.py``'s)
-COLLECTION = "moe"
+#: the collection of non-gradient state (``packed_decoder.COLLECTION``)
+COLLECTION = packed_decoder.COLLECTION
 
 #: the published pattern: two dense ``conv`` layers, then ``A c c c`` four
 #: times and ``A c c`` twice
@@ -157,7 +143,6 @@ def layer_kinds(config: Config) -> list:
 def leaf_shapes(config: Config) -> dict:
     """Name -> shape of every parameter, in forward order."""
     d, hd = config.hidden_size, config.head_dim
-    f, held = config.moe_intermediate_size, len(config.experts_held)
     out = {"embed": (config.vocab_size, d)}
     for p, mixer, ffn in layer_kinds(config):
         out[p + "norm1"] = (d,)
@@ -173,28 +158,24 @@ def leaf_shapes(config: Config) -> dict:
             out[p + "k_norm"] = (hd,)
             out[p + "wo"] = (config.num_attention_heads * hd, d)
         out[p + "norm2"] = (d,)
-        if ffn == "dense":
-            out[p + "mlp_gate"] = (d, config.intermediate_size)
-            out[p + "mlp_up"] = (d, config.intermediate_size)
-            out[p + "mlp_down"] = (config.intermediate_size, d)
-        else:
-            out[p + "router"] = (d, config.num_experts)
-            out[p + "experts_gate"] = (held, d, f)
-            out[p + "experts_up"] = (held, d, f)
-            out[p + "experts_down"] = (held, f, d)
+        out.update(packed_decoder.ffn_leaf_shapes(
+            p, ffn, d, config.intermediate_size, config.moe_intermediate_size,
+            routing(config)))
     out["final_norm"] = (d,)
     return out
 
 
-def parameter_count(config: Config) -> int:
-    return sum(int(np.prod(s)) for s in leaf_shapes(config).values())
-
-
-def collection_shapes(config: Config) -> dict:
-    """The ``moe`` collection: a row an expert layer, in forward order."""
+def routing(config: Config):
+    """This layout's routed layers, as ``parallel/moe.py`` names them; the
+    correction bias stays where ``use_expert_bias`` is off."""
     from tensorflowonspark_tpu.parallel import moe
 
-    return moe.routing_state_shapes(config.num_experts, config.expert_layers)
+    return moe.Routing(
+        n_experts=config.num_experts, layers=config.expert_layers,
+        held=config.experts_held, top_k=config.num_experts_per_tok,
+        scale=config.routed_scaling_factor,
+        normalize=config.norm_topk_prob, sum_eps=GATE_SUM_EPS,
+        speed=config.bias_update_speed if config.use_expert_bias else 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -202,12 +183,10 @@ def collection_shapes(config: Config) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def conv_mixer(params, prefix: str, h, seg, initializing: bool = False):
+def conv_mixer(params, prefix: str, h, seg):
     """The gated short convolution on one row: ``h`` (T, D) -> (T, D).  The
     gates and the convolution are float32 between the two products
-    (``c * conv(b * z)``, inside ``packed_rows.causal_conv``, which also
-    reads ``initializing``: the module is only learning its parameters from
-    this trace)."""
+    (``c * conv(b * z)``, inside ``packed_rows.causal_conv``)."""
     import jax
 
     dtype, d = h.dtype, h.shape[1]
@@ -217,8 +196,7 @@ def conv_mixer(params, prefix: str, h, seg, initializing: bool = False):
         b, c, z = (bcz[:, i * d:(i + 1) * d] for i in range(3))
         y = causal_conv(b, params[prefix + "conv_w"], 0.0, seg, times=z,
                         gate=c, out=dtype,
-                        scopes=("conv_mixer", "short_conv"),
-                        initializing=initializing)
+                        scopes=("conv_mixer", "short_conv"))
     with jax.named_scope("conv_out_proj"):
         return mm("te,ed->td", y, params[prefix + "out_proj"], dtype)
 
@@ -246,109 +224,35 @@ def attention(params, prefix: str, h, seg, pos, config: Config):
               dtype)
 
 
-def _layer(mixer: str, ffn: str, prefix: str, config: Config,
-           initializing: bool, lp, x, seg, pos, bias):
+def _layer(mixer: str, ffn: str, prefix: str, config: Config, scopes: tuple,
+           lp, x, seg, pos, bias):
     """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, counts)``;
-    ``counts`` is (E,) zeros for a dense layer.  ``initializing``: the
-    module is only learning its parameters from this trace
-    (``moe.routed_experts``)."""
+    ``counts`` is (E,) zeros for a dense layer.  ``bias`` (E,) enters the
+    experts' choice where ``use_expert_bias``."""
     import jax
     import jax.numpy as jnp
-
-    from tensorflowonspark_tpu.parallel import moe
 
     eps = config.norm_eps
     if mixer == "conv":
         scope, mix = "conv_mixer", lambda hr, sr, pr: conv_mixer(
-            lp, prefix, hr, sr, initializing)
+            lp, prefix, hr, sr)
     else:
         scope, mix = "attention", lambda hr, sr, pr: attention(
             lp, prefix, hr, sr, pr, config)
     with jax.named_scope(scope):
         x = x + jax.vmap(mix)(rms(x, lp[prefix + "norm1"], eps), seg, pos)
-    h = rms(x, lp[prefix + "norm2"], eps).reshape(-1, x.shape[-1])
-    if ffn == "dense":
-        with jax.named_scope("mlp"):
-            y = swiglu(h, lp[prefix + "mlp_gate"], lp[prefix + "mlp_up"],
-                       lp[prefix + "mlp_down"])
-        counts = jnp.zeros((config.num_experts,), jnp.int32)
-    else:
-        y, counts = moe.routed_experts(
-            h, lp[prefix + "router"], bias, lp[prefix + "experts_gate"],
-            lp[prefix + "experts_up"], lp[prefix + "experts_down"],
-            config.experts_held, top_k=config.num_experts_per_tok,
-            scale=config.routed_scaling_factor,
-            normalize=config.norm_topk_prob, sum_eps=GATE_SUM_EPS,
-            initializing=initializing)
-    return x + y.reshape(x.shape), counts
-
-
-def hidden_states(params, bias, tokens, seg, config: Config,
-                  initializing: bool = False):
-    """``(x, counts)``: the hidden states before the last norm (B, T, D) and
-    the tokens that chose each expert, (expert layers, E) int32 in forward
-    order.  ``bias`` (expert layers, E) enters the choice where
-    ``use_expert_bias``."""
-    import jax
-    import jax.numpy as jnp
-
-    pos = jax.vmap(document_positions)(seg)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(
-        jnp.dtype(config.dtype))
-    if not config.use_expert_bias:
+    if ffn == "experts" and not config.use_expert_bias:
         bias = jnp.zeros_like(bias)
-    counts = []
-    for prefix, mixer, ffn in layer_kinds(config):
-        mine = {k: v for k, v in params.items() if k.startswith(prefix)}
-        row = bias[len(counts)] if ffn == "experts" else None
-        x, c = jax.checkpoint(functools.partial(
-            _layer, mixer, ffn, prefix, config, initializing))(
-                mine, x, seg, pos, row)
-        if ffn == "experts":
-            counts.append(c)
-    return x, jnp.stack(counts) if counts else jnp.zeros(
-        (0, config.num_experts), jnp.int32)
+    return packed_decoder.feed_forward(lp, prefix, ffn, x, bias, eps,
+                                       routing(config), scopes=scopes)
 
 
-def _logits(params, x, config: Config):
+def logits(params, x, config: Config):
+    """The tied head on states ``x`` (N, D): float32 (N, V)."""
     import jax.numpy as jnp
 
     h = rms(x, params["final_norm"], config.norm_eps)
     return mm("td,vd->tv", h, params["embed"], h.dtype, out=jnp.float32)
-
-
-def apply_tokens(params, bias, tokens, segment_ids, config: Config,
-                 initializing: bool = False):
-    """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits of the tied head.  ``initializing`` is the calling
-    module's ``is_initializing()`` (``moe.routed_experts`` reads it)."""
-    import jax
-
-    x, _ = hidden_states(params, bias, tokens, segment_ids, config,
-                         initializing)
-    with jax.named_scope("lm_head"):
-        return jax.vmap(lambda xr: _logits(params, xr, config))(x)
-
-
-def loss_terms(params, bias, tokens, segment_ids, config: Config):
-    """``(sum of the cross-entropies, positions counted, counts)`` of a
-    batch of packed rows: position ``t`` is scored against ``u_{t+1}`` where
-    that is the same document's; the logits exist a block of tokens at a
-    time."""
-    import jax
-    import jax.numpy as jnp
-
-    x, counts = hidden_states(params, bias, tokens, segment_ids, config)
-
-    def row(xr, u, s):
-        valid = loss_positions(s)
-        return blocked_cross_entropy(
-            xr, lambda xb: _logits(params, xb, config), jnp.roll(u, -1),
-            valid, config.loss_block), jnp.sum(valid)
-
-    with jax.named_scope("lm_head"):
-        total, count = jax.vmap(row)(x, tokens, segment_ids)
-    return jnp.sum(total), jnp.sum(count), counts
 
 
 # ---------------------------------------------------------------------------
@@ -356,85 +260,39 @@ def loss_terms(params, bias, tokens, segment_ids, config: Config):
 # ---------------------------------------------------------------------------
 
 
-def make_model(config: Config, mesh=None):
+def _init(config: Config):
+    """``(name, shape) ->`` a leaf's initializer: unit norms, normal
+    matrices, the taps as PyTorch's ``Conv1d`` leaves them."""
     import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
 
-    shapes, state = leaf_shapes(config), collection_shapes(config)
-    ones = nn.initializers.ones
-    normal = nn.initializers.normal(config.init_std)
-    # the matrices that write into the residual stream start smaller, by
-    # the layers that add to it (``mla_moe.make_model`` says why a seeded
-    # router needs it)
-    out = nn.initializers.normal(config.init_std / math.sqrt(
-        2 * max(len(config.layer_types), 1)))
-
-    def taps(key, shape, dtype):    # as PyTorch's ``Conv1d`` leaves them
-        bound = 1.0 / math.sqrt(config.conv_L_cache)
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    normal, out = packed_decoder.normals(config.init_std,
+                                         len(config.layer_types))
+    taps = packed_decoder.conv_taps(config.conv_L_cache)
 
     def init(name, shape):
         if len(shape) == 1:
-            return ones
+            return nn.initializers.ones
         if name.endswith("_conv_w"):
             return taps
         return out if name.endswith(("_wo", "_out_proj", "_down")) else normal
 
-    class Lfm2Moe(nn.Module):
-        @nn.compact
-        def __call__(self, tokens, segment_ids):
-            params = {name: self.param(name, init(name, shape), shape,
-                                       jnp.float32)
-                      for name, shape in shapes.items()}
-            bias = self.variable(
-                COLLECTION, "bias", jnp.zeros, *state["bias"]).value
-            for name in ("counts", "busiest", "overflow"):
-                self.variable(COLLECTION, name, jnp.zeros, *state[name])
-            return apply_tokens(params, bias, tokens, segment_ids, config,
-                                initializing=self.is_initializing())
-
-    return Lfm2Moe()
+    return init
 
 
-def make_optimizer(config: Config, learning_rate: float):
-    import optax
-
-    return optax.adamw(learning_rate, **ADAMW)
-
-
-def make_loss_fn(module, config: Config):
-    """``loss(params, collections, batch) -> (loss, new collections)``: the
-    mean next-token cross-entropy over the positions whose next token is
-    the same document's; the ``moe`` collection moves on a step."""
-    import jax.numpy as jnp
-
-    from tensorflowonspark_tpu.parallel import moe
-
-    speed = config.bias_update_speed if config.use_expert_bias else 0.0
-
-    def loss_fn(params, collections, batch):
-        state = collections[COLLECTION]
-        total, count, counts = loss_terms(
-            params, state["bias"], batch["tokens"], batch["segment_ids"],
-            config)
-        return total / jnp.maximum(count, 1), {
-            **collections, COLLECTION: moe.step_routing_state(
-                state, counts, config.experts_held,
-                top_k=config.num_experts_per_tok, speed=speed,
-                tokens=batch["tokens"].size)}
-
-    loss_fn.stateful = True
-    return loss_fn
-
-
-def make_forward_fn(module, config: Config):
-    def forward(params, collections, batch):
-        return apply_tokens(params, collections[COLLECTION]["bias"],
-                            batch["tokens"], batch["segment_ids"], config)
-
-    forward.stateful = True
-    return forward
+_DECODER = packed_decoder.Decoder(
+    adamw=ADAMW, leaf_shapes=leaf_shapes, layers=layer_kinds, layer=_layer,
+    logits=logits, init=_init, routing=routing, positions=True)
+collection_shapes = _DECODER.collection_shapes
+hidden_states = _DECODER.hidden_states
+apply_tokens = _DECODER.apply_tokens
+loss_terms = _DECODER.next_token_terms
+make_model = _DECODER.make_model
+make_optimizer = _DECODER.make_optimizer
+make_loss_fn = _DECODER.make_loss_fn
+make_forward_fn = _DECODER.make_forward_fn
+device_counters = _DECODER.device_counters
+parameter_count = _DECODER.parameter_count
+example_batch = _DECODER.example_batch
 
 
 def batch_counters(batch, config: Config) -> dict:
@@ -451,24 +309,5 @@ def batch_counters(batch, config: Config) -> dict:
                            conv=(config.hidden_size, config.conv_L_cache)
                            if "conv" in config.layer_types else None),
             **moe.grouped_step_counters(
-                seg.size, config.num_experts_per_tok,
-                len(config.experts_held), config.num_experts,
-                config.hidden_size, config.moe_intermediate_size,
-                config.dtype)}
-
-
-def device_counters(collections, config: Config) -> dict:
-    """What the device decided, for the program's counters
-    (``moe.routing_counters`` of the ``moe`` collection)."""
-    from tensorflowonspark_tpu.parallel import moe
-
-    return moe.routing_counters(collections[COLLECTION],
-                                config.experts_held)
-
-
-def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
-                  seq_len: int | None = None):
-    """Packed rows of two documents each, ``seq_len`` tokens (at most 64
-    unless told: a step compiles at the shape it is fed)."""
-    return example_rows(config.vocab_size, batch_size, seed,
-                        int(seq_len or min(config.seq_len, 64)))
+                seg.size, routing(config), config.hidden_size,
+                config.moe_intermediate_size, config.dtype)}

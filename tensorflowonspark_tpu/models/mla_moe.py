@@ -33,59 +33,36 @@ document::
     every expert layer, once a step:  c_e = tokens that chose e;
               b_e += bias_update_speed * sign(mean(c) - c_e)
 
-``Config.experts_held`` says which of the ``n_routed_experts`` this chip
-holds (all of them unless told otherwise): the router stays as wide as
-published, the held experts' part of the result is computed
-(``parallel/moe.py::routed_experts``: every slot kept, the work sized to
-the rows that landed here) and what the others would have added is left
-out.  No exchange runs and none is stood in for.
-
-The correction biases take no gradient.  They live, with the cumulative
-count of tokens by expert, the cumulative size of each layer's fullest
-expert and the steps in which a layer's held slots overflowed
-``moe.prefix_rows``, in the ``moe`` collection, which the Trainer's stateful
-step threads and checkpoints; :func:`device_counters` names what of it the
-program's counters show.  (One data shard is what has run: on a
-data-parallel mesh the bucketed step averages the replicas' biases and
-keeps one replica's counts, ``ROADMAP.md`` B.)
-
-Parameters are float32, activations ``Config.dtype``.  Every layer is
-recomputed in the backward pass, attention runs a block of queries at a time
-and each loss a block of tokens at a time (``packed_rows``, shared with
-``granite_hybrid``); none of the three is an option.  On a TPU the published
-heads (20 x 256) run attention on the Pallas kernels of ``attention_pallas``
-(``packed_rows.attention_runs_fused`` is the rule), anywhere else and at
-``Config.tiny()`` as ``jnp`` code, and a step counts which applied
-(``attention_fused_steps_total`` / ``attention_plain_steps_total``).
-
-The latent attention itself is ``packed_rows.latent_attention`` (since PR
-43, when a second layout came to call it: ``kimi_linear``, with no query
-latent, no rotation and values narrower than its keys; this layout's keys
-and values happen to be one width, and the blocked attention no longer asks
-for that).
+``Config.experts_held`` are the experts this chip holds and the ``moe``
+collection what takes no gradient (the correction biases, the cumulative
+counts by expert, each layer's fullest expert and the steps in which a
+layer's held slots overflowed ``moe.prefix_rows``): ``packed_decoder``'s
+docstring has both, with what else holds for every packed-row decoder, and
+:func:`device_counters` names what of the collection the program's counters
+show.  On a TPU the published heads (20 x 256) run attention on the Pallas
+kernels of ``attention_pallas`` (``packed_rows.attention_runs_fused``),
+anywhere else and at ``Config.tiny()`` as ``jnp`` code, and a step counts
+which applied (``attention_fused_steps_total`` /
+``attention_plain_steps_total``).  The latent attention itself is
+``packed_rows.latent_attention``, which ``kimi_linear`` calls too (with no
+query latent, no rotation and values narrower than its keys).
 
 ``jax.named_scope`` names a device trace can be cut by: ``attention`` >
 ``mla_project`` (the latent projections, their norms, RoPE); ``mlp`` (the
 dense feed-forward); ``shared_expert``; ``moe_router``, ``moe_dispatch``,
 ``moe_experts`` (the grouped products), ``moe_combine``; ``mtp`` (the whole
 module but its head's loss); ``lm_head`` (both losses).
-
-The flax module only registers the parameters and the collection (flat
-dicts); the mathematics is in pure functions over them.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-import math
 
 import numpy as np
 
-from tensorflowonspark_tpu.models import packed_rows
+from tensorflowonspark_tpu.models import packed_decoder, packed_rows
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, blocked_cross_entropy, document_positions, example_rows,
-    loss_positions, mm, rms, row_counters, swiglu)
+    block, mm, rms, row_counters)
 
 #: no sequence-parallel sharding: attention sees a whole row
 SEQUENCE_AXES: dict = {}
@@ -93,8 +70,8 @@ SEQUENCE_AXES: dict = {}
 #: the recipe :func:`make_optimizer` builds (a continued-pre-training AdamW)
 ADAMW = {"b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1}
 
-#: the collection of non-gradient state (:func:`collection_shapes`)
-COLLECTION = "moe"
+#: the collection of non-gradient state (``packed_decoder.COLLECTION``)
+COLLECTION = packed_decoder.COLLECTION
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,7 +145,7 @@ def layer_prefixes(config: Config) -> list:
 def leaf_shapes(config: Config) -> dict:
     """Name -> shape of every parameter, in forward order."""
     d, heads = config.hidden_size, config.num_attention_heads
-    f, held = config.moe_intermediate_size, len(config.experts_held)
+    f = config.moe_intermediate_size
     out = {"embed": (config.vocab_size, d)}
     for p, kind in layer_prefixes(config):
         if p == "mtp_":
@@ -185,18 +162,9 @@ def leaf_shapes(config: Config) -> dict:
             config.qk_nope_head_dim + config.v_head_dim))
         out[p + "wo"] = (heads * config.v_head_dim, d)
         out[p + "norm2"] = (d,)
-        if kind == "dense":
-            out[p + "mlp_gate"] = (d, config.intermediate_size)
-            out[p + "mlp_up"] = (d, config.intermediate_size)
-            out[p + "mlp_down"] = (config.intermediate_size, d)
-        else:
-            out[p + "router"] = (d, config.n_routed_experts)
-            out[p + "shared_gate"] = (d, f * config.n_shared_experts)
-            out[p + "shared_up"] = (d, f * config.n_shared_experts)
-            out[p + "shared_down"] = (f * config.n_shared_experts, d)
-            out[p + "experts_gate"] = (held, d, f)
-            out[p + "experts_up"] = (held, d, f)
-            out[p + "experts_down"] = (held, f, d)
+        out.update(packed_decoder.ffn_leaf_shapes(
+            p, kind, d, config.intermediate_size, f, routing(config),
+            shared=f * config.n_shared_experts))
         if p == "mtp_":
             out[p + "head_norm"] = (d,)
     out["final_norm"] = (d,)
@@ -204,16 +172,15 @@ def leaf_shapes(config: Config) -> dict:
     return out
 
 
-def parameter_count(config: Config) -> int:
-    return sum(int(np.prod(s)) for s in leaf_shapes(config).values())
-
-
-def collection_shapes(config: Config) -> dict:
-    """The ``moe`` collection: a row an expert layer, in forward order."""
+def routing(config: Config):
+    """This layout's routed layers, as ``parallel/moe.py`` names them."""
     from tensorflowonspark_tpu.parallel import moe
 
-    return moe.routing_state_shapes(config.n_routed_experts,
-                                    config.expert_layers)
+    return moe.Routing(
+        n_experts=config.n_routed_experts, layers=config.expert_layers,
+        held=config.experts_held, top_k=config.num_experts_per_tok,
+        scale=config.routed_scaling_factor, normalize=config.norm_topk_prob,
+        speed=config.bias_update_speed)
 
 
 # ---------------------------------------------------------------------------
@@ -235,36 +202,11 @@ def latent_attention(params, prefix: str, h, seg, pos, config: Config,
         q_rank=config.q_lora_rank, theta=config.rope_theta, scopes=scopes)
 
 
-def expert_ffn(params, prefix: str, h, bias, config: Config,
-               initializing: bool = False, scopes: tuple = ()):
-    """The shared expert and the held routed experts on tokens ``h`` (N, D).
-    Returns ``(y, counts)``, ``counts`` (E,) the tokens that chose each of
-    the router's experts.  ``initializing``: the module is only learning its
-    parameters from this trace; ``scopes``: the named scopes the layer sits
-    under (both go to ``moe.routed_experts``)."""
-    import jax
-
-    from tensorflowonspark_tpu.parallel import moe
-
-    with jax.named_scope("shared_expert"):
-        y = swiglu(h, params[prefix + "shared_gate"],
-                   params[prefix + "shared_up"],
-                   params[prefix + "shared_down"])
-    routed, counts = moe.routed_experts(
-        h, params[prefix + "router"], bias, params[prefix + "experts_gate"],
-        params[prefix + "experts_up"], params[prefix + "experts_down"],
-        config.experts_held, top_k=config.num_experts_per_tok,
-        scale=config.routed_scaling_factor, normalize=config.norm_topk_prob,
-        initializing=initializing, scopes=scopes)
-    return y + routed, counts
-
-
-def _layer(kind: str, prefix: str, config: Config, scopes: tuple,
-           initializing: bool, lp, x, seg, pos, bias):
+def _layer(kind: str, prefix: str, config: Config, scopes: tuple, lp, x, seg,
+           pos, bias):
     """One layer on a batch of rows: ``x`` (B, T, D) -> ``(x, counts)``;
     ``counts`` is (E,) zeros for a dense layer."""
     import jax
-    import jax.numpy as jnp
 
     eps = config.rms_norm_eps
     with jax.named_scope("attention"):
@@ -272,49 +214,9 @@ def _layer(kind: str, prefix: str, config: Config, scopes: tuple,
             lambda hr, sr, pr: latent_attention(
                 lp, prefix, hr, sr, pr, config, scopes + ("attention",))
         )(rms(x, lp[prefix + "norm1"], eps), seg, pos)
-    h = rms(x, lp[prefix + "norm2"], eps)
-    if kind == "dense":
-        with jax.named_scope("mlp"):
-            y = swiglu(h.reshape(-1, h.shape[-1]), lp[prefix + "mlp_gate"],
-                       lp[prefix + "mlp_up"], lp[prefix + "mlp_down"])
-        counts = jnp.zeros((config.n_routed_experts,), jnp.int32)
-    else:
-        y, counts = expert_ffn(lp, prefix, h.reshape(-1, h.shape[-1]), bias,
-                               config, initializing, scopes)
-    return x + y.reshape(x.shape), counts
-
-
-def _run_layer(params, prefix, kind, x, seg, pos, bias, config: Config,
-               scopes: tuple = (), initializing: bool = False):
-    import jax
-
-    mine = {k: v for k, v in params.items() if k.startswith(prefix)}
-    return jax.checkpoint(functools.partial(
-        _layer, kind, prefix, config, scopes, initializing))(
-            mine, x, seg, pos, bias)
-
-
-def hidden_states(params, bias, tokens, seg, config: Config,
-                  initializing: bool = False):
-    """``(x, pos, counts)``: the main model's hidden states before the
-    last norm (B, T, D), the positions inside documents, and a (E,) count a
-    main expert layer (a list, forward order)."""
-    import jax
-    import jax.numpy as jnp
-
-    pos = jax.vmap(document_positions)(seg)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(
-        jnp.dtype(config.dtype))
-    counts = []
-    for prefix, kind in layer_prefixes(config):
-        if prefix == "mtp_":
-            continue
-        row = bias[len(counts)] if kind == "experts" else None
-        x, c = _run_layer(params, prefix, kind, x, seg, pos, row, config,
-                          initializing=initializing)
-        if kind == "experts":
-            counts.append(c)
-    return x, pos, counts
+    return packed_decoder.feed_forward(
+        lp, prefix, kind, x, bias, eps, routing(config), shared=True,
+        scopes=scopes)
 
 
 def prediction_states(params, bias_row, x_normed, tokens, seg, pos,
@@ -334,52 +236,32 @@ def prediction_states(params, bias_row, x_normed, tokens, seg, pos,
             [rms(nxt, params["mtp_enorm"], eps),
              rms(x_normed, params["mtp_hnorm"], eps)], axis=-1)
         h = mm("bte,ed->btd", both, params["mtp_eh_proj"], both.dtype)
-        return _run_layer(params, "mtp_", "experts", h, seg, pos, bias_row,
-                          config, scopes=("mtp",))
+        return packed_decoder.run_layer(
+            _layer, params, "mtp_", ("experts",), config, h, seg, pos,
+            bias_row, scopes=("mtp",))
 
 
-def _head(params, norm: str, config: Config):
+def logits(params, x, config: Config, norm: str = "final_norm"):
+    """The untied head on states ``x`` (N, D) normed by ``norm``: float32
+    (N, V)."""
     import jax.numpy as jnp
 
-    def logits(x):
-        h = rms(x, params[norm], config.rms_norm_eps)
-        return mm("td,vd->tv", h, params["head"], h.dtype, out=jnp.float32)
-
-    return logits
-
-
-def apply_tokens(params, bias, tokens, segment_ids, config: Config,
-                 initializing: bool = False):
-    """Teacher-forced forward: (B, T) tokens and segment ids -> (B, T, V)
-    float32 logits of the main head.  ``initializing`` is the calling
-    module's ``is_initializing()`` (``moe.routed_experts`` reads it)."""
-    import jax
-
-    x, _, _ = hidden_states(params, bias, tokens, segment_ids, config,
-                            initializing)
-    with jax.named_scope("lm_head"):
-        return jax.vmap(_head(params, "final_norm", config))(x)
+    h = rms(x, params[norm], config.rms_norm_eps)
+    return mm("td,vd->tv", h, params["head"], h.dtype, out=jnp.float32)
 
 
 def loss_terms(params, bias, tokens, segment_ids, config: Config):
     """``(main sum, main positions, mtp sum, mtp positions, counts)`` of a
     batch of packed rows; ``counts`` (expert layers, E) int32, a row an
     expert layer in forward order, the prediction module's last."""
-    import jax
     import jax.numpy as jnp
 
     x, pos, counts = hidden_states(params, bias, tokens, segment_ids, config)
 
     def sums(states, norm, ahead):
-        def row(xr, u, s):
-            valid = loss_positions(s, ahead)
-            return blocked_cross_entropy(
-                xr, _head(params, norm, config), jnp.roll(u, -ahead), valid,
-                config.loss_block), jnp.sum(valid)
-
-        with jax.named_scope("lm_head"):
-            total, count = jax.vmap(row)(states, tokens, segment_ids)
-        return jnp.sum(total), jnp.sum(count)
+        return packed_decoder.loss_sums(
+            lambda xb: logits(params, xb, config, norm), states, tokens,
+            segment_ids, config.loss_block, ahead)
 
     main = sums(x, "final_norm", 1)
     if not config.num_nextn_predict_layers:
@@ -391,95 +273,45 @@ def loss_terms(params, bias, tokens, segment_ids, config: Config):
     return (*main, *sums(h, "mtp_head_norm", 2), jnp.stack(counts + [c]))
 
 
-def step_collection(collection: dict, counts, config: Config,
-                    tokens: int) -> dict:
-    """The ``moe`` collection after a step whose ``tokens`` tokens chose
-    ``counts`` (expert layers, E): ``moe.step_routing_state`` at this
-    configuration's experts held, choices a token and bias speed."""
-    from tensorflowonspark_tpu.parallel import moe
-
-    return moe.step_routing_state(
-        collection, counts, config.experts_held,
-        top_k=config.num_experts_per_tok, speed=config.bias_update_speed,
-        tokens=tokens)
-
-
 # ---------------------------------------------------------------------------
 # The zoo's surface
 # ---------------------------------------------------------------------------
 
 
-def make_model(config: Config, mesh=None):
+def _init(config: Config):
+    """``(name, shape) ->`` a leaf's initializer: unit norms, normal
+    matrices."""
     import flax.linen as nn
-    import jax.numpy as jnp
 
-    shapes, state = leaf_shapes(config), collection_shapes(config)
-    ones = nn.initializers.ones
-    normal = nn.initializers.normal(config.init_std)
-    # the matrices that write into the residual stream start smaller, by
-    # the layers that add to it (GPT-2's and Megatron-LM's scaled
-    # initialisation): at one size for all, every token's hidden state is
-    # one shared vector after a layer and the router sends a row's tokens
-    # to the same few experts
-    out = nn.initializers.normal(config.init_std / math.sqrt(
-        2 * max(config.num_hidden_layers, 1)))
+    normal, out = packed_decoder.normals(config.init_std,
+                                         config.num_hidden_layers)
 
     def init(name, shape):
         if len(shape) == 1:
-            return ones
+            return nn.initializers.ones
         return out if name.endswith(("_wo", "_down")) else normal
 
-    class MlaMoe(nn.Module):
-        @nn.compact
-        def __call__(self, tokens, segment_ids):
-            params = {name: self.param(name, init(name, shape), shape,
-                                       jnp.float32)
-                      for name, shape in shapes.items()}
-            bias = self.variable(
-                COLLECTION, "bias", jnp.zeros, *state["bias"]).value
-            for name in ("counts", "busiest", "overflow"):
-                self.variable(COLLECTION, name, jnp.zeros, *state[name])
-            return apply_tokens(params, bias, tokens, segment_ids, config,
-                                initializing=self.is_initializing())
-
-    return MlaMoe()
+    return init
 
 
-def make_optimizer(config: Config, learning_rate: float):
-    import optax
-
-    return optax.adamw(learning_rate, **ADAMW)
-
-
-def make_loss_fn(module, config: Config):
-    """``loss(params, collections, batch) -> (loss, new collections)``: the
-    mean next-token cross-entropy plus ``mtp_loss_weight`` times the mean
-    cross-entropy of the token after, each over the positions whose targets
-    are the same document's; the ``moe`` collection moves on a step."""
-    import jax.numpy as jnp
-
-    def loss_fn(params, collections, batch):
-        state = collections[COLLECTION]
-        main, n_main, mtp, n_mtp, counts = loss_terms(
-            params, state["bias"], batch["tokens"], batch["segment_ids"],
-            config)
-        loss = (main / jnp.maximum(n_main, 1)
-                + config.mtp_loss_weight * mtp / jnp.maximum(n_mtp, 1))
-        return loss, {**collections,
-                      COLLECTION: step_collection(
-                          state, counts, config, batch["tokens"].size)}
-
-    loss_fn.stateful = True
-    return loss_fn
-
-
-def make_forward_fn(module, config: Config):
-    def forward(params, collections, batch):
-        return apply_tokens(params, collections[COLLECTION]["bias"],
-                            batch["tokens"], batch["segment_ids"], config)
-
-    forward.stateful = True
-    return forward
+#: the loop runs the main model's layers; :func:`loss_terms` the module's
+_DECODER = packed_decoder.Decoder(
+    adamw=ADAMW, leaf_shapes=leaf_shapes, layer=_layer, logits=logits,
+    layers=lambda config: [
+        layer for layer in layer_prefixes(config) if layer[0] != "mtp_"],
+    init=_init, routing=routing, loss_terms=loss_terms, positions=True,
+    later_weights=lambda config: (config.mtp_loss_weight,))
+collection_shapes = _DECODER.collection_shapes
+hidden_states = _DECODER.hidden_states
+apply_tokens = _DECODER.apply_tokens
+step_collection = _DECODER.step_collection
+make_model = _DECODER.make_model
+make_optimizer = _DECODER.make_optimizer
+make_loss_fn = _DECODER.make_loss_fn
+make_forward_fn = _DECODER.make_forward_fn
+device_counters = _DECODER.device_counters
+parameter_count = _DECODER.parameter_count
+example_batch = _DECODER.example_batch
 
 
 def batch_counters(batch, config: Config) -> dict:
@@ -496,27 +328,8 @@ def batch_counters(batch, config: Config) -> dict:
     return {**row_counters(seg, config.qk_head_dim,
                            v_head_dim=config.v_head_dim),
             **moe.grouped_step_counters(
-                seg.size, config.num_experts_per_tok,
-                len(config.experts_held), config.n_routed_experts,
-                config.hidden_size, config.moe_intermediate_size,
-                config.dtype),
+                seg.size, routing(config), config.hidden_size,
+                config.moe_intermediate_size, config.dtype),
             "mtp_loss_tokens_total": int(
                 (same[:, 1:] & same[:, :-1]).sum()
                 if config.num_nextn_predict_layers else 0)}
-
-
-def device_counters(collections, config: Config) -> dict:
-    """What the device decided, for the program's counters
-    (``moe.routing_counters`` of the ``moe`` collection)."""
-    from tensorflowonspark_tpu.parallel import moe
-
-    return moe.routing_counters(collections[COLLECTION],
-                                config.experts_held)
-
-
-def example_batch(config: Config, batch_size: int = 8, seed: int = 0,
-                  seq_len: int | None = None):
-    """Packed rows of two documents each, ``seq_len`` tokens (at most 64
-    unless told: a step compiles at the shape it is fed)."""
-    return example_rows(config.vocab_size, batch_size, seed,
-                        int(seq_len or min(config.seq_len, 64)))

@@ -1,15 +1,14 @@
-"""What the decoders trained on packed rows have in common
-(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``): the RMS
-norm, the product with operands in the activations' type, the SwiGLU
-feed-forward, the positions inside documents and the rotary embedding at
-them, the depthwise causal convolution that stops at a document's first
-token, causal attention inside documents a block of queries at a time (the
-values at their own width), latent attention over it, and the next-token
-cross-entropy a block of tokens at a time.
-
-A packed row is ``T`` tokens with segment ids ``s`` (the document's number
-inside the row; documents are contiguous and their ids differ).  One
-implementation of each piece, each called by two models or more
+"""The mathematics the decoders trained on packed rows have in common
+(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``;
+``packed_decoder`` holds their skeleton): the RMS norm, the product with
+operands in the activations' type, the SwiGLU feed-forward, the positions
+inside documents and the rotary embedding at them, the depthwise causal
+convolution that stops at a document's first token, causal attention inside
+documents a block of queries at a time (the values at their own width),
+latent attention over it, and the next-token cross-entropy a block of
+tokens at a time.  A packed row is ``T`` tokens with segment ids ``s`` (the
+document's number inside the row; documents are contiguous and their ids
+differ).  One implementation of each piece, each called by two models or more
 (:func:`causal_conv`: granite's state-space mixers, LFM2's gated short
 convolutions and Kimi Linear's delta-rule mixers; :func:`rope` and
 :func:`document_positions`: GLM's latent attention and LFM2's grouped-query
@@ -17,27 +16,18 @@ attention; :func:`latent_attention`: GLM's, with a query latent and RoPE, and
 Kimi Linear's, with neither): what is measured on one model's cell is what
 the others run.
 
-Attention is one algorithm with two executions
-(:func:`document_attention`): on a TPU, where a head fills whole rows of
-128 lanes (GLM's 20 x 256 do; granite's and LFM2's 32/8 x 64 and Kimi
-Linear's keys of 192 beside values of 128 do not), the
-Pallas kernels of ``attention_pallas`` keep every (queries x keys) score
-tile on the chip, forward and backward; on any other backend and at other
-shapes (``Config.tiny()``, the tests) the ``jnp`` form in this file runs,
-which is also the kernels' oracle.  :func:`attention_runs_fused` is the
-rule, and the models' steps count which applied
-(``attention_fused_steps_total`` / ``attention_plain_steps_total``).
-
-The convolution is one algorithm with two executions too
-(:func:`causal_conv`): on a TPU, where the channels are whole rows of 128
-lanes, the row of tokens whole tiles of the kernels' own and the module is
-not initialising (the published 8,192 x 4,352, x 4,096 and x 2,048 are;
-``Config.tiny()``'s are not), the Pallas kernels of ``conv_pallas`` read a
-tile of the row once, keep the ``K - 1`` rows before it and write once what
-the callers apply to the convolution (SiLU, a gate, the cast), forward and
-backward; anywhere else the ``K`` shifted sums in this file run, which are
-also the kernels' oracle.  :func:`conv_runs_fused` is the rule
-(``conv_fused_steps_total`` / ``conv_plain_steps_total``).
+Attention and the convolution are each one algorithm with two executions
+(:func:`document_attention`, :func:`causal_conv`): on a TPU at shapes that
+fill their tiles (a head in whole rows of 128 lanes — GLM's 20 x 256, not
+granite's and LFM2's 32/8 x 64 nor Kimi Linear's keys of 192 beside values
+of 128 —; the channels in whole rows of lanes and the row in whole tiles:
+the published 8,192 x 4,352, x 4,096 and x 2,048) the Pallas kernels of
+``attention_pallas`` and ``conv_pallas``, anywhere else (``Config.tiny()``,
+the tests) the ``jnp`` forms in this file, which are also the kernels'
+oracles.  :func:`attention_runs_fused` and :func:`conv_runs_fused` are the
+rules (``kernels.runs_fused``), and the models' steps count which applied
+(``attention_fused_steps_total`` / ``attention_plain_steps_total``,
+``conv_fused_steps_total`` / ``conv_plain_steps_total``).
 
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
@@ -51,6 +41,8 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 
 
 def rms(x, w, eps):
@@ -77,30 +69,16 @@ def block(total: int, want: int) -> int:
     return next(b for b in range(min(want, total), 0, -1) if total % b == 0)
 
 
-def _backend() -> str:
-    """The backend the process computes on (a compile test for a described
-    chip, on a CPU host, says "tpu" here)."""
-    import jax
-
-    return jax.default_backend()
-
-
 def attention_runs_fused(t: int, hd: int, vd: int | None = None) -> bool:
-    """How :func:`document_attention` executes on a row of ``t`` tokens,
-    heads of ``hd`` and values of ``vd`` (a head's width where not given):
-    on the Pallas kernels of ``attention_pallas`` (True) or as ``jnp`` code
-    (False).  Decided from what the code can observe: the backend is a TPU,
-    a head's row fills whole rows of 128 lanes, the row of tokens is whole
-    blocks of the kernels' own size (``attention_pallas.fits``: GLM's
-    published 20 x 256 over 8,192 tokens do; granite's 32/8 x 64, whose
-    heads would have to be packed in pairs, and ``Config.tiny()``'s do not)
-    and the values are as wide as the keys (the kernels take one width: a
-    latent layout with narrower values runs the ``jnp`` form).  The number
-    of heads does not enter: the kernels take any."""
+    """Whether :func:`document_attention` runs on the kernels of
+    ``attention_pallas`` on a row of ``t`` tokens, heads of ``hd`` and
+    values of ``vd`` (``hd`` where not given): ``kernels.runs_fused`` of
+    ``attention_pallas.fits`` (whole rows of 128 lanes, whole blocks of
+    tokens; any number of heads), and the values as wide as the keys (the
+    kernels take one width)."""
     from tensorflowonspark_tpu.models import attention_pallas
 
-    return (_backend() == "tpu" and vd in (None, hd)
-            and attention_pallas.fits(t, hd))
+    return runs_fused(attention_pallas, t, hd, when=vd in (None, hd))
 
 
 def swiglu(h, w_gate, w_up, w_down):
@@ -141,27 +119,20 @@ def rope(x, pos, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def conv_runs_fused(t: int, c: int, taps: int, b=0.0,
-                    initializing: bool = False) -> bool:
-    """How :func:`causal_conv` executes on a row of ``t`` tokens and ``c``
-    channels with ``taps`` taps and the bias ``b``: on the Pallas kernels of
-    ``conv_pallas`` (True) or as ``jnp`` code (False).  Decided from what
-    the code can observe: the backend is a TPU, the channels are whole rows
-    of 128 lanes, the row of tokens is whole tiles of the kernels' own size
-    and the taps fit their halo (``conv_pallas.fits``: the published 8,192 x
-    4,352 x 4, 8,192 x 4,096 x 4 and 8,192 x 2,048 x 3 do; ``Config.tiny()``'s
-    do not), the bias is a (C,) array or a Python number, and the module is
-    not initialising (``initializing``, flax's ``is_initializing()``: such a
-    trace only learns the parameters' shapes and pays for no kernel)."""
+def conv_runs_fused(t: int, c: int, taps: int, b=0.0) -> bool:
+    """Whether :func:`causal_conv` runs on the kernels of ``conv_pallas``
+    on a row of ``t`` tokens and ``c`` channels with ``taps`` taps and the
+    bias ``b``: ``kernels.runs_fused`` of ``conv_pallas.fits`` (whole rows
+    of 128 lanes, whole tiles of tokens, the taps inside the halo), and the
+    bias a (C,) array or a Python number."""
     from tensorflowonspark_tpu.models import conv_pallas
 
-    return (_backend() == "tpu" and not initializing
-            and conv_pallas.fits(t, c, taps)
-            and (isinstance(b, (int, float)) or np.shape(b) == (c,)))
+    return runs_fused(conv_pallas, t, c, taps, when=isinstance(
+        b, (int, float)) or np.shape(b) == (c,))
 
 
 def causal_conv(xbc, w, b, seg, *, times=None, gate=None, silu: bool = False,
-                out=None, scopes: tuple = (), initializing: bool = False):
+                out=None, scopes: tuple = ()):
     """Depthwise causal convolution over a packed row: ``u_t = b + sum_j
     w[K-1-j] * x_{t-j}`` over the taps ``j < K`` whose token ``t-j`` is in
     ``t``'s document, and what its callers apply straight to it: ``y =
@@ -176,14 +147,14 @@ def causal_conv(xbc, w, b, seg, *, times=None, gate=None, silu: bool = False,
     of the row once, keep the ``K - 1`` rows before it and write the result
     once, forward and backward (the backward pass under the
     ``jax.named_scope``s ``scopes``, the caller's: the forward pass runs
-    under the caller's own); anywhere else, and while a module initialises
-    (``initializing``), the ``jnp`` form below runs — ``K`` shifted sums
-    that JAX differentiates —, which is also the kernels' oracle."""
+    under the caller's own); anywhere else the ``jnp`` form below runs —
+    ``K`` shifted sums that JAX differentiates —, which is also the kernels'
+    oracle."""
     import jax
     import jax.numpy as jnp
 
     taps, t = w.shape[0], xbc.shape[0]
-    if conv_runs_fused(t, xbc.shape[1], taps, b, initializing):
+    if conv_runs_fused(t, xbc.shape[1], taps, b):
         from tensorflowonspark_tpu.models import conv_pallas
 
         return conv_pallas.fused_conv(xbc, w, b, seg, times=times, gate=gate,
@@ -444,28 +415,22 @@ def row_counters(segment_ids, head_dim: int, attends: bool = True,
     """What one step of packed rows adds to the program's counters, whatever
     the model.  From its host batch's segment ids (B, T): tokens, tokens
     that bear a loss (the next token is the same document's) and documents
-    (runs of one segment id).  From the rule its trace applied
+    (runs of one segment id).  From the rules its trace applied, a pair
+    each (``kernels.step_counters``): attention's
     (:func:`attention_runs_fused`; ``attends``: the model has an attention
-    layer; ``v_head_dim``: its values' width where it is not ``head_dim``):
-    one step of attention on the kernels or as ``jnp`` code, the other named
-    with 0 so that both are on the record.  For a model that calls
-    :func:`causal_conv` (``conv``: its (channels, taps); None: it has no
-    such layer and the pair is left out) likewise, by
-    :func:`conv_runs_fused`: ``conv_fused_steps_total`` /
-    ``conv_plain_steps_total``."""
+    layer; ``v_head_dim``: its values' width where it is not ``head_dim``)
+    and, for a model that calls :func:`causal_conv` (``conv``: its
+    (channels, taps); None: the pair is left out), the convolution's."""
     seg = np.asarray(segment_ids)
     same = seg[:, 1:] == seg[:, :-1]
-    on_chip = attends and attention_runs_fused(seg.shape[1], head_dim,
-                                               v_head_dim)
     counts = {"lm_tokens_total": int(seg.size),
               "lm_loss_tokens_total": int(same.sum()),
               "lm_documents_total": int(seg.shape[0] + (~same).sum()),
-              "attention_fused_steps_total": int(on_chip),
-              "attention_plain_steps_total": int(attends and not on_chip)}
+              **step_counters("attention", attention_runs_fused(
+                  seg.shape[1], head_dim, v_head_dim), attends)}
     if conv is not None:
-        fused = conv_runs_fused(seg.shape[1], *conv)
-        counts.update(conv_fused_steps_total=int(fused),
-                      conv_plain_steps_total=int(not fused))
+        counts.update(step_counters(
+            "conv", conv_runs_fused(seg.shape[1], *conv)))
     return counts
 
 

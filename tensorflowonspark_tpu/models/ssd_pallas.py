@@ -47,7 +47,8 @@ What per-head-per-token values the kernels need as a column (``cs_i``,
 ``dt``, the two scales) reaches them as rows (chunks, heads, Q), four values
 by sixteen heads a block, and is transposed on the chip.
 
-``granite_hybrid.scan_runs_fused`` says when this runs; interpret mode
+``granite_hybrid.scan_runs_fused`` says when this runs
+(``kernels.runs_fused`` of :func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
 """
 
@@ -56,6 +57,9 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from tensorflowonspark_tpu.models.kernels import (
+    compiler_params, dot as _dot, jitted)
 
 #: heads a grid cell handles: 16 measured faster than 8 at the published
 #: shapes (4.6 against 4.9 ms a layer, forward twice and backward: PERF.md)
@@ -99,14 +103,6 @@ def _masked_scores(c, b, seg_row):
     import jax.numpy as jnp
 
     return jnp.where(_mask(seg_row), _dot(c, b, (1, 1)), 0.0)
-
-
-def _dot(a, b, contract):
-    import jax
-    import jax.numpy as jnp
-
-    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
-                               preferred_element_type=jnp.float32)
 
 
 def _half(q: int, p: int):
@@ -297,13 +293,6 @@ def _shapes(x2, rows):
     return nc, nhb, hb, q, x2.shape[1] // (nhb * hb)
 
 
-def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
-
-
 def _tile_specs(hb: int, q: int, p: int, n: int):
     """The blocks of a grid cell (chunk ``c``, block of heads ``k``): a
     block of heads' values, the chunk's ``B`` or ``C``, the heads' states,
@@ -332,7 +321,7 @@ def _states(v2, rows, m2, quantity: int):
         functools.partial(_states_kernel, quantity, hb, p, m2.dtype),
         grid=(nc, nhb), in_specs=[wide, rows_spec, narrow], out_specs=state,
         out_shape=jax.ShapeDtypeStruct((nc, nhb * hb * p, n), jnp.float32),
-        compiler_params=_params(), name="ssd_states")(v2, rows, m2)
+        compiler_params=compiler_params(), name="ssd_states")(v2, rows, m2)
 
 
 def _output(x2, b2, c2, seg3, rows, entering):
@@ -351,7 +340,7 @@ def _output(x2, b2, c2, seg3, rows, entering):
         out_specs=wide,
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((q, q), jnp.float32)],
-        compiler_params=_params(), name="ssd_output",
+        compiler_params=compiler_params(), name="ssd_output",
     )(x2, b2, c2, seg3, rows, entering)
 
 
@@ -375,7 +364,7 @@ def _backward(x2, b2, c2, seg3, rows, entering, dy, dleft):
                    jax.ShapeDtypeStruct(c2.shape, f32),
                    jax.ShapeDtypeStruct(rows.shape, f32)],
         scratch_shapes=[pltpu.VMEM((q, q), f32), pltpu.VMEM((q, q), f32)],
-        compiler_params=_params(), name="ssd_backward",
+        compiler_params=compiler_params(), name="ssd_backward",
     )(x2, b2, c2, seg3, rows, entering, dy, dleft)
 
 
@@ -414,39 +403,25 @@ def _hand_back(through, entering, d_entering, p: int):
     return dleft.reshape(entering.shape), dthrough
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels():
-    """The three kernel calls under ``jax.jit``: a model of nine mixers
-    calls each of them nine times or more (forward, the linearised forward,
-    the backward pass), and a jitted function's body — eight pairs of heads
-    by two heads by three blocks, unrolled — is traced and lowered once a
-    shape, not once a call (the step's trace fell from 16 s to 6)."""
-    import jax
-
-    return (jax.jit(_states, static_argnums=3), jax.jit(_output),
-            jax.jit(_backward))
-
-
 def _core_fwd(x2, b2, c2, seg3, rows, through):
-    states, output, _ = _kernels()
     p = _shapes(x2, rows)[-1]
-    entering = _hand_over(through, states(x2, rows, b2, _W), p)
-    return (output(x2, b2, c2, seg3, rows, entering),
+    entering = _hand_over(
+        through, jitted(_states, 3)(x2, rows, b2, _W), p)
+    return (jitted(_output)(x2, b2, c2, seg3, rows, entering),
             (x2, b2, c2, seg3, rows, through, entering))
 
 
 def _core_bwd(saved, dy):
     import jax
 
-    states, _, backward = _kernels()
     x2, b2, c2, seg3, rows, through, entering = saved
     p = _shapes(x2, rows)[-1]
     # a custom_vjp's backward function does not inherit the caller's scope
     with jax.named_scope("ssm_scan"):
         dleft, dthrough = _hand_back(
-            through, entering, states(dy, rows, c2, _FS), p)
-        dx, db, dc, drows = backward(x2, b2, c2, seg3, rows, entering, dy,
-                                     dleft)
+            through, entering, jitted(_states, 3)(dy, rows, c2, _FS), p)
+        dx, db, dc, drows = jitted(_backward)(
+            x2, b2, c2, seg3, rows, entering, dy, dleft)
     return (dx, db.astype(b2.dtype), dc.astype(c2.dtype),
             np.zeros(seg3.shape, jax.dtypes.float0), drows, dthrough)
 

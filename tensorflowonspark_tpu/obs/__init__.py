@@ -137,28 +137,30 @@ step — never per record, row or chunk):
   ``table_update_rows_steps_total`` / ``table_update_full_steps_total``,
   one increment a wide&deep step, say which execution of the default
   table update the step's shapes chose (``models/widedeep.py``);
-  ``ssm_scan_fused_steps_total`` / ``ssm_scan_plain_steps_total``, one
-  increment a ``granite_hybrid`` step, say whether its state-space scan
-  ran on the Pallas kernels or as ``jnp`` code
-  (``models/granite_hybrid.py::scan_runs_fused``);
-  ``attention_fused_steps_total`` / ``attention_plain_steps_total``, one
-  increment a step of a packed-row decoder, say the same of
-  ``packed_rows.document_attention`` (``attention_runs_fused``);
-  ``conv_fused_steps_total`` / ``conv_plain_steps_total``, one increment a
-  step of ``granite_hybrid``, ``lfm2_moe`` and ``kimi_linear``, say
-  whether ``packed_rows.causal_conv`` ran on the Pallas kernels of
-  ``models/conv_pallas.py`` or as ``jnp`` code (``conv_runs_fused``);
-  ``kda_scan_fused_steps_total`` / ``kda_scan_plain_steps_total``, one
-  increment a ``kimi_linear`` step, say whether its chunked delta rule ran
-  on the Pallas kernels of ``models/kda_pallas.py`` or as ``jnp`` code
-  (``models/kimi_linear.py::kda_scan_runs_fused``);
-  ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``,
-  one increment a step of ``mla_moe`` and of ``lfm2_moe``, say whether the
-  routed experts' grouped products, in the form a step takes when a
-  layer's slots fit ``moe.prefix_rows``, ran on the Pallas kernels of
-  ``parallel/grouped_pallas.py`` or as ``jax.lax.ragged_dot``
-  (``parallel/moe.py::grouped_runs_fused``; ``moe_overflow_layers_total``
-  counts the layer-steps that took the other form).
+  and five pairs, one increment a step of a packed-row decoder that has
+  the site, that say whether it ran on its Pallas kernels or as its plain
+  form.  One rule and one writer serve all five (``models/kernels.py``:
+  ``runs_fused`` of the kernels' module and the shapes, ``step_counters``
+  of its answer); each site's own name for the rule is in brackets:
+  ``ssm_scan_fused_steps_total`` / ``ssm_scan_plain_steps_total``
+  (``granite_hybrid``'s state-space scan, ``models/ssd_pallas.py``;
+  ``granite_hybrid.scan_runs_fused``);
+  ``attention_fused_steps_total`` / ``attention_plain_steps_total``
+  (``packed_rows.document_attention``, ``models/attention_pallas.py``;
+  ``attention_runs_fused``);
+  ``conv_fused_steps_total`` / ``conv_plain_steps_total``
+  (``packed_rows.causal_conv`` of ``granite_hybrid``, ``lfm2_moe`` and
+  ``kimi_linear``, ``models/conv_pallas.py``; ``conv_runs_fused``);
+  ``kda_scan_fused_steps_total`` / ``kda_scan_plain_steps_total``
+  (``kimi_linear``'s chunked delta rule, ``models/kda_pallas.py``;
+  ``kimi_linear.kda_scan_runs_fused``);
+  ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``
+  (the routed experts' grouped products of ``mla_moe``, ``lfm2_moe`` and
+  ``kimi_linear`` in the form a step takes when a layer's slots fit
+  ``moe.prefix_rows``, ``parallel/grouped_pallas.py`` or
+  ``jax.lax.ragged_dot``; ``parallel/moe.py::grouped_runs_fused``;
+  ``moe_overflow_layers_total`` counts the layer-steps that took the other
+  form).
 
 Also instrumented: elastic regroups (``elastic``), serving
 (``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which

@@ -40,7 +40,8 @@ tile before the product (0 x NaN would be NaN), and **an empty group is
 visited once to write exact zeros** (the tile fetched for that visit is not
 used).
 
-``moe.grouped_runs_fused`` says when this runs; interpret mode
+``moe.grouped_runs_fused`` says when this runs (``kernels.runs_fused`` of
+:func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
 """
 
@@ -49,6 +50,9 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from tensorflowonspark_tpu.models.kernels import (
+    compiler_params, dot as _dot, jitted)
 
 #: rows a tile (:func:`plan`'s and both kernels').  With a group's weights
 #: resident the choice is between the boundary tiles' waste and the MXU's
@@ -125,14 +129,6 @@ def plan(group_sizes, rows: int):
             jnp.concatenate([jnp.zeros((1,), i32), ends]), jnp.sum(met))
 
 
-def _dot(a, b, contract):
-    import jax
-    import jax.numpy as jnp
-
-    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
 def _visit(groups, tiles, offsets, size: int):
     """``(whole, shared, mask)`` of this grid step's visit: whether every
     row of its tile is its group's, whether only some are (neither for an
@@ -207,14 +203,6 @@ def _weights_kernel(groups, tiles, offsets, rows_ref, d_ref, out_ref):
             for ref in (rows_ref, d_ref)), (0, 0))
 
 
-def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
-
-
 def _rows_product(rows, w, visits, transposed: bool):
     """``rows`` (R, C) by ``w`` (H, K, N): (R, N) float32 where ``C`` is
     ``K``, and against ``w`` transposed (R, K) in ``rows``' type where ``C``
@@ -242,7 +230,7 @@ def _rows_product(rows, w, visits, transposed: bool):
                                    lambda j, v, g, t, o: (t[v], j)),
             scratch_shapes=[pltpu.VMEM(block, rows.dtype)]),
         out_shape=jax.ShapeDtypeStruct((r, wide), out_dtype),
-        compiler_params=_params(), name="grouped_rows",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES), name="grouped_rows",
     )(*visits[:3], rows, w)
 
 
@@ -266,23 +254,14 @@ def _weights_product(rows, d, visits, groups: int):
             out_specs=pl.BlockSpec((None, tile, n),
                                    lambda j, v, g, t, o: (g[v], j, 0))),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), jnp.float32),
-        compiler_params=_params(), name="grouped_weights",
+        compiler_params=compiler_params(VMEM_LIMIT_BYTES),
+        name="grouped_weights",
     )(*visits[:3], rows, d)
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels():
-    """The two kernel calls under ``jax.jit``: a traced routed part calls
-    them twelve times, and a jitted function's body is traced and lowered
-    once a shape, not once a call."""
-    import jax
-
-    return (jax.jit(_rows_product, static_argnums=(3,)),
-            jax.jit(_weights_product, static_argnums=(3,)))
-
-
 def _product_fwd(rows, w, visits, scope):
-    return _kernels()[0](rows, w, visits, False), (rows, w, visits)
+    return (jitted(_rows_product, (3,))(rows, w, visits, False),
+            (rows, w, visits))
 
 
 def _product_bwd(scope, saved, d):
@@ -291,8 +270,9 @@ def _product_bwd(scope, saved, d):
     rows, w, visits = saved
     with jax.named_scope(scope):
         d = d.astype(rows.dtype)
-        d_rows = _kernels()[0](d, w, visits, True)
-        d_w = _kernels()[1](rows, d, visits, w.shape[0]).astype(w.dtype)
+        d_rows = jitted(_rows_product, (3,))(d, w, visits, True)
+        d_w = jitted(_weights_product, (3,))(
+            rows, d, visits, w.shape[0]).astype(w.dtype)
     return d_rows, d_w, tuple(np.zeros(a.shape, jax.dtypes.float0)
                               for a in visits)
 

@@ -29,46 +29,44 @@ Layout contract: tokens ``(T, M)`` in, experts' weights ``(E, M, H)`` /
 handles imbalance), but shard the token dim over the data axes as usual.
 
 **:func:`routed_experts` — top-k of a wide router, the experts held here,
-no token dropped** (called by ``models/mla_moe.py`` and
-``models/lfm2_moe.py``; the DeepSeek-V3 layout, arXiv:2412.19437).  The layer is told *which* of the router's experts this
-chip holds.  It scores every token against all of them (sigmoid, float32),
-chooses the ``top_k`` of score plus a correction bias that takes no
-gradient, weighs the chosen by their normalised scores, keeps every slot
-(token, choice) whose expert is held — any number of them, from none to
-all — sorts the kept slots by expert, the held experts' first, and
-multiplies those expert by expert as grouped products, and adds the results
-back weighted.  A grouped product is one algorithm with two executions
-(:func:`grouped_runs_fused` is the rule, and the models' steps count which
-applied: ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``):
-on a TPU, at shapes that fill their tiles, in the form a step takes when its
+no token dropped** (the DeepSeek-V3 layout, arXiv:2412.19437; called through
+:func:`expert_ffn` by ``models/mla_moe.py``, ``lfm2_moe.py`` and
+``kimi_linear.py``, each of which names its layout in a :class:`Routing`).
+The layer is told *which* of the router's experts this chip holds.  It
+scores every token against all of them (sigmoid, float32), chooses the
+``top_k`` of score plus a correction bias that takes no gradient, weighs the
+chosen by their normalised scores, keeps every slot (token, choice) whose
+expert is held — any number, from none to all — sorts the kept slots by
+expert, the held experts' first, multiplies those expert by expert as
+grouped products, and adds the results back weighted.  A grouped product is
+one algorithm with two executions (:func:`grouped_runs_fused` is the rule,
+and the models' steps count which applied:
+``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``): on a
+TPU, at shapes that fill their tiles, in the form a step takes when its
 slots fit, the Pallas kernels of ``grouped_pallas`` walk the row tiles that
-hold a live row and no others, so a product's time follows the step's live
-rows; on any other backend, at other shapes (``Config.tiny()``, the tests),
-while a module initialises (nothing of that trace is ever run) and in the
-form a layer takes when its slots overflow, ``jax.lax.ragged_dot`` runs,
-which is also the kernels' oracle.  The slots that
-landed here are a prefix of the sorted order whose length the device knows
-after the router: everything after the router is one function of a static
-row count, traced at :func:`prefix_rows` (three times an even router's share)
-and
-at all the slots, and a ``jax.lax.cond`` takes the first wherever the step's
-count fits it — so gathers, products, masks and sums work on the rows that
-landed here, not on the worst case, and a step that overflows is still
-exact.  What the experts held elsewhere would have added is left out; on one
-chip no exchange runs.  It returns how many tokens chose each of the
-router's experts, which is what the caller's bias update and counters read.
-No capacity factor exists and no auxiliary loss.  What such a model keeps
-beside its parameters — the correction biases, their update after a step and
-the counts the program's counters show — is :func:`routing_state_shapes`,
-:func:`step_routing_state` and :func:`routing_counters`, which both models
-call.
+hold a live row and no others; anywhere else and in the form a layer takes
+when its slots overflow ``jax.lax.ragged_dot`` runs, which is also the
+kernels' oracle.  The slots that landed here are a prefix of the sorted
+order whose length the device knows after the router: everything after the
+router is one function of a static row count, traced at :func:`prefix_rows`
+and at all the slots, and a ``jax.lax.cond`` takes the first wherever the
+step's count fits it — so gathers, products, masks and sums work on the rows
+that landed here, and a step that overflows is still exact.  What the
+experts held elsewhere would have added is left out; on one chip no exchange
+runs.  It returns how many tokens chose each of the router's experts, which
+the caller's bias update and counters read.  No capacity factor exists and
+no auxiliary loss.  What such a model keeps beside its parameters is
+:func:`routing_state_shapes`, :func:`step_routing_state` and
+:func:`routing_counters`.
 """
 
 from __future__ import annotations
 
 import functools
 import logging
-from typing import Any, Mapping
+from typing import Any, Mapping, NamedTuple
+
+from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 
 logger = logging.getLogger(__name__)
 
@@ -336,31 +334,16 @@ def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
     return chosen, scale * picked
 
 
-def _backend() -> str:
-    """The backend the process computes on (a compile test for a described
-    chip, on a CPU host, says "tpu" here)."""
-    import jax
-
-    return jax.default_backend()
-
-
 def grouped_runs_fused(rows: int, k: int, n: int, dtype, *,
-                       initializing: bool = False,
                        overflow: bool = False) -> bool:
-    """How a grouped product of ``rows`` sorted rows (rows, ``k``) by held
-    weights (H, ``k``, ``n``) executes, operands in ``dtype``: on the Pallas
-    kernels of ``grouped_pallas`` (True) or as ``jax.lax.ragged_dot``
-    (False).  Decided from what the code can observe:
+    """Whether a grouped product of ``rows`` sorted rows (rows, ``k``) by
+    held weights (H, ``k``, ``n``), operands in ``dtype``, runs on the
+    kernels of ``grouped_pallas`` (else as ``jax.lax.ragged_dot``):
 
-    - the backend is a TPU, ``k`` and ``n`` are whole rows of 128 lanes and
-      the rows whole row tiles of the kernels' own (``grouped_pallas.fits``:
-      the published 2,048 by 1,536 and by 1,792 over 12,288 and 24,576 rows
-      do; ``Config.tiny()``'s do not);
-    - the module is not ``initializing``: a flax module's ``init`` traces
-      its forward pass to learn its parameters and runs none of it
-      (``Trainer.__init__`` traces it twice); the kernels there cost a warm
-      start of ``lfm2_8b_a1b_packed_8k`` 1.6 s of tracing and importing
-      (PERF.md section 6, PR 42);
+    - ``models/kernels.py::runs_fused`` of ``grouped_pallas.fits`` (``k``
+      and ``n`` whole rows of 128 lanes, the rows whole row tiles: the
+      published 2,048 by 1,536 and by 1,792 over 12,288 and 24,576 rows do;
+      ``Config.tiny()``'s do not);
     - the form is not the ``overflow`` one: a layer whose live slots pass
       :func:`prefix_rows` takes all the slots, which happens in none of
       ``lfm2_8b_a1b_packed_8k``'s steps and on one seed in nine of
@@ -374,22 +357,22 @@ def grouped_runs_fused(rows: int, k: int, n: int, dtype, *,
     one way."""
     from tensorflowonspark_tpu.parallel import grouped_pallas
 
-    return (not initializing and not overflow and _backend() == "tpu"
-            and grouped_pallas.fits(rows, k, n, dtype))
+    return runs_fused(grouped_pallas, rows, k, n, dtype, when=not overflow)
 
 
-def grouped_step_counters(tokens: int, top_k: int, n_held: int,
-                          n_experts: int, d: int, f: int, dtype) -> dict:
+def grouped_step_counters(tokens: int, routing: "Routing", d: int, f: int,
+                          dtype) -> dict:
     """What one step of a model of such layers adds to the program's
-    counters: one step of grouped products on the kernels or as
-    ``jax.lax.ragged_dot``, the other named with 0 so that both are on the
-    record.  On the kernels means: the form :func:`routed_experts` takes for
-    ``tokens`` tokens of width ``d`` and experts ``f`` wide when a layer's
-    slots fit (:func:`prefix_rows` of them) runs all its products there."""
-    fused = grouped_runs_fused(
-        prefix_rows(tokens * top_k, n_held, n_experts), d, f, dtype)
-    return {"moe_grouped_fused_steps_total": int(fused),
-            "moe_grouped_plain_steps_total": int(not fused)}
+    counters (``kernels.step_counters``): one step of grouped products on
+    the kernels or as ``jax.lax.ragged_dot``, the other named with 0 so that
+    both are on the record.  On the kernels means: the form
+    :func:`routed_experts` takes for ``tokens`` tokens of width ``d`` and
+    experts ``f`` wide when a layer's slots fit (:func:`prefix_rows` of
+    them) runs all its products there."""
+    rows = prefix_rows(tokens * routing.top_k, len(routing.held),
+                       routing.n_experts)
+    return step_counters("moe_grouped",
+                         grouped_runs_fused(rows, d, f, dtype))
 
 
 def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
@@ -560,8 +543,7 @@ def _routed_part(scopes: tuple = ()):
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
                    top_k: int, scale: float, normalize: bool = True,
-                   sum_eps: float = 0.0, initializing: bool = False,
-                   scopes: tuple = ()):
+                   sum_eps: float = 0.0, scopes: tuple = ()):
     """The held experts' part of a routed SwiGLU layer on tokens ``x``
     (T, D): ``sum over e chosen and held of g_e W_down_e (silu(x W_gate_e)
     * (x W_up_e))``, and the tokens that chose each of the router's experts.
@@ -572,9 +554,7 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     ``[0, E)``) says which they are, in the weights' order.  Products take
     operands in ``x``'s type and accumulate in float32; routing is float32
     (:func:`topk_route`, which ``top_k``, ``scale``, ``normalize`` and
-    ``sum_eps`` go to).  ``initializing`` is what the calling module
-    observes of itself (flax's ``is_initializing()``): such a trace is never
-    run, and :func:`grouped_runs_fused` puts no kernel into it.  ``scopes``
+    ``sum_eps`` go to).  ``scopes``
     are the ``jax.named_scope``s the caller has opened round the layer, where
     a profile should tell its operations from another layer's (``mla_moe``'s
     prediction module): layers that name the same share one traced part.
@@ -614,12 +594,54 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     # slots: which of them run their products on the kernels
     fused = tuple(
         grouped_runs_fused(rows, *w_gate.shape[1:], x.dtype,
-                           initializing=initializing, overflow=overflow)
+                           overflow=overflow)
         for rows, overflow in ((n_prefix, False), (n_slots, True)))
     y = _routed_part(tuple(scopes))(n_prefix, fused, top_k, order, inv,
                                     counts[held], x, gates, w_gate, w_up,
                                     w_down)
     return y, counts
+
+
+class Routing(NamedTuple):
+    """A layout's routed layers under one set of names (every published
+    configuration names them its own way; a model's ``routing(config)``
+    translates once, for :func:`expert_ffn` and for the skeleton that steps
+    and reads the routing state, ``models/packed_decoder.py``)."""
+    n_experts: int          # the router's width
+    layers: int             # layers with a router: the routing state's rows
+    held: tuple             # the experts this chip holds, the weights' order
+    top_k: int
+    scale: float
+    normalize: bool
+    speed: float            # the correction bias's step (0.0: it stays)
+    sum_eps: float = 0.0    # :func:`topk_route`'s
+
+
+def expert_ffn(params, prefix: str, h, bias, routing: Routing, *,
+               shared: bool = False, scopes: tuple = ()):
+    """An expert layer's feed-forward on tokens ``h`` (N, D), from a flat
+    parameter dict: :func:`routed_experts` of ``router`` and
+    ``experts_{gate,up,down}`` under ``prefix`` and, where the layout has
+    one (``shared``), the shared expert's SwiGLU (``shared_{gate,up,down}``,
+    under the ``jax.named_scope`` ``shared_expert``) added to it.  ``bias``
+    (E,) is the layer's correction bias, ``scopes`` the named scopes the
+    layer sits under.  Returns ``(y, counts)``, ``counts`` (E,) the tokens
+    that chose each of the router's experts."""
+    import jax
+
+    from tensorflowonspark_tpu.models.packed_rows import swiglu
+
+    if shared:
+        with jax.named_scope("shared_expert"):
+            y = swiglu(h, params[prefix + "shared_gate"],
+                       params[prefix + "shared_up"],
+                       params[prefix + "shared_down"])
+    routed, counts = routed_experts(
+        h, params[prefix + "router"], bias, params[prefix + "experts_gate"],
+        params[prefix + "experts_up"], params[prefix + "experts_down"],
+        routing.held, top_k=routing.top_k, scale=routing.scale,
+        normalize=routing.normalize, sum_eps=routing.sum_eps, scopes=scopes)
+    return (y + routed if shared else routed), counts
 
 
 # ---------------------------------------------------------------------------

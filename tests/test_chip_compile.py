@@ -31,6 +31,7 @@ import jax  # noqa: E402
 from tensorflowonspark_tpu import (TFCluster, chip_info,  # noqa: E402
                                    compile_cache)
 from tensorflowonspark_tpu import models as model_zoo  # noqa: E402
+from tensorflowonspark_tpu.models import kernels as kernel_seam  # noqa: E402
 from tensorflowonspark_tpu.sparkapi import LocalSparkContext  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -450,7 +451,7 @@ def test_kda_kernels_compile_at_the_published_widths(topo, monkeypatch):
                                               packed_rows)
 
     t, heads, hd, bf, f32 = 8192, 32, 128, jnp.bfloat16, jnp.float32
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
     assert kimi_linear.kda_scan_runs_fused(64, heads, hd, hd)
     one = SingleDeviceSharding(topo.devices[0])
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
@@ -532,7 +533,7 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     donated and updated in place, and arguments plus temporaries stay under
     the chip's memory with room for the staged batches.  The scan is the
     one a chip runs (the Pallas kernels: here the backend is the CPU, so
-    the test says "tpu" in the model's place and in ``packed_rows``'s),
+    the test says "tpu" in ``kernels.backend``'s place),
     three kernels a layer and two more in its backward pass; so is the
     mixers' convolution (``conv_pallas``: a ``conv_forward`` a layer, one
     more in its recomputation, a ``conv_backward``).  PERF.md section 4
@@ -540,10 +541,7 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     import json
 
     from benchmark.configs.granite_4_0_h_micro import program
-    from tensorflowonspark_tpu.models import granite_hybrid, packed_rows
-
-    monkeypatch.setattr(granite_hybrid, "_backend", lambda: "tpu")
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs",
                            "granite_4_0_h_micro", "config.json")) as f:
@@ -641,8 +639,8 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     a layer; the compiler's own ``ragged-dot`` kernels in the overflow
     form),
     attention is the one a chip runs (the Pallas kernels: here the backend
-    is the CPU, so the test says "tpu" in ``packed_rows``'s place and in
-    ``moe``'s; a layer calls the forward kernel, calls it again in its
+    is the CPU, so the test says "tpu" in ``kernels.backend``'s place; a
+    layer calls the forward kernel, calls it again in its
     recomputation and the backward kernel once), and arguments plus
     temporaries stay under the chip's memory.  PERF.md section 4 holds the
     figures."""
@@ -650,11 +648,7 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     import re
 
     from benchmark.configs.glm_4_7_flash import program
-    from tensorflowonspark_tpu.models import packed_rows
-    from tensorflowonspark_tpu.parallel import moe
-
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs", "glm_4_7_flash",
                            "config.json")) as f:
@@ -701,7 +695,7 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     ``moe.prefix_rows`` of the slots — a quarter share leaves that under
     all of them — and the compiler's own ``ragged-dot`` kernels in the
     overflow form), attention at heads of 64 is ``jnp`` code on a TPU too (the
-    test says "tpu" in ``packed_rows``'s place and in ``moe``'s, the
+    test says "tpu" in ``kernels.backend``'s place, the
     attention rule still says plain, and no attention kernel is called),
     and arguments, temporaries and code stay under 15.75 GiB.  PERF.md
     section 4 holds the figures."""
@@ -709,13 +703,11 @@ def test_lfm2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
 
     from benchmark.configs.lfm2_8b_a1b import program
     from tensorflowonspark_tpu.models import packed_rows
-    from tensorflowonspark_tpu.parallel import moe
 
     with open(os.path.join(REPO, "benchmark", "configs", "lfm2_8b_a1b",
                            "config.json")) as f:
         published = json.load(f)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
     config = program.model_config(published)
     assert not packed_rows.attention_runs_fused(config.seq_len,
                                                 config.head_dim)
@@ -756,8 +748,8 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     ``moe.prefix_rows`` of the slots — 6,144 rows at a 1/32 share, 24 tiles
     of 256 — and the compiler's own ``ragged-dot`` kernels in the overflow
     form); attention at keys of 192 and values of 128 is ``jnp`` code on a
-    TPU too (the test says "tpu" in ``packed_rows``'s place and in
-    ``moe``'s, the attention rule still says plain, and no attention kernel
+    TPU too (the test says "tpu" in ``kernels.backend``'s place, the
+    attention rule still says plain, and no attention kernel
     is called), and arguments, temporaries and code stay under 15.75 GiB.
     PERF.md section 4 holds the figures."""
     import json
@@ -769,8 +761,7 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     with open(os.path.join(REPO, "benchmark", "configs",
                            "kimi_linear_48b_a3b", "config.json")) as f:
         published = json.load(f)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
     config = program.model_config(published)
     assert not packed_rows.attention_runs_fused(config.seq_len,
                                                 config.qk_head_dim)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from benchmark.configs.granite_4_0_h_micro import program, reference, work
 from benchmark.traffic import packed_documents
-from tensorflowonspark_tpu.models import granite_hybrid as gh
+from tensorflowonspark_tpu.models import granite_hybrid as gh, kernels
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG_DIR = os.path.join(REPO, "benchmark", "configs", "granite_4_0_h_micro")
@@ -120,7 +120,7 @@ def _reference_loss(weights, batch, ref_config):
 def test_granite_logits_loss_and_every_leafs_gradient_match_the_reference(tiny):
     config, ref_config, weights, params = tiny
     batch = _rows(config, 2, 1)
-    _close(gh.apply_tokens(params, batch["tokens"], batch["segment_ids"],
+    _close(gh.apply_tokens(params, None, batch["tokens"], batch["segment_ids"],
                            config),
            reference.forward(weights, jnp.asarray(batch["tokens"]),
                              jnp.asarray(batch["segment_ids"]), ref_config))
@@ -352,7 +352,7 @@ def test_granite_scan_rule_picks_the_kernels_on_a_tpu_at_the_published_shapes(
     assert jax.default_backend() == "cpu"
     assert not gh.scan_runs_fused(*shape(published))
     assert not gh.scan_runs_fused(*shape(tiny))
-    monkeypatch.setattr(gh, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     assert gh.scan_runs_fused(*shape(published))
     assert not gh.scan_runs_fused(*shape(tiny))
     # each of the kernels' tiles has to be whole
@@ -452,7 +452,7 @@ def test_granite_step_counts_the_execution_of_its_scan(tiny, monkeypatch):
     trainer.step(staged)
     np.testing.assert_array_equal(totals() - before, [0, 1])
     # what a chip's step at the published shapes counts
-    monkeypatch.setattr(gh, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     counts = gh.batch_counters(_rows(config, 1, 15),
                                program.model_config(_published()))
     assert (counts["ssm_scan_fused_steps_total"],
@@ -517,7 +517,7 @@ def test_granite_packed_documents_do_not_see_each_other(tiny, side):
     @jax.jit
     def logits(tokens, seg):
         if side == "program":
-            return gh.apply_tokens(params, tokens, seg, config)[0]
+            return gh.apply_tokens(params, None, tokens, seg, config)[0]
         return reference.forward(weights, tokens, seg, ref_config)[0]
 
     packed = logits(batch["tokens"], batch["segment_ids"])
@@ -543,8 +543,8 @@ def test_granite_vocabulary_slice_gives_the_whole_models_columns(tiny):
     sliced = dict(params, embed=params["embed"][:held])
     batch = _rows(config, 2, 7)
     tokens = batch["tokens"] % held
-    whole = gh.apply_tokens(params, tokens, batch["segment_ids"], config)
-    part = gh.apply_tokens(sliced, tokens, batch["segment_ids"],
+    whole = gh.apply_tokens(params, None, tokens, batch["segment_ids"], config)
+    part = gh.apply_tokens(sliced, None, tokens, batch["segment_ids"],
                            dataclasses.replace(config, vocab_size=held))
     assert part.shape[-1] == held
     _close(part, whole[..., :held])

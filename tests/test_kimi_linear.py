@@ -29,8 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.configs.kimi_linear_48b_a3b import program, reference
 from tensorflowonspark_tpu import obs
-from tensorflowonspark_tpu.models import (kda_pallas, kimi_linear, mla_moe,
-                                          packed_rows)
+from tensorflowonspark_tpu.models import (kda_pallas, kernels, kimi_linear,
+                                          mla_moe, packed_rows)
 from tensorflowonspark_tpu.parallel import moe
 
 BIG_SEED = 2 ** 31 + 4321           # the driver's seeds pass 32 signed bits
@@ -200,7 +200,7 @@ def test_kda_scan_stays_finite_at_any_decay(fused, monkeypatch):
     q, k, v, g, beta = _scan_inputs(64, heads, dk, dv, 12.0, seed=3)
     seg = jnp.zeros((64,), jnp.int32)
     if fused:
-        monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+        monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     assert kimi_linear.kda_scan_runs_fused(64, heads, dk, dv) == fused
     with pltpu.force_tpu_interpret_mode():
         out = kimi_linear.kda_scan(q, k, v, g, beta, seg, 64, jnp.float32)
@@ -245,7 +245,7 @@ def test_kda_kernels_are_the_recurrence_values_and_gradients(
         return reference.delta_rule(*a, seg)
 
     (_, want_out), want = _value_and_gradients(theirs, w, 5)(*args)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     with pltpu.force_tpu_interpret_mode():
         fn = _value_and_gradients(mine, w, 5)
         assert "kda_backward" in str(jax.make_jaxpr(fn)(*args))
@@ -274,7 +274,7 @@ def test_kda_kernels_follow_the_jnp_form_in_bfloat16(monkeypatch):
             jnp.float32)
 
     (_, want_out), want = _value_and_gradients(mine, w, 5)(q, k, v, g, beta)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     with pltpu.force_tpu_interpret_mode():
         (_, out), got = _value_and_gradients(mine, w, 5)(q, k, v, g, beta)
     _close(out, want_out, tol=2e-2)
@@ -303,7 +303,7 @@ def test_one_rule_says_which_recurrence_runs_and_which_is_counted(
             config = program.model_config(json.load(f))
         assert (config.kda_num_heads, config.kda_head_dim,
                 config.kda_chunk) == (32, 128, 64)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: backend)
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     assert kimi_linear.kda_scan_runs_fused(
         config.kda_chunk, config.kda_num_heads, config.kda_head_dim,
         config.kda_head_dim) == bool(fused)
@@ -381,7 +381,7 @@ def test_a_layers_recomputation_keeps_what_the_scan_names(fused, monkeypatch):
         config = dataclasses.replace(config, kda_num_heads=4,
                                      kda_head_dim=128, kda_chunk=64,
                                      seq_len=128)
-        monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+        monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     assert kimi_linear.batch_counters(
         {"segment_ids": np.zeros((1, config.seq_len), np.int32)}, config)[
             "kda_scan_fused_steps_total"] == int(fused)
@@ -486,7 +486,7 @@ def test_one_rule_says_which_attention_runs_and_which_is_counted(
     (the kernels take one width), ``row_counters`` counts the step by the
     same rule, and ``document_attention`` does what was counted (on this
     host the kernels could not run: the narrower values still compute)."""
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     t = 384
     assert packed_rows.attention_runs_fused(t, hd, vd) == bool(fused)
     counts = packed_rows.row_counters(np.zeros((1, t), np.int32), hd,
@@ -821,7 +821,8 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(tiny):
     # one share with its shared expert is the program's layer
     own = {f"x_{k}": (v[np.asarray(config.experts_held)]
                       if k.startswith("experts") else v) for k, v in w.items()}
-    y, _ = kimi_linear.expert_ffn(own, "x_", h, bias, config)
+    y, _ = moe.expert_ffn(own, "x_", h, bias, kimi_linear.routing(config),
+                          shared=True)
     theirs, _ = reference.experts(
         {k: (v[np.asarray(config.experts_held)] if k.startswith("experts")
              else v) for k, v in w.items()}, h, bias, ref_config,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from benchmark.configs.lfm2_8b_a1b import program, reference
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import (granite_hybrid, lfm2_moe, mla_moe,
-                                          packed_rows)
+                                          packed_decoder, packed_rows)
 from tensorflowonspark_tpu.parallel import moe
 
 BIG_SEED = 2 ** 31 + 4021           # the driver's seeds pass 32 signed bits
@@ -309,11 +309,14 @@ def test_the_shared_pieces_exist_once():
     call them hold the same objects, not copies."""
     assert granite_hybrid.causal_conv is packed_rows.causal_conv
     assert lfm2_moe.causal_conv is packed_rows.causal_conv
-    for piece in ("rope", "document_positions"):
-        assert getattr(lfm2_moe, piece) is getattr(packed_rows, piece)
-    # GLM's rotation went with its latent attention into ``packed_rows``
-    assert mla_moe.document_positions is packed_rows.document_positions
+    assert lfm2_moe.rope is packed_rows.rope
+    # GLM's rotation went with its latent attention into ``packed_rows``,
+    # and the positions both models' layers read are the skeleton's
     assert mla_moe.packed_rows is packed_rows and not hasattr(mla_moe, "rope")
+    for lib in (lfm2_moe, mla_moe):
+        assert lib._DECODER.positions
+        assert not hasattr(lib, "document_positions")
+    assert packed_decoder.document_positions is packed_rows.document_positions
     assert mla_moe.COLLECTION == lfm2_moe.COLLECTION == "moe"
     glm = mla_moe.Config.tiny()
     assert mla_moe.collection_shapes(glm) == moe.routing_state_shapes(

@@ -571,7 +571,8 @@ def test_routed_experts_refuse_experts_the_router_does_not_have():
 def test_positions_restart_at_every_document(tiny):
     seg = np.array([[3, 3, 3, 5, 5, 9, 9, 9, 9, 2]], np.int32)
     want = [0, 1, 2, 0, 1, 0, 1, 2, 3, 0]
-    assert mla_moe.document_positions(jnp.asarray(seg[0])).tolist() == want
+    assert packed_rows.document_positions(
+        jnp.asarray(seg[0])).tolist() == want
     assert reference.positions(seg)[0].tolist() == want
 
 

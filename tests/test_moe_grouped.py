@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from tensorflowonspark_tpu.models import lfm2_moe, mla_moe
+from tensorflowonspark_tpu.models import kernels, lfm2_moe, mla_moe
 from tensorflowonspark_tpu.parallel import grouped_pallas, moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -184,27 +184,25 @@ def test_grouped_plan_visits_the_tiles_a_group_has_rows_in(pattern):
 ])
 def test_grouped_rule_picks_the_kernels_on_a_tpu_at_shapes_that_fill_tiles(
         rows, k, n, backend, fused, monkeypatch):
-    monkeypatch.setattr(moe, "_backend", lambda: backend)
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     assert moe.grouped_runs_fused(rows, k, n, jnp.bfloat16) is fused
     assert grouped_pallas.fits(rows, k, n, "bfloat16") is (
         fused or backend != "tpu")
 
 
 @pytest.mark.parametrize("backend", ["tpu", "cpu"])
-@pytest.mark.parametrize("initializing", [False, True])
 @pytest.mark.parametrize("overflow", [False, True])
 def test_grouped_rule_keeps_the_kernels_out_of_what_a_start_pays_for(
-        overflow, initializing, backend, monkeypatch):
+        overflow, backend, monkeypatch):
     """At shapes that fit, on a TPU: the kernels in the form a step takes
-    when its slots fit and nowhere else — not while a module initialises
-    (that trace is never run), not in the overflow form (it ran in none of
-    the benchmark's 5,000 ``lfm2`` layer-steps and doubled the kernels a
-    start loads)."""
-    monkeypatch.setattr(moe, "_backend", lambda: backend)
+    when its slots fit and nowhere else — not in the overflow form (it ran
+    in none of the benchmark's 5,000 ``lfm2`` layer-steps and doubled the
+    kernels a start loads).  (A module that initialises traces no forward
+    pass at all: ``tests/test_packed_decoder.py``.)"""
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     assert moe.grouped_runs_fused(
-        24576, 2048, 1792, jnp.bfloat16, initializing=initializing,
-        overflow=overflow) is (
-            backend == "tpu" and not initializing and not overflow)
+        24576, 2048, 1792, jnp.bfloat16, overflow=overflow) is (
+            backend == "tpu" and not overflow)
 
 
 @pytest.mark.parametrize("model", ["glm_4_7_flash", "lfm2_8b_a1b"])
@@ -213,8 +211,8 @@ def test_published_shapes_fill_the_kernels_tiles_in_both_forms(model,
     """The two sizes ``routed_experts`` traces its routed part at, at the
     published widths: both whole row tiles that fit the kernels' memory
     (either form could run there), the rule puts the form a step takes on
-    the kernels and leaves the overflow form and an initialising module's
-    trace on ``ragged_dot``; ``Config.tiny()``'s shapes do not fit."""
+    the kernels and leaves the overflow form on ``ragged_dot``;
+    ``Config.tiny()``'s shapes do not fit."""
     config = _published(model)
     lib = mla_moe if model.startswith("glm") else lfm2_moe
     e = (config.n_routed_experts if lib is mla_moe else config.num_experts)
@@ -223,13 +221,11 @@ def test_published_shapes_fill_the_kernels_tiles_in_both_forms(model,
     assert (slots, prefix) == (32768, 12288 if lib is mla_moe else 24576)
     d, f = config.hidden_size, config.moe_intermediate_size
     assert (d, f) == (2048, 1536 if lib is mla_moe else 1792)
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     for rows in (prefix, slots):
         for k, n in ((d, f), (f, d)):
             assert grouped_pallas.fits(rows, k, n, config.dtype)
             assert moe.grouped_runs_fused(rows, k, n, config.dtype)
-            assert not moe.grouped_runs_fused(rows, k, n, config.dtype,
-                                              initializing=True)
             assert not moe.grouped_runs_fused(rows, k, n, config.dtype,
                                               overflow=True)
     tiny = lib.Config.tiny()
@@ -253,7 +249,7 @@ def test_both_models_count_the_execution_of_their_grouped_products(
     lib = mla_moe if model.startswith("glm") else lfm2_moe
     config = (lib.Config.tiny() if model.endswith("tiny")
               else _published(model))
-    monkeypatch.setattr(moe, "_backend", lambda: backend)
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     batch = {"segment_ids": np.zeros((1, config.seq_len), np.int32)}
     counts = lib.batch_counters(batch, config)
     assert (counts["moe_grouped_fused_steps_total"],
@@ -315,19 +311,16 @@ def test_routed_experts_on_the_kernels_are_routed_experts(form, dtype,
 
         return jax.jit(jax.value_and_grad(loss, has_aux=True))(layer)
 
-    (_, (want, want_counts)), want_grads = run()
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
-    assert moe.grouped_runs_fused(512, 128, 128, dtype)
     seen = []
     real = grouped_pallas.grouped_product
     monkeypatch.setattr(grouped_pallas, "grouped_product",
                         lambda rows, *a: seen.append(rows.shape[0])
                         or real(rows, *a))
-    # a module that initialises traces no kernel: no interpreter is needed
-    (_, (y, _)), _ = run(initializing=True)
+    # on another backend no kernel is traced: no interpreter is needed
+    (_, (want, want_counts)), want_grads = run()
     assert not seen
-    np.testing.assert_array_equal(np.asarray(y, np.float32),
-                                  np.asarray(want, np.float32))
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
+    assert moe.grouped_runs_fused(512, 128, 128, dtype)
     with pltpu.force_tpu_interpret_mode():
         (_, (y, counts)), grads = run()
     assert set(seen) == {n_prefix}      # the overflow form traces none
@@ -362,7 +355,7 @@ def _fitting(lib, held):
 
 def _kernel_calls(text: str):
     """``(call sites, functions that hold a kernel)`` of a lowered module:
-    the kernels sit under ``jax.jit`` (``grouped_pallas._kernels``), so a
+    the kernels sit under ``jax.jit`` (``kernels.jitted``), so a
     call site is a ``call @_rows_product…`` and a kernel's code is in the
     module once a function, not once a call."""
     import re
@@ -378,8 +371,9 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
     backend patched to a TPU and the programs only lowered for one.
 
     - What ``Trainer.__init__`` traces to make the parameters holds no
-      kernel, at its own example's shapes (the Trainer is built here) and
-      at the step's: a module that initialises takes ``ragged_dot``.
+      kernel and no forward pass, at its own example's shapes (the Trainer
+      is built here) and at the step's: a module that initialises only
+      declares its variables.
     - The step's expert layers share one routed part (``jax.jit``: two
       functions, the forward's and the backward's, called once a layer
       each; ``mla_moe``'s prediction module, whose operations a profile
@@ -398,7 +392,7 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
     from tensorflowonspark_tpu.trainer import Trainer
 
     lib = mla_moe if model == "mla_moe" else lfm2_moe
-    monkeypatch.setattr(moe, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32)
     for held, forms in (((3, 7), 2), ((3, 7, 1, 10), 1)):
         config = _fitting(lib, held)
@@ -412,9 +406,8 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
         trainer = Trainer(model, config=config, devices=jax.devices()[:1])
         init = jax.jit(lambda: trainer.model.init(
             jax.random.PRNGKey(0), tokens, tokens)).trace()
-        # traced, the forward pass is there (lowering drops it: only the
-        # parameters are returned), on ``ragged_dot`` in every form
-        assert "ragged_dot" in str(init.jaxpr)
+        # no routed layer at all is traced, on the kernels or off them
+        assert "ragged_dot" not in str(init.jaxpr)
         assert "pallas_call" not in str(init.jaxpr)
         assert _kernel_calls(init.lower(
             lowering_platforms=("tpu",)).as_text()) == (0, 0, 0)

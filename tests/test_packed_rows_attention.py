@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models import (attention_pallas, granite_hybrid,
-                                          mla_moe, packed_rows)
+                                          kernels, mla_moe, packed_rows)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK = 512     # the backward pass's; the forward's are twice that
@@ -95,7 +95,7 @@ def kernels_on_the_cpu(monkeypatch):
     kernels run in Pallas's interpreter."""
     from jax.experimental.pallas import tpu as pltpu
 
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     with pltpu.force_tpu_interpret_mode():
         yield
 
@@ -231,7 +231,7 @@ def test_attention_rule_picks_the_kernels_on_a_tpu_where_heads_fill_lanes(
     assert jax.default_backend() == "cpu"
     assert not any(packed_rows.attention_runs_fused(*s)
                    for s in shapes.values())
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     assert packed_rows.attention_runs_fused(*shapes["glm"])
     for name in ("granite", "glm_tiny", "granite_tiny"):
         assert not packed_rows.attention_runs_fused(*shapes[name]), name
@@ -258,7 +258,7 @@ def test_both_models_count_the_execution_of_their_attention(
     lib = mla_moe if model.startswith("glm") else granite_hybrid
     config = (lib.Config.tiny() if model.endswith("tiny")
               else _published(model))
-    monkeypatch.setattr(packed_rows, "_backend", lambda: backend)
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     batch = {"segment_ids": np.zeros((1, config.seq_len), np.int32)}
     counts = lib.batch_counters(batch, config)
     assert (counts["attention_fused_steps_total"],

@@ -23,8 +23,8 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflowonspark_tpu.models import (conv_pallas, granite_hybrid,
-                                          kimi_linear, lfm2_moe, mla_moe,
-                                          packed_rows)
+                                          kernels, kimi_linear, lfm2_moe,
+                                          mla_moe, packed_rows)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T, C, TILE = 128, 256, 32
@@ -164,29 +164,28 @@ def test_tap_positions_are_the_document_positions_clipped():
             jnp.minimum(packed_rows.document_positions(seg), taps - 1))
 
 
-@pytest.mark.parametrize("t,c,taps,b,backend,initializing,fused", [
-    (8192, 4352, 4, "array", "tpu", False, True),   # granite's
-    (8192, 4096, 4, 0.0, "tpu", False, True),       # Kimi Linear's
-    (8192, 2048, 3, 0.0, "tpu", False, True),       # LFM2's
-    (8192, 4352, 4, "array", "cpu", False, False),  # another backend
-    (8192, 4352, 4, "array", "tpu", True, False),   # a module initialising
-    (8192, 4352, 8, 0.0, "tpu", False, False),      # taps past the halo
-    (8192, 4000, 4, 0.0, "tpu", False, False),      # no whole rows of lanes
-    (8000, 4096, 4, 0.0, "tpu", False, False),      # no whole tiles of rows
-    (64, 16, 4, 0.0, "tpu", False, False),          # Config.tiny()'s
+@pytest.mark.parametrize("t,c,taps,b,backend,fused", [
+    (8192, 4352, 4, "array", "tpu", True),      # granite's
+    (8192, 4096, 4, 0.0, "tpu", True),          # Kimi Linear's
+    (8192, 2048, 3, 0.0, "tpu", True),          # LFM2's
+    (8192, 4352, 4, "array", "cpu", False),     # another backend
+    (8192, 4352, 8, 0.0, "tpu", False),         # taps past the halo
+    (8192, 4000, 4, 0.0, "tpu", False),         # no whole rows of lanes
+    (8000, 4096, 4, 0.0, "tpu", False),         # no whole tiles of rows
+    (64, 16, 4, 0.0, "tpu", False),             # Config.tiny()'s
 ])
-def test_the_rule_reads_backend_shapes_and_initialisation(
-        t, c, taps, b, backend, initializing, fused, monkeypatch):
-    monkeypatch.setattr(packed_rows, "_backend", lambda: backend)
+def test_the_rule_reads_backend_and_shapes(t, c, taps, b, backend, fused,
+                                           monkeypatch):
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     b = np.zeros(c, np.float32) if b == "array" else b
-    assert packed_rows.conv_runs_fused(t, c, taps, b, initializing) is fused
+    assert packed_rows.conv_runs_fused(t, c, taps, b) is fused
 
 
 def test_causal_conv_calls_the_kernels_where_the_rule_says(monkeypatch):
     """``causal_conv`` with the backend patched to a TPU at a shape the
     kernels' own tile divides runs them (the interpreter here) and agrees
-    with the ``jnp`` form it takes on the CPU; while a module initialises
-    it traces none."""
+    with the ``jnp`` form it takes on the CPU; at a row their tile does
+    not divide it calls none."""
     t = 2 * conv_pallas.ROW_TILE
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(t, 128)), jnp.bfloat16)
@@ -199,10 +198,9 @@ def test_causal_conv_calls_the_kernels_where_the_rule_says(monkeypatch):
     real = conv_pallas.fused_conv
     monkeypatch.setattr(conv_pallas, "fused_conv",
                         lambda *a, **k: seen.append(1) or real(*a, **k))
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     np.testing.assert_array_equal(
-        packed_rows.causal_conv(x, w, 0.0, seg, initializing=True, **kw),
-        want)
+        packed_rows.causal_conv(x[:-8], w, 0.0, seg[:-8], **kw), want[:-8])
     assert not seen
     with pltpu.force_tpu_interpret_mode():
         got = packed_rows.causal_conv(x, w, 0.0, seg, **kw)
@@ -233,7 +231,7 @@ def test_the_kernels_at_their_own_tiles_through_causal_conv(monkeypatch):
         return jnp.sum(y.astype(jnp.float32) * cot)
 
     want, want_grads = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(x, w, b)
-    monkeypatch.setattr(packed_rows, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     assert packed_rows.conv_runs_fused(t, c, 4, b)
     with pltpu.force_tpu_interpret_mode():
         got, grads = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(x, w, b)
@@ -269,7 +267,7 @@ def test_the_three_models_count_the_execution_of_their_convolution(
            "kimi": kimi_linear}[model.split("_")[0]]
     config = (lib.Config.tiny() if model.endswith("tiny")
               else _published(model))
-    monkeypatch.setattr(packed_rows, "_backend", lambda: backend)
+    monkeypatch.setattr(kernels, "backend", lambda: backend)
     batch = {"segment_ids": np.zeros((1, config.seq_len), np.int32)}
     counts = lib.batch_counters(batch, config)
     assert (counts["conv_fused_steps_total"],
