@@ -32,6 +32,21 @@ from the shapes alone, the segment ids only enter the mask — so a step's
 time does not follow its row.  Only the blocks the diagonal crosses compare
 positions.
 
+Under a sliding ``window`` (``i - j < window``) the loops **skip the blocks
+the window cannot reach**: a block of queries starts at the first block of
+keys that holds a key within ``window - 1`` of its first query, a block of
+keys ends at the last block of queries whose first query is within ``window
+- 1`` of its last key (:func:`forward_bounds`, :func:`backward_bounds`:
+``program_id`` arithmetic with the window in it, never the segment ids).
+The blocks the window's far edge crosses compare positions as the
+diagonal's do; the blocks wholly inside compare documents only.  The same
+blocks serve a window as serve none (read on the chip at 8,192 x 32/4 x 128
+and a window of 1,024, kernels alone, ms a call, PERF.md, PR 47: forward
+2.94 at 1,024 x 1,024, 2.86 at 512 x 1,024, 3.16 at 512 x 512, 5.23 at 256
+x 256, for 5.35 with no window; forward and backward 8.26 with the backward
+pass at 512 x 512, 8.28 at 256 x 512, 8.87 at 256 x 256, 8.94 at 1,024 x
+1,024, for 16.12 with no window).
+
 ``packed_rows.attention_runs_fused`` says when this runs
 (``kernels.runs_fused`` of :func:`fits`); interpret mode
 (``pltpu.force_tpu_interpret_mode``) runs it on the CPU for the tests.
@@ -95,7 +110,7 @@ def _offsets(rows: int, cols: int, by_row: bool):
     return r - c if by_row else c - r
 
 
-def _forward_kernel(scale, dtype, bk, q_ref, k_ref, v_ref, seg_q_ref,
+def _forward_kernel(scale, dtype, bk, window, q_ref, k_ref, v_ref, seg_q_ref,
                     seg_k_ref, out_ref, lse_ref, m_ref, l_ref, acc_ref):
     import jax
     import jax.numpy as jnp
@@ -109,11 +124,13 @@ def _forward_kernel(scale, dtype, bk, q_ref, k_ref, v_ref, seg_q_ref,
     l_ref[...] = jnp.zeros(l_ref.shape, f32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
-    def visit(j, diagonal: bool):
+    def visit(j, diagonal: bool, edge: bool = False):
         at = _rows_at(j, bk)
         mask = seg_q == seg_k_ref[j]
         if diagonal:
             mask = mask & (_offsets(bq, bk, True) >= j * bk - i * bq)
+        if edge:
+            mask = mask & (_offsets(bq, bk, True) < window + j * bk - i * bq)
         s = jnp.where(mask, _dot(q, k_ref[at, :], (1, 1)) * scale, MASKED)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -126,22 +143,29 @@ def _forward_kernel(scale, dtype, bk, q_ref, k_ref, v_ref, seg_q_ref,
         acc_ref[...] = acc_ref[...] * fade + _dot(
             p.astype(dtype), v_ref[at, :], (1, 0))
 
+    def visits(lo, hi, edge: bool):
+        def body(j, carry):
+            visit(j, False, edge)
+            return carry
+
+        jax.lax.fori_loop(lo, hi, body, 0)
+
     below = (i * bq) // bk      # blocks of keys wholly before the queries
-
-    def body(j, carry):
-        visit(j, False)
-        return carry
-
-    jax.lax.fori_loop(0, below, body, 0)
+    if window is None:
+        visits(0, below, False)
+    else:
+        first, inside = forward_bounds(i, bq, bk, window)
+        visits(first, inside, True)
+        visits(inside, below, False)
     for d in range(max(1, bq // bk)):
-        visit(below + d, True)
+        visit(below + d, True, _edge_on_diagonal(window, bq, bk))
     l = l_ref[...]
     out_ref[...] = (acc_ref[...] / l).astype(out_ref.dtype)
     lse = m_ref[...] + jnp.log(l)
     lse_ref[...] = jnp.broadcast_to(lse, (bq, 128)).T[0:1]
 
 
-def _backward_kernel(scale, dtype, bq, q_ref, do_ref, k_ref, v_ref,
+def _backward_kernel(scale, dtype, bq, window, q_ref, do_ref, k_ref, v_ref,
                      seg_k_ref, seg_q_ref, lse_ref, delta_ref, dq_ref,
                      dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
     import jax
@@ -160,12 +184,14 @@ def _backward_kernel(scale, dtype, bq, q_ref, do_ref, k_ref, v_ref,
     dv_acc[...] = jnp.zeros(dv_acc.shape, f32)
     k, v, seg_k = k_ref[...], v_ref[...], seg_k_ref[...]
 
-    def visit(i, diagonal: bool):
+    def visit(i, diagonal: bool, edge: bool = False):
         at = _rows_at(i, bq)
         q, do = q_ref[at, :], do_ref[at, :]
         mask = seg_k == seg_q_ref[i]
         if diagonal:
             mask = mask & (_offsets(bk, bq, False) >= j * bk - i * bq)
+        if edge:
+            mask = mask & (_offsets(bk, bq, False) < window + j * bk - i * bq)
         s = jnp.where(mask, _dot(k, q, (1, 1)) * scale, MASKED)
         p = jnp.exp(s - lse_ref[i])
         ds = p * (_dot(v, do, (1, 1)) - delta_ref[i]) * scale
@@ -174,23 +200,85 @@ def _backward_kernel(scale, dtype, bq, q_ref, do_ref, k_ref, v_ref,
         dk_acc[...] += _dot(ds, q, (1, 0))
         dq_acc[at, :] += _dot(ds, k, (0, 0))
 
+    def visits(lo, hi, edge: bool):
+        def body(i, carry):
+            visit(i, False, edge)
+            return carry
+
+        jax.lax.fori_loop(lo, hi, body, 0)
+
     first = (j * bk) // bq      # the first block of queries at these keys
     crossed = max(1, bk // bq)
     for d in range(crossed):
-        visit(first + d, True)
-
-    def body(i, carry):
-        visit(i, False)
-        return carry
-
-    jax.lax.fori_loop(first + crossed, q_ref.shape[0] // bq, body, 0)
+        visit(first + d, True, _edge_on_diagonal(window, bq, bk))
+    after, end = first + crossed, q_ref.shape[0] // bq
+    if window is None:
+        visits(after, end, False)
+    else:
+        inside, last = backward_bounds(j, bq, bk, window, end)
+        visits(after, inside, False)
+        visits(inside, last, True)
     dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
     # no later block of keys reaches these queries
     dq_ref[...] = dq_acc[_rows_at(j, bk), :].astype(dq_ref.dtype)
 
 
-def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
+def _edge_on_diagonal(window, bq: int, bk: int) -> bool:
+    """Whether a block the diagonal crosses holds a query and a key
+    ``window`` or more apart (they are under ``max(bq, bk)`` apart)."""
+    return window is not None and window < max(bq, bk)
+
+
+def forward_bounds(i, bq: int, bk: int, window: int) -> tuple:
+    """``(first, inside)`` of the forward kernel's block ``i`` of queries
+    under a window: the first block of keys with a key inside the first
+    query's window, and the first whose every key is inside the last
+    query's (no further than the diagonal's); the blocks between them are
+    the ones the window's far edge crosses.  ``i`` a ``program_id`` or a
+    Python number: shapes and the window alone."""
+    import jax.numpy as jnp
+
+    below = (i * bq) // bk
+    first = jnp.maximum(i * bq - (window - 1), 0) // bk
+    return first, jnp.clip((jnp.maximum(i * bq + bq - window, 0) + bk - 1)
+                           // bk, first, below)
+
+
+def backward_bounds(j, bq: int, bk: int, window: int, end) -> tuple:
+    """``(inside, last)`` of the backward kernel's block ``j`` of keys under
+    a window, both ends of a range: past the last block of queries (behind
+    the diagonal's, of ``end`` in the row) whose every query's window holds
+    the block's first key, and past the last with a query whose window
+    holds its last key; the blocks between them are the ones the window's
+    far edge crosses."""
+    import jax.numpy as jnp
+
+    after = (j * bk) // bq + max(1, bk // bq)
+    last = jnp.minimum((j * bk + bk + window - 2) // bq + 1, end)
+    return jnp.clip((jnp.maximum(j * bk + window - bq + 1, 0) + bq - 1)
+                    // bq, after, jnp.maximum(last, after)), last
+
+
+def visited(t: int, forward: tuple, backward: tuple, window=None) -> tuple:
+    """``(forward, backward)``: the (block of queries, block of keys) pairs
+    the two kernels' loops visit on a row of ``t`` tokens at each pass's
+    (queries, keys) a tile, from the bounds the kernels compute."""
+    bq, bk = _blocks(t, forward)
+    n_forward = sum(
+        (i * bq) // bk + max(1, bq // bk) - (0 if window is None else int(
+            forward_bounds(i, bq, bk, window)[0]))
+        for i in range(t // bq))
+    bq, bk = _blocks(t, backward)
+    end = t // bq
+    n_backward = sum(
+        (end if window is None else int(
+            backward_bounds(j, bq, bk, window, end)[1])) - (j * bk) // bq
+        for j in range(t // bk))
+    return n_forward, n_backward
+
+
+def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk, window=None):
     """``out`` (T, heads x hd) in ``dtype`` and the log-sum-exp (heads,
     T / bq, 1, bq) float32."""
     import jax
@@ -204,7 +292,7 @@ def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
     queries = pl.BlockSpec((bq, hd), lambda h, i: (i, h))
     row = pl.BlockSpec((t, hd), lambda h, i: (0, h // rep))
     return pl.pallas_call(
-        functools.partial(_forward_kernel, scale, dtype, bk),
+        functools.partial(_forward_kernel, scale, dtype, bk, window),
         grid=(heads, t // bq),
         in_specs=[queries, row, row,
                   pl.BlockSpec((bq, 1), lambda h, i: (i, 0)),
@@ -220,7 +308,8 @@ def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk):
     )(q2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bk, 1, bk))
 
 
-def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk):
+def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk,
+              window=None):
     """``dq``, ``dk``, ``dv`` a query head (T, heads x hd): ``dq`` in
     ``dtype``; ``dk`` and ``dv`` too where a key head serves one query
     head, else float32 for the sum over its ``rep``.  ``lse`` and ``delta``
@@ -240,7 +329,7 @@ def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk):
     gradient = pl.BlockSpec((bk, hd), lambda h, j: (j, h))
     summed = jax.ShapeDtypeStruct(q2.shape, dtype if rep == 1 else f32)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, scale, dtype, bq),
+        functools.partial(_backward_kernel, scale, dtype, bq, window),
         grid=(heads, t // bk),
         in_specs=[row, row, keys, keys,
                   pl.BlockSpec((bk, 1), lambda h, j: (j, 0)),
@@ -261,15 +350,17 @@ def _heads_along_lanes(x, dtype):
     return x.reshape(x.shape[0], -1).astype(dtype)
 
 
-def _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward, backward):
-    out, lse = jitted(_forward, (4, 5, 6, 7, 8))(
+def _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward, backward,
+                window):
+    out, lse = jitted(_forward, (4, 5, 6, 7, 8, 9))(
         *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg, scale,
-        dtype, q.shape[-1], *forward)
+        dtype, q.shape[-1], *forward, window)
     out = out.reshape(q.shape)
     return out, (q, k, v, seg, out, lse)
 
 
-def _attend_bwd(scale, dtype, scopes, forward, backward, saved, d_out):
+def _attend_bwd(scale, dtype, scopes, forward, backward, window, saved,
+                d_out):
     import jax
     import jax.numpy as jnp
 
@@ -279,12 +370,12 @@ def _attend_bwd(scale, dtype, scopes, forward, backward, saved, d_out):
     bq = backward[0]
     with under(scopes):
         delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
-        dq, dk, dv = jitted(_backward, (7, 8, 9, 10, 11))(
+        dq, dk, dv = jitted(_backward, (7, 8, 9, 10, 11, 12))(
             *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg,
             lse.reshape(kv * rep, t // bq, 1, bq),
             _heads_along_lanes(d_out, dtype),
             delta.reshape(t // bq, 1, bq, kv * rep).transpose(3, 0, 1, 2),
-            scale, dtype, hd, *backward)
+            scale, dtype, hd, *backward, window)
         if rep > 1:
             dk, dv = (g.reshape(q.shape).sum(2) for g in (dk, dv))
     return (dq.reshape(q.shape).astype(q.dtype),
@@ -297,10 +388,11 @@ def _attend_bwd(scale, dtype, scopes, forward, backward, saved, d_out):
 def _attend():
     import jax
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-    def attend(q, k, v, seg, scale, dtype, scopes, forward, backward):
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+    def attend(q, k, v, seg, scale, dtype, scopes, forward, backward,
+               window):
         return _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward,
-                           backward)[0]
+                           backward, window)[0]
 
     attend.defvjp(_attend_fwd, _attend_bwd)
     return attend
@@ -308,15 +400,17 @@ def _attend():
 
 def fused_attention(q, k, v, seg, scale: float, dtype, scopes: tuple,
                     forward: tuple = FORWARD_BLOCKS,
-                    backward: tuple = BACKWARD_BLOCKS):
+                    backward: tuple = BACKWARD_BLOCKS, window=None):
     """``packed_rows.document_attention`` on the kernels, for shapes that
     :func:`fits` admits: ``q`` (T, kv, rep, hd), ``k`` and ``v`` (T, kv,
     hd), ``seg`` (T,); returns (T, kv, rep, hd) in ``dtype``.  The backward
     pass runs under the ``jax.named_scope``s ``scopes``.  ``forward`` and
-    ``backward`` are each pass's (queries, keys) a tile."""
+    ``backward`` are each pass's (queries, keys) a tile; ``window`` is a
+    sliding-window layer's (None: none)."""
     import jax.numpy as jnp
 
     t = q.shape[0]
     return _attend()(q, k, v, seg.astype(jnp.int32), float(scale),
                      jnp.dtype(dtype), tuple(scopes), _blocks(t, forward),
-                     _blocks(t, backward))
+                     _blocks(t, backward),
+                     None if window is None else int(window))

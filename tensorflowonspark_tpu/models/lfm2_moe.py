@@ -50,13 +50,13 @@ feed-forward); ``moe_router``, ``moe_dispatch``, ``moe_experts``,
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import numpy as np
 
 from tensorflowonspark_tpu.models import packed_decoder
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, causal_conv, document_attention, mm, rms, rope, row_counters)
+    block, causal_conv, grouped_query_attention, mm, rms, rope_frequencies,
+    row_counters)
 
 #: no sequence-parallel sharding: the convolution has no halo over ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -202,26 +202,13 @@ def conv_mixer(params, prefix: str, h, seg):
 
 
 def attention(params, prefix: str, h, seg, pos, config: Config):
-    """Grouped-query attention on one row, every query and key head normed
-    and then rotated: ``h`` (T, D) -> (T, D).  Query head ``i`` reads key
-    head ``i // (heads / kv)``."""
-    import jax
-
-    dtype, t = h.dtype, h.shape[0]
-    kv, hd = config.num_key_value_heads, config.head_dim
-    rep = config.num_attention_heads // kv
-    q = mm("td,de->te", h, params[prefix + "wq"], dtype)
-    k = mm("td,de->te", h, params[prefix + "wk"], dtype)
-    v = mm("td,de->te", h, params[prefix + "wv"], dtype).reshape(t, kv, hd)
-    with jax.named_scope("qk_norm_rope"):
-        q = rope(rms(q.reshape(t, kv, rep, hd), params[prefix + "q_norm"],
-                     config.norm_eps), pos, config.rope_theta)
-        k = rope(rms(k.reshape(t, kv, hd), params[prefix + "k_norm"],
-                     config.norm_eps), pos, config.rope_theta)
-    o = document_attention(q, k, v, seg, 1.0 / math.sqrt(hd),
-                           block(t, config.attention_block), dtype)
-    return mm("te,ed->td", o.reshape(t, kv * rep * hd), params[prefix + "wo"],
-              dtype)
+    """``packed_rows.grouped_query_attention`` at this layout's sizes: plain
+    RoPE(``rope_theta``) over a whole head, no window."""
+    return grouped_query_attention(
+        params, prefix, h, seg, pos, heads=config.num_attention_heads,
+        kv=config.num_key_value_heads, hd=config.head_dim,
+        eps=config.norm_eps, size=block(h.shape[0], config.attention_block),
+        freq=rope_frequencies(config.rope_theta, config.head_dim // 2))
 
 
 def _layer(mixer: str, ffn: str, prefix: str, config: Config, scopes: tuple,

@@ -1,14 +1,15 @@
 """The registry's side of a decoder trained on packed rows, once
-(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``).
+(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``,
+``mellum_moe``).
 
 A decoder's module keeps what is its own — ``Config``, ``ADAMW``,
 ``leaf_shapes``, its ``layer_kinds``, its mixers, its ``_layer`` (which names
 the ``jax.named_scope``s), its ``logits``, its ``batch_counters`` — and
 describes itself in one :class:`Decoder`, whose methods it binds to the names
 the registry promises (``models/__init__.py``: ``make_model =
-_DECODER.make_model`` and so on, a dozen lines at the end of each of the four).
+_DECODER.make_model`` and so on, a dozen lines at the end of each of the five).
 
-What is here has one body for the four: the loop over the layers (a layer's
+What is here has one body for the five: the loop over the layers (a layer's
 leaves sliced by prefix, the layer recomputed in the backward pass but for
 what the model names to keep, the routing bias's row threaded to an expert
 layer and its counts gathered: :func:`run_layer` is the one-layer form), the
@@ -20,7 +21,7 @@ stateful loss and forward wrappers, and what of the routing state the
 program's counters show.  A recomputation policy, a batch axis or one
 ``jax.jit`` a layer shape is written here, once.
 
-What holds for all four and is no option of any: parameters are float32 (a
+What holds for all five and is no option of any: parameters are float32 (a
 flat dict; the flax module only declares them and the ``moe`` collection,
 and while it initialises traces no forward pass), activations
 ``Config.dtype``, the mathematics pure functions over the dict; every layer

@@ -1,26 +1,29 @@
 """The mathematics the decoders trained on packed rows have in common
-(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``;
-``packed_decoder`` holds their skeleton): the RMS norm, the product with
-operands in the activations' type, the SwiGLU feed-forward, the positions
-inside documents and the rotary embedding at them, the depthwise causal
-convolution that stops at a document's first token, causal attention inside
-documents a block of queries at a time (the values at their own width),
-latent attention over it, and the next-token cross-entropy a block of
-tokens at a time.  A packed row is ``T`` tokens with segment ids ``s`` (the
-document's number inside the row; documents are contiguous and their ids
-differ).  One implementation of each piece, each called by two models or more
-(:func:`causal_conv`: granite's state-space mixers, LFM2's gated short
-convolutions and Kimi Linear's delta-rule mixers; :func:`rope` and
-:func:`document_positions`: GLM's latent attention and LFM2's grouped-query
-attention; :func:`latent_attention`: GLM's, with a query latent and RoPE, and
-Kimi Linear's, with neither): what is measured on one model's cell is what
-the others run.
+(``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``,
+``mellum_moe``; ``packed_decoder`` holds their skeleton): the RMS norm, the
+product with operands in the activations' type, the SwiGLU feed-forward, the
+positions inside documents and the rotary embedding at them (plain or YaRN
+frequencies), the depthwise causal convolution that stops at a document's
+first token, causal attention inside documents a block of queries at a time
+(the values at their own width; under a sliding window the blocks behind it
+not visited), the grouped-query layer and latent attention over it, and the
+next-token cross-entropy a block of tokens at a time.  A packed row is ``T``
+tokens with segment ids ``s`` (the document's number inside the row;
+documents are contiguous and their ids differ).  One implementation of each
+piece, each called by two models or more (:func:`causal_conv`: granite's
+state-space mixers, LFM2's gated short convolutions and Kimi Linear's
+delta-rule mixers; :func:`rope` and :func:`document_positions`: GLM's latent
+attention and the grouped-query layer; :func:`grouped_query_attention`:
+LFM2's, with plain RoPE and no window, and Mellum's, with a window and a
+rotation by layer type; :func:`latent_attention`: GLM's, with a query latent
+and RoPE, and Kimi Linear's, with neither): what is measured on one model's
+cell is what the others run.
 
 Attention and the convolution are each one algorithm with two executions
 (:func:`document_attention`, :func:`causal_conv`): on a TPU at shapes that
-fill their tiles (a head in whole rows of 128 lanes — GLM's 20 x 256, not
-granite's and LFM2's 32/8 x 64 nor Kimi Linear's keys of 192 beside values
-of 128 —; the channels in whole rows of lanes and the row in whole tiles:
+fill their tiles (a head in whole rows of 128 lanes — GLM's 20 x 256 and
+Mellum's 32/4 x 128, not granite's and LFM2's 32/8 x 64 nor Kimi Linear's
+keys of 192 beside values of 128 —; the channels in whole rows of lanes and the row in whole tiles:
 the published 8,192 x 4,352, x 4,096 and x 2,048) the Pallas kernels of
 ``attention_pallas`` and ``conv_pallas``, anywhere else (``Config.tiny()``,
 the tests) the ``jnp`` forms in this file, which are also the kernels'
@@ -31,7 +34,7 @@ rules (``kernels.runs_fused``), and the models' steps count which applied
 
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
-are here too: host code, one copy for the four.
+are here too: host code, one copy for the five.
 
 JAX is imported where it is used, as in the models.
 """
@@ -103,17 +106,60 @@ def document_positions(seg):
     return at - jax.lax.cummax(jnp.where(first, at, 0))
 
 
-def rope(x, pos, theta: float):
+def rope_frequencies(theta: float, half: int):
+    """(half,) float32: plain RoPE's ``theta ** (-i / half)``."""
+    import jax.numpy as jnp
+
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def yarn_ramp(half: int, theta: float, original: int, beta_fast: float,
+              beta_slow: float) -> tuple:
+    """``(low, high, ramp)`` of YaRN's blend (arXiv:2309.00071, as
+    ``transformers``' ``_compute_yarn_parameters`` writes it): frequency
+    ``i`` turns ``c(r)`` times over the ``original`` positions where ``c(r)
+    = 2 half ln(original / (2 pi r)) / (2 ln theta)``; ``low =
+    floor(c(beta_fast))`` and ``high = ceil(c(beta_slow))`` inside ``[0, 2
+    half - 1]``, ``ramp`` (half,) ``clip((i - low) / (high - low), 0, 1)``:
+    0 where a frequency is kept, 1 where it is divided by the factor."""
+    import math
+
+    def turns(r):
+        return (2 * half * math.log(original / (2 * math.pi * r))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), 2 * half - 1)
+    span = (high - low) or 0.001
+    return low, high, np.clip((np.arange(half) - low) / span, 0.0,
+                              1.0).astype(np.float32)
+
+
+def yarn_frequencies(theta: float, half: int, factor: float, original: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """(half,) float32: plain RoPE's frequencies, those that turn fewer
+    than ``beta_slow`` times over the ``original`` positions divided by
+    ``factor``, those that turn more than ``beta_fast`` times kept, the ones
+    between blended (:func:`yarn_ramp`).  Static: the same at every
+    length."""
+    ramp = yarn_ramp(half, theta, original, beta_fast, beta_slow)[2]
+    return rope_frequencies(theta, half) * ((1.0 - ramp) + ramp / factor)
+
+
+def rope(x, pos, freq, factor: float = 1.0):
     """Rotary embedding over the last axis of ``x`` (T, ..., R), the two
     halves rotated (``[a | b] -> [a cos - b sin | b cos + a sin]``), at the
-    positions ``pos`` (T,); float32 inside."""
+    positions ``pos`` (T,) by the frequencies ``freq`` (R / 2,), cosine and
+    sine times ``factor`` (YaRN's attention factor; 1: plain); float32
+    inside."""
     import jax.numpy as jnp
 
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = pos.astype(jnp.float32)[:, None] * freq
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
     cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
@@ -174,16 +220,32 @@ def causal_conv(xbc, w, b, seg, *, times=None, gate=None, silu: bool = False,
     return y.astype(out or jnp.float32)
 
 
-def _scores(qb, kb, sq, sk, pq, pk, scale, dtype):
+def _scores(qb, kb, sq, sk, pq, pk, scale, dtype, window=None):
     """One block of queries against one block of keys: the scaled scores
-    (kv, rep, i, j) and the mask ``j <= i and same document``."""
+    (kv, rep, i, j) and the mask ``j <= i and same document`` and, under a
+    ``window``, ``i - j < window``."""
     import jax.numpy as jnp
 
     s = mm("ikrd,jkd->krij", qb, kb, dtype, out=jnp.float32) * scale
-    return s, (pq[:, None] >= pk[None, :]) & (sq[:, None] == sk[None, :])
+    mask = (pq[:, None] >= pk[None, :]) & (sq[:, None] == sk[None, :])
+    if window is not None:
+        mask = mask & (pq[:, None] - pk[None, :] < window)
+    return s, mask
 
 
-def _attend_fwd(q, k, v, seg, scale, size, dtype):
+def first_key_block(i, size: int, window):
+    """The first block of ``size`` keys that a block of ``size`` queries
+    visits, ``i`` the queries' block: 0, or under a ``window`` the block of
+    the key ``window - 1`` before the block's first query.  From the shapes
+    and the window alone."""
+    import jax.numpy as jnp
+
+    if window is None:
+        return 0
+    return jnp.maximum(i * size - (window - 1), 0) // size
+
+
+def _attend_fwd(q, k, v, seg, scale, size, dtype, window=None):
     import jax
     import jax.numpy as jnp
 
@@ -200,17 +262,18 @@ def _attend_fwd(q, k, v, seg, scale, size, dtype):
         def keys(j, carry):
             m, l, acc = carry
             s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
-                              dtype)
+                              dtype, window)
             m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, -1e30), -1))
             p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
             fade = jnp.exp(m - m_new)
             return (m_new, l * fade + p.sum(-1), acc * fade[..., None]
                     + mm("krij,jkd->krid", p, vb[j], dtype, out=f32))
 
-        m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
-            jnp.full((kv, rep, size), -1e30, f32),
-            jnp.zeros((kv, rep, size), f32),
-            jnp.zeros((kv, rep, size, vd), f32)))
+        m, l, acc = jax.lax.fori_loop(
+            first_key_block(i, size, window), i + 1, keys, (
+                jnp.full((kv, rep, size), -1e30, f32),
+                jnp.zeros((kv, rep, size), f32),
+                jnp.zeros((kv, rep, size, vd), f32)))
         return ((acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype),
                 m + jnp.log(l))
 
@@ -233,7 +296,7 @@ def under(scopes):
     return stack
 
 
-def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
+def _attend_bwd(scale, size, dtype, scopes, window, saved, d_out):
     import jax
     import jax.numpy as jnp
 
@@ -252,7 +315,7 @@ def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
         def keys(j, inner):
             dq, dk, dv = inner
             s, mask = _scores(qb, kb[j], sq, segb[j], pq, posb[j], scale,
-                              dtype)
+                              dtype, window)
             p = jnp.where(mask, jnp.exp(s - lse_b[..., None]), 0.0)
             dp = mm("ikrd,jkd->krij", dob, vb[j], dtype, out=f32)
             ds = p * (dp - delta_b[..., None]) * scale
@@ -263,7 +326,8 @@ def _attend_bwd(scale, size, dtype, scopes, saved, d_out):
                                     out=f32)))
 
         dq, dk, dv = jax.lax.fori_loop(
-            0, i + 1, keys, (jnp.zeros(qb.shape, f32),) + carry)
+            first_key_block(i, size, window), i + 1, keys,
+            (jnp.zeros(qb.shape, f32),) + carry)
         return (dk, dv), dq.astype(dtype)
 
     with under(scopes):
@@ -284,12 +348,12 @@ def _attend():
     module imports JAX only when it is used)."""
     import jax
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-    def attend(q, k, v, seg, scale, size, dtype, scopes):
-        return _attend_fwd(q, k, v, seg, scale, size, dtype)[0]
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+    def attend(q, k, v, seg, scale, size, dtype, scopes, window=None):
+        return _attend_fwd(q, k, v, seg, scale, size, dtype, window)[0]
 
-    def fwd(q, k, v, seg, scale, size, dtype, scopes):
-        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype)
+    def fwd(q, k, v, seg, scale, size, dtype, scopes, window=None):
+        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype, window)
         return out, (q, k, v, seg, out, lse)
 
     attend.defvjp(fwd, _attend_bwd)
@@ -297,7 +361,7 @@ def _attend():
 
 
 def document_attention(q, k, v, seg, scale: float, size: int, dtype,
-                       scopes: tuple = ("attention",)):
+                       scopes: tuple = ("attention",), window=None):
     """Causal attention inside documents over one packed row, blocks of
     queries against blocks of keys with a running softmax: a block of
     queries visits the blocks of keys up to its own, so no score above the
@@ -311,6 +375,14 @@ def document_attention(q, k, v, seg, scale: float, size: int, dtype,
     which a latent-attention layout may make narrower than its keys —,
     ``seg`` (T,); returns (T, kv, rep, vd).
 
+    ``window`` (a sliding-window layer's; None: none): a query also sees
+    only the ``window`` keys up to its own (``i - j < window``, itself
+    among them; places in the row, which inside a document differ as the
+    places in it do), and **a block of queries starts at the first block of
+    keys its window reaches** (:func:`first_key_block`): the blocks behind
+    the window are not visited, forward or backward.  The loops' bounds
+    still come from the shapes and the window alone.
+
     One algorithm, two executions (:func:`attention_runs_fused`): on a TPU,
     where a head fills whole rows of lanes, the kernels of
     ``attention_pallas`` keep each score tile on the chip, forward and
@@ -321,8 +393,44 @@ def document_attention(q, k, v, seg, scale: float, size: int, dtype,
         from tensorflowonspark_tpu.models import attention_pallas
 
         return attention_pallas.fused_attention(q, k, v, seg, scale, dtype,
-                                                scopes)
-    return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes))
+                                                scopes, window=window)
+    return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes), window)
+
+
+def grouped_query_attention(params, prefix: str, h, seg, pos, *, heads: int,
+                            kv: int, hd: int, eps: float, size: int, freq,
+                            factor: float = 1.0, window=None,
+                            scopes: tuple = ("attention",),
+                            inner: str | None = None):
+    """Grouped-query attention on one row, every query and key head normed
+    (one RMS scale of a head's width each: ``q_norm``, ``k_norm``) and then
+    turned by :func:`rope` (``freq``, ``factor``) at the positions ``pos``:
+    ``h`` (T, D) -> (T, D) from ``wq``, ``wk``, ``wv``, ``wo`` under
+    ``prefix``.  Query head ``i`` reads key head ``i // (heads / kv)``; the
+    softmax is scaled by ``1 / sqrt(hd)`` and runs in
+    :func:`document_attention` in blocks of ``size`` queries, under a
+    ``window`` where the layer has one, and under the ``jax.named_scope``
+    ``inner`` where the caller names the blocks apart from the
+    projections (a model with layers of two masks)."""
+    import math
+
+    import jax
+
+    dtype, t, rep = h.dtype, h.shape[0], heads // kv
+    q = mm("td,de->te", h, params[prefix + "wq"], dtype)
+    k = mm("td,de->te", h, params[prefix + "wk"], dtype)
+    v = mm("td,de->te", h, params[prefix + "wv"], dtype).reshape(t, kv, hd)
+    with jax.named_scope("qk_norm_rope"):
+        q = rope(rms(q.reshape(t, kv, rep, hd), params[prefix + "q_norm"],
+                     eps), pos, freq, factor)
+        k = rope(rms(k.reshape(t, kv, hd), params[prefix + "k_norm"], eps),
+                 pos, freq, factor)
+    inner = (inner,) if inner else ()
+    with under(inner):
+        o = document_attention(q, k, v, seg, 1.0 / math.sqrt(hd), size,
+                               dtype, tuple(scopes) + inner, window)
+    return mm("te,ed->td", o.reshape(t, heads * hd), params[prefix + "wo"],
+              dtype)
 
 
 def latent_attention(params, prefix: str, h, seg, pos, *, heads: int,
@@ -360,9 +468,10 @@ def latent_attention(params, prefix: str, h, seg, pos, *, heads: int,
             t, heads, nope + v_dim)
         k_rope = kv_a[:, kv_rank:]
         if theta is not None:
+            freq = rope_frequencies(theta, rope_dim // 2)
             q = jnp.concatenate(
-                [q[..., :nope], rope(q[..., nope:], pos, theta)], -1)
-            k_rope = rope(k_rope, pos, theta)
+                [q[..., :nope], rope(q[..., nope:], pos, freq)], -1)
+            k_rope = rope(k_rope, pos, freq)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
             k_rope[:, None, :], (t, heads, rope_dim))], -1)
     o = document_attention(

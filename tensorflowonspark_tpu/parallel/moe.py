@@ -33,7 +33,8 @@ no token dropped** (the DeepSeek-V3 layout, arXiv:2412.19437; called through
 :func:`expert_ffn` by ``models/mla_moe.py``, ``lfm2_moe.py`` and
 ``kimi_linear.py``, each of which names its layout in a :class:`Routing`).
 The layer is told *which* of the router's experts this chip holds.  It
-scores every token against all of them (sigmoid, float32), chooses the
+scores every token against all of them (sigmoid, or a softmax over all of
+them where the layout's is one; float32), chooses the
 ``top_k`` of score plus a correction bias that takes no gradient, weighs the
 chosen by their normalised scores, keeps every slot (token, choice) whose
 expert is held — any number, from none to all — sorts the kept slots by
@@ -69,6 +70,9 @@ from typing import Any, Mapping, NamedTuple
 from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 
 logger = logging.getLogger(__name__)
+
+#: what a layout's router makes of its logits (:class:`Routing`'s ``score``)
+SCORES = ("sigmoid", "softmax")
 
 #: flax logical axes for each param — models pass these to
 #: ``nn.with_partitioning`` so ``param_sharding_from_metadata`` maps the
@@ -309,20 +313,27 @@ def init_params(rng, num_experts: int, model_dim: int, hidden_dim: int,
 
 
 def topk_route(h, router_w, router_bias, *, top_k: int, scale: float,
-               normalize: bool = True, sum_eps: float = 0.0):
+               normalize: bool = True, sum_eps: float = 0.0,
+               score: str = "sigmoid"):
     """``(chosen, gates)`` of tokens ``h`` (T, D): the scores are
-    ``sigmoid(h W_r)`` in float32 at the highest precision (a choice hangs
-    on them), ``chosen`` (T, k) the ``top_k`` experts by score plus
-    ``router_bias`` (E,), ``gates`` (T, k) ``scale`` times the chosen
-    scores, over their sum (plus ``sum_eps``, where a layout writes one:
-    ``lfm2_moe``'s 1e-6) if ``normalize``.  The gradient runs through the
-    scores and not through the choice or the bias."""
+    ``sigmoid(h W_r)`` or, where the layout's ``score`` is ``"softmax"``,
+    the softmax of ``h W_r`` over all the router's experts, in float32 at
+    the highest precision (a choice hangs on them), ``chosen`` (T, k) the
+    ``top_k`` experts by score plus ``router_bias`` (E,), ``gates`` (T, k)
+    ``scale`` times the chosen scores, over their sum (plus ``sum_eps``,
+    where a layout writes one: ``lfm2_moe``'s 1e-6) if ``normalize``.  The
+    gradient runs through the scores and not through the choice or the
+    bias."""
     import jax
     import jax.numpy as jnp
 
-    scores = jax.nn.sigmoid(jnp.einsum(
+    if score not in SCORES:
+        raise ValueError(f"score {score!r}: want one of {sorted(SCORES)}")
+    logits = jnp.einsum(
         "td,de->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
     _, chosen = jax.lax.top_k(
         jax.lax.stop_gradient(scores) + router_bias, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
@@ -543,7 +554,8 @@ def _routed_part(scopes: tuple = ()):
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
                    top_k: int, scale: float, normalize: bool = True,
-                   sum_eps: float = 0.0, scopes: tuple = ()):
+                   sum_eps: float = 0.0, score: str = "sigmoid",
+                   scopes: tuple = ()):
     """The held experts' part of a routed SwiGLU layer on tokens ``x``
     (T, D): ``sum over e chosen and held of g_e W_down_e (silu(x W_gate_e)
     * (x W_up_e))``, and the tokens that chose each of the router's experts.
@@ -553,8 +565,8 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     experts held here, ``held`` (a static sequence of ``H`` distinct ids in
     ``[0, E)``) says which they are, in the weights' order.  Products take
     operands in ``x``'s type and accumulate in float32; routing is float32
-    (:func:`topk_route`, which ``top_k``, ``scale``, ``normalize`` and
-    ``sum_eps`` go to).  ``scopes``
+    (:func:`topk_route`, which ``top_k``, ``scale``, ``normalize``,
+    ``sum_eps`` and ``score`` go to).  ``scopes``
     are the ``jax.named_scope``s the caller has opened round the layer, where
     a profile should tell its operations from another layer's (``mla_moe``'s
     prediction module): layers that name the same share one traced part.
@@ -579,7 +591,7 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     with jax.named_scope("moe_router"):
         chosen, gates = topk_route(x, router_w, router_bias, top_k=top_k,
                                    scale=scale, normalize=normalize,
-                                   sum_eps=sum_eps)
+                                   sum_eps=sum_eps, score=score)
         slot_expert = chosen.reshape(-1)
         counts = jnp.sum(slot_expert[:, None] == jnp.arange(n_experts),
                          axis=0, dtype=jnp.int32)
@@ -615,6 +627,7 @@ class Routing(NamedTuple):
     normalize: bool
     speed: float            # the correction bias's step (0.0: it stays)
     sum_eps: float = 0.0    # :func:`topk_route`'s
+    score: str = "sigmoid"  # the layout's, of :data:`SCORES`
 
 
 def expert_ffn(params, prefix: str, h, bias, routing: Routing, *,
@@ -640,7 +653,8 @@ def expert_ffn(params, prefix: str, h, bias, routing: Routing, *,
         h, params[prefix + "router"], bias, params[prefix + "experts_gate"],
         params[prefix + "experts_up"], params[prefix + "experts_down"],
         routing.held, top_k=routing.top_k, scale=routing.scale,
-        normalize=routing.normalize, sum_eps=routing.sum_eps, scopes=scopes)
+        normalize=routing.normalize, sum_eps=routing.sum_eps,
+        score=routing.score, scopes=scopes)
     return (y + routed if shared else routed), counts
 
 

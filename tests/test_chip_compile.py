@@ -317,6 +317,55 @@ def test_attention_kernels_compile_at_the_published_widths(topo):
                 < 6 * t * heads * hd * 2)
 
 
+def test_window_attention_kernels_compile_at_the_published_widths(topo):
+    """The same kernels with a window, at the shapes ``mellum2_packed_8k``
+    runs — one row of 8,192 tokens, 32 query heads of 128 on 4 key heads,
+    bfloat16, a window of 1,024 — forward and gradient through the TPU's
+    compiler: the loops' bounds are ``program_id`` arithmetic with the
+    window in it (a clip, a floor division), which interpret mode cannot
+    refuse; every kernel call carries the caller's scopes, and no score
+    leaves a kernel."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import attention_pallas
+
+    t, kv, rep, hd = 8192, 4, 8, 128
+    assert attention_pallas.fits(t, hd)
+    one = SingleDeviceSharding(topo.devices[0])
+    bf = jnp.bfloat16
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((1, t, kv, rep, hd), bf), ((1, t, kv, hd), bf),
+        ((1, t, kv, hd), bf), ((1, t), jnp.int32))]
+    scopes = ("attention", "window_attention")
+
+    def loss(q, k, v, seg):
+        with jax.named_scope("attention"), \
+                jax.named_scope("window_attention"):
+            out = jax.vmap(lambda q, k, v, seg: (
+                attention_pallas.fused_attention(
+                    q, k, v, seg, hd ** -0.5, bf, scopes, window=1024)))(
+                        q, k, v, seg)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    for fn, kernels in ((loss, ["attention_forward"]),
+                        (jax.grad(loss, (0, 1, 2)),
+                         ["attention_forward", "attention_backward"])):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        names = re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+            compiled.as_text())
+        assert [n.split("/")[-2] for n in names] == kernels, names
+        assert all(re.search(rf"\b{scope}\b", name) for name in names
+                   for scope in scopes), names
+        # out, its float32 copy, the gradients; dk and dv a query head in
+        # float32 before their sum over a key head's eight
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 16 * t * kv * rep * hd * 2)
+
+
 @pytest.mark.parametrize("rows,k,n", [
     (24576, 2048, 1792), (24576, 1792, 2048),     # lfm2_8b_a1b_packed_8k
     (12288, 2048, 1536), (12288, 1536, 2048),     # glm47_flash_packed_8k
@@ -780,6 +829,66 @@ def test_kimi_linear_published_width_step_fits_one_v5e_chip(topo,
     _assert_conv_kernels(text, 4 * 3, "kda_mixer/kda_conv")
     _assert_kda_kernels(text, layers=4)
     assert "/attention_forward/" not in text
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    assert (_device_bytes(compiled) + stats.generated_code_size_in_bytes
+            < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
+
+
+@pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
+def test_mellum2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
+    """The ``mellum2_12b_a2_5b`` configuration as the benchmark builds it
+    (the published layers 0-3: three sliding-window layers and a
+    full-attention one, each with 16 of 64 softmax-routed experts held, a
+    quarter of the vocabulary and an untied head: 595,154,176 float32
+    parameters under AdamW) on one packed row of 8,192 tokens, through the
+    TPU compiler: parameters, both moments and the routing state are donated
+    and updated in place; attention at heads of 128 runs on the kernels of
+    ``attention_pallas`` under grouped queries (a layer calls the forward
+    kernel, calls it again in its recomputation and the backward kernel
+    once; the three sliding layers' under ``window_attention``, the full
+    one's under ``full_attention``, all under ``attention``); the grouped
+    products are the ones a chip runs (the Pallas kernels of
+    ``grouped_pallas`` in the form at ``moe.prefix_rows`` of the slots —
+    49,152 rows at a quarter share of 65,536 — and the compiler's own
+    ``ragged-dot`` kernels in the overflow form); and arguments,
+    temporaries and code stay under 15.75 GiB.  PERF.md section 4 holds the
+    figures."""
+    import json
+    import re
+
+    from benchmark.configs.mellum2_12b_a2_5b import program
+    from tensorflowonspark_tpu.models import packed_rows
+    from tensorflowonspark_tpu.parallel import grouped_pallas, moe
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mellum2_12b_a2_5b", "config.json")) as f:
+        published = json.load(f)
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
+    config = program.model_config(published)
+    assert packed_rows.attention_runs_fused(config.seq_len, config.head_dim)
+    rows = moe.prefix_rows(8 * 8192, 16, 64)
+    assert rows == 49152 and grouped_pallas.fits(rows, 2304, 896, "bfloat16")
+    step, state, batch = abstract_train_step(
+        "mellum_moe", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 595_154_176
+    assert state.collections["moe"]["bias"].shape == (4, 64)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"mellum2_12b_a2_5b, one described chip: {stats}")
+    text = compiled.as_text()
+    _assert_grouped_kernels(text, layers=4)
+    ours = [n for n in _pallas_calls(text) if "/attention_" in n]
+    for scope, layers in (("window_attention", 3), ("full_attention", 1)):
+        mine = [n for n in ours if re.search(rf"\b{scope}\b", n)]
+        for kernel, calls in (("attention_forward", 2),
+                              ("attention_backward", 1)):
+            assert sum(f"/{kernel}/" in n for n in mine) == layers * calls, \
+                (scope, kernel, mine)
+    assert all(re.search(r"\battention\b", n) for n in ours), ours
+    assert len(ours) == 12
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
     assert stats.argument_size_in_bytes < state_bytes + 2 ** 20

@@ -304,12 +304,15 @@ def test_lfm2_without_the_expert_bias_the_bias_neither_chooses_nor_moves(tiny):
 
 
 def test_the_shared_pieces_exist_once():
-    """``causal_conv``, ``rope``, ``document_positions`` and the routing
-    state are ``packed_rows``'s and ``parallel/moe.py``'s; the models that
-    call them hold the same objects, not copies."""
+    """``causal_conv``, the grouped-query layer (since PR 47, with the
+    rotation it calls), ``document_positions`` and the routing state are
+    ``packed_rows``'s and ``parallel/moe.py``'s; the models that call them
+    hold the same objects, not copies."""
     assert granite_hybrid.causal_conv is packed_rows.causal_conv
     assert lfm2_moe.causal_conv is packed_rows.causal_conv
-    assert lfm2_moe.rope is packed_rows.rope
+    assert lfm2_moe.grouped_query_attention \
+        is packed_rows.grouped_query_attention
+    assert not hasattr(lfm2_moe, "rope")
     # GLM's rotation went with its latent attention into ``packed_rows``,
     # and the positions both models' layers read are the skeleton's
     assert mla_moe.packed_rows is packed_rows and not hasattr(mla_moe, "rope")
@@ -510,7 +513,7 @@ def test_rope_turns_the_halves_by_the_position(tiny):
     rng = np.random.default_rng(14)
     x = jnp.asarray(rng.standard_normal((5, 2, 3, 16)), jnp.float32)
     pos = jnp.asarray([0, 1, 2, 0, 7], jnp.int32)
-    got = packed_rows.rope(x, pos, 1e6)
+    got = packed_rows.rope(x, pos, packed_rows.rope_frequencies(1e6, 8))
     _close(got, reference.rotate(x, pos, 1e6), tol=1e-6)
     np.testing.assert_array_equal(got[0], x[0])
     angle = 7 * 1e6 ** (-3 / 8)

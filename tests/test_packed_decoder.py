@@ -1,4 +1,4 @@
-"""The skeleton the four decoders trained on packed rows share
+"""The skeleton the five decoders trained on packed rows share
 (``models/packed_decoder.py``) and the one seam their kernels' rules read
 (``models/kernels.py``), each case once a decoder:
 
@@ -47,6 +47,9 @@ DECODERS = {
     "kimi_linear": (
         "kimi_linear_48b_a3b", ("kda_mixer", "attention"),
         {"kda_scan": 1, "conv": 1, "attention": 0, "moe_grouped": 1}),
+    "mellum_moe": (
+        "mellum2_12b_a2_5b", ("attention",),
+        {"attention": 1, "moe_grouped": 1}),
 }
 
 
