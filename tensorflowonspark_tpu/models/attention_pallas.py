@@ -60,7 +60,7 @@ import numpy as np
 
 from tensorflowonspark_tpu.models.kernels import (
     compiler_params, dot as _dot, jitted)
-from tensorflowonspark_tpu.models.packed_rows import under
+from tensorflowonspark_tpu.models.packed_rows import name_saved, under
 
 #: (queries, keys) a tile, forward and backward, chosen on the chip at the
 #: published 8,192 x 20 x 256 in bfloat16 (kernels alone, ms a call; PERF.md,
@@ -350,12 +350,16 @@ def _heads_along_lanes(x, dtype):
     return x.reshape(x.shape[0], -1).astype(dtype)
 
 
-def _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward, backward,
-                window):
+def _attend_primal(q, k, v, seg, scale, dtype, scopes, forward, backward,
+                   window):
     out, lse = jitted(_forward, (4, 5, 6, 7, 8, 9))(
         *(_heads_along_lanes(x, dtype) for x in (q, k, v)), seg, scale,
         dtype, q.shape[-1], *forward, window)
-    out = out.reshape(q.shape)
+    return out.reshape(q.shape), lse
+
+
+def _attend_fwd(q, k, v, seg, *static):
+    out, lse = name_saved(*_attend_primal(q, k, v, seg, *static))
     return out, (q, k, v, seg, out, lse)
 
 
@@ -391,8 +395,8 @@ def _attend():
     @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
     def attend(q, k, v, seg, scale, dtype, scopes, forward, backward,
                window):
-        return _attend_fwd(q, k, v, seg, scale, dtype, scopes, forward,
-                           backward, window)[0]
+        return _attend_primal(q, k, v, seg, scale, dtype, scopes, forward,
+                              backward, window)[0]
 
     attend.defvjp(_attend_fwd, _attend_bwd)
     return attend

@@ -11,8 +11,9 @@ _DECODER.make_model`` and so on, a dozen lines at the end of each of the five).
 
 What is here has one body for the five: the loop over the layers (a layer's
 leaves sliced by prefix, the layer recomputed in the backward pass but for
-what the model names to keep, the routing bias's row threaded to an expert
-layer and its counts gathered: :func:`run_layer` is the one-layer form), the
+attention's output and log-sum-exp and what the model names to keep
+besides, the routing bias's row threaded to an expert layer and its counts
+gathered: :func:`run_layer` is the one-layer form), the
 feed-forward half of an expert model's layer with its leaves' shapes
 (:func:`feed_forward`), the next-token loss over rows a block of tokens at a
 time (:func:`loss_sums`), the initializers two models or more draw from, the
@@ -25,15 +26,17 @@ What holds for all five and is no option of any: parameters are float32 (a
 flat dict; the flax module only declares them and the ``moe`` collection,
 and while it initialises traces no forward pass), activations
 ``Config.dtype``, the mathematics pure functions over the dict; every layer
-is recomputed in the backward pass, attention runs a block of queries at a
-time and the loss a block of tokens at a time.  An expert model is told
-which of its router's experts this chip holds (``Config.experts_held``, all
-unless told): the router stays as wide as published, the held experts' part
-is computed (``parallel/moe.py::routed_experts``) and what the others would
-have added is left out; no exchange runs and none is stood in for.  What
-takes no gradient — the correction biases, the counts behind them — is the
-``moe`` collection, which the Trainer's stateful step threads and
-checkpoints (one data shard is what has run: ``ROADMAP.md`` B).
+is recomputed in the backward pass but for what attention names
+(``packed_rows.ATTENTION_SAVED``: its forward blocks run once a step),
+attention runs a block of queries at a time and the loss a block of tokens
+at a time.  An expert model is told which of its router's experts this chip
+holds (``Config.experts_held``, all unless told): the router stays as wide
+as published, the held experts' part is computed
+(``parallel/moe.py::routed_experts``) and what the others would have added
+is left out; no exchange runs and none is stood in for.  What takes no
+gradient — the correction biases, the counts behind them — is the ``moe``
+collection, which the Trainer's stateful step threads and checkpoints (one
+data shard is what has run: ``ROADMAP.md`` B).
 
 JAX is imported where it is used, as in the models.
 """
@@ -48,13 +51,26 @@ from typing import Callable
 import numpy as np
 
 from tensorflowonspark_tpu.models.packed_rows import (
-    blocked_cross_entropy, document_positions, example_rows, loss_positions,
-    rms, swiglu)
+    ATTENTION_SAVED, blocked_cross_entropy, document_positions, example_rows,
+    loss_positions, rms, swiglu)
 
 
 #: the collection of a routed model's non-gradient state
 #: (``moe.routing_state_shapes``)
 COLLECTION = "moe"
+
+
+@functools.lru_cache(maxsize=None)
+def _keeping(names: tuple):
+    """The recomputation policy that keeps ``names``, made once: a
+    ``jax.jit`` inside a layer (the routed part, a kernel's call) is split
+    into what is kept and what is made again once a policy *object*, so the
+    layers share one traced and lowered function of it only while they
+    share the policy (``tests/test_moe_grouped.py`` counts the functions a
+    step holds: a warm start pays for each)."""
+    import jax
+
+    return jax.checkpoint_policies.save_only_these_names(*names)
 
 
 def run_layer(layer, params, prefix: str, kinds, config, x, seg, pos, bias,
@@ -66,15 +82,15 @@ def run_layer(layer, params, prefix: str, kinds, config, x, seg, pos, bias,
     the routing bias's row (each None where the model has none).
     ``scopes``: the named scopes the caller has opened round the layer (a
     custom backward pass opens them again); ``saved``: what the layer names
-    (``checkpoint_name``) for the recomputation to keep."""
+    (``checkpoint_name``) for the recomputation to keep, besides what
+    attention names in every layer that has it
+    (``packed_rows.ATTENTION_SAVED``)."""
     import jax
 
     mine = {k: v for k, v in params.items() if k.startswith(prefix)}
-    policy = (jax.checkpoint_policies.save_only_these_names(*saved)
-              if saved else None)
     return jax.checkpoint(
         functools.partial(layer, *kinds, prefix, config, scopes),
-        policy=policy)(mine, x, seg, pos, bias)
+        policy=_keeping((*ATTENTION_SAVED, *saved)))(mine, x, seg, pos, bias)
 
 
 def loss_sums(logits, states, tokens, seg, want: int, ahead: int = 1):

@@ -282,6 +282,20 @@ def _attend_fwd(q, k, v, seg, scale, size, dtype, window=None):
     return out.reshape(t, kv, rep, vd), lse
 
 
+#: what attention's forward rule names (``checkpoint_name``) of a layer: its
+#: output and its log-sum-exp, the two things its backward pass reads that
+#: only the forward blocks make.  A recomputed layer keeps them
+#: (``packed_decoder.run_layer``) and runs those blocks once a step.
+ATTENTION_SAVED = ("attention_out", "attention_lse")
+
+
+def name_saved(out, lse):
+    """``(out, lse)`` under the names :data:`ATTENTION_SAVED`."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return tuple(map(checkpoint_name, (out, lse), ATTENTION_SAVED))
+
+
 def under(scopes):
     """The ``jax.named_scope``s ``scopes``, opened one inside the other: a
     custom backward pass is traced outside the scopes its forward pass was
@@ -353,7 +367,8 @@ def _attend():
         return _attend_fwd(q, k, v, seg, scale, size, dtype, window)[0]
 
     def fwd(q, k, v, seg, scale, size, dtype, scopes, window=None):
-        out, lse = _attend_fwd(q, k, v, seg, scale, size, dtype, window)
+        out, lse = name_saved(*_attend_fwd(q, k, v, seg, scale, size, dtype,
+                                           window))
         return out, (q, k, v, seg, out, lse)
 
     attend.defvjp(fwd, _attend_bwd)

@@ -30,7 +30,8 @@ from jax.experimental.pallas import tpu as pltpu
 from benchmark.configs.kimi_linear_48b_a3b import program, reference
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import (kda_pallas, kernels, kimi_linear,
-                                          mla_moe, packed_rows)
+                                          mla_moe, packed_decoder,
+                                          packed_rows)
 from tensorflowonspark_tpu.parallel import moe
 
 BIG_SEED = 2 ** 31 + 4321           # the driver's seeds pass 32 signed bits
@@ -400,9 +401,10 @@ def test_a_layers_recomputation_keeps_what_the_scan_names(fused, monkeypatch):
         return grad.lower(params).as_text().count("stablehlo.while")
 
     kept = loops()
-    keep = jax.checkpoint_policies.save_only_these_names
-    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
-                        lambda *names: keep())
+    # (``run_layer`` makes its policy once a tuple of names: patch the maker)
+    monkeypatch.setattr(
+        packed_decoder, "_keeping",
+        lambda names: jax.checkpoint_policies.save_only_these_names())
     assert loops() > kept > 0
     assert not fused or kept == 1
 

@@ -7,7 +7,11 @@
 - ``kernels.backend`` alone turns every rule a model uses, and the pairs of
   counters its ``batch_counters`` writes with them;
 - the surface the registry promises (``models/__init__.py``) and the frozen
-  callers under ``benchmark/`` use is on each decoder's module.
+  callers under ``benchmark/`` use is on each decoder's module;
+- a layer's recomputation keeps what attention names
+  (``packed_rows.ATTENTION_SAVED``): the forward blocks run once in a
+  gradient, the gradient is the one the second run gave, and a layer with
+  no attention lowers to the text it had.
 
 (That the skeleton computes what the four copies computed is not held here:
 parameters, first loss and first gradients were bit-equal with the parent
@@ -15,6 +19,7 @@ commit in one sandbox, ``CHANGES.md`` PR 46; a digest of floats made on one
 host would make this file unsteady on another.)
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -233,3 +238,135 @@ def test_the_surface_the_registry_promises_is_on_the_module(name):
         assert lib.routing(config).held == config.experts_held
     else:
         assert not hasattr(lib, "device_counters")
+
+
+#: model -> what makes ``Config.tiny()`` one layer, of attention
+ONE_ATTENTION_LAYER = {
+    "granite_hybrid": dict(layer_types=("attention",)),
+    "mla_moe": dict(num_hidden_layers=1, first_k_dense_replace=0,
+                    num_nextn_predict_layers=0),
+    "lfm2_moe": dict(layer_types=("full_attention",), num_dense_layers=1),
+    "kimi_linear": dict(num_hidden_layers=1, kda_layers=(),
+                        full_attn_layers=(1,)),
+    "mellum_moe": dict(layer_types=("full_attention",), layers_run=(0,)),
+}
+#: ... and what makes that layer's heads fill the kernels' tiles (the two
+#: whose published heads do)
+ON_THE_KERNELS = {
+    "mla_moe": dict(qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=128,
+                    seq_len=128),
+    "mellum_moe": dict(head_dim=128, seq_len=128),
+}
+#: model -> what makes ``Config.tiny()`` one layer with no attention
+NO_ATTENTION_LAYER = {
+    "granite_hybrid": dict(layer_types=("mamba",)),
+    "lfm2_moe": dict(layer_types=("conv",), num_dense_layers=1),
+    "kimi_linear": dict(num_hidden_layers=1, kda_layers=(1,),
+                        full_attn_layers=()),
+}
+
+
+def _gradient(name: str, config):
+    """``(jitted gradient of the model's loss, its parameters)`` on one
+    example row, as the Trainer's step differentiates it."""
+    lib = zoo.get_model(name)
+    module = lib.make_model(config)
+    batch = lib.example_batch(config, 1, seq_len=config.seq_len)
+    variables = dict(module.init(jax.random.PRNGKey(0), batch["tokens"],
+                                 batch["segment_ids"]))
+    params = variables.pop("params")
+    loss_fn = lib.make_loss_fn(module, config)
+    if getattr(loss_fn, "stateful", False):
+        return jax.jit(jax.grad(
+            lambda p: loss_fn(p, variables, batch)[0])), params
+    return jax.jit(jax.grad(lambda p: loss_fn(p, batch))), params
+
+
+def _kernel_calls(jaxpr, kernel: str) -> int:
+    """The ``pallas_call`` equations named ``kernel`` in ``jaxpr`` and the
+    jaxprs inside it (the printed text writes a body that is called twice
+    once)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += kernel in str(eqn.params.get("name_and_src_info",
+                                              eqn.params.get("name")))
+        else:
+            n += sum(_kernel_calls(sub, kernel)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+@pytest.mark.parametrize("name,fused", [
+    *((name, False) for name in sorted(ONE_ATTENTION_LAYER)),
+    *((name, True) for name in sorted(ON_THE_KERNELS))])
+def test_a_layers_recomputation_keeps_what_attention_names(name, fused,
+                                                           monkeypatch):
+    """``run_layer`` recomputes a layer in the backward pass but for
+    ``packed_rows.ATTENTION_SAVED`` — attention's output and its
+    log-sum-exp, which both executions' forward rules name —, so the forward
+    blocks run once in a gradient and not twice: with the names taken out
+    of the policy the lowered gradient holds more loops (the ``jnp`` form)
+    or a second forward kernel (the kernels, traced with a TPU in the
+    backend's place), the backward pass is one either way, and the gradient
+    is the same (what is kept is what the second run made)."""
+    lib = zoo.get_model(name)
+    config = dataclasses.replace(lib.Config.tiny(),
+                                 **ONE_ATTENTION_LAYER[name],
+                                 **(ON_THE_KERNELS[name] if fused else {}))
+    if fused:
+        monkeypatch.setattr(kernels, "backend", lambda: "tpu")
+    assert lib.batch_counters(
+        {"segment_ids": np.zeros((1, config.seq_len), np.int32)}, config)[
+            "attention_fused_steps_total"] == int(fused)
+
+    def passes():
+        grad, params = _gradient(name, config)
+        if fused:
+            jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+            assert _kernel_calls(jaxpr, "attention_backward") == 1
+            return _kernel_calls(jaxpr, "attention_forward"), None
+        return (grad.lower(params).as_text().count("stablehlo.while"),
+                grad(params))
+
+    kept, ours = passes()
+    monkeypatch.setattr(packed_decoder, "ATTENTION_SAVED", ())
+    again, theirs = passes()
+    assert again > kept > 0
+    if fused:
+        assert (kept, again) == (1, 2)
+        return
+    largest = max(float(abs(g).max()) for g in jax.tree_util.tree_leaves(ours))
+    assert largest > 0
+    for key, g in ours.items():
+        assert float(abs(g - theirs[key]).max()) <= 1e-6 * largest, key
+
+
+@pytest.mark.parametrize("name", sorted(NO_ATTENTION_LAYER))
+def test_a_layer_with_no_attention_lowers_to_the_text_it_had(name,
+                                                             monkeypatch):
+    """A layer that names nothing of attention's (Mamba-2, the short
+    convolution, KDA) lowers under ``run_layer``'s policy to the text it
+    lowers to under the policy of the model's own names alone, which is
+    none at all where the model names nothing."""
+    lib = zoo.get_model(name)
+    config = dataclasses.replace(lib.Config.tiny(),
+                                 **NO_ATTENTION_LAYER[name])
+
+    def text():
+        grad, params = _gradient(name, config)
+        return grad.lower(params).as_text()
+
+    ours = text()
+    assert "stablehlo.while" in ours
+    saved = lib.make_model.__self__.saved
+    assert bool(saved) == (name == "kimi_linear")
+    keep, checkpoint = (jax.checkpoint_policies.save_only_these_names,
+                        jax.checkpoint)
+    given = []
+    # (``run_layer`` alone gives ``jax.checkpoint`` a policy)
+    monkeypatch.setattr(jax, "checkpoint", lambda f, policy=None: (
+        given.append(policy), checkpoint(
+            f, policy=keep(*saved) if policy and saved else None))[1])
+    assert text() == ours
+    assert sum(policy is not None for policy in given) == 1
