@@ -52,6 +52,19 @@ def save_pytree(state: Any, path: str) -> str:
     return path
 
 
+def saved_tree(path: str) -> Any:
+    """The tree a checkpoint written by :func:`save_pytree` holds, a leaf's
+    metadata in each array's place: what was saved, and no array is read."""
+    # orbax API drift: PyTreeCheckpointer.metadata returns the metadata tree
+    # directly (≤0.7-era), or an object carrying it under
+    # .item_metadata.tree (newer composite handlers)
+    meta_tree = _checkpointer().metadata(_canonical(path))
+    item_md = getattr(meta_tree, "item_metadata", None)
+    if item_md is not None:
+        meta_tree = getattr(item_md, "tree", item_md)
+    return meta_tree
+
+
 def load_pytree(path: str, target: Any | None = None) -> Any:
     """Restore a pytree saved by :func:`save_pytree`.
 
@@ -73,17 +86,10 @@ def load_pytree(path: str, target: Any | None = None) -> Any:
             import jax
             import numpy as np
 
-            ckptr = _checkpointer()
-            # orbax API drift: PyTreeCheckpointer.metadata returns the
-            # metadata tree directly (≤0.7-era), or an object carrying it
-            # under .item_metadata.tree (newer composite handlers)
-            meta_tree = ckptr.metadata(path)
-            item_md = getattr(meta_tree, "item_metadata", None)
-            if item_md is not None:
-                meta_tree = getattr(item_md, "tree", item_md)
             restore_args = jax.tree.map(
-                lambda _: ocp.RestoreArgs(restore_type=np.ndarray), meta_tree)
-            return ckptr.restore(
+                lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
+                saved_tree(path))
+            return _checkpointer().restore(
                 path, args=ocp.args.PyTreeRestore(restore_args=restore_args))
 
         # carry the TARGET's shardings into the restore: without them orbax
@@ -144,15 +150,11 @@ class CheckpointManager:
             return self._mgr.restore(step)
         return self._mgr.restore(step, args=ocp.args.StandardRestore(target))
 
-    def restore_latest(self, target: Any | None = None
-                       ) -> tuple[int, Any] | None:
-        """``(step, state)`` of the newest committed checkpoint, or None
-        when none has committed yet (async saves still in flight do not
-        count — ``latest_step`` names only durable checkpoints)."""
-        step = self._mgr.latest_step()
-        if step is None:
-            return None
-        return int(step), self.restore(step, target=target)
+    def saved_tree(self, step: int) -> Any:
+        """The tree checkpoint ``step`` holds, a leaf's metadata in each
+        array's place (:func:`saved_tree`)."""
+        meta = self._mgr.item_metadata(step)
+        return getattr(meta, "tree", meta)
 
     def wait_until_finished(self) -> None:
         self._mgr.wait_until_finished()

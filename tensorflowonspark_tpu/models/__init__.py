@@ -18,8 +18,11 @@ Every model module exposes the same surface:
 Optional hooks the Trainer looks for: ``make_optimizer``,
 ``make_sharded_train_step``, ``make_collection_shardings``,
 ``batch_counters(batch, config)`` (what a step's host batch adds to the
-program's counters) and ``device_counters(collections, config)`` (what of
-the step's collections the counters show: what the device decided).
+program's counters), ``device_counters(collections, config)`` (what of
+the step's collections the counters show: what the device decided) and
+``counter_rows(config)`` (the rows of a collection that only those counters
+read: a checkpoint written before one was counted restores with it at
+zero).
 
 The five decoders trained on packed rows — ``granite_hybrid`` (state-space
 mixers and a NoPE attention layer), ``mla_moe`` (latent attention, routed
@@ -39,7 +42,8 @@ keep their ``Config``, ``ADAMW``, ``leaf_shapes``, ``layer_kinds``
   that declares its variables and, while it initialises, traces no forward
   pass —, ``make_optimizer``, ``make_loss_fn``, ``make_forward_fn``,
   ``example_batch``, ``parameter_count``, ``apply_tokens``, and for the four
-  expert models ``collection_shapes`` and ``device_counters``), the
+  expert models ``collection_shapes``, ``device_counters`` and
+  ``counter_rows``), the
   checkpointed layer loop, the feed-forward half of an expert model's layer,
   the loss over rows;
 - ``packed_rows.py``, the mathematics: norm, products, SwiGLU, the positions

@@ -303,6 +303,7 @@ make_optimizer = _DECODER.make_optimizer
 make_loss_fn = _DECODER.make_loss_fn
 make_forward_fn = _DECODER.make_forward_fn
 device_counters = _DECODER.device_counters
+counter_rows = _DECODER.counter_rows
 parameter_count = _DECODER.parameter_count
 example_batch = _DECODER.example_batch
 

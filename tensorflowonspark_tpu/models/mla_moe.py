@@ -36,7 +36,8 @@ document::
 ``Config.experts_held`` are the experts this chip holds and the ``moe``
 collection what takes no gradient (the correction biases, the cumulative
 counts by expert, each layer's fullest expert and the steps in which a
-layer's held slots overflowed ``moe.prefix_rows``): ``packed_decoder``'s
+layer's held slots overflowed ``moe.prefix_rows`` or fitted
+``moe.tight_rows``): ``packed_decoder``'s
 docstring has both, with what else holds for every packed-row decoder, and
 :func:`device_counters` names what of the collection the program's counters
 show.  On a TPU the published heads (20 x 256) run attention on the Pallas
@@ -310,6 +311,7 @@ make_optimizer = _DECODER.make_optimizer
 make_loss_fn = _DECODER.make_loss_fn
 make_forward_fn = _DECODER.make_forward_fn
 device_counters = _DECODER.device_counters
+counter_rows = _DECODER.counter_rows
 parameter_count = _DECODER.parameter_count
 example_batch = _DECODER.example_batch
 

@@ -380,6 +380,14 @@ class Decoder:
         return moe.routing_counters(collections[COLLECTION],
                                     self.routing(config).held)
 
+    def counter_rows(self, config) -> dict:
+        """The rows of the collections that only the counters read
+        (``moe.COUNTER_ROWS``): what a checkpoint may lack and still be
+        restored, the row at zero."""
+        from tensorflowonspark_tpu.parallel import moe
+
+        return {COLLECTION: moe.COUNTER_ROWS}
+
     def parameter_count(self, config) -> int:
         return sum(int(np.prod(s))
                    for s in self.leaf_shapes(config).values())

@@ -155,12 +155,17 @@ step — never per record, row or chunk):
   (``kimi_linear``'s chunked delta rule, ``models/kda_pallas.py``;
   ``kimi_linear.kda_scan_runs_fused``);
   ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``
-  (the routed experts' grouped products of ``mla_moe``, ``lfm2_moe`` and
-  ``kimi_linear`` in the form a step takes when a layer's slots fit
-  ``moe.prefix_rows``, ``parallel/grouped_pallas.py`` or
-  ``jax.lax.ragged_dot``; ``parallel/moe.py::grouped_runs_fused``;
-  ``moe_overflow_layers_total`` counts the layer-steps that took the other
-  form).
+  (the routed experts' grouped products of ``mla_moe``, ``lfm2_moe``,
+  ``kimi_linear`` and ``mellum_moe`` in the two forms a step takes when a
+  layer's slots fit ``moe.tight_rows`` or ``moe.prefix_rows``,
+  ``parallel/grouped_pallas.py`` or ``jax.lax.ragged_dot``;
+  ``parallel/moe.py::grouped_runs_fused``); what the device decided a
+  layer and a step, of ``moe.routing_counters``:
+  ``moe_tight_layers_total`` counts the layer-steps whose held slots fitted
+  ``moe.tight_rows`` (the smallest of the routed part's three sizes),
+  ``moe_overflow_layers_total`` those that passed ``moe.prefix_rows`` and
+  took all the slots, on ``ragged_dot``; beside ``moe_slots_total``,
+  ``moe_local_slots_total`` and ``moe_busiest_expert_slots_total``.
 
 Also instrumented: elastic regroups (``elastic``), serving
 (``serving``, ``pipeline``), roofline probes, and ``bench.py`` (which

@@ -43,16 +43,19 @@ grouped products, and adds the results back weighted.  A grouped product is
 one algorithm with two executions (:func:`grouped_runs_fused` is the rule,
 and the models' steps count which applied:
 ``moe_grouped_fused_steps_total`` / ``moe_grouped_plain_steps_total``): on a
-TPU, at shapes that fill their tiles, in the form a step takes when its
+TPU, at shapes that fill their tiles, in the forms a step takes when its
 slots fit, the Pallas kernels of ``grouped_pallas`` walk the row tiles that
 hold a live row and no others; anywhere else and in the form a layer takes
 when its slots overflow ``jax.lax.ragged_dot`` runs, which is also the
 kernels' oracle.  The slots that landed here are a prefix of the sorted
 order whose length the device knows after the router: everything after the
-router is one function of a static row count, traced at :func:`prefix_rows`
-and at all the slots, and a ``jax.lax.cond`` takes the first wherever the
-step's count fits it — so gathers, products, masks and sums work on the rows
-that landed here, and a step that overflows is still exact.  What the
+router is one function of a static row count, traced at three sizes
+(:func:`row_sizes`: :func:`tight_rows`, half over an even router's
+share; :func:`prefix_rows`, three times it; all the slots), and a
+``jax.lax.switch`` takes the smallest that the step's count fits — so
+gathers, products, masks and sums work on little more than the rows that
+landed here, whatever the router's balance, and a step that overflows is
+still exact.  What the
 experts held elsewhere would have added is left out; on one chip no exchange
 runs.  It returns how many tokens chose each of the router's experts, which
 the caller's bias update and counters read.  No capacity factor exists and
@@ -355,7 +358,8 @@ def grouped_runs_fused(rows: int, k: int, n: int, dtype, *,
       and ``n`` whole rows of 128 lanes, the rows whole row tiles: the
       published 2,048 by 1,536 and by 1,792 over 12,288 and 24,576 rows do;
       ``Config.tiny()``'s do not);
-    - the form is not the ``overflow`` one: a layer whose live slots pass
+    - the form is not the ``overflow`` one (the tight and the prefix form
+      are the same to this rule): a layer whose live slots pass
       :func:`prefix_rows` takes all the slots, which happens in none of
       ``lfm2_8b_a1b_packed_8k``'s steps and on one seed in nine of
       ``glm47_flash_packed_8k``'s, over a buffer three quarters live, where
@@ -371,27 +375,43 @@ def grouped_runs_fused(rows: int, k: int, n: int, dtype, *,
     return runs_fused(grouped_pallas, rows, k, n, dtype, when=not overflow)
 
 
+def routed_forms(slots: int, n_held: int, n_experts: int, k: int, n: int,
+                 dtype) -> tuple:
+    """``(rows, on the kernels)`` of every form :func:`routed_experts`
+    traces for ``slots`` slots and experts (``k``, ``n``), rising by rows
+    (:func:`row_sizes`): a form past :func:`prefix_rows` is the overflow
+    one and runs as ``ragged_dot``, the others run where
+    :func:`grouped_runs_fused` says."""
+    prefix = prefix_rows(slots, n_held, n_experts)
+    return tuple(
+        (rows, grouped_runs_fused(rows, k, n, dtype, overflow=rows > prefix))
+        for rows in row_sizes(slots, n_held, n_experts))
+
+
 def grouped_step_counters(tokens: int, routing: "Routing", d: int, f: int,
                           dtype) -> dict:
     """What one step of a model of such layers adds to the program's
     counters (``kernels.step_counters``): one step of grouped products on
     the kernels or as ``jax.lax.ragged_dot``, the other named with 0 so that
-    both are on the record.  On the kernels means: the form
-    :func:`routed_experts` takes for ``tokens`` tokens of width ``d`` and
-    experts ``f`` wide when a layer's slots fit (:func:`prefix_rows` of
-    them) runs all its products there."""
-    rows = prefix_rows(tokens * routing.top_k, len(routing.held),
-                       routing.n_experts)
-    return step_counters("moe_grouped",
-                         grouped_runs_fused(rows, d, f, dtype))
+    both are on the record.  The form that counts is the smallest of
+    :func:`routed_forms`, the one :func:`routed_experts` takes for
+    ``tokens`` tokens of width ``d`` and experts ``f`` wide when a layer's
+    slots fit :func:`tight_rows`: on the kernels means that it runs all its
+    products there (``moe_tight_layers_total`` and
+    ``moe_overflow_layers_total`` say how often a layer took another)."""
+    forms = routed_forms(tokens * routing.top_k, len(routing.held),
+                         routing.n_experts, d, f, dtype)
+    return step_counters("moe_grouped", forms[0][1])
 
 
 def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
     """Rows the routed part of :func:`routed_experts` works on when a
-    step's held slots fit them: three times what an even router sends to
+    step's held slots fit them and not :func:`tight_rows` (the middle of
+    :func:`row_sizes`' three): three times what an even router sends to
     ``n_held`` of ``n_experts`` experts out of ``slots`` slots, in whole
     sublanes of 8, and never more than ``slots`` (where a third or more of
-    the experts are held there is one form only).  Three, because a router
+    the experts are held this is all the slots, the larger of two forms
+    and on the kernels).  Three, because a router
     of seeded weights sends one expert half the tokens: a layer that holds
     it lands a little over twice the even share in most steps, and at twice
     such a step took the whole form and 11 ms more, where the rows between
@@ -399,6 +419,38 @@ def prefix_rows(slots: int, n_held: int, n_experts: int) -> int:
     PERF.md section 6)."""
     share = -(-3 * slots * n_held // n_experts)
     return min(slots, -(-share // 8) * 8)
+
+
+def tight_rows(slots: int, n_held: int, n_experts: int) -> int:
+    """Rows the routed part of :func:`routed_experts` works on when a
+    step's held slots fit them: one and a half times what an even router
+    sends to ``n_held`` of ``n_experts`` experts out of ``slots`` slots, in
+    whole row tiles of the grouped kernels (``grouped_pallas.ROW_TILE``:
+    where :func:`prefix_rows` fits the kernels, this does), and never more
+    than :func:`prefix_rows`.  One and a half, for every model: a router
+    near even lands 1.05 to 1.09 times the even share over a step's layers
+    and a single layer more (at one and a quarter 2 to 11% of the
+    layer-steps of ``lfm2_8b_a1b_packed_8k`` and ``mellum2_packed_8k``
+    did not fit, on three seeds of eight), every buffer row costs a
+    layer-step 0.12-0.32 us in gathers, masks, casts and the pass between
+    the products whether it is live or not, and a router that is not even
+    takes the next size, a layer and a step at a time (PERF.md section 6,
+    PR 50)."""
+    from tensorflowonspark_tpu.parallel.grouped_pallas import ROW_TILE
+
+    share = -(-3 * slots * n_held // (2 * n_experts))
+    return min(prefix_rows(slots, n_held, n_experts),
+               -(-share // ROW_TILE) * ROW_TILE)
+
+
+def row_sizes(slots: int, n_held: int, n_experts: int) -> tuple:
+    """The distinct row counts the routed part is traced at, rising:
+    :func:`tight_rows`, :func:`prefix_rows` and all the ``slots`` (the
+    overflow form).  Sizes that coincide are one form: where a third or
+    more of the experts are held :func:`prefix_rows` is all the slots, and
+    where a tile is more than :func:`prefix_rows` the tight size is it."""
+    return tuple(sorted({tight_rows(slots, n_held, n_experts),
+                         prefix_rows(slots, n_held, n_experts), slots}))
 
 
 @functools.lru_cache(maxsize=None)
@@ -506,31 +558,29 @@ def _routed_part(scopes: tuple = ()):
             out = jnp.where(live, out, 0).astype(dtype)
             return combine(out, gates, idx, inv)
 
-    def by_count(n_prefix, fused, form, order, inv, group_sizes, *operands):
-        """``form(n_rows, fused)`` of the operands at ``n_prefix`` rows
-        where this step's live slots fit them, at all the slots (the
-        overflow form) where they do not; the device chooses.  ``fused``
-        says of each of the two whether its products run on the kernels."""
-        n_slots, args = order.shape[0], (order, inv, group_sizes, *operands)
-        if n_prefix >= n_slots:
-            return form(n_slots, fused[0])(*args)
-        return jax.lax.cond(jnp.sum(group_sizes) <= n_prefix,
-                            form(n_prefix, fused[0]),
-                            form(n_slots, fused[1]), *args)
+    def by_count(sizes, fused, form, order, inv, group_sizes, *operands):
+        """``form(n_rows, fused)`` of the operands at the smallest of the
+        rising ``sizes`` that holds this step's live slots (the last is
+        all the slots: the overflow form); the device chooses.  ``fused``
+        says of each size whether its products run on the kernels."""
+        rung = jnp.sum(jnp.sum(group_sizes)
+                       > jnp.asarray(sizes[:-1], group_sizes.dtype))
+        return jax.lax.switch(rung, [form(*at) for at in zip(sizes, fused)],
+                              order, inv, group_sizes, *operands)
 
-    # A differentiated ``cond`` has every branch write zeros in the place
-    # of the other's residuals: the forward and the backward pass choose
-    # each for itself, and the backward one makes the products again.
+    # A differentiated ``switch`` has every branch write zeros in the
+    # place of the others' residuals: the forward and the backward pass
+    # choose each for itself, and the backward one makes the products again.
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-    def routed(n_prefix, fused, k, *args):
-        return by_count(n_prefix, fused, lambda n, on_kernels:
+    def routed(sizes, fused, k, *args):
+        return by_count(sizes, fused, lambda n, on_kernels:
                         functools.partial(over_rows, n, on_kernels, k), *args)
 
-    def routed_fwd(n_prefix, fused, k, *args):
-        return routed(n_prefix, fused, k, *args), args
+    def routed_fwd(sizes, fused, k, *args):
+        return routed(sizes, fused, k, *args), args
 
-    def routed_bwd(n_prefix, fused, k, args, dy):
+    def routed_bwd(sizes, fused, k, args, dy):
         def backward(n_rows, on_kernels):
             def run(order, inv, group_sizes, dy, *operands):
                 return jax.vjp(functools.partial(
@@ -538,7 +588,7 @@ def _routed_part(scopes: tuple = ()):
                     group_sizes), *operands)[1](dy)
             return run
 
-        grads = by_count(n_prefix, fused, backward, *args[:3], dy, *args[3:])
+        grads = by_count(sizes, fused, backward, *args[:3], dy, *args[3:])
         return (*map(no_grad, args[:3]), *grads)
 
     routed.defvjp(routed_fwd, routed_bwd)
@@ -574,8 +624,9 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
     Every slot is kept: the ``T top_k`` slots are sorted by held expert
     (those of experts held elsewhere last), so the live ones are the first
     ``sum(counts[held])`` rows, and everything after the router runs over
-    :func:`prefix_rows` of them where the step's live rows fit, over all
-    the slots where they do not (one function at two sizes; the device
+    :func:`tight_rows` of them where the step's live rows fit, over
+    :func:`prefix_rows` where they fit those, over all the slots where they
+    do not (one function at three sizes, :func:`row_sizes`; the device
     chooses, a layer and a step at a time).  Returns ``(y, counts)``: ``y``
     (T, D) in ``x``'s type, ``counts`` (E,) int32."""
     import jax
@@ -601,14 +652,9 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, held, *,
         order = jnp.argsort(jnp.asarray(place)[slot_expert], stable=True)
         inv = jnp.argsort(order)
     n_slots = slot_expert.shape[0]
-    n_prefix = prefix_rows(n_slots, n_held, n_experts)
-    # the two forms, at ``n_prefix`` rows and (the overflow one) at all the
-    # slots: which of them run their products on the kernels
-    fused = tuple(
-        grouped_runs_fused(rows, *w_gate.shape[1:], x.dtype,
-                           overflow=overflow)
-        for rows, overflow in ((n_prefix, False), (n_slots, True)))
-    y = _routed_part(tuple(scopes))(n_prefix, fused, top_k, order, inv,
+    sizes, fused = zip(*routed_forms(n_slots, n_held, n_experts,
+                                     *w_gate.shape[1:], x.dtype))
+    y = _routed_part(tuple(scopes))(sizes, fused, top_k, order, inv,
                                     counts[held], x, gates, w_gate, w_up,
                                     w_down)
     return y, counts
@@ -662,16 +708,24 @@ def expert_ffn(params, prefix: str, h, bias, routing: Routing, *,
 # The routing state of a model of such layers: what takes no gradient
 # ---------------------------------------------------------------------------
 
+#: the rows of the routing state that only :func:`routing_counters` reads
+#: (no step's computation does): a checkpoint written before one of them
+#: was counted restores with it at zero (``Trainer.restore``)
+COUNTER_ROWS = ("counts", "busiest", "overflow", "tight")
+
+
 def routing_state_shapes(n_experts: int, expert_layers: int) -> dict:
     """Name -> ``(shape, dtype)`` of a model's routing state, a row an
     expert layer in forward order: every layer's correction bias (it enters
     the choice and takes no gradient), the cumulative count of tokens by
-    expert, the cumulative size of the layer's fullest expert, and the
-    steps in which the layer's held slots overflowed :func:`prefix_rows`.
+    expert, the cumulative size of the layer's fullest expert, the steps
+    in which the layer's held slots overflowed :func:`prefix_rows` and
+    those in which they fitted :func:`tight_rows`.
     The Trainer's stateful step threads and checkpoints the collection."""
     rows, e = expert_layers, n_experts
     return {"bias": ((rows, e), "float32"), "counts": ((rows, e), "int32"),
-            "busiest": ((rows,), "int32"), "overflow": ((rows,), "int32")}
+            "busiest": ((rows,), "int32"), "overflow": ((rows,), "int32"),
+            "tight": ((rows,), "int32")}
 
 
 def step_routing_state(state: dict, counts, held, *, top_k: int,
@@ -679,21 +733,23 @@ def step_routing_state(state: dict, counts, held, *, top_k: int,
     """The routing state after a step whose ``tokens`` tokens chose
     ``counts`` (expert layers, E): every layer's bias moves ``speed``
     towards its mean load (``b_e += speed * sign(mean(c) - c_e)``,
-    arXiv:2412.19437), the counts add up, and a layer whose held experts
-    were chosen more often than :func:`prefix_rows` allows
-    (:func:`routed_experts` then took all the slots) is counted."""
+    arXiv:2412.19437), the counts add up, and a layer is counted whose
+    held experts were chosen more often than :func:`prefix_rows` allows
+    (:func:`routed_experts` then took all the slots), or no more often
+    than :func:`tight_rows` does (it took the smallest size)."""
     import jax.numpy as jnp
 
     load = counts.astype(jnp.float32)
     held = jnp.asarray(held, jnp.int32)
-    fits = prefix_rows(tokens * top_k, len(held), counts.shape[-1])
+    shape = tokens * top_k, len(held), counts.shape[-1]
+    landed = jnp.sum(counts[:, held], axis=-1)
     return {
         "bias": state["bias"] + speed * jnp.sign(
             jnp.mean(load, axis=-1, keepdims=True) - load),
         "counts": state["counts"] + counts,
         "busiest": state["busiest"] + jnp.max(counts, axis=-1),
-        "overflow": state["overflow"] + (
-            jnp.sum(counts[:, held], axis=-1) > fits),
+        "overflow": state["overflow"] + (landed > prefix_rows(*shape)),
+        "tight": state["tight"] + (landed <= tight_rows(*shape)),
     }
 
 
@@ -703,8 +759,9 @@ def routing_counters(state: dict, held) -> dict:
     Trainer adds up, element by element (a running total would outgrow 32
     bits; an element takes a million steps of a row to).  Slots (a token's
     choice of an expert) routed, the slots whose expert is held here, every
-    layer's fullest expert, and the layers whose held slots overflowed
-    :func:`prefix_rows`.  One set of names for every model of such layers:
+    layer's fullest expert, the layers whose held slots overflowed
+    :func:`prefix_rows` and those whose held slots fitted
+    :func:`tight_rows`.  One set of names for every model of such layers:
     one reader serves them all."""
     import jax.numpy as jnp
 
@@ -712,4 +769,5 @@ def routing_counters(state: dict, held) -> dict:
     return {"moe_slots_total": state["counts"],
             "moe_local_slots_total": state["counts"][:, held],
             "moe_busiest_expert_slots_total": state["busiest"],
-            "moe_overflow_layers_total": state["overflow"]}
+            "moe_overflow_layers_total": state["overflow"],
+            "moe_tight_layers_total": state["tight"]}

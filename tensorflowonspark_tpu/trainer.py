@@ -564,16 +564,13 @@ class Trainer:
         mesh over the survivors and restores straight into it."""
         if self._ckpt_mgr is None:
             raise RuntimeError("checkpoint() was never enabled")
-        hit = self._ckpt_mgr.restore_latest(target=self._state_tree())
-        if hit is None:
+        step = self._ckpt_mgr.latest_step()
+        if step is None:
             return None
-        step, restored = hit
-        self.state = TrainState(restored["params"], restored["opt_state"],
-                                restored["step"],
-                                restored.get("collections", {}))
-        if self._device_counters is not None:
-            self._device_counters.rebase(self.state.collections)
-        return step
+        self._take_state(
+            self._ckpt_mgr.saved_tree(step),
+            lambda target: self._ckpt_mgr.restore(step, target=target))
+        return int(step)
 
     def finish_checkpoints(self) -> None:
         """Barrier on in-flight async checkpoint writes (shutdown/rejoin:
@@ -636,15 +633,38 @@ class Trainer:
     def restore(self, path: str) -> None:
         from tensorflowonspark_tpu import ckpt
 
-        template = {"params": self.state.params,
-                    "opt_state": self.state.opt_state,
-                    "step": self.state.step}
-        if self.state.collections:
-            template["collections"] = self.state.collections
-        restored = ckpt.load_pytree(path, template)
+        self._take_state(ckpt.saved_tree(path),
+                         lambda target: ckpt.load_pytree(path, target))
+
+    def _take_state(self, saved, load) -> None:
+        """Make ``load(target)`` of a checkpoint whose tree is ``saved``
+        this trainer's state.  The target is the trainer's own tree, so
+        whatever the checkpoint lacks of it is refused by the leaf's name —
+        but for the rows of a collection that only the counters read (the
+        model module's ``counter_rows(config) -> {collection: rows}``): a
+        checkpoint written before such a row was counted restores with the
+        row at zero, and resumes."""
+        target = self._state_tree()
+        zeros = {}
+        rows_of = getattr(self.module_lib, "counter_rows", None)
+        for name, rows in (rows_of(self.config) if rows_of else {}).items():
+            live = target.get("collections", {}).get(name, {})
+            have = saved.get("collections", {}).get(name, {})
+            zeros[name] = {row: live[row] * 0 for row in rows
+                           if row in live and row not in have}
+            if zeros[name]:
+                target["collections"] = {
+                    **target["collections"],
+                    name: {row: v for row, v in live.items()
+                           if row not in zeros[name]}}
+        restored = load(target)
+        collections = restored.get("collections", {})
+        for name, rows in zeros.items():
+            if rows:
+                collections = {**collections,
+                               name: {**collections[name], **rows}}
         self.state = TrainState(restored["params"], restored["opt_state"],
-                                restored["step"],
-                                restored.get("collections", {}))
+                                restored["step"], collections)
         if self._device_counters is not None:
             self._device_counters.rebase(self.state.collections)
 

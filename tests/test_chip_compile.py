@@ -370,6 +370,9 @@ def test_window_attention_kernels_compile_at_the_published_widths(topo):
     (24576, 2048, 1792), (24576, 1792, 2048),     # lfm2_8b_a1b_packed_8k
     (12288, 2048, 1536), (12288, 1536, 2048),     # glm47_flash_packed_8k
     (32768, 2048, 1792),        # all the slots: a model with one form
+    # ``moe.tight_rows`` of the four expert cells (PR 50)
+    (12288, 2048, 1792), (6144, 1536, 2048),    # lfm2, glm
+    (24576, 2304, 896), (3072, 1024, 2304),     # mellum2, kimi linear
 ])
 def test_grouped_kernels_compile_at_the_published_widths(topo, rows, k, n):
     """The Pallas kernels of the routed experts' grouped products
@@ -655,18 +658,19 @@ def _assert_kda_kernels(text: str, layers: int) -> None:
 
 
 def _assert_grouped_kernels(text: str, layers: int) -> None:
-    """``text``: a compiled step.  The form of the routed part that a step
-    takes when a layer's slots fit (``moe.prefix_rows`` of them) holds
-    twelve grouped products on the kernels: the forward three, the same
-    again where the backward pass asks for them, the rows' gradients and
-    the weights'; each under the ``moe_experts`` scope.  The overflow form
+    """``text``: a compiled step.  Each of the two forms of the routed
+    part that a step takes when a layer's slots fit (``moe.tight_rows`` of
+    them, or ``moe.prefix_rows``) holds twelve grouped products on the
+    kernels: the forward three, the same again where the backward pass asks
+    for them, the rows' gradients and the weights'; each under the
+    ``moe_experts`` scope.  The overflow form
     (all the slots) keeps the compiler's own ``ragged-dot`` kernels, twelve
     a layer: it is compiled, loaded at every start and run in almost no
     step, so it is kept small (``moe.grouped_runs_fused``)."""
     import re
 
     ours = [n for n in _pallas_calls(text) if "/grouped_" in n]
-    for kernel, calls in (("grouped_rows", 9), ("grouped_weights", 3)):
+    for kernel, calls in (("grouped_rows", 2 * 9), ("grouped_weights", 2 * 3)):
         assert sum(f"/{kernel}/" in n for n in ours) == layers * calls
     assert all(re.search(r"\bmoe_experts\b", n) for n in ours), ours
     # (the compiler drops the scopes a ``ragged_dot`` was traced under and
@@ -716,9 +720,9 @@ def test_glm_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     _assert_grouped_kernels(text, layers=5)
     # the five layers share one traced routed part (``jax.jit``), and each
     # copy the compiler makes of it still carries its caller's scopes: the
-    # prediction module's twelve products are named for it
+    # prediction module's twelve products a size are named for it
     grouped = [n for n in _pallas_calls(text) if "/grouped_" in n]
-    assert sum("mtp" in n.split("/") for n in grouped) == 12, grouped[:3]
+    assert sum("mtp" in n.split("/") for n in grouped) == 24, grouped[:3]
     ours = [n for n in _pallas_calls(text) if "/attention_" in n]
     for kernel, calls in (("attention_forward", 6),
                           ("attention_backward", 6)):

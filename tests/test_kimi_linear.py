@@ -730,7 +730,7 @@ def test_kimi_trainer_follows_the_reference_and_checkpoints_its_routing(
     assert fourth.sum() == routing["counts"].sum() // 3
     trainer.restore(str(tmp_path / "ckpt"))
     got = trainer.state.collections[kimi_linear.COLLECTION]
-    assert set(got) == {"bias", "counts", "busiest", "overflow"}
+    assert set(got) == {"bias", "counts", "busiest", "overflow", "tight"}
     for name in got:
         np.testing.assert_array_equal(got[name], routing[name])
     trainer.step(extra)

@@ -329,7 +329,8 @@ def test_the_shared_pieces_exist_once():
 def test_routing_state_is_a_function_of_experts_layers_choices_and_speed():
     """``moe.step_routing_state`` with no model's ``Config``: 4 experts, one
     held, 16 tokens choosing 2 (8 of 32 slots if the router is even, 24 fit
-    ``moe.prefix_rows``); a speed of 0 leaves the bias."""
+    ``moe.prefix_rows`` and, a whole row tile being more, ``tight_rows``);
+    a speed of 0 leaves the bias."""
     counts = jnp.asarray([[16, 0, 8, 8], [2, 2, 26, 2]], jnp.int32)
     state = {name: jnp.ones(shape, dtype) for name, (shape, dtype) in
              moe.routing_state_shapes(4, 2).items()}
@@ -341,6 +342,7 @@ def test_routing_state_is_a_function_of_experts_layers_choices_and_speed():
     assert new["counts"].tolist() == [[17, 1, 9, 9], [3, 3, 27, 3]]
     assert new["busiest"].tolist() == [17, 27]
     assert new["overflow"].tolist() == [1, 2]
+    assert new["tight"].tolist() == [2, 1]
     still = moe.step_routing_state(state, counts, (2,), top_k=2, speed=0.0,
                                    tokens=16)
     np.testing.assert_array_equal(still["bias"], state["bias"])
@@ -348,7 +350,8 @@ def test_routing_state_is_a_function_of_experts_layers_choices_and_speed():
     assert shown["moe_local_slots_total"].tolist() == [[9], [27]]
     assert set(shown) == {"moe_slots_total", "moe_local_slots_total",
                           "moe_busiest_expert_slots_total",
-                          "moe_overflow_layers_total"}
+                          "moe_overflow_layers_total",
+                          "moe_tight_layers_total"}
 
 
 def test_the_gates_sum_epsilon_is_an_argument_of_the_call():
@@ -633,7 +636,7 @@ def test_lfm2_checkpoints_carry_the_routing_state(tiny, tmp_path):
     trainer.step(batch)
     trainer.restore(str(tmp_path / "ckpt"))
     got = trainer.state.collections[lfm2_moe.COLLECTION]
-    assert set(got) == {"bias", "counts", "busiest", "overflow"}
+    assert set(got) == {"bias", "counts", "busiest", "overflow", "tight"}
     for name in got:
         np.testing.assert_array_equal(got[name], want[name])
     trainer.step(batch)

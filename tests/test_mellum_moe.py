@@ -340,7 +340,7 @@ def test_mellum_checkpoints_carry_the_routing_state(tiny, tmp_path):
     trainer.step(batch)
     trainer.restore(str(tmp_path / "ckpt"))
     got = trainer.state.collections[mellum_moe.COLLECTION]
-    assert set(got) == {"bias", "counts", "busiest", "overflow"}
+    assert set(got) == {"bias", "counts", "busiest", "overflow", "tight"}
     for name in got:
         np.testing.assert_array_equal(got[name], want[name])
     trainer.step(batch)

@@ -170,7 +170,8 @@ def test_mla_moe_collection_moves_on_a_step():
     bias goes a step towards the mean load (and stays where the load is
     the mean), and a layer is counted whose held expert (one of four: 8 of
     32 slots if the router is even, 24 fit ``moe.prefix_rows``) took more
-    than fit."""
+    than fit, and one whose held expert took no more than fit
+    ``moe.tight_rows`` (the same 24: a whole row tile is more)."""
     config = dataclasses.replace(
         mla_moe.Config.tiny(), n_routed_experts=4, experts_held=(2,),
         num_experts_per_tok=2)
@@ -178,7 +179,7 @@ def test_mla_moe_collection_moves_on_a_step():
                          jnp.int32)
     state = {"bias": jnp.full((3, 4), 0.5), "busiest": jnp.asarray([7] * 3),
              "counts": jnp.ones((3, 4), jnp.int32),
-             "overflow": jnp.asarray([3] * 3)}
+             "overflow": jnp.asarray([3] * 3), "tight": jnp.asarray([5] * 3)}
     new = mla_moe.step_collection(state, counts, config, tokens=16)
     np.testing.assert_allclose(
         new["bias"], [[0.499, 0.501, 0.5, 0.5], [0.5] * 4,
@@ -186,6 +187,7 @@ def test_mla_moe_collection_moves_on_a_step():
     assert new["counts"].tolist() == [[17, 1, 9, 9], [9] * 4, [3, 3, 27, 3]]
     assert new["busiest"].tolist() == [23, 15, 33]
     assert new["overflow"].tolist() == [3, 3, 4]
+    assert new["tight"].tolist() == [6, 6, 5]
 
 
 @pytest.mark.parametrize("favoured, overflowed", [
@@ -517,13 +519,13 @@ def _layer_jaxpr(held):
 
 def test_the_prefix_form_holds_no_row_a_slot():
     """Two of 16 experts held: 72 of the 192 slots' rows.  The forward pass
-    and the backward pass choose each for itself (two ``cond``s, and no
-    residual crosses one), and in the branch a fitting count takes no
-    array of any type has a row a slot (192 rows, or 64 x 3, of ``F`` or
-    ``D`` numbers): the products' outputs, the ``silu`` pass, the masks,
-    the gathers and the weighted sum all work on 72 rows or on a row a
-    token.  The other branch has such arrays in float32, so the check can
-    see one."""
+    and the backward pass choose each for itself (two ``cond``s — what a
+    ``switch`` binds —, and no residual crosses one), and in the branch a
+    fitting count takes no array of any type has a row a slot (192 rows,
+    or 64 x 3, of ``F`` or ``D`` numbers): the products' outputs, the
+    ``silu`` pass, the masks, the gathers and the weighted sum all work on
+    72 rows or on a row a token.  The other branch has such arrays in
+    float32, so the check can see one."""
     assert moe.prefix_rows(192, 2, 16) == 72
     conds = [eqn for jaxpr in _sub_jaxprs(_layer_jaxpr((3, 7)))
              for eqn in jaxpr.eqns if eqn.primitive.name == "cond"]
@@ -537,7 +539,7 @@ def test_the_prefix_form_holds_no_row_a_slot():
                 and int(np.prod(v.aval.shape[:-1])) == 192]
 
     for eqn in conds:
-        whole, prefix = eqn.params["branches"]      # the predicate's 0, 1
+        prefix, whole = eqn.params["branches"]      # the sizes, rising
         found = a_row_a_slot(prefix.jaxpr, (jnp.float32, jnp.bfloat16))
         assert not found, found
         assert a_row_a_slot(whole.jaxpr, (jnp.float32,))
@@ -652,12 +654,13 @@ def test_the_second_loss_scores_nothing_across_a_boundary(tiny):
 
 
 def test_mla_moe_checkpoints_carry_the_routing_state(tiny, tmp_path):
-    """A checkpoint holds the whole ``moe`` collection, the overflow row
-    with it.  One written before that row existed is not filled in with
-    zeros: a checkpoint's tree is that of the code that wrote it (no
-    format is published, the Trainer restores into its own template), and
-    the restore refuses the other tree by the missing row's name rather
-    than resume with a count that starts at nothing."""
+    """A checkpoint holds the whole ``moe`` collection, the counters' rows
+    with it.  One written without a row that enters the step (the biases)
+    is not filled in: a checkpoint's tree is that of the code that wrote it
+    (no format is published, the Trainer restores into its own template),
+    and the restore refuses the other tree by the missing row's name rather
+    than resume with a bias that starts at nothing.  (A row that only the
+    counters read is another matter: the tests after this one.)"""
     from tensorflowonspark_tpu import ckpt
     from tensorflowonspark_tpu.trainer import Trainer
 
@@ -678,15 +681,15 @@ def test_mla_moe_checkpoints_carry_the_routing_state(tiny, tmp_path):
     assert _routing_state(trainer)["counts"].sum() == 3 * per_step
     trainer.restore(str(tmp_path / "ckpt"))
     got = _routing_state(trainer)
-    assert set(got) == {"bias", "counts", "busiest", "overflow"}
+    assert set(got) == {"bias", "counts", "busiest", "overflow", "tight"}
     for name in got:
         np.testing.assert_array_equal(got[name], want[name])
     old = trainer._state_tree()
     old["collections"] = {mla_moe.COLLECTION: {
         k: v for k, v in old["collections"][mla_moe.COLLECTION].items()
-        if k != "overflow"}}
+        if k != "bias"}}
     ckpt.save_pytree(old, str(tmp_path / "before"))
-    with pytest.raises(ValueError, match="moe.overflow"):
+    with pytest.raises(ValueError, match="moe.bias"):
         trainer.restore(str(tmp_path / "before"))
     # restored counts are where the counters go on from, not growth
     trainer.step(batch)
@@ -696,3 +699,63 @@ def test_mla_moe_checkpoints_carry_the_routing_state(tiny, tmp_path):
     gc.collect()
     assert obs.get_registry().snapshot()["counters"]["moe_slots_total"] \
         - before == 4 * per_step        # every step run, no step twice
+
+
+@pytest.mark.parametrize("how", ["path", "manager"])
+@pytest.mark.parametrize("lacks", [("tight",), ("tight", "overflow")],
+                         ids="_".join)
+def test_a_checkpoint_written_before_a_counter_row_resumes(tiny, tmp_path,
+                                                           how, lacks):
+    """The collection of a checkpoint written before ``tight`` was counted
+    (PR 50), or ``overflow`` (PR 39): ``moe.COUNTER_ROWS`` are read by the
+    counters and by no step, so ``Trainer.restore`` and ``restore_latest``
+    take what the file has, start the missing rows at zero, and the
+    counters go on from there — the step after adds one step's growth to
+    each, not the restored totals."""
+    from tensorflowonspark_tpu import ckpt
+    from tensorflowonspark_tpu.parallel import moe
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config = tiny[0]
+    assert set(lacks) < set(moe.COUNTER_ROWS)
+    assert mla_moe.counter_rows(config) == {
+        mla_moe.COLLECTION: moe.COUNTER_ROWS}
+    trainer = Trainer("mla_moe", config=config, devices=jax.devices()[:1])
+    batch = mla_moe.example_batch(config, 2, seq_len=config.seq_len)
+    trainer.step(batch)
+    trainer.step(batch)
+    want = _routing_state(trainer)
+    assert want["tight"].sum() + want["overflow"].sum() > 0
+    old = trainer._state_tree()
+    old["collections"] = {mla_moe.COLLECTION: {
+        k: v for k, v in old["collections"][mla_moe.COLLECTION].items()
+        if k not in lacks}}
+    if how == "path":
+        ckpt.save_pytree(old, str(tmp_path / "before"))
+    else:
+        trainer.checkpoint(str(tmp_path / "steps"), every_steps=0,
+                           async_save=False)
+        trainer._ckpt_mgr.save(2, old)
+        trainer.finish_checkpoints()
+    trainer.step(batch)
+    if how == "path":
+        trainer.restore(str(tmp_path / "before"))
+    else:
+        assert trainer.restore_latest() == 2
+    got = _routing_state(trainer)
+    assert set(got) == set(want)
+    for name in got:
+        np.testing.assert_array_equal(
+            got[name], 0 * want[name] if name in lacks else want[name])
+    assert int(trainer.state.step) == 2
+    seen = obs.get_registry().snapshot()["counters"]
+    trainer.step(batch)
+    trainer._device_counters.drain()
+    now = obs.get_registry().snapshot()["counters"]
+    after = _routing_state(trainer)
+    for name in lacks:
+        assert now.get(f"moe_{name}_layers_total", 0) - seen.get(
+            f"moe_{name}_layers_total", 0) == after[name].sum()
+    assert now["moe_slots_total"] - seen["moe_slots_total"] == (
+        config.num_experts_per_tok * 2 * config.seq_len
+        * config.expert_layers)

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from tensorflowonspark_tpu.models import kernels, lfm2_moe, mla_moe
+from tensorflowonspark_tpu.models import (
+    kernels, kimi_linear, lfm2_moe, mellum_moe, mla_moe)
 from tensorflowonspark_tpu.parallel import grouped_pallas, moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -258,6 +259,61 @@ def test_both_models_count_the_execution_of_their_grouped_products(
             + counts["attention_plain_steps_total"]) == 1
 
 
+@pytest.mark.parametrize("model, lib, sizes", [
+    ("glm_4_7_flash", mla_moe, (6144, 12288, 32768)),
+    ("lfm2_8b_a1b", lfm2_moe, (12288, 24576, 32768)),
+    ("kimi_linear_48b_a3b", kimi_linear, (3072, 6144, 65536)),
+    ("mellum2_12b_a2_5b", mellum_moe, (24576, 49152, 65536)),
+])
+def test_the_three_sizes_at_the_published_shapes(model, lib, sizes,
+                                                 monkeypatch):
+    """``moe.row_sizes`` of the four cells' routed layers: ``tight_rows``
+    (half over the even share, in whole row tiles of the kernels),
+    ``prefix_rows`` as it was, all the slots; the first two on the kernels
+    at the published widths, the last on ``ragged_dot``."""
+    config = _published(model)
+    routing = lib.routing(config)
+    shape = (config.seq_len * routing.top_k, len(routing.held),
+             routing.n_experts)
+    assert moe.row_sizes(*shape) == sizes
+    assert (moe.tight_rows(*shape), moe.prefix_rows(*shape)) == sizes[:2]
+    even = shape[0] * shape[1] // shape[2]
+    assert sizes[0] == 3 * even // 2 and sizes[0] % TILE == 0
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
+    d, f = config.hidden_size, config.moe_intermediate_size
+    for rows in sizes[:2]:
+        assert grouped_pallas.fits(rows, d, f, config.dtype)
+        assert grouped_pallas.fits(rows, f, d, config.dtype)
+        assert moe.grouped_runs_fused(rows, d, f, config.dtype)
+
+
+@pytest.mark.parametrize("slots, n_held, n_experts, sizes", [
+    (1024, 2, 12, (256, 512, 1024)),    # 256 rows: a tile
+    (1024, 1, 12, (256, 1024)),         # tight is prefix_rows: one form
+    (1536, 3, 12, (768, 1152, 1536)),   # 576 rows: two and a quarter
+    (2048, 4, 16, (768, 1536, 2048)),   # 768 rows: three tiles
+    (1024, 4, 12, (512, 1024)),         # a third held: prefix_rows is all
+    (1024, 12, 12, (1024,)),            # all held
+    (96, 2, 8, (72, 96)),               # under a tile: prefix_rows
+    (8, 1, 64, (8,)),                   # a sublane is all the slots
+], ids=lambda v: "_".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_tight_rows_are_whole_tiles_under_prefix_rows(slots, n_held,
+                                                      n_experts, sizes):
+    """``tight_rows`` is half over the even share rounded up to whole
+    tiles of 256 rows and never more than ``prefix_rows``, which keeps its
+    values; sizes that coincide are one form (where a third of the experts
+    are held ``prefix_rows`` is all the slots, and the tight size stays)."""
+    shape = slots, n_held, n_experts
+    tight, prefix = moe.tight_rows(*shape), moe.prefix_rows(*shape)
+    assert tight <= prefix <= slots
+    assert 2 * n_experts * tight >= 3 * slots * n_held or tight == prefix
+    assert tight % TILE == 0 or tight == prefix
+    assert tight - TILE < -(-3 * slots * n_held // (2 * n_experts))
+    assert moe.row_sizes(*shape) == sizes
+    assert sizes[-1] == slots and list(sizes) == sorted(set(sizes))
+    assert set(sizes) <= {tight, prefix, slots}
+
+
 def _layer(dtype, seed=3):
     """512 tokens of 128, top-2 of 12 experts 128 wide, two held: 1,024
     slots, of which ``moe.prefix_rows`` takes 512 — both whole row tiles."""
@@ -274,30 +330,33 @@ def _layer(dtype, seed=3):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("form", ["prefix", "overflow", "only"])
+@pytest.mark.parametrize("form", ["tight", "prefix", "overflow", "third"])
 def test_routed_experts_on_the_kernels_are_routed_experts(form, dtype,
                                                           monkeypatch):
     """``routed_experts`` end to end with its grouped products on the
     kernels (the backend patched to a TPU, the interpreter running them)
     against the same call on ``jax.lax.ragged_dot``: ``y``, ``counts`` and
     the gradients to ``x``, to the router (the gates' path) and to the three
-    weight stacks.  ``prefix``: two of twelve experts held and no bias, a
-    sixth of the slots land here and the prefix form's 512 rows run on the
-    kernels.  ``overflow``: a bias sends every choice to the two held
+    weight stacks.  ``tight``: two of twelve experts held and no bias, a
+    sixth of the slots land here and the tight form's 256 rows run on the
+    kernels.  ``prefix``: a small bias towards the two held experts lands
+    twice that, past ``tight_rows``, and the prefix form's 512 rows run on
+    the kernels.  ``overflow``: a bias sends every choice to the two held
     experts, 1,024 live rows overflow ``prefix_rows``' 512 and the device
-    takes the overflow form, which stays on ``ragged_dot`` beside a prefix
-    form that is traced on the kernels and not run.  ``only``: four of
-    twelve held, ``prefix_rows`` is all 1,024 slots, the one form there is
-    runs on the kernels."""
+    takes the overflow form, which stays on ``ragged_dot`` beside the two
+    forms that are traced on the kernels and not run.  ``third``: four of
+    twelve held, ``prefix_rows`` is all 1,024 slots and no form is past it:
+    the tight form's 512 rows run, the other is traced, both on the
+    kernels."""
     dtype = jnp.dtype(dtype)
     layer = _layer(dtype)
-    held = (3, 7, 1, 10) if form == "only" else (3, 7)
+    held = (3, 7, 1, 10) if form == "third" else (3, 7)
     for name in ("gate", "up", "down"):
         layer[name] = jnp.concatenate([layer[name]] * (len(held) // 2))
     bias = jnp.zeros(12).at[jnp.asarray(held[:2])].set(
-        10.0 if form == "overflow" else 0.0)
-    n_prefix = moe.prefix_rows(1024, len(held), 12)
-    assert n_prefix == (1024 if form == "only" else 512)
+        {"prefix": 0.15, "overflow": 10.0}.get(form, 0.0))
+    sizes = moe.row_sizes(1024, len(held), 12)
+    assert sizes == ((512, 1024) if form == "third" else (256, 512, 1024))
 
     def run(**how):
         def loss(leaves):
@@ -323,15 +382,120 @@ def test_routed_experts_on_the_kernels_are_routed_experts(form, dtype,
     assert moe.grouped_runs_fused(512, 128, 128, dtype)
     with pltpu.force_tpu_interpret_mode():
         (_, (y, counts)), grads = run()
-    assert set(seen) == {n_prefix}      # the overflow form traces none
+    assert set(seen) == set(sizes[:2])  # the form past prefix_rows: none
     np.testing.assert_array_equal(counts, want_counts)
     landed = int(np.asarray(counts)[list(held)].sum())
-    assert (landed > 512) == (form == "overflow") and landed > 0
+    assert landed > 0 and sum(landed > n for n in sizes[:-1]) == {
+        "tight": 0, "prefix": 1, "overflow": 2, "third": 0}[form]
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     _close(y, want, tol)
     for name in want_grads:
         assert float(jnp.abs(want_grads[name]).max()) > 0, name
         _close(grads[name], want_grads[name], tol)
+
+
+def _landing(landed: int):
+    """:func:`_layer` in float32 with a router and tokens that land exactly
+    ``landed`` of the 1,024 slots on the held experts 3 and 7: a token's
+    first three features say which pair of experts it scores highest
+    (both held, one held, none), by 4 in the logits where the other 125
+    features move them by 0.1."""
+    layer = _layer(jnp.float32)
+    pairs = np.asarray([(3, 7), (3, 5), (5, 9)])
+    kind = np.full(512, 2)
+    kind[:landed // 2] = 0
+    kind[landed // 2:landed // 2 + landed % 2] = 1
+    x = np.array(layer["x"])
+    x[:, :3] = np.eye(3)[kind]
+    router = np.array(layer["router"]) * 0.1
+    router[:3] = 0
+    for row, pair in enumerate(pairs):
+        router[row, pair] = 4.0
+    return dict(layer, x=jnp.asarray(x), router=jnp.asarray(router))
+
+
+@pytest.mark.parametrize("landed, rung", [
+    (256, 0), (257, 1), (512, 1), (513, 2)],
+    ids=["tight", "tight_and_one", "prefix", "prefix_and_one"])
+def test_the_device_takes_the_smallest_size_that_fits_and_all_are_equal(
+        landed, rung, monkeypatch):
+    """Counts at ``tight_rows`` (256 of 1,024 slots), one over, at
+    ``prefix_rows`` (512) and one over: the device runs the form at the
+    smallest size that holds them (the forward pass and the backward pass,
+    each choosing for itself, run products at that row count and at no
+    other), and in float32 what it returns is what each size that holds the
+    count gives alone: the output, the gradient to the tokens and the
+    gradient to the router (the gates' path) to the last bit — a row's
+    numbers never depend on how many rows there are —, the gradients to the
+    three weight stacks to the order of a sum: they contract the rows'
+    axis, which the CPU's product cuts into blocks by its length (equal at
+    256 and 512 rows, 6e-7 of the largest entry apart at 1,024, where the
+    rows past the live ones add exact zeros in another order)."""
+    layer, sizes = _landing(landed), (256, 512, 1024)
+    assert moe.row_sizes(1024, 2, 12) == sizes
+    ran, real = [], jax.lax.ragged_dot
+
+    def ragged_dot(rows, *args, **kwargs):
+        jax.debug.callback(lambda: ran.append(rows.shape[0]))
+        return real(rows, *args, **kwargs)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+
+    def run():
+        def loss(leaves):
+            y, counts = moe.routed_experts(
+                leaves["x"], leaves["router"], jnp.zeros(12), leaves["gate"],
+                leaves["up"], leaves["down"], (3, 7), top_k=2, scale=1.5)
+            weigh = jnp.cos(jnp.arange(y.size, dtype=jnp.float32)
+                            ).reshape(y.shape)
+            return jnp.sum(y * weigh), (y, counts)
+
+        del ran[:]
+        out = jax.jit(jax.value_and_grad(loss, has_aux=True))(layer)
+        jax.effects_barrier()
+        return out, set(ran)
+
+    ((_, (y, counts)), grads), rows = run()
+    assert int(np.asarray(counts)[[3, 7]].sum()) == landed
+    assert rows == {sizes[rung]}
+    assert float(jnp.abs(y).max()) > 0
+    for name in grads:
+        assert float(jnp.abs(grads[name]).max()) > 0, name
+    for alone in sizes[rung:]:
+        monkeypatch.setattr(moe, "row_sizes", lambda *_: (alone,))
+        ((_, (y_alone, _)), grads_alone), rows = run()
+        assert rows == {alone}
+        np.testing.assert_array_equal(y, y_alone)
+        for name in ("x", "router"):
+            np.testing.assert_array_equal(grads[name], grads_alone[name],
+                                          err_msg=f"{name} at {alone}")
+        for name in ("gate", "up", "down"):
+            _close(grads[name], grads_alone[name], 2e-6)
+
+
+def test_routing_state_counts_the_layers_that_fit_the_tight_size():
+    """``step_routing_state`` at sizes where the three differ (512 tokens
+    choosing 2 of 12, two held: 256, 512, 1,024): a layer whose held slots
+    are at ``tight_rows`` or under is counted in ``tight``, one past
+    ``prefix_rows`` in ``overflow`` as before, one between in neither, and
+    ``routing_counters`` names both rows."""
+    landed = [0, 256, 257, 512, 513, 1024]
+    counts = np.zeros((len(landed), 12), np.int32)
+    counts[:, 3] = [n // 2 for n in landed]
+    counts[:, 7] = [n - n // 2 for n in landed]
+    counts[:, 0] = 1024 - np.asarray(landed)
+    state = {name: jnp.full(shape, 2, dtype) for name, (shape, dtype) in
+             moe.routing_state_shapes(12, len(landed)).items()}
+    new = moe.step_routing_state(state, jnp.asarray(counts), (3, 7), top_k=2,
+                                 speed=0.0, tokens=512)
+    assert new["tight"].tolist() == [3, 3, 2, 2, 2, 2]
+    assert new["overflow"].tolist() == [2, 2, 2, 2, 3, 3]
+    assert new["tight"].dtype == new["overflow"].dtype == jnp.int32
+    shown = moe.routing_counters(new, (3, 7))
+    np.testing.assert_array_equal(shown["moe_tight_layers_total"],
+                                  new["tight"])
+    np.testing.assert_array_equal(shown["moe_overflow_layers_total"],
+                                  new["overflow"])
 
 
 # ---------------------------------------------------------------------------
@@ -378,14 +542,18 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
       functions, the forward's and the backward's, called once a layer
       each; ``mla_moe``'s prediction module, whose operations a profile
       tells from the main layers' by their scope, has its own), so whatever
-      the layers' number a step is traced with, and its module holds, the
-      prefix form's twelve kernel calls once a part and none for the
-      overflow form, in eight functions (a product's forward and its
-      two gradients, at the gate's and at the down product's shape, and the
-      forward products once more as the backward pass asks for them): a
-      later change that doubles either fails here, not on the ledger's
-      ``setup_s``.
-    - A model with one form runs it on the kernels."""
+      the layers' number a step is traced with, and its module holds,
+      twelve kernel calls once a part and size on the kernels — the tight
+      form's (256 rows here) and the prefix form's (512), none for the
+      overflow form — in eight functions a size (a product's forward and
+      its two gradients, at the gate's and at the down product's shape, and
+      the forward products once more as the backward pass asks for them; a
+      kernel's body is unrolled at its row count, so two sizes share
+      none): the tight size is what PR 50 added to a start, a later change
+      that adds as much again fails here, not on the ledger's ``setup_s``.
+    - A model that holds a third of the experts has no form past
+      ``prefix_rows`` (all the slots): two sizes, both on the kernels, no
+      ``ragged_dot``."""
     import re
 
     from tensorflowonspark_tpu.parallel import mesh as mesh_lib
@@ -394,11 +562,13 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
     lib = mla_moe if model == "mla_moe" else lfm2_moe
     monkeypatch.setattr(kernels, "backend", lambda: "tpu")
     tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32)
-    for held, forms in (((3, 7), 2), ((3, 7, 1, 10), 1)):
+    for held, forms in (((3, 7), 3), ((3, 7, 1, 10), 2)):
         config = _fitting(lib, held)
         slots = config.seq_len * config.num_experts_per_tok
         prefix = moe.prefix_rows(slots, len(held), 12)
-        assert (prefix < slots) == (forms == 2)
+        assert (prefix < slots) == (forms == 3)
+        assert len(moe.row_sizes(slots, len(held), 12)) == forms
+        on_kernels = min(forms, 2)      # every size but the overflow one
         assert moe.grouped_runs_fused(prefix, 128, 256, config.dtype)
         assert lib.batch_counters(
             {"segment_ids": np.zeros((1, 512), np.int32)},
@@ -420,6 +590,8 @@ def test_a_start_pays_for_the_kernels_a_step_runs_and_no_others(
         assert len(re.findall(r"func\.func private @routed\w*\(",
                               step)) == 2 * parts
         assert len(re.findall(r"call @routed\w*\(", step)) == 2 * layers
-        assert _kernel_calls(step) == (12 * parts, 8, 8), (held, forms)
+        assert _kernel_calls(step) == (
+            12 * on_kernels * parts, 8 * on_kernels, 8 * on_kernels), (
+                held, forms)
         # the overflow form's products, where there is one
-        assert ("ragged_dot" in step) == (forms == 2)
+        assert ("ragged_dot" in step) == (forms == 3)

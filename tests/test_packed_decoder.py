@@ -234,7 +234,7 @@ def test_the_surface_the_registry_promises_is_on_the_module(name):
         assert lib.device_counters.__func__ is \
             packed_decoder.Decoder.device_counters
         assert set(lib.collection_shapes(config)) == {
-            "bias", "counts", "busiest", "overflow"}
+            "bias", "counts", "busiest", "overflow", "tight"}
         assert lib.routing(config).held == config.experts_held
     else:
         assert not hasattr(lib, "device_counters")
