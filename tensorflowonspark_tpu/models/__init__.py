@@ -24,15 +24,19 @@ the step's collections the counters show: what the device decided) and
 read: a checkpoint written before one was counted restores with it at
 zero).
 
-The five decoders trained on packed rows — ``granite_hybrid`` (state-space
+The six decoders trained on packed rows — ``granite_hybrid`` (state-space
 mixers and a NoPE attention layer), ``mla_moe`` (latent attention, routed
 and shared experts, the multi-token-prediction module), ``lfm2_moe`` (gated
 short-convolution mixers, a QK-normed RoPE attention layer, routed experts
 and no shared one), ``kimi_linear`` (Kimi Delta Attention mixers beside
 NoPE latent attention whose values are narrower than its keys, routed and
-shared experts) and ``mellum_moe`` (QK-normed RoPE attention in every layer,
+shared experts), ``mellum_moe`` (QK-normed RoPE attention in every layer,
 three in four behind a sliding window and the fourth with YaRN frequencies
-of its own, softmax-routed experts in every layer and no shared one) — each
+of its own, softmax-routed experts in every layer and no shared one) and
+``afmoe`` (QK-normed attention with an output gate, three layers in four
+behind a sliding window with RoPE and the fourth over the whole document
+with no position signal, four norms a layer, a scaled embedding, leading
+dense layers and then sigmoid-routed experts beside a shared one) — each
 keep their ``Config``, ``ADAMW``, ``leaf_shapes``, ``layer_kinds``
 (``mla_moe``: ``layer_prefixes``), mixers, ``_layer``, ``logits`` and
 ``batch_counters``, and share the rest:
@@ -41,17 +45,17 @@ keep their ``Config``, ``ADAMW``, ``leaf_shapes``, ``layer_kinds``
   are the surface above under every model's name (``make_model`` — a module
   that declares its variables and, while it initialises, traces no forward
   pass —, ``make_optimizer``, ``make_loss_fn``, ``make_forward_fn``,
-  ``example_batch``, ``parameter_count``, ``apply_tokens``, and for the four
-  expert models ``collection_shapes``, ``device_counters`` and
+  ``example_batch``, ``parameter_count``, ``apply_tokens``, and for the
+  five expert models ``collection_shapes``, ``device_counters`` and
   ``counter_rows``), the
   checkpointed layer loop, the feed-forward half of an expert model's layer,
   the loss over rows;
 - ``packed_rows.py``, the mathematics: norm, products, SwiGLU, the positions
   inside documents and RoPE at them (plain or YaRN frequencies), the
   depthwise causal convolution that stops at a document's first token, the
-  grouped-query layer and latent attention with or without a query latent
-  and rotation, attention inside documents with or without a sliding
-  window, the blocked loss;
+  grouped-query layer (gated or not, rotated or not) and latent attention
+  with or without a query latent and rotation, attention inside documents
+  with or without a sliding window, the blocked loss;
 - ``kernels.py``, the one seam between an algorithm and its Pallas kernels
   (``attention_pallas``, ``conv_pallas``, ``ssd_pallas``, ``kda_pallas``
   here, ``parallel/grouped_pallas.py``): ``backend()``, the rule
@@ -80,6 +84,7 @@ _REGISTRY = {
     "lfm2_moe": "tensorflowonspark_tpu.models.lfm2_moe",
     "kimi_linear": "tensorflowonspark_tpu.models.kimi_linear",
     "mellum_moe": "tensorflowonspark_tpu.models.mellum_moe",
+    "afmoe": "tensorflowonspark_tpu.models.afmoe",
 }
 
 

@@ -58,8 +58,8 @@ import numpy as np
 
 from tensorflowonspark_tpu.models import packed_decoder
 from tensorflowonspark_tpu.models.packed_rows import (
-    block, grouped_query_attention, mm, rms, rope_frequencies, row_counters,
-    yarn_frequencies)
+    BLOCKS_SCOPE, block, grouped_query_attention, mask_pairs, mm, rms,
+    rope_frequencies, row_counters, yarn_frequencies)
 
 #: no sequence-parallel sharding: a window has no neighbour's block over
 #: ``sp`` yet
@@ -76,10 +76,6 @@ PUBLISHED_LAYERS = ("sliding_attention",) * 3 + ("full_attention",)
 
 #: the rotations :func:`rotation` knows (``rope_parameters``' ``rope_type``)
 ROPE_TYPES = ("default", "yarn")
-
-#: the scope round a layer's blocks of scores, softmax and values, by type
-BLOCKS_SCOPE = {"sliding_attention": "window_attention",
-                "full_attention": "full_attention"}
 
 
 def published_rope_parameters() -> dict:
@@ -308,20 +304,6 @@ parameter_count = _DECODER.parameter_count
 example_batch = _DECODER.example_batch
 
 
-def mask_pairs(segment_ids, window=None) -> int:
-    """Query-key pairs a head's mask admits on the rows ``segment_ids`` (B,
-    T): ``j <= i`` in the same document and, under a ``window``, ``i - j <
-    window``.  A document of ``n`` tokens holds ``n (n + 1) / 2``, or ``w (w
-    + 1) / 2 + (n - w) w`` where it is longer than the window."""
-    seg = np.asarray(segment_ids)
-    edge = np.ones((seg.shape[0], 1), bool)
-    starts = np.flatnonzero(np.concatenate(
-        [edge, seg[:, 1:] != seg[:, :-1]], axis=1).reshape(-1))
-    n = np.diff(np.append(starts, seg.size)).astype(np.int64)
-    w = n if window is None else np.minimum(n, window)
-    return int(np.sum(w * (w + 1) // 2 + (n - w) * w))
-
-
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
@@ -329,7 +311,7 @@ def batch_counters(batch, config: Config) -> dict:
     ``moe.grouped_step_counters``: which execution of the routed experts'
     grouped products; and what the two masks really admit on this batch's
     documents, a head, summed over the layers of each kind:
-    :func:`mask_pairs`)."""
+    ``packed_rows.mask_pairs``)."""
     from tensorflowonspark_tpu.parallel import moe
 
     seg = np.asarray(batch["segment_ids"])

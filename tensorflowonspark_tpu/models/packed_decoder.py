@@ -1,28 +1,29 @@
 """The registry's side of a decoder trained on packed rows, once
 (``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``,
-``mellum_moe``).
+``mellum_moe``, ``afmoe``).
 
 A decoder's module keeps what is its own — ``Config``, ``ADAMW``,
 ``leaf_shapes``, its ``layer_kinds``, its mixers, its ``_layer`` (which names
 the ``jax.named_scope``s), its ``logits``, its ``batch_counters`` — and
 describes itself in one :class:`Decoder`, whose methods it binds to the names
 the registry promises (``models/__init__.py``: ``make_model =
-_DECODER.make_model`` and so on, a dozen lines at the end of each of the five).
+_DECODER.make_model`` and so on, a dozen lines at the end of each of the six).
 
-What is here has one body for the five: the loop over the layers (a layer's
+What is here has one body for the six: the loop over the layers (a layer's
 leaves sliced by prefix, the layer recomputed in the backward pass but for
 attention's output and log-sum-exp and what the model names to keep
 besides, the routing bias's row threaded to an expert layer and its counts
 gathered: :func:`run_layer` is the one-layer form), the
 feed-forward half of an expert model's layer with its leaves' shapes
-(:func:`feed_forward`), the next-token loss over rows a block of tokens at a
+(:func:`feed_forward`; where a layout norms what a half adds,
+:func:`add_normed`), the next-token loss over rows a block of tokens at a
 time (:func:`loss_sums`), the initializers two models or more draw from, the
 flax module, the optimizer, the example rows, the parameter count, the
 stateful loss and forward wrappers, and what of the routing state the
 program's counters show.  A recomputation policy, a batch axis or one
 ``jax.jit`` a layer shape is written here, once.
 
-What holds for all five and is no option of any: parameters are float32 (a
+What holds for all six and is no option of any: parameters are float32 (a
 flat dict; the flax module only declares them and the ``moe`` collection,
 and while it initialises traces no forward pass), activations
 ``Config.dtype``, the mathematics pure functions over the dict; every layer
@@ -134,21 +135,45 @@ def ffn_leaf_shapes(p: str, ffn: str, d: int, dense: int, experts: int,
     return out
 
 
+#: what a half of a layer that norms what it adds names of it
+#: (``checkpoint_name``): the mixer's and the feed-forward's result before
+#: their post-norms, whose backward pass reads them.  A model that lists one
+#: under ``Decoder.saved`` keeps it and does not make it a second time.
+MIXER_ADDED, FFN_ADDED = "mixer_added", "ffn_added"
+
+
+def add_normed(x, y, scale, eps: float, name: str | None = None):
+    """``x + y`` or, where the layout norms what a half of a layer adds
+    (``scale`` its post-norm's leaf; None: it has none), ``x + rms(y;
+    scale)`` under the ``jax.named_scope`` ``post_norm``, ``y`` named
+    ``name`` for a recomputation's policy."""
+    import jax
+    from jax.ad_checkpoint import checkpoint_name
+
+    if scale is None:
+        return x + y
+    with jax.named_scope("post_norm"):
+        return x + rms(checkpoint_name(y, name) if name else y, scale, eps)
+
+
 def feed_forward(lp, prefix: str, ffn: str, x, bias, eps: float, routing, *,
-                 shared: bool = False, scopes: tuple = ()):
+                 shared: bool = False, scopes: tuple = (),
+                 norm: str = "norm2", post_norm: str | None = None):
     """The second half of an expert model's layer on a batch of rows:
-    ``(x + FFN(rms(x)), counts)``.  ``ffn`` ``"dense"``: the SwiGLU of
-    ``mlp_{gate,up,down}`` under the ``jax.named_scope`` ``mlp``, ``counts``
-    (E,) zeros; ``"experts"``: ``moe.expert_ffn`` of the layout's
-    ``routing`` (with a ``shared`` expert or without, the layer's ``bias``
-    row, under the caller's ``scopes``) and the tokens that chose each
-    expert."""
+    ``(x + FFN(rms(x)), counts)``, the norm's leaf ``norm`` or, where the
+    layout norms the result too (``post_norm`` names that leaf), ``(x +
+    rms(FFN(rms(x))), counts)`` (:func:`add_normed`).  ``ffn`` ``"dense"``:
+    the SwiGLU of ``mlp_{gate,up,down}`` under the ``jax.named_scope``
+    ``mlp``, ``counts`` (E,) zeros; ``"experts"``: ``moe.expert_ffn`` of the
+    layout's ``routing`` (with a ``shared`` expert or without, the layer's
+    ``bias`` row, under the caller's ``scopes``) and the tokens that chose
+    each expert."""
     import jax
     import jax.numpy as jnp
 
     from tensorflowonspark_tpu.parallel import moe
 
-    h = rms(x, lp[prefix + "norm2"], eps).reshape(-1, x.shape[-1])
+    h = rms(x, lp[prefix + norm], eps).reshape(-1, x.shape[-1])
     if ffn == "dense":
         with jax.named_scope("mlp"):
             y = swiglu(h, lp[prefix + "mlp_gate"], lp[prefix + "mlp_up"],
@@ -157,7 +182,9 @@ def feed_forward(lp, prefix: str, ffn: str, x, bias, eps: float, routing, *,
     else:
         y, counts = moe.expert_ffn(lp, prefix, h, bias, routing,
                                    shared=shared, scopes=scopes)
-    return x + y.reshape(x.shape), counts
+    return add_normed(x, y.reshape(x.shape),
+                      lp[prefix + post_norm] if post_norm else None,
+                      eps, FFN_ADDED), counts
 
 
 def normals(std: float, layers: int) -> tuple:
@@ -200,12 +227,18 @@ def dt_bias(key, shape, dtype):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def embed(params, tokens, config):
-    """``E[u]`` in the activations' type."""
+def embed(params, tokens, config, multiplier: float | None = None):
+    """``E[u]`` in the activations' type; times ``multiplier`` first, in
+    the table's float32, where the layout scales its embedding (under the
+    ``jax.named_scope`` ``embed_scale``)."""
+    import jax
     import jax.numpy as jnp
 
-    return jnp.take(params["embed"], tokens, axis=0).astype(
-        jnp.dtype(config.dtype))
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if multiplier is not None:
+        with jax.named_scope("embed_scale"):
+            x = x * multiplier
+    return x.astype(jnp.dtype(config.dtype))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,7 +249,8 @@ class Decoder:
     leaf_shapes: Callable       # -> {name: shape}, in forward order
     #: -> [(prefix, *kinds)] of the layers the loop runs, in forward order;
     #: a layer whose last kind is "experts" takes a row of the routing bias
-    #: and gives the tokens that chose each expert
+    #: and gives the tokens that chose each expert (where the model has
+    #: ``gauges``: a tuple, the gauges' readings after them)
     layers: Callable
     layer: Callable             # :func:`run_layer`'s ``layer``
     logits: Callable            # (params, states, config) -> float32 logits
@@ -230,6 +264,10 @@ class Decoder:
     embed: Callable = embed     # (params, tokens, config) -> (B, T, D)
     positions: bool = False     # the layers read positions inside documents
     saved: tuple = ()           # names a layer's recomputation keeps
+    #: what an expert layer measures of itself beside its counts, an int32
+    #: scalar each: the collection's row (expert layers,) that adds a
+    #: gauge up -> the program's counter that shows the row
+    gauges: dict = dataclasses.field(default_factory=dict)
     example_tokens: Callable = lambda config: 64    # an example row's, at most
 
     def hidden_states(self, params, bias, tokens, seg, config):
@@ -264,14 +302,21 @@ class Decoder:
     def next_token_terms(self, params, bias, tokens, segment_ids, config):
         """``(sum of the cross-entropies, positions counted, counts)`` of a
         batch of packed rows (:func:`loss_sums`); ``counts`` (expert layers,
-        E) int32 in forward order (``[]`` for a model with no router)."""
+        E) int32 in forward order (``[]`` for a model with no router; with
+        the ``gauges``' (expert layers,) after it, a tuple, where the model
+        has some)."""
+        import jax
         import jax.numpy as jnp
 
         x, _, counts = self.hidden_states(params, bias, tokens, segment_ids,
                                           config)
-        if self.routing is not None:
-            counts = jnp.stack(counts) if counts else jnp.zeros(
-                (0, self.routing(config).n_experts), jnp.int32)
+        if self.routing is not None and counts:
+            counts = jax.tree.map(lambda *rows: jnp.stack(rows), *counts)
+        elif self.routing is not None:     # no layer of the run has a router
+            counts = jnp.zeros((0, self.routing(config).n_experts), jnp.int32)
+            if self.gauges:
+                counts = (counts, *(jnp.zeros((0,), jnp.int32)
+                                    for _ in self.gauges))
         return (*loss_sums(lambda xb: self.logits(params, xb, config), x,
                            tokens, segment_ids, config.loss_block), counts)
 
@@ -290,21 +335,28 @@ class Decoder:
 
     def collection_shapes(self, config) -> dict:
         """The ``moe`` collection: a row an expert layer, in forward order
-        (``moe.routing_state_shapes``)."""
+        (``moe.routing_state_shapes``, and an int32 a layer for every one
+        of ``gauges``)."""
         from tensorflowonspark_tpu.parallel import moe
 
         routing = self.routing(config)
-        return moe.routing_state_shapes(routing.n_experts, routing.layers)
+        return {**moe.routing_state_shapes(routing.n_experts, routing.layers),
+                **{row: ((routing.layers,), "int32") for row in self.gauges}}
 
     def step_collection(self, state: dict, counts, config, tokens: int):
         """The routing collection after a step whose ``tokens`` tokens chose
-        ``counts`` (expert layers, E): ``moe.step_routing_state``."""
+        ``counts`` (expert layers, E): ``moe.step_routing_state``, and every
+        gauge's readings (they follow ``counts`` in a tuple) added to its
+        row."""
         from tensorflowonspark_tpu.parallel import moe
 
+        counts, *readings = counts if self.gauges else (counts,)
         routing = self.routing(config)
-        return moe.step_routing_state(
+        return {**moe.step_routing_state(
             state, counts, routing.held, top_k=routing.top_k,
-            speed=routing.speed, tokens=tokens)
+            speed=routing.speed, tokens=tokens),
+                **{row: state[row] + reading
+                   for row, reading in zip(self.gauges, readings)}}
 
     def make_model(self, config, mesh=None):
         """The flax module: it declares every leaf of ``leaf_shapes`` and
@@ -374,19 +426,21 @@ class Decoder:
 
     def device_counters(self, collections, config) -> dict:
         """What the device decided, for the program's counters
-        (``moe.routing_counters`` of the routing collection)."""
+        (``moe.routing_counters`` of the routing collection, and the
+        ``gauges``' rows under their counters' names)."""
         from tensorflowonspark_tpu.parallel import moe
 
-        return moe.routing_counters(collections[COLLECTION],
-                                    self.routing(config).held)
+        state = collections[COLLECTION]
+        return {**moe.routing_counters(state, self.routing(config).held),
+                **{name: state[row] for row, name in self.gauges.items()}}
 
     def counter_rows(self, config) -> dict:
         """The rows of the collections that only the counters read
-        (``moe.COUNTER_ROWS``): what a checkpoint may lack and still be
-        restored, the row at zero."""
+        (``moe.COUNTER_ROWS`` and the ``gauges``'): what a checkpoint may
+        lack and still be restored, the row at zero."""
         from tensorflowonspark_tpu.parallel import moe
 
-        return {COLLECTION: moe.COUNTER_ROWS}
+        return {COLLECTION: (*moe.COUNTER_ROWS, *self.gauges)}
 
     def parameter_count(self, config) -> int:
         return sum(int(np.prod(s))

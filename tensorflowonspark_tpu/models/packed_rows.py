@@ -1,28 +1,31 @@
 """The mathematics the decoders trained on packed rows have in common
 (``granite_hybrid``, ``mla_moe``, ``lfm2_moe``, ``kimi_linear``,
-``mellum_moe``; ``packed_decoder`` holds their skeleton): the RMS norm, the
-product with operands in the activations' type, the SwiGLU feed-forward, the
-positions inside documents and the rotary embedding at them (plain or YaRN
-frequencies), the depthwise causal convolution that stops at a document's
-first token, causal attention inside documents a block of queries at a time
-(the values at their own width; under a sliding window the blocks behind it
-not visited), the grouped-query layer and latent attention over it, and the
-next-token cross-entropy a block of tokens at a time.  A packed row is ``T``
-tokens with segment ids ``s`` (the document's number inside the row;
+``mellum_moe``, ``afmoe``; ``packed_decoder`` holds their skeleton): the RMS
+norm, the product with operands in the activations' type, the SwiGLU
+feed-forward, the positions inside documents and the rotary embedding at
+them (plain or YaRN frequencies), the depthwise causal convolution that
+stops at a document's first token, causal attention inside documents a block
+of queries at a time (the values at their own width; under a sliding window
+the blocks behind it not visited), the grouped-query layer (with an output
+gate or without, rotated or position-free) and latent attention over it, and
+the next-token cross-entropy a block of tokens at a time.  A packed row is
+``T`` tokens with segment ids ``s`` (the document's number inside the row;
 documents are contiguous and their ids differ).  One implementation of each
 piece, each called by two models or more (:func:`causal_conv`: granite's
 state-space mixers, LFM2's gated short convolutions and Kimi Linear's
 delta-rule mixers; :func:`rope` and :func:`document_positions`: GLM's latent
 attention and the grouped-query layer; :func:`grouped_query_attention`:
-LFM2's, with plain RoPE and no window, and Mellum's, with a window and a
-rotation by layer type; :func:`latent_attention`: GLM's, with a query latent
-and RoPE, and Kimi Linear's, with neither): what is measured on one model's
-cell is what the others run.
+LFM2's, with plain RoPE and no window, Mellum's, with a window and a
+rotation by layer type, and ``afmoe``'s, gated, with a window and RoPE in
+its sliding layers and neither in its full ones; :func:`latent_attention`:
+GLM's, with a query latent and RoPE, and Kimi Linear's, with neither): what
+is measured on one model's cell is what the others run.
 
 Attention and the convolution are each one algorithm with two executions
 (:func:`document_attention`, :func:`causal_conv`): on a TPU at shapes that
 fill their tiles (a head in whole rows of 128 lanes — GLM's 20 x 256 and
-Mellum's 32/4 x 128, not granite's and LFM2's 32/8 x 64 nor Kimi Linear's
+Mellum's and ``afmoe``'s 32/4 x 128, not granite's and LFM2's 32/8 x 64 nor
+Kimi Linear's
 keys of 192 beside values of 128 —; the channels in whole rows of lanes and the row in whole tiles:
 the published 8,192 x 4,352, x 4,096 and x 2,048) the Pallas kernels of
 ``attention_pallas`` and ``conv_pallas``, anywhere else (``Config.tiny()``,
@@ -34,7 +37,7 @@ rules (``kernels.runs_fused``), and the models' steps count which applied
 
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
-are here too: host code, one copy for the five.
+are here too: host code, one copy for the six.
 
 JAX is imported where it is used, as in the models.
 """
@@ -412,40 +415,67 @@ def document_attention(q, k, v, seg, scale: float, size: int, dtype,
     return _attend()(q, k, v, seg, scale, size, dtype, tuple(scopes), window)
 
 
+#: what a gated grouped-query layer names (``checkpoint_name``) of its gate:
+#: the fifth projection's result, in the activations' type, before the
+#: sigmoid
+GATE_SAVED = "attention_gate"
+
+
 def grouped_query_attention(params, prefix: str, h, seg, pos, *, heads: int,
                             kv: int, hd: int, eps: float, size: int, freq,
                             factor: float = 1.0, window=None,
                             scopes: tuple = ("attention",),
-                            inner: str | None = None):
+                            inner: str | None = None, gate: bool = False):
     """Grouped-query attention on one row, every query and key head normed
     (one RMS scale of a head's width each: ``q_norm``, ``k_norm``) and then
-    turned by :func:`rope` (``freq``, ``factor``) at the positions ``pos``:
-    ``h`` (T, D) -> (T, D) from ``wq``, ``wk``, ``wv``, ``wo`` under
-    ``prefix``.  Query head ``i`` reads key head ``i // (heads / kv)``; the
-    softmax is scaled by ``1 / sqrt(hd)`` and runs in
+    turned by :func:`rope` (``freq``, ``factor``) at the positions ``pos``
+    or, where ``freq`` is None, left as they are (no positional encoding;
+    ``pos`` is not read): ``h`` (T, D) -> (T, D) from ``wq``, ``wk``,
+    ``wv``, ``wo`` under ``prefix``.  Query head ``i`` reads key head ``i //
+    (heads / kv)``; the softmax is scaled by ``1 / sqrt(hd)`` and runs in
     :func:`document_attention` in blocks of ``size`` queries, under a
     ``window`` where the layer has one, and under the ``jax.named_scope``
     ``inner`` where the caller names the blocks apart from the
-    projections (a model with layers of two masks)."""
+    projections (a model with layers of two masks).
+
+    ``gate`` (a layout whose attention has an output gate): a fifth
+    projection ``wg`` (D, heads hd) of ``h`` goes through a sigmoid, in
+    float32, and multiplies attention's output element by element before
+    ``wo``, under the ``jax.named_scope`` ``attention_gate``; the result is
+    then ``(out, open)``, ``open`` the sum of the sigmoids (float32, no
+    gradient: what the program's counters show of the gate)."""
     import math
 
     import jax
+    import jax.numpy as jnp
 
     dtype, t, rep = h.dtype, h.shape[0], heads // kv
     q = mm("td,de->te", h, params[prefix + "wq"], dtype)
     k = mm("td,de->te", h, params[prefix + "wk"], dtype)
     v = mm("td,de->te", h, params[prefix + "wv"], dtype).reshape(t, kv, hd)
+    def turn(x):
+        return x if freq is None else rope(x, pos, freq, factor)
+
     with jax.named_scope("qk_norm_rope"):
-        q = rope(rms(q.reshape(t, kv, rep, hd), params[prefix + "q_norm"],
-                     eps), pos, freq, factor)
-        k = rope(rms(k.reshape(t, kv, hd), params[prefix + "k_norm"], eps),
-                 pos, freq, factor)
+        q = turn(rms(q.reshape(t, kv, rep, hd), params[prefix + "q_norm"],
+                     eps))
+        k = turn(rms(k.reshape(t, kv, hd), params[prefix + "k_norm"], eps))
     inner = (inner,) if inner else ()
     with under(inner):
         o = document_attention(q, k, v, seg, 1.0 / math.sqrt(hd), size,
                                dtype, tuple(scopes) + inner, window)
-    return mm("te,ed->td", o.reshape(t, heads * hd), params[prefix + "wo"],
-              dtype)
+    o = o.reshape(t, heads * hd)
+    if gate:
+        from jax.ad_checkpoint import checkpoint_name
+
+        with jax.named_scope("attention_gate"):
+            g = jax.nn.sigmoid(checkpoint_name(
+                mm("td,de->te", h, params[prefix + "wg"], dtype),
+                GATE_SAVED).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * g).astype(dtype)
+            opened = jax.lax.stop_gradient(jnp.sum(g))
+    out = mm("te,ed->td", o, params[prefix + "wo"], dtype)
+    return (out, opened) if gate else out
 
 
 def latent_attention(params, prefix: str, h, seg, pos, *, heads: int,
@@ -556,6 +586,26 @@ def row_counters(segment_ids, head_dim: int, attends: bool = True,
         counts.update(step_counters(
             "conv", conv_runs_fused(seg.shape[1], *conv)))
     return counts
+
+
+#: the ``jax.named_scope`` round a layer's blocks of scores, softmax and
+#: values by the layer's published type, in a model with layers of two masks
+BLOCKS_SCOPE = {"sliding_attention": "window_attention",
+                "full_attention": "full_attention"}
+
+
+def mask_pairs(segment_ids, window=None) -> int:
+    """Query-key pairs a head's mask admits on the rows ``segment_ids`` (B,
+    T): ``j <= i`` in the same document and, under a ``window``, ``i - j <
+    window``.  A document of ``n`` tokens holds ``n (n + 1) / 2``, or ``w (w
+    + 1) / 2 + (n - w) w`` where it is longer than the window."""
+    seg = np.asarray(segment_ids)
+    edge = np.ones((seg.shape[0], 1), bool)
+    starts = np.flatnonzero(np.concatenate(
+        [edge, seg[:, 1:] != seg[:, :-1]], axis=1).reshape(-1))
+    n = np.diff(np.append(starts, seg.size)).astype(np.int64)
+    w = n if window is None else np.minimum(n, window)
+    return int(np.sum(w * (w + 1) // 2 + (n - w) * w))
 
 
 def example_rows(vocab_size: int, batch_size: int, seed: int, t: int) -> dict:

@@ -902,6 +902,71 @@ def test_mellum2_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
             < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
 
 
+@pytest.mark.slow  # ~2 min here; the builder's by-hand rehearsal
+def test_afmoe_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
+    """The ``trinity_mini`` configuration as the benchmark builds it (the
+    published layers 1-5: a dense layer and four expert layers with 16 of
+    128 sigmoid-routed experts held beside a shared one, three sliding to
+    one full, an eighth of the vocabulary and an untied head: 705,473,792
+    float32 parameters under AdamW) on one packed row of 8,192 tokens,
+    through the TPU compiler: parameters, both moments and the routing
+    state — the gate's row among it — are donated and updated in place;
+    attention at heads of 128 runs on the kernels of ``attention_pallas``
+    under grouped queries, **one function two ways in one step** (the four
+    sliding layers' calls under ``window_attention`` at a window of 2,048,
+    the position-free full layer's under ``full_attention``; a layer calls
+    the forward kernel once — its recomputation keeps what attention names
+    — and the backward kernel once); a layer keeps its gate's projection
+    and what each half adds before its post-norm too (``afmoe.SAVED``), so
+    the routed part runs as often as a sibling's; the grouped products are
+    the ones a chip runs (``grouped_pallas`` at ``moe.tight_rows`` and
+    ``moe.prefix_rows`` of the slots — 24,576 rows at an eighth share of
+    65,536 —, the compiler's ``ragged-dot`` in the overflow form); and
+    arguments, temporaries and code stay under 15.75 GiB.  PERF.md section
+    4 holds the figures."""
+    import json
+    import re
+
+    from benchmark.configs.trinity_mini import program
+    from tensorflowonspark_tpu.models import packed_rows
+    from tensorflowonspark_tpu.parallel import grouped_pallas, moe
+
+    with open(os.path.join(REPO, "benchmark", "configs", "trinity_mini",
+                           "config.json")) as f:
+        published = json.load(f)
+    monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
+    config = program.model_config(published)
+    assert packed_rows.attention_runs_fused(config.seq_len, config.head_dim)
+    for rows in moe.row_sizes(8 * 8192, 16, 128)[:2]:
+        assert grouped_pallas.fits(rows, 2048, 1024, "bfloat16"), rows
+    assert moe.prefix_rows(8 * 8192, 16, 128) == 24576
+    step, state, batch = abstract_train_step(
+        "afmoe", config, topo.devices[:1], 1, seq_len=config.seq_len)
+    assert batch["tokens"].shape == (1, 8192)
+    assert _param_count(state) == published["parameters"] == 705_473_792
+    assert state.collections["moe"]["bias"].shape == (4, 128)
+    assert state.collections["moe"]["gate_open"].shape == (4,)
+    compiled = step.lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    print(f"trinity_mini, one described chip: {stats}")
+    text = compiled.as_text()
+    _assert_grouped_kernels(text, layers=4)
+    ours = [n for n in _pallas_calls(text) if "/attention_" in n]
+    for scope, layers in (("window_attention", 4), ("full_attention", 1)):
+        mine = [n for n in ours if re.search(rf"\b{scope}\b", n)]
+        for kernel, calls in (("attention_forward", 1),
+                              ("attention_backward", 1)):
+            assert sum(f"/{kernel}/" in n for n in mine) == layers * calls, \
+                (scope, kernel, mine)
+    assert all(re.search(r"\battention\b", n) for n in ours), ours
+    assert len(ours) == 10
+    state_bytes = 12 * published["parameters"]
+    assert stats.alias_size_in_bytes >= state_bytes     # updated in place
+    assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
+    assert (_device_bytes(compiled) + stats.generated_code_size_in_bytes
+            < V5E_HBM_BYTES - 2 ** 28)      # 15.75 GiB
+
+
 def test_peak_tables_know_the_device_kind_the_chip_reports(topo):
     """``TPU v5 lite`` is what the v5e reports (chip run, PR 21) and what
     the described topology reports; both peak tables must resolve it."""

@@ -20,7 +20,7 @@ def test_registry_lists_all():
     assert ALL_MODELS == sorted(
         ["mnist_mlp", "cifar10_cnn", "resnet50", "inception_v3",
          "mobilenet_v1", "wide_deep", "bert", "tiny_lm", "granite_hybrid",
-         "mla_moe", "lfm2_moe", "kimi_linear", "mellum_moe"]
+         "mla_moe", "lfm2_moe", "kimi_linear", "mellum_moe", "afmoe"]
     )
 
 

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflowonspark_tpu.models import (
-    kernels, kimi_linear, lfm2_moe, mellum_moe, mla_moe)
+    afmoe, kernels, kimi_linear, lfm2_moe, mellum_moe, mla_moe)
 from tensorflowonspark_tpu.parallel import grouped_pallas, moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -264,10 +264,11 @@ def test_both_models_count_the_execution_of_their_grouped_products(
     ("lfm2_8b_a1b", lfm2_moe, (12288, 24576, 32768)),
     ("kimi_linear_48b_a3b", kimi_linear, (3072, 6144, 65536)),
     ("mellum2_12b_a2_5b", mellum_moe, (24576, 49152, 65536)),
+    ("trinity_mini", afmoe, (12288, 24576, 65536)),
 ])
 def test_the_three_sizes_at_the_published_shapes(model, lib, sizes,
                                                  monkeypatch):
-    """``moe.row_sizes`` of the four cells' routed layers: ``tight_rows``
+    """``moe.row_sizes`` of the five cells' routed layers: ``tight_rows``
     (half over the even share, in whole row tiles of the kernels),
     ``prefix_rows`` as it was, all the slots; the first two on the kernels
     at the published widths, the last on ``ragged_dot``."""

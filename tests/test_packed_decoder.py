@@ -1,4 +1,4 @@
-"""The skeleton the five decoders trained on packed rows share
+"""The skeleton the six decoders trained on packed rows share
 (``models/packed_decoder.py``) and the one seam their kernels' rules read
 (``models/kernels.py``), each case once a decoder:
 
@@ -54,6 +54,9 @@ DECODERS = {
         {"kda_scan": 1, "conv": 1, "attention": 0, "moe_grouped": 1}),
     "mellum_moe": (
         "mellum2_12b_a2_5b", ("attention",),
+        {"attention": 1, "moe_grouped": 1}),
+    "afmoe": (
+        "trinity_mini", ("attention",),
         {"attention": 1, "moe_grouped": 1}),
 }
 
@@ -234,7 +237,8 @@ def test_the_surface_the_registry_promises_is_on_the_module(name):
         assert lib.device_counters.__func__ is \
             packed_decoder.Decoder.device_counters
         assert set(lib.collection_shapes(config)) == {
-            "bias", "counts", "busiest", "overflow", "tight"}
+            "bias", "counts", "busiest", "overflow", "tight",
+            *getattr(lib, "GATE_OPEN", ())}     # and a model's gauges
         assert lib.routing(config).held == config.experts_held
     else:
         assert not hasattr(lib, "device_counters")
@@ -249,10 +253,13 @@ ONE_ATTENTION_LAYER = {
     "kimi_linear": dict(num_hidden_layers=1, kda_layers=(),
                         full_attn_layers=(1,)),
     "mellum_moe": dict(layer_types=("full_attention",), layers_run=(0,)),
+    "afmoe": dict(layer_types=("full_attention",), layers_run=(0,),
+                  num_dense_layers=1),
 }
-#: ... and what makes that layer's heads fill the kernels' tiles (the two
+#: ... and what makes that layer's heads fill the kernels' tiles (the three
 #: whose published heads do)
 ON_THE_KERNELS = {
+    "afmoe": dict(head_dim=128, seq_len=128),
     "mla_moe": dict(qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=128,
                     seq_len=128),
     "mellum_moe": dict(head_dim=128, seq_len=128),
