@@ -38,7 +38,11 @@ whole rows of 128 lanes (``packed_rows.attention_runs_fused``), so the
 published 32/8 heads of 64 keep the ``jnp`` form on every backend, and a
 step says so (``attention_plain_steps_total``).  Recomputation and the
 blocked attention and loss are why a row of 8,192 tokens at the published
-widths trains on one chip beside 16 bytes of state a parameter.
+widths trains on one chip beside 16 bytes of state a parameter.  Half of a
+step is the feed-forwards', so a layer's recomputation keeps the results of
+their two wide products (``packed_rows.SWIGLU_SAVED``, 2 x tokens x
+intermediate x 2 B a layer) and makes them once a step; a step counts the
+layers that do (``ffn_kept_layers_total``).
 
 ``jax.named_scope`` names a device trace can be cut by: ``ssm_mixer`` (the
 whole mixer) > ``ssm_conv``, ``ssm_scan``; ``attention``; ``mlp``;
@@ -52,8 +56,8 @@ import dataclasses
 from tensorflowonspark_tpu.models import packed_decoder
 from tensorflowonspark_tpu.models.kernels import runs_fused, step_counters
 from tensorflowonspark_tpu.models.packed_rows import (
-    block as _block, causal_conv, document_attention, mm as _mm, rms as _rms,
-    row_counters, swiglu)
+    SWIGLU_SAVED, block as _block, causal_conv, document_attention,
+    mm as _mm, rms as _rms, row_counters, swiglu)
 
 #: no sequence-parallel sharding: the scan's state does not cross ``sp`` yet
 SEQUENCE_AXES: dict = {}
@@ -366,7 +370,7 @@ def _init(config: Config):
 
 _DECODER = packed_decoder.Decoder(
     adamw=ADAMW, leaf_shapes=leaf_shapes, layers=layer_kinds, layer=_layer,
-    logits=logits, init=_init, embed=_embed,
+    logits=logits, init=_init, embed=_embed, saved=SWIGLU_SAVED,
     example_tokens=lambda config: 2 * config.mamba_chunk_size)
 make_model = _DECODER.make_model
 make_optimizer = _DECODER.make_optimizer
@@ -383,9 +387,14 @@ def batch_counters(batch, config: Config) -> dict:
     documents, and which executions of attention and of the mixers'
     convolution its trace applied) and, by
     the same kind of rule (:func:`scan_runs_fused`), one step of the scan on
-    the kernels or as ``jnp`` code, the other named with 0."""
+    the kernels or as ``jnp`` code, the other named with 0; and
+    ``ffn_kept_layers_total``, the layers whose recomputation keeps the
+    feed-forward's two wide products (all, or none where the decoder's
+    ``saved`` does not list ``packed_rows.SWIGLU_SAVED``)."""
     scans = "mamba" in config.layer_types
-    return {**row_counters(batch["segment_ids"], config.head_dim,
+    kept = set(SWIGLU_SAVED) <= set(_DECODER.saved)
+    return {"ffn_kept_layers_total": len(layer_kinds(config)) if kept else 0,
+            **row_counters(batch["segment_ids"], config.head_dim,
                            "attention" in config.layer_types,
                            conv=(config.conv_dim, config.mamba_d_conv)
                            if scans else None),

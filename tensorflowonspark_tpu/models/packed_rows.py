@@ -87,14 +87,24 @@ def attention_runs_fused(t: int, hd: int, vd: int | None = None) -> bool:
     return runs_fused(attention_pallas, t, hd, when=vd in (None, hd))
 
 
+#: what :func:`swiglu` names (``checkpoint_name``): the results of its two
+#: wide products, in the activations' type.  A model that lists them under
+#: ``Decoder.saved`` makes them once a step: ``silu(gate) * up`` is made
+#: again from the kept pair, an element-wise pass, and the down projection's
+#: result is nobody's residual.  A name no policy lists lowers to nothing.
+SWIGLU_SAVED = ("ffn_gate", "ffn_up")
+
+
 def swiglu(h, w_gate, w_up, w_down):
-    """``W_down (silu(h W_gate) * (h W_up))`` on (T, D) tokens."""
+    """``W_down (silu(h W_gate) * (h W_up))`` on (T, D) tokens, the two
+    wide products' results named :data:`SWIGLU_SAVED`."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
     dtype = h.dtype
-    gate = mm("td,df->tf", h, w_gate, dtype)
-    up = mm("td,df->tf", h, w_up, dtype)
+    gate = checkpoint_name(mm("td,df->tf", h, w_gate, dtype), SWIGLU_SAVED[0])
+    up = checkpoint_name(mm("td,df->tf", h, w_up, dtype), SWIGLU_SAVED[1])
     act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
     return mm("tf,fd->td", act, w_down, dtype)
 

@@ -593,6 +593,7 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     import json
 
     from benchmark.configs.granite_4_0_h_micro import program
+    from tensorflowonspark_tpu.models import packed_rows
     monkeypatch.setattr(kernel_seam, "backend", lambda: "tpu")
 
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -615,8 +616,14 @@ def test_granite_published_width_step_fits_one_v5e_chip(topo, monkeypatch):
     state_bytes = 12 * published["parameters"]
     assert stats.alias_size_in_bytes >= state_bytes     # updated in place
     assert stats.argument_size_in_bytes < state_bytes + 2 ** 20
-    # the whole gradient (4 bytes a parameter) is never held at once
-    assert stats.temp_size_in_bytes < 4 * published["parameters"]
+    # the whole gradient (4 bytes a parameter) is never held at once: the
+    # temporaries are under it plus what every layer's recomputation keeps
+    # of its feed-forward (``packed_rows.SWIGLU_SAVED``: the two wide
+    # products' results, in the activations' 2 bytes)
+    kept = (len(config.layer_types) * len(packed_rows.SWIGLU_SAVED)
+            * config.seq_len * config.intermediate_size * 2)
+    assert kept == 2_684_354_560
+    assert stats.temp_size_in_bytes < 4 * published["parameters"] + kept
     assert _device_bytes(compiled) < V5E_HBM_BYTES - 2 ** 30
 
 

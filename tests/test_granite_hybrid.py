@@ -11,6 +11,7 @@ a few hundred float32 additions in another order move, and is 1,000 times
 tighter than a forgotten boundary or multiplier would need.
 """
 
+import dataclasses
 import glob
 import json
 import os
@@ -23,7 +24,8 @@ import jax.numpy as jnp
 
 from benchmark.configs.granite_4_0_h_micro import program, reference, work
 from benchmark.traffic import packed_documents
-from tensorflowonspark_tpu.models import granite_hybrid as gh, kernels
+from tensorflowonspark_tpu.models import (granite_hybrid as gh, kernels,
+                                         packed_rows)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG_DIR = os.path.join(REPO, "benchmark", "configs", "granite_4_0_h_micro")
@@ -133,6 +135,89 @@ def test_granite_logits_loss_and_every_leafs_gradient_match_the_reference(tiny):
     for name, g in want.items():
         assert float(jnp.abs(g).max()) > 0, name    # every leaf is trained
         _close(grads[program.program_name(name)], g)
+
+
+def _without_kept_products():
+    """The decoder with nothing of the feed-forward's kept: the parent's."""
+    return dataclasses.replace(gh._DECODER, saved=())
+
+
+@pytest.mark.parametrize("layers", [("mamba",),
+                                    ("mamba", "attention", "mamba")])
+def test_granite_feed_forwards_make_their_wide_products_once(layers):
+    """A layer's recomputation keeps the results of the feed-forward's two
+    wide products (``packed_rows.SWIGLU_SAVED``, which ``_DECODER.saved``
+    lists), so the lowered gradient of the loss holds two ``dot_general``
+    a layer fewer than with nothing listed: 9 a feed-forward (3 forward, 6
+    backward) for 11 (2 more made again)."""
+    config = dataclasses.replace(gh.Config.tiny(), layer_types=layers)
+    assert gh._DECODER.saved == packed_rows.SWIGLU_SAVED
+    batch = _rows(config, 1, 3)
+    params = gh.make_model(config).init(
+        jax.random.PRNGKey(0), batch["tokens"], batch["segment_ids"])["params"]
+
+    def products(decoder):
+        return jax.jit(jax.grad(decoder.make_loss_fn(None, config))).lower(
+            params, batch).as_text().count("stablehlo.dot_general")
+
+    kept, again = products(gh._DECODER), products(_without_kept_products())
+    assert again - kept == 2 * len(layers)
+
+
+def test_granite_kept_products_are_the_ones_a_second_pass_made(tiny):
+    """What is kept is what the recomputation made: the loss and every
+    leaf's gradient are equal to the last bit with the two names kept and
+    with ``saved`` emptied.  Operation by operation, not jitted: the same
+    operations then run in the same order on both sides, where a compiler
+    given two whole programs fuses the sums round the kept values its own
+    way in each (a last bit of a fiftieth of the entries, on the CPU)."""
+    config = dataclasses.replace(tiny[0], layer_types=tiny[0].layer_types[:3])
+    assert set(config.layer_types) == {"mamba", "attention"}
+    params = {k: tiny[3][k] for k in gh.leaf_shapes(config)}
+    batch = _rows(config, 1, 4)
+
+    def value_and_grad(decoder):
+        with jax.disable_jit():
+            return jax.value_and_grad(decoder.make_loss_fn(None, config))(
+                params, batch)
+
+    (loss, grads), (want_loss, want) = (
+        value_and_grad(gh._DECODER), value_and_grad(_without_kept_products()))
+    assert np.asarray(loss).tobytes() == np.asarray(want_loss).tobytes()
+    assert set(grads) == set(want) == set(gh.leaf_shapes(config))
+    for name, g in want.items():
+        assert float(jnp.abs(g).max()) > 0, name
+        np.testing.assert_array_equal(np.asarray(grads[name]), np.asarray(g),
+                                      err_msg=name)
+
+
+def test_granite_step_counts_the_layers_that_keep_their_products(
+        tiny, monkeypatch):
+    """``ffn_kept_layers_total`` a step: the configuration's layers while
+    the decoder lists ``packed_rows.SWIGLU_SAVED``, 0 with ``saved``
+    emptied; a ``Trainer.step`` adds it to the program's counters."""
+    from tensorflowonspark_tpu import obs
+    from tensorflowonspark_tpu.trainer import Trainer
+
+    config = tiny[0]
+    batch = _rows(config, 1, 5)
+    assert gh.batch_counters(batch, config)["ffn_kept_layers_total"] == len(
+        config.layer_types) > 1
+    published = program.model_config(_published())
+    assert gh.batch_counters(batch, published)["ffn_kept_layers_total"] == 10
+
+    def total():
+        return obs.get_registry().snapshot()["counters"].get(
+            "ffn_kept_layers_total", 0)
+
+    trainer = Trainer("granite_hybrid", config=config,
+                      devices=jax.devices()[:1])
+    before = total()
+    trainer.step(batch)
+    assert total() - before == len(config.layer_types)
+    monkeypatch.setattr(gh, "_DECODER", _without_kept_products())
+    assert gh.batch_counters(batch, config)["ffn_kept_layers_total"] == 0
+    assert gh.batch_counters(batch, published)["ffn_kept_layers_total"] == 0
 
 
 def test_granite_trainer_follows_the_reference_for_three_adamw_steps(tiny):

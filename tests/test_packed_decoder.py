@@ -11,7 +11,9 @@
 - a layer's recomputation keeps what attention names
   (``packed_rows.ATTENTION_SAVED``): the forward blocks run once in a
   gradient, the gradient is the one the second run gave, and a layer with
-  no attention lowers to the text it had.
+  no attention lowers to the text it had;
+- what ``packed_rows.swiglu`` names (``SWIGLU_SAVED``) and no policy lists
+  lowers to nothing: the four expert decoders' steps are the text they were.
 
 (That the skeleton computes what the four copies computed is not held here:
 parameters, first loss and first gradients were bit-equal with the parent
@@ -367,7 +369,8 @@ def test_a_layer_with_no_attention_lowers_to_the_text_it_had(name,
     ours = text()
     assert "stablehlo.while" in ours
     saved = lib.make_model.__self__.saved
-    assert bool(saved) == (name == "kimi_linear")
+    # (granite keeps its feed-forwards' products, Kimi Linear its scan's)
+    assert bool(saved) == (name != "lfm2_moe")
     keep, checkpoint = (jax.checkpoint_policies.save_only_these_names,
                         jax.checkpoint)
     given = []
@@ -377,3 +380,49 @@ def test_a_layer_with_no_attention_lowers_to_the_text_it_had(name,
             f, policy=keep(*saved) if policy and saved else None))[1])
     assert text() == ours
     assert sum(policy is not None for policy in given) == 1
+
+
+@pytest.mark.parametrize("name", ["afmoe", "kimi_linear", "lfm2_moe",
+                                  "mla_moe"])
+def test_a_name_no_policy_lists_lowers_to_nothing(name, monkeypatch):
+    """``packed_rows.swiglu`` names its two wide products' results
+    (``SWIGLU_SAVED``) for the decoder that keeps them, granite's.  The
+    four expert decoders reach it too — a dense layer's feed-forward, a
+    shared expert — and list neither name, so the gradient of their tiny
+    step lowers to the text it lowers to with those two names never
+    given."""
+    import re
+
+    import jax.ad_checkpoint
+
+    from tensorflowonspark_tpu.models import packed_rows
+
+    lib = zoo.get_model(name)
+    config = lib.Config.tiny()
+    assert not set(packed_rows.SWIGLU_SAVED) & set(
+        lib.make_model.__self__.saved)
+
+    def text():
+        # a lowered function's name ends in a running number that counts
+        # the equations before it, a name's own too: each function is called
+        # here by the order in which the text first mentions it
+        grad, params = _gradient(name, config)
+        seen = {}
+        return re.sub(r"@[\w.]+", lambda m: seen.setdefault(
+            m.group(), f"@f{len(seen)}"), grad.lower(params).as_text())
+
+    ours = text()
+    assert "stablehlo.dot_general" in ours
+    named, name_it = [], jax.ad_checkpoint.checkpoint_name
+
+    def name_all_but_swiglus(x, given):
+        if given not in packed_rows.SWIGLU_SAVED:
+            return name_it(x, given)
+        named.append(given)
+        return x
+
+    # (every site imports the function when it runs)
+    monkeypatch.setattr(jax.ad_checkpoint, "checkpoint_name",
+                        name_all_but_swiglus)
+    assert text() == ours
+    assert set(named) == set(packed_rows.SWIGLU_SAVED)
