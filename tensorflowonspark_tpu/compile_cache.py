@@ -39,6 +39,27 @@ counts what it saves:
   thread-exact: JAX's monitoring events fire synchronously on the
   compiling thread, so ``serving.note_compile``'s settle logic can tell
   *this* forward's disk hit from a concurrent one.
+- **A start's seconds from inside**: this module is the one place that
+  listens to JAX's monitoring, so it also turns JAX's own time-span
+  events (``dispatch.log_elapsed_time`` round every trace, lowering and
+  backend compile, on the wall clock the ring is on) into ring spans
+  on the compiling thread, children of whatever span is open there
+  (``trainer.dispatch``, ``ckpt.restore``, a feed's thread):
+  ``jit.trace`` (attr ``fun``; only a trace of at least
+  :data:`TRACE_SPAN_FLOOR_S`, a nested one inside the one that holds it),
+  ``jit.lower`` (attr ``fun``) and ``jit.compile`` (attrs ``fun`` and
+  ``cache``: ``hit`` with ``retrieval_s`` and ``saved_s``, JAX's two
+  durations; ``miss`` with ``entry_bytes``, the put's ``len(val)``, and
+  ``written``, 1 where the entry's file is there after the put and was
+  not before; ``off`` where :func:`active` is false or the compile asked
+  no cache).  The persistent cache's read and write run inside the
+  compile's stretch, so the sibling listeners leave thread-local notes
+  that the span takes and clears.  Two counters:
+  ``jit_traces_total`` counts every trace, recorded or not, and
+  ``compile_cache_disk_misses_total`` every ``jit.compile`` that says
+  ``miss``.  The listeners are installed by :func:`ensure` whether or not
+  the cache is on; a steady step fires no JAX event, and under
+  ``TFOS_TRACE=0`` only the counters move.
 
 ``TFOS_COMPILE_CACHE=0`` opts a process out (the test suite does).  Every
 compile is worth writing (serving forwards are small and the whole point
@@ -68,7 +89,17 @@ SPOOL_DIR = os.path.join(_REPO, ".jax_cache_spool")
 #: WRITTEN — for us that is the disk-write counter, not a miss.
 _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_WRITE = "/jax/compilation_cache/cache_misses"
+_EV_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 _DUR_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_DUR_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+#: JAX's time-span events (jax/_src/dispatch.py) -> the ring span of each
+_SPAN_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_SPAN_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_SPAN_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+#: a trace shorter than this is counted and leaves no span: every ``jnp``
+#: primitive's own ``jit`` is traced and reported, thousands a start
+TRACE_SPAN_FLOOR_S = 0.005
 
 #: retrieval-time histogram bounds: a disk hit is mmap+deserialize —
 #: sub-ms local, tens of ms on shared fs, seconds only when something is
@@ -147,11 +178,17 @@ def ensure() -> str | None:
     else the local directory), or None when opted out or unconfigurable.
     Never raises: a cache problem must not take down a training step or a
     tenant load — the process just compiles like it always did, and the
-    reason lands in :func:`stats` (and so on ``/healthz``)."""
+    reason lands in :func:`stats` (and so on ``/healthz``).  Opted out or
+    not, the process gets the monitoring listeners: the ``jit.*`` spans of
+    its compiles say ``cache: off``."""
     with _LOCK:
         if _STATE["attempted"]:
             return _STATE["namespace"]
         if not enabled():
+            try:
+                _install_listeners()
+            except Exception as e:  # pragma: no cover - a jax without them
+                logger.warning("compile monitoring not installed: %s", e)
             return None
         _STATE["attempted"] = True
         try:
@@ -438,29 +475,41 @@ def _install_listeners() -> None:
     global _LISTENING
     if _LISTENING:
         return
-    from jax._src import monitoring
+    from tensorflowonspark_tpu import util
+
+    util.ensure_jax_platform()
+    from jax import monitoring
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_time_span_listener(_on_time_span)
     _LISTENING = True
+
+
+def _notes() -> dict:
+    """What the cache's events said on this thread since its last
+    ``jit.compile`` span: they fire inside the compile's stretch, before
+    its time-span event does."""
+    notes = getattr(_TLS, "notes", None)
+    if notes is None:
+        notes = _TLS.notes = {}
+    return notes
 
 
 def _on_event(event: str, **kw) -> None:
     # runs inside jax's compile path: must never raise
     try:
-        if event == _EV_HIT:
+        if event == _EV_REQUEST:
+            _notes()["requested"] = True
+        elif event == _EV_HIT:
             _instruments()[0].inc()
             _TLS.hits = getattr(_TLS, "hits", 0) + 1
-        elif event == _EV_WRITE:
+            _notes()["hit"] = True
+        elif event == _EV_WRITE and _STATE["active_dir"]:
             _count_files_written()
             sync_async()
     except Exception:  # pragma: no cover
         pass
-
-
-def _cache_entries() -> int:
-    with os.scandir(_STATE["active_dir"]) as it:
-        return sum(1 for e in it if e.name.endswith("-cache"))
 
 
 def _count_files_written() -> None:
@@ -471,7 +520,11 @@ def _count_files_written() -> None:
     21) — counting the event counted attempts.  So on the first write
     event the cache object's ``put`` is wrapped (the event fires just
     before jax looks ``put`` up, so the wrapper already sees that very
-    write) to count the entries the directory gained across it."""
+    write) to see whether the entry's own file is there after it and was
+    not before — not what the directory gained, which a put that evicts
+    older entries to make room makes zero or less.  The put's size and
+    outcome are left for the ``jit.compile`` span of the stretch it runs
+    in."""
     from jax._src import compilation_cache as cc
 
     cache = cc._cache
@@ -480,13 +533,15 @@ def _count_files_written() -> None:
     put = cache.put
 
     def counted_put(key, val):
-        before = _cache_entries()
+        path = os.path.join(_STATE["active_dir"], f"{key}-cache")
+        before = os.path.exists(path)
         try:
             put(key, val)
         finally:
-            written = _cache_entries() - before
-            if written > 0:
-                _instruments()[1].inc(written)
+            written = int(os.path.exists(path) and not before)
+            _notes().update(entry_bytes=len(val), written=written)
+            if written:
+                _instruments()[1].inc()
 
     cache.put = counted_put
     cache._tfos_counted = True
@@ -496,7 +551,51 @@ def _on_duration(event: str, duration: float, **kw) -> None:
     try:
         if event == _DUR_RETRIEVAL:
             _instruments()[3].observe(float(duration))
+            _notes()["retrieval_s"] = float(duration)
+        elif event == _DUR_SAVED:
+            _notes()["saved_s"] = float(duration)
     except Exception:  # pragma: no cover
+        pass
+
+
+def _on_time_span(event: str, start: float, end: float, fun_name: str = "",
+                  **kw) -> None:
+    """One trace, lowering or backend compile of JAX's, as a ring span on
+    the compiling thread (``start`` / ``end`` are JAX's reads of the wall
+    clock the ring is on).  Runs inside jax's compile path: must never raise."""
+    try:
+        from tensorflowonspark_tpu import obs
+
+        if event == _SPAN_TRACE:
+            obs.counter("jit_traces_total",
+                        "functions JAX traced to a jaxpr in this process "
+                        "(every jit, the jnp primitives' own included); "
+                        "none in a steady step").inc()
+            if end - start >= TRACE_SPAN_FLOOR_S:
+                obs.complete("jit.trace", start, end - start, fun=fun_name)
+        elif event == _SPAN_LOWER:
+            obs.complete("jit.lower", start, end - start, fun=fun_name)
+        elif event == _SPAN_COMPILE:
+            notes, _TLS.notes = _notes(), None
+            if not (active() and notes.get("requested")):
+                # jax "requests" its cache with no directory set too
+                attrs = {"cache": "off"}
+            elif notes.get("hit"):
+                attrs = {"cache": "hit",
+                         "retrieval_s": notes.get("retrieval_s"),
+                         "saved_s": notes.get("saved_s")}
+            else:
+                attrs = {"cache": "miss",
+                         "entry_bytes": notes.get("entry_bytes", 0),
+                         "written": notes.get("written", 0)}
+                obs.counter("compile_cache_disk_misses_total",
+                            "backend compiles that asked the persistent "
+                            "compile cache and were not served from it "
+                            "(XLA compiled; the put may or may not have "
+                            "written a file)").inc()
+            obs.complete("jit.compile", start, end - start, fun=fun_name,
+                         **attrs)
+    except Exception:
         pass
 
 

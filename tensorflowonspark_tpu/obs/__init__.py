@@ -132,6 +132,17 @@ step — never per record, row or chunk):
   ``after`` says which — to the loss ready; attrs ``input_wait_s``,
   ``dispatch_s``; an upper estimate of the device's time from the host's
   clock, not the device's own);
+- JAX's compile path (``compile_cache.py``, the one listener of JAX's
+  monitoring; from JAX's own time-span events, in the ring only, children
+  of the span open on the compiling thread — ``trainer.dispatch``,
+  ``ckpt.restore``, a feed's thread; none in a steady step):
+  ``jit.trace`` (attr ``fun``, JAX's ``fun_name``; only a trace of 5 ms
+  or more, nested ones inside the trace that holds them), ``jit.lower``
+  (attr ``fun``), ``jit.compile`` (attrs ``fun``, ``cache``: ``hit`` with
+  ``retrieval_s`` / ``saved_s``, ``miss`` with ``entry_bytes`` /
+  ``written``, or ``off``); counters ``jit_traces_total`` (every trace,
+  recorded or not) and ``compile_cache_disk_misses_total`` (one a
+  ``jit.compile`` that says ``miss``);
 - kernels: ``jax.named_scope`` ``forward`` and ``optimizer`` in the
   compiled step (``parallel/train.py``); counters
   ``table_update_rows_steps_total`` / ``table_update_full_steps_total``,
