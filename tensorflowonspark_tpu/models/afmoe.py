@@ -326,7 +326,8 @@ example_batch = _DECODER.example_batch
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters
     (``packed_rows.row_counters``: the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied;
+    documents, which execution of attention its trace applied and the
+    blocks its kernels visit, each layer at its own window;
     ``moe.grouped_step_counters``: which execution of the routed experts'
     grouped products; what the two masks really admit on this batch's
     documents, a head, summed over the layers of each kind:
@@ -338,7 +339,9 @@ def batch_counters(batch, config: Config) -> dict:
 
     seg = np.asarray(batch["segment_ids"])
     mixers = [mixer for _, mixer, _ in layer_kinds(config)]
-    return {**row_counters(seg, config.head_dim),
+    return {**row_counters(seg, config.head_dim, tuple(
+                config.sliding_window if mixer == "sliding_attention"
+                else None for mixer in mixers)),
             **moe.grouped_step_counters(
                 seg.size, routing(config), config.hidden_size,
                 config.moe_intermediate_size, config.dtype),

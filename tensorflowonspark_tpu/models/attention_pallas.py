@@ -26,18 +26,34 @@ out.  Two kernels under one ``jax.custom_vjp`` (:func:`fused_attention`):
 The mathematics is the ``jnp`` form's to the rounding: the mask is ``j <= i
 and same document``, masked scores are -1e30, every product takes operands
 in ``dtype`` (``p`` and ``ds`` cast before theirs) and accumulates in
-float32, the softmax is float32.  **Every block on or below the diagonal is
-visited whatever the documents are** — the grid and the loops' bounds come
-from the shapes alone, the segment ids only enter the mask — so a step's
-time does not follow its row.  Only the blocks the diagonal crosses compare
-positions.
+float32, the softmax is float32.  Only the blocks the diagonal crosses
+compare positions.
+
+**The loops stop at a document's edge.**  The grid comes from the shapes
+alone and the segment ids themselves are read into the mask only, but two
+small int32 vectors made of them outside the kernels (:func:`document_starts`:
+a running maximum of the places where the id changes, and its mirror) reach
+the kernels as scalars in SMEM and clip the loops: a block of queries starts
+at ``first_key_block[i]``, the block of keys that holds the first token of
+its first query's document, and a block of keys ends at
+``past_query_block[j]``, past the last block of queries that holds a token
+of its last key's document.  This rests on ``packed_rows``' contract —
+documents are contiguous and their ids differ —: every pair of a block left
+out is of two documents, hidden by the mask, and adds exactly 0 to the
+running sum, the output, ``dq``, ``dk`` and ``dv``.  A visited block still
+holds other documents' pairs and masks them as before; the blocks the
+diagonal crosses are always visited.  So **a step's time follows its row**:
+a row that is one document costs what the shapes say, a row of many short
+ones about half of it (:func:`visited` counts both, and
+``packed_rows.row_counters`` writes them into the program's counters).
 
 Under a sliding ``window`` (``i - j < window``) the loops **skip the blocks
 the window cannot reach**: a block of queries starts at the first block of
 keys that holds a key within ``window - 1`` of its first query, a block of
 keys ends at the last block of queries whose first query is within ``window
 - 1`` of its last key (:func:`forward_bounds`, :func:`backward_bounds`:
-``program_id`` arithmetic with the window in it, never the segment ids).
+``program_id`` arithmetic with the window in it, clipped by the documents'
+bound: the later start, the earlier end).
 The blocks the window's far edge crosses compare positions as the
 diagonal's do; the blocks wholly inside compare documents only.  The same
 blocks serve a window as serve none (read on the chip at 8,192 x 32/4 x 128
@@ -110,8 +126,9 @@ def _offsets(rows: int, cols: int, by_row: bool):
     return r - c if by_row else c - r
 
 
-def _forward_kernel(scale, dtype, bk, window, q_ref, k_ref, v_ref, seg_q_ref,
-                    seg_k_ref, out_ref, lse_ref, m_ref, l_ref, acc_ref):
+def _forward_kernel(scale, dtype, bk, window, first_key_ref, q_ref, k_ref,
+                    v_ref, seg_q_ref, seg_k_ref, out_ref, lse_ref, m_ref,
+                    l_ref, acc_ref):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -151,12 +168,10 @@ def _forward_kernel(scale, dtype, bk, window, q_ref, k_ref, v_ref, seg_q_ref,
         jax.lax.fori_loop(lo, hi, body, 0)
 
     below = (i * bq) // bk      # blocks of keys wholly before the queries
-    if window is None:
-        visits(0, below, False)
-    else:
-        first, inside = forward_bounds(i, bq, bk, window)
+    first, inside = forward_bounds(i, bq, bk, window, first_key_ref[i])
+    if window is not None:
         visits(first, inside, True)
-        visits(inside, below, False)
+    visits(inside, below, False)
     for d in range(max(1, bq // bk)):
         visit(below + d, True, _edge_on_diagonal(window, bq, bk))
     l = l_ref[...]
@@ -165,9 +180,9 @@ def _forward_kernel(scale, dtype, bk, window, q_ref, k_ref, v_ref, seg_q_ref,
     lse_ref[...] = jnp.broadcast_to(lse, (bq, 128)).T[0:1]
 
 
-def _backward_kernel(scale, dtype, bq, window, q_ref, do_ref, k_ref, v_ref,
-                     seg_k_ref, seg_q_ref, lse_ref, delta_ref, dq_ref,
-                     dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+def _backward_kernel(scale, dtype, bq, window, past_query_ref, q_ref, do_ref,
+                     k_ref, v_ref, seg_k_ref, seg_q_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -211,12 +226,10 @@ def _backward_kernel(scale, dtype, bq, window, q_ref, do_ref, k_ref, v_ref,
     crossed = max(1, bk // bq)
     for d in range(crossed):
         visit(first + d, True, _edge_on_diagonal(window, bq, bk))
-    after, end = first + crossed, q_ref.shape[0] // bq
-    if window is None:
-        visits(after, end, False)
-    else:
-        inside, last = backward_bounds(j, bq, bk, window, end)
-        visits(after, inside, False)
+    inside, last = backward_bounds(j, bq, bk, window, q_ref.shape[0] // bq,
+                                   past_query_ref[j])
+    visits(first + crossed, inside, False)
+    if window is not None:
         visits(inside, last, True)
     dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -230,52 +243,122 @@ def _edge_on_diagonal(window, bq: int, bk: int) -> bool:
     return window is not None and window < max(bq, bk)
 
 
-def forward_bounds(i, bq: int, bk: int, window: int) -> tuple:
-    """``(first, inside)`` of the forward kernel's block ``i`` of queries
-    under a window: the first block of keys with a key inside the first
-    query's window, and the first whose every key is inside the last
-    query's (no further than the diagonal's); the blocks between them are
-    the ones the window's far edge crosses.  ``i`` a ``program_id`` or a
-    Python number: shapes and the window alone."""
+def _np_or_jnp(x):
+    """numpy for what the host counts (Python and numpy numbers), ``jnp``
+    for what a kernel or a step traces: one arithmetic for both."""
+    if isinstance(x, (int, np.integer, np.ndarray)):
+        return np
     import jax.numpy as jnp
 
+    return jnp
+
+
+def document_starts(seg):
+    """(..., T) int32: the token at which every token's document starts, on
+    the rows ``seg`` (..., T).  From ``packed_rows``' contract that
+    **documents are contiguous and their ids differ**: a token whose id is
+    not the one before it starts a document, and every token up to the next
+    such one is that document's.  A running maximum over the places where
+    the id changes; ``seg`` a numpy array (the host's count) or a ``jnp``
+    one (the kernels' operands)."""
+    xp = _np_or_jnp(seg)
+    if xp is np:
+        running_max = functools.partial(np.maximum.accumulate, axis=-1)
+    else:
+        import jax
+
+        running_max = functools.partial(jax.lax.cummax, axis=seg.ndim - 1)
+    starts = xp.concatenate([xp.ones(seg.shape[:-1] + (1,), bool),
+                             seg[..., 1:] != seg[..., :-1]], axis=-1)
+    return running_max(xp.where(
+        starts, xp.arange(seg.shape[-1], dtype=xp.int32), 0))
+
+
+def first_key_blocks(seg, bq: int, bk: int):
+    """(..., T / bq) int32, one a block of queries of the forward pass: the
+    document of block ``i``'s first query starts at token ``p`` — no query
+    of the block sees a key before it (:func:`document_starts`) —; the
+    value is ``p // bk``, never past the diagonal's block."""
+    return document_starts(seg)[..., ::bq] // bk
+
+
+def past_query_blocks(seg, bq: int, bk: int):
+    """(..., T / bk) int32, one a block of keys of the backward pass: the
+    document of block ``j``'s last key ends before token ``e`` — no query
+    from ``e`` on sees a key of the block; the mirror of a start:
+    :func:`document_starts` of the row read from its end —; the value is
+    ``(e + bq - 1) // bq``, never before the diagonal's blocks."""
+    t = seg.shape[-1]
+    ends = t - document_starts(seg[..., ::-1])[..., ::-1]
+    return (ends[..., bk - 1::bk] + bq - 1) // bq
+
+
+def forward_bounds(i, bq: int, bk: int, window, first_key=0) -> tuple:
+    """``(first, inside)`` of the forward kernel's block ``i`` of queries,
+    the blocks of keys before the diagonal's in two ranges: from ``first``
+    to ``inside`` the ones the window's far edge crosses (none without a
+    ``window``), from ``inside`` to the diagonal's the ones wholly inside.
+    Under a window ``first`` is the first block of keys with a key inside
+    the first query's window and ``inside`` the first whose every key is
+    inside the last query's (no further than the diagonal's); both are then
+    no earlier than ``first_key``, the first block that holds the queries'
+    document (:func:`first_key_blocks`; 0: the shapes and the window alone).
+    ``i`` a ``program_id`` or numpy numbers."""
+    xp = _np_or_jnp(i)
+    if window is None:
+        return first_key, first_key
     below = (i * bq) // bk
-    first = jnp.maximum(i * bq - (window - 1), 0) // bk
-    return first, jnp.clip((jnp.maximum(i * bq + bq - window, 0) + bk - 1)
-                           // bk, first, below)
+    first = xp.maximum(i * bq - (window - 1), 0) // bk
+    inside = xp.clip((xp.maximum(i * bq + bq - window, 0) + bk - 1) // bk,
+                     first, below)
+    return xp.maximum(first, first_key), xp.maximum(inside, first_key)
 
 
-def backward_bounds(j, bq: int, bk: int, window: int, end) -> tuple:
-    """``(inside, last)`` of the backward kernel's block ``j`` of keys under
-    a window, both ends of a range: past the last block of queries (behind
-    the diagonal's, of ``end`` in the row) whose every query's window holds
-    the block's first key, and past the last with a query whose window
-    holds its last key; the blocks between them are the ones the window's
-    far edge crosses."""
-    import jax.numpy as jnp
+def backward_bounds(j, bq: int, bk: int, window, end, past_query=None
+                    ) -> tuple:
+    """``(inside, last)`` of the backward kernel's block ``j`` of keys, both
+    ends of a range of the blocks of queries behind the diagonal's (of
+    ``end`` in the row): up to ``inside`` the ones wholly inside the
+    window, from ``inside`` to ``last`` the ones its far edge crosses (none
+    without a ``window``).  Under a window ``inside`` is past the last
+    block whose every query's window holds the block's first key and
+    ``last`` past the last with a query whose window holds its last key;
+    both are then no later than ``past_query``, past the last block that
+    holds the keys' document (:func:`past_query_blocks`; None: the shapes and
+    the window alone), which is never before the diagonal's."""
+    xp = _np_or_jnp(j)
+    if window is None:
+        inside = last = end
+    else:
+        after = (j * bk) // bq + max(1, bk // bq)
+        last = xp.minimum((j * bk + bk + window - 2) // bq + 1, end)
+        inside = xp.clip((xp.maximum(j * bk + window - bq + 1, 0) + bq - 1)
+                         // bq, after, xp.maximum(last, after))
+    if past_query is None:
+        return inside, last
+    return xp.minimum(inside, past_query), xp.minimum(last, past_query)
 
-    after = (j * bk) // bq + max(1, bk // bq)
-    last = jnp.minimum((j * bk + bk + window - 2) // bq + 1, end)
-    return jnp.clip((jnp.maximum(j * bk + window - bq + 1, 0) + bq - 1)
-                    // bq, after, jnp.maximum(last, after)), last
 
-
-def visited(t: int, forward: tuple, backward: tuple, window=None) -> tuple:
+def visited(t: int, forward: tuple, backward: tuple, window=None, seg=None
+            ) -> tuple:
     """``(forward, backward)``: the (block of queries, block of keys) pairs
-    the two kernels' loops visit on a row of ``t`` tokens at each pass's
-    (queries, keys) a tile, from the bounds the kernels compute."""
+    a head's two kernels' loops visit at each pass's (queries, keys) a tile,
+    from the bounds the kernels compute: on the rows ``seg`` (..., ``t``) of
+    segment ids, summed over them, or, without them, on one row of ``t``
+    tokens by the shapes and the ``window`` alone (what a row that is one
+    document visits, and what every row did before the loops followed the
+    documents)."""
+    seg = None if seg is None else np.asarray(seg)
     bq, bk = _blocks(t, forward)
-    n_forward = sum(
-        (i * bq) // bk + max(1, bq // bk) - (0 if window is None else int(
-            forward_bounds(i, bq, bk, window)[0]))
-        for i in range(t // bq))
+    i = np.arange(t // bq)
+    first, _ = forward_bounds(i, bq, bk, window, 0 if seg is None
+                              else first_key_blocks(seg, bq, bk))
+    n_forward = np.sum((i * bq) // bk + max(1, bq // bk) - first)
     bq, bk = _blocks(t, backward)
-    end = t // bq
-    n_backward = sum(
-        (end if window is None else int(
-            backward_bounds(j, bq, bk, window, end)[1])) - (j * bk) // bq
-        for j in range(t // bk))
-    return n_forward, n_backward
+    j = np.arange(t // bk)
+    _, last = backward_bounds(j, bq, bk, window, t // bq, None if seg is None
+                              else past_query_blocks(seg, bq, bk))
+    return int(n_forward), int(np.sum(last - (j * bk) // bq))
 
 
 def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk, window=None):
@@ -294,7 +377,7 @@ def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk, window=None):
     return pl.pallas_call(
         functools.partial(_forward_kernel, scale, dtype, bk, window),
         grid=(heads, t // bq),
-        in_specs=[queries, row, row,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), queries, row, row,
                   pl.BlockSpec((bq, 1), lambda h, i: (i, 0)),
                   pl.BlockSpec((t // bk, 1, bk), lambda h, i: (0, 0, 0))],
         out_specs=[queries, pl.BlockSpec((None, None, 1, bq),
@@ -305,7 +388,8 @@ def _forward(q2, k2, v2, seg, scale, dtype, hd, bq, bk, window=None):
                         pltpu.VMEM((bq, hd), f32)],
         compiler_params=compiler_params(VMEM_LIMIT_BYTES),
         name="attention_forward",
-    )(q2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bk, 1, bk))
+    )(first_key_blocks(seg, bq, bk), q2, k2, v2, seg.reshape(t, 1),
+      seg.reshape(t // bk, 1, bk))
 
 
 def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk,
@@ -331,7 +415,7 @@ def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk,
     return pl.pallas_call(
         functools.partial(_backward_kernel, scale, dtype, bq, window),
         grid=(heads, t // bk),
-        in_specs=[row, row, keys, keys,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row, row, keys, keys,
                   pl.BlockSpec((bk, 1), lambda h, j: (j, 0)),
                   pl.BlockSpec((t // bq, 1, bq), lambda h, j: (0, 0, 0)),
                   per_query, per_query],
@@ -341,8 +425,8 @@ def _backward(q2, k2, v2, seg, lse, do2, delta, scale, dtype, hd, bq, bk,
                         pltpu.VMEM((bk, hd), f32)],
         compiler_params=compiler_params(VMEM_LIMIT_BYTES),
         name="attention_backward",
-    )(q2, do2, k2, v2, seg.reshape(t, 1), seg.reshape(t // bq, 1, bq), lse,
-      delta)
+    )(past_query_blocks(seg, bq, bk), q2, do2, k2, v2, seg.reshape(t, 1),
+      seg.reshape(t // bq, 1, bq), lse, delta)
 
 
 def _heads_along_lanes(x, dtype):

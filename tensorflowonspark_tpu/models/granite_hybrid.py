@@ -395,7 +395,7 @@ def batch_counters(batch, config: Config) -> dict:
     kept = set(SWIGLU_SAVED) <= set(_DECODER.saved)
     return {"ffn_kept_layers_total": len(layer_kinds(config)) if kept else 0,
             **row_counters(batch["segment_ids"], config.head_dim,
-                           "attention" in config.layer_types,
+                           (None,) * config.layer_types.count("attention"),
                            conv=(config.conv_dim, config.mamba_d_conv)
                            if scans else None),
             **step_counters("ssm_scan", scan_runs_fused(

@@ -679,7 +679,8 @@ def batch_counters(batch, config: Config) -> dict:
     mixers = [mixer for _, mixer, _ in layer_kinds(config)]
     scans = "kda" in mixers
     return {**row_counters(seg, config.qk_head_dim,
-                           "full_attention" in mixers, config.v_head_dim,
+                           (None,) * mixers.count("full_attention"),
+                           config.v_head_dim,
                            conv=(config.kda_width,
                                  config.short_conv_kernel_size)
                            if scans else None),

@@ -293,7 +293,8 @@ def batch_counters(batch, config: Config) -> dict:
 
     seg = np.asarray(batch["segment_ids"])
     return {**row_counters(seg, config.head_dim,
-                           "full_attention" in config.layer_types,
+                           (None,) * config.layer_types.count(
+                               "full_attention"),
                            conv=(config.hidden_size, config.conv_L_cache)
                            if "conv" in config.layer_types else None),
             **moe.grouped_step_counters(

@@ -319,7 +319,8 @@ example_batch = _DECODER.example_batch
 def batch_counters(batch, config: Config) -> dict:
     """What one step adds to the program's counters:
     ``packed_rows.row_counters`` (the host batch's tokens, loss tokens and
-    documents, and which execution of attention its trace applied), which
+    documents, which execution of attention its trace applied and the
+    blocks its kernels visit, every layer and the prediction module), which
     execution of the routed experts' grouped products
     (``moe.grouped_step_counters``) and the tokens that bear the second loss
     (the two next are the same document's)."""
@@ -328,7 +329,8 @@ def batch_counters(batch, config: Config) -> dict:
     seg = np.asarray(batch["segment_ids"])
     same = seg[:, 1:] == seg[:, :-1]
     return {**row_counters(seg, config.qk_head_dim,
-                           v_head_dim=config.v_head_dim),
+                           (None,) * len(layer_prefixes(config)),
+                           config.v_head_dim),
             **moe.grouped_step_counters(
                 seg.size, routing(config), config.hidden_size,
                 config.moe_intermediate_size, config.dtype),

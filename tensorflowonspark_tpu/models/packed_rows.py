@@ -33,7 +33,11 @@ the tests) the ``jnp`` forms in this file, which are also the kernels'
 oracles.  :func:`attention_runs_fused` and :func:`conv_runs_fused` are the
 rules (``kernels.runs_fused``), and the models' steps count which applied
 (``attention_fused_steps_total`` / ``attention_plain_steps_total``,
-``conv_fused_steps_total`` / ``conv_plain_steps_total``).
+``conv_fused_steps_total`` / ``conv_plain_steps_total``).  The attention
+kernels' loops stop at a document's edge, so a step on them follows its
+rows (``attention_blocks_visited_total`` of
+``attention_blocks_reached_total``); the ``jnp`` form visits every block the
+shapes and the window reach.
 
 What a step of packed rows adds to the program's counters from its host
 batch (:func:`row_counters`) and the zoo's example rows (:func:`example_rows`)
@@ -396,27 +400,34 @@ def document_attention(q, k, v, seg, scale: float, size: int, dtype,
     diagonal is ever made and none is held beyond its block.  The backward
     pass recomputes each block's probabilities from the saved log-sum-exp,
     under the ``jax.named_scope``s ``scopes`` (the caller's: the forward
-    pass runs under the caller's own).  Every row costs the same whatever
-    its documents are (the blocks of another document are visited and
-    masked): a step's time does not depend on the data.  ``q`` (T, kv, rep,
-    hd), ``k`` (T, kv, hd), ``v`` (T, kv, vd) — values at their own width,
-    which a latent-attention layout may make narrower than its keys —,
-    ``seg`` (T,); returns (T, kv, rep, vd).
+    pass runs under the caller's own).  ``q`` (T, kv, rep, hd), ``k`` (T,
+    kv, hd), ``v`` (T, kv, vd) — values at their own width, which a
+    latent-attention layout may make narrower than its keys —, ``seg``
+    (T,), the documents contiguous and their ids different; returns (T, kv,
+    rep, vd).
 
     ``window`` (a sliding-window layer's; None: none): a query also sees
     only the ``window`` keys up to its own (``i - j < window``, itself
     among them; places in the row, which inside a document differ as the
     places in it do), and **a block of queries starts at the first block of
     keys its window reaches** (:func:`first_key_block`): the blocks behind
-    the window are not visited, forward or backward.  The loops' bounds
-    still come from the shapes and the window alone.
+    the window are not visited, forward or backward.
 
     One algorithm, two executions (:func:`attention_runs_fused`): on a TPU,
     where a head fills whole rows of lanes, the kernels of
     ``attention_pallas`` keep each score tile on the chip, forward and
     backward (they take keys and values of one width); anywhere else the
     ``jnp`` form above runs in blocks of ``size``, which is also the
-    kernels' oracle."""
+    kernels' oracle.  **What a row costs differs between the two.**  In the
+    ``jnp`` form the loops' bounds come from the shapes and the window
+    alone: the blocks of another document are visited and masked, and every
+    row costs the same whatever its documents are.  The kernels' loops also
+    stop at a document's edge (``attention_pallas.first_key_blocks``,
+    ``past_query_blocks``: a block none of whose pairs the mask admits is
+    not visited, and adds exactly 0 where it is), so a row of many short
+    documents costs less than a row that is one, which costs what the shapes
+    say: there a step's time follows its rows (:func:`row_counters` counts
+    by how much)."""
     if attention_runs_fused(q.shape[0], q.shape[-1], v.shape[-1]):
         from tensorflowonspark_tpu.models import attention_pallas
 
@@ -573,7 +584,7 @@ def blocked_cross_entropy(x, logits_fn, targets, valid, want: int):
     return jnp.sum(sums)
 
 
-def row_counters(segment_ids, head_dim: int, attends: bool = True,
+def row_counters(segment_ids, head_dim: int, windows: tuple = (None,),
                  v_head_dim: int | None = None,
                  conv: tuple | None = None) -> dict:
     """What one step of packed rows adds to the program's counters, whatever
@@ -581,17 +592,42 @@ def row_counters(segment_ids, head_dim: int, attends: bool = True,
     that bear a loss (the next token is the same document's) and documents
     (runs of one segment id).  From the rules its trace applied, a pair
     each (``kernels.step_counters``): attention's
-    (:func:`attention_runs_fused`; ``attends``: the model has an attention
-    layer; ``v_head_dim``: its values' width where it is not ``head_dim``)
-    and, for a model that calls :func:`causal_conv` (``conv``: its
-    (channels, taps); None: the pair is left out), the convolution's."""
+    (:func:`attention_runs_fused`; ``windows``: the window of every
+    attention layer the step runs, None where a layer has none, empty for
+    a model with no attention layer; ``v_head_dim``: its values' width
+    where it is not ``head_dim``) and, for a model that calls
+    :func:`causal_conv` (``conv``: its (channels, taps); None: the pair is
+    left out), the convolution's.
+
+    And how far the attention kernels' loops followed the documents:
+    ``attention_blocks_visited_total``, the (block of queries, block of
+    keys) pairs a head's two kernels visit on these rows, forward and
+    backward at the kernels' own tiles, summed over the layers each at its
+    own window, of ``attention_blocks_reached_total``, what the shapes and
+    the windows alone reach (``attention_pallas.visited`` with the ids and
+    without: the arithmetic the kernels' operands are made by).  Equal on
+    rows that are one document each; both 0 where the ``jnp`` form runs,
+    whose loops visit every block reached."""
+    from tensorflowonspark_tpu.models import attention_pallas
+
     seg = np.asarray(segment_ids)
     same = seg[:, 1:] == seg[:, :-1]
+    fused = bool(windows) and attention_runs_fused(
+        seg.shape[1], head_dim, v_head_dim)
+    visited = reached = 0
+    for window in set(windows) if fused else ():
+        blocks = (seg.shape[1], attention_pallas.FORWARD_BLOCKS,
+                  attention_pallas.BACKWARD_BLOCKS, window)
+        layers = windows.count(window)
+        visited += layers * sum(attention_pallas.visited(*blocks, seg))
+        reached += layers * seg.shape[0] * sum(
+            attention_pallas.visited(*blocks))
     counts = {"lm_tokens_total": int(seg.size),
               "lm_loss_tokens_total": int(same.sum()),
               "lm_documents_total": int(seg.shape[0] + (~same).sum()),
-              **step_counters("attention", attention_runs_fused(
-                  seg.shape[1], head_dim, v_head_dim), attends)}
+              **step_counters("attention", fused, bool(windows)),
+              "attention_blocks_visited_total": visited,
+              "attention_blocks_reached_total": reached}
     if conv is not None:
         counts.update(step_counters(
             "conv", conv_runs_fused(seg.shape[1], *conv)))

@@ -158,7 +158,16 @@ step — never per record, row or chunk):
   ``granite_hybrid.scan_runs_fused``);
   ``attention_fused_steps_total`` / ``attention_plain_steps_total``
   (``packed_rows.document_attention``, ``models/attention_pallas.py``;
-  ``attention_runs_fused``);
+  ``attention_runs_fused``) and, beside them,
+  ``attention_blocks_visited_total`` of
+  ``attention_blocks_reached_total``: the kernels' loops stop at a
+  document's edge, so a step on them follows its rows — the (block of
+  queries, block of keys) pairs a head's two kernels visit on the step's
+  rows, forward and backward, summed over the attention layers each at
+  its own window, of what the shapes and the windows alone reach
+  (``packed_rows.row_counters`` from ``attention_pallas.visited``; equal
+  on rows that are one document each; both 0 where the ``jnp`` form
+  runs, whose loops do not follow the documents);
   ``conv_fused_steps_total`` / ``conv_plain_steps_total``
   (``packed_rows.causal_conv`` of ``granite_hybrid``, ``lfm2_moe`` and
   ``kimi_linear``, ``models/conv_pallas.py``; ``conv_runs_fused``);

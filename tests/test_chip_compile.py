@@ -269,6 +269,28 @@ def test_granite_scan_kernels_compile_at_the_published_widths(topo):
         assert compiled.memory_analysis().temp_size_in_bytes < tile_bytes
 
 
+def _bounds_operands(text: str) -> list:
+    """The length of every kernel call's first operand where it is a vector
+    of int32: the loops' bounds made of the segment ids
+    (``attention_pallas.first_key_blocks``, ``past_query_blocks``), which
+    reach the kernels as scalars in SMEM."""
+    import re
+
+    return [int(n) for n in re.findall(
+        r'custom_call_target="tpu_custom_call", '
+        r'operand_layout_constraints=\{s32\[(\d+)\]\{0\}', text)]
+
+
+def _bounds_of(t: int, kernels: list) -> list:
+    """A bound a block of queries forward, a bound a block of keys
+    backward."""
+    from tensorflowonspark_tpu.models import attention_pallas
+
+    size = {"attention_forward": attention_pallas.FORWARD_BLOCKS[0],
+            "attention_backward": attention_pallas.BACKWARD_BLOCKS[1]}
+    return [t // size[kernel] for kernel in kernels]
+
+
 def test_attention_kernels_compile_at_the_published_widths(topo):
     """The Pallas kernels of ``document_attention``
     (``models/attention_pallas.py``) through the TPU's compiler at the
@@ -276,8 +298,9 @@ def test_attention_kernels_compile_at_the_published_widths(topo):
     ``jax.vmap``, 20 heads of 256, bfloat16 — forward and gradient: this
     refuses what interpret mode cannot (a misaligned slice, a transpose the
     chip has not, more fast memory than a kernel may use: a head's whole row
-    is resident).  Compiled, every kernel call still carries ``attention``
-    as a word of its ``op_name`` (``benchmark/device_scopes.py`` finds
+    is resident; the loops' bounds read from SMEM, one a grid step, the
+    first operand of each call).  Compiled, every kernel call still carries
+    ``attention`` as a word of its ``op_name`` (``benchmark/device_scopes.py`` finds
     ``mla_device_ms`` by it; the backward pass opens the scope itself), and
     no score leaves a kernel: the temporaries are ``out``, the loss's
     float32 copy of it and the gradients, each the size of an operand (84
@@ -313,6 +336,7 @@ def test_attention_kernels_compile_at_the_published_widths(topo):
             compiled.as_text())
         assert [n.split("/")[-2] for n in names] == kernels, names
         assert all(re.search(r"\battention\b", name) for name in names), names
+        assert _bounds_operands(compiled.as_text()) == _bounds_of(t, kernels)
         assert (compiled.memory_analysis().temp_size_in_bytes
                 < 6 * t * heads * hd * 2)
 
@@ -322,9 +346,9 @@ def test_window_attention_kernels_compile_at_the_published_widths(topo):
     runs — one row of 8,192 tokens, 32 query heads of 128 on 4 key heads,
     bfloat16, a window of 1,024 — forward and gradient through the TPU's
     compiler: the loops' bounds are ``program_id`` arithmetic with the
-    window in it (a clip, a floor division), which interpret mode cannot
-    refuse; every kernel call carries the caller's scopes, and no score
-    leaves a kernel."""
+    window in it (a clip, a floor division) clipped by the documents' bound
+    read from SMEM, which interpret mode cannot refuse; every kernel call
+    carries the caller's scopes, and no score leaves a kernel."""
     import re
 
     import jax.numpy as jnp
@@ -360,6 +384,7 @@ def test_window_attention_kernels_compile_at_the_published_widths(topo):
         assert [n.split("/")[-2] for n in names] == kernels, names
         assert all(re.search(rf"\b{scope}\b", name) for name in names
                    for scope in scopes), names
+        assert _bounds_operands(compiled.as_text()) == _bounds_of(t, kernels)
         # out, its float32 copy, the gradients; dk and dv a query head in
         # float32 before their sum over a key head's eight
         assert (compiled.memory_analysis().temp_size_in_bytes

@@ -536,7 +536,10 @@ def test_blocks_behind_the_window_are_not_visited(form, request):
     (``test_packed_rows_attention.py``), under a window of one block it
     spoils the first two blocks of queries — the second visits the first
     block for its window's far edge — and no later one, whatever the
-    documents are: those blocks of scores are never made."""
+    documents are: those blocks of scores are never made.  (Without a
+    window the kernels' loops stop at a document's edge, and the last block
+    of queries of the row of three documents, whose own starts in the
+    third, is clean: ``test_packed_rows_attention.py``.)"""
     size, t, hd = (128, 512, 128) if form == "kernels" else (16, 64, 8)
     if form == "kernels":
         request.getfixturevalue("kernels_on_the_cpu")
@@ -551,7 +554,8 @@ def test_blocks_behind_the_window_are_not_visited(form, request):
             run = lambda w: packed_rows.document_attention(  # noqa: E731
                 q, k, v, seg, 0.1, size, jnp.float32, (), window=w)
         spoiled = np.isnan(np.asarray(run(None))).any(axis=(1, 2, 3))
-        assert spoiled.all()
+        reached = t - size if form == "kernels" and len(lengths) > 1 else t
+        assert spoiled[:reached].all() and not spoiled[reached:].any()
         spoiled = np.isnan(np.asarray(run(size))).any(axis=(1, 2, 3))
         assert spoiled[:2 * size].all() and not spoiled[2 * size:].any()
         # two tokens more and the third block's first query reaches the
@@ -608,7 +612,9 @@ def test_blocks_visited_are_the_hand_count():
 def test_the_kernels_loops_under_a_window_come_from_the_shapes_alone():
     """The forward kernel's jaxpr with a window: the segment ids are read
     into the mask's comparison only; the two loops' bounds (the blocks the
-    far edge crosses, the blocks inside) are ``program_id`` arithmetic."""
+    far edge crosses, the blocks inside) are ``program_id`` arithmetic,
+    clipped by the documents' bound (the call's first operand:
+    ``test_packed_rows_attention.py``)."""
     t, hd = 1024, 128
     shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
         ((t, 2 * hd), jnp.float32), ((t, 2 * hd), jnp.float32),
@@ -619,7 +625,7 @@ def test_the_kernels_loops_under_a_window_come_from_the_shapes_alone():
     )(*shapes)
     call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     kernel = call.params["jaxpr"]
-    tainted = set(kernel.invars[3:5])
+    tainted = set(kernel.invars[4:6])
     for e in kernel.eqns:
         if tainted & {v for v in e.invars if hasattr(v, "count")}:
             tainted |= set(e.outvars)
